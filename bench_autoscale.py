@@ -141,7 +141,7 @@ def _drive(fl, clock, run, rate, duration_s, step_dt=0.02,
             return True
         clock.advance(step_dt)
         it += 1
-        assert it < max_iter, "bench driver wedged"
+        assert it < max_iter, "bench driver stuck"
     return False
 
 

@@ -1,15 +1,12 @@
 """Communication-observatory bench: measure the measurement harness.
 
-Full mode (bench_all chain, TPU with CPU fallback): run a short sharded
-train job with the profiler TraceWindow open, decompose the capture
-through ``deepspeed_tpu/observability/commscope.py`` (exposed vs
-overlapped collective time, per-kind achieved bus bandwidth vs the ICI
-roofline), and write the rows into ``COMMSCOPE_BENCH.json`` PLUS a
-``commscope`` section in the newest ``MULTICHIP_r0*.json`` so
-``perf_ledger`` tracks ``exposed_comm_frac`` (down-is-good) and the
-per-kind achieved-GB/s columns (up-is-good) across PRs. On a backend
-whose profiler has no device op timeline (CPU) every measured column is
-null — recorded, never faked.
+Full mode (bench_all chain; in this process, exits non-zero without a
+TPU — only a TPU's profiler has the device op timeline the anatomy is
+reduced from): run a short sharded train job with the profiler TraceWindow
+open, decompose the capture through
+``deepspeed_tpu/observability/commscope.py`` (exposed vs overlapped
+collective time, per-kind achieved bus bandwidth vs the ICI roofline), and
+write the rows into ``COMMSCOPE_BENCH.json``.
 
 ``--smoke`` is the CPU tier-1 gate (wired via
 tests/unit/test_commscope.py, same pattern as bench_capacity.py):
@@ -32,13 +29,11 @@ tests/unit/test_commscope.py, same pattern as bench_capacity.py):
 Prints one JSON line ending in "smoke-pass"; exits nonzero on failure.
 """
 
-import glob
 import json
 import os
 import sys
 import tempfile
 
-_CHILD_MARK = "_DSTPU_COMMSCOPE_CHILD"
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 _OUT = os.path.join(_ROOT, "COMMSCOPE_BENCH.json")
 
@@ -224,12 +219,14 @@ def smoke():
 
 
 # ------------------------------------------------------------------- full
-def _run_child():
+def main():
     import time
 
     import jax
 
-    platform = jax.devices()[0].platform
+    import bench_common as bc
+
+    platform = bc.require_tpu("commscope")[0].platform
     tdir = tempfile.mkdtemp(prefix="commscope_bench_trace_")
     t0 = time.time()
     eng = build_engine(commscope=True, trace_dir=tdir)
@@ -248,9 +245,7 @@ def _run_child():
         "metric": "commscope_step_anatomy",
         "value": an["exposed_comm_frac"],
         "unit": "exposed-collective fraction of step wall "
-                f"(platform={platform}"
-                + ("" if platform == "tpu" else ", CPU-FALLBACK: "
-                   "no device op timeline — measured columns null") + ")",
+                f"(platform={platform})",
         "platform": platform,
         "n_devices": len(jax.devices()),
         "exposed_comm_frac": an["exposed_comm_frac"],
@@ -263,68 +258,9 @@ def _run_child():
         "seconds": round(time.time() - t0, 1),
         "iso": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
-    print(json.dumps(out), flush=True)
-
-
-def _patch_multichip(result: dict) -> None:
-    """Write the observatory columns into the newest MULTICHIP_r0*.json
-    (the per-round multichip record perf_ledger tracks as one stable
-    series): exposed fraction down-is-good, achieved GB/s up-is-good."""
-    import re
-
-    def round_no(p):
-        m = re.search(r"_r(\d+)\.json$", p)
-        return int(m.group(1)) if m else -1
-
-    # numeric round ordering (lexicographic would rank r100 below r99)
-    cands = sorted(glob.glob(os.path.join(_ROOT, "MULTICHIP_r*.json")),
-                   key=round_no)
-    if not cands:
-        return
-    path = cands[-1]
-    try:
-        with open(path, encoding="utf-8") as f:
-            obj = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return
-    if not isinstance(obj, dict):
-        return
-    obj["commscope"] = {
-        "exposed_comm_frac": result.get("exposed_comm_frac"),
-        "overlap_frac": result.get("overlap_frac"),
-        "achieved_busbw_gbps": {
-            k: v.get("busbw_gbps")
-            for k, v in (result.get("by_kind") or {}).items()},
-        "platform": result.get("platform"),
-    }
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(obj, f, indent=2)
-    print(f"[commscope] wrote commscope section into {path}", flush=True)
-
-
-def main():
-    import bench_common as bc
-
-    if os.environ.get(_CHILD_MARK) == "1":
-        _run_child()
-        return
-    env = dict(os.environ)
-    env[_CHILD_MARK] = "1"
-    me = os.path.abspath(__file__)
-    window_s = float(os.environ.get("DSTPU_BENCH_WINDOW_S", 10 * 60))
-    result = bc.run_with_tpu_window(me, env, window_s=window_s,
-                                    child_timeout=600, tag="commscope")
-    if result is None:
-        bc.log("TPU unavailable; measuring on CPU (anatomy columns "
-               "will be null — no device op timeline)", "commscope")
-        result = bc.run_child(me, bc.cpu_fallback_env(env, n_devices=8),
-                              timeout=600, tag="commscope")
-    if result is None:
-        raise SystemExit("commscope bench failed on TPU and CPU")
     with open(_OUT, "w") as f:
-        json.dump(result, f, indent=2)
-    print(json.dumps(result), flush=True)
-    _patch_multichip(result)
+        json.dump(out, f, indent=2)
+    print(json.dumps(out), flush=True)
 
 
 if __name__ == "__main__":

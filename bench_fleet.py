@@ -124,7 +124,7 @@ def drive(fleet, reqs, kill_after=None, max_iterations=100_000):
             fleet.results.pop(req.rid, None)
         it += 1
         if it > max_iterations:
-            raise RuntimeError("fleet bench driver wedged")
+            raise RuntimeError("fleet bench driver stuck")
     return rids, done
 
 

@@ -96,7 +96,7 @@ def _run_one(srv, prompt, seed, sid, clock=None):
         srv.step()
         it += 1
         if it > 200_000:
-            raise RuntimeError("serving wedged")
+            raise RuntimeError("serving stuck")
 
 
 def _prompts(n=2, seed=7):
@@ -264,7 +264,7 @@ def smoke():
             with open(fp, "r+b") as f:
                 f.write(b"\xff" * 64)
     # and one LOST file (unlink through the store so its fd cache
-    # can't serve the dead inode): the read must miss, not wedge
+    # can't serve the dead inode): the read must miss, not hang
     lost_key = next(iter(srv_nv.nvmekv.entries))
     srv_nv.nvmekv.store.unlink(srv_nv.nvmekv._file(lost_key))
     A, _B = _prompts()

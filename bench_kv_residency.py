@@ -64,7 +64,7 @@ def _run_one(srv, prompt, seed, sid):
         srv.step()
         it += 1
         if it > 200_000:
-            raise RuntimeError("serving wedged")
+            raise RuntimeError("serving stuck")
 
 
 def _prompts(n=2, seed=7):

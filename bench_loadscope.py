@@ -60,7 +60,7 @@ def _run_one(srv, prompt, seed):
         srv.step()
         it += 1
         if it > 200_000:
-            raise RuntimeError("serving wedged")
+            raise RuntimeError("serving stuck")
 
 
 def _traffic(srv, n=8, seed=7):

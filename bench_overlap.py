@@ -1,18 +1,14 @@
 """Quantized + overlapped collectives bench: the thing commscope priced.
 
-Full mode (bench_all chain, TPU with CPU fallback): train the fused-fp
+Full mode (bench_all chain; in this process, exits non-zero without a TPU —
+its subject is collectives over real ICI): train the fused-fp
 grad spelling vs the bucketed-overlap int8 spelling and measure step
 wall, run TP decode with the fp psum vs the two-sided int8 collective
 (``inference.tp_comm_quant``) and measure tokens/s, and land the
 commscope on/off rows — ``Comm/exposed_frac`` + per-kind busbw from
 ``engine.comm_observatory()`` for BOTH spellings — into
-``OVERLAP_BENCH.json``, a ``grad_overlap`` section in
-``COMMSCOPE_BENCH.json``, and an ``overlap`` section in the newest
-``MULTICHIP_r0*.json`` (perf_ledger tracks ``exposed``/``step_time``
-down-is-good, wire ratio down-is-good). On a CPU backend the profiler
-has no device op timeline, so the time-anatomy columns are null —
-recorded, never faked; the static wire-byte columns are exact either
-way.
+``OVERLAP_BENCH.json`` and a ``grad_overlap`` section in
+``COMMSCOPE_BENCH.json``.
 
 ``--smoke`` is the CPU tier-1 gate (wired via
 tests/unit/test_overlap_bench.py):
@@ -42,7 +38,6 @@ import sys
 import tempfile
 import time
 
-_CHILD_MARK = "_DSTPU_OVERLAP_CHILD"
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 _OUT = os.path.join(_ROOT, "OVERLAP_BENCH.json")
 
@@ -297,7 +292,7 @@ def _median(xs):
     return s[len(s) // 2]
 
 
-def _run_child():
+def _run_workload():
     import jax
     import numpy as np
 
@@ -359,7 +354,7 @@ def _run_child():
             for r in range(reps):
                 out = eng.generate(np.asarray(p[None]), 16, greedy=True,
                                    request_seeds=[5 + r], cache_len=64)
-            np.asarray(out)
+            jax.block_until_ready(out)
             dt = (time.perf_counter() - s) / reps
             decode_rows[name] = {"tokens_per_s": 16 / dt,
                                  "wall_s_per_request": dt}
@@ -384,10 +379,7 @@ def _run_child():
         # wire_ratio_vs_fp32 (down-is-good)
         "value": (1.0 / ratio) if ratio else None,
         "unit": "grad wire compression factor vs fp32 flat equivalent "
-                f"(platform={platform}"
-                + ("" if platform == "tpu" else ", CPU-FALLBACK: no "
-                   "device op timeline — exposed/busbw columns null")
-                + ")",
+                f"(platform={platform})",
         "platform": platform,
         "n_devices": n_dev,
         "train": rows,
@@ -400,16 +392,12 @@ def _run_child():
         "seconds": round(time.time() - t0, 1),
         "iso": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
-    print(json.dumps(out), flush=True)
+    return out
 
 
 def _patch_artifacts(result: dict) -> None:
-    """Land the on/off rows beside the PR-12 artifacts: a
-    ``grad_overlap`` section in COMMSCOPE_BENCH.json and an ``overlap``
-    section in the newest MULTICHIP_r0*.json (numeric round order)."""
-    import glob
-    import re
-
+    """Land the on/off rows beside the PR-12 artifact: a
+    ``grad_overlap`` section in COMMSCOPE_BENCH.json."""
     section = {
         "exposed_comm_frac_fused": (result.get("train", {})
                                     .get("fused_fp", {})
@@ -438,54 +426,12 @@ def _patch_artifacts(result: dict) -> None:
     except (OSError, json.JSONDecodeError):
         pass
 
-    def round_no(p):
-        m = re.search(r"_r(\d+)\.json$", p)
-        return int(m.group(1)) if m else -1
-
-    cands = sorted(glob.glob(os.path.join(_ROOT, "MULTICHIP_r*.json")),
-                   key=round_no)
-    if not cands:
-        return
-    path = cands[-1]
-    try:
-        with open(path, encoding="utf-8") as f:
-            obj = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return
-    if not isinstance(obj, dict):
-        return
-    obj["overlap"] = section
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(obj, f, indent=2)
-    print(f"[overlap] wrote overlap section into {path}", flush=True)
-
 
 def main():
     import bench_common as bc
 
-    if os.environ.get(_CHILD_MARK) == "1":
-        _run_child()
-        return
-    env = dict(os.environ)
-    env[_CHILD_MARK] = "1"
-    # multi-device collectives are the whole subject: give the child a
-    # multi-device host platform (affects the CPU backend only — a real
-    # TPU's device count is the hardware's)
-    flags = env.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    me = os.path.abspath(__file__)
-    window_s = float(os.environ.get("DSTPU_BENCH_WINDOW_S", 10 * 60))
-    result = bc.run_with_tpu_window(me, env, window_s=window_s,
-                                    child_timeout=600, tag="overlap")
-    if result is None:
-        bc.log("TPU unavailable; measuring on CPU (exposed/busbw columns "
-               "will be null — no device op timeline)", "overlap")
-        result = bc.run_child(me, bc.cpu_fallback_env(env, n_devices=8),
-                              timeout=600, tag="overlap")
-    if result is None:
-        raise SystemExit("overlap bench failed on TPU and CPU")
+    bc.require_tpu("overlap")
+    result = _run_workload()
     with open(_OUT, "w") as f:
         json.dump(result, f, indent=2)
     print(json.dumps(result), flush=True)

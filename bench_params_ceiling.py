@@ -19,27 +19,19 @@ probed safely; the binary search walks layer count at GPT-2-XL-class width
 (d=2560) until the compiler's per-device footprint crosses the HBM budget.
 
 Artifact ``PARAMS_CEILING.json``: per-tier ceilings with the AOT byte
-breakdown. vs_baseline = (best params/GB) / (13 B / 32 GB).  On the CPU
-fallback the HLO/buffer assignment is computed by XLA:CPU against the v5e
-budget — labeled ``platform=cpu`` (the buffer sizes are shape/dtype-driven
-and carry over; fusion deltas are second-order), superseded whenever the
-TPU window grants.
+breakdown. vs_baseline = (best params/GB) / (13 B / 32 GB). The verdicts are
+the TPU compiler's buffer assignment, so the search runs in this process on
+the chip and exits non-zero without one.
 """
 
 import json
-import math
 import os
-import sys
 import tempfile
-import time
 
 import bench_common as bc
 
-_CHILD_MARK = "_DSTPU_PCEIL_CHILD"
-_WINDOW_S = float(os.environ.get("DSTPU_BENCH_WINDOW_S", 20 * 60))
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 _OUT = os.path.join(_ROOT, "PARAMS_CEILING.json")
-_CACHE = os.path.join(_ROOT, "PARAMS_CEILING_TPU_CACHE.json")
 
 _V5E_HBM = 16 * 2 ** 30          # budget when the backend reports no limit
 _BUDGET_FRAC = 0.94              # leave allocator headroom
@@ -114,16 +106,8 @@ def _probe(tier: str, n_layer: int, budget: int, nvme_dir: str):
     return row["fits"], row
 
 
-def _run_search():
-    import jax
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    limit = None
-    try:
-        limit = (devices[0].memory_stats() or {}).get("bytes_limit")
-    except Exception:
-        pass
+def _run_search(devices):
+    limit = (devices[0].memory_stats() or {}).get("bytes_limit")
     budget = int((limit or _V5E_HBM) * _BUDGET_FRAC)
     nvme_dir = tempfile.mkdtemp(prefix="dstpu_pceil_nvme_")
 
@@ -137,7 +121,7 @@ def _run_search():
         l_try = l_est
         best_row = None
         n_probes = 0
-        max_probes = 6 if on_tpu else 8
+        max_probes = 6
         while n_probes < max_probes:
             l_try = max(1, min(l_try, 2000))
             n_probes += 1
@@ -167,11 +151,11 @@ def _run_search():
             l_try = nxt
         if best_row is not None:
             tiers[tier] = best_row
-    return tiers, probes, budget, on_tpu, devices[0].platform
+    return tiers, probes, budget
 
 
-def _run_child():
-    tiers, probes, budget, on_tpu, platform = _run_search()
+def _run_workload(devices):
+    tiers, probes, budget = _run_search(devices)
     if not tiers:
         raise SystemExit("no tier produced a feasible config")
     best_tier = max(tiers, key=lambda t: tiers[t]["params"])
@@ -185,37 +169,18 @@ def _run_child():
         "unit": (f"B params trainable on one chip ({budget_gb:.1f} GiB "
                  f"budget, tier={best_tier}, d={_D_MODEL} "
                  f"L={best['n_layer']} seq={_SEQ} mbs={_MICRO} remat=on, "
-                 f"AOT buffer-assignment verdicts, platform={platform}"
-                 + ("" if on_tpu else ", CPU-FALLBACK: XLA:CPU buffer "
-                    "assignment vs the v5e budget") + ")"),
+                 f"AOT buffer-assignment verdicts, "
+                 f"platform={devices[0].platform}, "
+                 f"device_kind={devices[0].device_kind})"),
         "tiers": tiers,
         "probes": [{k: v for k, v in p.items() if k != "detail"}
                    for p in probes],
     }
-    if on_tpu:
-        bc.save_tpu_cache(_CACHE, result)
-    print(json.dumps(result), flush=True)
+    return result
 
 
 def main():
-    if os.environ.get(_CHILD_MARK) == "1":
-        _run_child()
-        return
-    bc.emit_cache_upfront(_CACHE, tag="pceil", out_path=_OUT)
-    env = dict(os.environ)
-    env[_CHILD_MARK] = "1"
-    me = os.path.abspath(__file__)
-    result = bc.run_with_tpu_window(me, env, window_s=_WINDOW_S,
-                                    child_timeout=1500, tag="pceil")
-    if result is None:
-        result = bc.cached_result(_CACHE, tag="pceil")
-    if result is None:
-        bc.log("TPU unavailable; AOT search on XLA:CPU vs the v5e budget",
-               "pceil")
-        cpu_env = bc.cpu_fallback_env(env, n_devices=1)
-        result = bc.run_child(me, cpu_env, timeout=2400, tag="pceil")
-    if result is None:
-        raise SystemExit("params-ceiling bench failed on TPU and CPU")
+    result = _run_workload(bc.require_tpu("pceil"))
     with open(_OUT, "w") as f:
         json.dump(result, f, indent=2)
     print(json.dumps(result), flush=True)

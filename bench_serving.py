@@ -111,7 +111,7 @@ def run_multiturn(srv, plan, max_iterations=200_000, ttfts=None):
                 submit(s)
         it += 1
         if it > max_iterations:
-            raise RuntimeError("multi-turn driver wedged")
+            raise RuntimeError("multi-turn driver stuck")
     return prompts, outs
 
 

@@ -80,7 +80,7 @@ def _drive(srv, rid):
         if req is not None:
             return req
         srv.step()
-    raise RuntimeError("serving wedged")
+    raise RuntimeError("serving stuck")
 
 
 def _traffic(srv, plan, seed=7):

@@ -8,7 +8,6 @@ Pallas kernels for the hot ops, sharded universal checkpoints, inference/
 decode engine, and the observability stack.
 """
 
-from . import compat  # noqa: F401  (must run before any jax-0.9 API use)
 from .config import Config
 from .inference import (InferenceConfig, InferenceEngine, ServingConfig,
                         init_inference)

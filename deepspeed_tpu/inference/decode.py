@@ -695,7 +695,7 @@ def decode_tokens(model, params, carry: GenCarry, *, steps: int, sampler,
     # emitted tokens 0..steps-1 plus the final carry token. Constrain both
     # concat operands to an explicit replicated layout first: under TP the
     # partitioner resolves the scan-stacked ys and the carry token to
-    # DIFFERENT shardings, and (jax 0.4.x GSPMD) reconciles them with a
+    # DIFFERENT shardings, and GSPMD has reconciled them with a
     # spurious cross-shard reduce — every emitted token id summed tp_size
     # times. Token ids are (steps, B) int32 — replication is free next to
     # a decode step, and the constraint is a no-op off-mesh.

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..platform.mesh import MeshSpec, build_mesh
+from ..platform.mesh import MeshSpec, build_mesh, fit_specs
 from ..utils.logging import log_dist
 from .config import InferenceConfig
 from .decode import decode_tokens, generate_tokens, prefill_tokens
@@ -110,6 +110,8 @@ class InferenceEngine:
         if self._fused:
             cast = self._fuse_qkv_params(cast)
             specs = self._fuse_qkv_specs(specs)
+        # a TP-sharded dim the mesh does not divide (an odd vocab) replicates
+        specs = fit_specs(specs, cast, self.mesh)
         if cfg.quantize:
             # WOQ x TP: quantize straight into the sharded layout — the
             # shardings for the quantized tree come from the same

@@ -19,7 +19,7 @@ dim (the contraction dim of an ``x @ W`` projection) share one scale row:
 This is the layout that lets the fused GEMM fold the scale *outside* the
 int8 dot (one ``(1, bn)`` multiply per k-step) instead of dequantizing
 whole tiles. int4 packs two signed nibbles per byte along *adjacent rows*
-of the grouped dim (sublane-interleave unpack — Mosaic-friendly).
+of the grouped dim (the kernel unpacks them with shifts and a sublane concat).
 """
 
 from __future__ import annotations
@@ -151,6 +151,20 @@ def _mesh_tp():
     return mesh, int(mesh.shape["model"])
 
 
+def _whole_on_each_device(fn, *args):
+    """Run a Pallas GEMM whole on every device of a multi-device mesh —
+    GSPMD cannot partition a Mosaic kernel, and a leaf that reaches here
+    has no ``model``-sharded layout to run shard-local. A plain call on
+    one device or inside a manual region."""
+    from ..platform.mesh import kernel_mesh
+
+    mesh = kernel_mesh()
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(P() for _ in args),
+                         out_specs=P(), check_vma=False)(*args)
+
+
 def _has_model(entry) -> bool:
     names = entry if isinstance(entry, (tuple, list)) else (entry,)
     return "model" in names
@@ -234,8 +248,10 @@ def woq_dot(x, qt: QuantizedTensor, use_kernel: bool = False,
                            out_specs=P(None, None), check_vma=False)
         out2 = fn(x2, qt.q, qt.scale)
     else:
-        out2 = woq_matmul(x2, qt.q, qt.scale, group_size=gs, bits=bits,
-                          out_dtype=out_dtype)
+        out2 = _whole_on_each_device(
+            lambda xs, qs, ss: woq_matmul(xs, qs, ss, group_size=gs,
+                                          bits=bits, out_dtype=out_dtype),
+            x2, qt.q, qt.scale)
     return out2.reshape(x.shape[:-1] + (N,))
 
 
@@ -285,8 +301,10 @@ def woq_dot_t(x, qt: QuantizedTensor, use_kernel: bool = False,
         return jax.lax.dot_general(x, w, (((x.ndim - 1,), (1,)), ((), ())),
                                    preferred_element_type=out_dtype)
     else:
-        out2 = woq_matmul_t(x2, qt.q, qt.scale, group_size=gs, bits=bits,
-                            out_dtype=out_dtype)
+        out2 = _whole_on_each_device(
+            lambda xs, qs, ss: woq_matmul_t(xs, qs, ss, group_size=gs,
+                                            bits=bits, out_dtype=out_dtype),
+            x2, qt.q, qt.scale)
     return out2.reshape(x.shape[:-1] + (V,))
 
 
@@ -391,7 +409,12 @@ def quantized_shardings(specs: Any, qtree: Any, mesh) -> Any:
     ``scale`` — shaped ``orig[:-2] + (G, N)`` — takes the same entries
     with the second-to-last (grouped-dim) entry kept on G when G > 1 and
     dropped (replicated) when the leaf degraded to one whole group, where
-    a sharded size-1 dim would be invalid."""
+    a sharded size-1 dim would be invalid. An entry that does not divide
+    its dim (10 scale groups over ``model`` = 4) is dropped too
+    (``platform.mesh.fit_spec``); ``woq_dot`` then takes the per-use
+    dequant for that leaf."""
+    from ..platform.mesh import fit_spec
+
     def leaf_shardings(spec, q_or_leaf):
         spec = spec if spec is not None else P()
         if not isinstance(q_or_leaf, QuantizedTensor):
@@ -401,9 +424,11 @@ def quantized_shardings(specs: Any, qtree: Any, mesh) -> Any:
         n_groups = q_or_leaf.scale.shape[-2]
         group_entry = entries[-2] if n_groups > 1 else None
         return QuantizedTensor(
-            q=NamedSharding(mesh, P(*entries)),
-            scale=NamedSharding(mesh, P(*entries[:-2], group_entry,
-                                        entries[-1])),
+            q=NamedSharding(mesh, fit_spec(P(*entries), q_or_leaf.q.shape,
+                                           mesh)),
+            scale=NamedSharding(mesh, fit_spec(
+                P(*entries[:-2], group_entry, entries[-1]),
+                q_or_leaf.scale.shape, mesh)),
             group_size=q_or_leaf.group_size, bits=q_or_leaf.bits,
             pspec=q_or_leaf.pspec)
 

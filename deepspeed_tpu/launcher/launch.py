@@ -5,8 +5,8 @@ process per local rank with the distributed env set, redirect logs, poll
 children, and kill the whole local group if any child dies (the
 ``sigkill_handler``).  On TPU pods the common shape is ONE process per host
 owning all local chips (JAX convention), so ``--nproc`` defaults to 1; the
-multi-process-per-host mode exists for CPU simulation, subdevice tunnels,
-and the multi-process test harness (SURVEY §4's DistributedTest analog).
+multi-process-per-host mode exists for CPU simulation, one process per
+chip, and the multi-process test harness (SURVEY §4's DistributedTest analog).
 
 Env contract consumed by ``platform.accelerator.init_distributed``:
   DSTPU_COORDINATOR     coordinator address host:port (process 0's host)

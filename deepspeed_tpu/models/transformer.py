@@ -225,7 +225,10 @@ def vocab_parallel_lookup(table, ids):
     from ..platform.mesh import manual_axes_of
     manual = manual_axes_of(ctx) if ctx is not None else frozenset()
     if (ctx is None or "model" not in getattr(ctx, "axis_names", ())
-            or ctx.shape["model"] == 1 or manual):
+            or ctx.shape["model"] == 1 or manual
+            or table.shape[0] % ctx.shape["model"] != 0):
+        # (a vocab the model axis does not divide is replicated —
+        # platform.mesh.fit_spec — so the plain gather is already local)
         return table[ids]
 
     def lookup(tbl, idx):
@@ -314,9 +317,15 @@ def fused_nll_sharded(feats, targets, table, bias=None):
     if dp > 1 or tp > 1:
         has_b = bias is not None
 
+        # a vocab the model axis does not divide is replicated
+        # (platform.mesh.fit_spec): the model axis then splits the batch
+        # too, and every device runs the whole-vocab kernel over its own
+        # sequences (_fused_xent_active admits only batches that divide)
+        vocab_tp = tp > 1 and table.shape[0] % tp == 0
+
         def body(h, w, *rest):
             b, t = rest if has_b else (None, rest[0])
-            if tp > 1:
+            if vocab_tp:
                 return fused_token_nll_tp(h, w, b, t, "model")
             return fused_token_nll(h, w, b, t)
 
@@ -324,8 +333,11 @@ def fused_nll_sharded(feats, targets, table, bias=None):
         # mesh with, say, just a "data" axis still takes the fused path
         # instead of crashing on an unknown axis name (advisor r3). tp > 1
         # implies "model" exists (tp is read off the mesh above).
-        b_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names) or None
-        mdl = "model" if "model" in mesh.axis_names else None
+        b_axes = tuple(a for a in BATCH_AXES if a in mesh.axis_names)
+        if tp > 1 and not vocab_tp:
+            b_axes += ("model",)
+        b_axes = b_axes or None
+        mdl = "model" if vocab_tp else None
         in_specs = ((P(b_axes, None), P(mdl, None))
                     + ((P(mdl),) if has_b else ()) + (P(b_axes),))
         args = (h2, table) + ((bias,) if has_b else ()) + (t2,)
@@ -851,14 +863,14 @@ class TransformerLM:
             for ax in ("seq", "pipe"):
                 if ax in mesh.axis_names and mesh.shape[ax] != 1:
                     return False
-            # model-axis sharding IS supported (vocab-sharded TP kernel:
-            # per-shard partials + two collectives) when the vocab splits
-            # evenly across the axis
+            # model-axis sharding IS supported: the vocab-sharded TP
+            # kernel (per-shard partials + two collectives) when the vocab
+            # splits evenly across the axis, else the whole-vocab kernel
+            # on the replicated table (fused_nll_sharded)
             tp = int(mesh.shape.get("model", 1))
-            if tp > 1 and cfg.vocab_size % tp != 0:
-                return False
-            if batch_size is not None \
-                    and batch_size % self._dp_world(mesh) != 0:
+            rows = self._dp_world(mesh) * (
+                tp if cfg.vocab_size % tp != 0 else 1)
+            if batch_size is not None and batch_size % rows != 0:
                 return False
         if cfg.fused_xent:
             return True

@@ -688,7 +688,7 @@ class ReplayDriver:
                 raise RuntimeError(
                     f"replay failed to finish in {self.max_iterations} "
                     f"iterations ({len(collected)}/{len(self._rid_map)} "
-                    "collected) — engine wedged?")
+                    "collected) — engine stuck?")
         self._compare(rep, collected, recorded)
         return rep
 
@@ -1072,7 +1072,7 @@ def _drive_timeline(engine, trace: TrafficTrace, clock: ReplayClock,
         it += 1
         if it > max_iterations:
             raise RuntimeError(
-                f"scaling backtest wedged: {len(done)}/{submitted} "
+                f"scaling backtest stuck: {len(done)}/{submitted} "
                 f"finished after {max_iterations} iterations")
     return done, shed
 

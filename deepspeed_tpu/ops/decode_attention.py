@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 BIG_NEG = -2.0 ** 30
 SUBLANES = 8
@@ -103,6 +104,28 @@ def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
     scale = 1.0 / math.sqrt(hd)
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
     alibi = alibi_slopes is not None
+    from ..platform.mesh import attention_shard_axes
+
+    axes = attention_shard_axes(B, H, KV)
+    if axes is not None:
+        # GSPMD cannot partition a Mosaic kernel: run it per shard, slots
+        # over the example-parallel axes and heads over model/seq (inside
+        # the body the axes are manual, so the recursion lands below)
+        mesh, b_ax, h_ax = axes
+        cache = P(b_ax, h_ax, None, None)
+
+        def per_shard(q, ck, cv, n, *slopes):
+            return decode_attention(q, ck, cv, n, block=block,
+                                    interpret=interpret,
+                                    alibi_slopes=slopes[0] if slopes else None)
+
+        return jax.shard_map(
+            per_shard, mesh=mesh,
+            in_specs=(P(b_ax, None, h_ax, None), cache, cache, P(b_ax))
+            + ((P(h_ax),) if alibi else ()),
+            out_specs=P(b_ax, None, h_ax, None), check_vma=False)(
+                q, ck, cv, lengths,
+                *((jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()))
 
     # (B, 1, H, hd) → (B, H, SUBLANES, hd): sublane-replicated single query
     qs = jnp.broadcast_to(q.swapaxes(1, 2), (B, H, SUBLANES, hd))
@@ -126,6 +149,7 @@ def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
     )
     out = pl.pallas_call(
         partial(_decode_kernel, block=blk, scale=scale, alibi=alibi),
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, hd), q.dtype),
         interpret=interpret,

@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
 BIG_NEG = -2.0 ** 30
 SUBLANES = 8  # fp32 sublane tile: lse/delta rows replicated to (8, S)
@@ -209,6 +210,7 @@ def _fwd_call(q, k, v, mask, bias, *, block: int, causal: bool,
         args.append(alibi)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -373,6 +375,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, bias, *, block: int, causal: bool,
     dq_outs = pl.pallas_call(
         _make_bwd_dq_kernel(block, scale, causal, masked, biased, grad_bias,
                             has_alibi),
+        name="flash_attention_bwd_dq",
         grid=grid,
         in_specs=[blk_spec, full_spec, full_spec, blk_spec, row_blk, row_blk]
                  + extra_row,
@@ -388,6 +391,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, bias, *, block: int, causal: bool,
 
     dk, dv = pl.pallas_call(
         _make_bwd_dkv_kernel(block, scale, causal, masked, biased, has_alibi),
+        name="flash_attention_bwd_dkv",
         grid=grid,
         in_specs=[full_spec, blk_spec, blk_spec, full_spec, row_full, row_full]
                  + extra_col,
@@ -513,6 +517,7 @@ def _fwd_call_streamed(q, k, v, mask, *, block: int, causal: bool,
         args.append(alibi)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -658,6 +663,7 @@ def _bwd_call_streamed(q, k, v, o, lse, do, mask, *, block: int, causal: bool,
     dq = pl.pallas_call(
         _make_bwd_dq_kernel_streamed(block, scale, causal, masked,
                                      alibi is not None, nk),
+        name="flash_attention_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[q_blk, kv_blk, kv_blk, q_blk, row_q, row_q] + extra_dq,
         out_specs=[q_blk],
@@ -681,6 +687,7 @@ def _bwd_call_streamed(q, k, v, o, lse, do, mask, *, block: int, causal: bool,
     dk, dv = pl.pallas_call(
         _make_bwd_dkv_kernel_streamed(block, scale, causal, masked,
                                       alibi is not None, nq),
+        name="flash_attention_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=[q_blk2, kv_blk2, kv_blk2, q_blk2, row_q2, row_q2]
                  + extra_dkv,
@@ -901,6 +908,29 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     KV = k.shape[2]
+    from ..platform.mesh import attention_shard_axes
+
+    axes = attention_shard_axes(B, H, KV) if bias is None else None
+    if axes is not None:
+        # GSPMD cannot partition a Mosaic kernel: run it per shard, batch
+        # over the example-parallel axes and heads over model/seq (inside
+        # the body the axes are manual, so the recursion lands below)
+        mesh, b_ax, h_ax = axes
+        qkv = P(b_ax, None, h_ax, None)
+        extra = {"mask": (mask, P(b_ax, None)),
+                 "alibi_slopes": (alibi_slopes, P(h_ax))}
+        names = [n for n, (a, _) in extra.items() if a is not None]
+
+        def per_shard(q, k, v, *rest):
+            return flash_attention(q, k, v, causal=causal, block=blk,
+                                   interpret=interpret,
+                                   **dict(zip(names, rest)))
+
+        return jax.shard_map(
+            per_shard, mesh=mesh,
+            in_specs=(qkv, qkv, qkv) + tuple(extra[n][1] for n in names),
+            out_specs=qkv, check_vma=False)(
+                q, k, v, *(extra[n][0] for n in names))
     if KV != H:  # GQA: differentiable repeat — dk/dv group-sum via autodiff
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)

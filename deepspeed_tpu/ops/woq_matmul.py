@@ -27,10 +27,17 @@ row, so ``scale`` is ``(G, N)`` fp32 for a ``(K, N)`` weight with
   (``bv <= group_size``), the ``(1, bc)`` scale row broadcasts over the
   tile's rows in VMEM, then the MXU contracts the lane dim.
 
+The TPU lowering wants the last two dims of every block divisible by
+(8, 128) or equal to the array's, so a ``(1, bn)`` scale block is refused.
+The scale operand is therefore blocked ``(gb, bn)`` — eight group rows, or
+all ``G`` of them when ``G`` is no multiple of eight — and the kernel picks
+its row with a dynamic sublane slice (:func:`_scale_rows`).
+
 int4 packs two signed nibbles per byte along *adjacent rows* of the grouped
-dim (row ``2r`` low nibble, ``2r+1`` high): in-kernel unpack is two
-arithmetic shifts + a sublane interleave — lane layout untouched, which is
-what Mosaic relayouts care about. Everything runs under
+dim (row ``2r`` low nibble, ``2r+1`` high): in-kernel unpack is two int32
+shifts + a sublane concat (even rows, then odd rows); the matching column
+permutation is applied to the small activation (or output) outside the
+kernel, so the weight's lane layout is untouched. Everything runs under
 ``interpret=True`` off-TPU, so parity is tier-1-testable on CPU.
 """
 
@@ -51,16 +58,40 @@ from .xent import _pow2_ceil, _resolve_interpret
 
 
 def _unpack_rows(p):
-    """(R/2, C) packed bytes → (R, C) signed int4 values in int8: two
-    arithmetic shifts + a sublane interleave (lane dim untouched)."""
-    lo = (p << 4).astype(jnp.int8) >> 4          # sign-extend low nibble
+    """(R/2, C) packed bytes → (R, C) signed int4 values, DE-interleaved:
+    the R/2 low nibbles (even rows of the weight) stacked on the R/2 high
+    nibbles (odd rows). Shifts run in int32 (Mosaic has no int8 shift) and
+    the stack is a plain sublane concat — no interleave, lanes untouched.
+    The callers permute the activation's columns (:func:`_even_odd`) or
+    the output's (:func:`woq_matmul_t`) to match."""
+    p = p.astype(jnp.int32)
+    lo = (p << 28) >> 28                         # sign-extend low nibble
     hi = p >> 4                                  # arithmetic: high nibble
-    return jnp.stack([lo, hi], axis=1).reshape(p.shape[0] * 2, p.shape[1])
+    return jnp.concatenate([lo, hi], axis=0)
+
+
+def _even_odd(x, tile: int):
+    """Reorder the last dim so each ``tile`` holds its even columns, then
+    its odd ones — the order :func:`_unpack_rows` yields weight rows in."""
+    lead = x.shape[:-1]
+    return x.reshape(*lead, -1, tile // 2, 2).swapaxes(-1, -2).reshape(x.shape)
+
+
+def _odd_even_inverse(x, tile: int):
+    """Undo :func:`_even_odd` on the last dim."""
+    lead = x.shape[:-1]
+    return x.reshape(*lead, -1, 2, tile // 2).swapaxes(-1, -2).reshape(x.shape)
+
+
+def _scale_rows(G: int) -> int:
+    """Sublane extent of the scale block: 8 rows when that tiles ``G``,
+    else the whole group dim (a block dim equal to the array's is legal)."""
+    return 8 if G % 8 == 0 else G
 
 
 # --------------------------------------------------------- x @ W  (K, N)
 def _matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_sc, *, n_k: int,
-                   bits: int):
+                   bits: int, gb: int):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -77,7 +108,7 @@ def _matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_sc, *, n_k: int,
     # out of the dot and multiplies the fp32 partial instead (the MXU runs
     # a pure integer-valued matmul).
     part = jnp.dot(x, q.astype(x.dtype), preferred_element_type=jnp.float32)
-    acc_sc[...] += part * s_ref[...]             # (1, bn) broadcast
+    acc_sc[...] += part * s_ref[pl.ds(k % gb, 1), :]   # (1, bn) broadcast
 
     @pl.when(k == n_k - 1)
     def _emit():
@@ -104,19 +135,23 @@ def woq_matmul(x, q, scale, *, group_size: int, bits: int = 8,
 
     bm = min(block_m, max(16, _pow2_ceil(M)))
     bn = min(block_n, _pow2_ceil(N))
+    if bits == 4:
+        x = _even_odd(x, gs)
     xp = _pad_axis(x, bm, 0)
     qp = _pad_axis(q, bn, 1)
     sp = _pad_axis(scale, bn, 1)
     Mp, Np = xp.shape[0], qp.shape[1]
     rows = gs // 2 if bits == 4 else gs          # q rows per k-step
+    gb = _scale_rows(G)
 
     out = pl.pallas_call(
-        functools.partial(_matmul_kernel, n_k=G, bits=bits),
+        functools.partial(_matmul_kernel, n_k=G, bits=bits, gb=gb),
+        name="woq_matmul",
         grid=(Mp // bm, Np // bn, G),
         in_specs=[
             pl.BlockSpec((bm, gs), lambda i, j, k: (i, k)),
             pl.BlockSpec((rows, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),
+            pl.BlockSpec((gb, bn), lambda i, j, k: (k // gb, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
@@ -128,8 +163,9 @@ def woq_matmul(x, q, scale, *, group_size: int, bits: int = 8,
 
 # ------------------------------------------------------ x @ W.T  (V, K)
 def _matmul_t_kernel(x_ref, q_ref, s_ref, o_ref, acc_sc, *, n_k: int,
-                     bits: int):
+                     bits: int, gb: int, tiles_per_group: int):
     k = pl.program_id(2)
+    row = (pl.program_id(1) // tiles_per_group) % gb
 
     @pl.when(k == 0)
     def _init():
@@ -142,7 +178,7 @@ def _matmul_t_kernel(x_ref, q_ref, s_ref, o_ref, acc_sc, *, n_k: int,
     # the whole (bv, bc) tile sits in ONE row group (bv <= group_size), so
     # its scale is a single (1, bc) row broadcast down the tile — dequant
     # in VMEM, then contract the lane dim on the MXU
-    wd = (q.astype(jnp.float32) * s_ref[...]).astype(x.dtype)
+    wd = (q.astype(jnp.float32) * s_ref[pl.ds(row, 1), :]).astype(x.dtype)
     acc_sc[...] += lax.dot_general(x, wd, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
@@ -175,38 +211,40 @@ def woq_matmul_t(x, q, scale, *, group_size: int, bits: int = 8,
         # degraded single group (odd vocab): every row shares the scale
         # row, so the output tile is unconstrained by group alignment
         bv = min(block_v, max(2 if bits == 4 else 1, _pow2_ceil(V)))
-
-        def sidx(i, j, k):
-            return (0, k)
     else:
         # output tile bounded by (and aligned to) one group so its scale
         # is a single row: bv | gs, largest candidate first
         bv = block_v if gs % block_v == 0 else gs
-
-        def sidx(i, j, k):
-            return (j * bv // gs, k)
+    gb = _scale_rows(G)
 
     xp = _pad_axis(_pad_axis(x, bm, 0), bc, 1)
     qrows = bv // 2 if bits == 4 else bv
     qp = _pad_axis(_pad_axis(q, qrows, 0), bc, 1)
     Vp = qp.shape[0] * (2 if bits == 4 else 1)
+    # output tiles per scale row (one group: every tile reads row 0)
+    tiles_per_group = Vp // bv if G == 1 else gs // bv
     sp = _pad_axis(scale, bc, 1)
     Mp, Kp = xp.shape
     n_c = Kp // bc
 
     out = pl.pallas_call(
-        functools.partial(_matmul_t_kernel, n_k=n_c, bits=bits),
+        functools.partial(_matmul_t_kernel, n_k=n_c, bits=bits, gb=gb,
+                          tiles_per_group=tiles_per_group),
+        name="woq_matmul_t",
         grid=(Mp // bm, Vp // bv, n_c),
         in_specs=[
             pl.BlockSpec((bm, bc), lambda i, j, k: (i, k)),
             pl.BlockSpec((qrows, bc), lambda i, j, k: (j, k)),
-            pl.BlockSpec((1, bc), sidx),
+            pl.BlockSpec((gb, bc),
+                         lambda i, j, k: (j // tiles_per_group // gb, k)),
         ],
         out_specs=pl.BlockSpec((bm, bv), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Vp), out_dtype),
         scratch_shapes=[_vmem((bm, bv))],
         interpret=interpret,
     )(xp, qp, sp)
+    if bits == 4:
+        out = _odd_even_inverse(out, bv)
     return out[:M, :V]
 
 

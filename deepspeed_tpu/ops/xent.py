@@ -271,6 +271,7 @@ def _fwd(x, w, bias, targets, block_t, block_v, interpret, partials=False):
     n_ti, n_vj = Tp // bt, Vp // bv
     nll, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, V=V, n_vj=n_vj, partials=partials),
+        name="fused_xent_fwd",
         grid=(n_ti, n_vj),
         in_specs=[
             pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
@@ -314,6 +315,7 @@ def _bwd_kernels(x, w, bias, targets, lse, g, block_t, block_v, interpret):
 
     dx = pl.pallas_call(
         functools.partial(_dx_kernel, V=V, n_vj=n_vj),
+        name="fused_xent_bwd_dx",
         grid=(n_ti, n_vj),
         in_specs=[
             pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
@@ -331,6 +333,7 @@ def _bwd_kernels(x, w, bias, targets, lse, g, block_t, block_v, interpret):
 
     dw, db = pl.pallas_call(
         functools.partial(_dw_kernel, V=V, n_ti=n_ti),
+        name="fused_xent_bwd_dw",
         grid=(n_vj, n_ti),
         in_specs=[
             pl.BlockSpec((bt, d), lambda j, i: (i, 0)),

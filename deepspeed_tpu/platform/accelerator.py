@@ -48,7 +48,7 @@ class TpuAccelerator:
     """Device/platform facade over JAX.
 
     Named "Tpu" for the primary target, but transparently backed by whatever
-    platform JAX selected (tpu / cpu / gpu / experimental tunnels), the same
+    platform JAX selected (tpu / cpu / gpu), the same
     way the reference probes for the real accelerator at import time
     (``accelerator/real_accelerator.py``).
     """

@@ -92,11 +92,11 @@ def build_mesh(spec: MeshSpec | None = None,
         devices = jax.devices()
     sizes = spec.resolve(len(devices))
     shape = tuple(sizes[a] for a in AXIS_ORDER)
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
-        # Fallback (e.g. host-platform CPU devices with no topology info).
+    if devices[0].platform == "cpu":
+        # host-platform devices carry no topology to lay a mesh out on
         dev_array = np.asarray(list(devices)).reshape(shape)
+    else:
+        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
     mesh = Mesh(dev_array, AXIS_ORDER)
     logger.info(f"mesh: {dict(zip(AXIS_ORDER, shape))} over {len(devices)} devices")
     return mesh
@@ -134,43 +134,23 @@ def local_batch_slice(mesh: Mesh) -> tuple[int, int]:
 
 def current_mesh():
     """The mesh active in this trace/context, or None. Checks the abstract
-    mesh first (``jax.set_mesh`` / inside-jit), then the legacy
-    ``with mesh:`` thread resources."""
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:          # jax 0.4.x: no abstract-mesh API
-        get_abstract_mesh = None
-    if get_abstract_mesh is not None:
-        ctx = get_abstract_mesh()
-        if ctx is not None and not ctx.empty:
-            return ctx
-    try:
-        from jax._src.mesh import thread_resources
+    mesh first (``jax.set_mesh`` / inside-jit / a shard_map body), then the
+    ``with mesh:`` thread resources the engines enter."""
+    from jax._src.mesh import thread_resources
+    from jax.sharding import get_abstract_mesh
 
-        ctx = thread_resources.env.physical_mesh
-    except Exception:
-        return None
+    ctx = get_abstract_mesh()
+    if ctx is not None and not ctx.empty:
+        return ctx
+    ctx = thread_resources.env.physical_mesh
     return None if (ctx is None or ctx.empty) else ctx
 
 
 def manual_axes_of(mesh) -> frozenset:
     """Axis names that are *manual* in the current trace context — i.e.
     the caller already holds a per-device block of them (inside a
-    shard_map body). jax 0.9 exposes this as ``AbstractMesh.manual_axes``;
-    on 0.4.x the physical mesh carries no such attribute, but the bound
-    axis-env names ARE the manual axes."""
-    manual = getattr(mesh, "manual_axes", None)
-    if manual is not None:
-        # present-but-empty is an ANSWER (nothing manual) — falling
-        # through to the axis-env probe would misreport vmap/pmap
-        # axis_name frames as manual mesh axes
-        return frozenset(manual)
-    try:
-        from jax.core import unsafe_get_axis_names_DO_NOT_USE as _names
-
-        return frozenset(_names())
-    except (ImportError, AttributeError):
-        return frozenset()
+    shard_map body)."""
+    return frozenset(mesh.manual_axes)
 
 
 def constrain(x, *spec_or_pspec):
@@ -185,13 +165,70 @@ def constrain(x, *spec_or_pspec):
         return x
     spec = spec_or_pspec[0] if len(spec_or_pspec) == 1 and isinstance(
         spec_or_pspec[0], PartitionSpec) else PartitionSpec(*spec_or_pspec)
-    filtered = filter_spec(spec)
+    filtered = fit_spec(filter_spec(spec), x.shape, ctx)
     # Inside a manual region a fully-filtered (all-None) constraint is a
-    # no-op intent-wise; older JAX additionally has no replication rule
-    # for the primitive there (check_rep) — skip it outright.
+    # no-op intent-wise — skip it outright.
     if manual_axes_of(ctx) and all(e is None for e in filtered):
         return x
     return jax.lax.with_sharding_constraint(x, filtered)
+
+
+def fit_spec(spec: Optional[PartitionSpec], shape, mesh) -> PartitionSpec:
+    """Drop the entries of ``spec`` whose mesh-axis product does not divide
+    the dimension they shard: that dimension stays replicated. A published
+    width need not divide the mesh — GPT-2's vocabulary of 50257 is odd, so
+    its embedding cannot be vocab-split over any ``model`` axis — and JAX
+    refuses an uneven ``NamedSharding`` outright."""
+    entries = list(spec) if spec is not None else []
+
+    def fits(e, dim):
+        names = e if isinstance(e, (tuple, list)) else (e,)
+        n = int(np.prod([mesh.shape[a] for a in names
+                         if a in mesh.axis_names]))
+        return dim % n == 0
+
+    return PartitionSpec(*(
+        e if e is None or (i < len(shape) and fits(e, shape[i])) else None
+        for i, e in enumerate(entries)))
+
+
+def kernel_mesh():
+    """The context mesh when a Pallas call under it needs a ``shard_map``
+    — GSPMD cannot partition a Mosaic kernel — else None: no mesh, one
+    device, or already inside a manual region."""
+    mesh = current_mesh()
+    if mesh is None or mesh.empty or mesh.size == 1 or manual_axes_of(mesh):
+        return None
+    return mesh
+
+
+def attention_shard_axes(batch: int, heads: int, kv_heads: int):
+    """``(mesh, batch_axes, head_axes)`` to shard_map an attention kernel
+    over, or None where :func:`kernel_mesh` is: batch over the
+    example-parallel axes, heads over ``model``/``seq`` (the layout
+    ``_attention_block`` constrains to). An axis group that does not divide
+    its dim is left out (replicated)."""
+    mesh = kernel_mesh()
+    if mesh is None:
+        return None
+
+    def group(names, *dims):
+        names = tuple(a for a in names
+                      if a in mesh.axis_names and mesh.shape[a] > 1)
+        n = int(np.prod([mesh.shape[a] for a in names])) if names else 1
+        return names if names and all(d % n == 0 for d in dims) else None
+
+    return (mesh, group(BATCH_AXES, batch),
+            group(("model", "seq"), heads, kv_heads))
+
+
+def fit_specs(specs, shapes, mesh):
+    """:func:`fit_spec` over a ``param_specs()`` tree and the matching tree
+    of shapes (tuples) or arrays."""
+    return jax.tree.map(
+        lambda s, a: fit_spec(s, tuple(getattr(a, "shape", a)), mesh),
+        specs, shapes,
+        is_leaf=lambda x: x is None or isinstance(x, PartitionSpec))
 
 
 def filter_spec(spec: PartitionSpec) -> PartitionSpec:

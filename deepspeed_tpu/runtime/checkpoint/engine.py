@@ -343,14 +343,10 @@ def load_checkpoint(engine, load_dir: str, tag: str | None = None) -> str:
         mismatch = False
         if want_err:
             want_shapes = {k: tuple(v.shape) for k, v in want_err.items()}
-            try:
-                saved = ckptr.metadata(path / "state").get("comm_err") or {}
-                saved_shapes = {k: tuple(m.shape) for k, m in saved.items()}
-                mismatch = saved_shapes != want_shapes
-            except Exception as e:
-                log_dist("load_checkpoint: could not probe the saved "
-                         f"comm_err structure ({e}) — restoring strictly",
-                         ranks=[0])
+            saved = ckptr.metadata(path / "state").item_metadata.tree.get(
+                "comm_err") or {}
+            saved_shapes = {k: tuple(m.shape) for k, m in saved.items()}
+            mismatch = saved_shapes != want_shapes
         if mismatch:
             restored = ckptr.restore(
                 path / "state", item=abstract._replace(comm_err={}),

@@ -1403,7 +1403,7 @@ class ServingEngine:
             if it > max_iterations:
                 raise RuntimeError(
                     f"serving failed to drain in {max_iterations} "
-                    "iterations — scheduler wedged?")
+                    "iterations — scheduler stuck?")
         return self.results
 
     def pop_result(self, rid: int) -> Optional[Request]:
@@ -1532,7 +1532,7 @@ class ServingEngine:
             it += 1
             if it > 1_000_000:
                 raise RuntimeError("serve_batch failed to finish — "
-                                   "scheduler wedged?")
+                                   "scheduler stuck?")
         return [np.asarray(got[r].tokens, np.int32) for r in rids]
 
     # ------------------------------------------------------------ metrics
@@ -1744,6 +1744,43 @@ class ServingEngine:
                 "tokens": len(req.tokens), "hops": hop_trace(req)}
 
     # ----------------------------------------------------------- capacity
+    def _built_programs(self):
+        """``(name, jitted, args)`` for the programs traffic has actually
+        built: the slot decode step and every prefill bucket compiled so
+        far, with the avals each lowers for. Building (and
+        compile-counting) the step here would put a phantom compile in the
+        freeze gates and feed the compile-storm detector."""
+        params = self.engine.params
+        if "step" in self._programs:
+            yield "step", self._programs["step"], (params, self._state)
+        elif "step_chaos" in self._programs:
+            yield "step", self._programs["step_chaos"], (
+                params, self._state, jnp.int32(-1))
+        # avals only — a batch-1 cache never materializes
+        cache_aval = jax.eval_shape(
+            lambda: init_cache(self.model.cfg, 1, self.cfg.max_len,
+                               self.engine.compute_dtype))
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        rng_aval = jax.eval_shape(lambda: per_request_keys([0]))
+        for key in [k for k in self._programs
+                    if isinstance(k, tuple) and k[0] in ("chunk", "final")]:
+            stem, size = key
+            ids = jax.ShapeDtypeStruct((1, size), jnp.int32)
+            if stem == "chunk":
+                yield (f"chunk_{size}", self._programs[key],
+                       (params, cache_aval, ids, i32))
+            else:
+                yield (f"final_{size}", self._programs[key],
+                       (params, cache_aval, ids, i32, i32, i32, rng_aval))
+
+    def compiled_texts(self) -> dict:
+        """Optimized HLO text per built program (AOT-compiled for the live
+        shapes, nothing executes): where a caller checks which kernels
+        (``tpu_custom_call``) and collectives the compiler put in."""
+        with self.engine.mesh:
+            return {name: jitted.lower(*args).compile().as_text()
+                    for name, jitted, args in self._built_programs()}
+
     def capacity_census(self) -> dict:
         """Per-program cost census over the engine's bounded program set:
         static FLOPs / HBM bytes / collective bytes (compiler + HLO truth,
@@ -1757,35 +1794,8 @@ class ServingEngine:
 
         pf, bw = roofline_peaks()
         census = ProgramCensus(peak_flops=pf, peak_bw=bw)
-        mesh = self.engine.mesh
-        params = self.engine.params
-        # only programs traffic actually built — building (and compile-
-        # counting) the step here would put a phantom compile in the
-        # freeze gates and feed the compile-storm detector
-        if "step" in self._programs:
-            census.measure("step", self._programs["step"],
-                           params, self._state, mesh=mesh)
-        elif "step_chaos" in self._programs:
-            census.measure("step", self._programs["step_chaos"],
-                           params, self._state, jnp.int32(-1), mesh=mesh)
-        # prefill buckets: census exactly the chunk programs traffic
-        # built (avals only — a batch-1 cache never materializes)
-        cache_aval = jax.eval_shape(
-            lambda: init_cache(self.model.cfg, 1, self.cfg.max_len,
-                               self.engine.compute_dtype))
-        i32 = jax.ShapeDtypeStruct((), jnp.int32)
-        rng_aval = jax.eval_shape(lambda: per_request_keys([0]))
-        for key in [k for k in self._programs
-                    if isinstance(k, tuple) and k[0] in ("chunk", "final")]:
-            stem, size = key
-            ids = jax.ShapeDtypeStruct((1, size), jnp.int32)
-            if stem == "chunk":
-                census.measure(f"chunk_{size}", self._programs[key],
-                               params, cache_aval, ids, i32, mesh=mesh)
-            else:
-                census.measure(f"final_{size}", self._programs[key],
-                               params, cache_aval, ids, i32, i32, i32,
-                               rng_aval, mesh=mesh)
+        for name, jitted, args in self._built_programs():
+            census.measure(name, jitted, *args, mesh=self.engine.mesh)
         if self.spans is not None:
             census.attach_spans(self.spans.events())
         return census.report()

@@ -1032,7 +1032,7 @@ class FleetEngine:
             if it > max_iterations:
                 raise RuntimeError(
                     f"fleet failed to drain in {max_iterations} "
-                    "iterations — scheduler wedged?")
+                    "iterations — scheduler stuck?")
         return self.results
 
     def serve_batch(self, prompts, max_new_tokens=None, seeds=None,
@@ -1064,7 +1064,7 @@ class FleetEngine:
             it += 1
             if it > 1_000_000:
                 raise RuntimeError("fleet serve_batch failed to finish — "
-                                   "scheduler wedged?")
+                                   "scheduler stuck?")
         return [np.asarray(got[r].tokens, np.int32) for r in rids]
 
     # ------------------------------------------------------------- readout

@@ -367,7 +367,7 @@ class Scheduler:
         first-token stamp, page plan) resets so the request re-runs from
         prefill on THIS scheduler — per-request RNG folds from the seed,
         so the rerun's bits match a fresh submission. ``submit_t`` and the
-        ABSOLUTE deadlines are preserved: failover does not grant a
+        ABSOLUTE deadlines are preserved: failover does not give a
         request more wall time than its caller asked for."""
         if len(req.prompt) + req.max_new > self.max_len:
             raise ValueError(

@@ -143,6 +143,11 @@ class ThroughputTimer:
         return stats
 
 
+# Published per-chip peaks, keyed by platform and a substring of
+# ``device_kind`` (Google Cloud TPU documentation; v5e: 197 TFLOP/s bf16,
+# 819 GB/s HBM, 1600 Gbit/s ICI). A device that is not in a table is an
+# error, not a default: a utilization is a measurement against a known peak.
+
 # Peak dense bf16 FLOPS per chip, for MFU accounting.
 PEAK_FLOPS_BY_PLATFORM = {
     "tpu": {
@@ -150,10 +155,7 @@ PEAK_FLOPS_BY_PLATFORM = {
         "v5 lite": 197e12,  # v5e
         "v5": 459e12,       # v5p
         "v6 lite": 918e12,  # trillium
-        "default": 197e12,
     },
-    "cpu": {"default": 1e12},
-    "gpu": {"default": 312e12},
 }
 
 
@@ -168,33 +170,24 @@ PEAK_HBM_BW_BY_PLATFORM = {
         "v5": 2765e9,       # v5p
         "v6 lite": 1640e9,  # trillium
     },
-    "cpu": {"default": 50e9},
-    "gpu": {"default": 2039e9},
 }
 
 
 def _peak_lookup(device, tables: dict, env_var: str, what: str) -> float:
     """Shared per-chip peak lookup for utilization accounting. MFU/MBU are
-    the product's headline numbers, so an unknown TPU generation must fail
-    loudly rather than silently divide by a guessed peak; override with the
-    named env var when running on hardware the table predates."""
+    the product's headline numbers, so an unknown device must fail loudly
+    rather than silently divide by a guessed peak; override with the named
+    env var when running on hardware the table predates."""
     override = os.environ.get(env_var)
     if override:
         return float(override)
-    table = tables.get(device.platform)
-    if table is None:
-        raise ValueError(
-            f"no {what} entry for platform {device.platform!r}; set "
-            f"{env_var}=<per-chip value> to report utilization honestly")
     kind = getattr(device, "device_kind", "").lower()
-    for key, val in table.items():
-        if key != "default" and key in kind:
+    for key, val in tables.get(device.platform, {}).items():
+        if key in kind:
             return val
-    if device.platform == "tpu":
-        raise ValueError(
-            f"unknown TPU generation {kind!r} — refusing to guess {what}; "
-            f"set {env_var}=<per-chip value>")
-    return table["default"]
+    raise ValueError(
+        f"no {what} entry for {device.platform} device {kind!r} — refusing "
+        f"to guess; set {env_var}=<per-chip value> to report a utilization")
 
 
 def peak_hbm_bw_for(device) -> float:
@@ -223,9 +216,6 @@ PEAK_ICI_BW_BY_PLATFORM = {
         "v5": 600e9,        # v5p
         "v6 lite": 448e9,   # trillium
     },
-    # CPU "interconnect" is host memory; GPU default is NVLink-class.
-    "cpu": {"default": 10e9},
-    "gpu": {"default": 900e9},
 }
 
 

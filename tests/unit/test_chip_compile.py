@@ -1,0 +1,132 @@
+"""AOT compiles of the main path's Pallas kernels for a described TPU v5e.
+
+The TPU compiler is installed wherever jax[tpu] is; it compiles for a chip
+that is described and not attached, and refuses what the chip's compiler
+would refuse (block shapes off the (8, 128) tiling, too much scoped VMEM).
+Interpret-mode parity tests cannot see those. Nothing runs here: a compile
+that passes is not a chip run (``chip_smoke.py`` is).
+
+The topology is described inside a module-scoped fixture, in the test's
+own process, and only in this file: one process at a time may hold the TPU
+library, so a second file (or a child process) would skip in silence.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.decode_attention import decode_attention
+from deepspeed_tpu.ops.flash_attention import flash_attention
+from deepspeed_tpu.ops.woq_matmul import woq_matmul, woq_matmul_t
+from deepspeed_tpu.ops.xent import fused_token_nll
+
+# (name, d_model, heads, kv_heads, head_dim, d_ff, vocab) at published width
+GPT2_774M = ("gpt2-774m", 1280, 20, 20, 64, 5120, 50257)
+LLAMA2_7B = ("llama2-7b", 4096, 32, 32, 128, 11008, 32000)
+WIDTHS = [pytest.param(GPT2_774M, id="gpt2-774m"),
+          pytest.param(LLAMA2_7B, id="llama2-7b")]
+SEQ = 1024
+GROUP = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "kernel absent from the compiled text"
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention(one_chip, width, grad):
+    _, _, H, KV, hd, _, _ = width
+    qkv = ((2, SEQ, H, hd), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    _compile(fn, one_chip, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_decode_attention(one_chip, width):
+    _, _, H, KV, hd, _, _ = width
+    B = 8
+    cache = ((B, KV, SEQ, hd), jnp.bfloat16)
+    _compile(lambda q, ck, cv, n: decode_attention(q, ck, cv, n,
+                                                   interpret=False),
+             one_chip, ((B, 1, H, hd), jnp.bfloat16), cache, cache,
+             ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_fused_token_nll(one_chip, width, grad):
+    _, d, _, _, _, _, V = width
+    T = 2 * SEQ
+
+    def nll(x, w, t):
+        return fused_token_nll(x, w, None, t, 256, 512, False).sum()
+
+    fn = jax.grad(nll, argnums=(0, 1)) if grad else nll
+    _compile(fn, one_chip, ((T, d), jnp.bfloat16), ((V, d), jnp.bfloat16),
+             ((T,), jnp.int32))
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("bits", [8, 4], ids=["int8", "int4"])
+def test_woq_matmul(one_chip, width, bits):
+    """Up- and down-projection: G = K/128 is 10 and 40 (774M), 32 and 86
+    (7B) — both scale blockings (8 rows, whole group dim) are compiled."""
+    _, d, _, _, _, ff, _ = width
+    for K, N in ((d, ff), (ff, d)):
+        _compile(lambda x, q, s: woq_matmul(x, q, s, group_size=GROUP,
+                                            bits=bits, interpret=False),
+                 one_chip, ((8, K), jnp.bfloat16),
+                 ((K // 2 if bits == 4 else K, N), jnp.int8),
+                 ((K // GROUP, N), jnp.float32))
+
+
+@pytest.mark.parametrize("width,bits", [
+    pytest.param(GPT2_774M, 8, id="int8-gpt2-774m"),
+    pytest.param(LLAMA2_7B, 8, id="int8-llama2-7b"),
+    pytest.param(LLAMA2_7B, 4, id="int4-llama2-7b"),
+])
+def test_woq_matmul_t(one_chip, width, bits):
+    """Tied head: a vocab 128 does not divide (50257) quantizes to a single
+    group; 32000 has 250 groups of 128 rows. (An odd vocab cannot pack int4
+    row pairs: ``woq_matmul_t_eligible`` keeps it on XLA.)"""
+    _, d, _, _, _, _, V = width
+    gs = GROUP if V % GROUP == 0 else V
+    _compile(lambda x, q, s: woq_matmul_t(x, q, s, group_size=gs, bits=bits,
+                                          interpret=False),
+             one_chip, ((8, d), jnp.bfloat16),
+             ((V // 2 if bits == 4 else V, d), jnp.int8),
+             ((V // gs, d), jnp.float32))

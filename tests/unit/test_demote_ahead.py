@@ -72,7 +72,7 @@ def _run_one(srv, prompt, seed, sid):
         if req is not None:
             return req
         srv.step()
-    raise RuntimeError("serving wedged")
+    raise RuntimeError("serving stuck")
 
 
 def _counters(srv):

@@ -208,7 +208,7 @@ def test_nvme_restore_parity_under_tensor_parallel(devices, tmp_path):
                         break
                     srv.step()
                 else:
-                    raise RuntimeError("serving wedged")
+                    raise RuntimeError("serving stuck")
         return toks
 
     e1 = ds.init_inference(model, params, dict(base))

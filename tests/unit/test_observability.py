@@ -312,7 +312,10 @@ def _prompt(B=2, S=8):
     return np.asarray(rng.integers(0, 256, (B, S)), np.int32)
 
 
-def test_metrics_snapshot_cpu_smoke_and_parity():
+def test_metrics_snapshot_cpu_smoke_and_parity(monkeypatch):
+    # the peak table knows no CPU: name a bandwidth (the documented
+    # override) so the MBU plumbing is exercised
+    monkeypatch.setenv("DSTPU_PEAK_HBM_BW", "50e9")
     ids = _prompt()
     _, _, fused = _tiny_engine()
     _, _, traced = _tiny_engine(observability=True)
@@ -914,10 +917,11 @@ def test_doctor_cli_reports_from_files(tmp_path, capsys):
 
 
 # --------------------------------------------------- tier-1 subsystem smoke
-def test_train_and_generate_all_sinks_smoke(tmp_path):
+def test_train_and_generate_all_sinks_smoke(tmp_path, monkeypatch):
     """One train step + one generate() with every machine-readable sink
     enabled: JSONL parses, the Prometheus textfile parses, CSV has rows,
     and both engines' snapshots are well-formed."""
+    monkeypatch.setenv("DSTPU_PEAK_HBM_BW", "50e9")   # no CPU peak in the table
     engine = ds.initialize({
         "train_batch_size": 8,
         "steps_per_print": 1,

@@ -99,8 +99,9 @@ def test_engine_trains_with_fused_xent_data_parallel():
 
 def test_fused_gate_axis_eligibility():
     """Eligibility: seq/pipe-sharded meshes keep the XLA path; data and
-    model (vocab-sharded TP kernel) meshes take the fused path — unless
-    the vocab doesn't split evenly over the model axis."""
+    model (vocab-sharded TP kernel) meshes take the fused path. A vocab the
+    model axis does not divide is replicated and takes the whole-vocab
+    kernel, the batch split over data x model — where the batch divides."""
     from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
 
     model = build_model(tiny_test(n_layer=2, fused_xent=True))
@@ -109,31 +110,38 @@ def test_fused_gate_axis_eligibility():
     odd_vocab = build_model(tiny_test(n_layer=2, vocab_size=254,
                                       fused_xent=True))
     with jax.set_mesh(build_mesh(MeshSpec(data=2, model=4))):
-        assert not odd_vocab._fused_xent_active()  # 254 % 4 != 0
+        assert odd_vocab._fused_xent_active(batch_size=8)   # 254 % 4 != 0
+        assert not odd_vocab._fused_xent_active(batch_size=4)
     with jax.set_mesh(build_mesh(MeshSpec(data=2, seq=4))):
         assert not model._fused_xent_active()
     with jax.set_mesh(build_mesh(MeshSpec(data=8))):
         assert model._fused_xent_active()
 
 
-def test_engine_trains_with_fused_xent_tensor_parallel():
-    """e2e: data x model mesh — the vocab-sharded TP kernel runs under the
-    engine and the first-step loss matches the XLA path's."""
+@pytest.mark.parametrize("vocab", [256, 254],
+                         ids=["vocab-sharded", "odd-vocab-replicated"])
+def test_engine_trains_with_fused_xent_tensor_parallel(vocab):
+    """e2e: data x model mesh — the vocab-sharded TP kernel (or, for a
+    vocab that model=4 does not divide, the whole-vocab kernel on the
+    replicated table) runs under the engine and tracks the XLA path's
+    losses, so its gradients are right too."""
     losses = {}
     for fused in (True, False):
         engine = ds.initialize({
-            "train_batch_size": 4,
+            "train_batch_size": 8,
             "optimizer": {"type": "adamw", "params": {"lr": 2e-3}},
             "mesh": {"data": 2, "model": 4},
-        }, build_model(tiny_test(n_layer=2, fused_xent=fused)))
-        data = random_token_dataset(8, 32, 256, learnable=True)
-        batch = DataLoader(data, local_batch_size=4,
-                           shuffle=False).collate_fn(data[:4])
+        }, build_model(tiny_test(n_layer=2, vocab_size=vocab,
+                                 fused_xent=fused)))
+        data = random_token_dataset(8, 32, vocab, learnable=True)
+        batch = DataLoader(data, local_batch_size=8,
+                           shuffle=False).collate_fn(data[:8])
         seq = [float(engine.train_batch(dict(batch))["loss"])
                for _ in range(3)]
         assert all(np.isfinite(seq)) and seq[-1] < seq[0], (fused, seq)
         losses[fused] = seq
     assert abs(losses[True][0] - losses[False][0]) < 2e-3, losses
+    assert abs(losses[True][-1] - losses[False][-1]) < 2e-2, losses
 
 
 def test_fused_gate_declines_indivisible_batch():
