@@ -61,6 +61,7 @@ _TID_QUEUE = 1
 _TID_PREFILL = 2
 _TID_STEP = 3
 _TID_MARKERS = 4
+_TID_LOOP = 5       # the host loop: srv.step, its phases nested, srv.submit
 _TID_SLOT0 = 10
 # per-session residency tracks (kvscope lifecycle spans) allocate from
 # here in first-seen order — high enough that slot tids can never reach
@@ -147,6 +148,9 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
         elif e.kind == S.DECODE_STEP:
             add(PID_SERVING, _TID_STEP, "X", "decode_step", ts,
                 dur or 0.0, args)
+        elif e.kind.startswith("srv."):
+            add(PID_SERVING, _TID_LOOP, "X", e.kind[4:], ts, dur or 0.0,
+                args)
         elif e.kind == S.OCCUPANCY:
             # one counter track per sample name — Perfetto draws them as
             # stacked value timelines
@@ -154,6 +158,9 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
                 out.append({"name": k, "ph": "C", "pid": PID_SERVING,
                             "tid": 0, "ts": round(ts, 3),
                             "args": {k: v}})
+        elif e.kind == S.RETRACE:
+            add(PID_SERVING, _TID_MARKERS, "i",
+                f"retrace:{e.meta.get('program', '?')}", ts, None, args)
         elif e.kind == S.MARKER:
             nm = e.meta.get("name", "marker")
             add(PID_SERVING, _TID_MARKERS, "i", f"marker:{nm}", ts, None,
@@ -202,7 +209,7 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
         name_meta(PID_SERVING, f"{job_name}:serving")
         for tid, nm in ((_TID_QUEUE, "queue"), (_TID_PREFILL, "prefill"),
                         (_TID_STEP, "decode-step"),
-                        (_TID_MARKERS, "markers")):
+                        (_TID_MARKERS, "markers"), (_TID_LOOP, "loop")):
             if tid in used_tids[PID_SERVING]:
                 thread_meta(PID_SERVING, tid, nm)
         for tid in sorted(t for t in used_tids[PID_SERVING]

@@ -11,15 +11,23 @@ retirement, the wall-clock-breakdown timers) becomes a typed
 
 Cost discipline: recording is host-side floats into a deque under a
 lock — no device buffers, no host↔device syncs, no new compiled
-programs. Engines hold ``spans = None`` when disabled, so the hot path
-pays one ``is not None`` and the ``bench_serving.py --smoke``
-compile-freeze gate stays green. Timestamps come from the owner's
-injectable clock (the same one ``ServingStats`` fakes in tests).
+programs. Engines hold ``spans = None`` when the operator has not asked
+for a ring, and the ``bench_serving.py --smoke`` compile-freeze gate
+stays green. Timestamps come from the owner's injectable clock (the same
+one ``ServingStats`` fakes in tests).
 
 The ring is the substrate for two consumers: the Chrome-trace/Perfetto
 export (``export.py``) and the crash/stall flight recorder
 (``flight.py``), which snapshots the last-N events into a post-mortem
 artifact.
+
+The seam (:func:`span`, :func:`emit`) is the one way engine code times a
+piece of host work. It has two sinks: the owner's ring, when the operator
+set ``spans: true``, and, while a ``jax.profiler`` capture is live, both a
+``TraceAnnotation`` named ``ds.<name>`` (the span on the capture's own
+timeline, beside the device's) and the process's capture ring, which
+:func:`captured` hands out afterwards. With neither, a site costs one
+``TraceAnnotation.is_enabled()``.
 """
 
 from __future__ import annotations
@@ -30,6 +38,8 @@ import time
 from collections import deque
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 # ------------------------------------------------------------- event kinds
 # Serving request lifecycle (rid-carrying):
 QUEUED = "queued"                  # span: submit → admission (queue wait)
@@ -38,13 +48,30 @@ PLACED = "placed"                  # instant: request occupied a slot
 DECODE_RESIDENCY = "decode"        # span: first token → retirement, in slot
 RETIRED = "retired"                # instant: terminal status lands
 # Serving engine cadence (no rid):
-DECODE_STEP = "decode_step"        # span: one slot decode step (all slots)
+DECODE_STEP = "decode_step"        # span: one slot decode step (all slots):
+                                   # dispatch + read-back, the watchdog's
+                                   # window (meta: slots, queue)
 OCCUPANCY = "occupancy"            # counter: slots occupied / queue depth
+# One serving iteration and its phases (``step`` = the iteration; the
+# phases are disjoint and lie inside SRV_STEP, so the iteration's self
+# time is its span less theirs; docs/OBSERVABILITY.md has the table):
+SRV_SUBMIT = "srv.submit"          # span: one submit() (rid-carrying)
+SRV_STEP = "srv.step"              # span: one ServingEngine.step()
+SRV_DEADLINES = "srv.deadlines"    # span: the deadline sweep
+SRV_ADMIT = "srv.admit"            # span: pop_next → the prefill lane set,
+                                   # cache init/hydrate/restore dispatched
+SRV_PREFILL_READBACK = "srv.prefill_readback"  # span: the blocking read
+                                   # of a final chunk's first token
+SRV_PLACE = "srv.place"            # span: slot taken, insert dispatched
+SRV_DECODE_DISPATCH = "srv.decode_dispatch"    # span: the step enqueued
+SRV_DECODE_READBACK = "srv.decode_readback"    # span: the fused read-back
+SRV_RETIRE = "srv.retire"          # span: tokens accounted, rows retired
+SRV_TAIL = "srv.tail"              # span: demotes, stats, results stored
 # Training engine cadence:
 TRAIN_STEP = "train_step"          # span: one train_batch() call
-TRAIN_PHASE = "train_phase"        # span: a wall-clock-breakdown timer
-                                   # interval (batch_prep/step_dispatch/
-                                   # step_sync, fwd/bwd/host_step offload)
+TRAIN_PHASE = "train_phase"        # span: a part of it (meta: phase =
+                                   # batch_prep/step_dispatch/step_sync,
+                                   # bwd/host_step under offload)
 # Fleet request hops (serving/fleet.py — recorded in the FLEET-level
 # ring, rid-carrying; the cross-replica half of a distributed trace):
 ROUTE = "route"                    # instant: router picked an admission
@@ -69,6 +96,10 @@ COMM_OP = "comm_op"                # span: one collective op in flight
                                    # (meta: kind, op, device)
 COMM_EXPOSED = "comm_exposed"      # span: an exposed gap — collective
                                    # time NOT hidden behind compute
+RETRACE = "retrace"                # instant: a built serving program met a
+                                   # new argument signature (meta:
+                                   # program, signatures). Not a MARKER:
+                                   # warm-up has them and is no incident
 # Cross-cutting:
 MARKER = "marker"                  # instant: SLO burn, anomaly, watchdog,
                                    # compile storm — the "why" of a dump
@@ -145,10 +176,13 @@ class SpanRecorder:
         ev = SpanEvent(kind=kind, t0=float(t0),
                        t1=None if t1 is None else float(t1),
                        rid=rid, slot=slot, step=step, meta=meta)
+        self.append(ev)
+        return ev
+
+    def append(self, ev: SpanEvent) -> None:
         with self._lock:
             self._ring.append(ev)
             self._emitted += 1
-        return ev
 
     def marker(self, name: str, t: Optional[float] = None,
                **meta) -> SpanEvent:
@@ -180,3 +214,120 @@ class SpanRecorder:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+
+
+# ----------------------------------------------------------------- the seam
+# Events recorded while a profiler capture was live, whichever engine made
+# them: in a capture's seconds an engine with ``spans`` unset has no ring
+# of its own, and a reader outside the engine (a benchmark's reducer) has
+# no handle on it. Cleared when the next capture starts.
+_CAPTURE = SpanRecorder(capacity=1 << 15)
+_capture_live = False
+
+
+def _capturing() -> bool:
+    global _capture_live
+    live = TraceAnnotation.is_enabled()
+    if live != _capture_live:
+        _capture_live = live
+        if live:
+            _CAPTURE.clear()
+    return live
+
+
+def captured() -> list[SpanEvent]:
+    """What the seam recorded during the newest profiler capture, in the
+    order the spans closed. A capture that starts and stops between two
+    iterations holds whole iterations."""
+    return _CAPTURE.events()
+
+
+def emit(ring: Optional[SpanRecorder], kind: str, t0: float,
+         t1: Optional[float] = None, **fields) -> None:
+    """A span from stamps the caller already holds (a request's lifecycle,
+    the watchdog's window), or an instant or counter: to ``ring`` if there
+    is one, and to the capture ring while a capture is live. No
+    annotation: one cannot be opened in the past."""
+    _record(ring, _capturing(), kind, t0, t1, fields)
+
+
+def _record(ring, live, kind, t0, t1, fields) -> None:
+    if ring is not None:
+        ev = ring.emit(kind, t0, t1, **fields)
+        if live:
+            _CAPTURE.append(ev)
+    elif live:
+        _CAPTURE.emit(kind, t0, t1, **fields)
+
+
+def instant(ring: Optional[SpanRecorder], clock: Callable[[], float],
+            kind: str, **fields) -> None:
+    """An instant or a counter sample (OCCUPANCY, RETRACE) stamped now.
+    The clock is read only when something records: an engine on a
+    counting test clock keeps its stamps with spans off."""
+    live = _capturing()
+    if ring is not None or live:
+        _record(ring, live, kind, clock(), None, fields)
+
+
+class _Off:
+    """What :func:`span` hands out when nothing records."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **fields) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+class _Span:
+    __slots__ = ("ring", "clock", "kind", "fields", "t0", "_annotation")
+
+    def __init__(self, ring, clock, kind, annotation, fields):
+        self.ring, self.clock, self.kind = ring, clock, kind
+        self.fields = fields
+        self._annotation = annotation
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.clock()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        # in the capture ring exactly when it is in the capture
+        _record(self.ring, self._annotation is not None, self.kind,
+                self.t0, t1, self.fields)
+        return False
+
+    def note(self, **fields) -> None:
+        """What is known only inside the span (the rid ``submit`` drew)."""
+        self.fields.update(fields)
+
+
+def span(ring: Optional[SpanRecorder], clock: Callable[[], float],
+         kind: str, *, name: Optional[str] = None, **fields):
+    """Time a piece of host code: ``with span(ring, clock, KIND, step=n):``.
+    Records a :class:`SpanEvent` of ``kind`` as :func:`emit` does, stamped
+    with ``clock`` (the owner's, so spans and metrics agree), and while a
+    capture is live wraps the block in ``TraceAnnotation("ds.<name>")``
+    (``name`` defaults to ``kind``). ``fields`` are ``rid`` / ``slot`` /
+    ``step`` and meta. With no ring and no capture it returns a shared
+    no-op."""
+    live = _capturing()
+    if ring is None and not live:
+        return _OFF
+    return _Span(ring, clock, kind,
+                 TraceAnnotation("ds." + (name or kind)) if live else None,
+                 fields)

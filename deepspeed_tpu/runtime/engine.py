@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 import math
 import os
+import time
 from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -30,6 +31,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
+from ..observability import spans as _spans
 from ..observability.spans import TRAIN_PHASE, TRAIN_STEP
 from ..platform.accelerator import get_accelerator
 from ..platform.mesh import (BATCH_AXES, MeshSpec, build_mesh, dp_world_size,
@@ -925,43 +927,45 @@ class Engine:
         return jax.tree_util.tree_map_with_path(fn, grads)
 
     def _train_batch_offload(self, batch: dict) -> dict:
-        import time as _time
-
         self.throughput.start()
         if self.curriculum is not None:
             batch = self._apply_data_efficiency(batch)
         if not isinstance(next(iter(batch.values())), jax.Array):
             batch = self._make_global(batch)
-        t0 = _time.perf_counter()
-        scale = self._offload_ls.scale
-        with self.mesh:
-            grads, metrics = self._grad_step(self.compute_params, batch, scale)
-        # reading the norm back waits for the step; with pinned-host grad
-        # outputs the device->host DMAs already ran inside the step,
-        # overlapped with the tail of backward by XLA's latency-hiding
-        # scheduler.
-        gnorm = float(metrics["grad_norm"])
-        finite = bool(metrics["grads_finite"])
-        t_bwd = _time.perf_counter() - t0
-        lr = float(self.lr_schedule(jnp.int32(self.global_steps)))
-        t1 = _time.perf_counter()
-        if finite:
-            with self.mesh:
-                self.compute_params = self.host_opt.step(grads, lr)
-        else:
-            log_dist(f"offload fp16: non-finite grads, skipping host step "
-                     f"(loss scale {float(scale):.0f})", ranks=[0])
-        self._offload_ls = update_loss_scale(
-            self._offload_ls, metrics["grads_finite"], self.config.fp16)
-        t_host = _time.perf_counter() - t1
-        self.global_steps += 1
-        if self.spans is not None:
-            t2 = t1 + t_host
-            self.spans.emit(TRAIN_STEP, t0, t2, step=self.global_steps)
-            self.spans.emit(TRAIN_PHASE, t0, t0 + t_bwd,
-                            step=self.global_steps, phase="bwd")
-            self.spans.emit(TRAIN_PHASE, t1, t2, step=self.global_steps,
-                            phase="host_step")
+        # the clock reads below are the result's bwd_s / host_step_s; the
+        # spans come from the seam, as in the on-device path
+        n_step = self.global_steps + 1
+        with self._span(TRAIN_STEP, name="train.step", step=n_step):
+            t0 = time.perf_counter()
+            scale = self._offload_ls.scale
+            with self._span(TRAIN_PHASE, name="train.bwd", step=n_step,
+                            phase="bwd"):
+                with self.mesh:
+                    grads, metrics = self._grad_step(self.compute_params,
+                                                     batch, scale)
+                # reading the norm back waits for the step; with
+                # pinned-host grad outputs the device->host DMAs already
+                # ran inside the step, overlapped with the tail of
+                # backward by XLA's latency-hiding scheduler.
+                gnorm = float(metrics["grad_norm"])
+                finite = bool(metrics["grads_finite"])
+            t_bwd = time.perf_counter() - t0
+            lr = float(self.lr_schedule(jnp.int32(self.global_steps)))
+            t1 = time.perf_counter()
+            with self._span(TRAIN_PHASE, name="train.host_step",
+                            step=n_step, phase="host_step"):
+                if finite:
+                    with self.mesh:
+                        self.compute_params = self.host_opt.step(grads, lr)
+                else:
+                    log_dist("offload fp16: non-finite grads, skipping "
+                             f"host step (loss scale {float(scale):.0f})",
+                             ranks=[0])
+                self._offload_ls = update_loss_scale(
+                    self._offload_ls, metrics["grads_finite"],
+                    self.config.fp16)
+            t_host = time.perf_counter() - t1
+            self.global_steps += 1
         if self.commscope is not None:
             t2 = t1 + t_host
             self.commscope.on_step(
@@ -1577,6 +1581,15 @@ class Engine:
                              window, loss)
 
     # -------------------------------------------------------- observability
+    def _span(self, kind, **fields):
+        """The seam (observability/spans.py) on this engine's ring and its
+        clock: every timed piece of host code in this file goes through
+        here."""
+        ring = self.spans
+        return _spans.span(
+            ring, ring.clock if ring is not None else time.perf_counter,
+            kind, **fields)
+
     def _record_step_metrics(self, metrics: dict, stats: Optional[dict],
                              extra_gauges: Optional[dict] = None) -> None:
         """Step metrics → the engine registry (Train/* + Memory/*)."""
@@ -1768,132 +1781,128 @@ class Engine:
         if self.offload:
             return self._train_batch_offload(batch)
         wcb = self.config.wall_clock_breakdown
-        # one shared step-window clock for spans AND the comm
-        # observatory (commscope reuses the spans clock when both are
-        # on, so their windows agree to the exact float)
-        _step_clk = (self.spans.clock if self.spans is not None else
-                     (self.commscope.clock if self.commscope is not None
-                      else None))
-        t_step0 = _step_clk() if _step_clk is not None else 0.0
-        self.throughput.start()
-        if wcb:
-            self.timers.start("batch_prep")
-        if self.curriculum is not None or self._ltd is not None:
-            batch = self._apply_data_efficiency(batch)
-        if not isinstance(next(iter(batch.values())), jax.Array):
-            batch = self._make_global(batch)
-        if wcb:
-            self.timers.stop("batch_prep")
-        if self._moq is not None and self._moq_probe_batch is None:
-            # small fixed probe batch for the curvature power iteration:
-            # captured AFTER globalization (pre-converted jax batches
-            # arrive in the (gas, batch, ...) layout — flatten it), one
-            # row per data shard (the trunk's batch constraint needs
-            # dp-divisibility)
-            from ..models.transformer import mesh_dp_world
-
-            rows = max(1, mesh_dp_world(self.mesh))
-
-            def probe_rows(v):
-                # read only host-local shards: np.asarray on a globalized
-                # array raises on a multi-process mesh (remote shards)
-                if isinstance(v, jax.Array) and not v.is_fully_addressable:
-                    a = np.asarray(v.addressable_shards[0].data)
-                else:
-                    a = np.asarray(v)
-                if a.ndim >= 2:
-                    a = a.reshape((-1,) + a.shape[2:])
-                if len(a) < rows:        # tiny shard: tile up to dp rows
-                    a = np.resize(a, (rows,) + a.shape[1:])
-                return a[:rows]
-
-            self._moq_probe_batch = {k: probe_rows(v)
-                                     for k, v in batch.items()}
-        comp_active = tuple(sorted(
-            n for n, off in self._comp if self.global_steps >= off))
-        if self._moq is not None and "weight_quantization" in comp_active:
-            self._moq.maybe_step(self.global_steps, self._moq_eigenvalue)
-            comp_active = self._moq.annotate(comp_active)
-        warm = (in_warmup(self.onebit, self.global_steps)
-                if self.onebit is not None else False)
-        if wcb:
-            self.timers.start("step_dispatch")
-        with self.mesh:
-            self.state, metrics = self._train_step(
-                self.state, batch, max(0, self._ltd_tokens), comp_active, warm)
-        if wcb:
-            self.timers.stop("step_dispatch")
-        self.global_steps += 1
-        boundary = self.global_steps % self.config.steps_per_print == 0
-        if wcb or boundary:
-            # sync FIRST, then floatify: float() on the metrics arrays is
-            # itself a device wait, and running it before the step_sync
-            # timer would bury the whole device-execution time in no timer
+        # the step as the seam sees it (observability/spans.py): one
+        # train_step span and its parts, batch_prep / step_dispatch /
+        # step_sync, whenever a ring or a profiler capture records; the
+        # timers beside them only feed wall_clock_breakdown's log line.
+        # The step is numbered as it will be when it is done
+        n_step = self.global_steps + 1
+        cs = self.commscope
+        t_step0 = cs.clock() if cs is not None else 0.0
+        with self._span(TRAIN_STEP, name="train.step", step=n_step):
+            self.throughput.start()
             if wcb:
-                self.timers.start("step_sync")
-            jax.block_until_ready(self.state.step)
+                self.timers.start("batch_prep")
+            with self._span(TRAIN_PHASE, name="train.batch_prep",
+                            step=n_step, phase="batch_prep"):
+                if self.curriculum is not None or self._ltd is not None:
+                    batch = self._apply_data_efficiency(batch)
+                if not isinstance(next(iter(batch.values())), jax.Array):
+                    batch = self._make_global(batch)
             if wcb:
-                self.timers.stop("step_sync")
-            metrics = {k: float(v) for k, v in metrics.items()}
-            stats = self.throughput.stop(report=True)
+                self.timers.stop("batch_prep")
+            if self._moq is not None and self._moq_probe_batch is None:
+                # small fixed probe batch for the curvature power iteration:
+                # captured AFTER globalization (pre-converted jax batches
+                # arrive in the (gas, batch, ...) layout — flatten it), one
+                # row per data shard (the trunk's batch constraint needs
+                # dp-divisibility)
+                from ..models.transformer import mesh_dp_world
+
+                rows = max(1, mesh_dp_world(self.mesh))
+
+                def probe_rows(v):
+                    # read only host-local shards: np.asarray on a globalized
+                    # array raises on a multi-process mesh (remote shards)
+                    if isinstance(v, jax.Array) and not v.is_fully_addressable:
+                        a = np.asarray(v.addressable_shards[0].data)
+                    else:
+                        a = np.asarray(v)
+                    if a.ndim >= 2:
+                        a = a.reshape((-1,) + a.shape[2:])
+                    if len(a) < rows:        # tiny shard: tile up to dp rows
+                        a = np.resize(a, (rows,) + a.shape[1:])
+                    return a[:rows]
+
+                self._moq_probe_batch = {k: probe_rows(v)
+                                         for k, v in batch.items()}
+            comp_active = tuple(sorted(
+                n for n, off in self._comp if self.global_steps >= off))
+            if self._moq is not None and "weight_quantization" in comp_active:
+                self._moq.maybe_step(self.global_steps, self._moq_eigenvalue)
+                comp_active = self._moq.annotate(comp_active)
+            warm = (in_warmup(self.onebit, self.global_steps)
+                    if self.onebit is not None else False)
             if wcb:
-                # wall-clock breakdown → registry gauges (log() also prints
-                # the reference-style "time (ms)" line and resets). Gauges
-                # record per step; sinks still flush only at boundaries.
-                for name, ms in self.timers.log(reset=True).items():
-                    self.metrics.gauge(f"Train/time_{name}_ms").set(ms)
-            if boundary:
-                self._sentinel_at_boundary(metrics["loss"])
-                log_dist(f"step={self.global_steps} loss={metrics['loss']:.4f} "
-                         f"lr={metrics['lr']:.3e} gnorm={metrics['grad_norm']:.3f}",
-                         ranks=[0])
-                # recording + emission stay on the report cadence even
-                # under wall_clock_breakdown (the HBM watermark and sink
-                # flush are documented as per-boundary, never per-step)
-                self._record_step_metrics(metrics, stats)
-                extra = []
-                if self._moq is not None and any(
-                        n.startswith("weight_quantization")
-                        for n in comp_active):
-                    # observability for the quantization schedule (the
-                    # reference logs its quantizer's bit switches too);
-                    # only while QAT is actually active per its offset
-                    extra.append(("Train/moq_bits", self._moq.bits,
-                                  self.global_steps))
-                self._emit_monitor_events(extra)
-        else:
-            self.throughput.stop(report=False)
-        if _step_clk is not None:
-            t_step1 = _step_clk()
-            if self.spans is not None:
-                self.spans.emit(TRAIN_STEP, t_step0, t_step1,
-                                step=self.global_steps)
+                self.timers.start("step_dispatch")
+            with self._span(TRAIN_PHASE, name="train.dispatch",
+                            step=n_step, phase="step_dispatch"), self.mesh:
+                self.state, metrics = self._train_step(
+                    self.state, batch, max(0, self._ltd_tokens),
+                    comp_active, warm)
+            if wcb:
+                self.timers.stop("step_dispatch")
+            self.global_steps += 1
+            boundary = self.global_steps % self.config.steps_per_print == 0
+            if wcb or boundary:
+                # sync FIRST, then floatify: float() on the metrics arrays
+                # is itself a device wait, and running it before the
+                # step_sync span would bury the whole device-execution
+                # time in no span
                 if wcb:
-                    # re-emit the wall-clock-breakdown timer windows as
-                    # phase spans (last completed interval per timer; no
-                    # new clocks)
-                    for name in ("batch_prep", "step_dispatch",
-                                 "step_sync"):
-                        tm = self.timers(name)
-                        if tm.last_stop > 0:
-                            self.spans.emit(TRAIN_PHASE, tm.last_start,
-                                            tm.last_stop,
-                                            step=self.global_steps,
-                                            phase=name)
-            if self.commscope is not None:
-                # per-step host window + this process's completion stamp
-                # (multi-host launchers gather and feed cross-host stamps
-                # through observe_device_stamps; a lone process's single
-                # stamp leaves the straggler detector honestly inert).
-                # traced= marks steps inside the TraceWindow so the
-                # Perfetto rebase anchors the capture to THEM, not to
-                # whatever pre-window steps were also stamped
-                self.commscope.on_step(
-                    self.global_steps, t_step0, t_step1,
-                    traced=(self._trace_window is not None
-                            and self._trace_window.active))
-                self.commscope.observe_stamps(
-                    self.global_steps, {jax.process_index(): t_step1})
+                    self.timers.start("step_sync")
+                with self._span(TRAIN_PHASE, name="train.sync",
+                                step=n_step, phase="step_sync"):
+                    jax.block_until_ready(self.state.step)
+                if wcb:
+                    self.timers.stop("step_sync")
+                metrics = {k: float(v) for k, v in metrics.items()}
+                stats = self.throughput.stop(report=True)
+                if wcb:
+                    # wall-clock breakdown → registry gauges (log() also
+                    # prints the reference-style "time (ms)" line and
+                    # resets). Gauges record per step; sinks still flush
+                    # only at boundaries.
+                    for name, ms in self.timers.log(reset=True).items():
+                        self.metrics.gauge(f"Train/time_{name}_ms").set(ms)
+                if boundary:
+                    self._sentinel_at_boundary(metrics["loss"])
+                    log_dist(
+                        f"step={self.global_steps} "
+                        f"loss={metrics['loss']:.4f} "
+                        f"lr={metrics['lr']:.3e} "
+                        f"gnorm={metrics['grad_norm']:.3f}", ranks=[0])
+                    # recording + emission stay on the report cadence even
+                    # under wall_clock_breakdown (the HBM watermark and sink
+                    # flush are documented as per-boundary, never per-step)
+                    self._record_step_metrics(metrics, stats)
+                    extra = []
+                    if self._moq is not None and any(
+                            n.startswith("weight_quantization")
+                            for n in comp_active):
+                        # observability for the quantization schedule (the
+                        # reference logs its quantizer's bit switches too);
+                        # only while QAT is actually active per its offset
+                        extra.append(("Train/moq_bits", self._moq.bits,
+                                      self.global_steps))
+                    self._emit_monitor_events(extra)
+            else:
+                self.throughput.stop(report=False)
+        if cs is not None:
+            # per-step host window + this process's completion stamp
+            # (multi-host launchers gather and feed cross-host stamps
+            # through observe_device_stamps; a lone process's single
+            # stamp leaves the straggler detector honestly inert).
+            # traced= marks steps inside the TraceWindow so the
+            # Perfetto rebase anchors the capture to THEM, not to
+            # whatever pre-window steps were also stamped
+            t_step1 = cs.clock()
+            cs.on_step(
+                self.global_steps, t_step0, t_step1,
+                traced=(self._trace_window is not None
+                        and self._trace_window.active))
+            cs.observe_stamps(
+                self.global_steps, {jax.process_index(): t_step1})
         # Profiler fires OUTSIDE the throughput window (its extra timed step
         # + one-time AOT compile must not pollute samples/s accounting).
         if self.flops_profiler and self.flops_profiler.should_fire():

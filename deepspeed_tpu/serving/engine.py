@@ -26,6 +26,7 @@ static path scans.
 
 from __future__ import annotations
 
+import weakref
 from collections import OrderedDict
 from typing import Optional
 
@@ -41,6 +42,7 @@ from ..inference.sampling import per_request_keys, split_keys
 from ..inference.speculation import NGramTable
 from ..observability import spans as _spans
 from ..observability.export import request_record
+from ..observability.metrics import get_registry
 from ..observability.tracing import ServingStats
 from ..resilience.chaos import ChaosMonkey
 from ..resilience.guards import QueueFullError, RequestStatus
@@ -54,6 +56,10 @@ from .slots import init_slots, insert_request
 # (decode step + insert + 2 programs per chunk bucket) so eviction means a
 # config bug, not normal traffic.
 _MAX_PROGRAMS = 64
+# A built program -> the signatures Serve/retraces has counted for it.
+# Process-wide: a fleet's replicas share one program set, and a retrace is
+# counted by whichever of them sees it first.
+_SIGNATURES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 # Finished requests retained for pop_result(); a long-running server that
 # never collects results must not leak host memory without bound.
 _MAX_RESULTS = 4096
@@ -425,6 +431,8 @@ class ServingEngine:
         self._last_step_s = 0.0
         self._last_stall_iter: Optional[int] = None
         self._iterations = 0
+        # readable process-wide at 0 too (see _count_retraces)
+        get_registry().counter("Serve/retraces")
         with self.engine.mesh:
             if self._paged:
                 self._state = self._prog("init_slots", lambda: jax.jit(
@@ -522,6 +530,27 @@ class ServingEngine:
 
         return InferenceEngine._cached(self._programs, key, counted,
                                        cap=_MAX_PROGRAMS)
+
+    def _count_retraces(self) -> None:
+        """``Serve/retraces``: signatures a built program has been traced
+        for beyond its first. ``compiles`` counts ``_prog`` builds; a
+        ``jax.jit`` handed an argument of another type, layout or
+        placement traces and compiles again inside the same build, and
+        only its own cache shows that. Read at the end of every
+        iteration, counted always (warm-up is where retraces happen), in
+        this engine's registry and the process-wide one; a RETRACE
+        instant names the program when a ring records."""
+        for key, fn in self._programs.items():
+            n = fn._cache_size()
+            seen = _SIGNATURES.get(fn, 1)     # the first is the build's
+            if n <= seen:
+                continue
+            _SIGNATURES[fn] = n
+            for reg in (self.stats.registry, get_registry()):
+                reg.counter("Serve/retraces").inc(n - seen)
+            _spans.instant(self.spans, self.stats.clock, _spans.RETRACE,
+                           program=str(key), signatures=n,
+                           step=self._iterations)
 
     def _chunk_impl(self, params, cache, ids, start):
         """Intermediate prefill chunk: extend the request cache; the head
@@ -790,38 +819,44 @@ class ServingEngine:
         (observability/tenantscope.py). Raises
         :class:`~..resilience.guards.QueueFullError` (status ``SHED``)
         when the queue is at ``max_queue`` or the engine is draining."""
-        if self._draining:
-            self.stats.on_shed(self.sched.queue_depth)
+        with self._span(_spans.SRV_SUBMIT) as sp:
+            if self._draining:
+                self.stats.on_shed(self.sched.queue_depth)
+                if self.tenantscope is not None:
+                    self.tenantscope.on_shed(tenant_id)
+                raise QueueFullError(
+                    "serving engine is draining; request shed",
+                    queue_depth=self.sched.queue_depth,
+                    max_queue=self.cfg.max_queue)
+            max_new = int(max_new_tokens
+                          or self.engine.config.max_out_tokens)
+            try:
+                req = self.sched.submit(prompt, max_new, seed,
+                                        ttft_deadline_s=ttft_deadline_s,
+                                        total_deadline_s=total_deadline_s,
+                                        session_id=session_id,
+                                        tenant_id=tenant_id)
+            except QueueFullError:
+                # typed shed (queue full / pool can never fit it): billed
+                # to the tenant even though no Request object exists yet
+                if self.tenantscope is not None:
+                    self.tenantscope.on_shed(tenant_id)
+                raise
+            if req.deadline_ttft is not None \
+                    or req.deadline_total is not None:
+                self._any_deadlines = True
+            if self.capture is not None:
+                # record the OVERRIDES as passed (None = config default),
+                # so replay under the same config reproduces deadline
+                # semantics
+                self.capture.on_submit(req, ttft_deadline_s=ttft_deadline_s,
+                                       total_deadline_s=total_deadline_s)
+            if self.loadscope is not None:
+                self.loadscope.on_submit(len(req.prompt), req.max_new,
+                                         self.sched.queue_depth)
             if self.tenantscope is not None:
-                self.tenantscope.on_shed(tenant_id)
-            raise QueueFullError("serving engine is draining; request shed",
-                                 queue_depth=self.sched.queue_depth,
-                                 max_queue=self.cfg.max_queue)
-        max_new = int(max_new_tokens or self.engine.config.max_out_tokens)
-        try:
-            req = self.sched.submit(prompt, max_new, seed,
-                                    ttft_deadline_s=ttft_deadline_s,
-                                    total_deadline_s=total_deadline_s,
-                                    session_id=session_id,
-                                    tenant_id=tenant_id)
-        except QueueFullError:
-            # typed shed (queue full / pool can never fit it): billed to
-            # the tenant even though no Request object exists yet
-            if self.tenantscope is not None:
-                self.tenantscope.on_shed(tenant_id)
-            raise
-        if req.deadline_ttft is not None or req.deadline_total is not None:
-            self._any_deadlines = True
-        if self.capture is not None:
-            # record the OVERRIDES as passed (None = config default), so
-            # replay under the same config reproduces deadline semantics
-            self.capture.on_submit(req, ttft_deadline_s=ttft_deadline_s,
-                                   total_deadline_s=total_deadline_s)
-        if self.loadscope is not None:
-            self.loadscope.on_submit(len(req.prompt), req.max_new,
-                                     self.sched.queue_depth)
-        if self.tenantscope is not None:
-            self.tenantscope.on_submit(req)
+                self.tenantscope.on_submit(req)
+            sp.note(rid=req.rid)
         return req.rid
 
     def requeue(self, req: Request) -> Request:
@@ -862,7 +897,25 @@ class ServingEngine:
         finished this iteration — normally (status ``OK``) or through a
         guard (``TIMEOUT`` / ``NONFINITE``); all are also kept in
         ``results``. Chaos disabled adds nothing to the device work and
-        no host syncs beyond the step's one fused read-back."""
+        no host syncs beyond the step's one fused read-back.
+
+        The iteration is one ``srv.step`` span and its phases are its
+        children, disjoint and in this order: ``deadlines``, ``admit``,
+        ``prefill_chunk``, ``prefill_readback``, ``place``,
+        ``decode_dispatch``, ``decode_readback``, ``retire``, ``tail``
+        (observability/spans.py; the span less the children is the
+        loop's own time)."""
+        with self._span(_spans.SRV_STEP, step=self._iterations):
+            return self._iterate()
+
+    def _span(self, kind, **fields):
+        """The seam (observability/spans.py) on this engine's ring and
+        clock: every timed piece of host code in this file goes through
+        here."""
+        return _spans.span(self.spans, self.stats.clock, kind, **fields)
+
+    def _iterate(self) -> list[Request]:
+        n_it = self._iterations
         finished: list[Request] = []
         ran_chunk = ran_decode = False
         stall_excess = 0.0
@@ -883,7 +936,8 @@ class ServingEngine:
         # past request carried one — a deadline-free server never pays
         # the sweep (or its clock read)
         if self._any_deadlines:
-            finished += self._expire_deadlines()
+            with self._span(_spans.SRV_DEADLINES, step=n_it):
+                finished += self._expire_deadlines()
         with self.engine.mesh:
             if self._paged:
                 # retired rows cleared last iteration must reach the
@@ -892,47 +946,11 @@ class ServingEngine:
                 self._flush_table()
             # admission: start the head-of-queue request's prefill
             if self._prefill is None:
-                req = self.sched.pop_next()
-                if req is not None:
-                    wa = None
-                    if self.workload is not None:
-                        # admission hook: score the prompt's prefix overlap
-                        # / self-speculation potential (host-side only)
-                        wa = self.workload.on_admit(req.prompt,
-                                                    session_id=req.session_id)
-                    if self.tenantscope is not None:
-                        # partition the same estimate by tenant (prompt
-                        # tokens, shared-prefix overlap)
-                        self.tenantscope.on_admit(req, workload=wa)
-                    if self.kvscope is not None:
-                        # residency probe beside it: ghost-tree regret
-                        # match + session resume edge (host-side only)
-                        self.kvscope.on_admit(req)
-                    cache = self._prog("init_cache", lambda: jax.jit(
-                        lambda: init_cache(self.model.cfg, 1,
-                                           self.cfg.max_len,
-                                           self.engine.compute_dtype)))()
-                    alloc = req.page_alloc
-                    if alloc is not None and alloc.hydrate_pages > 0:
-                        # prefix sharing: gather the shared pages into
-                        # the prefill cache ONCE; the chunk plan then
-                        # recomputes only the unshared suffix
-                        hyd = self._prog("hydrate", lambda: jax.jit(
-                            hydrate_cache, donate_argnums=(1,)))
-                        cache = hyd(self._state, cache,
-                                    jnp.asarray(alloc.hydrate_row),
-                                    jnp.int32(alloc.hydrate_pages))
-                    if alloc is not None and alloc.restored:
-                        # host-tier restore: the pending-restore lane
-                        # beside the prefill lane — scatter the cold
-                        # blocks' tiles into the prefill cache; the
-                        # suffix chunks dispatched next overlap the H2D
-                        cache = self._restore_dispatch(cache, alloc)
-                    self._prefill = (req, self.sched.plan(req), 0, cache,
-                                     per_request_keys([req.seed]))
+                with self._span(_spans.SRV_ADMIT, step=n_it):
+                    self._admit()
             # prefill lane: one bucket-shaped chunk per iteration
             if self._prefill is not None:
-                finished += self._prefill_advance()
+                finished += self._prefill_advance(n_it)
                 ran_chunk = True
             # decode lane: every occupied slot advances one token — or,
             # with speculation on, up to max_draft + 1 through one
@@ -942,42 +960,47 @@ class ServingEngine:
                 t0 = self.stats.clock()
                 n_slots = len(self.sched.running)
                 plan = spec_out = None
-                if chaos is not None:
-                    chaos.maybe_hang(it)
-                    poison = chaos.poison_slot(self.sched.running.keys())
-                    step = self._prog("step_chaos", lambda: jax.jit(
-                        self._step_chaos_impl, donate_argnums=(1,)))
-                    self._state, ok = step(self.engine.params, self._state,
-                                           jnp.int32(poison))
-                else:
-                    if self._spec is not None:
-                        plan = self._spec_plan()
-                    if plan is None:
-                        step = self._prog("step", lambda: jax.jit(
-                            self._step_impl, donate_argnums=(1,)))
+                with self._span(_spans.SRV_DECODE_DISPATCH, step=n_it):
+                    if chaos is not None:
+                        chaos.maybe_hang(it)
+                        poison = chaos.poison_slot(
+                            self.sched.running.keys())
+                        step = self._prog("step_chaos", lambda: jax.jit(
+                            self._step_chaos_impl, donate_argnums=(1,)))
                         self._state, ok = step(self.engine.params,
-                                               self._state)
-                if plan is not None:
-                    # verify + host acceptance + commit, all inside the
-                    # watchdog window; scheduler effects deferred below
-                    spec_out = self._spec_verify_commit(plan)
-                else:
-                    # ONE fused host read-back per iteration (tok + done +
-                    # per-row logit finiteness together): the
-                    # per-iteration sync is the scheduler's steering cost
-                    # — don't pay it twice, and don't let the guard add a
-                    # second one
-                    toks, dones, oks = jax.device_get(
-                        (self._state.tok, self._state.done, ok))
+                                               self._state,
+                                               jnp.int32(poison))
+                    else:
+                        if self._spec is not None:
+                            plan = self._spec_plan()
+                        if plan is None:
+                            step = self._prog("step", lambda: jax.jit(
+                                self._step_impl, donate_argnums=(1,)))
+                            self._state, ok = step(self.engine.params,
+                                                   self._state)
+                with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
+                    if plan is not None:
+                        # verify + host acceptance + commit, all inside
+                        # the watchdog window; scheduler effects deferred
+                        # below
+                        spec_out = self._spec_verify_commit(plan)
+                    else:
+                        # ONE fused host read-back per iteration (tok +
+                        # done + per-row logit finiteness together): the
+                        # per-iteration sync is the scheduler's steering
+                        # cost — don't pay it twice, and don't let the
+                        # guard add a second one
+                        toks, dones, oks = jax.device_get(
+                            (self._state.tok, self._state.done, ok))
                 t1 = self.stats.clock()
                 self._last_step_s = t1 - t0
-                if self.spans is not None:
-                    # reuses the t0/t1 the watchdog already measures — the
-                    # span layer adds no clock reads to the decode window
-                    self.spans.emit(_spans.DECODE_STEP, t0, t1,
-                                    step=self._iterations, slots=n_slots,
-                                    **({"spec": True} if plan is not None
-                                       else {}))
+                # the parent of the decode pair, from the t0/t1 the
+                # watchdog measures anyway; the counts at this boundary
+                # (slots decoding, requests waiting) ride on it
+                _spans.emit(self.spans, _spans.DECODE_STEP, t0, t1,
+                            step=n_it, slots=n_slots,
+                            queue=self.sched.queue_depth,
+                            **({"spec": True} if plan is not None else {}))
                 wd = self.cfg.watchdog_s
                 if wd and self._last_step_s > wd:
                     # rising edge: the previous iteration was healthy. A
@@ -1015,60 +1038,109 @@ class ServingEngine:
                                          step_s=self._last_step_s,
                                          median_s=med, mad_s=mad,
                                          iteration=self._iterations)
-                if spec_out is not None:
-                    finished += self._spec_resolve(spec_out)
-                else:
-                    if not oks.all():
-                        # retire ONLY the poisoned rows, before on_step
-                        # can append their garbage tokens; every other
-                        # slot's bookkeeping (and output bits) is
-                        # untouched
-                        bad = [s for s in np.nonzero(~oks)[0]
-                               if int(s) in self.sched.running]
-                        finished += self.sched.retire_nonfinite(bad)
-                    self._decode_slot_steps += n_slots
-                    self._decode_emitted += len(self.sched.running)
-                    finished += self.sched.on_step(toks, dones)
+                with self._span(_spans.SRV_RETIRE, step=n_it):
+                    if spec_out is not None:
+                        finished += self._spec_resolve(spec_out)
+                    else:
+                        if not oks.all():
+                            # retire ONLY the poisoned rows, before
+                            # on_step can append their garbage tokens;
+                            # every other slot's bookkeeping (and output
+                            # bits) is untouched
+                            bad = [s for s in np.nonzero(~oks)[0]
+                                   if int(s) in self.sched.running]
+                            finished += self.sched.retire_nonfinite(bad)
+                        self._decode_slot_steps += n_slots
+                        self._decode_emitted += len(self.sched.running)
+                        finished += self.sched.on_step(toks, dones)
                 ran_decode = True
-        if self._demote_ahead is not None:
-            # background demotion lane: stage idle tree-held pages into
-            # the tier BEFORE pressure (the staged gathers drain with
-            # this same iteration's batch below)
-            self._demote_ahead_tick()
-        if self._pending_demotes:
-            # off the TTFT path: the gathers dispatched at admission
-            # land in the host tier after this iteration's device work
-            self._drain_demotes()
-        self.stats.on_iteration(self.sched.queue_depth, self.sched.occupancy,
-                                self.cfg.slots, ran_chunk, ran_decode)
-        if self.spans is not None:
-            self.spans.counter(queue_depth=self.sched.queue_depth,
-                               occupancy=self.sched.occupancy)
-        if self._compile_storm is not None:
-            new = self._compile_storm.update(self._iterations, self.compiles)
-            if new:
-                self.stats.registry.counter("Serve/compile_storms").inc()
-                warning_once(
-                    f"serving compile storm: {new} new programs within "
-                    f"{self._compile_storm.window} iterations after "
-                    "warmup — shape drift or program-cache eviction "
-                    "(see docs/SERVING.md bucket tuning)")
-                if self.flight is not None:
-                    self.flight.note("compile_storm", new_compiles=new,
-                                     total_compiles=self.compiles,
-                                     iteration=self._iterations)
-        self._iterations += 1
-        if gp is not None:
-            gp.on_serving_iteration(
-                gp_t0, gp.clock(),
-                decode_s=self._last_step_s if ran_decode else 0.0,
-                ran_decode=ran_decode, ran_chunk=ran_chunk,
-                compiled=self.compiles > gp_compiles0,
-                stall_excess_s=stall_excess, draining=self._draining,
-                idle=self.sched.idle and self._prefill is None)
-        for req in finished:
-            self._store_result(req)
+        with self._span(_spans.SRV_TAIL, step=n_it):
+            if self._demote_ahead is not None:
+                # background demotion lane: stage idle tree-held pages
+                # into the tier BEFORE pressure (the staged gathers drain
+                # with this same iteration's batch below)
+                self._demote_ahead_tick()
+            if self._pending_demotes:
+                # off the TTFT path: the gathers dispatched at admission
+                # land in the host tier after this iteration's device
+                # work
+                self._drain_demotes()
+            self.stats.on_iteration(
+                self.sched.queue_depth, self.sched.occupancy,
+                self.cfg.slots, ran_chunk, ran_decode)
+            _spans.instant(self.spans, self.stats.clock, _spans.OCCUPANCY,
+                           queue_depth=self.sched.queue_depth,
+                           occupancy=self.sched.occupancy)
+            self._count_retraces()
+            if self._compile_storm is not None:
+                new = self._compile_storm.update(self._iterations,
+                                                 self.compiles)
+                if new:
+                    self.stats.registry.counter(
+                        "Serve/compile_storms").inc()
+                    warning_once(
+                        f"serving compile storm: {new} new programs within "
+                        f"{self._compile_storm.window} iterations after "
+                        "warmup — shape drift or program-cache eviction "
+                        "(see docs/SERVING.md bucket tuning)")
+                    if self.flight is not None:
+                        self.flight.note("compile_storm", new_compiles=new,
+                                         total_compiles=self.compiles,
+                                         iteration=self._iterations)
+            self._iterations += 1
+            if gp is not None:
+                gp.on_serving_iteration(
+                    gp_t0, gp.clock(),
+                    decode_s=self._last_step_s if ran_decode else 0.0,
+                    ran_decode=ran_decode, ran_chunk=ran_chunk,
+                    compiled=self.compiles > gp_compiles0,
+                    stall_excess_s=stall_excess, draining=self._draining,
+                    idle=self.sched.idle and self._prefill is None)
+            for req in finished:
+                self._store_result(req)
         return finished
+
+    def _admit(self) -> None:
+        """Start the head-of-queue request's prefill: pop it, tell the
+        observatories, dispatch the cache's init / hydrate / restore, and
+        seat it in the prefill lane."""
+        req = self.sched.pop_next()
+        if req is None:
+            return
+        wa = None
+        if self.workload is not None:
+            # admission hook: score the prompt's prefix overlap /
+            # self-speculation potential (host-side only)
+            wa = self.workload.on_admit(req.prompt,
+                                        session_id=req.session_id)
+        if self.tenantscope is not None:
+            # partition the same estimate by tenant (prompt tokens,
+            # shared-prefix overlap)
+            self.tenantscope.on_admit(req, workload=wa)
+        if self.kvscope is not None:
+            # residency probe beside it: ghost-tree regret match +
+            # session resume edge (host-side only)
+            self.kvscope.on_admit(req)
+        cache = self._prog("init_cache", lambda: jax.jit(
+            lambda: init_cache(self.model.cfg, 1, self.cfg.max_len,
+                               self.engine.compute_dtype)))()
+        alloc = req.page_alloc
+        if alloc is not None and alloc.hydrate_pages > 0:
+            # prefix sharing: gather the shared pages into the prefill
+            # cache ONCE; the chunk plan then recomputes only the
+            # unshared suffix
+            hyd = self._prog("hydrate", lambda: jax.jit(
+                hydrate_cache, donate_argnums=(1,)))
+            cache = hyd(self._state, cache, jnp.asarray(alloc.hydrate_row),
+                        jnp.int32(alloc.hydrate_pages))
+        if alloc is not None and alloc.restored:
+            # host-tier restore: the pending-restore lane beside the
+            # prefill lane — scatter the cold blocks' tiles into the
+            # prefill cache; the suffix chunks dispatched next overlap
+            # the H2D
+            cache = self._restore_dispatch(cache, alloc)
+        self._prefill = (req, self.sched.plan(req), 0, cache,
+                         per_request_keys([req.seed]))
 
     def _store_result(self, req: Request) -> None:
         if self._paged and req.slot >= 0 \
@@ -1136,36 +1208,44 @@ class ServingEngine:
             except QueueFullError:
                 pass  # the shed IS the scenario; counted in Serve/shed
 
-    def _prefill_advance(self) -> list[Request]:
+    def _prefill_advance(self, n_it: int) -> list[Request]:
         req, plan, idx, cache, rng = self._prefill
         ch = plan[idx]
-        ids = jnp.asarray(ch.ids[None], jnp.int32)
         params = self.engine.params
-        sp = self.spans
-        ct0 = sp.clock() if sp is not None else 0.0
-        att = self.sched._attempt_meta(req)
+        # the span is the DISPATCH of one chunk program: where dispatch
+        # is asynchronous (any accelerator) it times an enqueue, and the
+        # chunk's device time shows up in whatever blocks next
+        # (prefill_readback on a final chunk, else decode_readback)
+        with self._span(_spans.PREFILL_CHUNK, name="srv.prefill_chunk",
+                        rid=req.rid, step=n_it, chunk=idx, size=ch.size,
+                        final=ch.final, **self.sched._attempt_meta(req)):
+            ids = jnp.asarray(ch.ids[None], jnp.int32)
+            if not ch.final:
+                fwd = self._prog(("chunk", ch.size), lambda: jax.jit(
+                    self._chunk_impl, donate_argnums=(1,)))
+                cache = fwd(params, cache, ids, jnp.int32(ch.start))
+            else:
+                fin = self._prog(("final", ch.size), lambda: jax.jit(
+                    self._final_impl, donate_argnums=(1,)))
+                pf = fin(params, cache, ids, jnp.int32(ch.start),
+                         jnp.int32(ch.last_index), jnp.int32(ch.true_len),
+                         rng)
         if not ch.final:
-            fwd = self._prog(("chunk", ch.size), lambda: jax.jit(
-                self._chunk_impl, donate_argnums=(1,)))
-            cache = fwd(params, cache, ids, jnp.int32(ch.start))
-            if sp is not None:
-                # dispatch wall time: honest on CPU, a lower bound where
-                # the chunk overlaps the async device queue
-                sp.emit(_spans.PREFILL_CHUNK, ct0, sp.clock(), rid=req.rid,
-                        chunk=idx, size=ch.size, final=False, **att)
             self._prefill = (req, plan, idx + 1, cache, rng)
             return []
-        fin = self._prog(("final", ch.size), lambda: jax.jit(
-            self._final_impl, donate_argnums=(1,)))
-        pf = fin(params, cache, ids, jnp.int32(ch.start),
-                 jnp.int32(ch.last_index), jnp.int32(ch.true_len), rng)
-        if sp is not None:
-            sp.emit(_spans.PREFILL_CHUNK, ct0, sp.clock(), rid=req.rid,
-                    chunk=idx, size=ch.size, final=True, **att)
         self._prefill = None
-        first_tok = int(np.asarray(pf.tok)[0])
-        if req.max_new == 1 or bool(np.asarray(pf.done)[0]):
+        with self._span(_spans.SRV_PREFILL_READBACK, step=n_it):
+            first_tok = int(np.asarray(pf.tok)[0])
+            ended = req.max_new == 1 or bool(np.asarray(pf.done)[0])
+        if ended:
             return [self.sched.complete_at_prefill(req, first_tok)]
+        with self._span(_spans.SRV_PLACE, step=n_it):
+            self._place(req, first_tok, pf)
+        return []
+
+    def _place(self, req: Request, first_tok: int, pf) -> None:
+        """Seat a prefilled request: take a slot, dispatch the insert of
+        its cache into the slot state."""
         slot = self.sched.place(req, first_tok)
         # donate only the slot state: the batch-1 prefill buffers have
         # different shapes and could never alias the slot cache anyway
@@ -1196,7 +1276,6 @@ class ServingEngine:
             # iteration's decode lane runs — a prefill replica never
             # spends a decode step on a handed-off request
             self.on_placed(req, slot)
-        return []
 
     # ------------------------------------------------------ tiered host KV
     def _demote_pages(self, entries: list) -> None:
@@ -1801,11 +1880,16 @@ class ServingEngine:
         return census.report()
 
     def _prefill_rate(self) -> Optional[dict]:
-        """Measured prefill throughput from the span ring's
-        ``prefill_chunk`` spans (dispatch tokens / dispatch wall) — the
-        recompute-cost side of the tiered_kv lever. None when spans are
-        off or no chunk has run (the lever then degrades to score 0:
-        unmeasured, not guessed)."""
+        """Prefill tokens over the wall time of the span ring's
+        ``prefill_chunk`` spans — the recompute-cost side of the
+        tiered_kv lever. A ``prefill_chunk`` span is the DISPATCH of a
+        chunk program: on the CPU backend, which runs it before
+        returning, this is the prefill rate; on a device, where dispatch
+        is an enqueue, it is tokens per second of enqueueing, a rate no
+        chip can reach, and the lever that reads it (loadscope, kvscope)
+        then underprices recompute. None when spans are off or no chunk
+        has run (the lever then degrades to score 0: unmeasured, not
+        guessed)."""
         if self.spans is None:
             return None
         from ..observability import spans as _sp

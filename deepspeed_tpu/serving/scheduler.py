@@ -208,8 +208,10 @@ class Scheduler:
         self.ttft_deadline_s = float(ttft_deadline_s)
         self.total_deadline_s = float(total_deadline_s)
         # lifecycle span emission (observability/spans.py): every edge the
-        # scheduler already stamps becomes a typed event. None (default) =
-        # zero extra work beyond these `is not None` checks.
+        # scheduler already stamps becomes a typed event, through the
+        # seam's emit(): into this ring if there is one, and into the
+        # capture ring while a profiler capture is live. None (default)
+        # and no capture = a check per edge.
         self.spans = spans
         self.queue: deque[Request] = deque()
         self.free: list[int] = list(range(slots))
@@ -303,16 +305,14 @@ class Scheduler:
             # failover attribution: kill → re-admission, its OWN series
             # so TTFT and requeue delay stay separable in the logs
             self.stats.on_requeue_delay(admit_t - req.requeue_t)
-        if self.spans is not None:
-            # the queue-wait span: submitted → picked for prefill. A
-            # requeued ATTEMPT's span starts at the requeue (its first
-            # attempt already burned the wait from submit_t) and carries
-            # the attempt index, so per-attempt timings never conflate.
-            self.spans.emit(_spans.QUEUED,
-                            req.submit_t if req.requeue_t is None
-                            else req.requeue_t,
-                            admit_t, rid=req.rid,
-                            **self._attempt_meta(req))
+        # the queue-wait span: submitted → picked for prefill. A
+        # requeued ATTEMPT's span starts at the requeue (its first
+        # attempt already burned the wait from submit_t) and carries
+        # the attempt index, so per-attempt timings never conflate.
+        _spans.emit(self.spans, _spans.QUEUED,
+                    req.submit_t if req.requeue_t is None
+                    else req.requeue_t,
+                    admit_t, rid=req.rid, **self._attempt_meta(req))
         return req
 
     @staticmethod
@@ -340,9 +340,8 @@ class Scheduler:
         slot = self.free.pop(0)
         req.slot = slot
         self.running[slot] = req
-        if self.spans is not None:
-            self.spans.emit(_spans.PLACED, req.first_token_t, rid=req.rid,
-                            slot=slot, **self._attempt_meta(req))
+        _spans.emit(self.spans, _spans.PLACED, req.first_token_t,
+                    rid=req.rid, slot=slot, **self._attempt_meta(req))
         return slot
 
     def adopt(self, req: Request) -> int:
@@ -394,10 +393,9 @@ class Scheduler:
         # queue wait once; survivors' fresher submissions queue behind it
         self.queue.appendleft(req)
         self.stats.on_requeue(len(self.queue))
-        if self.spans is not None:
-            self.spans.emit(_spans.RETIRED, req.requeue_t, rid=req.rid,
-                            slot=None, status=req.status.value,
-                            tokens=0, attempt=req.attempts)
+        _spans.emit(self.spans, _spans.RETIRED, req.requeue_t, rid=req.rid,
+                    slot=None, status=req.status.value, tokens=0,
+                    attempt=req.attempts)
         return req
 
     def take_live(self) -> list:
@@ -427,23 +425,19 @@ class Scheduler:
         """Terminal span pair: the decode-residency span (first token →
         retirement, when the request ever held a slot) plus the typed
         RETIRED instant every terminal path emits."""
-        if self.spans is None:
-            return
         if req.slot >= 0 and req.first_token_t is not None \
                 and req.finish_t is not None:
-            self.spans.emit(_spans.DECODE_RESIDENCY,
-                            req.import_t1 if req.import_t1 is not None
-                            else req.first_token_t,
-                            req.finish_t, rid=req.rid, slot=req.slot,
-                            tokens=len(req.tokens),
-                            **self._attempt_meta(req))
-        self.spans.emit(_spans.RETIRED,
-                        req.finish_t if req.finish_t is not None
-                        else req.submit_t,
-                        rid=req.rid,
-                        slot=req.slot if req.slot >= 0 else None,
-                        status=req.status.value, tokens=len(req.tokens),
-                        **self._attempt_meta(req))
+            _spans.emit(self.spans, _spans.DECODE_RESIDENCY,
+                        req.import_t1 if req.import_t1 is not None
+                        else req.first_token_t,
+                        req.finish_t, rid=req.rid, slot=req.slot,
+                        tokens=len(req.tokens), **self._attempt_meta(req))
+        _spans.emit(self.spans, _spans.RETIRED,
+                    req.finish_t if req.finish_t is not None
+                    else req.submit_t,
+                    rid=req.rid, slot=req.slot if req.slot >= 0 else None,
+                    status=req.status.value, tokens=len(req.tokens),
+                    **self._attempt_meta(req))
 
     # -------------------------------------------------------------- decode
     def on_step(self, toks: np.ndarray, dones: np.ndarray) -> list:

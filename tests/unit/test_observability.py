@@ -855,6 +855,197 @@ def test_serving_spans_add_no_programs_and_keep_outputs():
     assert rids == {0, 1, 2, 3}
     trace = to_chrome_trace(spanned.spans.events())
     assert validate_chrome_trace(trace) == []
+    loop = [e["name"] for e in trace["traceEvents"]
+            if e.get("ph") == "X" and e["tid"] == 5]
+    assert {"step", "admit", "decode_readback", "tail"} <= set(loop)
+    # off path: no ``spans``, no capture. Twenty more iterations with work
+    # in them leave no ring, record nothing for the capture's reader and
+    # build no program
+    assert plain.spans is None and not spans_mod.TraceAnnotation.is_enabled()
+    held = len(spans_mod.captured())
+    programs = plain.compiles
+    for p in prompts:
+        plain.submit(p, 6)
+    for _ in range(20):
+        plain.step()
+    assert len(spans_mod.captured()) == held
+    assert plain.compiles == programs
+
+
+@pytest.fixture(scope="module")
+def tiny_server():
+    """engine, prompts: a two-slot server on a counting clock."""
+    import jax.numpy as jnp
+
+    model = build_model(tiny_test(max_seq=64, dtype=jnp.float32))
+    eng = ds.init_inference(model, model.init(jax.random.PRNGKey(0)),
+                            {"dtype": "float32"})
+    rng = np.random.default_rng(5)
+    # 5 and 9 tokens: one final chunk; 21 and 33: chunks before it
+    prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
+               for n in (5, 21, 9, 33)]
+    return eng, prompts
+
+
+@pytest.fixture(scope="module")
+def iteration_spans(tiny_server):
+    """The ring of a server that served four requests with ``spans`` on,
+    by iteration: {step: (the srv.step span, [its children])}."""
+    eng, prompts = tiny_server
+    srv = ds.ServingEngine(eng, {"slots": 2, "max_len": 48,
+                                 "prefill_chunk": 16, "spans": True},
+                           clock=TickClock())
+    srv.serve_batch(prompts, 5, seeds=[1, 2, 3, 4])
+    evs = srv.spans.events()
+    steps = {e.step: (e, []) for e in evs if e.kind == spans_mod.SRV_STEP}
+    for e in evs:
+        if e.step is not None and e.t1 is not None \
+                and e.kind not in (spans_mod.SRV_STEP, spans_mod.DECODE_STEP):
+            steps[e.step][1].append(e)
+    return evs, steps
+
+
+PHASE_ORDER = ["srv.deadlines", "srv.admit", "prefill_chunk",
+               "srv.prefill_readback", "srv.place", "srv.decode_dispatch",
+               "srv.decode_readback", "srv.retire", "srv.tail"]
+
+
+@pytest.mark.parametrize("check", [
+    "children_inside_in_order", "self_time", "readback_on_final_chunks",
+    "decode_step_is_the_parent_of_the_decode_pair", "readers_kinds_and_meta",
+    "submit_carries_the_rid"])
+def test_serving_iteration_spans(iteration_spans, check):
+    """One serving iteration through the seam: ``srv.step`` and its
+    phases, disjoint, in the order the loop runs them, each carrying the
+    iteration that caused it."""
+    evs, steps = iteration_spans
+    assert len(steps) >= 8
+    if check == "children_inside_in_order":
+        for step, (parent, kids) in steps.items():
+            assert kids, step
+            kinds = [k.kind for k in kids]
+            assert kinds == sorted(kinds, key=PHASE_ORDER.index), kinds
+            assert len(set(kinds)) == len(kinds)
+            edges = [parent.t0] + [t for k in kids for t in (k.t0, k.t1)] \
+                + [parent.t1]
+            assert edges == sorted(edges), (step, kinds)
+            assert all(k.step == step for k in kids)
+    elif check == "self_time":
+        for parent, kids in steps.values():
+            own = parent.duration - sum(k.duration for k in kids)
+            assert own >= 0
+            # the counting clock: two reads a span, one more between
+            # neighbours, so the loop's own time is never nothing
+            assert own >= 0.001 * (len(kids) + 1) - 1e-9
+    elif check == "readback_on_final_chunks":
+        for parent, kids in steps.values():
+            chunk = [k for k in kids if k.kind == "prefill_chunk"]
+            read = [k for k in kids if k.kind == "srv.prefill_readback"]
+            assert len(chunk) <= 1
+            assert bool(read) == bool(chunk and chunk[0].meta["final"])
+        finals = [e.meta["final"] for e in evs if e.kind == "prefill_chunk"]
+        assert finals.count(True) == 4 and finals.count(False) == 3
+    elif check == "decode_step_is_the_parent_of_the_decode_pair":
+        decode = {e.step: e for e in evs if e.kind == "decode_step"}
+        assert decode
+        for step, d in decode.items():
+            pair = [k for k in steps[step][1] if k.kind in (
+                "srv.decode_dispatch", "srv.decode_readback")]
+            assert len(pair) == 2
+            assert d.t0 < pair[0].t0 and pair[1].t1 < d.t1
+            assert 1 <= d.meta["slots"] <= 2 and d.meta["queue"] >= 0
+    elif check == "readers_kinds_and_meta":
+        # what export.py, flight.py, capacity.py, _decode_rate and
+        # _prefill_rate read, under the names they read it by
+        kinds = {e.kind for e in evs}
+        assert {"queued", "prefill_chunk", "placed", "decode", "retired",
+                "decode_step", "occupancy"} <= kinds
+        chunk = next(e for e in evs if e.kind == "prefill_chunk")
+        assert {"chunk", "size", "final"} <= set(chunk.meta)
+        assert chunk.rid is not None
+        occ = next(e for e in evs if e.kind == "occupancy")
+        assert set(occ.meta) == {"queue_depth", "occupancy"}
+    else:
+        subs = [e for e in evs if e.kind == "srv.submit"]
+        assert [e.rid for e in subs] == [0, 1, 2, 3]
+        assert all(e.step is None for e in subs)
+
+
+def _host_annotations(trace_dir, prefix):
+    from jax.profiler import ProfileData
+
+    (path,) = (trace_dir / "plugins" / "profile").glob("*/*.xplane.pb")
+    found = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            mine = [e.name for e in line.events if e.name.startswith(prefix)]
+            if mine:
+                found.append((plane.name, line.name, mine))
+    return found
+
+
+def test_seam_records_in_a_live_capture_and_only_there(tiny_server,
+                                                       tmp_path):
+    """With ``spans`` unset a profiler capture switches the seam on: the
+    same spans are annotations on the capture's host line, under
+    ``ds.<name>``, and events behind ``captured()``; after ``stop_trace``
+    neither grows."""
+    eng, prompts = tiny_server
+    srv = ds.ServingEngine(eng, {"slots": 2, "max_len": 48,
+                                 "prefill_chunk": 16})
+    srv.serve_batch(prompts[:1], 3)            # warm, no capture
+    assert srv.spans is None
+    first = srv._iterations
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.serve_batch(prompts, 4, seeds=[1, 2, 3, 4])
+    finally:
+        jax.profiler.stop_trace()
+    iterations = srv._iterations - first
+    got = spans_mod.captured()
+    srv.serve_batch(prompts[:2], 3)            # after the capture
+    assert len(spans_mod.captured()) == len(got)
+    assert srv.spans is None
+    timed = [e for e in got if e.kind.startswith("srv.")
+             or e.kind == "prefill_chunk"]
+    ((plane, line, names),) = _host_annotations(tmp_path, "ds.")
+    assert plane == "/host:CPU" and line.startswith("python")
+    assert sorted(names) == sorted(
+        "ds.srv.prefill_chunk" if e.kind == "prefill_chunk"
+        else "ds." + e.kind for e in timed)
+    assert names.count("ds.srv.step") == iterations >= 8
+    # the lifecycle and the counts come along, from stamps: ring only
+    kinds = {e.kind for e in got}
+    assert {"queued", "placed", "decode", "retired", "decode_step",
+            "occupancy"} <= kinds
+    assert {e.rid for e in got if e.kind == "queued"} == {1, 2, 3, 4}
+
+
+@pytest.mark.parametrize("second, counted", [("same", 0), ("other", 1)])
+def test_retraces_counts_new_signatures_of_a_built_program(tiny_server,
+                                                           second, counted):
+    """``Serve/retraces``: a built program called with another argument
+    signature traces again inside its ``jax.jit`` (``compiles`` cannot
+    see that); the same signature again does not."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.observability import get_registry
+
+    eng, _ = tiny_server
+    srv = ds.ServingEngine(eng, {"slots": 2, "max_len": 48,
+                                 "prefill_chunk": 16, "spans": True})
+    everywhere = get_registry().counter("Serve/retraces")
+    before, built = everywhere.value, srv.compiles
+    prog = srv._prog("probe", lambda: jax.jit(lambda x: x + 1))
+    prog(jnp.zeros(3, jnp.float32))
+    prog(jnp.zeros(3, jnp.float32 if second == "same" else jnp.int32))
+    srv.step()
+    assert srv.compiles == built + 1
+    assert everywhere.value - before == counted
+    mine = srv.stats.registry.snapshot()["counters"].get(
+        "Serve/retraces", 0)
+    assert mine == counted
+    marks = [e.meta for e in srv.spans.events() if e.kind == "retrace"]
+    assert [m["program"] for m in marks] == ["probe"] * counted
 
 
 # ----------------------------------------------------------- doctor CLI
@@ -996,3 +1187,33 @@ def test_train_and_generate_all_sinks_smoke(tmp_path, monkeypatch):
     # untraced engine: publish is a no-op, not an error
     _, _, plain = _tiny_engine()
     assert plain.publish_metrics(mon) == 0
+
+
+@pytest.mark.parametrize("breakdown", [False, True])
+def test_train_step_spans_come_from_the_seam(breakdown):
+    """``train_step`` and its parts are recorded whenever a ring records,
+    not only under ``wall_clock_breakdown``; the parts lie inside the
+    step, in order, numbered as the step that caused them."""
+    engine = ds.initialize({
+        "train_batch_size": 8, "steps_per_print": 2,
+        "wall_clock_breakdown": breakdown,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+        "observability": {"spans": True},
+    }, build_model(tiny_test(n_layer=2)))
+    ids = np.random.default_rng(0).integers(0, 256, (8, 32)).astype(np.int32)
+    for _ in range(2):
+        engine.train_batch({"input_ids": ids, "labels": ids})
+    evs = engine.spans.events()
+    engine.close()
+    steps = [e for e in evs if e.kind == "train_step"]
+    assert [e.step for e in steps] == [1, 2]
+    for parent in steps:
+        parts = [e for e in evs if e.kind == "train_phase"
+                 and e.step == parent.step]
+        want = ["batch_prep", "step_dispatch"]
+        if breakdown or parent.step == 2:      # a sync: asked for, or due
+            want.append("step_sync")
+        assert [e.meta["phase"] for e in parts] == want
+        edges = [parent.t0] + [t for e in parts for t in (e.t0, e.t1)] \
+            + [parent.t1]
+        assert edges == sorted(edges)
