@@ -143,6 +143,20 @@ def _gib(b) -> str:
     return "n/a" if b is None else f"{b / 2**30:.2f} GiB"
 
 
+STEP_TEMP_LIMIT = 2 ** 30
+
+
+def step_temporaries(phase: str, compiled):
+    """Bytes the compiled step program holds beside its arguments and
+    outputs, by the compiler's own account (None where the backend gives
+    none)."""
+    mem = compiled.memory_analysis()
+    temp = getattr(mem, "temp_size_in_bytes", None)
+    say(f"{phase}: step program temporaries {_gib(temp)} beside arguments "
+        f"{_gib(getattr(mem, 'argument_size_in_bytes', None))}")
+    return temp
+
+
 # ------------------------------------------------------------------ sizes
 def sizes(rehearse: bool) -> dict:
     if rehearse:
@@ -351,22 +365,35 @@ def phase_serve(sz, seed, watch, devices):
     for o, n in zip(got, sz["new"]):
         check(len(o) == n and (o >= 0).all() and (o < cfg.vocab_size).all(),
               f"serve: answer of {n} in-vocab tokens")
-    texts = srv.compiled_texts()
+    programs = srv.compiled_programs()
+    texts = {name: c.as_text() for name, c in programs.items()}
     say(f"serve/contiguous: {srv.compiles} programs compiled")
     check(any(name.startswith("chunk_") for name in texts),
           "serve: chunked prefill ran (a prompt longer than prefill_chunk)")
-    report_programs("serve/contiguous", texts, expect=("decode_attention",))
+    report_programs("serve/contiguous", texts,
+                    expect=("decode_attention", "cache_append"))
+    # the step carries the cache donated and only its two kernels touch it:
+    # temporaries the size of a cache mean it is being copied or re-laid
+    # out again (PERF.md F10: 6.25 GiB at 32 slots)
+    temp = step_temporaries("serve/contiguous", programs["step"])
+    check(temp is None or temp <= STEP_TEMP_LIMIT,
+          f"serve/contiguous: step program temporaries {_gib(temp)} at or "
+          f"under {_gib(STEP_TEMP_LIMIT)}")
     compare("serve/contiguous vs solo generate", eng, prompts, got, ref,
             f32_pair({}))
     del srv
 
     # -- bf16, paged pool ------------------------------------------------
     srv, paged = serve(eng, sz, prompts, page_size=sz["page"])
-    texts = srv.compiled_texts()
+    programs = srv.compiled_programs()
+    texts = {name: c.as_text() for name, c in programs.items()}
     report_programs("serve/paged", texts)
     say("serve/paged: decode kernel in the paged step program: "
         f"{'decode_attention' in kernels_in(texts['step'])} (the page gather "
         "runs first either way — ROADMAP S2(b))")
+    # read, not judged: the paged step gathers every slot's pages into a
+    # contiguous view per layer, and that view is a temporary by design
+    step_temporaries("serve/paged", programs["step"])
     compare("serve/paged vs solo generate", eng, prompts, paged, ref,
             f32_pair({}, a_srv={"page_size": sz["page"]}))
     del srv, eng

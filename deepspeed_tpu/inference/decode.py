@@ -3,10 +3,14 @@
 Reference analog: the fused inference kernels and KV-cache workspace of
 ``csrc/transformer/inference/`` (``softmax_context`` = attention over the
 cache, ``inference_context.h`` = the cache allocator). TPU-native: the cache
-is a pair of ``(L, B, KV, max_len, hd)`` arrays updated with
-``dynamic_update_slice`` inside the compiled step; attention over the cache
+is a pair of ``(L, B, KV, hd, max_len)`` arrays; attention over the cache
 masks positions beyond the current length, so every decode step has an
 identical static shape (one compiled program for the whole generation).
+The T == 1 step carries the cache through its layer loop as ONE donated
+buffer that only two kernels touch (``ops/decode_attention.py``:
+``cache_append`` writes the new position in place, ``decode_attention``
+reads it by layer); T > 1 (prefill, speculative verify) appends with
+``dynamic_update_slice`` and attends densely over the same layout.
 """
 
 from __future__ import annotations
@@ -32,13 +36,16 @@ BIG_NEG = -2.0 ** 30
 
 
 class KVCache(NamedTuple):
-    # (L, B, KV, max_len, hd): heads-major so the Pallas decode kernel's
-    # cache operand blocks as (None, None, max_len, hd) — TPU lowering
-    # requires the last two block dims be (sublane, lane)-shaped, which a
-    # seq-major (max_len, KV, hd) layout cannot satisfy (round-5 hardware
-    # contact: "block shape ... (Squeezed(), Blocked(256), Squeezed(), 64)")
-    k: jnp.ndarray           # (L, B, KV, max_len, hd)
-    v: jnp.ndarray           # (L, B, KV, max_len, hd)
+    # (L, B, KV, hd, max_len): POSITIONS ON THE LANES. HBM tiles the last
+    # two dims 8 x 128 words, and max_len is a multiple of 128 wherever the
+    # kernels run, so the buffer has no padding at any head_dim and the
+    # decode kernels' (hd, max_len) / (KV, hd, 128) blocks are the memory
+    # as it lies. With hd last, a head_dim of 64 fills half of every tile:
+    # the compiler then stores the cache the other way round anyway and
+    # re-lays every layer's slab out around each kernel call (PERF.md F10).
+    # Heads-major, so a (slot, kv-head)'s positions are one block.
+    k: jnp.ndarray           # (L, B, KV, hd, max_len)
+    v: jnp.ndarray           # (L, B, KV, hd, max_len)
     length: jnp.ndarray      # i32 tokens cached: scalar (all rows advance
                              # together) or (B,) per-slot (serving/slots.py)
 
@@ -85,15 +92,16 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     into its slot (or scattered into its pages) with no relayout.
 
     ``page_size=0`` (default) is the contiguous per-slot layout
-    ``(L, batch, KV, max_len, hd)``; ``page_size > 0`` is the pooled page
-    layout ``(L, pages, KV, page_size, hd)`` — same trailing
-    (sublane, lane) = (positions, hd) shape per page, so one page is a
-    position-contiguous tile of the contiguous layout and the gather over
-    a slot's page-table row reassembles exactly the contiguous view."""
+    ``(L, batch, KV, hd, max_len)``, positions on the lanes (see
+    :class:`KVCache`); ``page_size > 0`` is the pooled page layout
+    ``(L, pages, KV, page_size, hd)`` — a page is ``page_size`` whole
+    positions, far fewer than a lane tile, so it keeps ``hd`` last; the
+    gather over a slot's page-table row (:func:`_paged_view`) and the
+    bridges in ``serving/pages.py`` turn pages into the contiguous view."""
     if page_size > 0:
         return ((cfg.n_layer, pages, cfg.kv_heads, page_size, cfg.head_dim),
                 dtype or cfg.dtype)
-    return ((cfg.n_layer, batch, cfg.kv_heads, max_len, cfg.head_dim),
+    return ((cfg.n_layer, batch, cfg.kv_heads, cfg.head_dim, max_len),
             dtype or cfg.dtype)
 
 
@@ -104,9 +112,36 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                    length=jnp.zeros((), jnp.int32))
 
 
+def _decode_kernel_ok(flash_decode: bool, T: int, max_len: int,
+                      *dtypes) -> bool:
+    """Whether a T-token forward over a cache of ``max_len`` positions runs
+    the Pallas decode kernels (``ops/decode_attention.py``) — the ONE gate
+    :func:`forward_with_cache` (which loop to build) and
+    :func:`_cache_attend` share."""
+    # Mosaic has no f16: an fp16 engine (or an externally-built fp16 KV
+    # cache under a bf16 trunk) must take the dense path on TPU instead of
+    # failing Mosaic compilation inside the decode scan — same gate and
+    # one-shot warning as flash_attention's.
+    f16_in = any(jnp.dtype(d) == jnp.float16 for d in dtypes) \
+        and jax.default_backend() == "tpu"
+    if f16_in and flash_decode:
+        from ..utils.logging import warning_once
+
+        warning_once(
+            "decode: float16 q/KV-cache falls back to the dense XLA "
+            "cache attention on TPU (Mosaic has no f16). The dense "
+            "path materializes (B, H, 1, max_len) scores per step — "
+            "prefer bf16 compute for long generations.")
+    # TPU lane tiling wants full 128-wide blocks: generate_tokens pads the
+    # cache to a 128 multiple when flash_decode is on, so this gate only
+    # declines externally-built odd caches (which take the dense path
+    # rather than risking an unaligned Pallas tile on hardware).
+    return (flash_decode and not f16_in and T == 1 and max_len % 128 == 0)
+
+
 def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
                   alibi=None):
-    """q: (B, T, H, hd) vs cache (B, KV, max_len, hd); positions >= length
+    """q: (B, T, H, hd) vs cache (B, KV, hd, max_len); positions >= length
     masked. For prefill T = prompt len (with causal offset); decode T = 1.
 
     ``length`` is a scalar (all rows at the same position — the
@@ -123,26 +158,9 @@ def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
     hot path to the Pallas streaming kernel (ops/decode_attention.py)
     instead of materializing the full (B, H, 1, max_len) score tensor."""
     B, T, H, hd = q.shape
-    # Mosaic has no f16: an fp16 engine (or an externally-built fp16 KV
-    # cache under a bf16 trunk) must take the dense path on TPU instead of
-    # failing Mosaic compilation inside the decode scan — same gate and
-    # one-shot warning as flash_attention's.
-    f16_in = any(jnp.dtype(x.dtype) == jnp.float16 for x in (q, ck, cv)) \
-        and jax.default_backend() == "tpu"
-    if f16_in and flash_decode:
-        from ..utils.logging import warning_once
-
-        warning_once(
-            "decode: float16 q/KV-cache falls back to the dense XLA "
-            "cache attention on TPU (Mosaic has no f16). The dense "
-            "path materializes (B, H, 1, max_len) scores per step — "
-            "prefer bf16 compute for long generations.")
-    # TPU lane tiling wants full 128-wide blocks: generate_tokens pads the
-    # cache to a 128 multiple when flash_decode is on, so this gate only
-    # declines externally-built odd caches (which take the dense path
-    # rather than risking an unaligned Pallas tile on hardware).
-    if (flash_decode and not f16_in and bias is None and T == 1
-            and ck.shape[2] % 128 == 0):
+    max_len = ck.shape[3]
+    if bias is None and _decode_kernel_ok(flash_decode, T, max_len,
+                                          q.dtype, ck.dtype, cv.dtype):
         from ..ops.decode_attention import decode_attention
 
         return decode_attention(q, ck, cv, length, alibi_slopes=alibi)
@@ -150,7 +168,7 @@ def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
     if KV != H:
         ck = jnp.repeat(ck, H // KV, axis=1)
         cv = jnp.repeat(cv, H // KV, axis=1)
-    scores = jnp.einsum("bthd,bhsd->bhts", q, ck).astype(jnp.float32)
+    scores = jnp.einsum("bthd,bhds->bhts", q, ck).astype(jnp.float32)
     scores = scores / math.sqrt(hd)
     if getattr(length, "ndim", 0) == 1:
         # per-slot lengths: the position grid gains a batch dim; an
@@ -162,7 +180,7 @@ def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
                              "alibi slopes instead")
         t_pos = length[:, None, None] - T \
             + jnp.arange(T)[None, :, None]               # (B, T, 1)
-        s_pos = jnp.arange(ck.shape[2])[None, None, :]   # (1, 1, max_len)
+        s_pos = jnp.arange(max_len)[None, None, :]       # (1, 1, max_len)
         if alibi is not None:
             rel = (s_pos - t_pos).astype(jnp.float32)    # (B, T, max_len)
             scores = scores + alibi[None, :, None, None] * rel[:, None]
@@ -172,7 +190,7 @@ def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
         # query t sits at global position length - T + t; key at slot s —
         # ONE set of position math drives both the alibi bias and the mask
         t_pos = length - T + jnp.arange(T)[:, None]      # (T, 1)
-        s_pos = jnp.arange(ck.shape[2])[None, :]         # (1, max_len)
+        s_pos = jnp.arange(max_len)[None, :]             # (1, max_len)
         if alibi is not None:
             rel = (s_pos - t_pos).astype(jnp.float32)    # (T, max_len)
             ab = alibi[:, None, None] * rel[None]        # (H, T, max_len)
@@ -182,7 +200,7 @@ def _cache_attend(q, ck, cv, length, flash_decode: bool = False, bias=None,
         keep = s_pos <= t_pos                            # (T, max_len)
         scores = jnp.where(keep[None, None], scores, BIG_NEG)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bhsd->bthd", probs, cv)
+    return jnp.einsum("bhts,bhds->bthd", probs, cv)
 
 
 def quantize_kv(x, axis: int = -1):
@@ -247,7 +265,7 @@ def _paged_append(ck, cv, ks, vs, k, v, page_table, new_len):
 
 def _paged_view(cp, sp, page_table, dtype):
     """Gather one layer's pool pages into the slot batch's contiguous
-    attention view ``(B, KV, max_len, hd)`` — the page-table indirection
+    attention view ``(B, KV, hd, max_len)`` — the page-table indirection
     the tentpole puts INSIDE the attention read. Page ids are data, not
     shapes: traffic churn changes table contents, never the program. An
     int8 pool dequantizes here, at the point of use (scales broadcast
@@ -256,11 +274,34 @@ def _paged_view(cp, sp, page_table, dtype):
     dequantized pool."""
     g = cp[page_table]                             # (B, n, KV, ps, hd)
     B, n, KV, ps, hd = g.shape
-    g = g.transpose(0, 2, 1, 3, 4).reshape(B, KV, n * ps, hd)
+    g = g.transpose(0, 2, 4, 1, 3).reshape(B, KV, hd, n * ps)
     if sp is not None:
-        s = sp[page_table].transpose(0, 2, 1, 3).reshape(B, KV, n * ps)
-        g = (g.astype(jnp.float32) * s[..., None]).astype(dtype)
+        s = sp[page_table].transpose(0, 2, 1, 3).reshape(B, KV, 1, n * ps)
+        g = (g.astype(jnp.float32) * s).astype(dtype)
     return g
+
+
+def _dense_append(cache, new, layer, length):
+    """Write T new positions ``new`` (B, T, KV, hd) into layer ``layer`` of
+    the carried cache ``(L, B, KV, hd, max_len)`` with XLA's own update,
+    ending at ``length`` (scalar, or (B,) per slot). Returns (the layer's
+    slab ``(B, KV, hd, max_len)`` to attend over, the cache)."""
+    T = new.shape[1]
+    start = length - T     # positions [start, start + T) get the new values
+    new = new.transpose(0, 2, 3, 1).astype(cache.dtype)     # (B, KV, hd, T)
+    if getattr(length, "ndim", 0) == 0:
+        cache = lax.dynamic_update_slice(cache, new[None],
+                                         (layer, 0, 0, 0, start))
+        return lax.dynamic_index_in_dim(cache, layer, keepdims=False), cache
+    # per-slot write positions: one dynamic_update_slice per row via vmap
+    # (lowers to a scatter) — each serving slot appends at its own length
+    # while the batch stays one static program. On the layer's slab: a
+    # scatter into the carried cache itself makes the compiler re-lay the
+    # WHOLE cache out for the update
+    slab = jax.vmap(lambda c, u, s: lax.dynamic_update_slice(c, u, (0, 0, s)))(
+        lax.dynamic_index_in_dim(cache, layer, keepdims=False), new, start)
+    return slab, lax.dynamic_update_slice(cache, slab[None],
+                                          (layer, 0, 0, 0, 0))
 
 
 def _tp_quant_eligible(model, p, T: int) -> int:
@@ -330,7 +371,7 @@ def _qkv_proj(model, y, p):
 
 @jax.named_scope("decode_layer")
 def _layer_step(model, x, p, cache_k, cache_v, length, positions,
-                flash_decode: bool = False, paged=None):
+                flash_decode: bool = False, paged=None, layer=None):
     """One transformer layer over x: (B, T, d), reading/writing the cache.
 
     Returns (x_out, new_cache_k, new_cache_v) — plus the new scale pools
@@ -347,6 +388,12 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     the attention read gathers the slot's pages back into the contiguous
     view — same values, same mask math, so the fp paged step is
     bit-identical to the contiguous one by construction.
+
+    Without ``paged``, ``cache_k``/``cache_v`` are the WHOLE carried
+    ``(L, B, KV, hd, max_len)`` cache and ``layer`` (traced i32) this
+    layer's index in it; ``flash_decode`` is then the gate's answer
+    (:func:`_decode_kernel_ok`, asked once by :func:`forward_with_cache`):
+    the two decode kernels append and attend in place, by layer index.
     """
     cfg = model.cfg
     B, T, d = x.shape
@@ -357,33 +404,6 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     if cfg.pos_embedding == "rope":
         q, k = _rope(q, k, positions, cfg.rope_theta, cfg.rotary_dim)
 
-    scale_k = scale_v = None
-    if paged is not None:
-        page_table, scale_k, scale_v = paged
-        cache_k, cache_v, scale_k, scale_v = _paged_append(
-            cache_k, cache_v, scale_k, scale_v, k, v, page_table, length)
-        attend_k = _paged_view(cache_k, scale_k, page_table, cfg.dtype)
-        attend_v = _paged_view(cache_v, scale_v, page_table, cfg.dtype)
-    else:
-        start = length - T  # cache slots [start, start+T) get the new k/v
-        if getattr(length, "ndim", 0) == 1:
-            # per-slot write positions: one dynamic_update_slice per row
-            # via vmap (lowers to a scatter) — each serving slot appends
-            # at its own length while the batch stays one static program
-            upd = jax.vmap(lambda c, u, s: lax.dynamic_update_slice(
-                c, u, (0, s, 0)))
-            cache_k = upd(cache_k, k.swapaxes(1, 2).astype(cache_k.dtype),
-                          start)
-            cache_v = upd(cache_v, v.swapaxes(1, 2).astype(cache_v.dtype),
-                          start)
-        else:
-            cache_k = lax.dynamic_update_slice(
-                cache_k, k.swapaxes(1, 2).astype(cache_k.dtype),
-                (0, 0, start, 0))
-            cache_v = lax.dynamic_update_slice(
-                cache_v, v.swapaxes(1, 2).astype(cache_v.dtype),
-                (0, 0, start, 0))
-        attend_k, attend_v = cache_k, cache_v
     alibi = None
     if cfg.pos_embedding == "alibi":
         # ALiBi positional signal (mirrors _attention_block's training
@@ -392,8 +412,26 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         from ..models.transformer import alibi_slopes
 
         alibi = alibi_slopes(h)
-    o = _cache_attend(q, attend_k, attend_v, length, flash_decode=flash_decode,
-                      alibi=alibi)
+    scale_k = scale_v = None
+    if paged is None and flash_decode:
+        from ..ops.decode_attention import cache_append, decode_attention
+
+        cache_k, cache_v = cache_append(cache_k, cache_v, k, v, length,
+                                        layer=layer)
+        o = decode_attention(q, cache_k, cache_v, length, layer=layer,
+                             alibi_slopes=alibi)
+    else:
+        if paged is not None:
+            page_table, scale_k, scale_v = paged
+            cache_k, cache_v, scale_k, scale_v = _paged_append(
+                cache_k, cache_v, scale_k, scale_v, k, v, page_table, length)
+            attend_k = _paged_view(cache_k, scale_k, page_table, cfg.dtype)
+            attend_v = _paged_view(cache_v, scale_v, page_table, cfg.dtype)
+        else:
+            attend_k, cache_k = _dense_append(cache_k, k, layer, length)
+            attend_v, cache_v = _dense_append(cache_v, v, layer, length)
+        o = _cache_attend(q, attend_k, attend_v, length,
+                          flash_decode=flash_decode, alibi=alibi)
     # Quantized TP decode collective (inference.tp_comm_quant): the wo
     # and dense-MLP w_out partial-sum reductions — the per-token
     # model-axis wire cost every TP decode step pays — spell as explicit
@@ -543,15 +581,30 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
         new_cache = PagedKVCache(k=ck, v=cv, k_scale=ks, v_scale=vs,
                                  page_table=cache.page_table, length=new_len)
     else:
-        def scan_fn(carry, layer_in):
-            x = carry
-            lp, ck, cv = layer_in
-            x, ck, cv = _layer_step(model, x, lp, ck, cv, new_len, positions,
-                                    flash_decode=flash_decode)
-            return x, (ck, cv)
+        # the cache is ONE buffer carried through the layer loop and
+        # indexed by layer. As the loop's xs/ys every layer's slab is
+        # sliced out and written back, and the whole cache copied around
+        # the loop. The T == 1 step appends and reads with the two decode
+        # kernels, in place; T > 1 (and a step the gate declines) appends
+        # with dynamic_update_slice and attends densely over the layer
+        fused = _decode_kernel_ok(flash_decode, T, cache.k.shape[4], x.dtype,
+                                  cache.k.dtype, cache.v.dtype)
+        if T == 1 and not fused:
+            from ..observability.metrics import get_registry
 
-        x, (ck, cv) = lax.scan(scan_fn,
-                               x, (params["layers"], cache.k, cache.v))
+            # counted where a step program is built (a trace, not a call):
+            # 0 says every step program of this process runs the kernels
+            get_registry().counter("Serve/decode_fallback_builds").inc()
+
+        def scan_fn(carry, layer_in):
+            x, ck, cv = carry
+            lp, layer = layer_in
+            return _layer_step(model, x, lp, ck, cv, new_len, positions,
+                               flash_decode=fused, layer=layer), None
+
+        (x, ck, cv), _ = lax.scan(
+            scan_fn, (x, cache.k, cache.v),
+            (params["layers"], jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
         new_cache = KVCache(k=ck, v=cv, length=new_len)
     if last_token_head:
         x = x[:, -1:] if last_index is None else \

@@ -10,18 +10,32 @@ softmax instead — the decode hot loop is bandwidth-bound, so not spilling
 scores is the win.
 
 Layout notes:
-- grid (B, H); each program handles one (batch, head) pair.
-- the cache keeps its storage layout (B, KV, max_len, hd) — heads-major so
-  the per-head block is (None, None, max_len, hd), whose last two dims are
-  (sublane, lane)-shaped as the TPU lowering requires (a seq-major cache
-  would squeeze the second-to-last dim: rejected on hardware). The GQA head
-  group mapping happens in the BlockSpec index_map (h // group), so there is
-  no repeated-KV materialization at all (the training kernel pays a
-  ``jnp.repeat``; decode can't afford it).
+- the cache is ``(L, B, KV, hd, max_len)``: POSITIONS ON THE LANES. HBM
+  tiles the last two dims (8 x 128 words): ``max_len`` is a multiple of
+  128 here, so no lane is padding, whatever ``hd`` is. With ``hd`` last, a
+  head of 64 fills half of every tile — the kernel's operand is then twice
+  the cache's bytes, and the compiler keeps the cache compact by storing it
+  the other way round and re-laying every slab out around each call (what
+  PERF.md F10 measured: more time moving K/V than attending to it).
+- both kernels take the WHOLE cache and a scalar-prefetched layer index,
+  and block it through the index map: the layer loop carries one buffer
+  and nothing slices a layer's slab out of it or writes one back.
+- ``decode_attention``: grid (B, H); a program's K/V block is one
+  (slot, kv-head)'s ``(hd, max_len)``. The GQA head group mapping happens
+  in the index map (h // group), so there is no repeated-KV
+  materialization at all (the training kernel pays a ``jnp.repeat``;
+  decode can't afford it). ``s = q @ k`` is a plain (8, hd) @ (hd, block);
+  ``p . v`` contracts the lane dims of both (the MXU's NT form).
 - the single query row is broadcast to the 8-sublane tile (q_sub trick) so
-  the s = q @ k.T matmul is MXU/VPU shaped.
+  the matmuls are MXU/VPU shaped.
 - the live length is a scalar-prefetch operand (SMEM), letting the kernel
   bound its streaming loop at ceil(length / block) instead of max_len.
+- ``cache_append``: grid (B, kv-blocks); writes the step's new K/V at
+  position ``length - 1`` of every slot as a read-modify-write of the one
+  128-lane tile that holds it, with the cache aliased to the output. An
+  XLA-level update of one position lets the compiler pick a layout FOR THE
+  UPDATE and convert the whole cache to it; inside an aliased kernel
+  nothing can.
 """
 
 from __future__ import annotations
@@ -37,25 +51,31 @@ from jax.sharding import PartitionSpec as P
 
 BIG_NEG = -2.0 ** 30
 SUBLANES = 8
+LANES = 128
+# cache_append's tile is (kv-block, hd, 128): the most KV heads a program
+# takes, so in, out and their double buffers stay far inside scoped VMEM
+_APPEND_TILE_BYTES = 512 * 1024
 
 
 def _decode_kernel(*refs, block: int, scale: float, alibi: bool):
     if alibi:
-        len_ref, slopes_ref, q_ref, k_ref, v_ref, o_ref = refs
+        len_ref, _, slopes_ref, q_ref, k_ref, v_ref, o_ref = refs
     else:
-        len_ref, q_ref, k_ref, v_ref, o_ref = refs
+        len_ref, _, q_ref, k_ref, v_ref, o_ref = refs
         slopes_ref = None
     b = pl.program_id(0)
     h = pl.program_id(1)
-    L = len_ref[b]
+    # an idle slot's length keeps counting past the cache: never past the
+    # block (the append clamps the same way)
+    L = jnp.minimum(len_ref[b], k_ref.shape[1])
     q = q_ref[...].astype(jnp.float32) * scale          # (SUBLANES, hd)
-    S = k_ref.shape[0]
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[pl.ds(j * block, block), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block, block), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (SUB, blk)
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        k = k_ref[:, at].astype(jnp.float32)             # (hd, blk)
+        v = v_ref[:, at].astype(jnp.float32)
+        s = jnp.dot(q, k, preferred_element_type=jnp.float32)  # (SUB, blk)
         col = j * block + jax.lax.broadcasted_iota(
             jnp.int32, (SUBLANES, block), 1)
         if slopes_ref is not None:
@@ -70,7 +90,9 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool):
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        acc = acc * corr + jax.lax.dot_general(          # p (SUB, blk) . vT
+            p, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m_new, l, acc
 
     nb = (L + block - 1) // block                        # only live blocks
@@ -81,10 +103,22 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool):
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
-                     block: int = 128, interpret: Optional[bool] = None):
-    """q: (B, 1, H, hd) current-token queries; ck/cv: (B, KV, max_len, hd)
-    cache; ``length`` scalar or (B,) live lengths (slots < length attended).
+def _shard_axes(ck, H):
+    from ..platform.mesh import attention_shard_axes
+
+    axes = attention_shard_axes(ck.shape[1], H, ck.shape[2])
+    if axes is None:
+        return None
+    mesh, b_ax, h_ax = axes
+    return mesh, b_ax, h_ax, P(None, b_ax, h_ax, None, None)
+
+
+def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
+                     block: int = LANES, interpret: Optional[bool] = None):
+    """q: (B, 1, H, hd) current-token queries; ck/cv: the cache
+    ``(L, B, KV, hd, max_len)`` with ``layer`` (traced i32) the layer to
+    attend over, or one layer's ``(B, KV, hd, max_len)``; ``length`` scalar
+    or (B,) live lengths (positions < length attended).
     ``alibi_slopes``: optional (H,) per-head slopes — the ALiBi distance
     bias is reconstructed in-kernel from the live length (Bloom decode
     stays on the streaming kernel instead of the dense fallback).
@@ -94,7 +128,10 @@ def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
 
     B, T, H, hd = q.shape
     assert T == 1, "decode kernel is single-token; use flash_attention for prefill"
-    KV, S = ck.shape[1], ck.shape[2]
+    if ck.ndim == 4:            # a layer's slab: a cache of that one layer
+        ck, cv, layer = ck[None], cv[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    KV, S = ck.shape[2], ck.shape[4]
     blk = min(block, S)
     if S % blk != 0:
         raise ValueError(f"cache length {S} not divisible by block {blk}")
@@ -104,45 +141,41 @@ def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
     scale = 1.0 / math.sqrt(hd)
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
     alibi = alibi_slopes is not None
-    from ..platform.mesh import attention_shard_axes
+    slopes = (jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()
 
-    axes = attention_shard_axes(B, H, KV)
+    axes = _shard_axes(ck, H)
     if axes is not None:
         # GSPMD cannot partition a Mosaic kernel: run it per shard, slots
         # over the example-parallel axes and heads over model/seq (inside
         # the body the axes are manual, so the recursion lands below)
-        mesh, b_ax, h_ax = axes
-        cache = P(b_ax, h_ax, None, None)
+        mesh, b_ax, h_ax, cache = axes
 
-        def per_shard(q, ck, cv, n, *slopes):
-            return decode_attention(q, ck, cv, n, block=block,
+        def per_shard(q, ck, cv, n, layer, *slopes):
+            return decode_attention(q, ck, cv, n, layer=layer[0], block=block,
                                     interpret=interpret,
                                     alibi_slopes=slopes[0] if slopes else None)
 
         return jax.shard_map(
             per_shard, mesh=mesh,
-            in_specs=(P(b_ax, None, h_ax, None), cache, cache, P(b_ax))
+            in_specs=(P(b_ax, None, h_ax, None), cache, cache, P(b_ax), P())
             + ((P(h_ax),) if alibi else ()),
             out_specs=P(b_ax, None, h_ax, None), check_vma=False)(
-                q, ck, cv, lengths,
-                *((jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()))
+                q, ck, cv, lengths, layer, *slopes)
 
     # (B, 1, H, hd) → (B, H, SUBLANES, hd): sublane-replicated single query
     qs = jnp.broadcast_to(q.swapaxes(1, 2), (B, H, SUBLANES, hd))
 
-    n_prefetch = 2 if alibi else 1
-    pre_args = ((lengths, jnp.asarray(alibi_slopes, jnp.float32))
-                if alibi else (lengths,))
+    def kv_block(b, h, n, layer, *_):
+        return (layer[0], b, h // group, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_prefetch,
+        num_scalar_prefetch=2 + len(slopes),
         grid=(B, H),
         in_specs=[
             pl.BlockSpec((None, None, SUBLANES, hd),
                          lambda b, h, *pre: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, S, hd),
-                         lambda b, h, *pre: (b, h // group, 0, 0)),
-            pl.BlockSpec((None, None, S, hd),
-                         lambda b, h, *pre: (b, h // group, 0, 0)),
+            pl.BlockSpec((None, None, None, hd, S), kv_block),
+            pl.BlockSpec((None, None, None, hd, S), kv_block),
         ],
         out_specs=pl.BlockSpec((None, None, SUBLANES, hd),
                                lambda b, h, *pre: (b, h, 0, 0)),
@@ -153,5 +186,86 @@ def decode_attention(q, ck, cv, length, *, alibi_slopes=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, hd), q.dtype),
         interpret=interpret,
-    )(*pre_args, qs, ck, cv)
+    )(lengths, layer, *slopes, qs, ck, cv)
     return out[:, :, :1, :].swapaxes(1, 2)               # (B, 1, H, hd)
+
+
+def _append_kernel(pos_ref, _, kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    r = pos_ref[b] % LANES                  # the position's lane in its tile
+    col = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
+    # the new values lie slots-on-lanes: slot b's column turns onto lane r
+    turn = (r - b % LANES) % LANES
+    for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
+                                      (vn_ref, v_ref, vo_ref)):
+        new = pltpu.roll(new_ref[...].astype(jnp.float32), turn, 2)
+        out_ref[...] = jnp.where(col == r, new.astype(out_ref.dtype),
+                                 old_ref[...])
+
+
+def cache_append(ck, cv, k, v, length, *, layer,
+                 interpret: Optional[bool] = None):
+    """Write this step's K/V into layer ``layer`` (traced i32) of the cache
+    ``(L, B, KV, hd, max_len)``, in place: ``k``/``v`` (B, 1, KV, hd) go to
+    position ``length - 1`` of every slot (``length`` scalar or (B,), the
+    lengths AFTER the append; clamped into the cache as
+    ``dynamic_update_slice`` clamps). Returns the cache with the outputs
+    aliased to the inputs, every other position bit-untouched."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    _, B, KV, hd, S = ck.shape
+    if S % LANES != 0:
+        raise ValueError(f"cache length {S} not a multiple of {LANES}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
+
+    axes = _shard_axes(ck, KV)
+    if axes is not None:
+        mesh, b_ax, h_ax, cache = axes
+        new = P(b_ax, None, h_ax, None)
+
+        def per_shard(ck, cv, k, v, n, layer):
+            return cache_append(ck, cv, k, v, n, layer=layer[0],
+                                interpret=interpret)
+
+        return jax.shard_map(
+            per_shard, mesh=mesh,
+            in_specs=(cache, cache, new, new, P(b_ax), P()),
+            out_specs=(cache, cache), check_vma=False)(
+                ck, cv, k, v, lengths, layer)
+
+    # (B, 1, KV, hd) → (KV, hd, slots): hd on the sublanes as in the cache,
+    # the slots on the lanes (a few KiB; the kernel turns its slot's column
+    # onto the position's lane)
+    pos = jnp.clip(lengths - 1, 0, S - 1)
+    pad = (-B) % LANES
+    kn, vn = (jnp.pad(x[:, 0].transpose(1, 2, 0).astype(ck.dtype),
+                      ((0, 0), (0, 0), (0, pad))) for x in (k, v))
+    kvb = max(d for d in range(1, KV + 1) if KV % d == 0 and (
+        d == 1 or d * hd * LANES * ck.dtype.itemsize <= _APPEND_TILE_BYTES))
+
+    def new_block(b, g, pos, layer):
+        return (g, 0, b // LANES)
+
+    def tile(b, g, pos, layer):
+        return (layer[0], b, g, 0, pos[b] // LANES)
+
+    new_spec = pl.BlockSpec((kvb, hd, LANES), new_block)
+    tile_spec = pl.BlockSpec((None, None, kvb, hd, LANES), tile)
+    return pl.pallas_call(
+        _append_kernel,
+        name="cache_append",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, KV // kvb),
+            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
+            out_specs=[tile_spec, tile_spec]),
+        out_shape=[jax.ShapeDtypeStruct(ck.shape, ck.dtype),
+                   jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret,
+    )(pos, layer, kn, vn, ck, cv)

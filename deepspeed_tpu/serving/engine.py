@@ -4,7 +4,7 @@ Reference analog: DeepSpeed-MII / FastGen's serving loop (continuous
 batching + Dynamic SplitFuse scheduling) re-expressed for XLA's
 static-shape world. The engine owns three device assets:
 
-- a slot state (``slots.py``): ONE persistent (L, slots, KV, max_len, hd)
+- a slot state (``slots.py``): ONE persistent (L, slots, KV, hd, max_len)
   KV cache plus per-slot length/tok/rng/done vectors, advanced by ONE
   compiled decode-step program regardless of which requests occupy it;
 - a prefill lane: per-request chunked prefill through shape-bucketed
@@ -431,8 +431,10 @@ class ServingEngine:
         self._last_step_s = 0.0
         self._last_stall_iter: Optional[int] = None
         self._iterations = 0
-        # readable process-wide at 0 too (see _count_retraces)
+        # readable process-wide at 0 too (see _count_retraces; the second
+        # counts in forward_with_cache, where a step program is traced)
         get_registry().counter("Serve/retraces")
+        get_registry().counter("Serve/decode_fallback_builds")
         with self.engine.mesh:
             if self._paged:
                 self._state = self._prog("init_slots", lambda: jax.jit(
@@ -1852,13 +1854,19 @@ class ServingEngine:
                 yield (f"final_{size}", self._programs[key],
                        (params, cache_aval, ids, i32, i32, i32, rng_aval))
 
-    def compiled_texts(self) -> dict:
-        """Optimized HLO text per built program (AOT-compiled for the live
-        shapes, nothing executes): where a caller checks which kernels
-        (``tpu_custom_call``) and collectives the compiler put in."""
+    def compiled_programs(self) -> dict:
+        """Each built program AOT-compiled for the live shapes (nothing
+        executes): ``as_text()`` says which kernels (``tpu_custom_call``)
+        and collectives the compiler put in, ``memory_analysis()`` what
+        the program holds beside its arguments."""
         with self.engine.mesh:
-            return {name: jitted.lower(*args).compile().as_text()
+            return {name: jitted.lower(*args).compile()
                     for name, jitted, args in self._built_programs()}
+
+    def compiled_texts(self) -> dict:
+        """Optimized HLO text per built program."""
+        return {name: c.as_text()
+                for name, c in self.compiled_programs().items()}
 
     def capacity_census(self) -> dict:
         """Per-program cost census over the engine's bounded program set:

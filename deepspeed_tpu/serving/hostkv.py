@@ -90,7 +90,7 @@ def restore_into_cache(cache, tiles, start, count):
     from .pages import _page_merge, _page_split
 
     R, ps = tiles["k"].shape[1], tiles["k"].shape[3]
-    max_len = cache.k.shape[3]
+    max_len = cache.k.shape[4]
     n = max_len // ps
     if "k_scale" in tiles:
         tk = dequantize_kv(tiles["k"], tiles["k_scale"], cache.k.dtype)

@@ -91,19 +91,20 @@ def init_paged_slots(cfg, slots: int, max_len: int, page_size: int,
 
 
 def _page_split(buf, n: int, ps: int):
-    """A batch-1 contiguous cache buffer (L, 1, KV, n*ps, hd) viewed as
-    per-page tiles (L, n, KV, ps, hd) — the relayout-free bridge between
-    the prefill lane and the pool (both orderings are position-major)."""
-    L, _, KV, _, hd = buf.shape
-    return buf[:, 0].reshape(L, KV, n, ps, hd).transpose(0, 2, 1, 3, 4)
+    """A batch-1 contiguous cache buffer (L, 1, KV, hd, n*ps) as per-page
+    tiles (L, n, KV, ps, hd) — the bridge between the prefill lane
+    (positions on the lanes, ``KVCache``) and the pool (``hd`` last: a
+    page is fewer positions than a lane tile)."""
+    L, _, KV, hd, _ = buf.shape
+    return buf[:, 0].reshape(L, KV, hd, n, ps).transpose(0, 3, 1, 4, 2)
 
 
 def _page_merge(tiles, like):
     """Inverse of :func:`_page_split`: per-page tiles back into the
     batch-1 contiguous layout of ``like``."""
-    L, _, KV, max_len, hd = like.shape
-    return tiles.transpose(0, 2, 1, 3, 4).reshape(
-        L, 1, KV, max_len, hd)
+    L, _, KV, hd, max_len = like.shape
+    return tiles.transpose(0, 2, 4, 1, 3).reshape(
+        L, 1, KV, hd, max_len)
 
 
 def insert_paged(state: GenCarry, slot, pf: GenCarry, page_row,

@@ -3,13 +3,16 @@
 Reference analog: DeepSpeed-MII / FastGen's blocked-KV "ragged batching"
 state. TPU-native translation: instead of a paged block table (dynamic
 indirection is hostile to XLA's static shapes), the serving state is ONE
-``(L, slots, KV, max_len, hd)`` cache — the same layout ``init_cache``
-allocates, via the shared :func:`~..inference.decode.cache_layout` — plus
-per-slot ``length`` / ``tok`` / ``rng`` / ``done`` vectors. A finished
-slot is immediately reusable: insertion overwrites the slot's FULL cache
-extent with the freshly prefilled request's cache (one donated
-``dynamic_update_slice``), so stale KV from the previous occupant can
-never leak into a successor's attention, and the decode step stays one
+``(L, slots, KV, hd, max_len)`` cache — the same layout ``init_cache``
+allocates, via the shared :func:`~..inference.decode.cache_layout`:
+positions on the lanes, so the buffer is compact in HBM at any head size
+and the decode step's two kernels append to it and read it where it lies
+(``ops/decode_attention.py``) — plus per-slot ``length`` / ``tok`` /
+``rng`` / ``done`` vectors. A finished slot is immediately reusable:
+insertion overwrites the slot's FULL cache extent with the freshly
+prefilled request's cache (one donated ``dynamic_update_slice`` of a
+slot's whole contiguous extent), so stale KV from the previous occupant
+can never leak into a successor's attention, and the decode step stays one
 static-shape program no matter which requests come and go.
 """
 
@@ -43,8 +46,8 @@ def insert_request(state: GenCarry, slot, pf: GenCarry) -> GenCarry:
 
     ``slot`` is a traced i32 scalar, so ONE compiled program inserts into
     any slot. The caller jits this with the state donated: the slot
-    cache updates in place — no second copy of the (L, slots, KV, max_len,
-    hd) buffers ever exists. The update spans the slot's full ``max_len``
+    cache updates in place — no second copy of the (L, slots, KV, hd,
+    max_len) buffers ever exists. The update spans the slot's full ``max_len``
     extent (the prefill cache is allocated at the slot's capacity), which
     is what guarantees a retired request's stale KV is fully overwritten
     before the new occupant's first decode step."""

@@ -12,12 +12,18 @@ library, so a second file (or a child process) would skip in silence.
 """
 
 import os
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.inference.decode import (GenCarry, KVCache, cache_layout,
+                                            decode_step)
+from deepspeed_tpu.inference.sampling import sample_logits
+from deepspeed_tpu.models import build_model, gpt2, llama2
 from deepspeed_tpu.ops.decode_attention import decode_attention
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.ops.woq_matmul import woq_matmul, woq_matmul_t
@@ -79,7 +85,7 @@ def test_flash_attention(one_chip, width, grad):
 def test_decode_attention(one_chip, width):
     _, _, H, KV, hd, _, _ = width
     B = 8
-    cache = ((B, KV, SEQ, hd), jnp.bfloat16)
+    cache = ((B, KV, hd, SEQ), jnp.bfloat16)
     _compile(lambda q, ck, cv, n: decode_attention(q, ck, cv, n,
                                                    interpret=False),
              one_chip, ((B, 1, H, hd), jnp.bfloat16), cache, cache,
@@ -130,3 +136,67 @@ def test_woq_matmul_t(one_chip, width, bits):
              one_chip, ((8, d), jnp.bfloat16),
              ((V // 2 if bits == 4 else V, d), jnp.int8),
              ((V // gs, d), jnp.float32))
+
+
+def _slot_step(one_chip, cfg, slots):
+    """The serving engine's donated slot step (``_step_impl``), compiled
+    for the described chip at full width; (compiled, cache shape)."""
+    model = build_model(cfg)
+    shape, dtype = cache_layout(cfg, slots, SEQ)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, cfg.dtype if a.dtype == jnp.float32 else a.dtype,
+            sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    kv = jax.ShapeDtypeStruct(shape, dtype)
+    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    carry = on_chip(GenCarry(
+        tok=i32((slots,)), cache=KVCache(k=kv, v=kv, length=i32((slots,))),
+        rng=jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+        done=jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    sampler = partial(sample_logits, greedy=False, temperature=0.8,
+                      top_k=40, top_p=1.0)
+    step = jax.jit(lambda p, c: decode_step(
+        model, p, c, sampler=sampler, flash_decode=True, logit_guard=True),
+        donate_argnums=(1,))
+    return step.lower(params, carry).compile(), shape
+
+
+@pytest.mark.parametrize("name,slots", [
+    pytest.param("gpt2-774m", 32, id="gpt2-774m-32slots"),
+    pytest.param("gpt2-774m", 48, id="gpt2-774m-48slots"),
+    pytest.param("llama2-7b", 8, id="llama2-7b-4layers"),
+])
+def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
+                                            slots):
+    """The built program, pinned: the cache enters donated, only the two
+    kernels touch it, and it leaves aliased to the output. On the layout
+    with ``hd`` last the step's temporaries were a second cache (6.25 GiB
+    at 32 slots, and 48 did not fit the chip): a whole-cache ``copy``
+    around the layer loop and a slice / transpose / write-back of every
+    layer's slab (PERF.md F10)."""
+    # the gates ask the backend whether Mosaic compiles this; the program
+    # is built for the described chip, so the answer is the chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = (gpt2("774m", max_seq=SEQ, dtype=jnp.bfloat16)
+           if name == "gpt2-774m" else
+           llama2("7b", n_layer=4, max_seq=SEQ, dtype=jnp.bfloat16))
+    compiled, (L, B, KV, hd, S) = _slot_step(one_chip, cfg, slots)
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * L * B * KV * hd * S * 2
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for kernel in ("decode_attention", "cache_append"):
+        assert any(kernel in ln for ln in calls), f"{kernel} absent"
+    # a result shaped like the cache, a layer's slab or a slot's (either
+    # way round) from anything that moves data
+    slab = re.compile(
+        rf"= \w+\[(?:{L},|1,)?{B},{KV},(?:{hd},{S}|{S},{hd})\]\S* "
+        r"(copy|scatter|dynamic-slice|dynamic-update-slice|transpose|"
+        r"fusion)\(")
+    moved = [ln.strip()[:160] for ln in text.splitlines() if slab.search(ln)]
+    assert not moved, moved

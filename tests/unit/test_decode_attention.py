@@ -3,6 +3,8 @@
 Reference analog: the ``softmax_context`` inference-kernel tests under
 ``tests/unit/ops/transformer/inference/``."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +17,8 @@ from deepspeed_tpu.ops.decode_attention import decode_attention
 def _setup(B=2, S=128, H=4, KV=2, hd=32, length=77, seed=0):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-    ck = jnp.asarray(rng.standard_normal((B, KV, S, hd)), jnp.float32)
-    cv = jnp.asarray(rng.standard_normal((B, KV, S, hd)), jnp.float32)
+    ck = jnp.asarray(rng.standard_normal((B, KV, hd, S)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((B, KV, hd, S)), jnp.float32)
     return q, ck, cv, jnp.int32(length)
 
 
@@ -86,8 +88,8 @@ def test_alibi_slopes_in_kernel_match_dense():
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
     slopes = alibi_slopes(H)
     for KV in (H, 2):
-        ck = jnp.asarray(rng.standard_normal((B, KV, S, hd)), jnp.float32)
-        cv = jnp.asarray(rng.standard_normal((B, KV, S, hd)), jnp.float32)
+        ck = jnp.asarray(rng.standard_normal((B, KV, hd, S)), jnp.float32)
+        cv = jnp.asarray(rng.standard_normal((B, KV, hd, S)), jnp.float32)
         for length in (jnp.int32(17), jnp.int32(64),
                        jnp.asarray([13, 49], jnp.int32)):
             got = decode_attention(q, ck, cv, length, alibi_slopes=slopes,
@@ -124,3 +126,176 @@ def test_bloom_generation_flash_vs_dense_decode():
     np.testing.assert_array_equal(
         np.asarray(flash.generate(ids, 6, greedy=True)),
         np.asarray(dense.generate(ids, 6, greedy=True)))
+
+
+# ------------------------------------------------- the carried-cache step
+# One layer loop for every contiguous cache (inference/decode.py): T == 1
+# under the gate appends and attends with the two kernels on the cache the
+# loop carries; the dense path is the oracle, solo generate() the contract.
+S = 256                                       # two lane tiles per slot
+EDGES = [1, 127, 128, 129, S]                 # live lengths AFTER the append
+FAMILIES = {
+    "mha-hd64": lambda: _tiny(n_head=2, d_model=128),
+    "gqa-hd64": lambda: _tiny(n_head=4, n_kv_head=2, d_model=256),
+    "mha-hd128": lambda: _tiny(n_head=2, d_model=256),
+    "alibi-hd64": lambda: _tiny(n_head=2, d_model=128, pos_embedding="alibi"),
+    "rope-gqa-hd64": lambda: _tiny(n_head=4, n_kv_head=2, d_model=256,
+                                   pos_embedding="rope"),
+}
+
+
+def _tiny(**kw):
+    from deepspeed_tpu.models import tiny_test
+
+    return tiny_test(n_layer=2, vocab_size=256, max_seq=S, d_ff=128,
+                     dtype=jnp.float32, **kw)
+
+
+_BUILT = {}
+
+
+def _family(name):
+    if name not in _BUILT:
+        from deepspeed_tpu.models import build_model
+
+        cfg = FAMILIES[name]()
+        model = build_model(cfg)
+        _BUILT[name] = (cfg, model, model.init(jax.random.PRNGKey(0)))
+    return _BUILT[name]
+
+
+def _slot_cache(cfg, lens, seed=0, stale=0.0):
+    """A slot cache whose rows hold ``lens`` live positions of noise and
+    ``stale`` behind them (what a retired occupant leaves)."""
+    from deepspeed_tpu.inference.decode import KVCache, cache_layout
+
+    lens = np.asarray(lens, np.int32)
+    shape, _ = cache_layout(cfg, len(lens), S)
+    rng = np.random.default_rng(seed)
+    live = np.arange(S) < lens.reshape(-1, 1, 1, 1)           # (B,1,1,S)
+    k, v = (np.where(live, rng.standard_normal(shape), sign * stale)
+            for sign in (1, -1))
+    return KVCache(k=jnp.asarray(k, jnp.float32),
+                   v=jnp.asarray(v, jnp.float32), length=jnp.asarray(lens))
+
+
+@pytest.mark.parametrize("lengths", ["ragged", "scalar-127", "scalar-128"])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_step_kernels_match_dense_path(family, lengths):
+    """The T == 1 forward on the kernels against the same forward on the
+    dense path: same logits; the append lands at ``length - 1`` of every
+    slot and nowhere else — an idle slot (length 0) writes its own row, a
+    neighbour's bits never move; K/V a previous occupant left past the live
+    length is never read (1e9 there would swamp any sum it entered)."""
+    from deepspeed_tpu.inference.decode import forward_with_cache
+
+    cfg, model, params = _family(family)
+    if lengths == "ragged":
+        length = before = np.asarray([n - 1 for n in EDGES] + [0])  # + idle
+    else:                                       # generate()'s: all rows at one
+        length = np.int32(int(lengths.split("-")[1]) - 1)
+        before = np.full((2,), length)
+    B = len(before)
+    cache = _slot_cache(cfg, before, stale=1e9)._replace(
+        length=jnp.asarray(length))
+    tok = jnp.asarray(np.random.default_rng(1).integers(0, 256, (B, 1)),
+                      jnp.int32)
+    fwd = jax.jit(partial(forward_with_cache, model),
+                  static_argnames=("flash_decode",))
+    want, dense = fwd(params, tok, cache, flash_decode=False)
+    got, fused = fwd(params, tok, cache, flash_decode=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(np.asarray(fused.length),
+                                  np.asarray(dense.length))
+    written = np.arange(S) == before.reshape(-1, 1, 1, 1)
+    for new, ref, old in ((fused.k, dense.k, cache.k),
+                          (fused.v, dense.v, cache.v)):
+        new, ref, old = (np.asarray(a) for a in (new, ref, old))
+        np.testing.assert_array_equal(np.where(written, 0, new),
+                                      np.where(written, 0, old))
+        # layer 0's new K/V do not pass through attention: bit-equal
+        np.testing.assert_array_equal(new[0], ref[0])
+        np.testing.assert_allclose(new, ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lens", [EDGES + [0], 129, 0],
+                         ids=["ragged", "scalar", "clamped"])
+def test_cache_append_is_bit_equal_to_set(lens, dtype):
+    """``cache_append`` against ``.at[].set``, bit for bit, at tile edges,
+    for a layer in the middle of the cache; a length of 0 clamps to
+    position 0 as ``dynamic_update_slice`` clamps."""
+    from deepspeed_tpu.ops.decode_attention import cache_append
+
+    L, B, KV, hd = 3, 6, 2, 16
+    rng = np.random.default_rng(0)
+    ck, cv = (jnp.asarray(rng.standard_normal((L, B, KV, hd, S)), dtype)
+              for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((B, 1, KV, hd)), dtype)
+            for _ in range(2))
+    n = jnp.asarray(lens, jnp.int32)
+    got_k, got_v = jax.jit(partial(cache_append, layer=1, interpret=True))(
+        ck, cv, k, v, n)
+    pos = np.clip(np.broadcast_to(np.asarray(lens), (B,)) - 1, 0, S - 1)
+    for got, old, new in ((got_k, ck, k), (got_v, cv, v)):
+        want = old.at[1, np.arange(B), :, :, pos].set(new[:, 0])
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+@pytest.mark.parametrize("family,tp", [(f, 1) for f in FAMILIES]
+                         + [("gqa-hd64", 2)])
+def test_served_on_the_kernels_equals_solo_generate(family, tp):
+    """Four requests through three slots on the kernel path, bit-identical
+    to solo ``generate()`` (scalar length, the same kernels): a slot idle
+    while its neighbours decode, lengths that walk over the 127/128/129
+    tile edge, and a slot re-used after ``insert_request`` while a
+    neighbour is mid-answer. Greedy tokens equal the dense engine's too.
+    Under a ``model`` axis both kernels run per shard of the KV heads."""
+    import deepspeed_tpu as ds
+
+    cfg, model, params = _family(family)
+    conf = {"dtype": "float32", "eos_token_id": 7, "tensor_parallel": tp}
+    eng = ds.init_inference(model, params, {**conf, "flash_decode": True})
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(8, 256, (P,)).astype(np.int32)
+               for P in (125, 9, 60, 126)]
+    new, seeds = [6, 3, 4, 5], [1, 2, 3, 4]
+    serve_cfg = {"slots": 3, "max_len": S, "prefill_chunk": 64,
+                 "greedy": True}
+    got = ds.ServingEngine(eng, serve_cfg).serve_batch(prompts, new, seeds)
+    dense = ds.ServingEngine(
+        ds.init_inference(model, params, {**conf, "flash_decode": False}),
+        serve_cfg).serve_batch(prompts, new, seeds)
+    for p, n, seed, g, d in zip(prompts, new, seeds, got, dense):
+        want = np.asarray(eng.generate(
+            jnp.asarray(p[None]), n, greedy=True, request_seeds=[seed],
+            cache_len=S))[0]
+        np.testing.assert_array_equal(g, want[:len(g)])
+        np.testing.assert_array_equal(g, d)
+
+
+def test_step_consumes_its_cache():
+    """The step donates its carry: the cache handed in is gone afterwards
+    and the one handed back lives where it lived."""
+    from deepspeed_tpu.inference.decode import GenCarry, decode_step
+    from deepspeed_tpu.inference.sampling import sample_logits
+
+    cfg, model, params = _family("mha-hd64")
+    cache = _slot_cache(cfg, [5, 0, 128])
+    carry = GenCarry(tok=jnp.zeros((3,), jnp.int32), cache=cache,
+                     rng=jnp.zeros((3, 2), jnp.uint32),
+                     done=jnp.zeros((3,), bool))
+    step = jax.jit(partial(
+        decode_step, model, flash_decode=True, logit_guard=True,
+        sampler=partial(sample_logits, greedy=True, temperature=1.0,
+                        top_k=0, top_p=1.0)), donate_argnums=(1,))
+    where = (cache.k.unsafe_buffer_pointer(), cache.v.unsafe_buffer_pointer())
+    out, ok = step(params, carry)
+    assert cache.k.is_deleted() and cache.v.is_deleted()
+    assert (out.cache.k.unsafe_buffer_pointer(),
+            out.cache.v.unsafe_buffer_pointer()) == where
+    assert bool(np.all(np.asarray(ok)))
+    np.testing.assert_array_equal(np.asarray(out.cache.length), [6, 1, 129])

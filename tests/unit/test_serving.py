@@ -196,8 +196,8 @@ def test_chunked_prefill_matches_whole(setup):
         # compare the LIVE extent [0, P): a right-padded bucket leaves pad
         # KV at positions >= P, which the attention mask ignores and the
         # first decode steps overwrite (the ragged-parity test proves it)
-        np.testing.assert_array_equal(np.asarray(cache.k[:, :, :, :P]),
-                                      np.asarray(whole.cache.k[:, :, :, :P]),
+        np.testing.assert_array_equal(np.asarray(cache.k[..., :P]),
+                                      np.asarray(whole.cache.k[..., :P]),
                                       err_msg=f"chunked cache drift, P={P}")
         assert int(cache.length) == P == int(whole.cache.length)
         assert int(tok[0]) == int(whole.tok[0]), f"first token drift, P={P}"
@@ -311,7 +311,7 @@ def test_cache_layout_single_source(setup):
     shared cache_layout helper."""
     cfg, model, params, eng = setup
     shape, dtype = cache_layout(cfg, 5, 32)
-    assert shape == (cfg.n_layer, 5, cfg.kv_heads, 32, cfg.head_dim)
+    assert shape == (cfg.n_layer, 5, cfg.kv_heads, cfg.head_dim, 32)
     one = init_cache(cfg, 5, 32)
     state = init_slots(cfg, 5, 32)
     assert one.k.shape == state.cache.k.shape == shape

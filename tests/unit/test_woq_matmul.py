@@ -336,8 +336,8 @@ def test_f16_decode_routes_dense_on_tpu(monkeypatch):
 
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((2, 1, 4, 32)), jnp.float16)
-    ck = jnp.asarray(rng.standard_normal((2, 2, 128, 32)), jnp.float16)
-    cv = jnp.asarray(rng.standard_normal((2, 2, 128, 32)), jnp.float16)
+    ck = jnp.asarray(rng.standard_normal((2, 2, 32, 128)), jnp.float16)
+    cv = jnp.asarray(rng.standard_normal((2, 2, 32, 128)), jnp.float16)
     out = _cache_attend(q, ck, cv, jnp.int32(77), flash_decode=True)
     assert out.shape == (2, 1, 4, 32)
     # bf16 inputs still go to the kernel (gate is f16-specific)
