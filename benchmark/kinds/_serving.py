@@ -11,16 +11,27 @@ server's main loop does: hand over what is due, one ``step()``, read what it
 emitted. Every request is timed from when it was *due*, which only this file
 knows; tokens are stamped when ``step()`` hands them back, which is when a
 streaming client could have them.
+
+``serve_tokens_per_s`` is all the window's output tokens over all the
+window's time, and ``itl_p95_ms`` the tail of all its gaps. The chip machine
+stands still for 0.13-0.19 s (now and then for seconds) inside a read-back,
+with no CPU time, a few times a window; that is in both, as it is in what a
+user of the machine gets. Beside them, per layer and in every run's notes:
+the time spent in such iterations (``host.stall_ms``; ``reduce.stalls`` says
+which they are) and the rate without what they took beyond a median
+iteration (``serve.tokens_per_s_less_stalls``), so that a rate that moved
+can be told from a window that held a stall.
 """
 
 from __future__ import annotations
 
+import statistics
 import time
 
 import numpy as np
 
 from ..harness import Cell, Outcome, TraceTail, settle_host, span
-from ..reduce import percentile
+from ..reduce import STALL_OVER, percentile, stalls, window_rate
 from ..traffic import Planned, plan_requests, rng_for
 
 # two candidates whose decision values differ by less than 2^-6 of the row's
@@ -219,18 +230,69 @@ class Book:
         return out
 
 
+class WindowLog:
+    """Every iteration of the window: when it began, how long it took
+    until what it emitted had been booked, and how many output tokens that
+    was. What the stalls and the rate without them are worked out from,
+    once the window has closed."""
+
+    def __init__(self):
+        self.began: list = []
+        self.durations: list = []
+        self.counts: list = []
+        self.occupied: list = []     # slots running after the step
+
+    def iteration(self, t_in: float, now: float, tokens: int,
+                  occupied: int) -> None:
+        self.began.append(t_in)
+        self.durations.append(now - t_in)
+        self.counts.append(tokens)
+        self.occupied.append(occupied)
+
+    def facts(self, t0: float, t1: float) -> dict:
+        """For ``reduce.window_rate`` and ``reduce.stall_time``."""
+        return {"t0": t0, "t1": t1, "counts": self.counts,
+                "durations": self.durations}
+
+    def note(self, w: dict) -> str:
+        """One line on the window ``w`` (what ``facts`` gave)."""
+        stall_s, _, n = stalls(w["durations"])
+        slow = sorted(zip(w["durations"], self.began), reverse=True)[:5]
+        facts = {"window": w}
+        return (
+            f"{len(self.began)} iterations, median "
+            f"{1e3 * statistics.median(w['durations']):.3f} ms, "
+            f"{statistics.fmean(self.occupied):.2f} slots running after a "
+            f"step on average; tokens/s of the whole window "
+            f"{window_rate(facts):.3f}; {1e3 * stall_s:.1f} ms in {n} "
+            f"iterations over {STALL_OVER:g}x the median, without their "
+            f"excess {window_rate(facts, less_stalls=True):.3f} tokens/s; "
+            "slowest iterations (ms, at s): "
+            + ", ".join(f"{1e3 * d:.0f} at {at - w['t0']:.1f}"
+                        for d, at in slow))
+
+
 def serve(cell: Cell, open_loop: bool) -> Outcome:
     import deepspeed_tpu as ds
 
     mix = cell.mix
     notes: list = []
+    phases = [("start", cell.t_process)]
+
+    def phase(name: str) -> None:
+        phases.append((name, time.perf_counter()))
+
     cfg, params, eng = build(cell)
+    phase("import, weights, engine")
     correct = check_logits(cell, cfg, params, eng, notes)
+    phase("reference check")
     srv = ds.ServingEngine(eng, dict(mix["engine"]),
                            clock=time.perf_counter)
     slots = int(mix["engine"]["slots"])
     correct &= check_served(cell, cfg, eng, srv, notes)
+    phase("served against solo")
     warm_buckets(cell, cfg, srv)
+    phase("warm-ups")
     planned = plan_requests(mix, cfg.vocab_size, cell.seed, cell.seconds)
     book = Book()
     sched = srv.sched
@@ -250,11 +312,10 @@ def serve(cell: Cell, open_loop: bool) -> Outcome:
             done = srv.step()
         with span("bookkeeping"):
             now = time.perf_counter()
-            longest.append((now - t_in, t_in - t0))
-            if len(longest) > 64:
-                longest.sort(reverse=True)
-                del longest[5:]
+            before = book.tokens
             book.emitted(list(sched.running.values()) + done, now)
+            log.iteration(t_in, now, book.tokens - before,
+                          len(sched.running))
             for r in done:
                 srv.results.pop(r.rid, None)
             if tail.on:
@@ -263,7 +324,7 @@ def serve(cell: Cell, open_loop: bool) -> Outcome:
 
     tail = TraceTail(cell)
     live: list = []
-    longest: list = []         # (seconds, at) of the slowest iterations
+    log = WindowLog()
     t0 = time.perf_counter()
     if not open_loop:
         # the whole backlog is there before the window opens, and the slots
@@ -278,10 +339,13 @@ def serve(cell: Cell, open_loop: bool) -> Outcome:
                      f" of {slots} slots are occupied")
     compiles0 = srv.compiles
     mark = cell.watch.mark()
-    longest.clear()
     settle_host()
     t0 = time.perf_counter()
     setup_s = t0 - cell.t_process
+    phases.append(("plan, ramp, settle", t0))
+    notes.append("set-up by phase (s): " + ", ".join(
+        f"{name} {b - a:.2f}" for (_, a), (name, b) in
+        zip(phases, phases[1:])))
     if not open_loop:
         # requests in flight when the window opens count from here on
         for row in book.rows.values():
@@ -355,20 +419,22 @@ def serve(cell: Cell, open_loop: bool) -> Outcome:
         notes.append(f"{failed} of {attempted} requests failed or had no "
                      f"first token {drain_s:g} s after the window")
     gaps = book.gaps
+    window = log.facts(t0, t_end)
     # first-token times are per-layer metrics (request_stat over the records)
     e2e = {"serve_tokens_per_s": book.tokens / (t_end - t0),
            "itl_p95_ms": 1e3 * percentile(gaps, 95) if gaps else None}
     samples = {"itl_p95_ms": len(gaps)}
-    longest.sort(reverse=True)
-    notes.append("slowest iterations (ms, at s): " + ", ".join(
-        f"{1e3 * d:.0f} at {at:.1f}" for d, at in longest[:5] if at >= 0))
+    if log.began:
+        notes.append(log.note(window))
     notes.append(
         f"{attempted} requests, {failed} failed, {book.tokens} output "
         f"tokens in {t_end - t0:.3f} s ({srv._iterations} iterations in "
-        f"all); itl samples {len(gaps)}; in the window {built}")
+        f"all); {len(gaps)} gaps between tokens, p95 "
+        f"{e2e['itl_p95_ms'] or 0.0:.4f} ms; in the window {built}")
     return Outcome(
         correct=bool(correct), attempted=attempted, failed=int(failed),
         end_to_end=e2e, setup_s=setup_s, samples=samples,
         facts={"requests": records, "decode_live_tokens": live,
+               "window": window, "token_gaps": gaps,
                "slots": slots, "seq_len": int(mix["engine"]["max_len"])},
         notes=notes)

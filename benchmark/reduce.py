@@ -22,7 +22,9 @@ Pallas kernel is a ``custom-call`` whose instruction name is the kernel's
 one event per asynchronous pair, from its ``-start`` to its ``-done``
 (copies, and across chips the collectives). Host threads are lines of the
 plane ``/host:CPU``; ``jax.profiler.TraceAnnotation`` spans are events of the
-``python3`` line there, on the same clock as the device planes.
+``python3`` line there, on the same clock as the device planes: the
+benchmark's own (``bench.<name>``) and, since PR 25, the program's
+(``ds.<name>``, ``observability/spans.py``), nested inside them.
 """
 
 from __future__ import annotations
@@ -40,8 +42,11 @@ ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 INSTRUCTION = re.compile(r"^%(\S+) = (\(?[a-z0-9]+\[[^\]]*\])?")
 HOST_PLANE = "/host:CPU"
-# what the host spans of the benchmark's own files start with
+# what the host spans of the benchmark's own files start with, and what the
+# program's own start with; both are kept, so that an idle gap is named by the
+# innermost of them (``ds.srv.admit`` inside ``bench.engine_step``)
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "ds."
 TRACED_WINDOW = SPAN_PREFIX + "traced_window"
 CONTROL_FLOW = re.compile(r"^(while|conditional|call)(\.\d+)?$")
 COLLECTIVE = re.compile(
@@ -106,7 +111,8 @@ class Trace:
     ``ops[device]``, ``async_ops[device]``, ``modules[device]``: lists of
     (name, t0, t1), sorted by t0; an op's name is its instruction's name
     (``fusion.379``) and ``shapes[name]`` its first result's shape.
-    ``spans``: the benchmark's host annotations, (name, t0, t1).
+    ``spans``: the benchmark's and the program's host annotations,
+    (name, t0, t1).
     ``window``: (t0, t1) of the traced window."""
 
     def __init__(self, ops: dict, modules: dict, spans: list,
@@ -213,7 +219,7 @@ def load_trace(trace_dir: str) -> Optional[Trace]:
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
                         a = e.start_ns * 1e-9
                         spans.append((e.name, a, a + e.duration_ns * 1e-9))
     if not ops:
@@ -283,7 +289,8 @@ def base_name(name: str) -> str:
 def breakdown(trace: Trace, top: int = 10) -> dict:
     """The device operations that took most time (self time, summed over the
     window and averaged over devices) and the longest idle gaps of the first
-    device, each named by the benchmark span the host was in meanwhile."""
+    device, each named by the innermost span, the benchmark's or the
+    program's, that the host was in meanwhile."""
     t0, t1 = trace.window
     per: dict = {}
     for d in trace.devices:
@@ -330,7 +337,7 @@ def innermost_segments(spans: list) -> list:
 
 def idle_by_host_span(idle: list, spans: list) -> dict:
     """Seconds of the device's idle intervals by what the host was doing:
-    each part of a gap goes to the innermost benchmark span open then."""
+    each part of a gap goes to the innermost span open then."""
     segs = innermost_segments([s for s in spans if s[0] != TRACED_WINDOW])
     out: dict = {}
     j = 0
@@ -349,6 +356,26 @@ def idle_by_host_span(idle: list, spans: list) -> dict:
             out["host:outside-any-span"] = out.get(
                 "host:outside-any-span", 0.0) + (b - a - covered)
     return out
+
+
+# ------------------------------------------------------- a window's stalls
+# an iteration longer than this many of the run's median iterations is a
+# stall: the longest sound ones (a chunk and a final program before the
+# step) are 1.2x, the chip machine's shortest stalls 2.1x. The one place the
+# threshold is written: the reducers and the kinds' notes all come here
+STALL_OVER = 2.0
+
+
+def stalls(durations: list) -> tuple:
+    """Of the iterations longer than ``STALL_OVER`` times the run's median
+    iteration: (the seconds spent in them, the seconds by which they
+    outlasted a median iteration, how many). Time the loop stood still,
+    whoever's fault."""
+    if not durations:
+        return 0.0, 0.0, 0
+    median = statistics.median(durations)
+    long = [d for d in durations if d > STALL_OVER * median]
+    return float(sum(long)), float(sum(long)) - median * len(long), len(long)
 
 
 # -------------------------------------------------------------- reducers
@@ -511,6 +538,36 @@ def model_flops_utilisation(facts, *, rate: str):
         facts["chips"] * facts["peaks"]["bf16_flops_per_s"])
 
 
+def window_rate(facts, *, less_stalls: bool = False):
+    """Output tokens per second of the measured window from the kind's own
+    record of it: all its tokens over all its time (what the end-to-end
+    ``serve_tokens_per_s`` is), or, with ``less_stalls``, over its time less
+    what its stalls (``stalls``) took beyond a median iteration each."""
+    win = facts.get("window")
+    if not win or win["t1"] <= win["t0"]:
+        return None
+    seconds = win["t1"] - win["t0"]
+    if less_stalls:
+        seconds -= stalls(win["durations"])[1]
+    return sum(win["counts"]) / seconds
+
+
+def stall_time(facts, *, scale: float = 1e3):
+    """Time spent in the window's stalls (``stalls``), in ms; 0 where there
+    was none."""
+    win = facts.get("window")
+    if not win or not win["durations"]:
+        return None
+    return stalls(win["durations"])[0] * scale
+
+
+def token_gap_stat(facts, *, statistic: str = "p95", scale: float = 1e3):
+    """A statistic over the window's gaps between successive output tokens
+    of one request, as the serving kinds booked them, in ms."""
+    v = _stat(facts.get("token_gaps") or [], statistic)
+    return None if v is None else v * scale
+
+
 def run_reducer(name: str, facts: dict, args: dict):
     """A generic reducer of this file, else ``benchmark/reducers/<name>.py``."""
     fn = globals().get(name) if name in GENERIC else None
@@ -520,4 +577,5 @@ def run_reducer(name: str, facts: dict, args: dict):
 
 
 GENERIC = ("program_time", "kernel_roofline", "gap_after", "idle_share",
-           "collective_exposed", "request_stat", "model_flops_utilisation")
+           "collective_exposed", "request_stat", "model_flops_utilisation",
+           "window_rate", "stall_time", "token_gap_stat")

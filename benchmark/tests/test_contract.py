@@ -95,8 +95,19 @@ def test_metrics(spec):
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
+        # PERF.md section 2: a rate or a tail is held at 1% to 2%, in half
+        # percents, and one that cannot be held there is a per-layer metric;
+        # only the one metric a cell has beside setup_s may take the wider
+        # bound its sets force
+        if m["name"] != "setup_s":
+            assert round(m["bound"] * 200) == pytest.approx(m["bound"] * 200)
+            only = any(
+                [e["name"] for e in e2e if e["name"] != "setup_s"
+                 and c in reporting(e, spec)] == [m["name"]] for c in cells)
+            assert m["bound"] <= (0.025 if only else 0.02), m["name"]
     setup = [m for m in e2e if m["name"] == "setup_s"]
     assert len(setup) == 1 and "workloads" not in setup[0]
+    assert setup[0]["bound"] == 0.1
     for m in per:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}

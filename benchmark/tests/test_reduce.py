@@ -161,3 +161,80 @@ def test_recorded_decode_step_start():
     assert R.program_time({"trace": whole},
                           program=r"^jit__step_impl\(") == \
         pytest.approx(1e3 * (mod[0][2] - mod[0][1]))
+
+
+def test_idle_gaps_are_named_by_the_program_s_spans_inside_the_benchmark_s():
+    """``load_trace`` keeps ``ds.``-prefixed annotations beside ``bench.``:
+    a gap goes to the innermost, so the admission inside ``engine_step``
+    gets its own name and what it leaves stays the step's."""
+    ops = {DEV: [("fusion.1", 0.0, 1.0), ("fusion.2", 4.0, 5.0)]}
+    spans = [("bench.traced_window", 0.0, 5.0), ("bench.engine_step", 0.5, 4.5),
+             ("ds.srv.step", 0.6, 4.4), ("ds.srv.admit", 1.2, 2.0),
+             ("ds.srv.decode_readback", 3.0, 4.4)]
+    b = R.breakdown(R.Trace(ops, {DEV: []}, spans))
+    idle = dict(b["idle_gaps"])
+    assert idle["ds.srv.admit"] == pytest.approx(0.8)
+    assert idle["ds.srv.decode_readback"] == pytest.approx(1.0)
+    assert idle["ds.srv.step"] == pytest.approx(1.2)      # 1-1.2 and 2-3
+    assert "bench.engine_step" not in idle               # all of it is inside
+    # no metric reads the spans: the same trace without the program's
+    without = R.Trace(ops, {DEV: []}, [s for s in spans
+                                       if s[0].startswith("bench.")])
+    for tr in (R.Trace(ops, {DEV: []}, spans), without):
+        assert R.idle_share({"trace": tr}) == pytest.approx(60.0)
+        assert tr.window == (0.0, 5.0)
+
+
+def uniform(n, dt, tokens, stall_at=None, stall=0.0, slower=1.0):
+    """``n`` iterations of ``dt`` seconds (times ``slower``) handing back
+    ``tokens`` each, one of them ``stall`` seconds longer: the window they
+    make, as the serving kinds record it."""
+    durations = [dt * slower + (stall if i == stall_at else 0.0)
+                 for i in range(n)]
+    return {"window": {"t0": 0.0, "t1": sum(durations),
+                       "counts": [tokens] * n, "durations": durations}}
+
+
+def test_stalls_by_hand():
+    assert R.STALL_OVER == 2.0
+    d = uniform(800, 0.05, 60, stall_at=300, stall=0.3)["window"]["durations"]
+    assert R.stalls(d) == (pytest.approx(0.35), pytest.approx(0.3), 1)
+    # 1.9x the median is a long iteration, not a stall; 2.1x is one
+    assert R.stalls([0.05] * 9 + [0.095]) == (0.0, 0.0, 0)
+    assert R.stalls([0.05] * 9 + [0.105])[2] == 1
+    # a run that is slow throughout has none: the median moves with it
+    assert R.stalls([0.2] * 50) == (0.0, 0.0, 0)
+    assert R.stalls([]) == (0.0, 0.0, 0)
+
+
+def test_a_stall_moves_the_window_s_rate_and_not_the_rate_less_stalls():
+    clean = uniform(800, 0.05, 60)
+    stalled = uniform(800, 0.05, 60, stall_at=300, stall=0.3)
+    # all the tokens over all the time: the end-to-end rate's arithmetic
+    assert R.window_rate(clean) == pytest.approx(1200.0)
+    assert R.window_rate(stalled) == pytest.approx(1200 * 40 / 40.3)
+    assert R.window_rate(stalled, less_stalls=True) == pytest.approx(1200.0)
+    assert R.stall_time(clean) == 0.0
+    assert R.stall_time(stalled) == pytest.approx(350.0)
+    # every token of the window is counted once in both
+    w = stalled["window"]
+    assert sum(w["counts"]) == 48000
+    assert R.window_rate(stalled) * (w["t1"] - w["t0"]) == pytest.approx(48000)
+    assert R.stall_time({}) is None and R.window_rate({}) is None
+
+
+def test_a_uniform_slowdown_moves_both_rates_by_as_much():
+    slow = uniform(800, 0.05, 60, slower=1.02)
+    assert R.window_rate(slow) == pytest.approx(1200 / 1.02)
+    assert R.window_rate(slow, less_stalls=True) == pytest.approx(1200 / 1.02)
+    assert R.stall_time(slow) == 0.0
+
+
+def test_token_gap_stat_is_the_end_to_end_tail_s_arithmetic():
+    gaps = [0.05] * 90 + [0.0] * 5 + [0.06] * 4 + [0.4]
+    assert R.token_gap_stat({"token_gaps": gaps}) == \
+        pytest.approx(1e3 * R.percentile(gaps, 95)) == pytest.approx(50.0)
+    assert R.token_gap_stat({"token_gaps": gaps}, statistic="p99") == \
+        pytest.approx(60.0)
+    assert R.token_gap_stat({"token_gaps": []}) is None
+    assert R.token_gap_stat({}) is None
