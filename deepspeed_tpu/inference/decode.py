@@ -11,6 +11,14 @@ buffer that only two kernels touch (``ops/decode_attention.py``:
 ``cache_append`` writes the new position in place, ``decode_attention``
 reads it by layer); T > 1 (prefill, speculative verify) appends with
 ``dynamic_update_slice`` and attends densely over the same layout.
+
+The attention kind decides the cache (:func:`cache_layout`). Latent
+attention (``cfg.attention == "mla"``, ``models/mla.py``) keeps ONE buffer
+``(L, B, rank + rope, max_len)`` of what its layers share between heads
+(:class:`LatentCache`), written by the latent projection and read two ways:
+T > 1 expands K and V from the live prefix's latents block by block and
+attends as published; the T == 1 step appends in place and attends absorbed
+(``ops/mla_attention.py``). No expanded K or V is ever stored.
 """
 
 from __future__ import annotations
@@ -50,6 +58,16 @@ class KVCache(NamedTuple):
                              # together) or (B,) per-slot (serving/slots.py)
 
 
+class LatentCache(NamedTuple):
+    """The cache of latent attention: per position the layer's normed
+    ``c`` (``kv_lora_rank`` values) and roped ``k_rope``
+    (``qk_rope_head_dim``), one for all heads — positions on the lanes as
+    in :class:`KVCache`, for the same reason."""
+
+    c: jnp.ndarray           # (L, B, rank + rope, max_len)
+    length: jnp.ndarray      # as KVCache.length
+
+
 class PagedKVCache(NamedTuple):
     """Page-pool KV state for the serving slot batch (serving/pages.py).
 
@@ -85,8 +103,8 @@ class PagedKVCache(NamedTuple):
 
 def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                  dtype=None, *, page_size: int = 0, pages: int = 0) -> tuple:
-    """(shape, dtype) of one K or V cache buffer — the single source of
-    truth shared by :func:`init_cache`, the serving slot allocator
+    """(shape, dtype) of one cache buffer (K or V; for latent attention
+    the one buffer of latents) — the single source of truth shared by :func:`init_cache`, the serving slot allocator
     (``serving/slots.py``), and the paged pool allocator
     (``serving/pages.py``), so a prefilled request's cache can be written
     into its slot (or scattered into its pages) with no relayout.
@@ -97,7 +115,18 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     ``(L, pages, KV, page_size, hd)`` — a page is ``page_size`` whole
     positions, far fewer than a lane tile, so it keeps ``hd`` last; the
     gather over a slot's page-table row (:func:`_paged_view`) and the
-    bridges in ``serving/pages.py`` turn pages into the contiguous view."""
+    bridges in ``serving/pages.py`` turn pages into the contiguous view.
+
+    Latent attention (``cfg.latent_dim > 0``): ``(L, batch, rank + rope,
+    max_len)``, contiguous only."""
+    # (duck-typed configs of other trunks have no attention kinds: K/V)
+    if getattr(cfg, "latent_dim", 0):
+        if page_size > 0:
+            raise NotImplementedError(
+                "the paged pool holds K and V pages; a latent cache is "
+                "contiguous only")
+        return ((cfg.n_layer, batch, cfg.latent_dim, max_len),
+                dtype or cfg.dtype)
     if page_size > 0:
         return ((cfg.n_layer, pages, cfg.kv_heads, page_size, cfg.head_dim),
                 dtype or cfg.dtype)
@@ -105,11 +134,30 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
             dtype or cfg.dtype)
 
 
+def cache_buffers(shape: tuple) -> int:
+    """How many buffers of ``shape`` (a contiguous :func:`cache_layout`) a
+    cache holds: K and V ``(L, B, KV, hd, max_len)``, or the one buffer of
+    latents ``(L, B, rank + rope, max_len)``."""
+    return 1 if len(shape) == 4 else 2
+
+
+def cache_bytes_per_token(cfg: TransformerConfig, dtype=None) -> int:
+    """Bytes one cached position costs over all layers, from
+    :func:`cache_layout`."""
+    shape, dt = cache_layout(cfg, 1, 1, dtype)
+    return cache_buffers(shape) * math.prod(shape) * jnp.dtype(dt).itemsize
+
+
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
-               dtype=None) -> KVCache:
+               dtype=None, length_shape: tuple = ()):
+    """An empty cache of the model's kind; ``length_shape`` () for rows that
+    advance together, (batch,) for serving slots."""
     shape, dtype = cache_layout(cfg, batch, max_len, dtype)
+    length = jnp.zeros(length_shape, jnp.int32)
+    if cache_buffers(shape) == 1:
+        return LatentCache(c=jnp.zeros(shape, dtype), length=length)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                   length=jnp.zeros((), jnp.int32))
+                   length=length)
 
 
 def _decode_kernel_ok(flash_decode: bool, T: int, max_len: int,
@@ -468,6 +516,103 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     return x, cache_k, cache_v
 
 
+@jax.named_scope("decode_layer")
+def _latent_layer_step(model, x, p, cache_c, length, positions, fused: bool,
+                       layer, banks=None, bank_layer=None):
+    """One latent-attention layer over x: (B, T, d) against the carried
+    latent cache ``(L, B, rank + rope, max_len)``, layer ``layer`` of it.
+    ``banks`` / ``bank_layer``: the segment's stacked expert weights and
+    this layer's index in them (``MoETransformerLM.experts``).
+    Returns (x_out, cache, (stats, routing)): the expert layer's counters
+    and chosen experts (B, T, k), or zeros for a dense FFN."""
+    from ..models import mla
+
+    cfg = model.cfg
+    B, T, _ = x.shape
+    y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+    q_nope, q_rope, new = mla.project(cfg, y, p, positions)
+    if fused:
+        from ..ops.mla_attention import latent_append, mla_decode_attention
+
+        cache_c = latent_append(cache_c, new[:, 0], length, layer=layer)
+        o_lat = mla_decode_attention(
+            mla.absorb_q(cfg, p, q_nope, q_rope), cache_c, length,
+            layer=layer, rank=cfg.kv_lora_rank, scale=mla.softmax_scale(cfg))
+        o = mla.absorb_o(cfg, p, o_lat)
+    elif T > 1 and getattr(length, "ndim", 0) == 0:
+        # prefill: the new latents into the carried cache, and the expanded
+        # read block by block out of it, by layer — no slab is sliced out
+        cache_c = lax.dynamic_update_slice(
+            cache_c, new.transpose(0, 2, 1)[None].astype(cache_c.dtype),
+            (layer, 0, 0, length - T))
+        o = mla.attend_expanded(cfg, p, q_nope, q_rope, cache_c, positions,
+                                length, layer=layer)
+    else:
+        # the K/V helper on the latent buffer seen as one head of
+        # rank + rope values: same update, same layout
+        slab, cache5 = _dense_append(cache_c[:, :, None], new[:, :, None],
+                                     layer, length)
+        cache_c, slab = cache5[:, :, 0], slab[:, 0]
+        if T == 1:
+            o = mla.absorb_o(cfg, p, mla.attend_absorbed(
+                cfg, mla.absorb_q(cfg, p, q_nope, q_rope), slab, length))
+        else:
+            o = mla.attend_expanded(cfg, p, q_nope, q_rope, slab, positions,
+                                    jnp.max(length))
+    x = x + matmul_any(o.reshape(B, T, cfg.n_head * cfg.v_dim), p["wo"],
+                       use_kernel=False)
+    y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
+    if "router" in p and cfg.moe_router == "sigmoid":
+        out, stats, idx = model.experts(y2, p, banks=banks, layer=bank_layer)
+    else:
+        out, stats = model._mlp_block(y2, p)[0], jnp.zeros((3,), jnp.float32)
+        idx = jnp.zeros((B, T, 0), jnp.int32)
+    return x + out, cache_c, (stats, idx)
+
+
+def _forward_latent(model, params, x, cache: LatentCache, new_len, positions,
+                    flash_decode: bool):
+    """The layer loop over a latent cache: every segment of the trunk scans
+    its own stacked weights, all of them carrying the one cache buffer.
+    Returns (x, cache, (stats (expert layers, 3), routing (expert layers,
+    B, T, k)) or None)."""
+    T = x.shape[1]
+    fused = _decode_kernel_ok(flash_decode, T, cache.c.shape[3], x.dtype,
+                              cache.c.dtype)
+    if T == 1 and not fused:
+        from ..observability.metrics import get_registry
+
+        get_registry().counter("Serve/decode_fallback_builds").inc()
+    c, first, stats = cache.c, 0, []
+    for (kind, n), seg in zip(model.cfg.segments,
+                              model.segment_params(params["layers"])):
+        # the expert banks stay out of the loop's xs: sliced per layer they
+        # would be copied (0.4 GB a matrix); the kernel indexes them by layer
+        names = getattr(model, "BANKS", ()) if kind == "moe" \
+            and model.cfg.moe_router == "sigmoid" else ()
+        banks = {k: seg[k] for k in names} or None
+        rest = {k: v for k, v in seg.items() if k not in names}
+
+        def scan_fn(carry, layer_in, banks=banks):
+            x, c = carry
+            lp, layer, local = layer_in
+            x, c, st = _latent_layer_step(model, x, lp, c, new_len,
+                                          positions, fused, layer,
+                                          banks=banks, bank_layer=local)
+            return (x, c), st
+
+        (x, c), st = lax.scan(
+            scan_fn, (x, c),
+            (rest, jnp.arange(first, first + n, dtype=jnp.int32),
+             jnp.arange(n, dtype=jnp.int32)))
+        first += n
+        if kind == "moe":
+            stats.append(st)
+    return (x, LatentCache(c=c, length=new_len),
+            tuple(jnp.concatenate(part) for part in zip(*stats))
+            if stats else None)
+
+
 def _embed_rows(table, ids, dtype):
     """Row gather from a dense or int8/int4-stored embedding table — a
     quantized table reads int8 bytes for exactly the batch's tokens."""
@@ -521,7 +666,8 @@ def _decode_head(model, params, x):
 
 def forward_with_cache(model, params, input_ids, cache: KVCache,
                        positions=None, flash_decode: bool = False,
-                       last_token_head: bool = False, last_index=None):
+                       last_token_head: bool = False, last_index=None,
+                       with_stats: bool = False, with_routing: bool = False):
     """Run T tokens through all layers, appending to the cache.
 
     input_ids: (B, T). Works for both prefill (T = prompt length, cache
@@ -539,6 +685,12 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
     of the whole prefill); ``last_index`` (traced i32 scalar) overrides
     which position that is — the serving engine's right-padded final
     prefill chunk puts the last real token at ``true_len - 1``, not T-1.
+    ``with_stats`` adds a third result: the expert layers' counters
+    ``(expert layers, 3)`` (``MoETransformerLM.experts``), or None where
+    the trunk has none to give (only the latent path collects them);
+    ``with_routing`` a further one, the experts chosen ``(expert layers, B,
+    T, k)``: what a comparison with a reference needs to follow the system's
+    choice at a near-tie.
     """
     cfg = model.cfg
     B, T = input_ids.shape
@@ -565,7 +717,11 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
         x = _norm(x, params["embed_ln_scale"], params.get("embed_ln_bias"),
                   cfg.norm, cfg.norm_eps)
 
-    if paged:
+    stats = None
+    if isinstance(cache, LatentCache):
+        x, new_cache, stats = _forward_latent(model, params, x, cache,
+                                              new_len, positions, flash_decode)
+    elif paged:
         def paged_scan(carry, layer_in):
             x = carry
             lp, ck, cv, ks, vs = layer_in
@@ -602,15 +758,22 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
             return _layer_step(model, x, lp, ck, cv, new_len, positions,
                                flash_decode=fused, layer=layer), None
 
-        (x, ck, cv), _ = lax.scan(
-            scan_fn, (x, cache.k, cache.v),
-            (params["layers"], jnp.arange(cache.k.shape[0], dtype=jnp.int32)))
+        carry, first = (x, cache.k, cache.v), 0
+        for (_, n), seg in zip(cfg.segments,
+                               model.segment_params(params["layers"])):
+            carry, _ = lax.scan(
+                scan_fn, carry,
+                (seg, jnp.arange(first, first + n, dtype=jnp.int32)))
+            first += n
+        x, ck, cv = carry
         new_cache = KVCache(k=ck, v=cv, length=new_len)
     if last_token_head:
         x = x[:, -1:] if last_index is None else \
             lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
     logits = _decode_head(model, params, x)
-    return logits, new_cache
+    counters, routing = stats if stats is not None else (None, None)
+    return (logits, new_cache) + ((counters,) if with_stats else ()) \
+        + ((routing,) if with_routing else ())
 
 
 class GenCarry(NamedTuple):
@@ -684,7 +847,8 @@ def prefill_tokens(model, params, input_ids, rng, *, max_new: int,
 
 def decode_step(model, params, carry: GenCarry, *, sampler,
                 eos_token_id=None, flash_decode: bool = False,
-                logit_guard: bool = False, poison_row=None):
+                logit_guard: bool = False, poison_row=None,
+                moe_stats: bool = False):
     """ONE decode iteration: forward the carry token, sample the next.
 
     The single definition shared by :func:`decode_tokens`' scan body and
@@ -702,13 +866,18 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
     that one row's logits with NaN before sampling — AFTER the forward, so
     the poison can never reach the KV cache or any other row. ``where``
     with a false mask returns the original logits bit-exactly, so a chaos
-    program running with poison_row=-1 matches the clean program."""
+    program running with poison_row=-1 matches the clean program.
+
+    ``moe_stats=True`` (with ``logit_guard``) returns ``(carry, ok, stats,
+    routing)``: the step's expert-layer counters, for the same read-back,
+    and the experts it chose (expert layers, B, 1, k)."""
     from .sampling import split_keys
 
     tok, cache, rng, done = carry
     with jax.named_scope("decode_step"):
-        lg, cache = forward_with_cache(model, params, tok[:, None], cache,
-                                       flash_decode=flash_decode)
+        lg, cache, stats, routing = forward_with_cache(
+            model, params, tok[:, None], cache, flash_decode=flash_decode,
+            with_stats=True, with_routing=True)
     if poison_row is not None:
         bad = jnp.arange(lg.shape[0], dtype=jnp.int32)[:, None, None] \
             == poison_row
@@ -720,7 +889,8 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
         done = done | (nxt == eos_token_id)
     out = GenCarry(nxt, cache, rng, done)
     if logit_guard:
-        return out, jnp.all(jnp.isfinite(lg), axis=(1, 2))
+        ok = jnp.all(jnp.isfinite(lg), axis=(1, 2))
+        return (out, ok, stats, routing) if moe_stats else (out, ok)
     return out
 
 

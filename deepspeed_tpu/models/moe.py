@@ -16,6 +16,11 @@ Design differences that make this TPU-idiomatic:
   are sharded over the ``expert`` mesh axis; constraining the dispatched
   activations ``(B, E, C, d)`` to the same axis makes GSPMD emit exactly
   the all-to-all the reference hand-codes (``sharded_moe.py:_AllToAll``).
+- **Two routers.** ``moe_router="gshard"`` is the capacity dispatch above.
+  ``"sigmoid"`` (DeepSeek-V3) has no capacity at any T: :meth:`experts`
+  sorts the routed rows by expert and multiplies each expert's rows by its
+  own weights (``ops/moe_matmul.py``), training forward, prefill and the
+  decode step alike, with a shared MLP on every token beside them.
 - **Gating in fp32**: router weights are exempted from the engine's bf16
   compute cast (``fp32_param_names``) so near-tie routing decisions don't
   flap across bf16 rounding, matching ``sharded_moe.py:top1gating``.
@@ -115,8 +120,19 @@ class MoETransformerLM(TransformerLM):
     # ------------------------------------------------------------- MoE MLP
     @jax.named_scope("moe_mlp")
     def _mlp_block(self, y, p):
-        """y: (B, S, d) post-norm activations. Groups = batch rows."""
+        """y: (B, S, d) post-norm activations. Groups = batch rows.
+
+        A segment's FFN kind is what its stacked weights hold: a layer
+        without a router is a dense layer of this trunk
+        (``moe_first_dense``)."""
         cfg = self.cfg
+        if "router" not in p:
+            return super()._mlp_block(y, p)
+        if cfg.moe_router == "sigmoid":
+            # no aux loss to give (noaux_tc): the layer's aux is its
+            # routing, the chosen experts (B, S, k) — see _fold_aux
+            out, _, idx = self.experts(y, p)
+            return out, idx
         B, S, d = y.shape
         E = cfg.num_experts
         # Eval uses the (larger) eval capacity factor so fewer tokens drop
@@ -181,26 +197,21 @@ class MoETransformerLM(TransformerLM):
     # -------------------------------------------------------- inference MoE
     @jax.named_scope("moe_mlp_infer")
     def _mlp_block_infer(self, y, p):
-        """Single-group MoE dispatch for the T=1 KV-cache decode step
-        (reference ``DeepSpeedMoEInference``,
+        """Single-group dispatch of the GShard router for the T=1 decode
+        step (reference ``DeepSpeedMoEInference``,
         ``ops/transformer/inference/moe_inference.py:159``).
 
-        The training dispatch groups tokens per batch row so each group's
-        capacity is a static function of S — but at decode T=1 that
-        degenerates to ``min_capacity`` slots per row on every expert
-        (min_capacity·E× the ideal compute). Decode instead flattens the
-        B·1 tokens into ONE routing group with capacity C = B: NO token is
-        ever dropped (a decode drop silently zeroes that token's FFN
-        contribution, with no training loss to compensate — a generation
-        quality bug, not a throughput tradeoff). Compute is E·B·d·f, E/k×
-        the routed ideal, but decode is HBM-bandwidth-bound on the expert
-        bank read, so the slack compute is hidden; the bench's MBU row
-        counts the full bank read for the same reason. Routing decisions
-        are per-token and independent of grouping, so the output equals
-        the training layer's exactly whenever the training path doesn't
-        drop either (the decode parity test pins this). Prefill (T>1)
-        keeps the training per-row dispatch — same memory profile as
-        training, no B× inflation of the dispatch one-hots."""
+        The training dispatch groups tokens per batch row, which at T=1
+        degenerates to ``min_capacity`` slots per row on every expert.
+        Decode instead flattens the B tokens into ONE routing group with
+        capacity C = B, so no token is dropped — and EVERY expert
+        multiplies C rows whether routed to it or not: E·B·d·f, E/k times
+        the routed rows, on the MXU each step (measured, not hidden: at 128
+        experts top-6 that is 21x). The sigmoid router does not come here:
+        :meth:`experts` multiplies routed rows only, at any T. Prefill
+        (T>1) keeps the training per-row dispatch."""
+        if "router" not in p or self.cfg.moe_router == "sigmoid":
+            return self._mlp_block(y, p)
         cfg = self.cfg
         B, T, d = y.shape
         E = cfg.num_experts
@@ -228,42 +239,169 @@ class MoETransformerLM(TransformerLM):
         res = jnp.einsum("tec,ecd->td", combine.astype(y.dtype), out)
         return res.reshape(B, T, d), aux.astype(jnp.float32)
 
+    # --------------------------------------------- routed rows, no capacity
+    @jax.named_scope("moe_route")
+    def route(self, yt, p):
+        """DeepSeek-V3 ``noaux_tc`` routing of (N, d) tokens, in float32 up
+        to the chosen weights: sigmoid scores; the top-k of score +
+        ``router_bias`` are chosen (one group); a chosen expert's weight is
+        its UNBIASED score, normalised over the chosen and scaled.
+        Returns (idx (N, k) i32, weights (N, k) f32)."""
+        cfg = self.cfg
+        score = jax.nn.sigmoid(jnp.dot(
+            yt.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(
+            score + p["router_bias"].astype(jnp.float32), cfg.moe_top_k)
+        w = jnp.take_along_axis(score, idx, axis=-1)
+        if cfg.moe_norm_topk:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w * cfg.moe_routed_scale
+
+    BANKS = ("w_gate", "w_in", "w_out")
+
+    def experts(self, y, p, banks=None, layer=None):
+        """The sigmoid-routed expert layer on (B, T, d): every token's k
+        rows sorted by expert, each expert's rows padded to whole blocks of
+        the dtype's sublane tile and multiplied by that expert alone
+        (``ops/moe_matmul.py``), the weighted rows gathered back per token,
+        the shared MLP added once. No capacity and no drop at any T: the
+        sorted layout has room for every row whichever experts they choose.
+        ``banks`` (with ``layer``, this layer's index in them): the
+        segment's stacked expert weights ``{name: (L, E, ·, ·)}`` in place
+        of ``p``'s own — a layer loop passes them so that no layer's bank is
+        sliced out (``ops/moe_matmul.py``).
+
+        Returns (out (B, T, d), stats, idx): ``stats`` f32 [most rows one
+        expert got, experts touched, rows multiplied (padding included)] —
+        the counters the serving spans carry (rows routed = B·T·k is
+        static); ``idx`` (B, T, k) i32 the experts chosen."""
+        from ..ops.moe_matmul import block_rows, experts_swiglu
+
+        cfg = self.cfg
+        B, T, d = y.shape
+        E, k = cfg.num_experts, cfg.moe_top_k
+        N, M = B * T, B * T * k
+        yt = y.reshape(N, d)
+        idx, w = self.route(yt, p)
+        with jax.named_scope("moe_experts"):
+            bm = block_rows(y.dtype)
+            e = idx.reshape(M)
+            order = jnp.argsort(e, stable=True)
+            e_sorted = e[order]
+            counts = jnp.bincount(e, length=E).astype(jnp.int32)
+            padded = (counts + bm - 1) // bm * bm
+            p_end = jnp.cumsum(padded)
+            first = jnp.cumsum(counts) - counts
+            # row of sorted pair i: its expert's padded start + its rank
+            dest = (p_end - padded)[e_sorted] \
+                + jnp.arange(M, dtype=jnp.int32) - first[e_sorted]
+            R = -(-(M + min(E, M) * (bm - 1)) // bm) * bm   # every case fits
+            row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
+                (order // k).astype(jnp.int32))
+            pair_row = jnp.zeros((M,), jnp.int32).at[order].set(dest)
+            used = p_end[-1] // bm
+            blocks = jnp.arange(R // bm, dtype=jnp.int32)
+            block_expert = jnp.minimum(jnp.searchsorted(
+                p_end, jnp.minimum(blocks, used - 1) * bm, side="right"),
+                E - 1)
+            bank = banks if banks is not None else p
+            out = experts_swiglu(yt[row_token], bank["w_gate"], bank["w_in"],
+                                 bank["w_out"], block_expert, used, bm=bm,
+                                 layer=layer)
+            routed = jnp.sum(out[pair_row].reshape(N, k, d).astype(jnp.float32)
+                             * w[..., None], axis=1).astype(y.dtype)
+        stats = jnp.stack([jnp.max(counts), jnp.sum(counts > 0),
+                           used * bm]).astype(jnp.float32)
+        if cfg.moe_shared_d_ff:
+            with jax.named_scope("moe_shared"):
+                u = jax.nn.silu(yt @ p["ws_gate"].astype(y.dtype)) \
+                    * (yt @ p["ws_in"].astype(y.dtype))
+                routed = routed + u @ p["ws_out"].astype(y.dtype)
+        return routed.reshape(B, T, d), stats, idx.reshape(B, T, k)
+
+    def _fold_aux(self, aux):
+        """Aux losses add up; the sigmoid router's aux is its routing
+        (expert layers, B, S, k), kept as the scan stacked it."""
+        return aux if jnp.issubdtype(aux.dtype, jnp.integer) \
+            else jnp.sum(aux)
+
+    def _join_aux(self, auxes: list):
+        """With the sigmoid router ``apply(..., return_aux=True)`` returns
+        the trunk's routing, the expert segments' (layers, B, S, k)
+        together in layer order (dense segments have none), from the same
+        program as the logits: what a comparison with a reference that
+        follows the system's choice at a near-tie needs. Else the sum of
+        the aux losses."""
+        routing = [a for a in auxes if jnp.issubdtype(a.dtype, jnp.integer)]
+        if routing:
+            return jnp.concatenate(routing)
+        return sum(auxes[1:], auxes[0])
+
     # ----------------------------------------------------------------- init
     def init(self, rng) -> dict:
         params = super().init(rng)
         cfg = self.cfg
-        d, f, L, E = cfg.d_model, cfg.ffn_dim, cfg.n_layer, cfg.num_experts
-        k = iter(jax.random.split(jax.random.fold_in(rng, 1), 8))
-        layers = params["layers"]  # base init skips the dense FFN for E > 1
+        d, f, E = cfg.d_model, cfg.expert_dim, cfg.num_experts
+        depth = cfg.n_layer
+        segs = self.segment_params(params["layers"])
 
         def dense(key, shape, scale):
             return jax.random.normal(key, shape, jnp.float32) * scale
 
-        layers["router"] = dense(next(k), (L, d, E), 0.02)
-        layers["w_in"] = dense(next(k), (L, E, d, f), 1.0 / math.sqrt(d))
-        layers["w_out"] = dense(next(k), (L, E, f, d), 1.0 / math.sqrt(2 * L * f))
-        if cfg.is_glu:
-            layers["w_gate"] = dense(next(k), (L, E, d, f), 1.0 / math.sqrt(d))
-        if cfg.use_bias:
-            layers["b_in"] = jnp.zeros((L, E, f), jnp.float32)
-            layers["b_out"] = jnp.zeros((L, E, d), jnp.float32)
+        for i, ((kind, L), layers) in enumerate(zip(cfg.segments, segs)):
+            if kind != "moe":
+                continue
+            # base init skips the dense FFN of an expert segment
+            k = iter(jax.random.split(jax.random.fold_in(rng, 1 + i), 8))
+            layers["router"] = dense(next(k), (L, d, E), 0.02)
+            layers["w_in"] = dense(next(k), (L, E, d, f), 1.0 / math.sqrt(d))
+            layers["w_out"] = dense(next(k), (L, E, f, d),
+                                    1.0 / math.sqrt(2 * depth * f))
+            if cfg.is_glu:
+                layers["w_gate"] = dense(next(k), (L, E, d, f),
+                                         1.0 / math.sqrt(d))
+            if cfg.use_bias:
+                layers["b_in"] = jnp.zeros((L, E, f), jnp.float32)
+                layers["b_out"] = jnp.zeros((L, E, d), jnp.float32)
+            if cfg.moe_router == "sigmoid":
+                # a trained model's selection bias is small and not zero;
+                # drawn so, that a path which drops it chooses differently
+                layers["router_bias"] = dense(next(k), (L, E), 0.02)
+            if cfg.moe_shared_d_ff:
+                fs = cfg.moe_shared_d_ff
+                layers["ws_in"] = dense(next(k), (L, d, fs), 1.0 / math.sqrt(d))
+                layers["ws_gate"] = dense(next(k), (L, d, fs),
+                                          1.0 / math.sqrt(d))
+                layers["ws_out"] = dense(next(k), (L, fs, d),
+                                         1.0 / math.sqrt(2 * depth * fs))
         return params
 
     # ---------------------------------------------------------------- specs
     def param_specs(self) -> dict:
         specs = super().param_specs()
-        layers = specs["layers"]  # base specs skip the dense FFN for E > 1
-        layers["router"] = P(None, None, None)
-        layers["w_in"] = P(None, "expert", None, "model")
-        layers["w_out"] = P(None, "expert", "model", None)
-        if self.cfg.is_glu:
-            layers["w_gate"] = P(None, "expert", None, "model")
-        if self.cfg.use_bias:
-            layers["b_in"] = P(None, "expert", "model")
-            layers["b_out"] = P(None, "expert", None)
+        cfg = self.cfg
+        segs = self.segment_params(specs["layers"])
+        for (kind, _), layers in zip(cfg.segments, segs):
+            if kind != "moe":
+                continue
+            layers["router"] = P(None, None, None)
+            layers["w_in"] = P(None, "expert", None, "model")
+            layers["w_out"] = P(None, "expert", "model", None)
+            if cfg.is_glu:
+                layers["w_gate"] = P(None, "expert", None, "model")
+            if cfg.use_bias:
+                layers["b_in"] = P(None, "expert", "model")
+                layers["b_out"] = P(None, "expert", None)
+            if cfg.moe_router == "sigmoid":
+                layers["router_bias"] = P(None, None)
+            if cfg.moe_shared_d_ff:
+                layers["ws_in"] = P(None, None, "model")
+                layers["ws_gate"] = P(None, None, "model")
+                layers["ws_out"] = P(None, "model", None)
         return specs
 
     def fp32_param_names(self) -> tuple[str, ...]:
         """Leaf names kept in fp32 by the engine's compute cast (router
         precision governs tie-breaking stability)."""
-        return ("router",)
+        return ("router", "router_bias")

@@ -55,6 +55,35 @@ def mixtral(size: str = "8x7b", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def deepseek_v3(size: str = "kanana-2-30b-a3b", **overrides) -> TransformerConfig:
+    """The DeepSeek-V3 block (``model_type: deepseek_v3``): latent
+    attention, a leading dense layer, then sigmoid-routed experts beside a
+    shared MLP. ``kanana-2-30b-a3b`` is kakaocorp/kanana-2-30b-a3b-
+    instruct-2601's ``config.json`` (``q_lora_rank`` null; ``n_group`` 1, so
+    the router chooses over one group)."""
+    table = {
+        "tiny": dict(n_layer=3, n_head=4, d_model=128, d_ff=256,
+                     vocab_size=509, max_seq=256, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     num_experts=8, moe_top_k=2, moe_d_ff=64,
+                     moe_shared_d_ff=64),
+        "kanana-2-30b-a3b": dict(
+            n_layer=48, n_head=32, d_model=2048, d_ff=6144,
+            vocab_size=128256, max_seq=32768, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            num_experts=128, moe_top_k=6, moe_d_ff=768,
+            moe_shared_d_ff=2 * 768, moe_routed_scale=2.448),
+    }
+    base = dict(attention="mla", pos_embedding="rope", norm="rmsnorm",
+                norm_eps=1e-6, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, moe_router="sigmoid",
+                moe_norm_topk=True, moe_first_dense=1, rope_theta=1e6,
+                fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def bert(size: str = "base", **overrides) -> TransformerConfig:
     """Encoder (bidirectional) trunk + MLM objective — the BERT family the
     reference's flagship pretraining baseline uses

@@ -96,10 +96,60 @@ class TransformerConfig:
     moe_min_capacity: int = 4
     moe_drop_tokens: bool = True          # False: capacity covers ALL tokens
     moe_aux_loss_weight: float = 0.01
+    # DeepSeek-V3 expert layers (models/moe.py ``experts``):
+    # "sigmoid" scores each expert with a sigmoid, chooses the top-k of
+    # score + a learned selection bias (``router_bias``; the weight is the
+    # unbiased score), never drops a token and has no aux loss.
+    moe_router: str = "gshard"            # "gshard" (softmax, capacity) | "sigmoid"
+    moe_d_ff: Optional[int] = None        # an expert's width (default ffn_dim)
+    moe_shared_d_ff: int = 0              # shared experts, as ONE MLP of this
+                                          # width on every token (0: none)
+    moe_norm_topk: bool = True            # chosen weights sum to 1 ...
+    moe_routed_scale: float = 1.0         # ... times this
+    moe_first_dense: int = 0              # leading layers with a dense FFN of
+                                          # width ffn_dim before the expert layers
+    # attention kind: "mha" (MHA/GQA/MQA over cached K and V) | "mla"
+    # (latent attention, models/mla.py: the cache holds kv_lora_rank +
+    # qk_rope_head_dim values a token). The kind decides cache_layout().
+    attention: str = "mha"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     @property
     def head_dim(self) -> int:
+        """A head's query/key width."""
+        if self.attention == "mla":
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_head
+
+    @property
+    def v_dim(self) -> int:
+        """A head's value width (MLA's differs from its query/key width)."""
+        return self.v_head_dim if self.attention == "mla" else self.head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values the latent cache holds per token per layer (0: K/V)."""
+        return (self.kv_lora_rank + self.qk_rope_head_dim
+                if self.attention == "mla" else 0)
+
+    @property
+    def expert_dim(self) -> int:
+        return self.moe_d_ff or self.ffn_dim
+
+    @property
+    def segments(self) -> tuple:
+        """The trunk as an ordered list of (FFN kind, layers): each segment
+        is one block kind scanned over its own stacked weights
+        (``params["layers"]``: the stacked tree of a one-segment trunk, a
+        tuple of them otherwise). The attention kind is the model's."""
+        if self.num_experts == 1:
+            return (("dense", self.n_layer),)
+        k = min(self.moe_first_dense, self.n_layer)
+        return tuple(s for s in (("dense", k), ("moe", self.n_layer - k))
+                     if s[1] > 0)
 
     @property
     def kv_heads(self) -> int:
@@ -126,28 +176,45 @@ class TransformerConfig:
         For MoE only the ``moe_top_k`` routed experts do work per token, so
         FLOPs use the *active* parameter count, not the total bank size."""
         n_params = self.param_count(non_embedding=True, active_only=True)
-        attn = 12 * self.n_layer * self.d_model * self.max_seq
+        # scores + values: 2 * S * H * (qk width + v width) forward, x3
+        attn = 6 * self.n_layer * self.n_head * (
+            self.head_dim + self.v_dim) * self.max_seq
         head = (0 if self.objective == "feature"
                 else 6 * self.d_model * self.vocab_size)
         return 6 * n_params + attn + head
 
-    def _ffn_params_per_layer(self, active_only: bool = False) -> int:
-        d, f, E = self.d_model, self.ffn_dim, self.num_experts
-        per_expert = d * f * (3 if self.is_glu else 2)
-        if E == 1:
-            return per_expert
+    def _ffn_params_per_layer(self, active_only: bool = False,
+                              kind: Optional[str] = None) -> int:
+        """Matmul parameters of one layer's FFN of ``kind`` ("dense" |
+        "moe"; default: the trunk's last segment's)."""
+        d, E = self.d_model, self.num_experts
+        mats = 3 if self.is_glu else 2
+        if (kind or self.segments[-1][0]) == "dense":
+            return d * self.ffn_dim * mats
         router = d * E
         mult = min(self.moe_top_k, E) if active_only else E
-        return router + mult * per_expert
+        return (router + mult * d * self.expert_dim * mats
+                + d * self.moe_shared_d_ff * mats)
+
+    def _attn_params_per_layer(self) -> int:
+        d, h = self.d_model, self.n_head
+        if self.attention == "mla":
+            r = self.kv_lora_rank
+            return (d * h * self.head_dim + d * self.latent_dim
+                    + r * h * (self.qk_nope_head_dim + self.v_dim)
+                    + h * self.v_dim * d)
+        kv, hd = self.kv_heads, self.head_dim
+        return d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
 
     def param_count(self, non_embedding: bool = False,
                     active_only: bool = False) -> int:
-        d, L = self.d_model, self.n_layer
-        h, kv, hd = self.n_head, self.kv_heads, self.head_dim
-        per_layer = d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
-        per_layer += self._ffn_params_per_layer(active_only=active_only)
+        """Matmul parameters (norms, biases and position tables left out)."""
+        d = self.d_model
         emb = self.vocab_size * d
-        total = L * per_layer + (emb if not non_embedding else 0)
+        total = sum(n * (self._attn_params_per_layer()
+                         + self._ffn_params_per_layer(active_only, kind))
+                    for kind, n in self.segments)
+        total += emb if not non_embedding else 0
         if (not self.tie_embeddings and not non_embedding
                 and self.objective != "feature"):
             total += emb
@@ -397,49 +464,43 @@ class TransformerLM:
                 "alibi needs an additive score bias; this attention_fn "
                 "accepts neither a bias nor alibi slopes (flash and ring "
                 "attention do; sparse/Ulysses still do not)")
+        if config.attention == "mla":
+            if (config.use_bias or config.pos_embedding != "rope"
+                    or not config.causal or config.post_ln
+                    or config.parallel_residual or attention_fn is not None
+                    or min(config.kv_lora_rank, config.qk_nope_head_dim,
+                           config.qk_rope_head_dim, config.v_head_dim) <= 0):
+                raise ValueError(
+                    "attention='mla' is the DeepSeek block: causal, rope on "
+                    "qk_rope_head_dim, no biases, pre-norm, its own blocked "
+                    "attention (no attention_fn), and kv_lora_rank / "
+                    "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
+        elif config.attention != "mha":
+            raise ValueError(f"unknown attention kind {config.attention!r}")
         self.attention_fn = attention_fn or partial(causal_attention,
                                                     causal=config.causal)
 
     # ----------------------------------------------------------------- init
     def init(self, rng) -> dict:
         cfg = self.cfg
-        d, f, L = cfg.d_model, cfg.ffn_dim, cfg.n_layer
-        h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+        d, L = cfg.d_model, cfg.n_layer
         k = iter(jax.random.split(rng, 16))
 
         def dense(key, shape, scale=None):
             scale = scale or (1.0 / math.sqrt(shape[-2] if len(shape) > 1 else shape[-1]))
             return (jax.random.normal(key, shape, jnp.float32) * scale)
 
-        dense_ffn = cfg.num_experts == 1  # MoE trunks build expert banks instead
-        two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
-        layers = {
-            "ln1_scale": jnp.ones((L, d), jnp.float32),
-            "wq": dense(next(k), (L, d, h * hd)),
-            "wk": dense(next(k), (L, d, kv * hd)),
-            "wv": dense(next(k), (L, d, kv * hd)),
-            "wo": dense(next(k), (L, h * hd, d), scale=1.0 / math.sqrt(2 * L * d)),
-        }
-        if two_ln:
-            layers["ln2_scale"] = jnp.ones((L, d), jnp.float32)
-        if dense_ffn:
-            layers["w_in"] = dense(next(k), (L, d, f))
-            layers["w_out"] = dense(next(k), (L, f, d), scale=1.0 / math.sqrt(2 * L * f))
-            if cfg.is_glu:
-                layers["w_gate"] = dense(next(k), (L, d, f))
-        if cfg.use_bias:
-            layers.update({
-                "ln1_bias": jnp.zeros((L, d), jnp.float32),
-                "bq": jnp.zeros((L, h * hd), jnp.float32),
-                "bk": jnp.zeros((L, kv * hd), jnp.float32),
-                "bv": jnp.zeros((L, kv * hd), jnp.float32),
-                "bo": jnp.zeros((L, d), jnp.float32),
-            })
-            if two_ln:
-                layers["ln2_bias"] = jnp.zeros((L, d), jnp.float32)
-            if dense_ffn:
-                layers["b_in"] = jnp.zeros((L, f), jnp.float32)
-                layers["b_out"] = jnp.zeros((L, d), jnp.float32)
+        segs = cfg.segments
+        if len(segs) == 1:
+            layers = self._init_segment(k, dense, segs[0][0], L, L)
+        else:
+            # block kinds: every segment draws from its own key, so a
+            # segment's weights do not depend on what stands beside it
+            layers = tuple(
+                self._init_segment(
+                    iter(jax.random.split(jax.random.fold_in(rng, 100 + i), 16)),
+                    dense, kind, n, L)
+                for i, (kind, n) in enumerate(segs))
         params = {
             "tok_embed": jax.random.normal(next(k), (cfg.vocab_size, d), jnp.float32) * 0.02,
             "layers": layers,
@@ -466,38 +527,74 @@ class TransformerLM:
             params["lm_head"] = dense(next(k), (d, cfg.vocab_size), scale=0.02)
         return params
 
+
+    def _init_segment(self, k, dense, kind: str, n: int, depth: int) -> dict:
+        """Stacked weights of ``n`` layers of one block kind (the model's
+        attention kind x FFN ``kind``); ``depth`` is the whole trunk's, for
+        the residual projections' scale. "moe" segments get their expert
+        banks from the MoE trunk (models/moe.py)."""
+        cfg = self.cfg
+        d, f, L = cfg.d_model, cfg.ffn_dim, n
+        h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+        dense_ffn = kind == "dense"
+        two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
+        layers = {"ln1_scale": jnp.ones((L, d), jnp.float32)}
+        if cfg.attention == "mla":
+            r = cfg.kv_lora_rank
+            layers.update({
+                "wq": dense(next(k), (L, d, h * hd)),
+                "wkv_a": dense(next(k), (L, d, cfg.latent_dim)),
+                "kv_norm_scale": jnp.ones((L, r), jnp.float32),
+                "wkv_b": dense(next(k), (
+                    L, r, h * (cfg.qk_nope_head_dim + cfg.v_dim))),
+                "wo": dense(next(k), (L, h * cfg.v_dim, d),
+                            scale=1.0 / math.sqrt(2 * depth * d)),
+            })
+        else:
+            layers.update({
+                "wq": dense(next(k), (L, d, h * hd)),
+                "wk": dense(next(k), (L, d, kv * hd)),
+                "wv": dense(next(k), (L, d, kv * hd)),
+                "wo": dense(next(k), (L, h * hd, d),
+                            scale=1.0 / math.sqrt(2 * depth * d)),
+            })
+        if two_ln:
+            layers["ln2_scale"] = jnp.ones((L, d), jnp.float32)
+        if dense_ffn:
+            layers["w_in"] = dense(next(k), (L, d, f))
+            layers["w_out"] = dense(next(k), (L, f, d),
+                                    scale=1.0 / math.sqrt(2 * depth * f))
+            if cfg.is_glu:
+                layers["w_gate"] = dense(next(k), (L, d, f))
+        if cfg.use_bias:
+            layers.update({
+                "ln1_bias": jnp.zeros((L, d), jnp.float32),
+                "bq": jnp.zeros((L, h * hd), jnp.float32),
+                "bk": jnp.zeros((L, kv * hd), jnp.float32),
+                "bv": jnp.zeros((L, kv * hd), jnp.float32),
+                "bo": jnp.zeros((L, d), jnp.float32),
+            })
+            if two_ln:
+                layers["ln2_bias"] = jnp.zeros((L, d), jnp.float32)
+            if dense_ffn:
+                layers["b_in"] = jnp.zeros((L, f), jnp.float32)
+                layers["b_out"] = jnp.zeros((L, d), jnp.float32)
+        return layers
+
+    @staticmethod
+    def segment_params(layers) -> tuple:
+        """``params["layers"]`` as one stacked tree per segment."""
+        return tuple(layers) if isinstance(layers, (tuple, list)) \
+            else (layers,)
+
     # ---------------------------------------------------------------- specs
     def param_specs(self) -> dict:
         """TP (Megatron-style) sharding over the ``model`` axis:
         qkv/w_in column-split, wo/w_out row-split, embeddings vocab-split."""
         cfg = self.cfg
-        dense_ffn = cfg.num_experts == 1
-        two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
-        layers = {
-            "ln1_scale": P(None, None),
-            "wq": P(None, None, "model"),
-            "wk": P(None, None, "model"),
-            "wv": P(None, None, "model"),
-            "wo": P(None, "model", None),
-        }
-        if two_ln:
-            layers["ln2_scale"] = P(None, None)
-        if dense_ffn:
-            layers["w_in"] = P(None, None, "model")
-            layers["w_out"] = P(None, "model", None)
-            if cfg.is_glu:
-                layers["w_gate"] = P(None, None, "model")
-        if cfg.use_bias:
-            layers.update({
-                "ln1_bias": P(None, None),
-                "bq": P(None, "model"), "bk": P(None, "model"), "bv": P(None, "model"),
-                "bo": P(None, None),
-            })
-            if two_ln:
-                layers["ln2_bias"] = P(None, None)
-            if dense_ffn:
-                layers["b_in"] = P(None, "model")
-                layers["b_out"] = P(None, None)
+        layers = tuple(self._segment_specs(kind) for kind, _ in cfg.segments)
+        if len(layers) == 1:
+            layers = layers[0]
         specs = {
             "tok_embed": P("model", None),
             "layers": layers,
@@ -523,12 +620,52 @@ class TransformerLM:
             specs["lm_head_bias"] = P("model")
         return specs
 
+    def _segment_specs(self, kind: str) -> dict:
+        cfg = self.cfg
+        dense_ffn = kind == "dense"
+        two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
+        layers = {"ln1_scale": P(None, None)}
+        if cfg.attention == "mla":
+            # heads column-split as wq/wo are; the latent projection and
+            # its norm are shared by all heads and stay replicated
+            layers.update({
+                "wq": P(None, None, "model"), "wkv_a": P(None, None, None),
+                "kv_norm_scale": P(None, None),
+                "wkv_b": P(None, None, "model"), "wo": P(None, "model", None),
+            })
+        else:
+            layers.update({
+                "wq": P(None, None, "model"),
+                "wk": P(None, None, "model"),
+                "wv": P(None, None, "model"),
+                "wo": P(None, "model", None),
+            })
+        if two_ln:
+            layers["ln2_scale"] = P(None, None)
+        if dense_ffn:
+            layers["w_in"] = P(None, None, "model")
+            layers["w_out"] = P(None, "model", None)
+            if cfg.is_glu:
+                layers["w_gate"] = P(None, None, "model")
+        if cfg.use_bias:
+            layers.update({
+                "ln1_bias": P(None, None),
+                "bq": P(None, "model"), "bk": P(None, "model"), "bv": P(None, "model"),
+                "bo": P(None, None),
+            })
+            if two_ln:
+                layers["ln2_bias"] = P(None, None)
+            if dense_ffn:
+                layers["b_in"] = P(None, "model")
+                layers["b_out"] = P(None, None)
+        return layers
+
     def stacked_fn(self):
         """Which param shapes are layer-stacked (leading scan dim)."""
-        L = self.cfg.n_layer
+        counts = {n for _, n in self.cfg.segments}
 
         def is_stacked(shape) -> bool:
-            return len(shape) >= 2 and shape[0] == L
+            return len(shape) >= 2 and shape[0] in counts
 
         return is_stacked
 
@@ -544,6 +681,19 @@ class TransformerLM:
         h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
         y = x if cfg.post_ln else _norm(x, p["ln1_scale"], p.get("ln1_bias"),
                                         cfg.norm, cfg.norm_eps)
+        if cfg.attention == "mla":
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "latent attention takes no padding mask yet")
+            from . import mla
+
+            q_nope, q_rope, new = mla.project(cfg, y, p, positions)
+            # the full forward is prefill into an empty cache: the latents
+            # lie as the cache holds them, positions on the lanes
+            o = mla.attend_expanded(cfg, p, q_nope, q_rope,
+                                    new.transpose(0, 2, 1), positions, S)
+            o = constrain(o, P(B_AXES, "seq", "model", None))
+            return o.reshape(B, S, h * cfg.v_dim) @ p["wo"].astype(x.dtype)
         q = self._maybe_bias(y @ p["wq"].astype(y.dtype), p, "bq").reshape(B, S, h, hd)
         kk = self._maybe_bias(y @ p["wk"].astype(y.dtype), p, "bk").reshape(B, S, kv, hd)
         vv = self._maybe_bias(y @ p["wv"].astype(y.dtype), p, "bv").reshape(B, S, kv, hd)
@@ -713,8 +863,19 @@ class TransformerLM:
             new_x, aux = body(carry, layer_params)
             return new_x, aux
 
-        x, aux_losses = lax.scan(scan_fn, x, layers)
-        return x, jnp.sum(aux_losses)
+        x, aux = lax.scan(scan_fn, x, layers)
+        return x, self._fold_aux(aux)
+
+    @staticmethod
+    def _fold_aux(aux):
+        """A segment's per-layer aux (stacked by the scan) as one value:
+        aux losses add up."""
+        return jnp.sum(aux)
+
+    @staticmethod
+    def _join_aux(auxes: list):
+        """The segments' folded aux as the trunk's."""
+        return sum(auxes[1:], auxes[0])
 
     def _head_norm(self, params, x):
         """Final layernorm only (the pipeline's vocab-sharded head applies
@@ -771,8 +932,12 @@ class TransformerLM:
     def _trunk(self, params, input_ids, attn_mask, remat_policy):
         """Embed + layer stack: (B, S) → ((B, S, D) pre-final-norm, aux)."""
         x, positions = self._embed(params, input_ids)
-        return self._scan_layers(x, params["layers"], positions, attn_mask,
-                                 remat_policy)
+        auxes = []
+        for seg in self.segment_params(params["layers"]):
+            x, a = self._scan_layers(x, seg, positions, attn_mask,
+                                     remat_policy)
+            auxes.append(a)
+        return x, self._join_aux(auxes)
 
     def apply(self, params, input_ids, *, attn_mask=None, remat_policy=None,
               return_aux: bool = False):
@@ -828,7 +993,7 @@ class TransformerLM:
             ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         else:
             ce = jnp.mean(nll)
-        if self.cfg.num_experts > 1:
+        if self.cfg.num_experts > 1 and self.cfg.moe_router == "gshard":
             ce = ce + self.cfg.moe_aux_loss_weight * aux
         return ce
 

@@ -79,7 +79,8 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
                    kv_quant_bits: int = 0) -> dict:
     """KV-cache byte breakdown for the slot engine's ONE persistent cache,
     from the same :func:`~..inference.decode.cache_layout` the allocator
-    uses (k + v buffers).
+    uses: K and V buffers, or the one buffer of a latent cache
+    (``per_token_bytes`` then follows the layout's rank + rope values).
 
     ``page_size > 0`` accounts the pooled page layout instead: the
     resident total is the pool (+ the fp32 scale planes when the pool is
@@ -88,7 +89,7 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
     the operator sizes the pool in (docs/OPERATIONS.md)."""
     import jax.numpy as jnp
 
-    from ..inference.decode import cache_layout
+    from ..inference.decode import cache_buffers, cache_layout
 
     if page_size > 0:
         shape, dt = cache_layout(model_cfg, slots, max_len, dtype,
@@ -114,7 +115,7 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
                 "kv_quant_bits": kv_quant_bits}
     shape, dt = cache_layout(model_cfg, slots, max_len, dtype)
     itemsize = jnp.dtype(dt).itemsize
-    total = 2 * int(math.prod(shape)) * itemsize
+    total = cache_buffers(shape) * int(math.prod(shape)) * itemsize
     per_slot = total // slots
     return {"total_bytes": total, "per_slot_bytes": per_slot,
             "per_token_bytes": per_slot // max_len,

@@ -251,13 +251,15 @@ def emit(ring: Optional[SpanRecorder], kind: str, t0: float,
     _record(ring, _capturing(), kind, t0, t1, fields)
 
 
-def _record(ring, live, kind, t0, t1, fields) -> None:
+def _record(ring, live, kind, t0, t1, fields) -> Optional[SpanEvent]:
+    ev = None
     if ring is not None:
         ev = ring.emit(kind, t0, t1, **fields)
         if live:
             _CAPTURE.append(ev)
     elif live:
-        _CAPTURE.emit(kind, t0, t1, **fields)
+        ev = _CAPTURE.emit(kind, t0, t1, **fields)
+    return ev
 
 
 def instant(ring: Optional[SpanRecorder], clock: Callable[[], float],
@@ -274,6 +276,7 @@ class _Off:
     """What :func:`span` hands out when nothing records."""
 
     __slots__ = ()
+    recording = False
 
     def __enter__(self):
         return self
@@ -284,12 +287,16 @@ class _Off:
     def note(self, **fields) -> None:
         pass
 
+    amend = note
+
 
 _OFF = _Off()
 
 
 class _Span:
-    __slots__ = ("ring", "clock", "kind", "fields", "t0", "_annotation")
+    __slots__ = ("ring", "clock", "kind", "fields", "t0", "_annotation",
+                 "event")
+    recording = True
 
     def __init__(self, ring, clock, kind, annotation, fields):
         self.ring, self.clock, self.kind = ring, clock, kind
@@ -307,13 +314,19 @@ class _Span:
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         # in the capture ring exactly when it is in the capture
-        _record(self.ring, self._annotation is not None, self.kind,
-                self.t0, t1, self.fields)
+        self.event = _record(self.ring, self._annotation is not None,
+                             self.kind, self.t0, t1, self.fields)
         return False
 
     def note(self, **fields) -> None:
         """What is known only inside the span (the rid ``submit`` drew)."""
         self.fields.update(fields)
+
+    def amend(self, **meta) -> None:
+        """Meta known only after the span closed: a count the device held
+        until a later read-back brought it (a chunk's expert rows)."""
+        if self.event is not None:
+            self.event.meta.update(meta)
 
 
 def span(ring: Optional[SpanRecorder], clock: Callable[[], float],

@@ -190,16 +190,18 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
     return out[:, :, :1, :].swapaxes(1, 2)               # (B, 1, H, hd)
 
 
-def _append_kernel(pos_ref, _, kn_ref, vn_ref, k_ref, v_ref, ko_ref, vo_ref):
+def _append_kernel(pos_ref, _, *refs):
+    """``refs``: n new-value blocks, n cache tiles, n output tiles."""
     from jax.experimental.pallas import tpu as pltpu
 
+    n = len(refs) // 3
     b = pl.program_id(0)
     r = pos_ref[b] % LANES                  # the position's lane in its tile
-    col = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 2)
+    col = jax.lax.broadcasted_iota(jnp.int32, refs[n].shape, 2)
     # the new values lie slots-on-lanes: slot b's column turns onto lane r
     turn = (r - b % LANES) % LANES
-    for new_ref, old_ref, out_ref in ((kn_ref, k_ref, ko_ref),
-                                      (vn_ref, v_ref, vo_ref)):
+    for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                         refs[2 * n:]):
         new = pltpu.roll(new_ref[...].astype(jnp.float32), turn, 2)
         out_ref[...] = jnp.where(col == r, new.astype(out_ref.dtype),
                                  old_ref[...])
@@ -238,13 +240,30 @@ def cache_append(ck, cv, k, v, length, *, layer,
             out_specs=(cache, cache), check_vma=False)(
                 ck, cv, k, v, lengths, layer)
 
+    return append_in_place((ck, cv), (k, v), lengths, layer,
+                           name="cache_append", interpret=interpret)
+
+
+def append_in_place(caches: tuple, news: tuple, lengths, layer, *, name: str,
+                    interpret: bool):
+    """The kernel behind :func:`cache_append`, for any number of buffers
+    ``(L, B, KV, hd, max_len)`` written at the same positions (K and V; the
+    one buffer of a latent cache, ``ops/mla_attention.py``): ``news``
+    (B, 1, KV, hd) each, ``lengths`` (B,) AFTER the append, ``layer`` (1,)
+    i32. Returns the caches, outputs aliased to the inputs."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    ck = caches[0]
+    _, B, KV, hd, S = ck.shape
+    n = len(caches)
     # (B, 1, KV, hd) → (KV, hd, slots): hd on the sublanes as in the cache,
     # the slots on the lanes (a few KiB; the kernel turns its slot's column
     # onto the position's lane)
     pos = jnp.clip(lengths - 1, 0, S - 1)
     pad = (-B) % LANES
-    kn, vn = (jnp.pad(x[:, 0].transpose(1, 2, 0).astype(ck.dtype),
-                      ((0, 0), (0, 0), (0, pad))) for x in (k, v))
+    news = tuple(jnp.pad(x[:, 0].transpose(1, 2, 0).astype(c.dtype),
+                         ((0, 0), (0, 0), (0, pad)))
+                 for x, c in zip(news, caches))
     kvb = max(d for d in range(1, KV + 1) if KV % d == 0 and (
         d == 1 or d * hd * LANES * ck.dtype.itemsize <= _APPEND_TILE_BYTES))
 
@@ -258,14 +277,13 @@ def cache_append(ck, cv, k, v, length, *, layer,
     tile_spec = pl.BlockSpec((None, None, kvb, hd, LANES), tile)
     return pl.pallas_call(
         _append_kernel,
-        name="cache_append",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, KV // kvb),
-            in_specs=[new_spec, new_spec, tile_spec, tile_spec],
-            out_specs=[tile_spec, tile_spec]),
-        out_shape=[jax.ShapeDtypeStruct(ck.shape, ck.dtype),
-                   jax.ShapeDtypeStruct(cv.shape, cv.dtype)],
-        input_output_aliases={4: 0, 5: 1},
+            in_specs=[new_spec] * n + [tile_spec] * n,
+            out_specs=[tile_spec] * n),
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
-    )(pos, layer, kn, vn, ck, cv)
+    )(pos, layer, *news, *caches)

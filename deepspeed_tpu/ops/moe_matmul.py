@@ -1,0 +1,108 @@
+"""Pallas grouped expert product: rows sorted by expert, multiplied per expert.
+
+The expert layer (``models/moe.py`` ``experts``) lays the routed rows out
+sorted by expert, every expert's rows padded to whole blocks of ``bm`` rows,
+so a block belongs to ONE expert; ``block_expert`` (scalar-prefetched) names
+it and the index map fetches that expert's weights. Blocks of the same
+expert follow each other, and a weight tile whose index did not change is
+not fetched again: every touched expert's weights are read once, an
+untouched expert's never. Blocks behind the last used one repeat its expert
+(no fetch) and are skipped.
+
+- ``experts_up``: ``silu(x @ w_gate[e]) * (x @ w_in[e])`` → (R, f); grid
+  (f tiles, blocks), the blocks innermost.
+- ``experts_down``: ``h @ w_out[e]`` → (R, d); grid (d tiles, blocks).
+
+Rows of skipped blocks are left unwritten; nothing reads them (the combine
+gathers routed rows only).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+LANES = 128
+
+
+def block_rows(dtype) -> int:
+    """Rows of one block: the sublane tile of ``dtype`` (8 x 32-bit words),
+    the least a matmul operand can hold — what padding costs per expert."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def _tile(n: int, most: int) -> int:
+    """Largest multiple of 128 dividing ``n`` that is <= ``most``; else n."""
+    for t in range(most - most % LANES, 0, -LANES):
+        if n % t == 0:
+            return t
+    return n
+
+
+def _up_kernel(be_ref, used_ref, _, x_ref, wg_ref, wi_ref, o_ref):
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wi_ref[...], preferred_element_type=jnp.float32)
+        o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
+
+
+def _down_kernel(be_ref, used_ref, _, h_ref, wo_ref, o_ref):
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        o_ref[...] = jnp.dot(h_ref[...], wo_ref[...],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
+
+
+def _call(kernel, name, rows, weights, block_expert, used, layer, bm, n_out,
+          tile, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, k_in = rows.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_out // tile, R // bm),
+        in_specs=[pl.BlockSpec((bm, k_in), lambda t, b, *_: (b, 0))]
+        + [pl.BlockSpec((None, None, k_in, tile),
+                        lambda t, b, be, used, layer: (layer[0], be[b], 0, t))
+           ] * len(weights),
+        out_specs=pl.BlockSpec((bm, tile), lambda t, b, *_: (b, t)))
+    return pl.pallas_call(
+        kernel, name=name, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, n_out), rows.dtype),
+        interpret=interpret,
+    )(block_expert, used, layer, rows, *weights)
+
+
+def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
+                   bm: int, layer=None, interpret: Optional[bool] = None):
+    """``xs`` (R, d): rows sorted by expert and padded to blocks of ``bm``;
+    ``w_gate``/``w_in`` (E, d, f), ``w_out`` (E, f, d) — or the banks of
+    ALL layers, (L, E, ·, ·), with ``layer`` (traced i32) the one to use:
+    a layer loop then carries the stacked banks untouched and the index map
+    picks the layer, where slicing a layer's bank out for the call would
+    copy 0.4 GB a matrix (PERF.md, PR 29). ``block_expert`` (R // bm,) i32
+    the expert of every block; ``blocks_used`` i32 how many blocks hold
+    rows. Returns (R, d): row i through its block's expert."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    R, d = xs.shape
+    if R % bm:
+        raise ValueError(f"{R} rows are not whole blocks of {bm}")
+    if w_in.ndim == 3:
+        w_gate, w_in, w_out, layer = w_gate[None], w_in[None], w_out[None], 0
+    f = w_in.shape[3]
+    be = block_expert.astype(jnp.int32)
+    used = jnp.asarray(blocks_used, jnp.int32).reshape(1)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    h = _call(_up_kernel, "moe_experts_up", xs,
+              (w_gate.astype(xs.dtype), w_in.astype(xs.dtype)), be, used,
+              layer, bm, f, _tile(f, 512), interpret)
+    return _call(_down_kernel, "moe_experts_down", h,
+                 (w_out.astype(xs.dtype),), be, used, layer, bm, d,
+                 _tile(d, 1024), interpret)

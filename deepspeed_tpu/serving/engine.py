@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.config import ServingConfig
-from ..inference.decode import (GenCarry, decode_step, forward_with_cache,
+from ..inference.decode import (GenCarry, cache_bytes_per_token,
+                                decode_step, forward_with_cache,
                                 init_cache)
 from ..inference.engine import InferenceEngine
 from ..inference.sampling import per_request_keys, split_keys
@@ -123,12 +124,47 @@ class ServingEngine:
             raise ValueError(
                 f"serving max_len={self.cfg.max_len} exceeds the model's "
                 f"learned-position table (max_seq={mcfg.max_seq})")
+        # cache kinds and expert layers (docs/SERVING.md): what does not
+        # compose with them yet fails here, never falls back
+        self._latent = bool(getattr(mcfg, "latent_dim", 0))
+        self._moe_stats = self._latent \
+            and getattr(mcfg, "moe_router", "") == "sigmoid" \
+            and any(kind == "moe" for kind, _ in mcfg.segments)
+        if self._latent or getattr(mcfg, "moe_router", "") == "sigmoid":
+            refused = [name for name, on in (
+                ("the paged pool (page_size)", self.cfg.page_size > 0),
+                ("an int8 KV cache (kv_quant_bits)",
+                 bool(self.cfg.kv_quant_bits)),
+                ("speculation", self.cfg.speculation is not None
+                 and self.cfg.speculation.enabled),
+                ("weight-only quantization", bool(engine.config.quantize)),
+                ("a mesh of several devices (tensor/expert parallel)",
+                 engine.mesh.size > 1)) if on]
+            if refused:
+                raise ValueError(
+                    "a latent (MLA) cache and sigmoid-routed expert layers "
+                    "do not yet compose with " + ", ".join(refused))
         self._flash = engine.config.flash_decode_resolved()
         if self._flash and self.cfg.max_len % 128 != 0:
             raise ValueError(
                 f"flash_decode needs max_len to be a multiple of 128 "
                 f"(Pallas lane blocks), got {self.cfg.max_len} — round up "
                 "or set flash_decode=False")
+        # expert counters of chunks dispatched since the last decode
+        # read-back: (span, device stats, chunk size)
+        self._chunk_stats: list = []
+        # a tap, not an option: set to a dict and every request's expert
+        # choices are kept there, {rid: [(first position, (expert layers,
+        # positions, k) experts)]} in the order computed (a later entry
+        # overwrites an overlapping earlier one, as the cache does). Two
+        # bf16 programs round a router's input differently now and then
+        # and take different experts at a near-tie; a comparison of this
+        # path with a reference has to follow THIS path's choices
+        # (benchmark/kinds/backlog_routed.py). None: nothing is fetched.
+        self.routing_log: Optional[dict] = None
+        self._chunk_routing: list = []   # (rid, start, device routing, real)
+        self._cache_bytes_per_token = cache_bytes_per_token(
+            mcfg, engine.compute_dtype) if self._latent else None
         self._eos = engine.config.eos_token_id
         self._sampler = engine._sampler(self.cfg.temperature, self.cfg.top_k,
                                         self.cfg.top_p, self.cfg.greedy)
@@ -421,6 +457,9 @@ class ServingEngine:
         self._max_results = _MAX_RESULTS
         # (request, chunk plan, next chunk idx, device prefill cache, rng)
         self._prefill = None
+        # (chunk, its program's output, its span): the lane's next chunk
+        # where _prefill_ahead dispatched it behind the last decode step
+        self._ahead = None
         # resilience state: chaos only exists when explicitly enabled —
         # disabled serving carries a single `is not None` check per step
         self.chaos: Optional[ChaosMonkey] = None
@@ -559,8 +598,10 @@ class ServingEngine:
         is never computed (nothing consumes the logits, XLA removes it)."""
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
-        _, cache = forward_with_cache(self.model, mat(params), ids, cache)
-        return cache
+        _, cache, stats, routing = forward_with_cache(
+            self.model, mat(params), ids, cache, with_stats=True,
+            with_routing=True)
+        return (cache, stats, routing) if self._moe_stats else cache
 
     def _final_impl(self, params, cache, ids, start, last_index, true_len,
                     rng):
@@ -569,22 +610,25 @@ class ServingEngine:
         put it before the chunk end), leaving the cache at ``true_len``."""
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
-        logits, cache = forward_with_cache(
+        logits, cache, stats, routing = forward_with_cache(
             self.model, mat(params), ids, cache, last_token_head=True,
-            last_index=last_index)
+            last_index=last_index, with_stats=True, with_routing=True)
         rng, sub = split_keys(rng)
         tok = self._sampler(logits[:, -1], sub)
         done = (tok == self._eos) if self._eos is not None \
             else jnp.zeros(tok.shape, bool)
-        return GenCarry(tok=tok, cache=cache._replace(length=true_len),
-                        rng=rng, done=done)
+        pf = GenCarry(tok=tok, cache=cache._replace(length=true_len),
+                      rng=rng, done=done)
+        return (pf, stats, routing) if self._moe_stats else pf
 
     def _step_impl(self, params, carry):
         # logit_guard: the (B,) per-row finiteness flags ride the step's
         # existing fused read-back — the guard costs zero extra host syncs
+        # ... and neither do the expert layers' counters (moe_stats: a
+        # third result on that same read-back, for the decode_step span)
         return decode_step(self.model, params, carry, sampler=self._sampler,
                            eos_token_id=self._eos, flash_decode=self._flash,
-                           logit_guard=True)
+                           logit_guard=True, moe_stats=self._moe_stats)
 
     def _step_chaos_impl(self, params, carry, poison_row):
         """Chaos build of the step: identical program + a traced poison-row
@@ -906,7 +950,10 @@ class ServingEngine:
         ``prefill_chunk``, ``prefill_readback``, ``place``,
         ``decode_dispatch``, ``decode_readback``, ``retire``, ``tail``
         (observability/spans.py; the span less the children is the
-        loop's own time)."""
+        loop's own time). Where the prefill lane has a further chunk, its
+        ``prefill_chunk`` stands between ``decode_dispatch`` and
+        ``decode_readback`` of the iteration before
+        (``_prefill_ahead``)."""
         with self._span(_spans.SRV_STEP, step=self._iterations):
             return self._iterate()
 
@@ -915,6 +962,42 @@ class ServingEngine:
         clock: every timed piece of host code in this file goes through
         here."""
         return _spans.span(self.spans, self.stats.clock, kind, **fields)
+
+    def _moe_counts(self, moe: list, pending: list) -> dict:
+        """What the decode read-back brought beside the tokens, as meta of
+        the ``decode_step`` span: of the step's expert layers
+        (``MoETransformerLM.experts``' counters, one row a layer) the most
+        rows any expert got over the mean, the rows the expert products
+        multiplied (padding included) over the rows routed, the experts
+        touched (mean over layers); and what a cached token costs. The
+        chunks' counters go onto their own ``prefill_chunk`` spans. Rows
+        routed count the whole slot batch: an idle slot's token is routed
+        and multiplied like any other."""
+        if not moe:          # no expert trunk, or the chaos build's step
+            return {}
+        k = self.model.cfg.moe_top_k
+        for (chunk_span, _, size), st in zip(pending, moe[1:]):
+            chunk_span.amend(moe_rows_over_routed=float(
+                st[:, 2].sum() / (len(st) * size * k)))
+        st = moe[0]
+        routed = self.cfg.slots * k
+        return {"moe_load_max_over_mean": float(
+                    st[:, 0].max() * self.model.cfg.num_experts / routed),
+                "moe_rows_over_routed": float(
+                    st[:, 2].sum() / (len(st) * routed)),
+                "experts_touched": float(st[:, 1].mean()),
+                "cache_bytes_per_token": self._cache_bytes_per_token}
+
+    def _log_routing(self, step, tapped: list, chunks: list) -> None:
+        """Into ``routing_log``: the chunks' choices (their real tokens,
+        at the positions they wrote), then this step's, one position for
+        every running request: that of the token it was fed."""
+        log = self.routing_log
+        for (rid, start, _, real), routing in zip(tapped, chunks):
+            log.setdefault(rid, []).append((start, routing[:, 0, :real]))
+        for slot, req in self.sched.running.items():
+            log.setdefault(req.rid, []).append(
+                (req.prompt_len + len(req.tokens) - 1, step[:, slot]))
 
     def _iterate(self) -> list[Request]:
         n_it = self._iterations
@@ -962,6 +1045,7 @@ class ServingEngine:
                 t0 = self.stats.clock()
                 n_slots = len(self.sched.running)
                 plan = spec_out = None
+                moe: list = []      # the step's expert counters, if any
                 with self._span(_spans.SRV_DECODE_DISPATCH, step=n_it):
                     if chaos is not None:
                         chaos.maybe_hang(it)
@@ -978,8 +1062,11 @@ class ServingEngine:
                         if plan is None:
                             step = self._prog("step", lambda: jax.jit(
                                 self._step_impl, donate_argnums=(1,)))
-                            self._state, ok = step(self.engine.params,
-                                                   self._state)
+                            self._state, ok, *moe = step(self.engine.params,
+                                                         self._state)
+                if plan is None and chaos is None \
+                        and self._prefill is not None:
+                    self._prefill_ahead(n_it)
                 with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
                     if plan is not None:
                         # verify + host acceptance + commit, all inside
@@ -992,17 +1079,32 @@ class ServingEngine:
                         # per-iteration sync is the scheduler's steering
                         # cost — don't pay it twice, and don't let the
                         # guard add a second one
-                        toks, dones, oks = jax.device_get(
-                            (self._state.tok, self._state.done, ok))
+                        # ... nor the expert layers' counters (this
+                        # step's, and those of the chunks dispatched since
+                        # the last read-back) a third
+                        pending, self._chunk_stats = self._chunk_stats, []
+                        tapped, self._chunk_routing = self._chunk_routing, []
+                        if self.routing_log is None:
+                            moe = moe[:1]        # the step's routing stays
+                        toks, dones, oks, *moe = jax.device_get(
+                            (self._state.tok, self._state.done, ok, *moe,
+                             *(st for _, st, _ in pending),
+                             *(r for _, _, r, _ in tapped)))
+                        if self.routing_log is not None and moe:
+                            self._log_routing(
+                                moe.pop(1), tapped,
+                                [moe.pop() for _ in tapped][::-1])
+                        counts = self._moe_counts(moe, pending)
                 t1 = self.stats.clock()
                 self._last_step_s = t1 - t0
                 # the parent of the decode pair, from the t0/t1 the
                 # watchdog measures anyway; the counts at this boundary
-                # (slots decoding, requests waiting) ride on it
+                # (slots decoding, requests waiting; an expert trunk's
+                # rows and a latent cache's bytes) ride on it
                 _spans.emit(self.spans, _spans.DECODE_STEP, t0, t1,
                             step=n_it, slots=n_slots,
                             queue=self.sched.queue_depth,
-                            **({"spec": True} if plan is not None else {}))
+                            **({"spec": True} if plan is not None else counts))
                 wd = self.cfg.watchdog_s
                 if wd and self._last_step_s > wd:
                     # rising edge: the previous iteration was healthy. A
@@ -1210,7 +1312,9 @@ class ServingEngine:
             except QueueFullError:
                 pass  # the shed IS the scenario; counted in Serve/shed
 
-    def _prefill_advance(self, n_it: int) -> list[Request]:
+    def _chunk_dispatch(self, n_it: int, **ahead) -> tuple:
+        """Dispatch the prefill lane's next chunk; (the chunk, what its
+        program returned, its span)."""
         req, plan, idx, cache, rng = self._prefill
         ch = plan[idx]
         params = self.engine.params
@@ -1220,25 +1324,59 @@ class ServingEngine:
         # (prefill_readback on a final chunk, else decode_readback)
         with self._span(_spans.PREFILL_CHUNK, name="srv.prefill_chunk",
                         rid=req.rid, step=n_it, chunk=idx, size=ch.size,
-                        final=ch.final, **self.sched._attempt_meta(req)):
+                        final=ch.final, **ahead,
+                        **self.sched._attempt_meta(req)) as chunk_span:
             ids = jnp.asarray(ch.ids[None], jnp.int32)
             if not ch.final:
                 fwd = self._prog(("chunk", ch.size), lambda: jax.jit(
                     self._chunk_impl, donate_argnums=(1,)))
-                cache = fwd(params, cache, ids, jnp.int32(ch.start))
+                out = fwd(params, cache, ids, jnp.int32(ch.start))
             else:
                 fin = self._prog(("final", ch.size), lambda: jax.jit(
                     self._final_impl, donate_argnums=(1,)))
-                pf = fin(params, cache, ids, jnp.int32(ch.start),
-                         jnp.int32(ch.last_index), jnp.int32(ch.true_len),
-                         rng)
+                out = fin(params, cache, ids, jnp.int32(ch.start),
+                          jnp.int32(ch.last_index), jnp.int32(ch.true_len),
+                          rng)
+        return ch, out, chunk_span
+
+    def _prefill_ahead(self, n_it: int) -> None:
+        """Behind the decode step just dispatched, the chunk the prefill
+        lane would dispatch first thing next iteration: the device runs it
+        while the host reads the step back, retires and books, instead of
+        standing idle until the host comes round. It stays next
+        iteration's chunk (one an iteration, consumed by
+        ``_prefill_advance``); a lane cleared meanwhile (cancel, deadline)
+        leaves it unused. Its span says ``ahead`` and carries the
+        iteration that dispatched it."""
+        self._ahead = self._chunk_dispatch(n_it, ahead=True)
+        # the program took the lane's cache (donated)
+        self._prefill = self._prefill[:3] + (None,) + self._prefill[4:]
+
+    def _prefill_advance(self, n_it: int) -> list[Request]:
+        req, plan, idx, _, rng = self._prefill
+        ahead, self._ahead = self._ahead, None
+        if ahead is None or ahead[0] is not plan[idx]:
+            ahead = self._chunk_dispatch(n_it)
+        ch, out, chunk_span = ahead
+        if self._moe_stats:
+            # the chunk's expert counters stay on the device until the
+            # next decode read-back fetches them beside its own
+            out, stats, routing = out
+            if chunk_span.recording:
+                self._chunk_stats.append((chunk_span, stats, ch.size))
+            if self.routing_log is not None:
+                self._chunk_routing.append(
+                    (req.rid, ch.start, routing,
+                     ch.last_index + 1 if ch.final else ch.size))
         if not ch.final:
-            self._prefill = (req, plan, idx + 1, cache, rng)
+            self._prefill = (req, plan, idx + 1, out, rng)
             return []
+        pf = out
         self._prefill = None
         with self._span(_spans.SRV_PREFILL_READBACK, step=n_it):
-            first_tok = int(np.asarray(pf.tok)[0])
-            ended = req.max_new == 1 or bool(np.asarray(pf.done)[0])
+            tok, done = jax.device_get((pf.tok, pf.done))
+            first_tok = int(tok[0])
+            ended = req.max_new == 1 or bool(done[0])
         if ended:
             return [self.sched.complete_at_prefill(req, first_tok)]
         with self._span(_spans.SRV_PLACE, step=n_it):

@@ -3,8 +3,8 @@
 Reference analog: DeepSpeed-MII / FastGen's blocked-KV "ragged batching"
 state. TPU-native translation: instead of a paged block table (dynamic
 indirection is hostile to XLA's static shapes), the serving state is ONE
-``(L, slots, KV, hd, max_len)`` cache — the same layout ``init_cache``
-allocates, via the shared :func:`~..inference.decode.cache_layout`:
+``(L, slots, KV, hd, max_len)`` cache (``(L, slots, rank + rope, max_len)``
+for latent attention) — the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
 positions on the lanes, so the buffer is compact in HBM at any head size
 and the decode step's two kernels append to it and read it where it lies
 (``ops/decode_attention.py``) — plus per-slot ``length`` / ``tok`` /
@@ -21,7 +21,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-from ..inference.decode import GenCarry, KVCache, cache_layout
+from ..inference.decode import GenCarry, init_cache
 
 __all__ = ["init_slots", "insert_request"]
 
@@ -31,10 +31,10 @@ def init_slots(cfg, slots: int, max_len: int, dtype=None) -> GenCarry:
 
     The carry is a plain :class:`~..inference.decode.GenCarry` whose cache
     ``length`` is a (slots,) vector — the decode stack's per-slot paths key
-    off that shape, so the same ``decode_step`` serves both worlds."""
-    shape, dtype = cache_layout(cfg, slots, max_len, dtype)
-    cache = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                    length=jnp.zeros((slots,), jnp.int32))
+    off that shape, so the same ``decode_step`` serves both worlds. The
+    cache is of the model's kind (K and V, or latents), from
+    ``cache_layout``: what follows treats its buffers alike."""
+    cache = init_cache(cfg, slots, max_len, dtype, length_shape=(slots,))
     return GenCarry(tok=jnp.zeros((slots,), jnp.int32), cache=cache,
                     rng=jnp.zeros((slots, 2), jnp.uint32),
                     done=jnp.ones((slots,), bool))
@@ -52,15 +52,17 @@ def insert_request(state: GenCarry, slot, pf: GenCarry) -> GenCarry:
     is what guarantees a retired request's stale KV is fully overwritten
     before the new occupant's first decode step."""
     kc = state.cache
-    k = lax.dynamic_update_slice(kc.k, pf.cache.k.astype(kc.k.dtype),
-                                 (0, slot, 0, 0, 0))
-    v = lax.dynamic_update_slice(kc.v, pf.cache.v.astype(kc.v.dtype),
-                                 (0, slot, 0, 0, 0))
+    # every buffer of the cache (K and V; the latents) has the slot second
+    buffers = {
+        name: lax.dynamic_update_slice(
+            buf, getattr(pf.cache, name).astype(buf.dtype),
+            (0, slot) + (0,) * (buf.ndim - 2))
+        for name, buf in kc._asdict().items() if name != "length"}
     length = lax.dynamic_update_slice(
         kc.length, pf.cache.length.reshape(1).astype(jnp.int32), (slot,))
     tok = lax.dynamic_update_slice(state.tok, pf.tok.astype(jnp.int32),
                                    (slot,))
     rng = lax.dynamic_update_slice(state.rng, pf.rng, (slot, 0))
     done = lax.dynamic_update_slice(state.done, pf.done, (slot,))
-    return GenCarry(tok=tok, cache=KVCache(k=k, v=v, length=length),
+    return GenCarry(tok=tok, cache=kc._replace(length=length, **buffers),
                     rng=rng, done=done)

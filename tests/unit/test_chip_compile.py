@@ -200,3 +200,85 @@ def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
         r"fusion)\(")
     moved = [ln.strip()[:160] for ln in text.splitlines() if slab.search(ln)]
     assert not moved, moved
+
+
+# ------------------------------------------------- latent cache, expert rows
+KANANA = dict(L=7, slots=48, H=32, rank=512, rope=64, S=8192, E=128, d=2048,
+              f=768)
+
+
+@pytest.mark.parametrize("kernel", ["mla_decode_attention", "latent_append",
+                                    "experts_swiglu-step",
+                                    "experts_swiglu-chunk"])
+def test_latent_and_expert_kernels(one_chip, kernel):
+    """The latent decode step's two kernels and the grouped expert product
+    at Kanana-2-30B-A3B's published widths: the 576-value contraction, the
+    (576, 128) append tile and 16-row expert blocks have to pass Mosaic."""
+    from deepspeed_tpu.ops.mla_attention import (latent_append,
+                                                 mla_decode_attention)
+    from deepspeed_tpu.ops.moe_matmul import experts_swiglu
+
+    k = KANANA
+    D, bf, i32 = k["rank"] + k["rope"], jnp.bfloat16, jnp.int32
+    cache = ((k["L"], k["slots"], D, k["S"]), bf)
+    if kernel == "mla_decode_attention":
+        _compile(lambda q, c, n, layer: mla_decode_attention(
+            q, c, n, layer=layer[0], rank=k["rank"], scale=0.072,
+            interpret=False), one_chip,
+            ((k["slots"], k["H"], D), bf), cache, ((k["slots"],), i32),
+            ((1,), i32))
+    elif kernel == "latent_append":
+        _compile(lambda c, new, n, layer: latent_append(
+            c, new, n, layer=layer[0], interpret=False), one_chip,
+            cache, ((k["slots"], D), bf), ((k["slots"],), i32), ((1,), i32))
+    else:
+        tokens = k["slots"] if kernel.endswith("step") else 512
+        rows = -(-(tokens * 6 + k["E"] * 15) // 16) * 16
+        bank = ((k["E"], k["d"], k["f"]), bf)
+        _compile(lambda xs, wg, wi, wo, be, used: experts_swiglu(
+            xs, wg, wi, wo, be, used[0], bm=16, interpret=False), one_chip,
+            ((rows, k["d"]), bf), bank, bank, ((k["E"], k["f"], k["d"]), bf),
+            ((rows // 16,), i32), ((1,), i32))
+
+
+def test_latent_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch):
+    """The 48-slot step of the seven-layer cut: the latent cache enters
+    donated and leaves aliased, the four kernels are in the program, and
+    the live set fits the chip (15.75 GiB) with the weights beside it."""
+    from deepspeed_tpu.models import deepseek_v3
+    from deepspeed_tpu.serving.slots import init_slots
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    k = KANANA
+    cfg = deepseek_v3("kanana-2-30b-a3b", n_layer=k["L"], dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda key: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), model.init(key)),
+        jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(
+        lambda: init_slots(cfg, k["slots"], k["S"], jnp.bfloat16)))
+
+    def step(params, carry):
+        return decode_step(model, params, carry, flash_decode=True,
+                           sampler=partial(sample_logits, temperature=1.0),
+                           logit_guard=True, moe_stats=True)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(params,
+                                                        state).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = k["L"] * k["slots"] * 576 * k["S"] * 2
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < 15.75 * 2 ** 30, live
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for kernel in ("mla_decode_attention", "mla_cache_append",
+                   "moe_experts_up", "moe_experts_down"):
+        assert any(kernel in ln for ln in calls), f"{kernel} absent"

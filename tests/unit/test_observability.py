@@ -907,7 +907,14 @@ def iteration_spans(tiny_server):
 
 PHASE_ORDER = ["srv.deadlines", "srv.admit", "prefill_chunk",
                "srv.prefill_readback", "srv.place", "srv.decode_dispatch",
-               "srv.decode_readback", "srv.retire", "srv.tail"]
+               "prefill_chunk.ahead", "srv.decode_readback", "srv.retire",
+               "srv.tail"]
+
+
+def _phase(e):
+    """A chunk dispatched behind the decode step for the next iteration
+    (``_prefill_ahead``) is a phase of its own."""
+    return e.kind + (".ahead" if e.meta.get("ahead") else "")
 
 
 @pytest.mark.parametrize("check", [
@@ -923,7 +930,7 @@ def test_serving_iteration_spans(iteration_spans, check):
     if check == "children_inside_in_order":
         for step, (parent, kids) in steps.items():
             assert kids, step
-            kinds = [k.kind for k in kids]
+            kinds = [_phase(k) for k in kids]
             assert kinds == sorted(kinds, key=PHASE_ORDER.index), kinds
             assert len(set(kinds)) == len(kinds)
             edges = [parent.t0] + [t for k in kids for t in (k.t0, k.t1)] \
@@ -938,11 +945,20 @@ def test_serving_iteration_spans(iteration_spans, check):
             # neighbours, so the loop's own time is never nothing
             assert own >= 0.001 * (len(kids) + 1) - 1e-9
     elif check == "readback_on_final_chunks":
-        for parent, kids in steps.values():
-            chunk = [k for k in kids if k.kind == "prefill_chunk"]
+        # a final chunk is read back in the iteration it belongs to: its
+        # own, or the one after where it was dispatched ahead
+        final_before = False
+        for step in sorted(steps):
+            kids = steps[step][1]
+            chunk = [k for k in kids if _phase(k) == "prefill_chunk"]
+            ahead = [k for k in kids if _phase(k) == "prefill_chunk.ahead"]
             read = [k for k in kids if k.kind == "srv.prefill_readback"]
-            assert len(chunk) <= 1
-            assert bool(read) == bool(chunk and chunk[0].meta["final"])
+            assert len(chunk) <= 1 and len(ahead) <= 1
+            assert not (chunk and final_before)
+            assert bool(read) == bool(
+                final_before or (chunk and chunk[0].meta["final"]))
+            final_before = bool(ahead and ahead[0].meta["final"])
+        assert any(_phase(e) == "prefill_chunk.ahead" for e in evs)
         finals = [e.meta["final"] for e in evs if e.kind == "prefill_chunk"]
         assert finals.count(True) == 4 and finals.count(False) == 3
     elif check == "decode_step_is_the_parent_of_the_decode_pair":

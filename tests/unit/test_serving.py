@@ -131,6 +131,38 @@ def test_ragged_workload_parity(setup):
     assert snap["ttft_s"]["count"] == 16
 
 
+@pytest.mark.parametrize("then", ["runs_on", "cancelled"])
+def test_chunk_dispatched_ahead(setup, then):
+    """With a slot decoding, the prefill lane's next chunk is dispatched
+    behind the decode step and consumed by the next iteration, one chunk
+    an iteration still; a lane cancelled with a chunk in flight leaves it
+    unused. The tokens are solo ``generate()``'s either way."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 2, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20})
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), N, 40 + i)
+            for i, (P, N) in enumerate([(6, 20), (37, 5), (29, 6)])]
+    first = srv.submit(reqs[0][0], reqs[0][1], seed=reqs[0][2])
+    srv.step()                               # seated: a slot decodes
+    long = srv.submit(reqs[1][0], reqs[1][1], seed=reqs[1][2])
+    srv.step()                               # chunk 0, chunk 1 goes ahead
+    assert srv._prefill[0].rid == long and srv._prefill[2] == 1
+    assert srv._ahead is not None and srv._prefill[3] is None
+    chunks = srv.metrics_snapshot()["prefill_chunks"]
+    if then == "cancelled":
+        srv.cancel(long)
+        assert srv._prefill is None
+    last = srv.submit(reqs[2][0], reqs[2][1], seed=reqs[2][2])
+    srv.step()
+    assert srv.metrics_snapshot()["prefill_chunks"] == chunks + 1
+    srv.drain()
+    want = [r for r in reqs if then == "runs_on" or r is not reqs[1]]
+    got = [srv.pop_result(rid).tokens for rid in
+           ([first, long, last] if then == "runs_on" else [first, last])]
+    _check_parity(model, params, want, got)
+
+
 def test_slot_reuse_no_stale_kv(setup):
     """One slot, sequential requests: the second and third requests reuse
     the retired slot and must still match their solo runs — and the insert
