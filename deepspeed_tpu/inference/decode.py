@@ -47,11 +47,11 @@ class KVCache(NamedTuple):
     # (L, B, KV, hd, max_len): POSITIONS ON THE LANES. HBM tiles the last
     # two dims 8 x 128 words, and max_len is a multiple of 128 wherever the
     # kernels run, so the buffer has no padding at any head_dim and the
-    # decode kernels' (hd, max_len) / (KV, hd, 128) blocks are the memory
-    # as it lies. With hd last, a head_dim of 64 fills half of every tile:
-    # the compiler then stores the cache the other way round anyway and
-    # re-lays every layer's slab out around each kernel call (PERF.md F10).
-    # Heads-major, so a (slot, kv-head)'s positions are one block.
+    # decode kernels' (KV, hd, 128) blocks are the memory as it lies. With
+    # hd last, a head_dim of 64 fills half of every tile: the compiler then
+    # stores the cache the other way round anyway and re-lays every layer's
+    # slab out around each kernel call (PERF.md F10).
+    # Heads-major, so a slot's heads over 128 positions are one block.
     k: jnp.ndarray           # (L, B, KV, hd, max_len)
     v: jnp.ndarray           # (L, B, KV, hd, max_len)
     length: jnp.ndarray      # i32 tokens cached: scalar (all rows advance

@@ -17,19 +17,32 @@ Layout notes:
   the cache's bytes, and the compiler keeps the cache compact by storing it
   the other way round and re-laying every slab out around each call (what
   PERF.md F10 measured: more time moving K/V than attending to it).
-- both kernels take the WHOLE cache and a scalar-prefetched layer index,
-  and block it through the index map: the layer loop carries one buffer
-  and nothing slices a layer's slab out of it or writes one back.
-- ``decode_attention``: grid (B, H); a program's K/V block is one
-  (slot, kv-head)'s ``(hd, max_len)``. The GQA head group mapping happens
-  in the index map (h // group), so there is no repeated-KV
-  materialization at all (the training kernel pays a ``jnp.repeat``;
-  decode can't afford it). ``s = q @ k`` is a plain (8, hd) @ (hd, block);
-  ``p . v`` contracts the lane dims of both (the MXU's NT form).
-- the single query row is broadcast to the 8-sublane tile (q_sub trick) so
-  the matmuls are MXU/VPU shaped.
-- the live length is a scalar-prefetch operand (SMEM), letting the kernel
-  bound its streaming loop at ceil(length / block) instead of max_len.
+- both kernels take the WHOLE cache and a scalar-prefetched layer index:
+  the layer loop carries one buffer and nothing slices a layer's slab out
+  of it or writes one back.
+- ``decode_attention``: grid (B, KV / hb); a program's work is a slot's
+  ``hb`` KV heads over that slot's LIVE blocks of ``block`` (128)
+  positions. ``hb`` is the largest divisor of ``KV`` whose K block
+  ``(hb, hd, block)`` fits ``_ATTEND_BLOCK_BYTES`` in the cache's dtype
+  (``_heads_per_program``: all 20 heads of GPT-2 774M, a TP shard's 5, 32
+  of 64 at ``hd`` 128): derived from the shapes, never from the batch, so
+  a slot's bits do not depend on its neighbours. The cache stays in HBM
+  (``pl.ANY``); the kernel copies ``ceil(length / block)`` blocks of K and
+  of V into two VMEM buffers each with ``make_async_copy``, the next block
+  in flight behind the current one's products, and after a slot's last
+  block the first block of the NEXT program (which buffer, and whether
+  that copy was started, ride in SMEM scratch: the grid runs in order).
+  Positions behind the live length are neither fetched nor multiplied.
+- the GQA head group mapping is a reshape of q to ``(B, KV, group, hd)``:
+  a KV head's query rows are real rows of one product, padded to the 8
+  sublanes, and there is no repeated-KV materialization at all
+  (the training kernel pays a ``jnp.repeat``; decode can't afford it).
+  K and V enter the MXU as stored: ``s = q @ k`` is a batch over heads of
+  (rows, hd) @ (hd, block), ``p . v`` contracts the lane dims of both (the
+  MXU's NT form) with ``p`` in the cache's dtype; scores, the scale, the
+  running max, the sum and the accumulator are float32 (the loop's carry).
+- the live length is a scalar-prefetch operand (SMEM): it bounds the
+  kernel's loop and its copies at ceil(length / block) instead of max_len.
 - ``cache_append``: grid (B, kv-blocks); writes the step's new K/V at
   position ``length - 1`` of every slot as a read-modify-write of the one
   128-lane tile that holds it, with the cache aliased to the output. An
@@ -55,51 +68,118 @@ LANES = 128
 # cache_append's tile is (kv-block, hd, 128): the most KV heads a program
 # takes, so in, out and their double buffers stay far inside scoped VMEM
 _APPEND_TILE_BYTES = 512 * 1024
+# decode_attention's K (and V) block is (kv-block, hd, block): two buffers
+# of each, the scores and the accumulator stay under a third of the 16 MiB
+# of scoped VMEM
+_ATTEND_BLOCK_BYTES = 1024 * 1024
 
 
-def _decode_kernel(*refs, block: int, scale: float, alibi: bool):
+def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int):
+    """One program: a slot's ``hb`` KV heads (``q_ref`` (hb, rows, hd), the
+    group's query rows padded to the 8 sublanes) over that slot's live
+    blocks, copied out of the cache in HBM by the kernel itself."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    len_ref, layer_ref, *refs = refs
+    slopes_ref = refs.pop(0) if alibi else None
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, ahead = refs
+    b, g = pl.program_id(0), pl.program_id(1)
+    n_slots, n_groups = pl.num_programs(0), pl.num_programs(1)
+    hb, rows, _ = q_ref.shape
+    S = k_hbm.shape[4]
+    # an idle slot's length keeps counting past the cache: never past it
+    # (the append clamps the same way)
+    L = jnp.minimum(len_ref[b], S)
+    nb = (L + block - 1) // block                        # only live blocks
+
+    def copies(buf, slot, heads, j):
+        at = (layer_ref[0], slot, pl.ds(heads * hb, hb), slice(None),
+              pl.ds(pl.multiple_of(j * block, block), block))
+        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf],
+                                      sem.at[1, buf]))
+
+    def fetch(buf, slot, heads, j):
+        for copy in copies(buf, slot, heads, j):
+            copy.start()
+
+    # ``ahead``: which buffer this program's first block goes to, and
+    # whether the program before already started that copy
+    @pl.when((b == 0) & (g == 0))
+    def _():
+        ahead[0] = 0
+        ahead[1] = 0
+
+    first = ahead[0]
+
+    @pl.when((nb > 0) & (ahead[1] == 0))
+    def _():
+        fetch(first, b, g, 0)
+
+    # the program after this one, and whether it has a block to fetch
+    wraps = g == n_groups - 1
+    b_next = jnp.where(wraps, b + 1, b)
+    g_next = jnp.where(wraps, 0, g + 1)
+    next_live = (b_next < n_slots) & (
+        len_ref[jnp.minimum(b_next, n_slots - 1)] > 0)
+
+    q = q_ref[...]
+    slope = None
     if alibi:
-        len_ref, _, slopes_ref, q_ref, k_ref, v_ref, o_ref = refs
-    else:
-        len_ref, _, q_ref, k_ref, v_ref, o_ref = refs
-        slopes_ref = None
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    # an idle slot's length keeps counting past the cache: never past the
-    # block (the append clamps the same way)
-    L = jnp.minimum(len_ref[b], k_ref.shape[1])
-    q = q_ref[...].astype(jnp.float32) * scale          # (SUBLANES, hd)
+        # (hb, rows, 1) of per-query-head slopes, from SMEM scalars
+        head = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 1)
+        slope = jnp.zeros((hb, rows, 1), jnp.float32)
+        for i in range(hb):
+            for r in range(group):
+                slope = jnp.where((head == i) & (row == r),
+                                  slopes_ref[(g * hb + i) * group + r], slope)
 
     def body(j, carry):
         m, l, acc = carry
-        at = pl.ds(pl.multiple_of(j * block, block), block)
-        k = k_ref[:, at].astype(jnp.float32)             # (hd, blk)
-        v = v_ref[:, at].astype(jnp.float32)
-        s = jnp.dot(q, k, preferred_element_type=jnp.float32)  # (SUB, blk)
-        col = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (SUBLANES, block), 1)
-        if slopes_ref is not None:
+        buf = (first + j) % 2
+
+        # behind this block's products: the slot's next block, or after
+        # its last the first block of the next program
+        @pl.when(j + 1 < nb)
+        def _():
+            fetch(1 - buf, b, g, j + 1)
+
+        @pl.when((j + 1 == nb) & next_live)
+        def _():
+            fetch(1 - buf, b_next, g_next, 0)
+
+        for copy in copies(buf, b, g, j):
+            copy.wait()
+        k, v = k_buf[buf], v_buf[buf]                    # (hb, hd, blk)
+        s = jax.lax.dot_general(                         # (hb, rows, blk)
+            q, k, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale
+        col = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        if slope is not None:
             # ALiBi is a pure function of (slot, live length): slope·(s -
             # t) with the query at global position t = L-1 — no (H, S)
             # bias tensor ever exists (the dense fallback builds one per
-            # step; Bloom's positional signal costs one SMEM scalar here)
-            s = s + slopes_ref[h] * (col - (L - 1)).astype(jnp.float32)
+            # step; Bloom's positional signal costs SMEM scalars here)
+            s = s + slope * (col - (L - 1)).astype(jnp.float32)
         keep = col < L
         s = jnp.where(keep, s, BIG_NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jax.lax.dot_general(          # p (SUB, blk) . vT
-            p, v, (((1,), (1,)), ((), ())),
+        acc = acc * corr + jax.lax.dot_general(          # p . vT per head
+            p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         return m_new, l, acc
 
-    nb = (L + block - 1) // block                        # only live blocks
-    m0 = jnp.full((SUBLANES, 1), BIG_NEG, jnp.float32)
-    l0 = jnp.zeros((SUBLANES, 1), jnp.float32)
+    m0 = jnp.full((hb, rows, 1), BIG_NEG, jnp.float32)
+    l0 = jnp.zeros((hb, rows, 1), jnp.float32)
     acc0 = jnp.zeros(q.shape, jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
+    ahead[0] = (first + nb) % 2
+    ahead[1] = ((nb > 0) & next_live).astype(jnp.int32)
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -113,6 +193,15 @@ def _shard_axes(ck, H):
     return mesh, b_ax, h_ax, P(None, b_ax, h_ax, None, None)
 
 
+def _heads_per_program(KV: int, hd: int, blk: int, dtype) -> int:
+    """The most KV heads (a divisor of ``KV``) whose K block fits
+    ``_ATTEND_BLOCK_BYTES``: all 20 of GPT-2 774M, a TP shard's 5, all 32
+    of a 7B model at ``hd`` 128."""
+    return max(d for d in range(1, KV + 1) if KV % d == 0 and (
+        d == 1 or d * hd * blk * jnp.dtype(dtype).itemsize
+        <= _ATTEND_BLOCK_BYTES))
+
+
 def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
                      block: int = LANES, interpret: Optional[bool] = None):
     """q: (B, 1, H, hd) current-token queries; ck/cv: the cache
@@ -122,6 +211,10 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
     ``alibi_slopes``: optional (H,) per-head slopes — the ALiBi distance
     bias is reconstructed in-kernel from the live length (Bloom decode
     stays on the streaming kernel instead of the dense fallback).
+
+    A slot's result depends on that slot's row and length alone: the block
+    and the heads a program takes follow from ``(KV, hd, max_len, dtype)``,
+    never from ``B``.
 
     Returns (B, 1, H, hd)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -162,32 +255,38 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
             out_specs=P(b_ax, None, h_ax, None), check_vma=False)(
                 q, ck, cv, lengths, layer, *slopes)
 
-    # (B, 1, H, hd) → (B, H, SUBLANES, hd): sublane-replicated single query
-    qs = jnp.broadcast_to(q.swapaxes(1, 2), (B, H, SUBLANES, hd))
-
-    def kv_block(b, h, n, layer, *_):
-        return (layer[0], b, h // group, 0, 0)
-
+    hb = _heads_per_program(KV, hd, blk, ck.dtype)
+    # (B, 1, H, hd) → (B, KV, rows, hd): a KV head's ``group`` query rows,
+    # as the cache is stored, padded to the sublane tile — the GQA mapping
+    # is this reshape, K/V are never repeated
+    rows = -(-group // SUBLANES) * SUBLANES
+    qs = jnp.pad(q.reshape(B, KV, group, hd).astype(ck.dtype),
+                 ((0, 0), (0, 0), (0, rows - group), (0, 0)))
+    q_spec = pl.BlockSpec((None, hb, rows, hd),
+                          lambda b, g, *pre: (b, g, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2 + len(slopes),
-        grid=(B, H),
-        in_specs=[
-            pl.BlockSpec((None, None, SUBLANES, hd),
-                         lambda b, h, *pre: (b, h, 0, 0)),
-            pl.BlockSpec((None, None, None, hd, S), kv_block),
-            pl.BlockSpec((None, None, None, hd, S), kv_block),
-        ],
-        out_specs=pl.BlockSpec((None, None, SUBLANES, hd),
-                               lambda b, h, *pre: (b, h, 0, 0)),
+        grid=(B, KV // hb),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=q_spec,
+        scratch_shapes=[pltpu.VMEM((2, hb, hd, blk), ck.dtype),
+                        pltpu.VMEM((2, hb, hd, blk), cv.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((2,), jnp.int32)],
     )
     out = pl.pallas_call(
-        partial(_decode_kernel, block=blk, scale=scale, alibi=alibi),
+        partial(_decode_kernel, block=blk, scale=scale, alibi=alibi,
+                group=group),
         name="decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, SUBLANES, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q.dtype),
+        # a program starts the next one's first copy: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(lengths, layer, *slopes, qs, ck, cv)
-    return out[:, :, :1, :].swapaxes(1, 2)               # (B, 1, H, hd)
+    return out[:, :, :group].reshape(B, 1, H, hd)
 
 
 def _append_kernel(pos_ref, _, *refs):

@@ -5,11 +5,11 @@ The cache is ``(L, B, rank + rope, max_len)``: per position the normed
 ``c`` and the roped ``k_rope`` that ALL heads share (``inference/decode.py``
 ``LatentCache``), positions on the lanes.
 
-- ``mla_decode_attention``: ``ops/decode_attention.py`` runs one program per
-  (slot, head), each reading its head's K/V. Here the heads share the
-  latents, so that grid would read the same bytes H times over. A program
-  takes a slot's H query rows at once: ``s = q (H, rank+rope) @ lat
-  (rank+rope, block)``, ``o_lat += p (H, block) . c (rank, block)^T`` — the
+- ``mla_decode_attention``: in ``ops/decode_attention.py`` every KV head
+  has K/V of its own and a product of its own. Here the heads share the
+  latents: a program takes a slot's H query rows at once: ``s = q (H,
+  rank+rope) @ lat (rank+rope, block)``, ``o_lat += p (H, block) . c
+  (rank, block)^T`` — the
   heads are the matmul's rows, and no single row is broadcast over
   sublanes. Grid (slots, position blocks): the online softmax's state lives
   in VMEM scratch across a slot's blocks; the index map clamps the block to

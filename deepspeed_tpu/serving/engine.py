@@ -45,6 +45,7 @@ from ..observability import spans as _spans
 from ..observability.export import request_record
 from ..observability.metrics import get_registry
 from ..observability.tracing import ServingStats
+from ..ops.decode_attention import LANES
 from ..resilience.chaos import ChaosMonkey
 from ..resilience.guards import QueueFullError, RequestStatus
 from ..utils.logging import warning_once
@@ -292,6 +293,13 @@ class ServingEngine:
         # (page_size=0, the default) builds none of it — the engine is
         # bit-for-bit the contiguous-slot engine, same program set.
         self._paged = self.cfg.page_size > 0
+        # where the step attends with ``decode_attention`` over the slot
+        # cache: a host mirror of the slots' lengths (set at placement,
+        # advanced by one a step, as the device's vector is), from which
+        # the ``decode_step`` span says how far past the live positions the
+        # kernel fetched. No device read
+        self._slot_len = np.zeros(self.cfg.slots, np.int64) \
+            if self._flash and not (self._paged or self._latent) else None
         self.pool: Optional[PagePool] = None
         self._table = None
         self._table_dirty = False
@@ -988,6 +996,17 @@ class ServingEngine:
                 "experts_touched": float(st[:, 1].mean()),
                 "cache_bytes_per_token": self._cache_bytes_per_token}
 
+    def _attn_counts(self) -> dict:
+        """``attn_fetched_over_live`` of the step just dispatched: the
+        positions ``decode_attention`` fetches (every slot's length, idle
+        ones too, clamped to the cache and rounded up to the kernel's
+        block) over those the running requests attend to; 1 is ideal."""
+        fetched = -(-np.minimum(self._slot_len, self.cfg.max_len)
+                    // LANES) * LANES
+        live = sum(req.prompt_len + len(req.tokens)
+                   for req in self.sched.running.values())
+        return {"attn_fetched_over_live": float(fetched.sum() / live)}
+
     def _log_routing(self, step, tapped: list, chunks: list) -> None:
         """Into ``routing_log``: the chunks' choices (their real tokens,
         at the positions they wrote), then this step's, one position for
@@ -1046,6 +1065,7 @@ class ServingEngine:
                 n_slots = len(self.sched.running)
                 plan = spec_out = None
                 moe: list = []      # the step's expert counters, if any
+                counts: dict = {}   # what rides on the decode_step span
                 with self._span(_spans.SRV_DECODE_DISPATCH, step=n_it):
                     if chaos is not None:
                         chaos.maybe_hang(it)
@@ -1067,6 +1087,9 @@ class ServingEngine:
                 if plan is None and chaos is None \
                         and self._prefill is not None:
                     self._prefill_ahead(n_it)
+                if self._slot_len is not None:
+                    self._slot_len += 1     # the step appends, then attends
+                    counts = self._attn_counts()
                 with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
                     if plan is not None:
                         # verify + host acceptance + commit, all inside
@@ -1094,7 +1117,7 @@ class ServingEngine:
                             self._log_routing(
                                 moe.pop(1), tapped,
                                 [moe.pop() for _ in tapped][::-1])
-                        counts = self._moe_counts(moe, pending)
+                        counts.update(self._moe_counts(moe, pending))
                 t1 = self.stats.clock()
                 self._last_step_s = t1 - t0
                 # the parent of the decode pair, from the t0/t1 the
@@ -1410,6 +1433,8 @@ class ServingEngine:
             ins = self._prog("insert", lambda: jax.jit(
                 insert_request, donate_argnums=(0,)))
             self._state = ins(self._state, jnp.int32(slot), pf)
+            if self._slot_len is not None:
+                self._slot_len[slot] = req.prompt_len
         if self.on_placed is not None:
             # disaggregated handoff: the fleet may export the freshly
             # seated request and release the slot before this very

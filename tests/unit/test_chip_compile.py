@@ -92,6 +92,27 @@ def test_decode_attention(one_chip, width):
              ((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("B,H,KV,hd,L,alibi", [
+    pytest.param(48, 20, 20, 64, 36, False, id="gpt2-774m-cell"),
+    pytest.param(48, 5, 5, 64, 36, False, id="tp4-shard-5heads"),
+    pytest.param(8, 32, 8, 128, 4, False, id="gqa-group4-hd128"),
+    pytest.param(8, 16, 4, 128, 4, True, id="alibi-gqa"),
+    pytest.param(4, 64, 64, 128, 2, False, id="64heads-two-programs"),
+])
+def test_decode_attention_over_the_whole_cache(one_chip, B, H, KV, hd, L,
+                                               alibi):
+    """The kernel as the slot step calls it: the whole ``(L, B, KV, hd,
+    max_len)`` cache, a traced ``layer``, (B,) lengths; at the serving
+    cells' real shape, and where the tile rule gives another tile."""
+    cache = ((L, B, KV, hd, SEQ), jnp.bfloat16)
+    slopes = (((H,), jnp.float32),) if alibi else ()
+    _compile(lambda q, ck, cv, n, layer, *s: decode_attention(
+        q, ck, cv, n, layer=layer, alibi_slopes=s[0] if s else None,
+        interpret=False),
+        one_chip, ((B, 1, H, hd), jnp.bfloat16), cache, cache,
+        ((B,), jnp.int32), ((), jnp.int32), *slopes)
+
+
 @pytest.mark.parametrize("width", WIDTHS)
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 def test_fused_token_nll(one_chip, width, grad):
@@ -186,7 +207,9 @@ def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
     compiled, (L, B, KV, hd, S) = _slot_step(one_chip, cfg, slots)
     mem = compiled.memory_analysis()
     cache_bytes = 2 * L * B * KV * hd * S * 2
-    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    # 0.009 GiB at 48 slots of GPT-2 774M: the kernels' operands are the
+    # cache itself, a query row and a new position a slot
+    assert mem.temp_size_in_bytes < 0.02 * 2 ** 30, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]

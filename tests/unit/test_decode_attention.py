@@ -299,3 +299,152 @@ def test_step_consumes_its_cache():
             out.cache.v.unsafe_buffer_pointer()) == where
     assert bool(np.all(np.asarray(ok)))
     np.testing.assert_array_equal(np.asarray(out.cache.length), [6, 1, 129])
+
+
+# ------------------------------------- a slot's heads, live blocks only
+# The kernel takes a slot's KV heads in one program and copies that slot's
+# live blocks out of the cache itself (PR 30): edge lengths in one batch,
+# the head counts and sizes its tile rule has to hold for, and what the
+# serving cells' ``correct`` rests on: a slot's bits do not depend on its
+# neighbours, and nothing behind the live length is read.
+S3 = 384                                      # three lane tiles per slot
+MIXED = [0, 1, 127, 128, 129, S3, S3 + 77, 300]   # past S3: clamped
+SHAPES = {                                    # B, H, KV, hd
+    "mha-20x64-48slots": (48, 20, 20, 64),
+    "gqa-group4": (8, 8, 2, 64),
+    "tp-shard-5heads": (8, 5, 5, 64),
+    "mha-hd128": (8, 4, 4, 128),
+    "mqa-group16": (8, 16, 1, 64),
+}
+
+
+def _case(B, H, KV, hd, S=S3, dtype=jnp.float32, seed=0, layers=None):
+    rng = np.random.default_rng(seed)
+    lead = () if layers is None else (layers,)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), dtype)
+    ck, cv = (jnp.asarray(rng.standard_normal(lead + (B, KV, hd, S)), dtype)
+              for _ in range(2))
+    n = jnp.asarray((MIXED * (B // len(MIXED) + 1))[:B], jnp.int32)
+    return q, ck, cv, n
+
+
+def _dense_rows(q, ck, cv, n, alibi=None):
+    """The dense path a row at a time (it takes one length), the length
+    clamped into the cache as the kernel clamps it."""
+    S = ck.shape[-1]
+    return jnp.concatenate([
+        _cache_attend(q[b:b + 1], ck[b:b + 1], cv[b:b + 1],
+                      jnp.minimum(n[b], S), alibi=alibi)
+        for b in range(q.shape[0])])
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_mixed_lengths_in_one_batch_match_dense(shape, alibi):
+    """Lengths 0, 1, 127, 128, 129, max_len and past max_len (clamped) in
+    one batch against the dense path, for 20 x 64 at 48 slots, a GQA group
+    of 4, a TP shard's 5 heads, ``hd`` 128 and MQA; with ALiBi the slopes
+    index by QUERY head while the cache is read by KV head."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    B, H, KV, hd = SHAPES[shape]
+    q, ck, cv, n = _case(B, H, KV, hd)
+    slopes = alibi_slopes(H) if alibi else None
+    got = np.asarray(decode_attention(q, ck, cv, n, alibi_slopes=slopes,
+                                      interpret=True))
+    want = np.asarray(_dense_rows(q, ck, cv, n, alibi=slopes))
+    live = np.asarray(n) > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    # a slot that holds nothing attends to nothing: zeros, not NaN
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_slab_entry_equals_cache_entry_with_traced_layer(dtype):
+    """The two ways in: a layer's 4-D slab (``_cache_attend``, the paged
+    view) and the whole 5-D cache with a traced ``layer``, bit for bit."""
+    q, ck, cv, n = _case(8, 4, 2, 64, dtype=dtype, layers=3)
+    whole = jax.jit(lambda q, ck, cv, n, layer: decode_attention(
+        q, ck, cv, n, layer=layer, interpret=True))
+    for layer in range(3):
+        slab = decode_attention(q, ck[layer], cv[layer], n, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(whole(q, ck, cv, n, jnp.int32(layer)), np.float32),
+            np.asarray(slab, np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("slot", [0, 17, 28, 47])
+def test_slot_of_48_is_bit_equal_to_the_row_alone(slot, dtype):
+    """Slot ``i`` of a batch of 48 (20 heads x 64) against the same row run
+    alone at batch 1 with a scalar length, as solo ``generate()`` runs it:
+    the same bits. The tile and the order of accumulation over a slot's
+    blocks follow from the slot's own shapes and length."""
+    q, ck, cv, n = _case(48, 20, 20, 64, dtype=dtype, layers=2)
+    batch = decode_attention(q, ck, cv, n, layer=1, interpret=True)
+    alone = decode_attention(q[slot:slot + 1], ck[:, slot:slot + 1],
+                             cv[:, slot:slot + 1], n[slot], layer=1,
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(batch[slot], np.float32),
+                                  np.asarray(alone[0], np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [1, 100, 128, 129, 300])
+def test_garbage_behind_the_live_length_is_never_read(length, dtype):
+    """Large finite garbage (1e4) behind the live length, in the live
+    length's own block and in the later ones, leaves the output
+    bit-unchanged (``0 x NaN`` would poison ``p . v`` in any kernel that
+    multiplies a whole block, so NaN cannot be the probe)."""
+    q, ck, cv, _ = _case(4, 20, 20, 64, dtype=dtype)
+    n = jnp.asarray([length, 5, length, 0], jnp.int32)
+    clean = decode_attention(q, ck, cv, n, interpret=True)
+    behind = np.arange(S3) >= np.asarray(n).reshape(-1, 1, 1, 1)
+    dirty = decode_attention(q, jnp.where(behind, 1e4, ck).astype(dtype),
+                             jnp.where(behind, -1e4, cv).astype(dtype), n,
+                             interpret=True)
+    np.testing.assert_array_equal(np.asarray(clean, np.float32),
+                                  np.asarray(dirty, np.float32))
+
+
+def test_heads_per_program_follow_the_shapes():
+    """No knob: the KV heads a program takes are the largest divisor of
+    ``KV`` whose K block fits the budget, whatever the batch."""
+    from deepspeed_tpu.ops.decode_attention import _heads_per_program
+
+    assert _heads_per_program(20, 64, 128, jnp.bfloat16) == 20   # GPT-2 774M
+    assert _heads_per_program(5, 64, 128, jnp.bfloat16) == 5     # TP shard
+    assert _heads_per_program(32, 128, 128, jnp.bfloat16) == 32  # 7B, hd 128
+    assert _heads_per_program(64, 128, 128, jnp.bfloat16) == 32  # over: halve
+    assert _heads_per_program(64, 128, 128, jnp.float32) == 16
+    assert _heads_per_program(7, 4096, 128, jnp.float32) == 1    # never 0
+
+
+def test_decode_step_span_says_fetched_over_live():
+    """``attn_fetched_over_live`` on the ``decode_step`` span: host
+    arithmetic on a mirror of the slots' lengths, which tracks the
+    device's vector through placements, steps and idle slots."""
+    import deepspeed_tpu as ds
+
+    cfg, model, params = _family("mha-hd64")
+    eng = ds.init_inference(model, params, {"dtype": "float32",
+                                            "eos_token_id": 7,
+                                            "flash_decode": True})
+    srv = ds.ServingEngine(eng, {"slots": 3, "max_len": S, "greedy": True,
+                                 "prefill_chunk": 64, "spans": True})
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(8, 256, (P,)).astype(np.int32)
+               for P in (120, 9, 60, 130)]
+    srv.serve_batch(prompts, [12, 3, 4, 5], [1, 2, 3, 4])
+    np.testing.assert_array_equal(srv._slot_len,
+                                  np.asarray(srv._state.cache.length))
+    steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
+    ratios = [e.meta["attn_fetched_over_live"] for e in steps]
+    assert len(ratios) == len(steps) > 0
+    assert min(ratios) >= 1.0
+    # the first step: one request of 120 tokens seated, attending 121
+    # positions of its one block; two slots idle at length 1: a block each
+    assert ratios[0] == pytest.approx(3 * 128 / 121)
