@@ -328,7 +328,7 @@ class Autotuner:
         except subprocess.TimeoutExpired:
             exp.error = f"child timeout after {self.child_timeout_s:.0f}s"
             return exp
-        # guarded parse (bench_common.run_child's pattern): a child killed
+        # guarded parse: a child killed
         # mid-flush can leave a truncated '{'-line — that is a failed
         # experiment, never a crashed tune
         result = None

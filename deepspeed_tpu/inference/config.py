@@ -346,7 +346,7 @@ class InferenceConfig:
     # Subsumed knob, accepted for config compat: decode now keeps weights
     # quantized end-to-end and dispatches the dequant at each consumption
     # site, so there is no hoisted whole-tree dequant to toggle anymore
-    # (round-5 WOQ_PROBE showed XLA hoisting it either way).
+    # (XLA hoisted it out of the scan either way: docs/WOQ_DECODE.md).
     dequant_per_step: bool = False
     # Request tracing (observability/tracing.py): every generate() records
     # TTFT, per-token decode latency, tokens/s, and roofline MBU into a

@@ -810,8 +810,8 @@ def prefill_tokens(model, params, input_ids, rng, *, max_new: int,
     alternative (re-materializing the whole tree in the scan body and
     hoping XLA fuses the convert) measurably did not fuse — XLA hoisted
     the loop-invariant dequant and decode re-read a bf16 copy
-    (``WOQ_PROBE.json`` round 5) — which is why the consumption sites
-    dispatch explicitly now.
+    (docs/WOQ_DECODE.md) — which is why the consumption sites dispatch
+    explicitly now.
     """
     from .sampling import split_keys
 

@@ -10,7 +10,7 @@ loop, the in-kernel design of ``csrc/transformer/inference/``) or, off-TPU
 and for kernel-ineligible leaves, by a per-use XLA dequant at the point of
 consumption. The previous whole-matrix ``dequantize_params`` hoist — which
 let XLA materialize a bf16 copy outside the decode scan and re-read *that*
-(``WOQ_PROBE.json`` round 5: int8 decode slower than bf16) — is gone from
+(int8 decode then ran no faster than bf16: docs/WOQ_DECODE.md) — is gone from
 the decode path; it survives only for the cold full-forward.
 
 Layout: groups of ``group_size`` rows along the weight's second-to-last

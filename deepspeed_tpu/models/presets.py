@@ -1,6 +1,6 @@
 """Model-family presets over the unified TransformerLM.
 
-Covers the model families exercised by the reference baselines (BASELINE.md):
+Covers the model families of the reference's published results (SURVEY.md §6):
 GPT-2 (125M/1.5B), Llama-2 (7B/13B/70B), BERT-class encoder sizes are served
 by the same trunk with ``causal=False`` planned, Mixtral via ``num_experts``.
 """

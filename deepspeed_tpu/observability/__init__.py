@@ -31,10 +31,6 @@ from .goodput import BADPUT_BUCKETS, GoodputLedger
 from .kvscope import KVScope, KVScopeConfig, measure_copy_bandwidth
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, Reservoir,
                       get_registry)
-# perf_ledger is intentionally NOT imported here: like doctor.py it is a
-# `python -m` CLI, and importing it from the package __init__ makes the
-# -m runner warn about the double module object. Import it as
-# deepspeed_tpu.observability.perf_ledger.
 from .replay import (TRACE_SCHEMA, ReplayClock, ReplayDriver, ReplayReport,
                      TrafficCapture, TrafficTrace, advisor_backtest,
                      trace_from_request_log, write_backtest_report)

@@ -649,8 +649,8 @@ def capacity_report(*, ledger: dict, census: Optional[dict] = None,
         elif rbw is None:
             why_nv = ("NVMe read bandwidth unmeasured (no verified "
                       "promotions yet) — disk restore cost unknown, "
-                      "sub-estimate degraded; see AIO_BENCH.json for "
-                      "the standalone sweep")
+                      "sub-estimate degraded; python -m "
+                      "deepspeed_tpu.ops.aio_bench sweeps the disk alone")
         elif pr is None or not ptb or mean_tok is None:
             why_nv = ("prefill/recompute cost unmeasured — cannot "
                       "price disk restore against recompute")

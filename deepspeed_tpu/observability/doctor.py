@@ -19,9 +19,6 @@ running engine, no device):
   the record half of record→replay, bundled into flight/incident dumps)
   schema-validated, plus the last replay parity verdict
   (``REPLAY_REPORT*.json`` — ``observability/replay.py``);
-- ``[perf]`` — the cross-PR perf ledger (``PERF_LEDGER.json``,
-  ``observability/perf_ledger.py``): trajectory summary and the
-  regression gate vs each series' rolling best;
 - ``[comm]`` — the communication observatory
   (``observability/commscope.py``): exposed/overlap collective
   fractions, per-kind achieved bus bandwidth, and the per-device skew
@@ -39,8 +36,7 @@ storm — something fired since the record was cut), when any
 the newest incident dir is UNRECONCILED (per-replica dumps from fewer
 replicas than the fleet had live — the post-mortem is incomplete), when
 the newest traffic trace is invalid or the last replay verdict is a
-parity FAILURE, when the perf ledger holds a series worse than its
-rolling best beyond the margin, or when a straggler gauge is burning
+parity FAILURE, or when a straggler gauge is burning
 (``dstpu_train_straggler_active`` > 0); 0 on a clean replica. ``--no-gate``
 restores the always-0 report-only behavior. ``--targets`` combined with
 ``--flight-dir`` runs the incident gate alongside fleet triage.
@@ -362,31 +358,6 @@ def report_replay(dirs) -> list:
             f"{len(div)} request(s) diverged"
             + (f" (rids {rids})" if rids else ""))
     return findings
-
-
-def report_perf(ledger_path: Path, margin: float = 0.2) -> list:
-    """Print the ``[perf]`` trajectory summary; gate findings are every
-    series whose newest point is worse than its rolling best beyond the
-    margin (``perf_ledger.check_regressions``)."""
-    from .perf_ledger import check_regressions, load_ledger, summarize
-
-    if not Path(ledger_path).is_file():
-        print(f"[perf] no ledger at {ledger_path} (run "
-              "python -m deepspeed_tpu.observability.perf_ledger)")
-        return []
-    led = load_ledger(ledger_path)
-    s = summarize(led)
-    print(f"[perf] {ledger_path}: {s['series']} series "
-          f"({s['directed_series']} directed, "
-          f"{s['series_with_history']} with history) over {s['runs']} "
-          f"run(s), last {s['last_run']}")
-    regs = check_regressions(led, margin=margin)
-    for r in regs[:8]:
-        print(f"  REGRESSION {r['series']} [{r['direction']}] "
-              f"best {r['best']:g} -> {r['last']:g} at {r['last_run']}")
-    return [f"perf regression: {r['series']} best {r['best']:g} -> "
-            f"{r['last']:g} ({r['direction']}, margin {margin:g})"
-            for r in regs]
 
 
 def report_capacity(d: Path, levers: int = 4) -> None:
@@ -1103,12 +1074,6 @@ def main(argv=None) -> int:
                          "SLO gauge, or flight why-marker gates")
     ap.add_argument("--timeout", type=float, default=3.0,
                     help="per-endpoint timeout in live mode (default 3s)")
-    ap.add_argument("--ledger", default=None,
-                    help="perf ledger path for the [perf] section "
-                         "(default <dir>/PERF_LEDGER.json)")
-    ap.add_argument("--perf-margin", type=float, default=0.2,
-                    help="relative regression margin for the [perf] gate "
-                         "(default 0.2)")
     ap.add_argument("--kv-regret-max", type=float, default=0.5,
                     help="[kv] gate: regretted share of prefill work "
                          "above this trips (default 0.5)")
@@ -1153,9 +1118,6 @@ def main(argv=None) -> int:
         findings += report_tenants(
             d, fairness_min=args.tenant_fairness_min)
         findings += report_replay([d] if fdir == d else [d, fdir])
-        ledger = Path(args.ledger) if args.ledger \
-            else d / "PERF_LEDGER.json"
-        findings += report_perf(ledger, margin=args.perf_margin)
     if findings:
         print(f"[gate] {len(findings)} finding(s):")
         for f in findings:

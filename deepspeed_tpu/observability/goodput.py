@@ -25,8 +25,8 @@ the latest observation** to exactly one bucket:
 - ``preempt`` — the SIGTERM grace window (PreemptionGuard handler);
 - ``other`` — host scheduling overhead inside a working iteration.
 
-The invariant — pinned by the fake-clock tests and the
-``bench_telemetry.py --smoke`` gate — is ``productive + sum(badput) ==
+The invariant — pinned by the fake-clock tests of
+``tests/unit/test_telemetry.py`` — is ``productive + sum(badput) ==
 wall`` to within float tolerance: attribution that doesn't sum to wall
 time is attribution that silently dropped a failure mode.
 

@@ -35,9 +35,8 @@ nothing measured yet. This module measures all three sides of it:
 
 Cost discipline, like every layer before it: everything here is
 host-side Python over arrays the scheduler already holds — zero device
-syncs, zero new compiled programs (the ``bench_serving.py --smoke`` /
-``bench_kv_residency.py --smoke`` compile-freeze gates are the
-acceptance tests). Disabled (the default) the serving engine holds
+syncs, zero new compiled programs (``tests/unit/test_kvscope.py``
+compares compile counts with the observatory on and off). Disabled (the default) the serving engine holds
 ``kvscope = None`` and the page pool ``on_evict = None``: one ``is not
 None`` per admission/retirement/eviction, nothing else. The
 copy-bandwidth probe runs only when a capacity report asks for it.

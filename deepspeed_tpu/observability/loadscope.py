@@ -34,8 +34,8 @@ Cost discipline: everything is host-side arithmetic over a bounded
 deque plus one pass over the span ring at *readout* time (scrape /
 report cadence, never per token). Disabled (the default) the serving
 engine holds ``loadscope = None`` and pays one ``is not None`` per
-submit — zero new compiled programs (the ``bench_serving.py --smoke``
-compile-freeze gate stays the acceptance test). Validation is replay-
+submit — zero new compiled programs (``tests/unit/test_loadscope.py``
+compares compile counts on and off). Validation is replay-
 backtested: :func:`~.replay.scaling_backtest` replays a synthetic
 diurnal+bursty trace on the fake clock at two fleet sizes and scores
 predicted queue-wait/goodput deltas against achieved (±10 pt band).

@@ -858,7 +858,7 @@ def advisor_backtest(trace: TrafficTrace, engine, serving: dict,
       ``CAPACITY_REPORT.json`` lever estimate when given, else the PR-6
       estimator on the trace) vs ACHIEVED prefill-tokens-saved fraction
       with the radix tree on; ``abs_error_pts`` is the headline number
-      (the ±10-point acceptance band in ``bench_replay.py --smoke``).
+      (held to ±10 points in ``tests/unit/test_replay.py``).
     - ``kv_quantization`` — predicted int8/fp KV bytes-per-token ratio
       (the ledger math) vs the achieved ledger ratio in the int8 replay.
     - ``speculative_decoding`` — predicted first-draft acceptance (the
@@ -867,7 +867,9 @@ def advisor_backtest(trace: TrafficTrace, engine, serving: dict,
       first-draft accept rate from the spec-on replay's engine
       snapshot. The what-if forces ``greedy: True`` (self-speculation
       requires it); ``speculation`` overrides the lever's config
-      (default ``{"ngram": 3, "max_draft": 4}``).
+      (default ``{"ngram": 3, "max_draft": 4}``). Where either side has
+      no draft to count (outputs of a few tokens), the entry carries
+      ``abstained`` with the reason and no ``abs_error_pts``.
     """
     from ..serving.engine import ServingEngine
 
@@ -957,6 +959,13 @@ def advisor_backtest(trace: TrafficTrace, engine, serving: dict,
                  "parity": rep.parity}
         if predicted is not None and achieved is not None:
             entry["abs_error_pts"] = abs(predicted - achieved) * 100.0
+        else:
+            # nothing to score is an answer, not an error: say which side
+            # had no draft to count
+            entry["abstained"] = (
+                "no n-gram repeats inside any recorded decode region: the "
+                "table never predicts" if predicted is None else
+                "the live drafter never proposed in the spec-on replay")
         out["levers"]["speculative_decoding"] = entry
     return out
 

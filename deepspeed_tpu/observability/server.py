@@ -44,8 +44,8 @@ set; without a token they are accepted from loopback peers only.
 
 Cost discipline: config-gated, off by default — a disabled engine
 builds no server object, spawns **zero threads**, compiles zero
-programs, and adds zero host syncs (the ``bench_serving.py --smoke``
-compile-freeze gate is the oracle). Enabled, request handling runs on
+programs, and adds zero host syncs (``tests/unit/test_telemetry.py``
+compares compile counts with the plane on and off). Enabled, request handling runs on
 daemon threads and only ever touches host-side Python state (registry
 snapshots under their own locks, scheduler tables copied defensively).
 """

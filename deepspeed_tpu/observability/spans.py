@@ -12,8 +12,8 @@ retirement, the wall-clock-breakdown timers) becomes a typed
 Cost discipline: recording is host-side floats into a deque under a
 lock — no device buffers, no host↔device syncs, no new compiled
 programs. Engines hold ``spans = None`` when the operator has not asked
-for a ring, and the ``bench_serving.py --smoke`` compile-freeze gate
-stays green. Timestamps come from the owner's injectable clock (the same
+for a ring, and a ring adds no program (``tests/unit/test_observability.py``
+compares compile counts with spans on and off). Timestamps come from the owner's injectable clock (the same
 one ``ServingStats`` fakes in tests).
 
 The ring is the substrate for two consumers: the Chrome-trace/Perfetto

@@ -31,8 +31,8 @@ admission stream, so every what-if in the capacity advisor
 
 Cost discipline: everything here is host-side Python/numpy over prompt
 arrays the scheduler already holds — O(tokens) per request, zero device
-syncs, zero new compiled programs (the ``bench_serving.py --smoke``
-compile-freeze gate stays the acceptance test). Disabled (the default)
+syncs, zero new compiled programs (``tests/unit/test_capacity.py``
+compares compile counts with the analytics on and off). Disabled (the default)
 the serving engine holds ``workload = None`` and pays one ``is not
 None`` per admission. The analyzer's own overhead is measured into
 ``Serve/workload_analysis_s`` so the capacity report carries the cost of

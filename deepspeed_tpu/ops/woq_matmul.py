@@ -4,11 +4,11 @@ The decode hot loop is HBM-bandwidth-bound on the weight re-read, and the
 reference's entire int8 inference stack (``csrc/transformer/inference/``,
 ``csrc/quantization/``) exists to cut that traffic. The repo's previous WOQ
 path stored int8 but dequantized whole matrices in XLA, which hoists the
-loop-invariant convert out of the decode scan (``WOQ_PROBE.json`` round 5:
-"hoisted/not-fused: no decode bandwidth win" — int8 decode *slower* than
-bf16). These kernels make the hoist impossible: the int8 (or nibble-packed
-int4) tiles stream HBM→VMEM, are dequantized *inside the matmul loop* on
-the VPU, and feed the MXU in the activation dtype with an fp32 accumulator.
+loop-invariant convert out of the decode scan: decode then re-read a bf16
+copy and int8 was no faster than bf16 (docs/WOQ_DECODE.md). These kernels
+make the hoist impossible: the int8 (or nibble-packed int4) tiles stream
+HBM→VMEM, are dequantized *inside the matmul loop* on the VPU, and feed
+the MXU in the activation dtype with an fp32 accumulator.
 HBM weight traffic per token drops ~2x (int8) / ~4x (int4) vs bf16 — the
 EQuARX/qwZ principle of dequantizing at the point of consumption.
 

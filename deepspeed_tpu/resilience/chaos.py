@@ -8,9 +8,7 @@ Two delivery mechanisms, both inert-by-default:
   the decode-step watchdog, flood the queue at startup. The serving
   engine only constructs a monkey when ``chaos.enabled`` is true; with
   chaos off the engine holds ``None`` and the hot path pays a single
-  ``is not None`` check — no extra host syncs, no extra programs
-  (the acceptance gate: ``bench_serving.py --smoke``'s compile freeze
-  still passes).
+  ``is not None`` check — no extra host syncs, no extra programs.
 
 - **Environment-gated** (:func:`kill_point` / :func:`preempt_step`):
   process-death faults that only make sense in a subprocess test — die
@@ -154,7 +152,7 @@ class FleetChaosConfig:
     ``kill_replica_step`` the fleet abruptly drops one live replica —
     its queued and in-flight requests requeue onto survivors with a
     typed ``REQUEUED`` transition and a bumped ``attempts`` counter (the
-    zero-request-loss oracle in ``bench_fleet.py --smoke``). The victim
+    zero-request-loss oracle of ``tests/unit/test_fleet.py``). The victim
     is ``kill_replica`` when named, else a seeded choice among the live
     replicas at that instant. ``enabled: false`` (default) builds no
     monkey — the fleet step pays one ``is not None`` check."""

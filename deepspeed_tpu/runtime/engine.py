@@ -662,8 +662,7 @@ class Engine:
         try:
             peak = peak_flops_for(self.acc.current_device()) * len(jax.devices())
         except ValueError as e:
-            # Unknown hardware must not abort training — only the MFU stat
-            # (bench.py, where MFU *is* the artifact, keeps the hard raise).
+            # Unknown hardware must not abort training — only the MFU stat.
             log_dist(f"MFU reporting disabled: {e}", level="WARNING")
             peak = 0.0
         self.throughput = ThroughputTimer(
@@ -1426,8 +1425,8 @@ class Engine:
     def compile_train_step(self, batch: dict) -> dict:
         """AOT-compile the train step and return the compiler's
         buffer-assignment summary (``*_size_in_bytes``). This is how
-        memory levers are *measured* (bench_act_offload.py, autotuner
-        feasibility): the numbers are the compiler's own."""
+        memory levers are *measured* (autotuner feasibility): the numbers
+        are the compiler's own."""
         from ..profiling.flops_profiler import compiled_memory_analysis
 
         return compiled_memory_analysis(self._compiled_step(batch))

@@ -25,8 +25,8 @@ it:
 - **a flap budget** — direction reversals (add after remove or vice
   versa) inside ``flap_window_s`` are counted; at ``flap_budget`` the
   loop FREEZES itself and alarms instead of oscillating (an
-  oscillating trace must cost at most ``flap_budget`` reversals — the
-  ``bench_autoscale.py`` flap-bait oracle);
+  oscillating trace must cost at most ``flap_budget`` reversals:
+  ``test_flap_budget_exhaustion_freezes_the_loop``);
 - **score-trust gating** — a what-if that self-demoted to 0 with a
   stated reason, an unmeasured ρ (null report / empty what-ifs), or a
   ``saturated`` forecast (the queue-wait prediction is null past the
@@ -61,11 +61,11 @@ and the fleet's incident dumps (``fleet/autoscale_audit.jsonl``).
 
 Inert by default: ``serving.autoscale=None`` builds NOTHING — the
 fleet pays one ``is not None`` per step, zero threads, zero new
-compiled programs, zero syncs (the ``bench_autoscale.py --smoke``
-compile freeze is the oracle). The loop has no thread of its own even
+compiled programs, zero syncs
+(``test_fleet_attach_inert_and_config_reject``). The loop has no thread of its own even
 when on: it piggybacks on :meth:`~.fleet.FleetEngine.step` at
 ``tick_s`` cadence on the fleet's injectable clock, so fake-clock
-chaos benches drive it deterministically.
+tests drive it deterministically.
 """
 
 from __future__ import annotations
@@ -93,7 +93,7 @@ class AutoscaleConfig:
     in the fleet clock's seconds (fake seconds under a test clock).
     Sizing guidance lives in docs/OPERATIONS.md ("running the
     autoscaler"): thresholds come from the what-if score distribution
-    in ``LOADSCOPE_BENCH.json``, cooldowns from the loadscope window,
+    of ``GET /scaling`` on your traffic, cooldowns from the loadscope window,
     the flap budget from how often you can stomach a reversal."""
 
     enabled: bool = True

@@ -33,7 +33,7 @@ are per-replica). What the fleet adds:
 - **Replica loss/join** — ``remove_replica`` / a chaos kill requeues
   the victim's queued and in-flight requests onto survivors with a
   typed ``REQUEUED`` transition and a bumped ``Request.attempts`` (zero
-  request loss — the ``bench_fleet.py --smoke`` oracle); per-request
+  request loss — ``tests/unit/test_fleet.py``'s oracle); per-request
   RNG folds from the seed, so a rerun's bits match a fresh submission.
   ``add_replica`` warms from the fleet's shared compiled-program cache:
   a joining replica serves traffic with ZERO new compiles.

@@ -1,6 +1,5 @@
-"""Train a 1.00 B-param decoder on ONE 16 GiB TPU chip — the measured
-round-5 recipe (GPT_LARGE_BENCH.json: ~0.33-0.45 MFU depending on
-attention path; see docs/TUNING.md "Remat").
+"""Train a 1.00 B-param decoder on ONE 16 GiB TPU chip (see docs/TUNING.md
+"Remat"; the benchmark's train cells, PERF.md §4, measure a step like it).
 
 The three knobs that make 1 B fit and run fast on a single v5e:
 

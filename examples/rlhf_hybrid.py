@@ -1,4 +1,5 @@
-"""RLHF PPO loop with LoRA adapters on the hybrid engine (BASELINE cfg 5).
+"""RLHF PPO loop with LoRA adapters on the hybrid engine (the reference's
+DeepSpeed-Chat configuration, SURVEY.md §6).
 
 The reference's DeepSpeed-Chat actor step (``blogs/deepspeed-chat/
 README.md:41`` + ``runtime/hybrid_engine.py:32``): rollouts generate

@@ -8,8 +8,9 @@ partitioning path compiles and executes exactly as it would across 8 chips.
 
 The tests run on the CPU backend wherever they are started: this module pins
 ``JAX_PLATFORMS=cpu`` and the eight virtual devices before jax is imported,
-and subprocesses spawned by tests (launcher tests, bench ``--smoke`` gates)
-inherit both. What only the chip can show is ``chip_smoke.py``'s job.
+and subprocesses spawned by tests (the launcher, the examples, the
+checkpoint-chaos script) inherit both. What only the chip can show is
+``chip_smoke.py``'s job.
 """
 
 import os
@@ -31,10 +32,9 @@ jax.config.update("jax_platforms", "cpu")
 # steps; cache hits cut the suite from ~40 min toward ~10.  Keyed by HLO, so
 # correctness is XLA's problem, not ours. Placed from outside where
 # JAX_COMPILATION_CACHE_DIR is set; tests/.jax_cache otherwise. The variable
-# is then set for the bench --smoke subprocess gates (test_serving /
-# test_resilience / test_paged_kv / ... spawn `python bench_*.py --smoke`
-# with `env=dict(os.environ, ...)`), which must share the same cache: without
-# it every gate recompiles its whole tiny-model program set on every run.
+# is then set so that the few tests that must start a process of their own
+# (test_examples, test_launcher, test_resilience's crash script) compile
+# into the same cache.
 os.environ["JAX_COMPILATION_CACHE_DIR"] = configure_compile_cache(
     os.path.join(os.path.dirname(__file__), ".jax_cache"))
 # threshold 0: tiny-model test programs mostly compile in <0.5s, which the

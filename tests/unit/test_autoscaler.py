@@ -23,14 +23,13 @@ Oracles:
   add_replica and replica-scoped drain edges apply on a matching
   topology and counted-skip (never crash) on a mismatched one;
 - remove_replica handoff ordering (the satellite-2 seam) is covered in
-  test_fleet.py; the end-to-end chaos arc is ``bench_autoscale.py
-  --smoke`` (the tier-1 gate at the bottom).
+  test_fleet.py;
+- the loop closed over a real fleet on a fake clock: overload -> warm
+  add with the report it fired on -> lull -> clean drain-then-remove,
+  zero loss, solo parity, SLO burn green; doctor's [autoscale] gates.
 """
 
 import json
-import os
-import subprocess
-import sys
 import types
 import urllib.request
 from collections import OrderedDict
@@ -53,8 +52,6 @@ from deepspeed_tpu.serving.autoscaler import (ACTUATED, ALARM,
                                               REMOVED_AT_DEADLINE,
                                               SUPPRESSED)
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 EOS = 7
 
 
@@ -554,21 +551,100 @@ def test_replay_topology_mismatch_is_counted_skip(setup):
         srv.close()
 
 
-# ------------------------------------------------------------------ CI gate
-def test_bench_autoscale_smoke_gate():
-    """Tier-1 wiring of ``bench_autoscale.py --smoke``: inert attach +
-    compile freeze, the warm scale-up with verbatim report inputs, the
-    clean drain-down, the mid-traffic kill latch, the flap-bait freeze,
-    SLO-green gauges through every phase, and the doctor [autoscale]
-    gates — deterministic on a fake clock, CPU-only."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_autoscale.py"),
-         "--smoke"], capture_output=True, text=True, timeout=540, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    assert row["drain_clean"] is True
-    assert row["flaps"] <= 1
-    assert row["doctor"] == {"flap_gate": 1, "stale_gate": 1, "clean": 0}
+# ------------------------------------------- the loop on a real fleet
+def test_real_fleet_scales_up_warm_then_drains_down_clean(setup):
+    """The control loop closed over a REAL fleet and its own
+    ``scaling_report()`` (the stub tests above pin each guard): declared
+    service rates on a fake clock, an overload until the add actuates,
+    then a lull until the drained replica is removed. The joined replica
+    compiles nothing; the add's decision record carries the report it
+    fired on; the removal is a clean drain; nothing is lost, every output
+    equals solo generate(), and no SLO burn gauge passes 1."""
+    _, _, _, eng = setup
+    clock = ReplayClock(dt=1e-4)
+    asc = {"tick_s": 1.0, "up_ticks": 2, "down_ticks": 2,
+           "add_score_min": 60.0, "remove_score_min": 60.0,
+           "cooldown_up_s": 3.0, "cooldown_down_s": 3.0,
+           "drain_deadline_s": 5.0, "min_replicas": 2, "max_replicas": 4}
+    fl = _fleet(eng, clock=clock, autoscale=asc, slo={"ttft_p99_s": 30.0},
+                loadscope={"window_s": 8.0})
+    # one replica serves 20 decode tokens per fake second; requests want 6
+    service = {"slots": 2, "decode_tokens_per_slot_s": 10.0,
+               "decode_tokens_per_s": 20.0, "prefill_tokens_per_s": 400.0}
+    rng = np.random.default_rng(2)
+    subs, done = {}, {}
+
+    def drive(rho, n_replicas, until, budget_s):
+        """Offer ``rho`` of ``n_replicas``' declared capacity until
+        ``until()`` holds; False if ``budget_s`` fake seconds pass first."""
+        gap = 6 / (rho * n_replicas * 20.0)
+        t_next, t_end = clock.t, clock.t + budget_s
+        while clock.t < t_end:
+            while t_next <= clock.t:
+                prompt = rng.integers(0, 256, (9,)).astype(np.int32)
+                seed = len(subs)
+                subs[fl.submit(prompt, 6, seed=seed)] = (prompt, seed)
+                t_next += gap
+            done.update((r.rid, r) for r in fl.step())
+            for e in fl.replicas.values():
+                e.loadscope.service_override = service
+            if until():
+                return True
+            clock.advance(0.02)
+        return False
+
+    try:
+        assert drive(0.96, 2, lambda: len(fl.replicas) == 3, 25.0), \
+            fl.autoscale_audit()[-3:]
+        joined = next(n for n in fl.replicas if n not in ("r0", "r1"))
+        assert fl.replicas[joined].compiles == 0
+        add = [d for d in fl.autoscale_audit()
+               if d["action"] == "add_replica"
+               and d["outcome"] == ACTUATED][-1]["inputs"]
+        assert add["fleet"]["replica_count"] == 2
+        assert add["fleet"]["rho"] is not None
+        assert add["what_if"]["action"] == "add_replica"
+        assert add["what_if"]["score"] >= asc["add_score_min"]
+
+        assert drive(0.10, 3, lambda: len(fl.replicas) == 2, 45.0), \
+            fl.autoscale_audit()[-3:]
+        outcomes = [d["outcome"] for d in fl.autoscale_audit()]
+        assert DRAIN_STARTED in outcomes and REMOVED in outcomes
+        assert REMOVED_AT_DEADLINE not in outcomes
+        while set(subs) - set(done):
+            done.update((r.rid, r) for r in fl.step())
+            clock.advance(0.02)
+        for rid, (prompt, seed) in subs.items():
+            assert done[rid].ok, (rid, done[rid].status)
+            want = np.asarray(eng.generate(
+                jnp.asarray(prompt[None]), 6, temperature=0.8, top_k=20,
+                request_seeds=[seed], cache_len=48))[0]
+            got = np.asarray(done[rid].tokens, np.int32)
+            np.testing.assert_array_equal(got, want[:len(got)])
+        for e in fl.replicas.values():
+            e.slo.score()
+            snap = e.stats.registry.snapshot()
+            assert not any(v > 1.0 for k, v in snap["gauges"].items()
+                           if k.startswith("Serve/slo_")
+                           and k.endswith("_burn"))
+            assert int(snap["counters"].get("Serve/slo_violations", 0)) == 0
+    finally:
+        fl.close()
+
+
+@pytest.mark.parametrize("frozen,stale_s,budget_left,rc", [
+    (1, 12.0, 0, 1),        # flap budget exhausted
+    (1, 4000.0, 2, 1),      # frozen by hand and forgotten
+    (0, 0.0, 2, 0),         # a clean loop is no finding
+])
+def test_doctor_autoscale_section_gates(tmp_path, capsys, frozen, stale_s,
+                                        budget_left, rc):
+    from deepspeed_tpu.observability import doctor
+
+    (tmp_path / "autoscale.prom").write_text(
+        "dstpu_fleet_autoscale_evals 50\n"
+        f"dstpu_fleet_autoscale_frozen {frozen}\n"
+        f"dstpu_fleet_autoscale_frozen_stale_s {stale_s}\n"
+        f"dstpu_fleet_autoscale_flap_budget_remaining {budget_left}\n")
+    assert doctor.main(["--dir", str(tmp_path)]) == rc
+    assert "[autoscale]" in capsys.readouterr().out

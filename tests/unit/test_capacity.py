@@ -15,14 +15,12 @@ Oracles:
 - satellites: time-weighted Serve/slot_occupancy_avg on a fake clock,
   Flight/write_errors counting failed dump artifacts, doctor capacity
   section;
-- bench_capacity.py --smoke: the tier-1 estimator/ledger/advisor gate.
+- a live engine's report: hand-computed weight/KV bytes, the advisor's
+  ranking on prefix-heavy traffic, and no program added by the analytics.
 """
 
 import json
 import math
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -38,9 +36,6 @@ from deepspeed_tpu.observability.workload import (WorkloadAnalyzer,
                                                   WorkloadConfig,
                                                   prefix_hashes,
                                                   selfspec_acceptance)
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 
 # ------------------------------------------------------- workload analytics
@@ -404,8 +399,8 @@ def test_doctor_capacity_section(tmp_path, capsys):
 # ----------------------------------------------------- serving integration
 def test_serving_workload_wiring():
     """The admission hook feeds the analyzer; disabled (default) builds
-    nothing. Program count parity between the two is the bench gate's
-    job (bench_capacity --smoke asserts the compile freeze)."""
+    nothing. Program-count parity between the two is
+    ``test_live_engine_report_hand_bytes_ranking_and_no_added_program``."""
     import jax
     import jax.numpy as jnp
 
@@ -486,15 +481,53 @@ def test_train_step_cost_census(devices):
     engine.close()
 
 
-# ------------------------------------------------------------- CI smoke
-def test_capacity_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_capacity.py --smoke``: overlap estimator
-    ±5 points, exact ledger bytes, schema-valid advisor ranking prefix
-    sharing first — deterministic on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_capacity.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+def test_live_engine_report_hand_bytes_ranking_and_no_added_program(
+        tmp_path):
+    """The capacity layer measuring a live engine, not a synthetic
+    snapshot: with ``workload`` and ``spans`` on, the same traffic builds
+    exactly the programs the plain engine builds and more traffic builds
+    none; the written report's weight and KV bytes equal the sum of the
+    parameter leaves and K + V of ``cache_layout``; on 80%-shared prompts
+    the advisor ranks prefix sharing above KV quantization; and the
+    analyzer's own cost per admission is in the report."""
+    import jax
+    import jax.numpy as jnp
+
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.inference.decode import cache_layout
+    from deepspeed_tpu.models import build_model, tiny_test
+
+    slots, max_len, n = 4, 64, 40
+    model = build_model(tiny_test(n_layer=2, d_model=64, d_ff=128, n_head=2,
+                                  max_seq=max_len))
+    eng = ds.init_inference(model, model.init(jax.random.PRNGKey(0)),
+                            {"dtype": "float32"})
+    base = {"slots": slots, "max_len": max_len, "prefill_chunk": 16,
+            "greedy": True}
+    rng = np.random.default_rng(0)
+    prefix = rng.integers(0, 256, 32).astype(np.int32)
+    prompts = [np.concatenate([prefix, rng.integers(0, 256, 8)
+                               .astype(np.int32)]) for _ in range(n + 12)]
+    plain = ds.ServingEngine(eng, base)
+    plain.serve_batch(prompts[:n], max_new_tokens=2)
+    srv = ds.ServingEngine(eng, {**base, "spans": True,
+                                 "workload": {"block": 8}})
+    srv.serve_batch(prompts[:n], max_new_tokens=2)
+    assert srv.compiles == plain.compiles
+    srv.serve_batch(prompts[n:], max_new_tokens=2)
+    assert srv.compiles == plain.compiles
+
+    path = tmp_path / "CAPACITY_REPORT.json"
+    srv.capacity_report(path=str(path))
+    rep = json.loads(path.read_text())
+    assert validate_capacity_report(rep) == []
+    shape, dt = cache_layout(model.cfg, slots, max_len, eng.compute_dtype)
+    assert rep["ledger"]["weights_bytes"] == sum(
+        leaf.size * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(eng.params))
+    assert rep["ledger"]["kv_bytes"] == \
+        2 * math.prod(shape) * jnp.dtype(dt).itemsize
+    ranked = rep["advisor"]["ranked"]
+    assert ranked.index(LEVER_PREFIX) < ranked.index(LEVER_KV_QUANT)
+    assert rep["workload"]["analysis_s"]["count"] >= n
+    assert rep["workload"]["analysis_s"]["mean"] >= 0.0

@@ -38,8 +38,6 @@ PRINT_ALLOWED = {
     "observability/doctor.py",  # ops triage CLI: the report IS its stdout
     "observability/fleet_scrape.py",  # aggregator CLI: stdout is the
                                       # merged exposition (no --out)
-    "observability/perf_ledger.py",   # ledger CLI: the regression report
-                                      # IS its stdout (doctor-style gate)
 }
 
 _BARE_PRINT = re.compile(r"^\s*print\(")

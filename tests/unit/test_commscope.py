@@ -21,26 +21,20 @@ What is pinned here:
 - the capacity advisor's quantize/overlap-collectives lever upgrades to
   the MEASURED exposed fraction when an observatory report is attached;
 - the doctor's ``[comm]`` section gates on a burning straggler gauge;
-- ``bench_commscope.py --smoke`` (the tier-1 gate) passes in a
-  subprocess.
+- a sharded training engine with the observatory on: the same programs
+  and bit-identical losses as with it off, and a CPU capture that
+  degrades to a null anatomy without raising.
 """
 
 import gzip
 import json
-import os
-import subprocess
-import sys
 
 import pytest
 
 from deepspeed_tpu.observability import commscope as C
 from deepspeed_tpu.observability import spans as S
 
-_ROOT = os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))))
-
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _fake_clock import TickClock  # noqa: E402
+from _fake_clock import TickClock
 
 
 # ---------------------------------------------------------- interval math
@@ -78,6 +72,30 @@ def test_step_anatomy_tiles_the_wall():
     assert a["exposed_comm_frac"] == pytest.approx(0.2)
     assert a["overlap_frac"] == pytest.approx(1 - 0.020 / 0.030)
     assert a["by_kind"]["all-reduce"]["exposed_s"] == pytest.approx(0.010)
+
+
+@pytest.mark.parametrize("spelling,ops,collective_ms,exposed_frac", [
+    # one flat all-reduce serialized after the backward
+    ("fused", [("fusion.bwd", 0, 60, None),
+               ("all-reduce.grads", 60, 90, "all-reduce")], 30, 0.3),
+    # 35 ms of collectives, more than fused, in buckets riding the
+    # backward: the first hidden whole, the second exposed for [60, 65),
+    # the gather bare
+    ("bucketed", [("fusion.bwd", 0, 60, None),
+                  ("fusion.bwd.tail", 65, 95, None),
+                  ("all-to-all.b0", 20, 35, "all-to-all"),
+                  ("all-to-all.b1", 55, 70, "all-to-all"),
+                  ("all-gather.b1", 95, 100, "all-gather")], 35, 0.1),
+])
+def test_anatomy_prices_what_bucketed_overlap_buys(spelling, ops,
+                                                   collective_ms,
+                                                   exposed_frac):
+    a = C.step_anatomy([C.OpSpan(n, t0 * 1e-3, t1 * 1e-3, "d0", k)
+                        for n, t0, t1, k in ops], 0.0, 0.100)
+    assert a["collective_s"] == pytest.approx(collective_ms * 1e-3)
+    assert a["exposed_comm_frac"] == pytest.approx(exposed_frac)
+    assert a["compute_s"] + a["exposed_collective_s"] + a["other_s"] \
+        == pytest.approx(a["wall_s"], abs=1e-12)
 
 
 def test_decompose_multi_device_and_window():
@@ -459,36 +477,6 @@ def test_doctor_comm_gate(tmp_path, capsys):
     assert doctor.main(["--dir", str(tmp_path)]) == 0
 
 
-# ------------------------------------------------------------- perf ledger
-def test_perf_ledger_multichip_series_and_directions(tmp_path):
-    from deepspeed_tpu.observability.perf_ledger import (
-        bench_files, direction_of, series_stem, update_ledger)
-
-    assert series_stem("MULTICHIP_r05.json") == "MULTICHIP"
-    assert series_stem("SERVING_BENCH.json") == "SERVING_BENCH"
-    assert direction_of("commscope.exposed_comm_frac") == "down"
-    assert direction_of("commscope.overlap_frac") == "up"
-    assert direction_of("by_kind.all-reduce.busbw_gbps") == "up"
-    assert direction_of("straggler_episodes") == "down"
-    (tmp_path / "MULTICHIP_r01.json").write_text(
-        json.dumps({"commscope": {"exposed_comm_frac": 0.5}}))
-    (tmp_path / "MULTICHIP_r02.json").write_text(
-        json.dumps({"commscope": {"exposed_comm_frac": 0.3}}))
-    files = bench_files(tmp_path)
-    assert [p.name for p in files] == ["MULTICHIP_r02.json"]
-    # NUMERIC round ordering: r100 beats r99 (lexicographic would not)
-    (tmp_path / "MULTICHIP_r99.json").write_text(json.dumps({"x": 1}))
-    (tmp_path / "MULTICHIP_r100.json").write_text(json.dumps({"x": 2}))
-    assert [p.name for p in bench_files(tmp_path)] == \
-        ["MULTICHIP_r100.json"]
-    (tmp_path / "MULTICHIP_r99.json").unlink()
-    (tmp_path / "MULTICHIP_r100.json").unlink()
-    led = update_ledger(tmp_path, tmp_path / "PERF_LEDGER.json")
-    ser = led["series"]["MULTICHIP/commscope.exposed_comm_frac"]
-    assert ser["direction"] == "down"
-    assert ser["points"][-1][1] == 0.3      # only the newest round
-
-
 # ----------------------------------------------------------- config + engine
 def test_commscope_config_validation():
     with pytest.raises(ValueError, match="unknown commscope"):
@@ -520,16 +508,45 @@ def test_engine_commscope_off_by_default():
     eng.close()
 
 
-# ------------------------------------------------------------- CI smoke
-def test_commscope_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_commscope.py --smoke``: fake-trace
-    tiling within 1%, exact ledger-vs-census bytes, compile freeze with
-    the observatory on, CPU null degradation, doctor gate — all
-    deterministic on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_commscope.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+def test_engine_observatory_on_adds_no_program_and_moves_no_loss(tmp_path):
+    """A sharded training engine with the observatory, spans and a
+    profiler window on takes the same number of train-step programs as one
+    without, and its losses are bit-identical. The CPU capture has no
+    device timeline: ``comm_observatory()`` answers with a null anatomy, not
+    a raise, while the ledger still carries the compiled step's collective
+    bytes."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models import build_model, tiny_test
+    from deepspeed_tpu.runtime.dataloader import (DataLoader,
+                                                  random_token_dataset)
+
+    def run(observability):
+        eng = ds.initialize({
+            "train_batch_size": 8, "seed": 0,
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 2},
+            "mesh": {"data": 4, "model": 2},
+            "observability": observability,
+        }, build_model(tiny_test(max_seq=32)))
+        data = random_token_dataset(8, seq_len=32, vocab_size=256)
+        batch = DataLoader(data, local_batch_size=8,
+                           shuffle=False).collate_fn(data)
+        return eng, [float(eng.train_batch(batch)["loss"])
+                     for _ in range(5)]
+
+    on, losses_on = run({"commscope": {"enabled": True}, "spans": True,
+                         "trace_steps": [1, 3],
+                         "trace_dir": str(tmp_path)})
+    off, losses_off = run({})
+    try:
+        assert losses_on == losses_off
+        assert on._train_step._cache_size() == \
+            off._train_step._cache_size()
+        rep = on.comm_observatory()
+        assert rep["anatomy"]["exposed_comm_frac"] is None
+        rows = rep["ledger"]["by_kind"]
+        assert rows and all(r["mbytes_per_step"] > 0 and
+                            r["busbw_gbps"] is None for r in rows.values())
+    finally:
+        on.close()
+        off.close()

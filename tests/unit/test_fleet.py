@@ -17,12 +17,10 @@ Oracles:
 - disaggregated prefill/decode page handoff is bit-identical to a
   single engine;
 - doctor --targets fleet triage gates on down replicas;
-- bench_fleet.py --smoke: the tier-1 chaos/parity gate.
+- the whole arc at once: a decode replica killed mid-traffic on a traced
+  disaggregated fleet (zero loss, parity, frozen compiles, hops sum to
+  e2e, audited requeues, one valid merged trace).
 """
-
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -35,9 +33,6 @@ from deepspeed_tpu.observability.export import request_record
 from deepspeed_tpu.serving import (FleetEngine, QueueFullError,
                                    RequestStatus)
 from _fake_clock import TickClock
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 M = 48          # per-replica slot capacity across these tests
 EOS = 7
@@ -843,14 +838,64 @@ def test_remove_replica_repumps_victim_owned_handoffs(setup):
         fleet.close()
 
 
-# ------------------------------------------------------------------- smoke
-def test_fleet_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_fleet.py --smoke``: chaos-kill zero-loss
-    + frozen compiles + warm join + disaggregated parity on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_fleet.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+# ------------------------------------------------ the whole failover arc
+def test_decode_kill_mid_traffic_traced_end_to_end(setup):
+    """One arc, on the fake clock, that the tests above hold in pieces: a
+    traced disaggregated fleet loses a decode replica while it is decoding
+    handed-off requests. Nothing is lost and every output still equals solo
+    generate(); the survivors compile nothing through the kill and the
+    requeue; every request's hops — the requeued ones' too — sum to its
+    e2e within 1%; the audit has one requeue entry per requeued request;
+    and the merged trace is valid with flows across replicas and at least
+    router + two replica pids."""
+    from deepspeed_tpu.observability import validate_chrome_trace
+
+    _, _, _, eng = setup
+    fleet = _fleet(eng, replicas=3, clock=TickClock(), prefill_replicas=1,
+                   serving={"page_size": 8, "spans": True})
+    try:
+        # every program the arc needs is built before the kill: the
+        # second pass hits the prefix tree, as a requeued prefill will
+        for _ in range(2):
+            _drive(fleet, [fleet.submit(p, 5, seed=240 + i)
+                           for i, p in enumerate(_prompts(6, seed=14))])
+        warm = {n: e.compiles for n, e in fleet.replicas.items()}
+        prompts = _prompts(6, seed=15)
+        rids = [fleet.submit(p, 5, seed=250 + i, session_id=f"s{i % 3}")
+                for i, p in enumerate(prompts)]
+        done, it = {}, 0
+        while len(done) < len(rids):
+            for req in fleet.step():
+                done[req.rid] = req
+            if "d1" in fleet.replicas and fleet.replicas["d1"].sched.running:
+                fleet.kill_replica("d1")
+            it += 1
+            assert it < 50_000, "fleet driver stuck"
+        assert "d1" not in fleet.replicas, "d1 never decoded: no kill"
+        requeued = [r for r in rids if done[r].attempts > 0]
+        counters = fleet.registry.snapshot()["counters"]
+        assert requeued and int(counters["Fleet/requeued"]) == len(requeued)
+        for i, rid in enumerate(rids):
+            assert done[rid].status is RequestStatus.OK
+            got = np.asarray(done[rid].tokens, np.int32)
+            np.testing.assert_array_equal(
+                got, _solo(eng, prompts[i], 5, 250 + i)[:len(got)])
+            hops = fleet.request_trace(rid)["hops"]
+            parts = sum(hops[f"{k}_s"] or 0.0 for k in (
+                "queue_wait", "prefill", "handoff_wait", "import",
+                "decode"))
+            assert parts == pytest.approx(hops["e2e_s"], rel=0.01), rid
+            assert all(e["candidates"] for e in fleet.route_audit(rid))
+        assert {n: e.compiles for n, e in fleet.replicas.items()} \
+            == {n: warm[n] for n in fleet.replicas}
+        moves = [e for e in fleet.route_audit()
+                 if e["event"] in ("requeue", "requeue_shed")]
+        assert sorted(e["rid"] for e in moves) == sorted(requeued)
+        merged = fleet.merge_trace()
+        assert validate_chrome_trace(merged) == []
+        evs = merged["traceEvents"]
+        flows = [e for e in evs if e["ph"] in ("s", "t", "f")]
+        assert len({e["pid"] for e in flows}) >= 2
+        assert len({e["pid"] for e in evs if e["ph"] != "M"}) >= 3
+    finally:
+        fleet.close()

@@ -16,12 +16,11 @@ Oracles:
   tree-held = usable, tier bytes = sum of entries <= budget);
 - inert-by-default: ``host_pool_bytes=0`` compiles exactly the plain
   paged program set; config validation refuses a tier without paging;
-- bench_host_kv.py --smoke: the tier-1 parity/TTFT/doctor gate.
+- the warm tier end to end: a bounded added program set and no compile
+  under further restores, the advisor's achieved block and the ledger's
+  tier bytes, corrupt/lost disk copies counted and recomputed; doctor's
+  [kv] tier verdicts.
 """
-
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -34,9 +33,6 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models import build_model, tiny_test
 from deepspeed_tpu.serving import FleetEngine
 from deepspeed_tpu.serving.hostkv import HostKVTier
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 PS = 8          # page size
 P = 32          # prompt length (page-aligned: 4 full blocks)
@@ -402,17 +398,84 @@ def test_router_ranks_host_tier_residency(setup):
     fleet_off.close()
 
 
-# --------------------------------------------------------------- CI smoke
-def test_host_kv_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_host_kv.py --smoke``: fp parity vs
-    recompute + solo generate, zero-regret restore A/B, resume-TTFT
-    restore-beats-recompute (or stated CPU degrade), compile freeze,
-    advisor achieved rows, doctor host-tier verdict — deterministic on
-    CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_host_kv.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+# ------------------------------------- the warm tier, end to end
+def test_warm_tier_program_set_advisor_and_corrupt_disk(setup, tmp_path):
+    """What the tests above leave open, on one pair of engines. The tier
+    adds a bounded program set (``demote``, ``restore`` and the short final
+    bucket a near-whole restore plans) and a warm tiered engine compiles
+    nothing under further restores. The capacity report shows what the
+    tier achieved beside its projection, and its bytes in the HBM ledger.
+    With the disk rung below a host rung too small for one request,
+    truncated, overwritten and unlinked files become counted fallbacks:
+    the resume recomputes and its tokens equal the tierless engine's."""
+    _cfg, _model, _params, eng = setup
+    plain = ds.ServingEngine(eng, _scfg(host=False))
+    _cycle(plain, rounds=2)
+    srv = ds.ServingEngine(eng, _scfg(host=True))
+    _cycle(srv, rounds=3)
+    assert set(srv._programs) - set(plain._programs) == \
+        {"demote", "restore", ("final", 8)}
+    warm = srv.compiles
+    _cycle(srv, rounds=2)
+    assert srv.compiles == warm
+
+    hs = srv.hostkv.snapshot()
+    rep = srv.capacity_report(census=False)
+    lever = {l["name"]: l for l in rep["advisor"]["levers"]}["tiered_kv"]
+    achieved = lever["estimate"]["achieved"]
+    assert achieved["restores"] == hs["restores"] > 0
+    assert achieved["restored_tokens"] == hs["restored_tokens"]
+    assert "host tier ACTIVE" in lever["why"]
+    assert rep["ledger"]["kv_host_tier_bytes"] == hs["bytes"]
+
+    page_bytes = hs["bytes"] // hs["pages"]
+    nv = ds.ServingEngine(eng, {
+        **_scfg(host=False), "host_pool_bytes": page_bytes,
+        "nvme_pool_bytes": 64 << 20, "nvme_path": str(tmp_path)})
+    try:
+        assert _cycle(nv, rounds=2) == _cycle(
+            ds.ServingEngine(eng, _scfg(host=False)), rounds=2)
+        assert nv.nvmekv.snapshot()["fallbacks"] == 0
+        nv.nvmekv.flush()                   # settle the write-behind
+        files = sorted(tmp_path.rglob("*.bin"))
+        assert len(files) >= 3, files
+        for n, f in enumerate(files):
+            if n % 2:
+                with open(f, "r+b") as fh:   # a torn write
+                    fh.truncate(max(1, f.stat().st_size // 2))
+            else:
+                with open(f, "r+b") as fh:   # bit rot
+                    fh.write(b"\xff" * 64)
+        # and one lost file, through the store so that its cached
+        # descriptor cannot serve the dead inode
+        nv.nvmekv.store.unlink(nv.nvmekv._file(next(iter(
+            nv.nvmekv.entries))))
+        A, _B = _prompts()
+        assert _run_one(nv, A, 1003, "sa").tokens == \
+            _run_one(plain, A, 1003, "sa").tokens
+        assert nv.nvmekv.snapshot()["fallbacks"] >= 1
+    finally:
+        nv.nvmekv.close()
+
+
+@pytest.mark.parametrize("prom,rc", [
+    ("dstpu_serve_host_tier_pages 4\ndstpu_serve_host_tier_fallbacks 3\n",
+     1),
+    ("dstpu_serve_host_tier_pages 4\ndstpu_serve_host_tier_fallbacks 0\n"
+     "dstpu_serve_host_tier_restores 12\n", 0),
+    ("dstpu_serve_nvme_tier_pages 6\ndstpu_serve_nvme_tier_fallbacks 2\n",
+     1),
+    ("dstpu_serve_nvme_tier_pages 6\ndstpu_serve_nvme_aio_errors 1\n", 1),
+    ("dstpu_serve_nvme_tier_pages 6\ndstpu_serve_nvme_tier_promotions 9\n"
+     "dstpu_serve_nvme_tier_fallbacks 0\n", 0),
+], ids=["host-fallbacks", "host-clean", "nvme-fallbacks", "nvme-aio-errors",
+        "nvme-clean"])
+def test_doctor_kv_tier_verdicts(tmp_path, capsys, prom, rc):
+    """Lost or corrupt tier copies and AIO transport errors trip
+    ``doctor``'s [kv] section; a tier that spills and restores cleanly
+    does not."""
+    from deepspeed_tpu.observability import doctor
+
+    (tmp_path / "tier.prom").write_text(prom)
+    assert doctor.main(["--dir", str(tmp_path)]) == rc
+    assert "tier verdict" in capsys.readouterr().out

@@ -20,13 +20,9 @@ Oracles:
 - fleet: a regretted resume on the session's sticky replica counts
   Fleet/affinity_regret;
 - doctor [kv]: runaway-regret gate trip/clean;
-- bench_kv_residency.py --smoke: the tier-1 gate subprocess.
+- a live paged engine: exact regret on forced-eviction traffic, the
+  measured tiered_kv ranking, and no program added by the observatory.
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -39,8 +35,6 @@ from deepspeed_tpu.observability.metrics import MetricsRegistry
 from deepspeed_tpu.observability.workload import (WorkloadAnalyzer,
                                                   token_hash)
 from deepspeed_tpu.serving.pages import PagePool
-
-_ROOT = os.path.join(os.path.dirname(__file__), "..", "..")
 
 
 class _Req:
@@ -450,18 +444,70 @@ def test_doctor_kv_gate_clean_and_threshold(tmp_path, capsys):
     capsys.readouterr()
 
 
-# ------------------------------------------------------------- CI smoke
-def test_kv_residency_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_kv_residency.py --smoke``: exact regret
-    on forced-eviction traffic, measured tiered_kv advisor ranking,
-    compile-freeze with kvscope on, doctor [kv] gate — deterministic on
-    CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_kv_residency.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    assert row["regret_tokens"] == row["hand_expected"]
+# --------------------------------------------------- a live engine
+def test_live_engine_regret_advisor_and_no_added_program():
+    """The observatory on a live paged engine (the tests above drive the
+    host-only hooks). Two page-aligned sessions cycle through a pool that
+    holds one request: each resubmission re-pays P - 1 prefill tokens and
+    the ghost ledger says exactly that; the advisor, fed by the measured
+    regret, the copy-bandwidth probe and the span ring's prefill timings,
+    ranks ``tiered_kv`` first. On an unpressured pool the same traffic
+    books no regret and the lever scores 0 with its reason. The engine
+    with kvscope and spans on builds exactly the programs it builds with
+    both off, and more traffic builds none."""
+    import jax
+    import jax.numpy as jnp
+
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.models import build_model, tiny_test
+    from deepspeed_tpu.observability.capacity import validate_capacity_report
+
+    P, ps, max_new, rounds = 32, 8, 8, 2
+    model = build_model(tiny_test(max_seq=64, dtype=jnp.float32))
+    eng = ds.init_inference(model, model.init(jax.random.PRNGKey(0)),
+                            {"dtype": "float32"})
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, 256, (P,)).astype(np.int32)
+               for _ in range(2)]
+
+    def cycle(pool_pages, observed):
+        srv = ds.ServingEngine(eng, {
+            "slots": 2, "max_len": 64, "prefill_chunk": 16, "greedy": True,
+            "page_size": ps, "pool_pages": pool_pages, **(
+                {"spans": True, "kvscope": {"dead_after_s": 3600.0}}
+                if observed else {})})
+        for r in range(rounds):
+            for sid, prompt in enumerate(prompts):
+                srv.serve_batch([prompt], [max_new], [1000 * sid + r],
+                                session_ids=[f"s{sid}"])
+        return srv
+
+    one_request = 1 + (P + max_new - 1 + ps - 1) // ps
+    srv = cycle(one_request, observed=True)
+    snap = srv.kvscope.snapshot()
+    assert snap["regret"]["regret_tokens"] == 2 * (rounds - 1) * (P - 1)
+    assert snap["sessions"]["resumed"] == 2
+    assert snap["sessions"]["regret_resumes"] == 2
+    pool = srv.pool.snapshot()
+    assert pool["eviction_events"] == 3 and pool["pages_evicted"] == 12
+    rep = srv.capacity_report(census=False)
+    assert validate_capacity_report(rep) == []
+    lever = {l["name"]: l for l in rep["advisor"]["levers"]}["tiered_kv"]
+    assert rep["advisor"]["ranked"][0] == "tiered_kv" and lever["score"] > 0
+    assert lever["estimate"]["copy_h2d_gbps"] is not None
+    assert lever["estimate"]["measured_recompute_s_per_resume"] is not None
+    assert "kv_idle_resident_bytes" in rep["ledger"]
+
+    roomy = cycle(0, observed=True)           # 0: the pool sizes itself
+    assert roomy.kvscope.snapshot()["regret"]["regret_tokens"] == 0
+    assert roomy.pool.snapshot()["eviction_events"] == 0
+    quiet = {l["name"]: l for l in roomy.capacity_report(census=False)
+             ["advisor"]["levers"]}["tiered_kv"]
+    assert quiet["score"] == 0.0 and "no eviction regret" in quiet["why"]
+
+    assert cycle(one_request, observed=False).compiles == srv.compiles
+    warm = srv.compiles
+    for sid, prompt in enumerate(prompts):
+        srv.serve_batch([prompt], [max_new], [77 + sid],
+                        session_ids=[f"s{sid}"])
+    assert srv.compiles == warm

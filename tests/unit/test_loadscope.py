@@ -30,13 +30,12 @@ Oracles:
 - replay trace generator: deterministic under a seed, rate-shaped,
   validated inputs;
 - doctor [load]: sustained-overload gate trip / clean / --no-gate;
-- bench_loadscope.py --smoke: the tier-1 gate subprocess.
+- a live engine's measured rho in the capacity lever, and
+  ``scaling_backtest``: predicted goodput and queue wait within the band
+  of fake-clock replays at two fleet sizes.
 """
 
 import json
-import os
-import subprocess
-import sys
 import urllib.request
 from urllib.error import HTTPError
 
@@ -60,9 +59,6 @@ from deepspeed_tpu.observability.expfmt import (exposition_from_events,
 from deepspeed_tpu.observability.fleet_scrape import FleetScraper
 from deepspeed_tpu.serving import FleetEngine
 from _fake_clock import TickClock
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 EOS = 7
 
@@ -513,18 +509,42 @@ def test_doctor_load_gate_clean_paths(tmp_path, capsys):
     capsys.readouterr()
 
 
-# ------------------------------------------------------------- CI smoke
-def test_loadscope_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_loadscope.py --smoke``: estimator math,
-    measured-rho path, degradation matrix, compile-freeze inertness,
-    the two-fleet-size replay backtest inside the +-10 pt band, and the
-    doctor [load] gate — deterministic on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_loadscope.py"),
-         "--smoke"], capture_output=True, text=True, timeout=540, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
-    row = json.loads(out.stdout.strip().splitlines()[-1])
-    assert row["backtest_pass"] is True
+# ---------------------------------------- measured, then backtested
+def test_measured_rho_feeds_the_lever_and_backtest_holds_at_two_sizes(setup):
+    """The path the estimator tests above feed by hand, on a live engine:
+    with spans on, served traffic yields a measured rho, per-slot decode
+    rate and an add_replica what-if, and the capacity report's ``scaling``
+    lever carries that same rho. Then the advisor is held to its own
+    predictions: ``scaling_backtest`` replays a diurnal, bursty trace on a
+    fake clock at fleet sizes 1 and 2, and predicted goodput and queue
+    wait stay within its tolerance of what the replays achieved."""
+    from deepspeed_tpu.observability.capacity import (LEVER_SCALING,
+                                                      validate_capacity_report)
+    from deepspeed_tpu.observability.replay import scaling_backtest
+
+    _, _, _, eng = setup
+    srv = _serving(eng, loadscope={"window_s": 3600.0}, spans=True)
+    try:
+        _run_all(srv, n=6, max_new=8)
+        snap = srv.scaling_snapshot()
+        assert snap["utilization"]["rho"] is not None, snap["unmeasured"]
+        assert snap["service"]["decode_tokens_per_slot_s"] is not None
+        assert any(w["action"] == "add_replica" for w in snap["what_ifs"])
+        rep = srv.capacity_report(census=False)
+        assert validate_capacity_report(rep) == []
+        lever = [lv for lv in rep["advisor"]["levers"]
+                 if lv["name"] == LEVER_SCALING][0]
+        assert lever["estimate"]["rho"] == snap["utilization"]["rho"]
+    finally:
+        srv.close()
+
+    bt = scaling_backtest(eng, {"slots": 2, "max_len": 32,
+                                "prefill_chunk": 8, "greedy": True},
+                          sizes=(1, 2), requests_target=40, prompt_len=6,
+                          max_new=8, seed=5)
+    assert [s["replicas"] for s in bt["sizes"]] == [1, 2]
+    for s in bt["sizes"]:
+        assert s["goodput_error_pts"] <= bt["tolerance_pts"], s
+        assert s["wait_error_pts"] <= bt["tolerance_pts"], s
+    assert bt["pass"] is True
+    assert bt["runs"]["1"]["rho"] > bt["runs"]["2"]["rho"]

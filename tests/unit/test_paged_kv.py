@@ -10,12 +10,9 @@ Oracles:
 - allocator/tree invariants: refcounts, LRU eviction, COW pinning,
   typed PagePoolExhausted at submit, defer-then-admit-after-retirement
   on a fake clock — the OOM-shaped mid-decode crash is unreachable;
-- bench_paged_kv.py --smoke: the tier-1 sharing/quant/parity gate.
+- multi-turn sessions: sharing at least halves the prefill tokens paid,
+  within 5 points of the workload estimator's prediction.
 """
-
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -24,15 +21,14 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.inference.decode import (PagedKVCache, cache_layout,
+                                            dequantize_kv,
+                                            forward_with_cache, init_cache,
                                             quantize_kv)
 from deepspeed_tpu.models import build_model, tiny_test
 from deepspeed_tpu.serving import (PagePool, PagePoolExhausted,
                                    RadixPrefixTree, RequestStatus,
                                    plan_chunks)
 from deepspeed_tpu.serving.pages import init_paged_slots
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 M = 48          # slot capacity used across these tests
 PS = 8          # page size
@@ -241,9 +237,20 @@ def test_paged_cow_multiturn_parity(setup):
 
 
 def test_paged_int8_greedy_short_context_parity(setup):
-    """The int8-KV oracle: greedy tokens match fp exactly on short
-    contexts (quantization noise below the argmax margin), and the
-    ledger's per-token KV cost at least halves."""
+    """The int8-KV oracle: greedy tokens equal the fp engine's on short
+    contexts, and the ledger's per-token KV cost at least halves.
+
+    Equality is the claim wherever the argmax has a margin. On a random
+    tiny model it sometimes has none: logits span about +-0.5 and two
+    tokens can stand 5e-4 apart, inside what rounding K and V to int8
+    moves them. A first difference is excused only there: the fp logits
+    at that position must put the fp token and the int8 token closer
+    together than the error of an int8 cache measured at that position,
+    by running the same context over the fp cache and over that cache
+    rounded through ``quantize_kv``. That measurement never touches the
+    paged engine, so a defect in its int8 path cannot excuse itself;
+    after a first difference the two runs have different contexts and
+    are not compared further."""
     cfg, model, params, eng = setup
     rng = np.random.default_rng(5)
     reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), N, 0)
@@ -251,8 +258,32 @@ def test_paged_int8_greedy_short_context_parity(setup):
     srv_c, base = _serve(eng, reqs, {"greedy": True})
     srv_q, outs = _serve(eng, reqs, {"greedy": True, "page_size": PS,
                                      "kv_quant_bits": 8})
-    for i, (a, b) in enumerate(zip(base, outs)):
-        np.testing.assert_array_equal(a, b, err_msg=f"req {i}")
+
+    def through_int8(x):
+        q, scale = quantize_kv(x, axis=-2)
+        return dequantize_kv(q, scale, x.dtype, axis=-2)
+
+    for i, (fp, q8) in enumerate(zip(base, outs)):
+        assert len(fp) == len(q8), f"req {i}"
+        if np.array_equal(fp, q8):
+            continue
+        pos = int(np.nonzero(fp != q8)[0][0])
+        ctx = jnp.asarray(np.concatenate([reqs[i][0], fp[:pos]])[None])
+        _, cache = forward_with_cache(model, params, ctx[:, :-1],
+                                      init_cache(cfg, 1, M, jnp.float32))
+        cache = cache._replace(length=jnp.int32(ctx.shape[1] - 1))
+        exact = np.asarray(forward_with_cache(
+            model, params, ctx[:, -1:], cache)[0][0, -1])
+        rounded = np.asarray(forward_with_cache(
+            model, params, ctx[:, -1:], cache._replace(
+                k=through_int8(cache.k), v=through_int8(cache.v)))[0][0, -1])
+        assert int(exact.argmax()) == fp[pos], f"req {i}"
+        margin = exact[fp[pos]] - exact[q8[pos]]
+        noise = np.abs(rounded - exact).max()
+        assert margin <= noise, (
+            f"req {i} position {pos}: int8 chose {q8[pos]} over {fp[pos]} "
+            f"across a margin of {margin:.2e}, int8 cache error {noise:.2e}"
+            ": not a near-tie")
     led_q, led_c = srv_q.hbm_ledger(), srv_c.hbm_ledger()
     assert 2 * led_q["kv_per_token_bytes"] <= led_c["kv_per_token_bytes"]
     assert led_q["kv_quant_bits"] == 8
@@ -387,15 +418,34 @@ def test_paged_flight_snapshot_and_capacity_report(setup, tmp_path):
     assert kv["score"] == 0.0
 
 
-# ------------------------------------------------------------- CI smoke
-def test_paged_kv_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_paged_kv.py --smoke``: parity + frozen
-    compiles + >= 2x prefill reduction + estimator agreement + int8 KV
-    byte halving must pass on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_paged_kv.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+# ------------------------------------------- what sharing is worth
+def test_multiturn_sharing_halves_prefill_as_the_estimator_predicts(setup):
+    """Chat-shaped traffic, counted: four sessions open on one system
+    prompt and every turn replays its conversation so far. The paged
+    engine pays for at most half the prompt tokens the unshared engine
+    pays for, and the share it saves is within 5 points of what the
+    workload estimator predicts from the same admission stream (the
+    capacity advisor's prefix-sharing lever, closed against the thing it
+    advises)."""
+    cfg, model, params, eng = setup
+    srv = ds.ServingEngine(eng, {
+        "slots": 3, "max_len": M, "prefill_chunk": 16, "temperature": 0.8,
+        "top_k": 20, "page_size": PS, "pool_pages": 64,
+        "workload": {"block": PS}})
+    rng = np.random.default_rng(3)
+    system = rng.integers(0, 256, (16,)).astype(np.int32)
+    history = [system] * 4
+    prompt_tokens = 0
+    for turn, user in enumerate((8, 5, 5)):     # prompts of 24, 32, 40
+        prompts = [np.concatenate([h, rng.integers(0, 256, (user,))
+                                   .astype(np.int32)]) for h in history]
+        prompt_tokens += sum(len(p) for p in prompts)
+        replies = srv.serve_batch(prompts, 3,
+                                  [10 * turn + s for s in range(4)])
+        history = [np.concatenate([p, r])
+                   for p, r in zip(prompts, replies)]
+    saved = srv.pool.snapshot()["prefill_tokens_saved"]
+    assert prompt_tokens >= 2 * (prompt_tokens - saved), \
+        (prompt_tokens, saved)
+    assert saved / prompt_tokens == pytest.approx(
+        srv.workload.prefix_overlap, abs=0.05)

@@ -1,5 +1,4 @@
-"""Traffic capture & deterministic replay (observability/replay.py) +
-the cross-PR perf ledger (observability/perf_ledger.py).
+"""Traffic capture & deterministic replay (observability/replay.py).
 
 Oracles:
 - trace schema: round-trips through JSONL byte-stable, the validator
@@ -16,17 +15,14 @@ Oracles:
   and counted;
 - backtest: the advisor's prefix-sharing prediction on synthetic
   80%-overlap traffic scores within ±10 points of achieved savings;
-- perf ledger: bench JSONs normalize into directed series, the
-  regression gate trips on an injected regression and passes clean,
-  the CLI and the doctor's [perf]/[replay] sections gate the same way;
-- bench_replay.py --smoke: the tier-1 capture/replay/backtest gate.
+  a captured multi-turn run backtests from the live capacity report,
+  and the speculation lever scores where replies repeat and abstains,
+  with its reason, where they are too short to;
+- the doctor's [replay] section gates on a failed parity report and on
+  an invalid trace.
 """
 
-import copy
 import json
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +32,6 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import build_model, tiny_test
 from deepspeed_tpu.observability import doctor
-from deepspeed_tpu.observability import perf_ledger as pl
 from deepspeed_tpu.observability.export import request_record
 from deepspeed_tpu.observability.replay import (ReplayClock, ReplayDriver,
                                                 TrafficCapture,
@@ -44,9 +39,6 @@ from deepspeed_tpu.observability.replay import (ReplayClock, ReplayDriver,
                                                 advisor_backtest,
                                                 resolve_prompt,
                                                 trace_from_request_log)
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 M = 48
 EOS = 510
@@ -509,97 +501,70 @@ def test_advisor_backtest_scores_synthetic_overlap(setup):
     assert bt["trace"]["requests"] == 8
 
 
-# -------------------------------------------------------------- perf ledger
-def _bench_dir(tmp_path, n=5, scale=1.0):
-    d = tmp_path / "benches"
-    d.mkdir(exist_ok=True)
-    for i in range(n):
-        (d / f"FAKE{i}_BENCH.json").write_text(json.dumps({
-            "workload": {"requests": 8},
-            "run": {"wall_s": (2.0 + i) / scale,
-                    "tokens_per_s": 100.0 * (i + 1) * scale,
-                    "ttft_s": {"count": 8, "p50": 0.5 / scale,
-                               "p99": 1.0 / scale},
-                    "verdict": "smoke-pass"},
-        }))
-    return d
+def test_backtest_of_captured_sessions_scores_or_abstains(setup):
+    """The advisor held to a captured run, with the live capacity report
+    as the source of its predictions. On multi-turn session traffic the
+    prefix-sharing lever lands within 10 points of the tokens the radix
+    tree saved in replay and int8 KV at least halves the ledger's bytes
+    per token. That trace's replies are 3 tokens long: no n-gram repeats
+    inside them, the spec-on replay proposes no draft, and the
+    speculation lever says so (``abstained``) instead of scoring nothing
+    against nothing. On replies long enough to repeat, the n-gram
+    estimate lands within 10 points of the live first-draft accept rate.
+    Every what-if replay reproduces the recorded tokens."""
+    _, _, _, eng = setup
+    base = {"slots": 2, "max_len": M, "prefill_chunk": 16, "greedy": True}
 
+    def capturing():
+        return ds.ServingEngine(eng, {**base, "capture": True,
+                                      "page_size": 8,
+                                      "workload": {"block": 8}},
+                                clock=ReplayClock(dt=1e-3))
 
-def test_ledger_direction_inference():
-    assert pl.direction_of("continuous.tokens_per_s") == "up"
-    assert pl.direction_of("run.wall_s") == "down"
-    assert pl.direction_of("continuous.ttft_s.p99") == "down"
-    assert pl.direction_of("continuous.ttft_s.count") is None
-    assert pl.direction_of("workload.requests") is None
-    assert pl.direction_of("paged.prefill_tokens_saved") == "up"
-    assert pl.direction_of("paged.prefill_tokens_paid") == "down"
-    assert pl.direction_of("kv_per_token_bytes") == "down"
-    assert pl.direction_of("goodput_speedup_wall") == "up"
-    assert pl.direction_of("failover.requeued") is None
+    def captured(srv):
+        out = srv.capture.trace(), srv.capacity_report(census=False)
+        srv.close()
+        return out
 
-
-def test_ledger_normalize_skips_non_numeric(tmp_path):
-    d = _bench_dir(tmp_path, n=1)
-    rows = pl.normalize_bench(d / "FAKE0_BENCH.json")
-    assert "run.wall_s" in rows and rows["run.wall_s"][1] == "down"
-    assert "run.verdict" not in rows          # strings skipped
-    torn = d / "TORN_BENCH.json"
-    torn.write_text('{"a": ')
-    assert pl.normalize_bench(torn) == {}     # degrade, never raise
-
-
-def test_ledger_update_and_regression_gate(tmp_path):
-    d = _bench_dir(tmp_path, n=5)
-    out = tmp_path / "PERF_LEDGER.json"
-    led = pl.update_ledger(d, out)
-    assert led["ingested"]["benches"] == 5
-    assert pl.check_regressions(led) == []            # one point: clean
-    led = pl.update_ledger(d, out)                    # same values again
-    assert len(led["runs"]) == 2
-    assert pl.check_regressions(led) == []            # flat: clean
-    # worsen the benches 2x and ingest run 3: the gate trips on every
-    # directed series, worst first
-    _bench_dir(tmp_path, n=5, scale=0.5)
-    led = pl.update_ledger(d, out)
-    regs = pl.check_regressions(led, margin=0.2)
-    assert regs, "2x regression did not trip"
-    assert any(r["series"].endswith("run.tokens_per_s") for r in regs)
-    assert any(r["series"].endswith("run.wall_s") for r in regs)
-    assert all(r["rel_excess"] > 0 for r in regs)
-    # a wide margin swallows it; the margin is the knob
-    assert pl.check_regressions(led, margin=2.0) == []
-    # history bounded — and default run labels stay UNIQUE past the
-    # bound (the label derives from a monotonic counter, not the
-    # trimmed runs list)
+    srv = capturing()
+    rng = np.random.default_rng(3)
+    history = [rng.integers(0, 256, (16,)).astype(np.int32)] * 3
     for _ in range(3):
-        led = pl.update_ledger(d, out, max_points=4)
-    assert all(len(s["points"]) <= 4 for s in led["series"].values())
-    assert len(led["runs"]) <= 4
-    labels = [r["run"] for r in led["runs"]]
-    assert len(set(labels)) == len(labels)
-    assert led["runs"][-1]["run"] == f"r{led['run_seq']}"
+        prompts = [np.concatenate([h, rng.integers(0, 256, (5,))
+                                   .astype(np.int32)]) for h in history]
+        replies = srv.serve_batch(prompts, 3, [0, 1, 2])
+        history = [np.concatenate([p, r]) for p, r in zip(prompts, replies)]
+    trace, report = captured(srv)
+    assert trace.validate() == [] and len(trace.results) == 9
+    bt = advisor_backtest(trace, eng, base, capacity_report=report,
+                          levers=("prefix_sharing", "kv_quantization",
+                                  "speculative_decoding"), page_size=8)
+    ps = bt["levers"]["prefix_sharing"]
+    assert ps["source"] == "capacity_report" and ps["abs_error_pts"] <= 10
+    assert bt["levers"]["kv_quantization"]["achieved"] <= 0.5
+    sd = bt["levers"]["speculative_decoding"]
+    assert sd["predicted"] is None and sd["achieved"] is None
+    assert "never predicts" in sd["abstained"] and "abs_error_pts" not in sd
+    assert sd["what_if"]["speculation"]["proposed_tokens"] == 0
+    assert all(lv["parity"] is True for lv in bt["levers"].values())
 
-
-def test_ledger_cli_gates(tmp_path, capsys):
-    d = _bench_dir(tmp_path, n=5)
-    out = tmp_path / "PERF_LEDGER.json"
-    assert pl.main(["--root", str(d), "--out", str(out)]) == 0
-    _bench_dir(tmp_path, n=5, scale=0.5)              # 2x worse
-    assert pl.main(["--root", str(d), "--out", str(out)]) == 1
-    cap = capsys.readouterr().out
-    assert "regression(s) vs rolling best" in cap
-    # --no-gate reports but exits 0; --check-only does not add a run
-    assert pl.main(["--root", str(d), "--out", str(out),
-                    "--check-only", "--no-gate"]) == 0
-    runs = json.loads(out.read_text())["runs"]
-    assert len(runs) == 2
+    srv = capturing()
+    srv.serve_batch([np.full((12,), t, np.int32) for t in range(1, 7)], 30,
+                    list(range(6)))
+    trace, _ = captured(srv)
+    sd = advisor_backtest(trace, eng, base,
+                          levers=("speculative_decoding",),
+                          page_size=8)["levers"]["speculative_decoding"]
+    assert sd["source"] == "ngram_estimator" and sd["parity"] is True
+    assert sd["what_if"]["speculation"]["proposed_tokens"] > 0
+    assert "abstained" not in sd and sd["abs_error_pts"] <= 10
 
 
 # ------------------------------------------------------------------ doctor
-def test_doctor_replay_and_perf_sections(tmp_path, capsys):
+def test_doctor_replay_section(tmp_path, capsys):
     d = tmp_path / "monitor"
     d.mkdir()
-    # clean dir: notes only, no findings from the new sections
+    # clean dir: notes only, no findings from the section
     assert doctor.main(["--dir", str(d)]) == 0
     # a valid trace + a parity-true report: still clean
     _synthetic_trace().write(d / "traffic_trace.jsonl")
@@ -623,35 +588,3 @@ def test_doctor_replay_and_perf_sections(tmp_path, capsys):
         '{"kind": "request", "t_rel": 0.0, "rid": 0, "max_new": 1, '
         '"seed": 0}\n')                       # no prompt and no gen
     assert doctor.main(["--dir", str(d)]) == 1
-    (d / "traffic_trace.jsonl").unlink()
-    capsys.readouterr()
-    # [perf]: a ledger with an injected regression gates; clean passes
-    bench = _bench_dir(tmp_path, n=5)
-    out_ledger = d / "PERF_LEDGER.json"
-    led = pl.update_ledger(bench, out_ledger)
-    assert doctor.main(["--dir", str(d)]) == 0
-    sick = copy.deepcopy(led)
-    key = next(k for k, s in sick["series"].items()
-               if s["direction"] == "down")
-    sick["series"][key]["points"].append(
-        ["bad", sick["series"][key]["points"][-1][1] * 3])
-    out_ledger.write_text(json.dumps(sick))
-    assert doctor.main(["--dir", str(d)]) == 1
-    cap = capsys.readouterr().out
-    assert "[perf]" in cap and "REGRESSION" in cap
-    assert doctor.main(["--dir", str(d), "--no-gate"]) == 0
-
-
-# ------------------------------------------------------------- CI smoke
-def test_replay_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_replay.py --smoke``: capture→replay
-    parity (engine + fleet with a recorded kill), divergence-as-data,
-    backtest within ±10 pts, ledger gate trip/clean — deterministic on
-    CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_replay.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout

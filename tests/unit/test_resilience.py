@@ -14,9 +14,10 @@ Oracles:
 - the non-finite sentinel halts a collapsed run with a typed error;
 - elastic restart visibility: DSTPU_ELASTIC_RESTART / _LAST_RC land in
   Train/* metrics;
-- ``bench_resilience.py --smoke``: the serving chaos gate (non-finite
-  injection parity, flood/shed, watchdog, drain/evict) — tier-1 wired
-  here, same pattern as the serving/WOQ gates.
+- a live serving engine under injected faults: a poisoned slot retires
+  NONFINITE alone while its neighbours' tokens stay bit-identical, a
+  submit flood is shed and the rest served, a drain refuses new work and
+  an uncollected results store evicts at its cap.
 """
 
 import json
@@ -585,16 +586,69 @@ def test_serving_request_log_and_flight_requests(tmp_path):
     assert len(read_flight_record(d)["requests"]) == 3
 
 
-# ------------------------------------------------------------- chaos smoke
-def test_resilience_smoke_gate():
-    """Tier-1 wiring of ``bench_resilience.py --smoke``: non-finite
-    injection parity, fake-clock deadlines, flood/shed, watchdog, and
-    drain/evict — deterministic on CPU (same pattern as the serving and
-    WOQ gates)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_resilience.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+# ------------------------------------------- guards on a live engine
+def test_serving_guards_react_to_injected_faults():
+    """Three faults through the chaos harness into a live engine, each
+    held to the guard's exact reaction. NaN logits in one occupied slot at
+    one decode step: exactly that request retires NONFINITE, cut at the
+    poisoned step, and every other request's tokens are bit-identical to
+    a clean run. Sixteen submits slammed into a queue of four: the
+    overflow is shed (typed, counted) and everything admitted is served.
+    A drain: new submits are refused and readiness drops while the
+    backlog finishes, and a results store nobody collects evicts at its
+    cap and counts it."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import build_model, tiny_test
+
+    model = build_model(tiny_test(max_seq=64, dtype=jnp.float32))
+    eng = ds.init_inference(model, model.init(jax.random.PRNGKey(0)),
+                            {"dtype": "float32", "eos_token_id": 7})
+    scfg = {"slots": 3, "max_len": 48, "prefill_chunk": 16,
+            "temperature": 0.8, "top_k": 20}
+    rng = np.random.default_rng(3)
+    reqs = [(rng.integers(0, 256, (int(rng.choice([5, 9, 16, 23])),))
+             .astype(np.int32), int(rng.integers(4, 12)), 500 + i)
+            for i in range(8)]
+
+    def run(srv):
+        rids = [srv.submit(p, n, seed=s) for p, n, s in reqs]
+        srv.drain()
+        return [srv.results[r] for r in rids]
+
+    clean = run(ds.ServingEngine(eng, scfg))
+    chaotic = ds.ServingEngine(eng, {**scfg, "chaos": {
+        "enabled": True, "seed": 1, "nonfinite_decode_step": 5}})
+    out = run(chaotic)
+    assert chaotic.chaos.injected
+    poisoned = [i for i, r in enumerate(out)
+                if r.status is RequestStatus.NONFINITE]
+    assert len(poisoned) == 1
+    for i, (got, want) in enumerate(zip(out, clean)):
+        if i in poisoned:
+            assert len(got.tokens) < len(want.tokens)
+        assert got.tokens == want.tokens[:len(got.tokens)]
+    assert chaotic.metrics_snapshot()["nonfinite"] == 1
+
+    flooded = ds.ServingEngine(eng, {**scfg, "max_queue": 4, "chaos": {
+        "enabled": True, "seed": 2, "flood_submits": 16}})
+    flooded.step()                 # iteration 0 floods through chaos
+    assert flooded.metrics_snapshot()["shed"] >= 10
+    assert flooded.sched.queue_depth <= 4
+    flooded.drain()
+    snap = flooded.metrics_snapshot()
+    assert snap["retired"] == snap["admitted"] > 0
+
+    srv = ds.ServingEngine(eng, scfg)
+    srv._max_results = 2
+    for p, n, s in reqs[:5]:
+        srv.submit(p, n, seed=s)
+    srv.begin_drain()
+    with pytest.raises(QueueFullError):
+        srv.submit(reqs[0][0], 2, seed=9)
+    assert not srv.health()["ready"]
+    srv.drain()
+    snap = srv.metrics_snapshot()
+    assert snap["retired"] == 5 and snap["results_evicted"] >= 3
+    assert len(srv.results) <= 2

@@ -13,15 +13,13 @@ Oracles:
   bucket, the cold engine's compile window in the compile bucket);
 - fleet degradation: a dead target becomes ``dstpu_scrape_up 0``, never
   an exception, and drops out of the weighted rollups;
-- ``bench_telemetry.py --smoke``: the tier-1 gate (zero added programs
-  with telemetry on, live scrape parses, byte-compat, goodput sums).
+- no program added: telemetry and goodput on compile what the plain
+  engine compiles, and a live scrape equals the sink's file.
 """
 
 import json
 import math
 import os
-import subprocess
-import sys
 import urllib.request
 from urllib.error import HTTPError, URLError
 
@@ -42,8 +40,6 @@ from deepspeed_tpu.observability.server import (TelemetryConfig,
                                                 TelemetryServer)
 from deepspeed_tpu.observability.sinks import PrometheusTextfileSink
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 EOS = 7
 
 
@@ -719,15 +715,29 @@ def test_doctor_url_unreachable_is_a_finding(capsys):
     assert rc == 1 and "unreachable" in out
 
 
-# ----------------------------------------------------------- tier-1 smoke
-def test_telemetry_bench_smoke_gate():
-    """Tier-1 wiring of ``bench_telemetry.py --smoke``: telemetry adds
-    zero programs, the live scrape parses + byte-matches the sink, and
-    the goodput decomposition sums to wall time."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_telemetry.py"),
-         "--smoke"], capture_output=True, text=True, timeout=420, env=env,
-        cwd=_ROOT)
-    assert out.returncode == 0, out.stderr[-2000:]
-    assert "smoke-pass" in out.stdout, out.stdout
+# ------------------------------------------------- no program added
+def test_plane_adds_no_program_and_scrape_equals_the_sink_file(setup,
+                                                               tmp_path):
+    """The ops surface adds threads and clock reads, never programs: the
+    same requests compile the same number of programs with the listener
+    and the goodput ledger on as with both off. And one renderer, two
+    transports, on a live engine: the ``/metrics`` body equals, byte for
+    byte, the file the Prometheus sink writes from the same registry."""
+    _, _, eng = setup
+    off = _serving(eng)
+    _run_all(off, n=6)
+    srv = _serving(eng, goodput=True,
+                   telemetry={"enabled": True, "port": 0})
+    try:
+        _run_all(srv, n=6)
+        assert srv.compiles == off.compiles
+        body = _req(f"http://127.0.0.1:{srv.telemetry.port}/metrics")[2]
+        reg = srv.stats.registry
+        sink = PrometheusTextfileSink({"output_path": str(tmp_path),
+                                       "job_name": "live"})
+        sink.write_events(reg.to_events(
+            int(reg.counter("Serve/iterations").value)))
+        sink.flush()
+        assert (tmp_path / "live.prom").read_text() == body
+    finally:
+        srv.close()

@@ -27,13 +27,12 @@ Oracles:
   clock, flight why-marker + incident dump on open, cooldown gates the
   re-trigger;
 - doctor [tenants]: fairness floor gate trip / clean / absent;
-- bench_tenantscope.py --smoke: the tier-1 gate subprocess.
+- a live paged, tiered engine on an exact clock: page-seconds and host
+  tier bytes conserve across tenants, and a bursting tenant under SLO
+  burn is named in a real flight dump's ``tenant_breakdown.json``.
 """
 
 import json
-import os
-import subprocess
-import sys
 import urllib.request
 from types import SimpleNamespace
 from urllib.error import HTTPError
@@ -60,9 +59,6 @@ from deepspeed_tpu.observability.tenantscope import (OVERFLOW_TENANT,
                                                      jain_index)
 from deepspeed_tpu.serving import FleetEngine
 from _fake_clock import TickClock
-
-_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 EOS = 7
 
@@ -479,16 +475,79 @@ def test_doctor_tenants_fairness_gate(tmp_path, capsys):
     assert report_tenants(other, fairness_min=0.8) == []
 
 
-# ------------------------------------------------------------ smoke gate
-def test_bench_tenantscope_smoke_gate():
-    """Tier-1 wiring of ``bench_tenantscope.py --smoke``: exact token /
-    page-second / tier-byte conservation, compile-freeze inertness, the
-    injected noisy neighbor with its incident artifact, and the doctor
-    [tenants] fairness gate — deterministic on CPU."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench_tenantscope.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=540, env=env, cwd=_ROOT)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "smoke-pass" in r.stdout
+# ------------------------------------------- a live paged engine
+def test_tier_owner_bytes_move_with_bytes_used():
+    """``owner_bytes`` is ``bytes_used`` split by first writer, through
+    put, a replace that re-owns the entry, and the LRU prunes a full
+    store forces."""
+    from deepspeed_tpu.serving.hostkv import HostKVTier
+
+    store = HostKVTier(1000, page_size=8)
+    keys = [tuple(range(i, i + 8)) for i in range(6)]
+    owners = ["t0", "t1", "t0", "big", "big", "big"]
+    for key, owner in zip(keys[:3], owners):
+        store.put(key, {"k": np.zeros(250, np.int8)}, owner=owner)
+    store.put(keys[0], {"k": np.zeros(250, np.int8)}, owner="t9")
+    assert sum(store.owner_bytes.values()) == store.bytes_used
+    for key, owner in zip(keys[3:], owners[3:]):    # each put prunes
+        store.put(key, {"k": np.zeros(250, np.int8)}, owner=owner)
+        assert sum(store.owner_bytes.values()) == store.bytes_used
+
+
+def test_live_engine_conserves_pages_and_names_the_noisy_tenant(setup,
+                                                                tmp_path):
+    """Conservation on a live paged, tiered engine whose clock ticks in
+    exact binary fractions: the tenants' page-second integrals sum to the
+    pool's own integral as equal floats, and the host tier's bytes split
+    by tenant sum to what its entries say they own. Then the detector
+    end to end: a tenant that bursts while the fleet burns its SLO opens
+    an episode in its name, the flight recorder dumps once with
+    ``tenant_breakdown.json`` beside the trace, and the episode closes
+    when the burn clears."""
+    _, _, _, eng = setup
+    srv = _serving(
+        eng, clock=TickClock(dt=2.0 ** -10), greedy=True, page_size=8,
+        pool_pages=12, host_pool_bytes=1 << 20,
+        flight_dir=str(tmp_path),
+        tenantscope={"min_burst_arrivals": 6, "burst_share": 0.6,
+                     "burn_threshold": 0.5, "check_interval_s": 0.0,
+                     "cooldown_s": 0.0, "window_s": 1e9})
+    rng = np.random.default_rng(7)
+    prefix = {t: rng.integers(0, 256, (32,)).astype(np.int32)
+              for t in ("acme", "umbrella", "chatty")}
+
+    def serve(tenant, n):
+        for i in range(n):
+            prompt = prefix[tenant].copy()
+            prompt[-1] = i
+            srv.serve_batch([prompt], 8, [1000 + i], tenant_ids=[tenant])
+
+    try:
+        serve("acme", 3)
+        serve("umbrella", 2)
+        snap = srv.tenants_snapshot()
+        assert set(snap["tenants"]) == {"acme", "umbrella"}
+        assert snap["totals"]["page_seconds"] \
+            == snap["totals"]["pool_page_seconds"] > 0.0
+        owned = sum(srv.hostkv.owner_bytes.values())
+        assert 0 < owned <= srv.hostkv.bytes_used
+        assert sum(r["tier_bytes"].get("host_tier", 0)
+                   for r in snap["tenants"].values()) == owned
+        assert srv.tenantscope.active_episode is None
+
+        srv.stats.registry.gauge("Serve/slo_ttft_burn").set(2.0)
+        serve("chatty", 8)
+        assert srv.tenantscope.active_episode["tenant"] == "chatty"
+        dumps = [d for d in tmp_path.iterdir()
+                 if "noisy_neighbor" in d.name]
+        assert len(dumps) == 1
+        breakdown = json.loads(
+            (dumps[0] / "tenant_breakdown.json").read_text())
+        assert breakdown["noisy"]["active"]["tenant"] == "chatty"
+        assert {"acme", "umbrella", "chatty"} <= set(breakdown["tenants"])
+        srv.stats.registry.gauge("Serve/slo_ttft_burn").set(0.0)
+        serve("acme", 1)
+        assert srv.tenantscope.active_episode is None
+        assert srv.tenantscope.last_episode["tenant"] == "chatty"
+    finally:
+        srv.close()
