@@ -370,9 +370,11 @@ def phase_serve(sz, seed, watch, devices):
     say(f"serve/contiguous: {srv.compiles} programs compiled")
     check(any(name.startswith("chunk_") for name in texts),
           "serve: chunked prefill ran (a prompt longer than prefill_chunk)")
-    report_programs("serve/contiguous", texts,
-                    expect=("decode_attention", "cache_append"))
-    # the step carries the cache donated and only its two kernels touch it:
+    report_programs("serve/contiguous", texts, expect=("decode_attention",))
+    check("cache_append" not in kernels_in(texts["step"]),
+          "serve/contiguous: the step appends inside decode_attention "
+          "(no cache_append kernel)")
+    # the step carries the cache donated and only its one kernel touches it:
     # temporaries the size of a cache mean it is being copied or re-laid
     # out again (PERF.md F10: 6.25 GiB at 32 slots)
     temp = step_temporaries("serve/contiguous", programs["step"])

@@ -7,9 +7,10 @@ is a pair of ``(L, B, KV, hd, max_len)`` arrays; attention over the cache
 masks positions beyond the current length, so every decode step has an
 identical static shape (one compiled program for the whole generation).
 The T == 1 step carries the cache through its layer loop as ONE donated
-buffer that only two kernels touch (``ops/decode_attention.py``:
-``cache_append`` writes the new position in place, ``decode_attention``
-reads it by layer); T > 1 (prefill, speculative verify) appends with
+buffer that only one kernel touches (``ops/decode_attention.py``:
+``decode_attention`` takes the step's new K/V, puts them into the last
+live block it has fetched anyway, attends, and writes that block back in
+place, by layer); T > 1 (prefill, speculative verify) appends with
 ``dynamic_update_slice`` and attends densely over the same layout.
 
 The attention kind decides the cache (:func:`cache_layout`). Latent
@@ -441,7 +442,7 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     ``(L, B, KV, hd, max_len)`` cache and ``layer`` (traced i32) this
     layer's index in it; ``flash_decode`` is then the gate's answer
     (:func:`_decode_kernel_ok`, asked once by :func:`forward_with_cache`):
-    the two decode kernels append and attend in place, by layer index.
+    the decode kernel appends and attends in place, by layer index.
     """
     cfg = model.cfg
     B, T, d = x.shape
@@ -462,12 +463,11 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         alibi = alibi_slopes(h)
     scale_k = scale_v = None
     if paged is None and flash_decode:
-        from ..ops.decode_attention import cache_append, decode_attention
+        from ..ops.decode_attention import decode_attention
 
-        cache_k, cache_v = cache_append(cache_k, cache_v, k, v, length,
-                                        layer=layer)
-        o = decode_attention(q, cache_k, cache_v, length, layer=layer,
-                             alibi_slopes=alibi)
+        o, cache_k, cache_v = decode_attention(
+            q, cache_k, cache_v, length, k=k, v=v, layer=layer,
+            alibi_slopes=alibi)
     else:
         if paged is not None:
             page_table, scale_k, scale_v = paged

@@ -17,7 +17,7 @@ Layout notes:
   the cache's bytes, and the compiler keeps the cache compact by storing it
   the other way round and re-laying every slab out around each call (what
   PERF.md F10 measured: more time moving K/V than attending to it).
-- both kernels take the WHOLE cache and a scalar-prefetched layer index:
+- the kernels take the WHOLE cache and a scalar-prefetched layer index:
   the layer loop carries one buffer and nothing slices a layer's slab out
   of it or writes one back.
 - ``decode_attention``: grid (B, KV / hb); a program's work is a slot's
@@ -33,6 +33,22 @@ Layout notes:
   block the first block of the NEXT program (which buffer, and whether
   that copy was started, ride in SMEM scratch: the grid runs in order).
   Positions behind the live length are neither fetched nor multiplied.
+- the step APPENDS INSIDE the same kernel: called with the step's new K/V
+  (``k=``, ``v=``) the caches are aliased outputs and ``length`` is the
+  length after the append. The new position ``length - 1`` lies in the
+  slot's last live block, which the kernel fetches anyway: once that block
+  has landed, the program that owns it (never the one that fetched it
+  ahead) puts the slot's new column on lane ``(length - 1) % 128`` of both
+  buffers, takes the products from the patched buffers and copies them
+  back to the same block of the cache, once. A column alone cannot be
+  copied: every (sublanes x 128) HBM tile of the block holds part of it
+  and Mosaic refuses a slice narrower than the tile ("Slice shape along
+  dimension 3 must be aligned to tiling (128), but is 1"); so a step
+  writes a 128-lane block a slot, and reads nothing twice. Called without
+  new values (the paged view's slab, a caller that has appended) the same
+  body only reads. An XLA-level update of one position lets the compiler
+  pick a layout FOR THE UPDATE and convert the whole cache to it; inside
+  an aliased kernel nothing can.
 - the GQA head group mapping is a reshape of q to ``(B, KV, group, hd)``:
   a KV head's query rows are real rows of one product, padded to the 8
   sublanes, and there is no repeated-KV materialization at all
@@ -43,12 +59,12 @@ Layout notes:
   running max, the sum and the accumulator are float32 (the loop's carry).
 - the live length is a scalar-prefetch operand (SMEM): it bounds the
   kernel's loop and its copies at ceil(length / block) instead of max_len.
-- ``cache_append``: grid (B, kv-blocks); writes the step's new K/V at
-  position ``length - 1`` of every slot as a read-modify-write of the one
-  128-lane tile that holds it, with the cache aliased to the output. An
-  XLA-level update of one position lets the compiler pick a layout FOR THE
-  UPDATE and convert the whole cache to it; inside an aliased kernel
-  nothing can.
+- ``append_in_place``: an append alone, as a read-modify-write of the one
+  128-lane tile that holds position ``length - 1`` with the cache aliased
+  to the output: what the K/V step did in a kernel of its own
+  (``cache_append``) until the attention kernel took it over; kept for the
+  latent cache (``ops/mla_attention.py``), whose attention is another
+  kernel and whose append is a twentieth of the bytes.
 """
 
 from __future__ import annotations
@@ -65,7 +81,7 @@ from jax.sharding import PartitionSpec as P
 BIG_NEG = -2.0 ** 30
 SUBLANES = 8
 LANES = 128
-# cache_append's tile is (kv-block, hd, 128): the most KV heads a program
+# append_in_place's tile is (kv-block, hd, 128): the most KV heads a program
 # takes, so in, out and their double buffers stay far inside scoped VMEM
 _APPEND_TILE_BYTES = 512 * 1024
 # decode_attention's K (and V) block is (kv-block, hd, block): two buffers
@@ -74,15 +90,37 @@ _APPEND_TILE_BYTES = 512 * 1024
 _ATTEND_BLOCK_BYTES = 1024 * 1024
 
 
-def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int):
+def _with_column(old_ref, new_ref, b, r):
+    """The tile in ``old_ref`` (.., 128 lanes) with lane ``r`` taken from
+    ``new_ref``, whose values lie slots-on-lanes: slot ``b``'s column turns
+    onto lane ``r`` (Mosaic rotates 32-bit values by a traced amount, so
+    through float32)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    col = jax.lax.broadcasted_iota(jnp.int32, old_ref.shape, 2)
+    turn = (r - b % LANES) % LANES
+    new = pltpu.roll(new_ref[...].astype(jnp.float32), turn, 2)
+    return jnp.where(col == r, new.astype(old_ref.dtype), old_ref[...])
+
+
+def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
+                   append: bool):
     """One program: a slot's ``hb`` KV heads (``q_ref`` (hb, rows, hd), the
     group's query rows padded to the 8 sublanes) over that slot's live
-    blocks, copied out of the cache in HBM by the kernel itself."""
+    blocks, copied out of the cache in HBM by the kernel itself. With
+    ``append`` the step's new K/V (``new_k`` / ``new_v`` (hb, hd, 128),
+    slots on the lanes) go onto lane ``(L - 1) % block`` of the slot's last
+    live block once it has landed in VMEM; the products read the patched
+    buffers and one copy takes them back to the aliased cache."""
     from jax.experimental.pallas import tpu as pltpu
 
     len_ref, layer_ref, *refs = refs
     slopes_ref = refs.pop(0) if alibi else None
-    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, ahead = refs
+    if append:
+        (q_ref, new_k, new_v, k_hbm, v_hbm, o_ref, k_out, v_out,
+         k_buf, v_buf, sem, ahead) = refs
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, ahead = refs
     b, g = pl.program_id(0), pl.program_id(1)
     n_slots, n_groups = pl.num_programs(0), pl.num_programs(1)
     hb, rows, _ = q_ref.shape
@@ -92,26 +130,54 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int):
     L = jnp.minimum(len_ref[b], S)
     nb = (L + block - 1) // block                        # only live blocks
 
+    def at(slot, heads, j):
+        return (layer_ref[0], slot, pl.ds(heads * hb, hb), slice(None),
+                pl.ds(pl.multiple_of(j * block, block), block))
+
     def copies(buf, slot, heads, j):
-        at = (layer_ref[0], slot, pl.ds(heads * hb, hb), slice(None),
-              pl.ds(pl.multiple_of(j * block, block), block))
-        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[buf],
-                                      sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[buf],
-                                      sem.at[1, buf]))
+        return (pltpu.make_async_copy(k_hbm.at[at(slot, heads, j)],
+                                      k_buf.at[buf], sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at(slot, heads, j)],
+                                      v_buf.at[buf], sem.at[1, buf]))
+
+    def writes(buf, j):
+        """The copies of this program's patched block ``j`` back to the
+        cache (to wait on one only the semaphore and the size matter)."""
+        return (pltpu.make_async_copy(k_buf.at[buf],
+                                      k_out.at[at(b, g, j)], sem.at[2, 0]),
+                pltpu.make_async_copy(v_buf.at[buf],
+                                      v_out.at[at(b, g, j)], sem.at[2, 1]))
+
+    def patch(buf):
+        """Lane ``(L - 1) % block`` of both buffers takes the slot's new
+        column."""
+        for new_ref, c_buf in ((new_k, k_buf), (new_v, v_buf)):
+            c_buf[buf] = _with_column(c_buf.at[buf], new_ref, b,
+                                      (L - 1) % block)
 
     def fetch(buf, slot, heads, j):
         for copy in copies(buf, slot, heads, j):
             copy.start()
 
-    # ``ahead``: which buffer this program's first block goes to, and
-    # whether the program before already started that copy
+    # ``ahead``: which buffer this program's first block goes to, whether
+    # the program before already started that copy and, appending, whether
+    # it left a write-back in flight
     @pl.when((b == 0) & (g == 0))
     def _():
         ahead[0] = 0
         ahead[1] = 0
+        if append:
+            ahead[2] = 0
 
     first = ahead[0]
+
+    if append:
+        # the program before left its patched block on its way back to the
+        # cache, out of the buffer this program's second block goes to
+        @pl.when(ahead[2] == 1)
+        def _():
+            for copy in writes(0, 0):
+                copy.wait()
 
     @pl.when((nb > 0) & (ahead[1] == 0))
     def _():
@@ -152,6 +218,14 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int):
 
         for copy in copies(buf, b, g, j):
             copy.wait()
+        if append:
+            # the last live block holds position L - 1: patched in VMEM,
+            # attended to from there, written back once
+            @pl.when(j + 1 == nb)
+            def _():
+                patch(buf)
+                for copy in writes(buf, j):
+                    copy.start()
         k, v = k_buf[buf], v_buf[buf]                    # (hb, hd, blk)
         s = jax.lax.dot_general(                         # (hb, rows, blk)
             q, k, (((2,), (1,)), ((0,), (0,))),
@@ -178,6 +252,18 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int):
     l0 = jnp.zeros((hb, rows, 1), jnp.float32)
     acc0 = jnp.zeros(q.shape, jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
+    if append:
+        # the write runs behind the last block's products, the next
+        # program's first fetch (which is in the other buffer) and the turn
+        # of the programs: the next one waits for it, the last one here
+        last = (b == n_slots - 1) & wraps
+
+        @pl.when((nb > 0) & last)
+        def _():
+            for copy in writes(0, 0):
+                copy.wait()
+
+        ahead[2] = ((nb > 0) & jnp.logical_not(last)).astype(jnp.int32)
     ahead[0] = (first + nb) % 2
     ahead[1] = ((nb > 0) & next_live).astype(jnp.int32)
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -202,8 +288,17 @@ def _heads_per_program(KV: int, hd: int, blk: int, dtype) -> int:
         <= _ATTEND_BLOCK_BYTES))
 
 
-def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
-                     block: int = LANES, interpret: Optional[bool] = None):
+def _slots_on_lanes(x, dtype):
+    """(B, 1, KV, hd) → (KV, hd, slots): ``hd`` on the sublanes as in the
+    cache, the slots on the lanes, padded to whole tiles (a few KiB; a
+    kernel turns its slot's column onto the position's lane)."""
+    x = x[:, 0].transpose(1, 2, 0).astype(dtype)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % LANES)))
+
+
+def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
+                     alibi_slopes=None, block: int = LANES,
+                     interpret: Optional[bool] = None):
     """q: (B, 1, H, hd) current-token queries; ck/cv: the cache
     ``(L, B, KV, hd, max_len)`` with ``layer`` (traced i32) the layer to
     attend over, or one layer's ``(B, KV, hd, max_len)``; ``length`` scalar
@@ -212,22 +307,37 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
     bias is reconstructed in-kernel from the live length (Bloom decode
     stays on the streaming kernel instead of the dense fallback).
 
+    ``k`` / ``v`` (B, 1, KV, hd): the step's new K/V, not yet in the cache.
+    The kernel then appends as it attends: they go to position
+    ``length - 1`` of every slot (``length`` is the length AFTER the
+    append; past the cache it clamps to the last position, as
+    ``dynamic_update_slice`` clamps; a slot of length 0 is left alone),
+    in the slot's last live block while it is in VMEM, and that block is
+    written back to the cache, whose outputs are aliased to the inputs:
+    every other position keeps every bit. Without them the kernel only
+    reads (a caller that has appended already: the paged view's slab).
+
     A slot's result depends on that slot's row and length alone: the block
     and the heads a program takes follow from ``(KV, hd, max_len, dtype)``,
     never from ``B``.
 
-    Returns (B, 1, H, hd)."""
+    Returns (B, 1, H, hd); with ``k`` / ``v`` also the two caches."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, hd = q.shape
     assert T == 1, "decode kernel is single-token; use flash_attention for prefill"
-    if ck.ndim == 4:            # a layer's slab: a cache of that one layer
+    append = k is not None
+    slab = ck.ndim == 4         # a layer's slab: a cache of that one layer
+    if slab:
         ck, cv, layer = ck[None], cv[None], 0
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     KV, S = ck.shape[2], ck.shape[4]
     blk = min(block, S)
     if S % blk != 0:
         raise ValueError(f"cache length {S} not divisible by block {blk}")
+    if append and blk != LANES:
+        raise ValueError(f"appending takes blocks of {LANES} positions, "
+                         f"not {blk}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     group = H // KV
@@ -235,6 +345,7 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
     alibi = alibi_slopes is not None
     slopes = (jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()
+    news = (k, v) if append else ()
 
     axes = _shard_axes(ck, H)
     if axes is not None:
@@ -242,18 +353,21 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
         # over the example-parallel axes and heads over model/seq (inside
         # the body the axes are manual, so the recursion lands below)
         mesh, b_ax, h_ax, cache = axes
+        rows = P(b_ax, None, h_ax, None)
 
-        def per_shard(q, ck, cv, n, layer, *slopes):
-            return decode_attention(q, ck, cv, n, layer=layer[0], block=block,
-                                    interpret=interpret,
+        def per_shard(q, ck, cv, n, layer, *rest):
+            k, v = rest[:2] if append else (None, None)
+            slopes = rest[len(news):]
+            return decode_attention(q, ck, cv, n, k=k, v=v, layer=layer[0],
+                                    block=block, interpret=interpret,
                                     alibi_slopes=slopes[0] if slopes else None)
 
         return jax.shard_map(
             per_shard, mesh=mesh,
-            in_specs=(P(b_ax, None, h_ax, None), cache, cache, P(b_ax), P())
-            + ((P(h_ax),) if alibi else ()),
-            out_specs=P(b_ax, None, h_ax, None), check_vma=False)(
-                q, ck, cv, lengths, layer, *slopes)
+            in_specs=(rows, cache, cache, P(b_ax), P())
+            + (rows,) * len(news) + ((P(h_ax),) if alibi else ()),
+            out_specs=(rows, cache, cache) if append else rows,
+            check_vma=False)(q, ck, cv, lengths, layer, *news, *slopes)
 
     hb = _heads_per_program(KV, hd, blk, ck.dtype)
     # (B, 1, H, hd) → (B, KV, rows, hd): a KV head's ``group`` query rows,
@@ -264,105 +378,73 @@ def decode_attention(q, ck, cv, length, *, layer=None, alibi_slopes=None,
                  ((0, 0), (0, 0), (0, rows - group), (0, 0)))
     q_spec = pl.BlockSpec((None, hb, rows, hd),
                           lambda b, g, *pre: (b, g, 0, 0))
+    new_spec = pl.BlockSpec((hb, hd, LANES),
+                            lambda b, g, *pre: (g, 0, b // LANES))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    n_pre = 2 + len(slopes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 + len(slopes),
+        num_scalar_prefetch=n_pre,
         grid=(B, KV // hb),
-        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=q_spec,
+        in_specs=[q_spec] + [new_spec] * len(news) + [in_hbm, in_hbm],
+        out_specs=[q_spec] + [in_hbm] * len(news),
+        # two buffers of K and of V; a semaphore a buffer for the fetches
+        # and one more pair for the write-back; ``ahead`` (see the kernel)
         scratch_shapes=[pltpu.VMEM((2, hb, hd, blk), ck.dtype),
                         pltpu.VMEM((2, hb, hd, blk), cv.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2)),
-                        pltpu.SMEM((2,), jnp.int32)],
+                        pltpu.SemaphoreType.DMA((2 + append, 2)),
+                        pltpu.SMEM((2 + append,), jnp.int32)],
     )
-    out = pl.pallas_call(
+    out, *caches = pl.pallas_call(
         partial(_decode_kernel, block=blk, scale=scale, alibi=alibi,
-                group=group),
+                group=group, append=append),
         name="decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, KV, rows, hd), q.dtype)]
+        + ([jax.ShapeDtypeStruct(c.shape, c.dtype) for c in (ck, cv)]
+           if append else []),
+        # the caches come back where they were
+        input_output_aliases={n_pre + 1 + len(news) + i: 1 + i
+                              for i in range(len(news))},
         # a program starts the next one's first copy: the grid runs in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(lengths, layer, *slopes, qs, ck, cv)
-    return out[:, :, :group].reshape(B, 1, H, hd)
+    )(lengths, layer, *slopes, qs,
+      *(_slots_on_lanes(x, ck.dtype) for x in news), ck, cv)
+    out = out[:, :, :group].reshape(B, 1, H, hd)
+    if not append:
+        return out
+    return (out,) + tuple(c[0] if slab else c for c in caches)
 
 
 def _append_kernel(pos_ref, _, *refs):
     """``refs``: n new-value blocks, n cache tiles, n output tiles."""
-    from jax.experimental.pallas import tpu as pltpu
-
     n = len(refs) // 3
     b = pl.program_id(0)
     r = pos_ref[b] % LANES                  # the position's lane in its tile
-    col = jax.lax.broadcasted_iota(jnp.int32, refs[n].shape, 2)
-    # the new values lie slots-on-lanes: slot b's column turns onto lane r
-    turn = (r - b % LANES) % LANES
     for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
                                          refs[2 * n:]):
-        new = pltpu.roll(new_ref[...].astype(jnp.float32), turn, 2)
-        out_ref[...] = jnp.where(col == r, new.astype(out_ref.dtype),
-                                 old_ref[...])
-
-
-def cache_append(ck, cv, k, v, length, *, layer,
-                 interpret: Optional[bool] = None):
-    """Write this step's K/V into layer ``layer`` (traced i32) of the cache
-    ``(L, B, KV, hd, max_len)``, in place: ``k``/``v`` (B, 1, KV, hd) go to
-    position ``length - 1`` of every slot (``length`` scalar or (B,), the
-    lengths AFTER the append; clamped into the cache as
-    ``dynamic_update_slice`` clamps). Returns the cache with the outputs
-    aliased to the inputs, every other position bit-untouched."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    _, B, KV, hd, S = ck.shape
-    if S % LANES != 0:
-        raise ValueError(f"cache length {S} not a multiple of {LANES}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
-
-    axes = _shard_axes(ck, KV)
-    if axes is not None:
-        mesh, b_ax, h_ax, cache = axes
-        new = P(b_ax, None, h_ax, None)
-
-        def per_shard(ck, cv, k, v, n, layer):
-            return cache_append(ck, cv, k, v, n, layer=layer[0],
-                                interpret=interpret)
-
-        return jax.shard_map(
-            per_shard, mesh=mesh,
-            in_specs=(cache, cache, new, new, P(b_ax), P()),
-            out_specs=(cache, cache), check_vma=False)(
-                ck, cv, k, v, lengths, layer)
-
-    return append_in_place((ck, cv), (k, v), lengths, layer,
-                           name="cache_append", interpret=interpret)
+        out_ref[...] = _with_column(old_ref, new_ref, b, r)
 
 
 def append_in_place(caches: tuple, news: tuple, lengths, layer, *, name: str,
                     interpret: bool):
-    """The kernel behind :func:`cache_append`, for any number of buffers
-    ``(L, B, KV, hd, max_len)`` written at the same positions (K and V; the
-    one buffer of a latent cache, ``ops/mla_attention.py``): ``news``
-    (B, 1, KV, hd) each, ``lengths`` (B,) AFTER the append, ``layer`` (1,)
-    i32. Returns the caches, outputs aliased to the inputs."""
+    """An append alone, for a cache that :func:`decode_attention` does not
+    read (the one buffer of a latent cache, ``ops/mla_attention.py``): any
+    number of buffers ``(L, B, KV, hd, max_len)`` written at the same
+    positions, ``news`` (B, 1, KV, hd) each at position ``length - 1`` of
+    every slot (``lengths`` (B,) AFTER the append, clamped into the cache
+    as ``dynamic_update_slice`` clamps), ``layer`` (1,) i32. A
+    read-modify-write of the one 128-lane tile that holds the position.
+    Returns the caches, outputs aliased to the inputs, every other
+    position bit-untouched."""
     from jax.experimental.pallas import tpu as pltpu
 
     ck = caches[0]
     _, B, KV, hd, S = ck.shape
     n = len(caches)
-    # (B, 1, KV, hd) → (KV, hd, slots): hd on the sublanes as in the cache,
-    # the slots on the lanes (a few KiB; the kernel turns its slot's column
-    # onto the position's lane)
     pos = jnp.clip(lengths - 1, 0, S - 1)
-    pad = (-B) % LANES
-    news = tuple(jnp.pad(x[:, 0].transpose(1, 2, 0).astype(c.dtype),
-                         ((0, 0), (0, 0), (0, pad)))
-                 for x, c in zip(news, caches))
+    news = tuple(_slots_on_lanes(x, c.dtype) for x, c in zip(news, caches))
     kvb = max(d for d in range(1, KV + 1) if KV % d == 0 and (
         d == 1 or d * hd * LANES * ck.dtype.itemsize <= _APPEND_TILE_BYTES))
 

@@ -997,15 +997,23 @@ class ServingEngine:
                 "cache_bytes_per_token": self._cache_bytes_per_token}
 
     def _attn_counts(self) -> dict:
-        """``attn_fetched_over_live`` of the step just dispatched: the
-        positions ``decode_attention`` fetches (every slot's length, idle
-        ones too, clamped to the cache and rounded up to the kernel's
-        block) over those the running requests attend to; 1 is ideal."""
+        """Of the step just dispatched, two ratios in which 1 is ideal.
+        ``attn_fetched_over_live``: the positions ``decode_attention``
+        fetches (every slot's length, idle ones too, clamped to the cache
+        and rounded up to the kernel's block) over those the running
+        requests attend to. ``append_moved_over_new``: the bytes the
+        kernel moves between HBM and VMEM to append (the block of 128
+        positions it writes back for every slot whose length is over 0;
+        the block's read is the attention's own fetch) over the bytes of
+        the running requests' new K/V: a ratio of positions, since both
+        are K and V of every head and layer."""
         fetched = -(-np.minimum(self._slot_len, self.cfg.max_len)
                     // LANES) * LANES
-        live = sum(req.prompt_len + len(req.tokens)
-                   for req in self.sched.running.values())
-        return {"attn_fetched_over_live": float(fetched.sum() / live)}
+        running = self.sched.running.values()
+        live = sum(req.prompt_len + len(req.tokens) for req in running)
+        written = LANES * np.count_nonzero(self._slot_len > 0)
+        return {"attn_fetched_over_live": float(fetched.sum() / live),
+                "append_moved_over_new": float(written / len(running))}
 
     def _log_routing(self, step, tapped: list, chunks: list) -> None:
         """Into ``routing_log``: the chunks' choices (their real tokens,

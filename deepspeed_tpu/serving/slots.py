@@ -6,7 +6,7 @@ indirection is hostile to XLA's static shapes), the serving state is ONE
 ``(L, slots, KV, hd, max_len)`` cache (``(L, slots, rank + rope, max_len)``
 for latent attention) — the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
 positions on the lanes, so the buffer is compact in HBM at any head size
-and the decode step's two kernels append to it and read it where it lies
+and the decode step's kernel appends to it and reads it where it lies
 (``ops/decode_attention.py``) — plus per-slot ``length`` / ``tok`` /
 ``rng`` / ``done`` vectors. A finished slot is immediately reusable:
 insertion overwrites the slot's FULL cache extent with the freshly

@@ -39,7 +39,7 @@ GROUP = 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -54,9 +54,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, one_chip, *shapes):
@@ -111,6 +116,66 @@ def test_decode_attention_over_the_whole_cache(one_chip, B, H, KV, hd, L,
         interpret=False),
         one_chip, ((B, 1, H, hd), jnp.bfloat16), cache, cache,
         ((B,), jnp.int32), ((), jnp.int32), *slopes)
+
+
+def _appending(q, ck, cv, n, layer, k, v):
+    return decode_attention(q, ck, cv, n, k=k, v=v, layer=layer,
+                            interpret=False)
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_decode_attention_appending(one_chip, width):
+    """The call the slot step makes: the step's new K/V as operands, the
+    caches handed back where they were (aliased: no temporary of their
+    size) by the one kernel."""
+    _, _, H, KV, hd, _, _ = width
+    B, L = 48, 4
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, 1, H, hd), jnp.bfloat16), ((L, B, KV, hd, SEQ), jnp.bfloat16),
+        ((L, B, KV, hd, SEQ), jnp.bfloat16), ((B,), jnp.int32),
+        ((), jnp.int32), ((B, 1, KV, hd), jnp.bfloat16),
+        ((B, 1, KV, hd), jnp.bfloat16))]
+    compiled = jax.jit(_appending, donate_argnums=(1, 2)).lower(
+        *args).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "decode_attention" in calls[0], calls
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * L * B * KV * hd * SEQ * 2
+    assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
+
+
+def test_decode_attention_appending_under_a_model_axis(topo):
+    """``model`` = 4 over the described host's four chips: the kernel runs
+    per shard of 5 KV heads inside a ``shard_map`` with the caches in its
+    ``out_specs`` (GSPMD cannot partition a Mosaic kernel)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
+
+    _, _, H, KV, hd, _, _ = GPT2_774M
+    B, L = 48, 4
+    mesh = build_mesh(MeshSpec(data=1, model=4), devices=topo.devices)
+    rows, cache = P(None, None, "model", None), P(None, None, "model")
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    args = (arg((B, 1, H, hd), jnp.bfloat16, rows),
+            arg((L, B, KV, hd, SEQ), jnp.bfloat16, cache),
+            arg((L, B, KV, hd, SEQ), jnp.bfloat16, cache),
+            arg((B,), jnp.int32, P()), arg((), jnp.int32, P()),
+            arg((B, 1, KV, hd), jnp.bfloat16, rows),
+            arg((B, 1, KV, hd), jnp.bfloat16, rows))
+    with mesh:
+        compiled = jax.jit(_appending, donate_argnums=(1, 2)).lower(
+            *args).compile()
+    text = compiled.as_text()
+    assert "decode_attention" in text and "tpu_custom_call" in text
+    # a shard's caches: 5 of 20 heads, donated and handed back in place
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * L * B * (KV // 4) * hd * SEQ * 2
 
 
 @pytest.mark.parametrize("width", WIDTHS)
@@ -192,8 +257,9 @@ def _slot_step(one_chip, cfg, slots):
 ])
 def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
                                             slots):
-    """The built program, pinned: the cache enters donated, only the two
-    kernels touch it, and it leaves aliased to the output. On the layout
+    """The built program, pinned: the cache enters donated, only the one
+    kernel that appends and attends touches it, and it leaves aliased to the
+    output. On the layout
     with ``hd`` last the step's temporaries were a second cache (6.25 GiB
     at 32 slots, and 48 did not fit the chip): a whole-cache ``copy``
     around the layer loop and a slice / transpose / write-back of every
@@ -213,8 +279,9 @@ def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
     assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    for kernel in ("decode_attention", "cache_append"):
-        assert any(kernel in ln for ln in calls), f"{kernel} absent"
+    assert any("decode_attention" in ln for ln in calls)
+    # the append is the attention kernel's own: no second kernel a layer
+    assert not any("cache_append" in ln for ln in calls)
     # a result shaped like the cache, a layer's slab or a slot's (either
     # way round) from anything that moves data
     slab = re.compile(

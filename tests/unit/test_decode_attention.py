@@ -130,8 +130,8 @@ def test_bloom_generation_flash_vs_dense_decode():
 
 # ------------------------------------------------- the carried-cache step
 # One layer loop for every contiguous cache (inference/decode.py): T == 1
-# under the gate appends and attends with the two kernels on the cache the
-# loop carries; the dense path is the oracle, solo generate() the contract.
+# under the gate appends and attends with one kernel on the cache the loop
+# carries; the dense path is the oracle, solo generate() the contract.
 S = 256                                       # two lane tiles per slot
 EDGES = [1, 127, 128, 129, S]                 # live lengths AFTER the append
 FAMILIES = {
@@ -222,25 +222,25 @@ def test_step_kernels_match_dense_path(family, lengths):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("lens", [EDGES + [0], 129, 0],
-                         ids=["ragged", "scalar", "clamped"])
-def test_cache_append_is_bit_equal_to_set(lens, dtype):
-    """``cache_append`` against ``.at[].set``, bit for bit, at tile edges,
-    for a layer in the middle of the cache; a length of 0 clamps to
-    position 0 as ``dynamic_update_slice`` clamps."""
-    from deepspeed_tpu.ops.decode_attention import cache_append
-
+                         ids=["ragged", "scalar", "untouched"])
+def test_appended_caches_are_bit_equal_to_set(lens, dtype):
+    """The caches the appending call hands back against ``.at[].set``, bit
+    for bit, at tile edges, for a layer in the middle of the cache; a slot
+    of length 0 writes nothing and its row keeps every bit."""
     L, B, KV, hd = 3, 6, 2, 16
     rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((B, 1, KV, hd)), dtype)
     ck, cv = (jnp.asarray(rng.standard_normal((L, B, KV, hd, S)), dtype)
               for _ in range(2))
     k, v = (jnp.asarray(rng.standard_normal((B, 1, KV, hd)), dtype)
             for _ in range(2))
     n = jnp.asarray(lens, jnp.int32)
-    got_k, got_v = jax.jit(partial(cache_append, layer=1, interpret=True))(
-        ck, cv, k, v, n)
-    pos = np.clip(np.broadcast_to(np.asarray(lens), (B,)) - 1, 0, S - 1)
+    _, got_k, got_v = jax.jit(partial(decode_attention, layer=1,
+                                      interpret=True))(q, ck, cv, n, k=k, v=v)
+    after = np.broadcast_to(np.asarray(lens), (B,))
+    rows, = np.nonzero(after > 0)
     for got, old, new in ((got_k, ck, k), (got_v, cv, v)):
-        want = old.at[1, np.arange(B), :, :, pos].set(new[:, 0])
+        want = old.at[1, rows, :, :, after[rows] - 1].set(new[rows, 0])
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32))
 
@@ -410,6 +410,118 @@ def test_garbage_behind_the_live_length_is_never_read(length, dtype):
                                   np.asarray(dirty, np.float32))
 
 
+# ------------------------------------------- the step appends as it attends
+# With the step's new K/V as operands the kernel patches them into the last
+# live block it has fetched, attends from the patched buffers and writes the
+# block back once (PR 32). The oracle is what the step did before: the
+# append alone (``append_in_place``, kept for the latent cache), then the
+# read-only call.
+FUSED = {**SHAPES, "64heads-two-programs": (6, 64, 64, 128)}
+
+
+def _append_then_attend(q, ck, cv, k, v, n, layer, alibi=None):
+    """(o, cache_k, cache_v) by the two kernels of the parent; a slot of
+    length 0, which the parent's append wrote at position 0, keeps its row."""
+    from deepspeed_tpu.ops.decode_attention import append_in_place
+
+    ak, av = append_in_place((ck, cv), (k, v), n, jnp.int32(layer).reshape(1),
+                             name="cache_append", interpret=True)
+    idle = (np.asarray(n) == 0).reshape(1, -1, 1, 1, 1)
+    ak, av = jnp.where(idle, ck, ak), jnp.where(idle, cv, av)
+    return (decode_attention(q, ak, av, n, layer=layer, alibi_slopes=alibi,
+                             interpret=True), ak, av)
+
+
+def _new(B, KV, hd, dtype=jnp.float32):
+    """A step's new K and V, (B, 1, KV, hd) each."""
+    rng = np.random.default_rng(1)
+    return tuple(jnp.asarray(rng.standard_normal((B, 1, KV, hd)), dtype)
+                 for _ in range(2))
+
+
+def _bits(x):
+    return np.asarray(x, np.float32)
+
+
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("shape", list(FUSED))
+def test_appending_call_equals_append_then_attend(shape, alibi):
+    """Lengths 0, 1, 127, 128, 129, 300, max_len and past it (clamped) in
+    one batch: the output and both caches of the one call, bit for bit
+    those of the append followed by the read-only call. With 64 heads of
+    128 in bfloat16 two programs take a slot, each its own heads' block."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+
+    B, H, KV, hd = FUSED[shape]
+    dtype = jnp.bfloat16 if shape == "64heads-two-programs" else jnp.float32
+    q, ck, cv, n = _case(B, H, KV, hd, dtype=dtype, layers=2)
+    k, v = _new(B, KV, hd, dtype)
+    slopes = alibi_slopes(H) if alibi else None
+    want = _append_then_attend(q, ck, cv, k, v, n, 1, slopes)
+    got = decode_attention(q, ck, cv, n, k=k, v=v, layer=1,
+                           alibi_slopes=slopes, interpret=True)
+    for g, w, what in zip(got, want, ("o", "cache_k", "cache_v")):
+        np.testing.assert_array_equal(_bits(g), _bits(w), err_msg=what)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_short_slot_behind_a_long_one_is_patched_by_its_owner(dtype):
+    """A slot of at most 128 positions behind a long one: its only block is
+    the one the program before it fetched ahead, unpatched; the slot's own
+    program patches it (and a long slot behind a short one, and an empty
+    one between)."""
+    lens = [300, 5, S3, 128, 200, 1, 0, 2, 257, 127]
+    q, ck, cv, _ = _case(len(lens), 4, 4, 64, dtype=dtype, layers=2)
+    k, v = _new(len(lens), 4, 64, dtype)
+    n = jnp.asarray(lens, jnp.int32)
+    want = _append_then_attend(q, ck, cv, k, v, n, 0)
+    got = decode_attention(q, ck, cv, n, k=k, v=v, layer=0, interpret=True)
+    for g, w, what in zip(got, want, ("o", "cache_k", "cache_v")):
+        np.testing.assert_array_equal(_bits(g), _bits(w), err_msg=what)
+    rows = np.arange(len(lens))[np.asarray(lens) > 0]
+    at = np.asarray(lens)[rows] - 1
+    np.testing.assert_array_equal(_bits(got[1])[0, rows, :, :, at],
+                                  _bits(k)[rows, 0])
+
+
+@pytest.mark.parametrize("slot,length", [(0, 1), (3, 128), (5, 129),
+                                         (7, S3 + 9)])
+def test_append_touches_one_column_of_one_slot_of_one_layer(slot, length):
+    """One slot appends, its neighbours hold nothing: of the three layers
+    of both caches exactly ``KV x hd`` values change, those at
+    ``length - 1`` (clamped) of that slot in that layer."""
+    B, KV, hd = 8, 5, 64
+    q, ck, cv, _ = _case(B, KV, KV, hd, layers=3)
+    k, v = 100.0 + q, -100.0 - q                  # no value of the cache
+    n = jnp.zeros((B,), jnp.int32).at[slot].set(length)
+    _, got_k, got_v = decode_attention(q, ck, cv, n, k=k, v=v, layer=1,
+                                       interpret=True)
+    for got, old, new in ((got_k, ck, k), (got_v, cv, v)):
+        moved = np.argwhere(_bits(got) != _bits(old))
+        assert len(moved) == KV * hd
+        assert {tuple(m[[0, 1, 4]]) for m in moved} \
+            == {(1, slot, min(length, S3) - 1)}
+        np.testing.assert_array_equal(
+            _bits(got)[1, slot, :, :, min(length, S3) - 1], _bits(new)[slot, 0])
+
+
+def test_read_only_call_is_the_appended_caches_reader():
+    """Without new values the call reads and hands back the output alone:
+    over the caches the appending call returned it gives that call's
+    output, bit for bit (the paged view's slab, a caller that appended)."""
+    q, ck, cv, n = _case(8, 8, 2, 64, layers=2)
+    k, v = _new(8, 2, 64)
+    o, ak, av = decode_attention(q, ck, cv, n, k=k, v=v, layer=1,
+                                 interpret=True)
+    again = decode_attention(q, ak, av, n, layer=1, interpret=True)
+    assert isinstance(again, jax.Array)
+    np.testing.assert_array_equal(_bits(again), _bits(o))
+    slab = decode_attention(q, ck[1], cv[1], n, k=k, v=v, interpret=True)
+    for g, w in zip(slab, (o, ak[1], av[1])):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
 def test_heads_per_program_follow_the_shapes():
     """No knob: the KV heads a program takes are the largest divisor of
     ``KV`` whose K block fits the budget, whatever the batch."""
@@ -448,3 +560,30 @@ def test_decode_step_span_says_fetched_over_live():
     # the first step: one request of 120 tokens seated, attending 121
     # positions of its one block; two slots idle at length 1: a block each
     assert ratios[0] == pytest.approx(3 * 128 / 121)
+
+
+def test_decode_step_span_says_append_moved_over_new():
+    """``append_moved_over_new`` on the ``decode_step`` span: the block of
+    128 positions the kernel writes back for every slot whose length is
+    over 0 (after a step: every slot) over the one new position of each
+    running request."""
+    import deepspeed_tpu as ds
+
+    cfg, model, params = _family("mha-hd64")
+    eng = ds.init_inference(model, params, {"dtype": "float32",
+                                            "eos_token_id": 7,
+                                            "flash_decode": True})
+    srv = ds.ServingEngine(eng, {"slots": 3, "max_len": S, "greedy": True,
+                                 "prefill_chunk": 64, "spans": True})
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(8, 256, (P,)).astype(np.int32)
+               for P in (120, 9, 60, 130)]
+    srv.serve_batch(prompts, [12, 3, 4, 5], [1, 2, 3, 4])
+    steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
+    assert len(steps) > 0
+    # the first step: one request running, three slots written
+    assert steps[0].meta["append_moved_over_new"] == 3 * 128 / 1
+    running = {e.meta["slots"] for e in steps}
+    assert running >= {1, 2, 3}
+    for e in steps:
+        assert e.meta["append_moved_over_new"] == 3 * 128 / e.meta["slots"]
