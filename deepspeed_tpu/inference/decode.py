@@ -119,8 +119,17 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     bridges in ``serving/pages.py`` turn pages into the contiguous view.
 
     Latent attention (``cfg.latent_dim > 0``): ``(L, batch, rank + rope,
-    max_len)``, contiguous only."""
+    max_len)``, contiguous only.
+
+    A looped trunk (``cfg.loop_steps > 1``) keeps a pass's keys and values
+    apart from every other pass's: ``L`` = ``n_layer x loop_steps`` planes,
+    pass ``r``'s layer ``l`` at ``r * n_layer + l``, contiguous only."""
     # (duck-typed configs of other trunks have no attention kinds: K/V)
+    loops = getattr(cfg, "loop_steps", 1)
+    if loops > 1 and page_size > 0:
+        raise NotImplementedError(
+            "the paged pool holds one plane a layer; a looped trunk's "
+            "n_layer x loop_steps planes are contiguous only")
     if getattr(cfg, "latent_dim", 0):
         if page_size > 0:
             raise NotImplementedError(
@@ -131,8 +140,8 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     if page_size > 0:
         return ((cfg.n_layer, pages, cfg.kv_heads, page_size, cfg.head_dim),
                 dtype or cfg.dtype)
-    return ((cfg.n_layer, batch, cfg.kv_heads, cfg.head_dim, max_len),
-            dtype or cfg.dtype)
+    return ((cfg.n_layer * loops, batch, cfg.kv_heads, cfg.head_dim,
+             max_len), dtype or cfg.dtype)
 
 
 def cache_buffers(shape: tuple) -> int:
@@ -506,11 +515,11 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         out, _aux = mlp(y2, p)
         x = x + o + out
     else:
-        x = x + o
+        x = x + model._post_norm(o, p, "ln1")      # sandwich norms, if any
         y2 = _norm(x, p["ln2_scale"], p.get("ln2_bias"), cfg.norm,
                    cfg.norm_eps)
         out, _aux = mlp(y2, p)
-        x = x + out
+        x = x + model._post_norm(out, p, "ln2")
     if paged is not None:
         return x, cache_k, cache_v, scale_k, scale_v
     return x, cache_k, cache_v
@@ -667,7 +676,8 @@ def _decode_head(model, params, x):
 def forward_with_cache(model, params, input_ids, cache: KVCache,
                        positions=None, flash_decode: bool = False,
                        last_token_head: bool = False, last_index=None,
-                       with_stats: bool = False, with_routing: bool = False):
+                       with_stats: bool = False, with_routing: bool = False,
+                       with_passes: bool = False):
     """Run T tokens through all layers, appending to the cache.
 
     input_ids: (B, T). Works for both prefill (T = prompt length, cache
@@ -690,7 +700,9 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
     the trunk has none to give (only the latent path collects them);
     ``with_routing`` a further one, the experts chosen ``(expert layers, B,
     T, k)``: what a comparison with a reference needs to follow the system's
-    choice at a near-tie.
+    choice at a near-tie. ``with_passes`` a last one: what a looped trunk's
+    passes left (``TransformerLM.loop_passes``: every pass's closed hidden
+    state and the exit distribution), None for any other trunk.
     """
     cfg = model.cfg
     B, T = input_ids.shape
@@ -717,7 +729,7 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
         x = _norm(x, params["embed_ln_scale"], params.get("embed_ln_bias"),
                   cfg.norm, cfg.norm_eps)
 
-    stats = None
+    stats = passes = None
     if isinstance(cache, LatentCache):
         x, new_cache, stats = _forward_latent(model, params, x, cache,
                                               new_len, positions, flash_decode)
@@ -758,14 +770,31 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
             return _layer_step(model, x, lp, ck, cv, new_len, positions,
                                flash_decode=fused, layer=layer), None
 
-        carry, first = (x, cache.k, cache.v), 0
-        for (_, n), seg in zip(cfg.segments,
-                               model.segment_params(params["layers"])):
-            carry, _ = lax.scan(
-                scan_fn, carry,
-                (seg, jnp.arange(first, first + n, dtype=jnp.int32)))
-            first += n
-        x, ck, cv = carry
+        def stack(carry, plane0=None):
+            """Every layer once; ``plane0`` (traced) is the cache plane of
+            layer 0 where that is not plane 0: a looped trunk's later
+            passes."""
+            first = 0
+            for (_, n), seg in zip(cfg.segments,
+                                   model.segment_params(params["layers"])):
+                planes = jnp.arange(first, first + n, dtype=jnp.int32)
+                carry, _ = lax.scan(
+                    scan_fn, carry,
+                    (seg, planes if plane0 is None else plane0 + planes))
+                first += n
+            return carry
+
+        if cfg.loop_steps > 1:
+            # the passes are a loop of the program too (one layer body):
+            # each appends to and reads from its own n_layer planes
+            def one_pass(x, kv, r):
+                x, ck, cv = stack((x, *kv), r * cfg.n_layer)
+                return x, (ck, cv)
+
+            x, (ck, cv), passes = model.loop_passes(
+                params, x, (cache.k, cache.v), one_pass)
+        else:
+            x, ck, cv = stack((x, cache.k, cache.v))
         new_cache = KVCache(k=ck, v=cv, length=new_len)
     if last_token_head:
         x = x[:, -1:] if last_index is None else \
@@ -773,7 +802,8 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
     logits = _decode_head(model, params, x)
     counters, routing = stats if stats is not None else (None, None)
     return (logits, new_cache) + ((counters,) if with_stats else ()) \
-        + ((routing,) if with_routing else ())
+        + ((routing,) if with_routing else ()) \
+        + ((passes,) if with_passes else ())
 
 
 class GenCarry(NamedTuple):
@@ -848,7 +878,7 @@ def prefill_tokens(model, params, input_ids, rng, *, max_new: int,
 def decode_step(model, params, carry: GenCarry, *, sampler,
                 eos_token_id=None, flash_decode: bool = False,
                 logit_guard: bool = False, poison_row=None,
-                moe_stats: bool = False):
+                moe_stats: bool = False, exit_pdf: bool = False):
     """ONE decode iteration: forward the carry token, sample the next.
 
     The single definition shared by :func:`decode_tokens`' scan body and
@@ -870,14 +900,17 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
 
     ``moe_stats=True`` (with ``logit_guard``) returns ``(carry, ok, stats,
     routing)``: the step's expert-layer counters, for the same read-back,
-    and the experts it chose (expert layers, B, 1, k)."""
+    and the experts it chose (expert layers, B, 1, k). ``exit_pdf=True``
+    (with ``logit_guard``; a looped trunk with its gate) returns ``(carry,
+    ok, pdf)``: each row's distribution over exit passes (B, passes), for
+    that read-back too."""
     from .sampling import split_keys
 
     tok, cache, rng, done = carry
     with jax.named_scope("decode_step"):
-        lg, cache, stats, routing = forward_with_cache(
+        lg, cache, stats, routing, passes = forward_with_cache(
             model, params, tok[:, None], cache, flash_decode=flash_decode,
-            with_stats=True, with_routing=True)
+            with_stats=True, with_routing=True, with_passes=True)
     if poison_row is not None:
         bad = jnp.arange(lg.shape[0], dtype=jnp.int32)[:, None, None] \
             == poison_row
@@ -890,6 +923,8 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
     out = GenCarry(nxt, cache, rng, done)
     if logit_guard:
         ok = jnp.all(jnp.isfinite(lg), axis=(1, 2))
+        if exit_pdf:
+            return out, ok, passes["exit_pdf"][:, 0]
         return (out, ok, stats, routing) if moe_stats else (out, ok)
     return out
 

@@ -84,6 +84,29 @@ def deepseek_v3(size: str = "kanana-2-30b-a3b", **overrides) -> TransformerConfi
     return TransformerConfig(**base)
 
 
+def ouro(size: str = "2.6b", **overrides) -> TransformerConfig:
+    """The Ouro looped LM (``model_type: ouro``, arXiv:2510.25741): a
+    Llama-shaped trunk (rope, RMSNorm, SwiGLU, no biases, untied head) with
+    a norm after each sub-layer as well as before it, applied
+    ``total_ut_steps`` times over the same weights, the final norm closing
+    every pass, and an exit gate. ``2.6b`` is ByteDance/Ouro-2.6B's
+    ``config.json``; its ``early_exit_threshold`` 1 exits at the last pass,
+    which is the one thing the trunk does."""
+    table = {
+        "tiny": dict(n_layer=4, n_head=4, d_model=64, d_ff=176,
+                     vocab_size=251, max_seq=256, loop_steps=3),
+        "2.6b": dict(n_layer=48, n_head=16, d_model=2048, d_ff=5632,
+                     vocab_size=49152, max_seq=65536, loop_steps=4),
+    }
+    base = dict(pos_embedding="rope", norm="rmsnorm", norm_eps=1e-6,
+                activation="silu_glu", use_bias=False, tie_embeddings=False,
+                rope_theta=1e6, sandwich_norm=True, exit_gate=True,
+                fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def bert(size: str = "base", **overrides) -> TransformerConfig:
     """Encoder (bidirectional) trunk + MLM objective — the BERT family the
     reference's flagship pretraining baseline uses

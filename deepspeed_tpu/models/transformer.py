@@ -116,6 +116,21 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # looped trunk (Ouro, arXiv:2510.25741): the whole stack of n_layer
+    # layers is applied loop_steps times to every token over the SAME
+    # weights, the final norm closing every pass (the last included); a
+    # pass has its own keys and values, so the cache holds n_layer x
+    # loop_steps planes (inference/decode.py cache_layout)
+    loop_steps: int = 1
+    # a norm AFTER each sub-layer as well as before it: x + norm(attn(
+    # norm(x))), x + norm(mlp(norm(x))) (ln1_post_scale, ln2_post_scale)
+    sandwich_norm: bool = False
+    # a looped trunk's exit gate: sigmoid of ONE Linear(d_model, 1) on the
+    # hidden state closing each pass; exit_pdf() turns the passes' values
+    # into the distribution over exit passes. Every pass always runs (the
+    # published early_exit_threshold 1 exits at the last): the
+    # distribution comes back beside the logits, it steers nothing
+    exit_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -175,9 +190,10 @@ class TransformerConfig:
 
         For MoE only the ``moe_top_k`` routed experts do work per token, so
         FLOPs use the *active* parameter count, not the total bank size."""
-        n_params = self.param_count(non_embedding=True, active_only=True)
+        n_params = self.loop_steps * self.param_count(
+            non_embedding=True, active_only=True)   # every pass multiplies
         # scores + values: 2 * S * H * (qk width + v width) forward, x3
-        attn = 6 * self.n_layer * self.n_head * (
+        attn = 6 * self.loop_steps * self.n_layer * self.n_head * (
             self.head_dim + self.v_dim) * self.max_seq
         head = (0 if self.objective == "feature"
                 else 6 * self.d_model * self.vocab_size)
@@ -275,6 +291,17 @@ def _activation(u, name: str):
     if name == "quick_gelu":
         return u * jax.nn.sigmoid(1.702 * u)       # CLIP's sigmoid approx
     raise ValueError(f"unknown activation {name!r}")
+
+
+def exit_pdf(lam):
+    """A looped trunk's gate values ``lam`` (passes, ...) as the distribution
+    over exit passes (..., passes): p_r = lam_r * prod_{j<r} (1 - lam_j),
+    and the last pass takes what is left, prod_{j<R} (1 - lam_j) — its own
+    gate value is never used."""
+    before = jnp.cumprod(jnp.concatenate(
+        [jnp.ones_like(lam[:1]), 1.0 - lam[:-1]]), axis=0)
+    return jnp.moveaxis(jnp.concatenate(
+        [lam[:-1] * before[:-1], before[-1:]]), 0, -1)
 
 
 def vocab_parallel_lookup(table, ids):
@@ -477,6 +504,18 @@ class TransformerLM:
                     "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
         elif config.attention != "mha":
             raise ValueError(f"unknown attention kind {config.attention!r}")
+        if config.loop_steps < 1 or (config.exit_gate
+                                     and config.loop_steps == 1):
+            raise ValueError("loop_steps counts the trunk's passes (>= 1); "
+                             "exit_gate is the gate between them")
+        if (config.loop_steps > 1 or config.sandwich_norm) and (
+                config.attention != "mha" or config.num_experts > 1
+                or config.post_ln or config.parallel_residual
+                or config.use_bias or config.objective != "clm"):
+            raise ValueError(
+                "a looped trunk (loop_steps > 1) and sandwich norms are the "
+                "Ouro block: a causal LM of pre-norm MHA layers with a "
+                "dense FFN, two-hop residual, no biases")
         self.attention_fn = attention_fn or partial(causal_attention,
                                                     causal=config.causal)
 
@@ -525,6 +564,10 @@ class TransformerLM:
             params["lm_head_bias"] = jnp.zeros((cfg.vocab_size,), jnp.float32)
         if not cfg.tie_embeddings and cfg.objective != "feature":
             params["lm_head"] = dense(next(k), (d, cfg.vocab_size), scale=0.02)
+        if cfg.exit_gate:
+            # on a closed pass (unit RMS) the gate's logit is ~N(0, 1)
+            params["exit_gate_w"] = dense(next(k), (d,))
+            params["exit_gate_b"] = jnp.zeros((), jnp.float32)
         return params
 
 
@@ -560,6 +603,15 @@ class TransformerLM:
             })
         if two_ln:
             layers["ln2_scale"] = jnp.ones((L, d), jnp.float32)
+        if cfg.sandwich_norm:
+            # a norm after the sub-layer undoes the depth scaling of wo /
+            # w_out below, so its gain carries it: every branch then adds
+            # 1/sqrt(2 depth) of a unit vector to the stream, as in the
+            # plain block. At gain 1 a randomly initialised trunk is
+            # chaotic: bf16 and float32 logits part by 0.2-0.4 of the
+            # largest (PERF.md, PR 34)
+            post = jnp.full((L, d), 1.0 / math.sqrt(2 * depth), jnp.float32)
+            layers["ln1_post_scale"] = layers["ln2_post_scale"] = post
         if dense_ffn:
             layers["w_in"] = dense(next(k), (L, d, f))
             layers["w_out"] = dense(next(k), (L, f, d),
@@ -618,6 +670,9 @@ class TransformerLM:
             specs["lm_head"] = P(None, "model")
         if cfg.lm_head_bias:
             specs["lm_head_bias"] = P("model")
+        if cfg.exit_gate:
+            specs["exit_gate_w"] = P(None)
+            specs["exit_gate_b"] = P()
         return specs
 
     def _segment_specs(self, kind: str) -> dict:
@@ -642,6 +697,9 @@ class TransformerLM:
             })
         if two_ln:
             layers["ln2_scale"] = P(None, None)
+        if cfg.sandwich_norm:
+            layers["ln1_post_scale"] = P(None, None)
+            layers["ln2_post_scale"] = P(None, None)
         if dense_ffn:
             layers["w_in"] = P(None, None, "model")
             layers["w_out"] = P(None, "model", None)
@@ -803,12 +861,20 @@ class TransformerLM:
             out, aux = self._mlp_block(y, p)
             x = x + o + out
         else:
-            x = x + o
+            x = x + self._post_norm(o, p, "ln1")
             y = _norm(x, p["ln2_scale"], p.get("ln2_bias"),
                       cfg.norm, cfg.norm_eps)
             out, aux = self._mlp_block(y, p)
-            x = x + out
+            x = x + self._post_norm(out, p, "ln2")
         return constrain(x, P(B_AXES, "seq", None)), aux
+
+    def _post_norm(self, y, p, ln: str):
+        """A sub-layer's output on its way into the residual stream: normed
+        once more under ``sandwich_norm``, as it is otherwise."""
+        if not self.cfg.sandwich_norm:
+            return y
+        return _norm(y, p[f"{ln}_post_scale"], None, self.cfg.norm,
+                     self.cfg.norm_eps)
 
     def _tok_lookup(self, table, ids):
         return vocab_parallel_lookup(table, ids)
@@ -880,11 +946,43 @@ class TransformerLM:
     def _head_norm(self, params, x):
         """Final layernorm only (the pipeline's vocab-sharded head applies
         its own unembedding slice). Post-LN trunks have no final norm —
-        each block already ends normalized."""
-        if self.cfg.post_ln:
+        each block already ends normalized — and a looped trunk's passes
+        each end in it (:meth:`loop_passes`), the last included."""
+        if self.cfg.post_ln or self.cfg.loop_steps > 1:
             return x
+        return self._final_norm(params, x)
+
+    def _final_norm(self, params, x):
         return _norm(x, params["lnf_scale"], params.get("lnf_bias"),
                      self.cfg.norm, self.cfg.norm_eps)
+
+    def loop_passes(self, params, x, carry, one_pass):
+        """A looped trunk's passes as ONE scan over the pass index:
+        ``one_pass(x, carry, r) -> (x, carry)`` runs the whole stack (the
+        same weights every pass; ``carry`` is what the caller threads
+        through, a cache or nothing), the final norm closes the pass, and
+        what it leaves is what the next pass starts from and what the gate
+        reads. Returns (the last pass's closed state, carry, {"hidden":
+        (passes, B, T, d) every pass's closed state, "exit_pdf": (B, T,
+        passes) float32, with ``exit_gate``})."""
+        gate = self.cfg.exit_gate
+
+        def body(c, r):
+            x, carry = one_pass(*c, r)
+            x = self._final_norm(params, x)
+            lam = jax.nn.sigmoid(
+                jnp.sum(x.astype(jnp.float32)
+                        * params["exit_gate_w"].astype(jnp.float32), -1)
+                + params["exit_gate_b"].astype(jnp.float32)) if gate else None
+            return (x, carry), (x, lam)
+
+        (x, carry), (hidden, lam) = lax.scan(
+            body, (x, carry),
+            jnp.arange(self.cfg.loop_steps, dtype=jnp.int32))
+        passes = {"hidden": hidden}
+        if gate:
+            passes["exit_pdf"] = exit_pdf(lam)
+        return x, carry, passes
 
     def _pre_head(self, params, x):
         """Final norm + (BERT) MLM transform: everything before the
@@ -930,14 +1028,25 @@ class TransformerLM:
         return () if self.cfg.tie_embeddings else ("tok_embed",)
 
     def _trunk(self, params, input_ids, attn_mask, remat_policy):
-        """Embed + layer stack: (B, S) → ((B, S, D) pre-final-norm, aux)."""
+        """Embed + layer stack: (B, S) → ((B, S, D) pre-final-norm, aux).
+        A looped trunk hands back its last pass's closed state (the head's
+        norm is then none) and, as aux, what :meth:`loop_passes` says of
+        the passes."""
         x, positions = self._embed(params, input_ids)
-        auxes = []
-        for seg in self.segment_params(params["layers"]):
-            x, a = self._scan_layers(x, seg, positions, attn_mask,
-                                     remat_policy)
-            auxes.append(a)
-        return x, self._join_aux(auxes)
+
+        def stack(x):
+            auxes = []
+            for seg in self.segment_params(params["layers"]):
+                x, a = self._scan_layers(x, seg, positions, attn_mask,
+                                         remat_policy)
+                auxes.append(a)
+            return x, self._join_aux(auxes)
+
+        if self.cfg.loop_steps > 1:
+            x, _, passes = self.loop_passes(
+                params, x, None, lambda x, _, r: (stack(x)[0], None))
+            return x, passes
+        return stack(x)
 
     def apply(self, params, input_ids, *, attn_mask=None, remat_policy=None,
               return_aux: bool = False):

@@ -175,6 +175,13 @@ class Engine:
                 "reduction order is compiler-managed (no pre-allreduce "
                 "division point exists), and fp16 overflow is handled by "
                 "dynamic loss scaling — remove the flag")
+        if getattr(getattr(model, "cfg", None), "loop_steps", 1) > 1:
+            raise ValueError(
+                "a looped trunk (loop_steps > 1) is served, not trained "
+                "here: its objective is the expected loss over the exit "
+                "distribution with an entropy term (arXiv:2510.25741), and "
+                "a next-token loss on the last pass under the model's name "
+                "would be a guess")
         mcfg = self.config.moe
         if mcfg.enabled:
             # ds_config moe section overrides the model's MoE knobs

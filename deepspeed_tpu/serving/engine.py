@@ -71,6 +71,14 @@ _MAX_RESULTS = 4096
 _DEGRADED_WINDOW = 64
 
 
+def _mean_exit_pdf(passes: dict, real):
+    """Of a batch-1 chunk of a looped trunk: the mean over its first
+    ``real`` (traced) positions of the distribution over exit passes."""
+    pdf = passes["exit_pdf"][0]                               # (T, passes)
+    keep = jnp.arange(pdf.shape[0])[:, None] < real
+    return jnp.sum(jnp.where(keep, pdf, 0.0), axis=0) / real
+
+
 def expand_per_request(v, n: int, default, coerce=None) -> list:
     """One scalar-or-per-request ``serve_batch`` argument expanded to
     ``n`` values (shared by ``ServingEngine`` and ``FleetEngine`` so the
@@ -145,6 +153,26 @@ class ServingEngine:
                 raise ValueError(
                     "a latent (MLA) cache and sigmoid-routed expert layers "
                     "do not yet compose with " + ", ".join(refused))
+        # a looped trunk (models/transformer.py loop_steps): its passes'
+        # n_layer x loop_steps cache planes are contiguous bf16/fp only
+        self._loops = int(getattr(mcfg, "loop_steps", 1))
+        self._exit_gate = self._loops > 1 and mcfg.exit_gate
+        if self._loops > 1:
+            refused = [why for why, on in (
+                ("the paged pool (page_size): it holds one plane a layer",
+                 self.cfg.page_size > 0),
+                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
+                 "pool", bool(self.cfg.kv_quant_bits)),
+                ("speculation: its verify forward has no pass loop under a "
+                 "test", self.cfg.speculation is not None
+                 and self.cfg.speculation.enabled),
+                ("a mesh of several devices: no sharding of n_layer x "
+                 "loop_steps planes is under a test", engine.mesh.size > 1))
+                if on]
+            if refused:
+                raise ValueError(
+                    f"a looped trunk (loop_steps={self._loops}) does not "
+                    "yet compose with " + "; ".join(refused))
         self._flash = engine.config.flash_decode_resolved()
         if self._flash and self.cfg.max_len % 128 != 0:
             raise ValueError(
@@ -165,7 +193,19 @@ class ServingEngine:
         self.routing_log: Optional[dict] = None
         self._chunk_routing: list = []   # (rid, start, device routing, real)
         self._cache_bytes_per_token = cache_bytes_per_token(
-            mcfg, engine.compute_dtype) if self._latent else None
+            mcfg, engine.compute_dtype) \
+            if self._latent or self._loops > 1 else None
+        if self._loops > 1:
+            # what a looped program reads of the weights, from the served
+            # tree's shapes: the layers once a pass, and the head (with the
+            # closing norm and the gate) once
+            layers, whole = (sum(a.nbytes for a in jax.tree.leaves(tree))
+                             for tree in (engine.params["layers"],
+                                          engine.params))
+            self._loop_weight_bytes = (
+                self._loops * layers,
+                whole - layers - engine.params["tok_embed"].nbytes
+                * (not mcfg.tie_embeddings))
         self._eos = engine.config.eos_token_id
         self._sampler = engine._sampler(self.cfg.temperature, self.cfg.top_k,
                                         self.cfg.top_p, self.cfg.greedy)
@@ -606,9 +646,11 @@ class ServingEngine:
         is never computed (nothing consumes the logits, XLA removes it)."""
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
-        _, cache, stats, routing = forward_with_cache(
+        _, cache, stats, routing, passes = forward_with_cache(
             self.model, mat(params), ids, cache, with_stats=True,
-            with_routing=True)
+            with_routing=True, with_passes=True)
+        if self._exit_gate:
+            return cache, _mean_exit_pdf(passes, ids.shape[1]), None
         return (cache, stats, routing) if self._moe_stats else cache
 
     def _final_impl(self, params, cache, ids, start, last_index, true_len,
@@ -618,25 +660,30 @@ class ServingEngine:
         put it before the chunk end), leaving the cache at ``true_len``."""
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
-        logits, cache, stats, routing = forward_with_cache(
+        logits, cache, stats, routing, passes = forward_with_cache(
             self.model, mat(params), ids, cache, last_token_head=True,
-            last_index=last_index, with_stats=True, with_routing=True)
+            last_index=last_index, with_stats=True, with_routing=True,
+            with_passes=True)
         rng, sub = split_keys(rng)
         tok = self._sampler(logits[:, -1], sub)
         done = (tok == self._eos) if self._eos is not None \
             else jnp.zeros(tok.shape, bool)
         pf = GenCarry(tok=tok, cache=cache._replace(length=true_len),
                       rng=rng, done=done)
+        if self._exit_gate:
+            return pf, _mean_exit_pdf(passes, last_index + 1), None
         return (pf, stats, routing) if self._moe_stats else pf
 
     def _step_impl(self, params, carry):
         # logit_guard: the (B,) per-row finiteness flags ride the step's
         # existing fused read-back — the guard costs zero extra host syncs
         # ... and neither do the expert layers' counters (moe_stats: a
-        # third result on that same read-back, for the decode_step span)
+        # third result on that same read-back, for the decode_step span;
+        # exit_pdf: a looped trunk's exit distribution a slot, likewise)
         return decode_step(self.model, params, carry, sampler=self._sampler,
                            eos_token_id=self._eos, flash_decode=self._flash,
-                           logit_guard=True, moe_stats=self._moe_stats)
+                           logit_guard=True, moe_stats=self._moe_stats,
+                           exit_pdf=self._exit_gate)
 
     def _step_chaos_impl(self, params, carry, poison_row):
         """Chaos build of the step: identical program + a traced poison-row
@@ -996,6 +1043,33 @@ class ServingEngine:
                 "experts_touched": float(st[:, 1].mean()),
                 "cache_bytes_per_token": self._cache_bytes_per_token}
 
+    def _loop_meta(self, tokens: int, head: bool = True) -> dict:
+        """What a looped trunk's ``decode_step`` and ``prefill_chunk`` spans
+        say beside their times, all host arithmetic: the passes, the cache
+        planes and bytes a token costs (``cache_layout()``), and the bytes
+        of weights the program reads — the layers once a pass, the head —
+        for each of the ``tokens`` it works on."""
+        layer_bytes, head_bytes = self._loop_weight_bytes
+        return {"loop_steps": self._loops,
+                "cache_planes": self.model.cfg.n_layer * self._loops,
+                "cache_bytes_per_token": self._cache_bytes_per_token,
+                "weight_bytes_per_token":
+                    (layer_bytes + head * head_bytes) / max(tokens, 1)}
+
+    def _loop_counts(self, pdfs: list, pending: list, running) -> dict:
+        """Meta of a looped trunk's ``decode_step`` span: :meth:`_loop_meta`
+        over the requests running at dispatch and, where the trunk has its
+        gate, ``exit_pdf``: the mean over their slots of the distribution
+        over exit passes, which the read-back brought beside the tokens
+        (``pdfs[0]``, (slots, passes)). The chunks' means (``pdfs[1:]``) go
+        onto their own ``prefill_chunk`` spans."""
+        for (chunk_span, _, _), pdf in zip(pending, pdfs[1:]):
+            chunk_span.amend(exit_pdf=pdf.tolist())
+        meta = self._loop_meta(len(running))
+        if pdfs:
+            meta["exit_pdf"] = pdfs[0][running].mean(0).tolist()
+        return meta
+
     def _attn_counts(self) -> dict:
         """Of the step just dispatched, two ratios in which 1 is ideal.
         ``attn_fetched_over_live``: the positions ``decode_attention``
@@ -1125,7 +1199,11 @@ class ServingEngine:
                             self._log_routing(
                                 moe.pop(1), tapped,
                                 [moe.pop() for _ in tapped][::-1])
-                        counts.update(self._moe_counts(moe, pending))
+                        counts.update(
+                            self._loop_counts(moe, pending,
+                                              list(self.sched.running))
+                            if self._loops > 1
+                            else self._moe_counts(moe, pending))
                 t1 = self.stats.clock()
                 self._last_step_s = t1 - t0
                 # the parent of the decode pair, from the t0/t1 the
@@ -1356,6 +1434,9 @@ class ServingEngine:
         with self._span(_spans.PREFILL_CHUNK, name="srv.prefill_chunk",
                         rid=req.rid, step=n_it, chunk=idx, size=ch.size,
                         final=ch.final, **ahead,
+                        **(self._loop_meta(ch.last_index + 1 if ch.final
+                                           else ch.size, head=ch.final)
+                           if self._loops > 1 else {}),
                         **self.sched._attempt_meta(req)) as chunk_span:
             ids = jnp.asarray(ch.ids[None], jnp.int32)
             if not ch.final:
@@ -1389,9 +1470,10 @@ class ServingEngine:
         if ahead is None or ahead[0] is not plan[idx]:
             ahead = self._chunk_dispatch(n_it)
         ch, out, chunk_span = ahead
-        if self._moe_stats:
-            # the chunk's expert counters stay on the device until the
-            # next decode read-back fetches them beside its own
+        if self._moe_stats or self._exit_gate:
+            # the chunk's expert counters (a looped trunk's: its mean exit
+            # distribution) stay on the device until the next decode
+            # read-back fetches them beside its own
             out, stats, routing = out
             if chunk_span.recording:
                 self._chunk_stats.append((chunk_span, stats, ch.size))

@@ -372,3 +372,79 @@ def test_latent_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch):
     for kernel in ("mla_decode_attention", "mla_cache_append",
                    "moe_experts_up", "moe_experts_down"):
         assert any(kernel in ln for ln in calls), f"{kernel} absent"
+
+
+# ------------------------------------------------------------ a looped trunk
+@pytest.mark.parametrize("program", ["slot step", "final chunk"])
+def test_looped_trunk_keeps_the_cache_in_place(one_chip, monkeypatch,
+                                               program):
+    """Ouro-2.6B whole at the cell's 12 slots x 384: the pass loop and the
+    layer loop are loops of the program around ONE kernel call (not 192
+    unrolled layers), the 192-plane cache enters donated and leaves aliased,
+    temporaries stay under 64 MiB, and the step's live set leaves the chip
+    (15.75 GiB) over 1 GiB beside the batch-1 prefill cache."""
+    from deepspeed_tpu.inference.decode import (forward_with_cache,
+                                                init_cache)
+    from deepspeed_tpu.models import ouro
+    from deepspeed_tpu.serving.slots import init_slots
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 12, 384, 128
+    cfg = ouro("2.6b", dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def served(key):
+        """The tree as ``InferenceEngine`` serves it: bf16, [wq | wk | wv]
+        one weight (with the three apart the compiler merges the products
+        itself and concatenates the whole stacks outside the loops: 1.4 GiB
+        of temporaries)."""
+        p = jax.tree.map(lambda a: a.astype(jnp.bfloat16), model.init(key))
+        layers = dict(p["layers"])
+        layers["wqkv"] = jnp.concatenate(
+            [layers.pop(k) for k in ("wq", "wk", "wv")], axis=-1)
+        return {**p, "layers": layers}
+
+    params = on_chip(jax.eval_shape(served, jax.random.PRNGKey(0)))
+    planes = cfg.n_layer * cfg.loop_steps
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, slots, max_len, jnp.bfloat16)))
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, exit_pdf=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        batch = slots
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, max_len, jnp.bfloat16)))
+        ids = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+        i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=True, last_index=last, with_passes=True),
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        batch = 1
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * planes * batch * 16 * 128 * max_len * 2
+    assert planes == 192 and cache_bytes == batch * max_len * 1572864
+    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    prefill_cache = max_len * 1572864
+    assert live + (prefill_cache if batch > 1 else 0) \
+        < (15.75 - 1.0) * 2 ** 30, live
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert len(loops) == 2, len(loops)        # the passes, the layers
+    if program == "slot step":
+        assert len(calls) == 1 and "decode_attention" in calls[0]
+    else:
+        assert not calls      # T > 1 attends densely over its plane
