@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -297,27 +297,28 @@ def _paged_append(ck, cv, ks, vs, k, v, page_table, new_len):
     writes. T == 1 is the plain decode step; T > 1 is the speculative
     verify forward (``serving/engine.py``), whose headroom gate
     guarantees every live row has ``new_len <= max_len`` so the clip
-    below never folds a live write back onto the row's last page. Rows
-    whose table entries are scratch (idle or freshly retired slots)
-    write harmlessly into page 0; a live row past its last page clips
-    onto scratch-redirected entries the host cleared at retirement, so
-    stale rows can never touch another slot's pages."""
+    below never folds a live write back onto the row's last page. A row
+    that is not running has ``new_len`` 0 (:func:`forward_with_cache`)
+    and writes nowhere: its page id is put behind the pool and the
+    scatter drops it, whatever its table row still holds (the host
+    clears a retired row's table only at the end of its iteration)."""
     B, T = k.shape[0], k.shape[1]
     ps, n = ck.shape[2], page_table.shape[1]
     pos = (new_len - T)[:, None] + jnp.arange(T, dtype=new_len.dtype)[None, :]
     pidx = jnp.clip(pos // ps, 0, n - 1)
     pid = jnp.take_along_axis(page_table, pidx, axis=1)     # (B, T)
+    pid = jnp.where((new_len > 0)[:, None], pid, ck.shape[0])
     off = pos % ps
     if ks is not None:
         qk, sk = quantize_kv(k)
         qv, sv = quantize_kv(v)
-        ck = ck.at[pid, :, off, :].set(qk)
-        cv = cv.at[pid, :, off, :].set(qv)
-        ks = ks.at[pid, :, off].set(sk)
-        vs = vs.at[pid, :, off].set(sv)
+        ck = ck.at[pid, :, off, :].set(qk, mode="drop")
+        cv = cv.at[pid, :, off, :].set(qv, mode="drop")
+        ks = ks.at[pid, :, off].set(sk, mode="drop")
+        vs = vs.at[pid, :, off].set(sv, mode="drop")
     else:
-        ck = ck.at[pid, :, off, :].set(k.astype(ck.dtype))
-        cv = cv.at[pid, :, off, :].set(v.astype(cv.dtype))
+        ck = ck.at[pid, :, off, :].set(k.astype(ck.dtype), mode="drop")
+        cv = cv.at[pid, :, off, :].set(v.astype(cv.dtype), mode="drop")
     return ck, cv, ks, vs
 
 
@@ -712,8 +713,15 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
     # keeps every live slot's post-append length within max_len. Prefill
     # still runs through a contiguous per-request cache and is scattered
     # into pages at insert (serving/pages.py).
-    new_len = cache.length + T
     per_slot = getattr(cache.length, "ndim", 0) == 1
+    # ONE rule for the three cache kinds (new_len feeds each loop below): a
+    # slot at length 0 is not running (serving/slots.py: every seated
+    # request has its prompt cached) and stays at 0, where the decode
+    # kernels neither fetch nor write for it and the XLA appends land in
+    # the row's own extent (the paged pool: on the scratch page, through
+    # the row's cleared table), which the next insert overwrites whole
+    new_len = jnp.where(cache.length > 0, cache.length + T, 0) if per_slot \
+        else cache.length + T
     if positions is None:
         base = cache.length[:, None] if per_slot else cache.length
         positions = base + jnp.broadcast_to(
@@ -817,7 +825,11 @@ class GenCarry(NamedTuple):
     tok: jnp.ndarray         # (B,) i32 — latest sampled token
     cache: KVCache
     rng: jnp.ndarray         # (2,) or (B, 2) uint32
-    done: jnp.ndarray        # (B,) bool — eos reached
+    done: jnp.ndarray        # (B,) bool — eos reached (a slot: not running)
+    # serving slots only (serving/slots.py): (B,) i32, the tokens a row may
+    # still emit. None (no leaf: nothing is carried) wherever rows run
+    # together for a fixed number of steps
+    left: Optional[jnp.ndarray] = None
 
 
 def prefill_tokens(model, params, input_ids, rng, *, max_new: int,
@@ -903,10 +915,18 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
     and the experts it chose (expert layers, B, 1, k). ``exit_pdf=True``
     (with ``logit_guard``; a looped trunk with its gate) returns ``(carry,
     ok, pdf)``: each row's distribution over exit passes (B, passes), for
-    that read-back too."""
+    that read-back too.
+
+    A carry with ``left`` is the serving slots' (``serving/slots.py``): a
+    running row (not ``done``) has one token fewer left after the step; at
+    none, as at eos, it is ``done``, and a row that is ``done`` stands at
+    length 0: the forward leaves such a row where it is, so from the step
+    after its last token until the next insert it costs the decode kernels
+    nothing. The host retires a request on the same two conditions off the
+    same read-back, so it never has to tell the device."""
     from .sampling import split_keys
 
-    tok, cache, rng, done = carry
+    tok, cache, rng, done, left = carry
     with jax.named_scope("decode_step"):
         lg, cache, stats, routing, passes = forward_with_cache(
             model, params, tok[:, None], cache, flash_decode=flash_decode,
@@ -920,7 +940,11 @@ def decode_step(model, params, carry: GenCarry, *, sampler,
     if eos_token_id is not None:
         nxt = jnp.where(done, eos_token_id, nxt)
         done = done | (nxt == eos_token_id)
-    out = GenCarry(nxt, cache, rng, done)
+    if left is not None:
+        left = left - (~carry.done).astype(left.dtype)
+        done = done | (left <= 0)
+        cache = cache._replace(length=jnp.where(done, 0, cache.length))
+    out = GenCarry(nxt, cache, rng, done, left)
     if logit_guard:
         ok = jnp.all(jnp.isfinite(lg), axis=(1, 2))
         if exit_pdf:

@@ -125,8 +125,9 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
     n_slots, n_groups = pl.num_programs(0), pl.num_programs(1)
     hb, rows, _ = q_ref.shape
     S = k_hbm.shape[4]
-    # an idle slot's length keeps counting past the cache: never past it
-    # (the append clamps the same way)
+    # a caller's length may lie past the cache (a scalar that counts on):
+    # never past it (the append clamps the same way). A serving slot that
+    # is not running stands at 0: no fetch, no products, no write-back
     L = jnp.minimum(len_ref[b], S)
     nb = (L + block - 1) // block                        # only live blocks
 

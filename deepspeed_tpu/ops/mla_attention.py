@@ -50,7 +50,8 @@ def _refuse_mesh(what: str) -> None:
 def _mla_kernel(len_ref, _, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
                 block: int, rank: int, scale: float):
     b, j = pl.program_id(0), pl.program_id(1)
-    # an idle slot's length keeps counting past the cache
+    # a caller's length may lie past the cache; a slot that is not running
+    # stands at 0 and multiplies nothing
     L = jnp.minimum(len_ref[b], block * pl.num_programs(1))
 
     @pl.when(j == 0)

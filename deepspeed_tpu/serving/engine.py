@@ -52,7 +52,7 @@ from ..utils.logging import warning_once
 from .pages import (PagePool, export_slot, hydrate_cache, import_slot,
                     init_paged_slots, insert_paged)
 from .scheduler import Request, Scheduler
-from .slots import init_slots, insert_request
+from .slots import init_slots, insert_request, retire_slots
 
 # Serving programs kept per engine; generously above the steady-state set
 # (decode step + insert + 2 programs per chunk bucket) so eviction means a
@@ -334,10 +334,11 @@ class ServingEngine:
         # bit-for-bit the contiguous-slot engine, same program set.
         self._paged = self.cfg.page_size > 0
         # where the step attends with ``decode_attention`` over the slot
-        # cache: a host mirror of the slots' lengths (set at placement,
-        # advanced by one a step, as the device's vector is), from which
-        # the ``decode_step`` span says how far past the live positions the
-        # kernel fetched. No device read
+        # cache: a host mirror of the slots' lengths as the device's vector
+        # has them (set at placement, advanced by one a step while the row
+        # runs, 0 from its retirement on), from which the ``decode_step``
+        # span says how far past the live positions the kernel fetched. No
+        # device read
         self._slot_len = np.zeros(self.cfg.slots, np.int64) \
             if self._flash and not (self._paged or self._latent) else None
         self.pool: Optional[PagePool] = None
@@ -718,14 +719,16 @@ class ServingEngine:
         """Resolve the host-side acceptance: active rows rewind their
         cache length to the committed extent (rejected drafts' KV past
         it is dead by length — every future append overwrites position
-        == committed length first) and take their new carry token / done
-        flag. Inactive rows (idle slots, nonfinite-retired rows) keep
-        the verify step's values, mirroring how plain steps advance idle
-        rows — insert resets them either way.
+        == committed length first) and take their new carry token, done
+        flag and tokens left; a row whose request ends with this commit
+        (eos, or nothing left) is ``done`` at length 0, as the plain step
+        leaves it. Inactive rows are the slots that were not running,
+        which the verify forward left at length 0, and the non-finite
+        rows, which ``_spec_resolve`` retires next (``_unseat``).
 
-        ``packed`` is one (4, slots) int32 — active / new_len / new_tok /
-        new_done rows — so the commit costs a single host->device upload
-        per step instead of four."""
+        ``packed`` is one (5, slots) int32 — active / new_len / new_tok /
+        new_done / new_left rows — so the commit costs a single
+        host->device upload per step instead of five."""
         active = packed[0].astype(bool)
         new_len, new_tok = packed[1], packed[2]
         new_done = packed[3].astype(bool)
@@ -733,7 +736,8 @@ class ServingEngine:
         length = jnp.where(active, new_len, cache.length)
         tok = jnp.where(active, new_tok, carry.tok)
         done = jnp.where(active, new_done, carry.done)
-        return carry._replace(tok=tok, done=done,
+        left = jnp.where(active, packed[4], carry.left)
+        return carry._replace(tok=tok, done=done, left=left,
                               cache=cache._replace(length=length))
 
     def _spec_plan(self):
@@ -803,9 +807,10 @@ class ServingEngine:
         m, vok = jax.device_get((m_dev, ok_dev))
         eos = self._eos
         B = self.cfg.slots
-        # rows: active, new_len, new_tok, new_done — one packed upload
-        packed = np.zeros((4, B), np.int32)
-        active, new_len, new_tok, new_done = packed
+        # rows: active, new_len, new_tok, new_done, new_left — one packed
+        # upload
+        packed = np.zeros((5, B), np.int32)
+        active, new_len, new_tok, new_done, new_left = packed
         emitted: dict = {}
         bad: list = []
         proposed = accepted = first_scored = first_hits = 0
@@ -832,9 +837,12 @@ class ServingEngine:
                     first_hits += 1
             live = len(req.prompt) + len(req.tokens) - 1
             active[slot] = True
-            new_len[slot] = live + len(toks)
             new_tok[slot] = toks[-1]
-            new_done[slot] = eos is not None and toks[-1] == eos
+            new_left[slot] = req.max_new - len(req.tokens) - len(toks)
+            # on_spec_step's predicate: the request ends with this commit
+            new_done[slot] = new_left[slot] <= 0 \
+                or (eos is not None and toks[-1] == eos)
+            new_len[slot] = 0 if new_done[slot] else live + len(toks)
             emitted[slot] = toks
         com = self._prog("spec_commit", lambda: jax.jit(
             self._spec_commit_impl, donate_argnums=(0,)))
@@ -852,6 +860,7 @@ class ServingEngine:
         finished: list = []
         if bad:
             finished += self.sched.retire_nonfinite(bad)
+            self._unseat(bad)
             for slot in bad:
                 self._spec_tables.pop(slot, None)
         n_emitted = sum(len(t) for t in emitted.values())
@@ -988,6 +997,7 @@ class ServingEngine:
         else:
             req = self.sched.cancel(rid)
         if req is not None:
+            self._unseat([req.slot])
             self._store_result(req)
         return req
 
@@ -1071,23 +1081,28 @@ class ServingEngine:
         return meta
 
     def _attn_counts(self) -> dict:
-        """Of the step just dispatched, two ratios in which 1 is ideal.
+        """Of the step just dispatched, two ratios in which 1 is ideal,
+        from the mirror of the device's lengths.
         ``attn_fetched_over_live``: the positions ``decode_attention``
-        fetches (every slot's length, idle ones too, clamped to the cache
-        and rounded up to the kernel's block) over those the running
-        requests attend to. ``append_moved_over_new``: the bytes the
-        kernel moves between HBM and VMEM to append (the block of 128
-        positions it writes back for every slot whose length is over 0;
-        the block's read is the attention's own fetch) over the bytes of
-        the running requests' new K/V: a ratio of positions, since both
-        are K and V of every head and layer."""
-        fetched = -(-np.minimum(self._slot_len, self.cfg.max_len)
-                    // LANES) * LANES
-        running = self.sched.running.values()
-        live = sum(req.prompt_len + len(req.tokens) for req in running)
-        written = LANES * np.count_nonzero(self._slot_len > 0)
-        return {"attn_fetched_over_live": float(fetched.sum() / live),
-                "append_moved_over_new": float(written / len(running))}
+        fetches (every slot's length rounded up to the kernel's block)
+        over those the running requests attend to.
+        ``append_moved_over_new``: the bytes the kernel moves between HBM
+        and VMEM to append (the block of 128 positions it writes back for
+        every slot whose length is over 0; the block's read is the
+        attention's own fetch) over the bytes of the running requests' new
+        K/V: a ratio of positions, since both are K and V of every head
+        and layer. And ``idle_fetched``: the positions of those fetched
+        that belong to no running request, 0 while every row that is not
+        running stands at length 0."""
+        fetched = -(-self._slot_len // LANES) * LANES
+        running = self.sched.running
+        live = sum(req.prompt_len + len(req.tokens)
+                   for req in running.values())
+        written = LANES * np.count_nonzero(self._slot_len)
+        total = fetched.sum()
+        return {"attn_fetched_over_live": float(total / live),
+                "append_moved_over_new": float(written / len(running)),
+                "idle_fetched": int(total - fetched[list(running)].sum())}
 
     def _log_routing(self, step, tapped: list, chunks: list) -> None:
         """Into ``routing_log``: the chunks' choices (their real tokens,
@@ -1170,7 +1185,8 @@ class ServingEngine:
                         and self._prefill is not None:
                     self._prefill_ahead(n_it)
                 if self._slot_len is not None:
-                    self._slot_len += 1     # the step appends, then attends
+                    # a running row appends, then attends; the others stay
+                    self._slot_len[self._slot_len > 0] += 1
                     counts = self._attn_counts()
                 with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
                     if plan is not None:
@@ -1263,9 +1279,15 @@ class ServingEngine:
                             bad = [s for s in np.nonzero(~oks)[0]
                                    if int(s) in self.sched.running]
                             finished += self.sched.retire_nonfinite(bad)
+                            self._unseat(bad)
                         self._decode_slot_steps += n_slots
                         self._decode_emitted += len(self.sched.running)
-                        finished += self.sched.on_step(toks, dones)
+                        ended = self.sched.on_step(toks, dones)
+                        if ended and self._slot_len is not None:
+                            # at eos or out of tokens: the step has put
+                            # these rows at length 0 itself
+                            self._slot_len[[r.slot for r in ended]] = 0
+                        finished += ended
                 ran_decode = True
         with self._span(_spans.SRV_TAIL, step=n_it):
             if self._demote_ahead is not None:
@@ -1399,6 +1421,7 @@ class ServingEngine:
         """One deadline sweep over queue + slots + the prefill lane."""
         now = self.stats.clock()
         expired = self.sched.expire_deadlines(now)
+        self._unseat([req.slot for req in expired])
         if self._prefill is not None:
             req = self._prefill[0]
             if (req.deadline_ttft is not None and now >= req.deadline_ttft) \
@@ -1511,7 +1534,8 @@ class ServingEngine:
                 insert_paged, donate_argnums=(0,)))
             self._state = ins(self._state, jnp.int32(slot), pf,
                               jnp.asarray(alloc.row),
-                              jnp.int32(alloc.shared))
+                              jnp.int32(alloc.shared),
+                              np.int32(req.max_new - 1))
             # the prompt's blocks are in the pool now: index them for
             # future sharing and release the copy-on-write source pin
             self.pool.on_inserted(req.rid, req.prompt)
@@ -1522,7 +1546,8 @@ class ServingEngine:
         else:
             ins = self._prog("insert", lambda: jax.jit(
                 insert_request, donate_argnums=(0,)))
-            self._state = ins(self._state, jnp.int32(slot), pf)
+            self._state = ins(self._state, jnp.int32(slot), pf,
+                              np.int32(req.max_new - 1))
             if self._slot_len is not None:
                 self._slot_len[slot] = req.prompt_len
         if self.on_placed is not None:
@@ -1531,6 +1556,27 @@ class ServingEngine:
             # iteration's decode lane runs — a prefill replica never
             # spends a decode step on a handed-off request
             self.on_placed(req, slot)
+
+    def _unseat(self, slots) -> None:
+        """Slots whose requests the host has just retired for a reason the
+        device cannot foresee (a deadline, ``cancel``, non-finite logits,
+        a hand-off to another replica) stop running on the device as well:
+        one small program marks them ``done`` at length 0
+        (``serving/slots.py`` ``retire_slots``) and the mirror follows.
+        Entries under 0 (a request that held no slot) are skipped. At eos
+        and at a request's ``max_new`` the step retires the row itself and
+        nothing runs here: the common path has no program of this kind."""
+        slots = [int(s) for s in slots if s >= 0]
+        if not slots:
+            return
+        mask = np.zeros(self.cfg.slots, bool)
+        mask[slots] = True
+        with self.engine.mesh:
+            ret = self._prog("retire", lambda: jax.jit(
+                retire_slots, donate_argnums=(0,)))
+            self._state = ret(self._state, mask)
+        if self._slot_len is not None:
+            self._slot_len[slots] = 0
 
     # ------------------------------------------------------ tiered host KV
     def _demote_pages(self, entries: list) -> None:
@@ -1782,6 +1828,7 @@ class ServingEngine:
                 and self.sched.running.get(slot) is None:
             self._table[slot] = 0
             self._table_dirty = True
+        self._unseat([slot])
         if self.kvscope is not None:
             # the handoff ends the session's activity on THIS replica
             # (its tree keeps the prompt blocks); without this edge a

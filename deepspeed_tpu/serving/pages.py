@@ -37,8 +37,9 @@ The host half lives here too:
   pages: the OOM-shaped mid-decode crash is impossible by construction.
 
 Pool page 0 is reserved scratch: idle slots' table rows point there, and
-the insert scatter redirects shared-page entries there — a retired slot
-or a shared prefix can never be written by construction.
+the insert scatter redirects shared-page entries there; a row that is not
+running (length 0) writes nowhere at all (``_paged_append`` drops it) — a
+retired slot or a shared prefix can never be written by construction.
 
 Metrics land in the serving registry (``Serve/page_*``); ``snapshot()``
 is the flight-recorder provider, so a stall dump shows pool state.
@@ -56,6 +57,7 @@ from jax import lax
 from ..inference.decode import GenCarry, PagedKVCache, cache_layout, \
     dequantize_kv, quantize_kv
 from ..resilience.guards import PagePoolExhausted
+from .slots import seat_row
 
 __all__ = ["PagePool", "RadixPrefixTree", "PageAllocation",
            "init_paged_slots", "insert_paged", "hydrate_cache",
@@ -87,7 +89,8 @@ def init_paged_slots(cfg, slots: int, max_len: int, page_size: int,
         length=jnp.zeros((slots,), jnp.int32))
     return GenCarry(tok=jnp.zeros((slots,), jnp.int32), cache=cache,
                     rng=jnp.zeros((slots, 2), jnp.uint32),
-                    done=jnp.ones((slots,), bool))
+                    done=jnp.ones((slots,), bool),
+                    left=jnp.zeros((slots,), jnp.int32))
 
 
 def _page_split(buf, n: int, ps: int):
@@ -108,9 +111,10 @@ def _page_merge(tiles, like):
 
 
 def insert_paged(state: GenCarry, slot, pf: GenCarry, page_row,
-                 first_private) -> GenCarry:
+                 first_private, left=None) -> GenCarry:
     """Scatter a freshly prefilled request's contiguous cache into its
-    pool pages and seat the per-slot vectors.
+    pool pages and seat the per-slot vectors (``left`` as
+    ``insert_request``'s).
 
     ``page_row`` is the slot's full (pages_per_slot,) table row;
     ``first_private`` the count of leading SHARED pages — those scatter
@@ -137,15 +141,12 @@ def insert_paged(state: GenCarry, slot, pf: GenCarry, page_row,
         k = c.k.at[:, tgt].set(vk.astype(c.k.dtype))
         v = c.v.at[:, tgt].set(vv.astype(c.v.dtype))
         k_scale, v_scale = c.k_scale, c.v_scale
-    length = lax.dynamic_update_slice(
-        c.length, pf.cache.length.reshape(1).astype(jnp.int32), (slot,))
-    tok = lax.dynamic_update_slice(state.tok, pf.tok.astype(jnp.int32),
-                                   (slot,))
-    rng = lax.dynamic_update_slice(state.rng, pf.rng, (slot, 0))
-    done = lax.dynamic_update_slice(state.done, pf.done, (slot,))
+    tok, rng, done, length, left = seat_row(
+        state, slot, tok=pf.tok, rng=pf.rng, done=pf.done,
+        length=pf.cache.length, left=left)
     cache = PagedKVCache(k=k, v=v, k_scale=k_scale, v_scale=v_scale,
                          page_table=c.page_table, length=length)
-    return GenCarry(tok=tok, cache=cache, rng=rng, done=done)
+    return GenCarry(tok=tok, cache=cache, rng=rng, done=done, left=left)
 
 
 def hydrate_cache(state: GenCarry, cache, hydrate_row, count):
@@ -183,7 +184,8 @@ def export_slot(state: GenCarry, row, slot) -> dict:
     compiled program exports any request on any slot. The payload is the
     request's complete decode state: its prompt KV tiles (int8 pools
     include the scale planes), the first sampled token, the per-request
-    RNG chain *after* that sample, the done flag, and the cache length —
+    RNG chain *after* that sample, the done flag, the cache length and
+    the tokens it may still emit —
     everything a decode replica needs to continue the exact bit-stream.
     The caller ``device_get``s the result: the transfer is host-mediated
     by design (replicas share no device state)."""
@@ -192,7 +194,8 @@ def export_slot(state: GenCarry, row, slot) -> dict:
            "tok": lax.dynamic_slice(state.tok, (slot,), (1,)),
            "rng": lax.dynamic_slice(state.rng, (slot, 0), (1, 2)),
            "done": lax.dynamic_slice(state.done, (slot,), (1,)),
-           "length": lax.dynamic_slice(c.length, (slot,), (1,))}
+           "length": lax.dynamic_slice(c.length, (slot,), (1,)),
+           "left": lax.dynamic_slice(state.left, (slot,), (1,))}
     if c.k_scale is not None:
         out["k_scale"] = c.k_scale[:, row]
         out["v_scale"] = c.v_scale[:, row]
@@ -221,15 +224,13 @@ def import_slot(state: GenCarry, slot, payload: dict, row,
         v_scale = c.v_scale.at[:, tgt].set(payload["v_scale"])
     else:
         k_scale, v_scale = c.k_scale, c.v_scale
-    length = lax.dynamic_update_slice(
-        c.length, payload["length"].astype(jnp.int32), (slot,))
-    tok = lax.dynamic_update_slice(state.tok,
-                                   payload["tok"].astype(jnp.int32), (slot,))
-    rng = lax.dynamic_update_slice(state.rng, payload["rng"], (slot, 0))
-    done = lax.dynamic_update_slice(state.done, payload["done"], (slot,))
+    tok, rng, done, length, left = seat_row(
+        state, slot, tok=payload["tok"], rng=payload["rng"],
+        done=payload["done"], length=payload["length"],
+        left=payload["left"])
     cache = PagedKVCache(k=k, v=v, k_scale=k_scale, v_scale=v_scale,
                          page_table=c.page_table, length=length)
-    return GenCarry(tok=tok, cache=cache, rng=rng, done=done)
+    return GenCarry(tok=tok, cache=cache, rng=rng, done=done, left=left)
 
 
 # -------------------------------------------------------------- host side

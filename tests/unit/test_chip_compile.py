@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deepspeed_tpu.inference.decode import (GenCarry, KVCache, cache_layout,
+from deepspeed_tpu.inference.decode import (GenCarry, cache_layout,
                                             decode_step)
 from deepspeed_tpu.inference.sampling import sample_logits
 from deepspeed_tpu.models import build_model, gpt2, llama2
@@ -28,6 +28,8 @@ from deepspeed_tpu.ops.decode_attention import decode_attention
 from deepspeed_tpu.ops.flash_attention import flash_attention
 from deepspeed_tpu.ops.woq_matmul import woq_matmul, woq_matmul_t
 from deepspeed_tpu.ops.xent import fused_token_nll
+from deepspeed_tpu.serving.slots import (init_slots, insert_request,
+                                         retire_slots)
 
 # (name, d_model, heads, kv_heads, head_dim, d_ff, vocab) at published width
 GPT2_774M = ("gpt2-774m", 1280, 20, 20, 64, 5120, 50257)
@@ -228,7 +230,7 @@ def _slot_step(one_chip, cfg, slots):
     """The serving engine's donated slot step (``_step_impl``), compiled
     for the described chip at full width; (compiled, cache shape)."""
     model = build_model(cfg)
-    shape, dtype = cache_layout(cfg, slots, SEQ)
+    shape, _ = cache_layout(cfg, slots, SEQ)
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -236,12 +238,10 @@ def _slot_step(one_chip, cfg, slots):
             sharding=one_chip), tree)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    kv = jax.ShapeDtypeStruct(shape, dtype)
-    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-    carry = on_chip(GenCarry(
-        tok=i32((slots,)), cache=KVCache(k=kv, v=kv, length=i32((slots,))),
-        rng=jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
-        done=jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    # the slots' own state: lengths a slot (0 where a row is not running),
+    # ``done`` and the tokens left, which the step counts down
+    carry = on_chip(jax.eval_shape(lambda: init_slots(cfg, slots, SEQ)))
+    assert carry.cache.k.shape == shape and carry.left.shape == (slots,)
     sampler = partial(sample_logits, greedy=False, temperature=0.8,
                       top_k=40, top_p=1.0)
     step = jax.jit(lambda p, c: decode_step(
@@ -292,6 +292,50 @@ def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
     assert not moved, moved
 
 
+@pytest.mark.parametrize("kind", ["contiguous", "latent"])
+def test_seating_and_retiring_keep_the_cache_in_place(one_chip, kind):
+    """The two small programs around the step that touch the slot state:
+    ``insert_request`` with the tokens left as one more scalar, and
+    ``retire_slots`` (a mask of rows to length 0, for a retirement the
+    device cannot foresee). Both take the state donated and hand the cache
+    back where it was: a retirement that copied it would cost 8.4 GiB."""
+    from deepspeed_tpu.inference.decode import init_cache
+    from deepspeed_tpu.models import deepseek_v3
+
+    if kind == "latent":
+        slots, S = KANANA["slots"], KANANA["S"]
+        cfg = deepseek_v3("kanana-2-30b-a3b", n_layer=KANANA["L"],
+                          dtype=jnp.bfloat16)
+    else:
+        slots, S = 48, SEQ
+        cfg = gpt2("774m", max_seq=SEQ, dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    state = on_chip(jax.eval_shape(
+        lambda: init_slots(cfg, slots, S, jnp.bfloat16)))
+    cache_bytes = sum(a.size * 2 for name, a in state.cache._asdict().items()
+                      if name != "length")
+    one = on_chip(jax.eval_shape(
+        lambda: init_cache(cfg, 1, S, jnp.bfloat16)))
+    pf = on_chip(GenCarry(
+        tok=jax.ShapeDtypeStruct((1,), jnp.int32), cache=one,
+        rng=jax.ShapeDtypeStruct((1, 2), jnp.uint32),
+        done=jax.ShapeDtypeStruct((1,), jnp.bool_)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+    for compiled in (
+            jax.jit(insert_request, donate_argnums=(0,)).lower(
+                state, i32, pf, i32).compile(),
+            jax.jit(retire_slots, donate_argnums=(0,)).lower(
+                state, mask).compile()):
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes
+        assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
+
+
 # ------------------------------------------------- latent cache, expert rows
 KANANA = dict(L=7, slots=48, H=32, rank=512, rope=64, S=8192, E=128, d=2048,
               f=768)
@@ -336,7 +380,6 @@ def test_latent_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch):
     donated and leaves aliased, the four kernels are in the program, and
     the live set fits the chip (15.75 GiB) with the weights beside it."""
     from deepspeed_tpu.models import deepseek_v3
-    from deepspeed_tpu.serving.slots import init_slots
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     k = KANANA
@@ -386,7 +429,6 @@ def test_looped_trunk_keeps_the_cache_in_place(one_chip, monkeypatch,
     from deepspeed_tpu.inference.decode import (forward_with_cache,
                                                 init_cache)
     from deepspeed_tpu.models import ouro
-    from deepspeed_tpu.serving.slots import init_slots
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     slots, max_len, chunk = 12, 384, 128

@@ -184,9 +184,11 @@ def _slot_cache(cfg, lens, seed=0, stale=0.0):
 def test_step_kernels_match_dense_path(family, lengths):
     """The T == 1 forward on the kernels against the same forward on the
     dense path: same logits; the append lands at ``length - 1`` of every
-    slot and nowhere else — an idle slot (length 0) writes its own row, a
-    neighbour's bits never move; K/V a previous occupant left past the live
-    length is never read (1e9 there would swamp any sum it entered)."""
+    running slot and nowhere else — a slot that is not running (length 0)
+    stays at 0: the kernel leaves its row alone, the dense path writes
+    one position of it, the row's own; a neighbour's bits never move; K/V a
+    previous occupant left past the live length is never read (1e9 there
+    would swamp any sum it entered)."""
     from deepspeed_tpu.inference.decode import forward_with_cache
 
     cfg, model, params = _family(family)
@@ -204,19 +206,30 @@ def test_step_kernels_match_dense_path(family, lengths):
                   static_argnames=("flash_decode",))
     want, dense = fwd(params, tok, cache, flash_decode=False)
     got, fused = fwd(params, tok, cache, flash_decode=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+    run = before > 0        # a row that is not running: its logits are nobody's
+    np.testing.assert_allclose(np.asarray(got)[run], np.asarray(want)[run],
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_array_equal(np.asarray(fused.length),
                                   np.asarray(dense.length))
+    np.testing.assert_array_equal(np.asarray(fused.length),
+                                  np.where(before > 0, before + 1, 0))
     written = np.arange(S) == before.reshape(-1, 1, 1, 1)
+    # (the dense path's update of a row at length 0 starts at -1, which
+    # dynamic_update_slice wraps to the row's last position)
+    idle = np.broadcast_to((before == 0).reshape(-1, 1, 1, 1), written.shape)
     for new, ref, old in ((fused.k, dense.k, cache.k),
                           (fused.v, dense.v, cache.v)):
         new, ref, old = (np.asarray(a) for a in (new, ref, old))
-        np.testing.assert_array_equal(np.where(written, 0, new),
-                                      np.where(written, 0, old))
+        np.testing.assert_array_equal(np.where(written & ~idle, 0, new),
+                                      np.where(written & ~idle, 0, old))
+        np.testing.assert_array_equal(np.where(written | idle, 0, ref),
+                                      np.where(written | idle, 0, old))
         # layer 0's new K/V do not pass through attention: bit-equal
-        np.testing.assert_array_equal(new[0], ref[0])
-        np.testing.assert_allclose(new, ref, rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(np.where(idle, 0, new[0]),
+                                      np.where(idle, 0, ref[0]))
+        np.testing.assert_allclose(np.where(idle, 0, new),
+                                   np.where(idle, 0, ref),
+                                   rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -298,7 +311,8 @@ def test_step_consumes_its_cache():
     assert (out.cache.k.unsafe_buffer_pointer(),
             out.cache.v.unsafe_buffer_pointer()) == where
     assert bool(np.all(np.asarray(ok)))
-    np.testing.assert_array_equal(np.asarray(out.cache.length), [6, 1, 129])
+    # the slot at length 0 is not running: it stays there
+    np.testing.assert_array_equal(np.asarray(out.cache.length), [6, 0, 129])
 
 
 # ------------------------------------- a slot's heads, live blocks only
@@ -538,7 +552,9 @@ def test_heads_per_program_follow_the_shapes():
 def test_decode_step_span_says_fetched_over_live():
     """``attn_fetched_over_live`` on the ``decode_step`` span: host
     arithmetic on a mirror of the slots' lengths, which tracks the
-    device's vector through placements, steps and idle slots."""
+    device's vector through placements, steps and retirements; a slot
+    that is not running stands at length 0 and nothing is fetched for it
+    (``idle_fetched``)."""
     import deepspeed_tpu as ds
 
     cfg, model, params = _family("mha-hd64")
@@ -558,15 +574,16 @@ def test_decode_step_span_says_fetched_over_live():
     assert len(ratios) == len(steps) > 0
     assert min(ratios) >= 1.0
     # the first step: one request of 120 tokens seated, attending 121
-    # positions of its one block; two slots idle at length 1: a block each
-    assert ratios[0] == pytest.approx(3 * 128 / 121)
+    # positions of its one block; two slots not running: nothing
+    assert ratios[0] == pytest.approx(128 / 121)
+    assert [e.meta["idle_fetched"] for e in steps] == [0] * len(steps)
 
 
 def test_decode_step_span_says_append_moved_over_new():
     """``append_moved_over_new`` on the ``decode_step`` span: the block of
     128 positions the kernel writes back for every slot whose length is
-    over 0 (after a step: every slot) over the one new position of each
-    running request."""
+    over 0 (the running ones alone: a row that is not running stands at 0)
+    over the one new position of each running request."""
     import deepspeed_tpu as ds
 
     cfg, model, params = _family("mha-hd64")
@@ -581,9 +598,8 @@ def test_decode_step_span_says_append_moved_over_new():
     srv.serve_batch(prompts, [12, 3, 4, 5], [1, 2, 3, 4])
     steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
     assert len(steps) > 0
-    # the first step: one request running, three slots written
-    assert steps[0].meta["append_moved_over_new"] == 3 * 128 / 1
     running = {e.meta["slots"] for e in steps}
     assert running >= {1, 2, 3}
+    # whatever runs: a block for each running request and for no other slot
     for e in steps:
-        assert e.meta["append_moved_over_new"] == 3 * 128 / e.meta["slots"]
+        assert e.meta["append_moved_over_new"] == 128
