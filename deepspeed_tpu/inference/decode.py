@@ -102,6 +102,22 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[3]
 
 
+class HybridCache(NamedTuple):
+    """The cache of a trunk of one mixer a layer (``cfg.block_pattern``,
+    ``models/hybrid.py``): K/V planes for the attention layers ONLY, laid
+    out as :class:`KVCache`'s, beside what does not grow with the position —
+    per Mamba-2 layer and slot a float32 SSM state and the conv's last
+    ``K - 1`` inputs (``models/ssm.py``). Every buffer has the slot second,
+    so ``serving/slots.py`` seats a request by overwriting the slot's whole
+    extent of each: a successor never reads its predecessor's state."""
+
+    k: jnp.ndarray           # (attention layers, B, KV, hd, max_len)
+    v: jnp.ndarray           # (attention layers, B, KV, hd, max_len)
+    ssm: jnp.ndarray         # (Mamba layers, B, H, P, N) float32
+    conv: jnp.ndarray        # (Mamba layers, B, K - 1, conv channels)
+    length: jnp.ndarray      # as KVCache.length
+
+
 def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                  dtype=None, *, page_size: int = 0, pages: int = 0) -> tuple:
     """(shape, dtype) of one cache buffer (K or V; for latent attention
@@ -125,6 +141,16 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     apart from every other pass's: ``L`` = ``n_layer x loop_steps`` planes,
     pass ``r``'s layer ``l`` at ``r * n_layer + l``, contiguous only."""
     # (duck-typed configs of other trunks have no attention kinds: K/V)
+    pattern = getattr(cfg, "block_pattern", "")
+    if pattern:
+        # one mixer a layer: planes for the attention layers only; what the
+        # other layers keep is state_layout()'s
+        if page_size > 0:
+            raise NotImplementedError(
+                "the paged pool holds pages of K and V; a recurrent state "
+                "beside them has no pages: contiguous only")
+        return ((pattern.count("*"), batch, cfg.kv_heads, cfg.head_dim,
+                 max_len), dtype or cfg.dtype)
     loops = getattr(cfg, "loop_steps", 1)
     if loops > 1 and page_size > 0:
         raise NotImplementedError(
@@ -142,6 +168,27 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                 dtype or cfg.dtype)
     return ((cfg.n_layer * loops, batch, cfg.kv_heads, cfg.head_dim,
              max_len), dtype or cfg.dtype)
+
+
+def state_layout(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
+    """{name: (shape, dtype)} of what a cache holds per slot whatever the
+    position — a ``block_pattern`` trunk's Mamba-2 layers' SSM state and
+    conv window (:class:`HybridCache`); {} for every other trunk."""
+    pattern = getattr(cfg, "block_pattern", "")
+    if "M" not in pattern:
+        return {}
+    from ..models.ssm import state_shapes
+
+    n = pattern.count("M")
+    return {name: ((n,) + shape,
+                   jnp.float32 if name == "ssm" else dtype or cfg.dtype)
+            for name, shape in state_shapes(cfg, batch).items()}
+
+
+def state_bytes_per_slot(cfg: TransformerConfig, dtype=None) -> int:
+    """Bytes a slot's fixed-size state costs, from :func:`state_layout`."""
+    return sum(math.prod(shape) * jnp.dtype(dt).itemsize
+               for shape, dt in state_layout(cfg, 1, dtype).values())
 
 
 def cache_buffers(shape: tuple) -> int:
@@ -162,8 +209,14 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
                dtype=None, length_shape: tuple = ()):
     """An empty cache of the model's kind; ``length_shape`` () for rows that
     advance together, (batch,) for serving slots."""
+    state = state_layout(cfg, batch, dtype)
     shape, dtype = cache_layout(cfg, batch, max_len, dtype)
     length = jnp.zeros(length_shape, jnp.int32)
+    if state:
+        return HybridCache(
+            k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+            length=length, **{name: jnp.zeros(sh, dt)
+                              for name, (sh, dt) in state.items()})
     if cache_buffers(shape) == 1:
         return LatentCache(c=jnp.zeros(shape, dtype), length=length)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
@@ -623,6 +676,98 @@ def _forward_latent(model, params, x, cache: LatentCache, new_len, positions,
             if stats else None)
 
 
+def _run(body, carry, seg, n: int, first: int):
+    """``body(carry, layer weights, index)`` over a run of ``n`` layers
+    stacked in ``seg``, ``first`` the run's first index in its kind's
+    buffers: a scan, or the body itself with a static index for a run of
+    one (its slices of the carried buffers are then static too)."""
+    if n == 1:
+        carry, out = body(carry, jax.tree.map(lambda a: a[0], seg), first)
+        return carry, jax.tree.map(lambda a: a[None], out)
+    return lax.scan(lambda c, xs: body(c, *xs), carry,
+                    (seg, jnp.arange(first, first + n, dtype=jnp.int32)))
+
+
+def _forward_hybrid(model, params, x, cache: HybridCache, new_len, valid,
+                    flash_decode: bool):
+    """The layer loop of a ``block_pattern`` trunk (``models/hybrid.py``):
+    each run of equal layers over its own stacked weights, all of them
+    carrying the cache's four buffers, a layer touching only its kind's.
+    ``valid`` (traced i32 or None): how many of the T tokens are real — a
+    right-padded final chunk must leave the recurrent state as its last
+    real token did. Returns (x, cache, (stats (expert layers, 4), routing
+    (expert layers, B, T, k)) or None)."""
+    from ..models import ssm
+
+    cfg = model.cfg
+    B, T, _ = x.shape
+    per_slot = getattr(new_len, "ndim", 0) == 1
+    if per_slot and T > 1:
+        raise NotImplementedError(
+            "a recurrent state advances one token a slot (T == 1) or a chunk "
+            "of ONE request (scalar length): no multi-token verify forward")
+    fused = _decode_kernel_ok(flash_decode, T, cache.k.shape[4], x.dtype,
+                              cache.k.dtype, cache.v.dtype)
+    if T == 1 and not fused:
+        from ..observability.metrics import get_registry
+
+        get_registry().counter("Serve/decode_fallback_builds").inc()
+    # a slot at length 0 is not running: its state stays as it is
+    lens = new_len if per_slot else jnp.broadcast_to(new_len, (B,))
+    in_place = ssm.step_kernel_ok(cfg, fused)
+
+    def mamba(carry, p, layer):
+        x, k, v, S, W = carry
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        if T == 1:
+            out, S, W = ssm.mix_step(cfg, p, y, S, W, layer, lens, in_place)
+        else:
+            out, s_l, w_l = ssm.mix_chunk(
+                cfg, p, y, lax.dynamic_index_in_dim(S, layer, keepdims=False),
+                lax.dynamic_index_in_dim(W, layer, keepdims=False), valid)
+            S = lax.dynamic_update_slice(S, s_l[None], (layer, 0, 0, 0, 0))
+            W = lax.dynamic_update_slice(W, w_l[None], (layer, 0, 0, 0))
+        return (x + out, k, v, S, W), ()
+
+    def attention(carry, p, layer):
+        x, ck, cv, S, W = carry
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        q, k, v = _qkv_proj(model, y, p)          # no position code
+        if fused:
+            from ..ops.decode_attention import decode_attention
+
+            o, ck, cv = decode_attention(q, ck, cv, new_len, k=k, v=v,
+                                         layer=layer)
+        else:
+            slab_k, ck = _dense_append(ck, k, layer, new_len)
+            slab_v, cv = _dense_append(cv, v, layer, new_len)
+            o = _cache_attend(q, slab_k, slab_v, new_len)
+        o = matmul_any(o.reshape(B, T, cfg.n_head * cfg.head_dim), p["wo"],
+                       use_kernel=False)
+        return (x + o, ck, cv, S, W), ()
+
+    def experts(carry, p, layer):
+        x = carry[0]
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        out, stats, idx = model.latent_experts(y, p)
+        return (x + out,) + carry[1:], (stats, idx)
+
+    bodies = {"M": mamba, "*": attention, "E": experts}
+    carry = (x, cache.k, cache.v, cache.ssm, cache.conv)
+    seen = dict.fromkeys(bodies, 0)
+    stats = []
+    for (kind, n), seg in zip(cfg.segments, params["layers"]):
+        with jax.named_scope("decode_layer"):
+            carry, out = _run(bodies[kind], carry, seg, n, seen[kind])
+        seen[kind] += n
+        if kind == "E":
+            stats.append(out)
+    x, k, v, S, W = carry
+    return (x, HybridCache(k=k, v=v, ssm=S, conv=W, length=new_len),
+            tuple(jnp.concatenate(part) for part in zip(*stats))
+            if stats else None)
+
+
 def _embed_rows(table, ids, dtype):
     """Row gather from a dense or int8/int4-stored embedding table — a
     quantized table reads int8 bytes for exactly the batch's tokens."""
@@ -738,7 +883,11 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
                   cfg.norm, cfg.norm_eps)
 
     stats = passes = None
-    if isinstance(cache, LatentCache):
+    if isinstance(cache, HybridCache):
+        x, new_cache, stats = _forward_hybrid(
+            model, params, x, cache, new_len,
+            None if last_index is None else last_index + 1, flash_decode)
+    elif isinstance(cache, LatentCache):
         x, new_cache, stats = _forward_latent(model, params, x, cache,
                                               new_len, positions, flash_decode)
     elif paged:

@@ -1,0 +1,235 @@
+"""A trunk of one mixer a layer (NemotronH, ``model_type: nemotron_h``).
+
+Layer ``i`` is ``cfg.block_pattern[i]`` alone: ``x <- x + mixer_i(RMSNorm_i(
+x))``, no FFN beside it; then the final norm and an untied head.
+
+- ``M``: a Mamba-2 mixer (``models/ssm.py``).
+- ``*``: causal GQA attention with NO position code (the family applies
+  none), the trunk's own ``_attention_block``.
+- ``E``: latent experts. The router is DeepSeek-V3's ``noaux_tc`` over ALL
+  ``num_experts`` (``MoETransformerLM.route``: sigmoid, selection bias,
+  normalised top-k x scale). The routed experts live in a latent of
+  ``moe_latent_dim`` under the model width: ``u = y W_dn``; expert e is
+  ``relu(u W1_e)^2 W2_e`` (not gated: one up matrix); ``routed = (sum_chosen
+  w_e expert_e(u)) W_up``. A shared expert ``relu(y Ws1)^2 Ws2`` on the full
+  width is added to every token. **The layer is told which experts it
+  holds** (``moe_first_held`` .. + ``moe_experts_held``): it routes over all
+  of them, sorts only the rows that chose a held expert and computes those —
+  its part of the sum, which is linear in the experts up to ``W_up`` (the
+  model-configs guide's chip's share; ``tests/unit/test_hybrid_trunk.py``
+  adds the shares up to the whole layer).
+
+Segments are runs of equal letters (``TransformerConfig.segments``), each
+scanned over its own stacked weights; ``params["layers"]`` is the tuple of
+them. The cache path is ``inference/decode.py`` ``_forward_hybrid``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from . import ssm
+from .moe import MoETransformerLM
+from .transformer import TransformerLM, _norm
+
+KINDS = "ME*"
+
+
+class HybridLM(TransformerLM):
+    """init / apply / param_specs for a ``block_pattern`` trunk."""
+
+    route = MoETransformerLM.route
+
+    def __init__(self, config, attention_fn=None):
+        super().__init__(config, attention_fn=attention_fn)
+        c = config
+        pat = c.block_pattern
+        if len(pat) != c.n_layer or set(pat) - set(KINDS):
+            raise ValueError(
+                f"block_pattern {pat!r} has to name each of the {c.n_layer} "
+                f"layers' mixer, one of {KINDS!r}")
+        if (c.use_bias or c.norm != "rmsnorm" or not c.causal
+                or c.pos_embedding != "none" or c.objective != "clm"
+                or c.loop_steps > 1 or c.sandwich_norm or c.post_ln
+                or c.parallel_residual or c.tie_embeddings
+                or c.attention != "mha" or attention_fn is not None):
+            raise ValueError(
+                "a block_pattern trunk is the NemotronH block: a causal LM, "
+                "RMSNorm before each layer's one mixer, no biases, no "
+                "position code (pos_embedding='none'), an untied head")
+        if "M" in pat and (min(c.ssm_heads, c.ssm_head_dim, c.ssm_state) <= 0
+                           or c.ssm_heads % c.ssm_groups or c.ssm_conv < 2):
+            raise ValueError("'M' layers need ssm_heads / ssm_head_dim / "
+                             "ssm_state, ssm_groups dividing the heads")
+        if "E" in pat:
+            E, held = c.num_experts, c.held_experts
+            if (c.moe_router != "sigmoid" or c.moe_top_k > E or held > E
+                    or c.moe_first_held % held or c.moe_first_held >= E):
+                raise ValueError(
+                    "'E' layers take the sigmoid router over num_experts and "
+                    "hold moe_experts_held of them from a multiple of that on")
+
+    # ----------------------------------------------------------------- init
+    def init(self, rng) -> dict:
+        cfg = self.cfg
+        d, depth = cfg.d_model, cfg.n_layer
+        k_embed, k_head = jax.random.split(rng)
+        return {
+            "tok_embed": jax.random.normal(k_embed, (cfg.vocab_size, d),
+                                           jnp.float32) * 0.02,
+            "layers": tuple(
+                self._init_run(jax.random.fold_in(rng, 100 + i), kind, n,
+                               depth)
+                for i, (kind, n) in enumerate(cfg.segments)),
+            "lnf_scale": jnp.ones((d,), jnp.float32),
+            "lm_head": jax.random.normal(k_head, (d, cfg.vocab_size),
+                                         jnp.float32) * 0.02,
+        }
+
+    def _init_run(self, key, kind: str, n: int, depth: int) -> dict:
+        """Stacked weights of a run of ``n`` layers of ``kind``. Every
+        layer adds ONE branch to the stream, so an output projection is
+        scaled by 1 / sqrt(depth) (``rescale_prenorm_residual``)."""
+        cfg = self.cfg
+        d = cfg.d_model
+        if kind == "M":
+            return ssm.init_params(cfg, key, n, depth)
+        k = iter(jax.random.split(key, 10))
+
+        def dense(shape, fan_in, branch: bool = False):
+            return jax.random.normal(next(k), shape, jnp.float32) \
+                / math.sqrt(fan_in * (depth if branch else 1))
+
+        out = {"ln1_scale": jnp.ones((n, d), jnp.float32)}
+        if kind == "*":
+            h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+            out.update(wq=dense((n, d, h * hd), d),
+                       wk=dense((n, d, kv * hd), d),
+                       wv=dense((n, d, kv * hd), d),
+                       wo=dense((n, h * hd, d), h * hd, True))
+            return out
+        E, held = cfg.num_experts, cfg.held_experts
+        lat, f, fs = cfg.moe_latent_dim or d, cfg.expert_dim, \
+            cfg.moe_shared_d_ff
+        out.update(
+            router=jax.random.normal(next(k), (n, d, E), jnp.float32) * 0.02,
+            # a trained model's selection bias is small and not zero; drawn
+            # so, that a path which drops it chooses differently
+            router_bias=jax.random.normal(next(k), (n, E), jnp.float32) * 0.02,
+            w1=dense((n, held, lat, f), lat),
+            w2=dense((n, held, f, lat), f, lat == d))
+        if lat != d:
+            out.update(w_dn=dense((n, d, lat), d),
+                       w_up=dense((n, lat, d), lat, True))
+        if fs:
+            out.update(ws_in=dense((n, d, fs), d),
+                       ws_out=dense((n, fs, d), fs, True))
+        return out
+
+    def param_specs(self) -> dict:
+        """Every leaf replicated: a mesh is refused for these block kinds
+        (``serving/engine.py``), so no rule here is under a test."""
+        shapes = jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        return jax.tree.map(lambda a: P(*(None,) * a.ndim), shapes)
+
+    def fp32_param_names(self) -> tuple:
+        return ("router", "router_bias") + ssm.FP32_NAMES
+
+    # -------------------------------------------------------------- mixers
+    @jax.named_scope("latent_experts")
+    def latent_experts(self, y, p):
+        """The ``E`` mixer on (B, T, d). Returns (out, stats, idx):
+        ``stats`` f32 [most rows one held expert got, held experts touched,
+        rows multiplied (padding included), rows that chose a held expert];
+        ``idx`` (B, T, k) i32 the experts chosen, of all ``num_experts``."""
+        from ..ops.moe_matmul import block_rows, experts_relu2
+
+        cfg = self.cfg
+        B, T, d = y.shape
+        k, Eh = cfg.moe_top_k, cfg.held_experts
+        N, M = B * T, B * T * cfg.moe_top_k
+        yt = y.reshape(N, d)
+        idx, w = self.route(yt, p)
+        u = yt @ p["w_dn"].astype(y.dtype) if "w_dn" in p else yt
+        bm = block_rows(y.dtype)
+        # pairs that chose an expert held elsewhere sort behind every held
+        # one and get no row
+        e = idx.reshape(M) - cfg.moe_first_held
+        e = jnp.where((e >= 0) & (e < Eh), e, Eh)
+        order = jnp.argsort(e, stable=True)
+        e_sorted = e[order]
+        held = e_sorted < Eh
+        es = jnp.minimum(e_sorted, Eh - 1)
+        counts = jnp.bincount(e, length=Eh + 1)[:Eh].astype(jnp.int32)
+        padded = (counts + bm - 1) // bm * bm
+        p_end = jnp.cumsum(padded)
+        first = jnp.cumsum(counts) - counts
+        R = -(-(M + min(Eh, M) * (bm - 1)) // bm) * bm       # every case fits
+        dest = jnp.where(held, (p_end - padded)[es]
+                         + jnp.arange(M, dtype=jnp.int32) - first[es], R)
+        row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
+            (order // k).astype(jnp.int32), mode="drop")
+        pair_row = jnp.zeros((M,), jnp.int32).at[order].set(
+            jnp.where(held, dest, 0))
+        pair_held = jnp.zeros((M,), bool).at[order].set(held)
+        used = p_end[-1] // bm
+        blocks = jnp.arange(R // bm, dtype=jnp.int32)
+        block_expert = jnp.minimum(jnp.searchsorted(
+            p_end, jnp.maximum(jnp.minimum(blocks, used - 1), 0) * bm,
+            side="right"), Eh - 1)
+        out = experts_relu2(u[row_token], p["w1"], p["w2"], block_expert,
+                            used, bm=bm)
+        # a row no block wrote is never read: pairs held elsewhere add 0
+        rows = jnp.where(pair_held[:, None], out[pair_row], 0)
+        routed = jnp.sum(rows.reshape(N, k, -1).astype(jnp.float32)
+                         * w[..., None], axis=1).astype(y.dtype)
+        if "w_up" in p:
+            routed = routed @ p["w_up"].astype(y.dtype)
+        stats = jnp.stack([jnp.max(counts), jnp.sum(counts > 0), used * bm,
+                           jnp.sum(counts)]).astype(jnp.float32)
+        if cfg.moe_shared_d_ff:
+            with jax.named_scope("moe_shared"):
+                h = jnp.square(jax.nn.relu(yt @ p["ws_in"].astype(y.dtype)))
+                routed = routed + h @ p["ws_out"].astype(y.dtype)
+        return routed.reshape(B, T, d), stats, idx.reshape(B, T, k)
+
+    def _mixer(self, kind: str, x, p, positions, attn_mask):
+        """One layer of ``kind`` on the whole sequence (no cache): (x, the
+        experts an ``E`` layer chose (B, S, k), else None)."""
+        cfg = self.cfg
+        if kind == "*":
+            return x + self._attention_block(x, p, positions, attn_mask), None
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        if kind == "E":
+            out, _, idx = self.latent_experts(y, p)
+            return x + out, idx
+        B = x.shape[0]
+        empty = {name: jnp.zeros(shape, jnp.float32 if name == "ssm"
+                                 else x.dtype)
+                 for name, shape in ssm.state_shapes(cfg, B).items()}
+        return x + ssm.mix_chunk(cfg, p, y, empty["ssm"],
+                                 empty["conv"])[0], None
+
+    def _trunk(self, params, input_ids, attn_mask, remat_policy):
+        """Embed + the layers: (B, S) -> ((B, S, d) before the final norm,
+        the ``E`` layers' routing (expert layers, B, S, k) in layer order:
+        what a comparison with a reference that follows the system's choice
+        at a near-tie needs, from the same program as the logits)."""
+        if attn_mask is not None or remat_policy is not None:
+            raise NotImplementedError(
+                "a block_pattern trunk is served, not trained: no padding "
+                "mask (the recurrence has none) and no remat policy")
+        x, positions = self._embed(params, input_ids)
+        routing = []
+        for (kind, _), seg in zip(self.cfg.segments, params["layers"]):
+            x, idx = lax.scan(
+                lambda x, p, kind=kind: self._mixer(kind, x, p, positions,
+                                                    None), x, seg)
+            if kind == "E":
+                routing.append(idx)
+        return x, jnp.concatenate(routing) if routing else jnp.float32(0.0)

@@ -84,6 +84,37 @@ def deepseek_v3(size: str = "kanana-2-30b-a3b", **overrides) -> TransformerConfi
     return TransformerConfig(**base)
 
 
+def nemotron_h(size: str = "3-super-120b-a12b", **overrides) -> TransformerConfig:
+    """The NemotronH block (``model_type: nemotron_h``): every layer ONE
+    mixer, its kind a letter of the published ``hybrid_override_pattern`` —
+    ``M`` Mamba-2, ``E`` latent experts, ``*`` GQA attention with no position
+    code (``models/hybrid.py``). ``3-super-120b-a12b`` is
+    nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16's ``config.json``."""
+    table = {
+        "tiny": dict(block_pattern="MEM*EM", n_head=4, n_kv_head=2,
+                     d_model=64, vocab_size=251, max_seq=256, ssm_heads=8,
+                     ssm_head_dim=16, ssm_groups=2, ssm_state=16,
+                     ssm_chunk=8, num_experts=16, moe_top_k=4, moe_d_ff=48,
+                     moe_latent_dim=32, moe_shared_d_ff=96,
+                     moe_routed_scale=2.5),
+        "3-super-120b-a12b": dict(
+            block_pattern="MEMEMEM*E" + 2 * "MEMEMEM*E" + 4 * "MEMEMEMEM*E"
+            + "MEMEMEM*E" + "MEMEMEME",
+            n_head=32, n_kv_head=2, d_model=4096, vocab_size=131072,
+            max_seq=262144, ssm_heads=128, ssm_head_dim=64, ssm_groups=8,
+            ssm_state=128, ssm_chunk=128, num_experts=512, moe_top_k=22,
+            moe_d_ff=2688, moe_latent_dim=1024, moe_shared_d_ff=5376,
+            moe_routed_scale=5.0),
+    }
+    base = dict(pos_embedding="none", norm="rmsnorm", norm_eps=1e-5,
+                activation="relu2", use_bias=False, tie_embeddings=False,
+                moe_router="sigmoid", moe_norm_topk=True, fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    base.setdefault("n_layer", len(base["block_pattern"]))
+    return TransformerConfig(**base)
+
+
 def ouro(size: str = "2.6b", **overrides) -> TransformerConfig:
     """The Ouro looped LM (``model_type: ouro``, arXiv:2510.25741): a
     Llama-shaped trunk (rope, RMSNorm, SwiGLU, no biases, untied head) with
@@ -174,6 +205,10 @@ def build_model(cfg, attention_fn=None):
     if isinstance(cfg, T5Config):
         assert attention_fn is None, "T5 uses its own unscaled attention"
         return T5Model(cfg)
+    if cfg.block_pattern:
+        from .hybrid import HybridLM
+
+        return HybridLM(cfg, attention_fn=attention_fn)
     if cfg.num_experts > 1:
         from .moe import MoETransformerLM
 

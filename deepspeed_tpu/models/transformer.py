@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
+from itertools import groupby
 from typing import Any, Optional
 
 import jax
@@ -131,6 +132,34 @@ class TransformerConfig:
     # published early_exit_threshold 1 exits at the last): the
     # distribution comes back beside the logits, it steers nothing
     exit_gate: bool = False
+    # one mixer a layer (NemotronH, ``model_type: nemotron_h``): layer i is
+    # ``block_pattern[i]`` alone, x + mixer(norm(x)) with no FFN beside it —
+    # "M" a Mamba-2 mixer (models/ssm.py), "E" latent experts, "*" attention
+    # with no position code (models/hybrid.py). "" is the attention + FFN
+    # block of every other family. The pattern decides cache_layout(): K/V
+    # planes for the "*" layers only, beside a recurrent state a slot
+    block_pattern: str = ""
+    ssm_heads: int = 0                    # Mamba-2: heads H ...
+    ssm_head_dim: int = 0                 # ... of P channels (d_inner = H P)
+    ssm_groups: int = 1                   # B and C are shared by H / G heads
+    ssm_state: int = 0                    # N: a head's state is P x N
+    ssm_conv: int = 4                     # the depthwise conv's kernel
+    ssm_chunk: int = 128                  # the chunked scan's block
+    ssm_dt_init: tuple = (1e-3, 1e-1, 1e-4)   # dt at init: min, max, floor
+    # latent experts: one projection d_model -> moe_latent_dim before the
+    # routed experts and one back after them, shared by all experts, which
+    # are moe_d_ff wide over the latent (0: experts on d_model itself)
+    moe_latent_dim: int = 0
+    # the experts THIS device holds of every expert layer: moe_experts_held
+    # of num_experts from moe_first_held on (0: all). The router keeps its
+    # num_experts outputs and its top-k; the layer computes its own experts'
+    # part of the result (the model-configs guide's chip's share)
+    moe_experts_held: int = 0
+    moe_first_held: int = 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.moe_experts_held or self.num_experts
 
     @property
     def head_dim(self) -> int:
@@ -159,7 +188,12 @@ class TransformerConfig:
         """The trunk as an ordered list of (FFN kind, layers): each segment
         is one block kind scanned over its own stacked weights
         (``params["layers"]``: the stacked tree of a one-segment trunk, a
-        tuple of them otherwise). The attention kind is the model's."""
+        tuple of them otherwise). The attention kind is the model's. With a
+        ``block_pattern`` the kind is the layer's one mixer ("M" | "E" |
+        "*"), a segment a run of equal letters."""
+        if self.block_pattern:
+            return tuple((kind, len(list(run)))
+                         for kind, run in groupby(self.block_pattern))
         if self.num_experts == 1:
             return (("dense", self.n_layer),)
         k = min(self.moe_first_dense, self.n_layer)
@@ -193,7 +227,9 @@ class TransformerConfig:
         n_params = self.loop_steps * self.param_count(
             non_embedding=True, active_only=True)   # every pass multiplies
         # scores + values: 2 * S * H * (qk width + v width) forward, x3
-        attn = 6 * self.loop_steps * self.n_layer * self.n_head * (
+        n_attn = self.block_pattern.count("*") if self.block_pattern \
+            else self.n_layer
+        attn = 6 * self.loop_steps * n_attn * self.n_head * (
             self.head_dim + self.v_dim) * self.max_seq
         head = (0 if self.objective == "feature"
                 else 6 * self.d_model * self.vocab_size)
@@ -222,14 +258,35 @@ class TransformerConfig:
         kv, hd = self.kv_heads, self.head_dim
         return d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
 
+    def _mixer_params_per_layer(self, kind: str, active_only: bool) -> int:
+        """Matmul parameters of one ``block_pattern`` layer of ``kind``."""
+        d = self.d_model
+        if kind == "*":
+            return self._attn_params_per_layer()
+        if kind == "M":
+            inner = self.ssm_heads * self.ssm_head_dim
+            bc = 2 * self.ssm_groups * self.ssm_state
+            return d * (2 * inner + bc + self.ssm_heads) + inner * d
+        lat = self.moe_latent_dim or d
+        experts = min(self.moe_top_k, self.num_experts) if active_only \
+            else self.held_experts
+        return (d * self.num_experts + (2 * d * lat if lat != d else 0)
+                + experts * 2 * lat * self.expert_dim
+                + 2 * d * self.moe_shared_d_ff)
+
     def param_count(self, non_embedding: bool = False,
                     active_only: bool = False) -> int:
-        """Matmul parameters (norms, biases and position tables left out)."""
+        """Matmul parameters (norms, biases and position tables left out);
+        of an expert layer's bank the experts held here."""
         d = self.d_model
         emb = self.vocab_size * d
-        total = sum(n * (self._attn_params_per_layer()
-                         + self._ffn_params_per_layer(active_only, kind))
-                    for kind, n in self.segments)
+        if self.block_pattern:
+            total = sum(n * self._mixer_params_per_layer(kind, active_only)
+                        for kind, n in self.segments)
+        else:
+            total = sum(n * (self._attn_params_per_layer()
+                             + self._ffn_params_per_layer(active_only, kind))
+                        for kind, n in self.segments)
         total += emb if not non_embedding else 0
         if (not self.tie_embeddings and not non_embedding
                 and self.objective != "feature"):
@@ -286,6 +343,8 @@ def _activation(u, name: str):
         return jax.nn.gelu(u, approximate=False)   # erf gelu
     if name == "relu":
         return jax.nn.relu(u)
+    if name == "relu2":
+        return jnp.square(jax.nn.relu(u))           # NemotronH's experts
     if name in ("silu", "swish"):
         return jax.nn.silu(u)
     if name == "quick_gelu":

@@ -106,3 +106,37 @@ def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
     return _call(_down_kernel, "moe_experts_down", h,
                  (w_out.astype(xs.dtype),), be, used, layer, bm, d,
                  _tile(d, 1024), interpret)
+
+
+def _up_relu2_kernel(be_ref, used_ref, _, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        u = jnp.dot(x_ref[...], w_ref[...],
+                    preferred_element_type=jnp.float32)
+        o_ref[...] = jnp.square(jnp.maximum(u, 0.0)).astype(o_ref.dtype)
+
+
+def experts_relu2(xs, w1, w2, block_expert, blocks_used, *, bm: int,
+                  interpret: Optional[bool] = None):
+    """Non-gated experts over a latent (``models/hybrid.py``): row i of
+    ``xs`` (R, lat), laid out as for :func:`experts_swiglu`, through its
+    block's expert ``relu(x @ w1[e]) ** 2 @ w2[e]``; ``w1`` (E, lat, f),
+    ``w2`` (E, f, lat), the experts this device holds. The calls have names
+    of their own (``latent_experts_up`` / ``_down``): one up matrix at the
+    latent width is another count of operations and bytes than gate + up at
+    the model width (``benchmark/kernels``)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    R, lat = xs.shape
+    if R % bm:
+        raise ValueError(f"{R} rows are not whole blocks of {bm}")
+    f = w1.shape[2]
+    be = block_expert.astype(jnp.int32)
+    used = jnp.asarray(blocks_used, jnp.int32).reshape(1)
+    layer = jnp.zeros((1,), jnp.int32)
+    h = _call(_up_relu2_kernel, "latent_experts_up", xs,
+              (w1[None].astype(xs.dtype),), be, used, layer, bm, f,
+              _tile(f, 1024), interpret)
+    return _call(_down_kernel, "latent_experts_down", h,
+                 (w2[None].astype(xs.dtype),), be, used, layer, bm, lat,
+                 _tile(lat, 512), interpret)

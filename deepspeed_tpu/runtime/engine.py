@@ -182,6 +182,11 @@ class Engine:
                 "distribution with an entropy term (arXiv:2510.25741), and "
                 "a next-token loss on the last pass under the model's name "
                 "would be a guess")
+        if getattr(getattr(model, "cfg", None), "block_pattern", ""):
+            raise ValueError(
+                "a trunk of one mixer a layer (block_pattern) is served, not "
+                "trained here: the chunked scan's backward and the held "
+                "experts' exchange are not written")
         mcfg = self.config.moe
         if mcfg.enabled:
             # ds_config moe section overrides the model's MoE knobs

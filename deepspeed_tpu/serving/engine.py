@@ -36,6 +36,7 @@ import numpy as np
 
 from ..inference.config import ServingConfig
 from ..inference.decode import (GenCarry, cache_bytes_per_token,
+                                state_bytes_per_slot,
                                 decode_step, forward_with_cache,
                                 init_cache)
 from ..inference.engine import InferenceEngine
@@ -139,7 +140,9 @@ class ServingEngine:
         self._moe_stats = self._latent \
             and getattr(mcfg, "moe_router", "") == "sigmoid" \
             and any(kind == "moe" for kind, _ in mcfg.segments)
-        if self._latent or getattr(mcfg, "moe_router", "") == "sigmoid":
+        self._hybrid = bool(getattr(mcfg, "block_pattern", ""))
+        if (self._latent or getattr(mcfg, "moe_router", "") == "sigmoid") \
+                and not self._hybrid:       # (its own list is below)
             refused = [name for name, on in (
                 ("the paged pool (page_size)", self.cfg.page_size > 0),
                 ("an int8 KV cache (kv_quant_bits)",
@@ -153,6 +156,34 @@ class ServingEngine:
                 raise ValueError(
                     "a latent (MLA) cache and sigmoid-routed expert layers "
                     "do not yet compose with " + ", ".join(refused))
+        # one mixer a layer (models/hybrid.py block_pattern): a recurrent
+        # state a slot beside K/V planes for the attention layers only,
+        # some of every expert layer's experts held here
+        if self._hybrid:
+            refused = [why for why, on in (
+                ("the paged pool and prefix sharing (page_size): a recurrent "
+                 "state has no pages, and a shared prefix would need the "
+                 "state as it stood at the prefix's end",
+                 self.cfg.page_size > 0),
+                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
+                 "pool", bool(self.cfg.kv_quant_bits)),
+                ("speculation: a rejected draft would have to roll the "
+                 "recurrent state back, and the model's own drafting head "
+                 "is not held", self.cfg.speculation is not None
+                 and self.cfg.speculation.enabled),
+                ("tiered / host KV (host_pool_bytes): it moves pages",
+                 self.cfg.host_pool_bytes > 0),
+                ("weight-only quantization: the mixers' projections take "
+                 "dense weights", bool(engine.config.quantize)),
+                ("a mesh of several devices: the experts held are told by "
+                 "the configuration, no axis exchanges rows yet",
+                 engine.mesh.size > 1)) if on]
+            if refused:
+                raise ValueError(
+                    f"a trunk of one mixer a layer (block_pattern="
+                    f"{mcfg.block_pattern!r}) does not yet compose with "
+                    + "; ".join(refused))
+            self._moe_stats = "E" in mcfg.block_pattern
         # a looped trunk (models/transformer.py loop_steps): its passes'
         # n_layer x loop_steps cache planes are contiguous bf16/fp only
         self._loops = int(getattr(mcfg, "loop_steps", 1))
@@ -194,7 +225,9 @@ class ServingEngine:
         self._chunk_routing: list = []   # (rid, start, device routing, real)
         self._cache_bytes_per_token = cache_bytes_per_token(
             mcfg, engine.compute_dtype) \
-            if self._latent or self._loops > 1 else None
+            if self._latent or self._hybrid or self._loops > 1 else None
+        self._state_bytes_per_slot = state_bytes_per_slot(
+            mcfg, engine.compute_dtype) if self._hybrid else 0
         if self._loops > 1:
             # what a looped program reads of the weights, from the served
             # tree's shapes: the layers once a pass, and the head (with the
@@ -486,7 +519,8 @@ class ServingEngine:
                                ttft_deadline_s=self.cfg.ttft_deadline_s,
                                total_deadline_s=self.cfg.total_deadline_s,
                                spans=self.spans, pages=self.pool,
-                               rid_source=rid_source)
+                               rid_source=rid_source,
+                               recurrent=self._hybrid)
         self._programs: OrderedDict = \
             programs if programs is not None else OrderedDict()
         # disaggregated-serving hook (serving/fleet.py): a side-effecting
@@ -1038,6 +1072,8 @@ class ServingEngine:
         chunks' counters go onto their own ``prefill_chunk`` spans. Rows
         routed count the whole slot batch: an idle slot's token is routed
         and multiplied like any other."""
+        if self._hybrid:
+            return self._hybrid_counts(moe, pending)
         if not moe:          # no expert trunk, or the chaos build's step
             return {}
         k = self.model.cfg.moe_top_k
@@ -1052,6 +1088,42 @@ class ServingEngine:
                     st[:, 2].sum() / (len(st) * routed)),
                 "experts_touched": float(st[:, 1].mean()),
                 "cache_bytes_per_token": self._cache_bytes_per_token}
+
+    def _hybrid_meta(self) -> dict:
+        """What every ``decode_step`` and ``prefill_chunk`` span of a
+        ``block_pattern`` trunk says beside its times: what a cached token
+        and a slot's recurrent state cost (``cache_layout()``,
+        ``state_layout()``)."""
+        return {"cache_bytes_per_token": self._cache_bytes_per_token,
+                "state_bytes_per_slot": self._state_bytes_per_slot}
+
+    def _hybrid_counts(self, moe: list, pending: list) -> dict:
+        """Meta of a ``block_pattern`` trunk's ``decode_step`` span. Of the
+        step's expert layers (``HybridLM.latent_experts``' counters, one row
+        a layer: most rows a held expert got, held experts touched, rows
+        multiplied, rows that chose a held expert): ``held_rows`` and
+        ``experts_touched``, means over the layers; ``held_rows_share`` of
+        the slots x k rows routed; the load as :meth:`_moe_counts` has it,
+        over the held experts. (That a slot at length 0 costs the step's
+        ``ssm_state_step`` nothing is no field here: the host could only
+        assert it. ``benchmark/kinds/backlog_hybrid.py`` holds the idle
+        slots' state to bit-equality on every run.)"""
+        meta = self._hybrid_meta()
+        if not moe:
+            return meta
+        k, held = self.model.cfg.moe_top_k, self.model.cfg.held_experts
+        for (chunk_span, _, size), st in zip(pending, moe[1:]):
+            chunk_span.amend(held_rows=float(st[:, 3].mean()),
+                             experts_touched=float(st[:, 1].mean()))
+        st = moe[0]
+        held_rows = float(st[:, 3].mean())
+        rows = max(held_rows, 1.0)
+        meta.update(
+            held_rows=held_rows,
+            held_rows_share=held_rows / (self.cfg.slots * k),
+            experts_touched=float(st[:, 1].mean()),
+            moe_load_max_over_mean=float(st[:, 0].max() * held / rows))
+        return meta
 
     def _loop_meta(self, tokens: int, head: bool = True) -> dict:
         """What a looped trunk's ``decode_step`` and ``prefill_chunk`` spans
@@ -1460,6 +1532,7 @@ class ServingEngine:
                         **(self._loop_meta(ch.last_index + 1 if ch.final
                                            else ch.size, head=ch.final)
                            if self._loops > 1 else {}),
+                        **(self._hybrid_meta() if self._hybrid else {}),
                         **self.sched._attempt_meta(req)) as chunk_span:
             ids = jnp.asarray(ch.ids[None], jnp.int32)
             if not ch.final:

@@ -73,7 +73,8 @@ def _pow2_ceil(n: int) -> int:
     return p
 
 
-def plan_chunks(prompt: np.ndarray, chunk: int, skip: int = 0) -> list:
+def plan_chunks(prompt: np.ndarray, chunk: int, skip: int = 0,
+                overlap: bool = True) -> list:
     """Split one prompt into bucket-shaped prefill chunks.
 
     Full ``chunk``-size chunks cover the head of the prompt; the residual
@@ -85,7 +86,13 @@ def plan_chunks(prompt: np.ndarray, chunk: int, skip: int = 0) -> list:
     only the suffix is recomputed. Chunk shapes stay in the same bucket
     set regardless of ``skip`` — sharing never compiles a new program —
     and the final overlap bucket may rewind INTO the hydrated region,
-    rewriting bit-identical KV (the chunked==whole prefill oracle)."""
+    rewriting bit-identical KV (the chunked==whole prefill oracle).
+
+    ``overlap=False`` (a model with a recurrent state,
+    ``Scheduler.recurrent``): a token must pass the state once, so the
+    residual is never rewound — it starts where the full chunks end and is
+    right-padded to its bucket (the same sizes in the same order: no other
+    program is built), and the model stops its state at ``last_index``."""
     prompt = np.asarray(prompt, np.int32).reshape(-1)
     P = len(prompt)
     if P < 1:
@@ -99,13 +106,15 @@ def plan_chunks(prompt: np.ndarray, chunk: int, skip: int = 0) -> list:
                        ids=prompt[skip + i * chunk:skip + (i + 1) * chunk])
              for i in range(k)]
     b = max(_MIN_BUCKET, _pow2_ceil(r))
-    if P >= b:        # overlap: recompute the last b prompt tokens
+    if overlap and P >= b:      # recompute the last b prompt tokens
         plans.append(ChunkPlan(start=P - b, ids=prompt[P - b:], final=True,
                                last_index=b - 1, true_len=P))
-    else:             # short prompt: right-pad to the bucket
-        ids = np.concatenate([prompt, np.zeros(b - P, np.int32)])
-        plans.append(ChunkPlan(start=0, ids=ids, final=True,
-                               last_index=P - 1, true_len=P))
+    else:       # right-pad to the bucket: a short prompt whole, or (never
+        #         rewound) the residual from where the full chunks end
+        at = 0 if overlap else P - r
+        ids = np.concatenate([prompt[at:], np.zeros(b - (P - at), np.int32)])
+        plans.append(ChunkPlan(start=at, ids=ids, final=True,
+                               last_index=P - at - 1, true_len=P))
     return plans
 
 
@@ -188,10 +197,13 @@ class Scheduler:
                  ttft_deadline_s: float = 0.0,
                  total_deadline_s: float = 0.0,
                  spans: "Optional[_spans.SpanRecorder]" = None,
-                 pages=None, rid_source=None):
+                 pages=None, rid_source=None, recurrent: bool = False):
         self.slots = slots
         self.max_len = max_len
         self.prefill_chunk = prefill_chunk
+        # a model that keeps a recurrent state (models/hybrid.py): plans
+        # never rewind (plan_chunks overlap=False)
+        self.recurrent = recurrent
         self.max_queue = max_queue
         self.eos_token_id = eos_token_id
         # paged-KV pool (serving/pages.py PagePool): admission consults
@@ -238,6 +250,15 @@ class Scheduler:
                 f"prompt ({len(prompt)}) + max_new ({max_new}) exceeds the "
                 f"slot capacity max_len={self.max_len} — raise "
                 f"serving.max_len or trim the request")
+        if self.recurrent:
+            # a plan that never rewinds pads its last chunk behind the
+            # prompt: the pad has to fit too (an update past the cache's
+            # end would be clamped back onto live positions)
+            last = plan_chunks(prompt, self.prefill_chunk, overlap=False)[-1]
+            if last.start + last.size > self.max_len:
+                raise ValueError(
+                    f"a prompt of {len(prompt)} tokens, its last chunk "
+                    f"padded to {last.size}, exceeds max_len={self.max_len}")
         if self.max_queue and len(self.queue) >= self.max_queue:
             self.stats.on_shed(len(self.queue))
             raise QueueFullError(
@@ -324,7 +345,8 @@ class Scheduler:
 
     def plan(self, req: Request) -> list:
         skip = req.page_alloc.skip if req.page_alloc is not None else 0
-        return plan_chunks(req.prompt, self.prefill_chunk, skip=skip)
+        return plan_chunks(req.prompt, self.prefill_chunk, skip=skip,
+                           overlap=not self.recurrent)
 
     def _release_pages(self, req: Request) -> None:
         """Every terminal path funnels here: drop the request's page

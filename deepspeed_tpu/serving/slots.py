@@ -4,7 +4,9 @@ Reference analog: DeepSpeed-MII / FastGen's blocked-KV "ragged batching"
 state. TPU-native translation: instead of a paged block table (dynamic
 indirection is hostile to XLA's static shapes), the serving state is ONE
 ``(L, slots, KV, hd, max_len)`` cache (``(L, slots, rank + rope, max_len)``
-for latent attention) — the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
+for latent attention; for a trunk of one mixer a layer K/V planes for its
+attention layers only beside a recurrent state a slot, ``HybridCache``) —
+the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
 positions on the lanes, so the buffer is compact in HBM at any head size
 and the decode step's kernel appends to it and reads it where it lies
 (``ops/decode_attention.py``) — plus per-slot ``length`` / ``tok`` /
@@ -40,8 +42,9 @@ def init_slots(cfg, slots: int, max_len: int, dtype=None) -> GenCarry:
     The carry is a plain :class:`~..inference.decode.GenCarry` whose cache
     ``length`` is a (slots,) vector — the decode stack's per-slot paths key
     off that shape, so the same ``decode_step`` serves both worlds. The
-    cache is of the model's kind (K and V, or latents), from
-    ``cache_layout``: what follows treats its buffers alike."""
+    cache is of the model's kind (K and V, latents, or K/V beside a
+    recurrent state), from ``cache_layout`` / ``state_layout``: what follows
+    treats its buffers alike."""
     cache = init_cache(cfg, slots, max_len, dtype, length_shape=(slots,))
     return GenCarry(tok=jnp.zeros((slots,), jnp.int32), cache=cache,
                     rng=jnp.zeros((slots, 2), jnp.uint32),
@@ -79,7 +82,8 @@ def insert_request(state: GenCarry, slot, pf: GenCarry,
     is what guarantees a retired request's stale KV is fully overwritten
     before the new occupant's first decode step."""
     kc = state.cache
-    # every buffer of the cache (K and V; the latents) has the slot second
+    # every buffer of the cache (K and V; the latents; a recurrent state
+    # beside K/V) has the slot second
     buffers = {
         name: lax.dynamic_update_slice(
             buf, getattr(pf.cache, name).astype(buf.dtype),

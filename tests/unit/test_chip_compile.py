@@ -490,3 +490,99 @@ def test_looped_trunk_keeps_the_cache_in_place(one_chip, monkeypatch,
         assert len(calls) == 1 and "decode_attention" in calls[0]
     else:
         assert not calls      # T > 1 attends densely over its plane
+
+
+# -------------------------------------------------- one mixer a layer
+@pytest.mark.parametrize("program", ["slot step", "chunk", "final chunk"])
+def test_hybrid_trunk_keeps_the_state_in_place(one_chip, monkeypatch,
+                                               program, capsys):
+    """Nemotron-3-Super's share (layers MEMEMEM*EME, 128 of 512 experts,
+    ``benchmark/configs/nemotron-3-super-l11-e128.json``) at the cell's 64
+    slots x 6144, chunks of 512: the cache's four buffers enter donated and
+    leave aliased; no copy of the (5, 64, ...) state stands around a layer
+    (temporaries under 64 MiB in the step, under 1 GiB in a chunk); every
+    new kernel lowers for the chip; the step's live set leaves the chip over
+    1 GiB beside the batch-1 prefill cache. The pattern's eleven runs of one
+    layer are eleven bodies of the program (no loop to scan: PERF.md), and
+    the compile time says what that costs."""
+    import json
+    import time
+
+    from benchmark.models import nemotron_h as fam
+    from deepspeed_tpu.inference.decode import (forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 64, 6144, 512
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-super-l11-e128.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+    keep = set(model.fp32_param_names())
+
+    def served(path, a):
+        """As ``InferenceEngine`` serves it: bf16 but for the leaves the
+        model keeps in float32 (the routers, the decay's scalars)."""
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype if name in keep else jnp.bfloat16,
+            sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = jax.tree_util.tree_map_with_path(
+        served, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, slots, max_len, jnp.bfloat16)))
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        batch = slots
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, max_len, jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True)[final ^ 1:],
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        batch = 1
+    took = time.perf_counter() - t0
+    with capsys.disabled():
+        print(f"\n[hybrid {program}: compiled for a described v5e in "
+              f"{took:.1f} s]")
+    mem = compiled.memory_analysis()
+    held = batch * (state_bytes_per_slot(cfg, jnp.bfloat16) + max_len * 1024)
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 21278720
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert mem.temp_size_in_bytes < (64 if batch > 1 else 1024) * 2 ** 20, \
+        mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    prefill_cache = state_bytes_per_slot(cfg, jnp.bfloat16) + max_len * 1024
+    assert live + (prefill_cache if batch > 1 else 0) \
+        < (15.75 - 1.0) * 2 ** 30, live
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "ssm_state_step", "latent_experts_up", "latent_experts_down",
+        "decode_attention", "moe_experts_up")}
+    # (a chunk with no head drops its last layer, an E: nothing reads it)
+    experts = 5 if program != "chunk" else 4
+    assert count == {
+        "ssm_state_step": 5 if batch > 1 else 0,
+        "latent_experts_up": experts, "latent_experts_down": experts,
+        "decode_attention": 1 if batch > 1 else 0, "moe_experts_up": 0}, count
