@@ -541,8 +541,13 @@ class ServingEngine:
         # (request, chunk plan, next chunk idx, device prefill cache, rng)
         self._prefill = None
         # (chunk, its program's output, its span): the lane's next chunk
-        # where _prefill_ahead dispatched it behind the last decode step
+        # as _lane_dispatch left it for _prefill_advance, behind the last
+        # decode step or in front of this one
         self._ahead = None
+        # (a scalar of this iteration's insert program, the batch-1 cache
+        # it read): an admission behind the iteration's step waits for the
+        # one and frees the other (_admit)
+        self._seated = None
         # resilience state: chaos only exists when explicitly enabled —
         # disabled serving carries a single `is not None` check per step
         self.chaos: Optional[ChaosMonkey] = None
@@ -1049,10 +1054,12 @@ class ServingEngine:
         ``prefill_chunk``, ``prefill_readback``, ``place``,
         ``decode_dispatch``, ``decode_readback``, ``retire``, ``tail``
         (observability/spans.py; the span less the children is the
-        loop's own time). Where the prefill lane has a further chunk, its
-        ``prefill_chunk`` stands between ``decode_dispatch`` and
-        ``decode_readback`` of the iteration before
-        (``_prefill_ahead``)."""
+        loop's own time). Where a request was waiting with a slot free,
+        or the lane had a further chunk, when the step before went out,
+        ``admit`` and ``prefill_chunk`` (``ahead`` 1) stand between that
+        iteration's ``decode_dispatch`` and ``decode_readback`` instead
+        (``_lane_dispatch``); in front of the step they are the fallback
+        for a request that arrived, or a slot that was freed, since."""
         with self._span(_spans.SRV_STEP, step=self._iterations):
             return self._iterate()
 
@@ -1217,10 +1224,9 @@ class ServingEngine:
                 # device BEFORE their pages can be reused by this
                 # iteration's admission or written by this decode step
                 self._flush_table()
-            # admission: start the head-of-queue request's prefill
-            if self._prefill is None:
-                with self._span(_spans.SRV_ADMIT, step=n_it):
-                    self._admit()
+            # admission and the lane's chunk, in front of the step: the
+            # fallback for what the last step's dispatch could not see
+            self._lane_dispatch(n_it, ahead=0)
             # prefill lane: one bucket-shaped chunk per iteration
             if self._prefill is not None:
                 finished += self._prefill_advance(n_it)
@@ -1253,9 +1259,12 @@ class ServingEngine:
                                 self._step_impl, donate_argnums=(1,)))
                             self._state, ok, *moe = step(self.engine.params,
                                                          self._state)
-                if plan is None and chaos is None \
-                        and self._prefill is not None:
-                    self._prefill_ahead(n_it)
+                lane_s = 0.0
+                if plan is None and chaos is None:
+                    # next iteration's admission and chunk, behind the step
+                    lane_t0 = self.stats.clock()
+                    self._lane_dispatch(n_it, ahead=1)
+                    lane_s = self.stats.clock() - lane_t0
                 if self._slot_len is not None:
                     # a running row appends, then attends; the others stay
                     self._slot_len[self._slot_len > 0] += 1
@@ -1293,7 +1302,10 @@ class ServingEngine:
                             if self._loops > 1
                             else self._moe_counts(moe, pending))
                 t1 = self.stats.clock()
-                self._last_step_s = t1 - t0
+                # the step's dispatch and read-back: the lane's host work
+                # behind the step (a tree match, a tiered restore's tiles)
+                # is not the step's, however long it takes
+                self._last_step_s = t1 - t0 - lane_s
                 # the parent of the decode pair, from the t0/t1 the
                 # watchdog measures anyway; the counts at this boundary
                 # (slots decoding, requests waiting; an expert trunk's
@@ -1362,6 +1374,7 @@ class ServingEngine:
                         finished += ended
                 ran_decode = True
         with self._span(_spans.SRV_TAIL, step=n_it):
+            self._seated = None     # inserted long since: let its cache go
             if self._demote_ahead is not None:
                 # background demotion lane: stage idle tree-held pages
                 # into the tier BEFORE pressure (the staged gathers drain
@@ -1428,9 +1441,25 @@ class ServingEngine:
             # residency probe beside it: ghost-tree regret match +
             # session resume edge (host-side only)
             self.kvscope.on_admit(req)
-        cache = self._prog("init_cache", lambda: jax.jit(
-            lambda: init_cache(self.model.cfg, 1, self.cfg.max_len,
-                               self.engine.compute_dtype)))()
+        if self._seated is not None:
+            # one batch-1 cache at a time: behind a step that follows a
+            # seat the seated cache may still wait for its insert, and the
+            # runtime makes room for init_cache's output at dispatch. The
+            # insert went out to an idle device and the step runs
+            # meanwhile, so the wait is short and costs the device nothing
+            inserted, old = self._seated
+            self._seated = None
+            jax.block_until_ready(inserted)
+            for buf in jax.tree_util.tree_leaves(old):
+                buf.delete()
+        # the batch-1 cache and the request's key from ONE program: the
+        # key is per_request_keys([seed]) traced, bit for bit, where the
+        # eager call is three small programs in front of the chunk
+        cache, rng = self._prog("init_cache", lambda: jax.jit(
+            lambda seed: (init_cache(self.model.cfg, 1, self.cfg.max_len,
+                                     self.engine.compute_dtype),
+                          jax.random.PRNGKey(seed)[None])))(
+            np.int64(req.seed))
         alloc = req.page_alloc
         if alloc is not None and alloc.hydrate_pages > 0:
             # prefix sharing: gather the shared pages into the prefill
@@ -1438,16 +1467,15 @@ class ServingEngine:
             # unshared suffix
             hyd = self._prog("hydrate", lambda: jax.jit(
                 hydrate_cache, donate_argnums=(1,)))
-            cache = hyd(self._state, cache, jnp.asarray(alloc.hydrate_row),
-                        jnp.int32(alloc.hydrate_pages))
+            cache = hyd(self._state, cache, alloc.hydrate_row,
+                        np.int32(alloc.hydrate_pages))
         if alloc is not None and alloc.restored:
             # host-tier restore: the pending-restore lane beside the
             # prefill lane — scatter the cold blocks' tiles into the
             # prefill cache; the suffix chunks dispatched next overlap
             # the H2D
             cache = self._restore_dispatch(cache, alloc)
-        self._prefill = (req, self.sched.plan(req), 0, cache,
-                         per_request_keys([req.seed]))
+        self._prefill = (req, self.sched.plan(req), 0, cache, rng)
 
     def _store_result(self, req: Request) -> None:
         if self._paged and req.slot >= 0 \
@@ -1516,56 +1544,65 @@ class ServingEngine:
             except QueueFullError:
                 pass  # the shed IS the scenario; counted in Serve/shed
 
-    def _chunk_dispatch(self, n_it: int, **ahead) -> tuple:
-        """Dispatch the prefill lane's next chunk; (the chunk, what its
-        program returned, its span)."""
+    def _lane_dispatch(self, n_it: int, ahead: int) -> None:
+        """The prefill lane's host work for one iteration: admit the head
+        of the queue if the lane is empty, and dispatch the lane's next
+        chunk, final or not, into ``_ahead`` for ``_prefill_advance``.
+        Called behind the decode step just dispatched (``ahead=1``) it is
+        NEXT iteration's admission and chunk: the device runs
+        ``init_cache`` and the chunk while the host reads the step back,
+        retires and books, instead of standing idle until the host comes
+        round. Called at the top of an iteration (``ahead=0``) it does what
+        is left: a request that arrived or a slot that was freed since the
+        last step, an engine with nothing running. Either way one chunk an
+        iteration; a lane cleared meanwhile (cancel, deadline) leaves its
+        chunk unused and it is dropped here. The spans say ``ahead`` and
+        carry the iteration that dispatched them."""
+        if self._prefill is None and (self.sched.queue or not ahead):
+            self._ahead = None
+            if ahead:
+                # rows cleared since the top of the iteration reach the
+                # device before an admission can reuse their pages
+                self._flush_table()
+            with self._span(_spans.SRV_ADMIT, step=n_it, ahead=ahead):
+                self._admit()
+        if self._prefill is None or self._ahead is not None:
+            return
         req, plan, idx, cache, rng = self._prefill
         ch = plan[idx]
         params = self.engine.params
         # the span is the DISPATCH of one chunk program: where dispatch
         # is asynchronous (any accelerator) it times an enqueue, and the
         # chunk's device time shows up in whatever blocks next
-        # (prefill_readback on a final chunk, else decode_readback)
+        # (prefill_readback on a final chunk, else decode_readback). The
+        # scalars go in as numpy: an argument of the chunk's program, not
+        # a convert program of their own in front of it
         with self._span(_spans.PREFILL_CHUNK, name="srv.prefill_chunk",
                         rid=req.rid, step=n_it, chunk=idx, size=ch.size,
-                        final=ch.final, **ahead,
+                        final=ch.final, ahead=ahead,
                         **(self._loop_meta(ch.last_index + 1 if ch.final
                                            else ch.size, head=ch.final)
                            if self._loops > 1 else {}),
                         **(self._hybrid_meta() if self._hybrid else {}),
                         **self.sched._attempt_meta(req)) as chunk_span:
-            ids = jnp.asarray(ch.ids[None], jnp.int32)
+            ids = ch.ids[None]
             if not ch.final:
                 fwd = self._prog(("chunk", ch.size), lambda: jax.jit(
                     self._chunk_impl, donate_argnums=(1,)))
-                out = fwd(params, cache, ids, jnp.int32(ch.start))
+                out = fwd(params, cache, ids, np.int32(ch.start))
             else:
                 fin = self._prog(("final", ch.size), lambda: jax.jit(
                     self._final_impl, donate_argnums=(1,)))
-                out = fin(params, cache, ids, jnp.int32(ch.start),
-                          jnp.int32(ch.last_index), jnp.int32(ch.true_len),
+                out = fin(params, cache, ids, np.int32(ch.start),
+                          np.int32(ch.last_index), np.int32(ch.true_len),
                           rng)
-        return ch, out, chunk_span
-
-    def _prefill_ahead(self, n_it: int) -> None:
-        """Behind the decode step just dispatched, the chunk the prefill
-        lane would dispatch first thing next iteration: the device runs it
-        while the host reads the step back, retires and books, instead of
-        standing idle until the host comes round. It stays next
-        iteration's chunk (one an iteration, consumed by
-        ``_prefill_advance``); a lane cleared meanwhile (cancel, deadline)
-        leaves it unused. Its span says ``ahead`` and carries the
-        iteration that dispatched it."""
-        self._ahead = self._chunk_dispatch(n_it, ahead=True)
+        self._ahead = ch, out, chunk_span
         # the program took the lane's cache (donated)
-        self._prefill = self._prefill[:3] + (None,) + self._prefill[4:]
+        self._prefill = (req, plan, idx, None, rng)
 
     def _prefill_advance(self, n_it: int) -> list[Request]:
         req, plan, idx, _, rng = self._prefill
-        ahead, self._ahead = self._ahead, None
-        if ahead is None or ahead[0] is not plan[idx]:
-            ahead = self._chunk_dispatch(n_it)
-        ch, out, chunk_span = ahead
+        (ch, out, chunk_span), self._ahead = self._ahead, None
         if self._moe_stats or self._exit_gate:
             # the chunk's expert counters (a looped trunk's: its mean exit
             # distribution) stay on the device until the next decode
@@ -1592,23 +1629,30 @@ class ServingEngine:
             self._place(req, first_tok, pf)
         return []
 
+    def _insert_impl(self, state, slot, *rest):
+        """The seat's program, and a scalar of the state it leaves: what
+        the host can wait for to know the insert has run, since the state
+        itself goes on into the step (donated)."""
+        state = (insert_paged if self._paged else insert_request)(
+            state, slot, *rest)
+        return state, state.done[slot]
+
     def _place(self, req: Request, first_tok: int, pf) -> None:
         """Seat a prefilled request: take a slot, dispatch the insert of
         its cache into the slot state."""
         slot = self.sched.place(req, first_tok)
         # donate only the slot state: the batch-1 prefill buffers have
         # different shapes and could never alias the slot cache anyway
+        ins = self._prog("insert", lambda: jax.jit(
+            self._insert_impl, donate_argnums=(0,)))
         if self._paged:
             alloc = req.page_alloc
             self._table[slot] = alloc.row
             self._table_dirty = True
             self._flush_table()
-            ins = self._prog("insert", lambda: jax.jit(
-                insert_paged, donate_argnums=(0,)))
-            self._state = ins(self._state, jnp.int32(slot), pf,
-                              jnp.asarray(alloc.row),
-                              jnp.int32(alloc.shared),
-                              np.int32(req.max_new - 1))
+            self._state, inserted = ins(
+                self._state, np.int32(slot), pf, alloc.row,
+                np.int32(alloc.shared), np.int32(req.max_new - 1))
             # the prompt's blocks are in the pool now: index them for
             # future sharing and release the copy-on-write source pin
             self.pool.on_inserted(req.rid, req.prompt)
@@ -1617,12 +1661,11 @@ class ServingEngine:
                 # of these blocks bills its tier bytes to this tenant
                 self.tenantscope.on_blocks(req)
         else:
-            ins = self._prog("insert", lambda: jax.jit(
-                insert_request, donate_argnums=(0,)))
-            self._state = ins(self._state, jnp.int32(slot), pf,
-                              np.int32(req.max_new - 1))
+            self._state, inserted = ins(self._state, np.int32(slot), pf,
+                                        np.int32(req.max_new - 1))
             if self._slot_len is not None:
                 self._slot_len[slot] = req.prompt_len
+        self._seated = inserted, pf.cache
         if self.on_placed is not None:
             # disaggregated handoff: the fleet may export the freshly
             # seated request and release the slot before this very
@@ -1809,9 +1852,9 @@ class ServingEngine:
             for key, v in tiles.items():
                 pad = np.zeros(v.shape[:1] + (R,) + v.shape[2:], v.dtype)
                 pad[:, :cnt] = v[:, off:off + cnt]
-                batch[key] = jnp.asarray(pad)
-            cache = prog(cache, batch, jnp.int32(alloc.shared + off),
-                         jnp.int32(cnt))
+                batch[key] = pad
+            cache = prog(cache, batch, np.int32(alloc.shared + off),
+                         np.int32(cnt))
             off += cnt
         self.pool.host.on_restore(self.stats.clock() - t0,
                                   pages=alloc.restored,

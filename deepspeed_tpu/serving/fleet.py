@@ -421,6 +421,8 @@ class FleetEngine:
             live.append(eng._prefill[0])
             eng._prefill = None
         live += eng.sched.take_live()
+        # the lane's request may be younger than one that is running
+        live.sort(key=lambda r: (r.submit_t, r.rid))
         requeued = []
         requeue_role = ROLE_PREFILL if self._disagg else ROLE_SERVE
         # ONE ranking pass for the whole failover burst (the pattern

@@ -907,13 +907,14 @@ def iteration_spans(tiny_server):
 
 PHASE_ORDER = ["srv.deadlines", "srv.admit", "prefill_chunk",
                "srv.prefill_readback", "srv.place", "srv.decode_dispatch",
-               "prefill_chunk.ahead", "srv.decode_readback", "srv.retire",
-               "srv.tail"]
+               "srv.admit.ahead", "prefill_chunk.ahead",
+               "srv.decode_readback", "srv.retire", "srv.tail"]
 
 
 def _phase(e):
-    """A chunk dispatched behind the decode step for the next iteration
-    (``_prefill_ahead``) is a phase of its own."""
+    """An admission and a chunk dispatched behind the decode step for the
+    next iteration (``_lane_dispatch(ahead=1)``) are phases of their
+    own."""
     return e.kind + (".ahead" if e.meta.get("ahead") else "")
 
 

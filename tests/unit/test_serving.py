@@ -158,6 +158,325 @@ def test_chunk_dispatched_ahead(setup, then):
     _check_parity(model, params, want, got)
 
 
+def _chunks(srv):
+    return [e for e in srv.spans.events() if e.kind == "prefill_chunk"]
+
+
+def _between_the_decode_pair(srv, span):
+    """``span`` lies after ``srv.decode_dispatch`` and before
+    ``srv.decode_readback`` of the iteration it carries."""
+    pair = {e.kind: e for e in srv.spans.events() if e.step == span.step
+            and e.kind in ("srv.decode_dispatch", "srv.decode_readback")}
+    return pair["srv.decode_dispatch"].t1 <= span.t0 \
+        and span.t1 <= pair["srv.decode_readback"].t0
+
+
+@pytest.mark.parametrize("prompts", [(5, 7, 6, 4, 8), (5, 21, 7, 37, 12)],
+                         ids=["single_final_chunks", "mixed"])
+def test_waiting_request_is_admitted_behind_the_step(setup, prompts):
+    """Requests queued with a slot free: the first is admitted in front
+    (nothing runs yet), every later admission and every later chunk, a
+    single final one too, goes out behind a decode step (``ahead`` 1,
+    between that iteration's dispatch and read-back) and is consumed by
+    the next iteration, one chunk an iteration; the tokens are solo
+    ``generate()``'s."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 6, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20,
+                              "spans": True})
+    rng = np.random.default_rng(11)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), 10, 70 + i)
+            for i, P in enumerate(prompts)]
+    rids = [srv.submit(p, n, seed=s) for p, n, s in reqs]
+    srv.drain()
+    chunks = _chunks(srv)
+    assert [c.meta["ahead"] for c in chunks] == [0] + [1] * (len(chunks) - 1)
+    # one chunk an iteration: each is consumed by the iteration after the
+    # one whose step it went out behind
+    assert [c.step + c.meta["ahead"] for c in chunks] \
+        == list(range(len(chunks)))
+    assert all(_between_the_decode_pair(srv, c) for c in chunks[1:])
+    admits = [e for e in srv.spans.events() if e.kind == "srv.admit"
+              and e.meta["ahead"]]
+    assert len(admits) >= len(reqs) - 1
+    assert all(_between_the_decode_pair(srv, a) for a in admits)
+    # a final chunk is read back, and its request seated, by the iteration
+    # after the one that dispatched it
+    reads = {e.step for e in srv.spans.events()
+             if e.kind == "srv.prefill_readback"}
+    assert reads == {c.step + c.meta["ahead"] for c in chunks
+                     if c.meta["final"]}
+    assert srv.metrics_snapshot()["prefill_chunks"] == len(chunks)
+    _check_parity(model, params, reqs,
+                  [srv.pop_result(r).tokens for r in rids])
+
+
+def test_arrival_after_the_dispatch_is_admitted_in_front(setup):
+    """A request submitted into an engine whose queue was empty when the
+    last step went out is admitted, prefilled and seated by the very next
+    ``step()``, in front of its decode step (``ahead`` 0): no admission
+    waits longer than before."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 3, "max_len": M, "prefill_chunk": 16,
+                              "temperature": 0.8, "top_k": 20,
+                              "spans": True})
+    rng = np.random.default_rng(12)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), 12, 80 + i)
+            for i, P in enumerate([9, 13])]
+    first = srv.submit(*reqs[0][:2], seed=reqs[0][2])
+    srv.step()
+    srv.step()                      # decoding; nothing waited at dispatch
+    assert srv._prefill is None and srv._ahead is None
+    late = srv.submit(*reqs[1][:2], seed=reqs[1][2])
+    at = srv._iterations
+    srv.step()
+    chunk = _chunks(srv)[-1]
+    assert chunk.rid == late and chunk.step == at
+    assert chunk.meta["ahead"] == 0 and chunk.meta["final"]
+    dispatch = next(e for e in srv.spans.events() if e.step == at
+                    and e.kind == "srv.decode_dispatch")
+    assert chunk.t1 <= dispatch.t0
+    assert {r.rid for r in srv.sched.running.values()} == {first, late}
+    assert len(srv.sched.running[1].tokens) == 2    # seated, then stepped
+    srv.drain()
+    _check_parity(model, params, reqs,
+                  [srv.pop_result(r).tokens for r in (first, late)])
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+@pytest.mark.parametrize("how", ["cancel", "deadline"])
+def test_lane_cleared_with_its_admission_ahead(setup, how, paged):
+    """A request admitted behind the step whose chunk is in flight is
+    cancelled (or runs out of time) before the next iteration: aborted
+    once, the chunk's result dropped, no slot and no page leaked, and the
+    next in line takes the lane in front."""
+    from _fake_clock import TickClock
+
+    cfg, model, params, eng = setup
+    clock = TickClock()
+    srv = ServingEngine(eng, {"slots": 3, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20,
+                              **({"page_size": 8} if paged else {})},
+                        clock=clock)
+    rng = np.random.default_rng(13)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), N, 90 + i)
+            for i, (P, N) in enumerate([(6, 20), (7, 5), (21, 6)])]
+    first = srv.submit(*reqs[0][:2], seed=reqs[0][2])
+    doomed = srv.submit(*reqs[1][:2], seed=reqs[1][2],
+                        ttft_deadline_s=0.0 if how == "cancel" else 50.0)
+    srv.step()            # first seated; doomed admitted behind the step
+    assert srv._prefill[0].rid == doomed and srv._ahead is not None
+    free = list(srv.sched.free)
+    last = srv.submit(*reqs[2][:2], seed=reqs[2][2])
+    if how == "cancel":
+        assert srv.cancel(doomed).status.name == "CANCELLED"
+        assert srv.cancel(doomed) is None
+    else:
+        clock.advance(60.0)
+    done = srv.step()
+    if how == "deadline":
+        assert [(r.rid, r.status.name) for r in done] == [(doomed, "TIMEOUT")]
+    assert srv.results[doomed].tokens == []
+    assert srv.sched.free == free               # no slot taken or lost
+    assert srv._prefill[0].rid == last          # in front, then ahead
+    srv.drain()
+    assert srv._ahead is None and len(srv.sched.free) == 3
+    if paged:
+        snap = srv.pool.snapshot()
+        assert snap["free_pages"] + snap["tree_held_pages"] == snap["usable_pages"]
+        assert snap["live_requests"] == 0
+    snap = srv.metrics_snapshot()
+    assert snap["retired"] == 2 and snap["submitted"] == 3
+    _check_parity(model, params, [reqs[0], reqs[2]],
+                  [srv.pop_result(r).tokens for r in (first, last)])
+
+
+@pytest.mark.parametrize("what", ["shared_prefix", "tiered_restore"])
+def test_paged_admission_behind_the_step(setup, what):
+    """On the paged pool an admission behind the step hydrates a shared
+    prefix from pages a running slot holds, or restores blocks the host
+    tier holds, as one in front does: same plan, same tokens."""
+    cfg, model, params, eng = setup
+    rng = np.random.default_rng(15)
+    tiered = what == "tiered_restore"
+    srv = ServingEngine(eng, {
+        "slots": 3, "max_len": M, "prefill_chunk": 16, "page_size": 8,
+        "temperature": 0.8, "top_k": 20, "spans": True,
+        **({"pool_pages": 11, "host_pool_bytes": 8 << 20} if tiered
+           else {})})
+
+    def finish(rids):
+        while not all(r in srv.results for r in rids):
+            srv.step()
+        return [srv.pop_result(r).tokens for r in rids]
+
+    old = [(rng.integers(0, 256, (32,)).astype(np.int32), 8, 20 + i)
+           for i in range(4)]
+    if tiered:
+        # ten usable pages, five a request: by the fourth every block of
+        # the first prompt has been evicted to the host tier and drained
+        for p, n, seed in old:
+            finish([srv.submit(p, n, seed=seed)])
+        assert srv.hostkv.snapshot()["pages"] >= 4
+    short = (rng.integers(0, 256, (9,)).astype(np.int32), 16, 30)
+    again = (old[0][0] if tiered else np.concatenate(
+        [short[0][:8], rng.integers(0, 256, (13,)).astype(np.int32)]), 8, 31)
+    rids = [srv.submit(p, n, seed=s) for p, n, s in (short, again)]
+    srv.step()              # short seated in front; again behind the step
+    req = srv._prefill[0]
+    assert req.rid == rids[1] and srv._ahead is not None
+    admit = [e for e in srv.spans.events() if e.kind == "srv.admit"][-1]
+    assert admit.meta["ahead"] == 1
+    if tiered:
+        assert req.page_alloc.restored == 4
+        assert srv.hostkv.snapshot()["restores"] == 1
+    else:
+        assert req.page_alloc.shared == 1 == req.page_alloc.hydrate_pages
+    _check_parity(model, params, [short, again], finish(rids))
+
+
+def test_lane_work_behind_the_step_is_not_the_steps_time(setup):
+    """A tiered restore admitted behind the step that keeps the HOST busy
+    far longer than the watchdog allows a decode step: the step's time
+    (``_last_step_s``: the watchdog, the anomaly detector, goodput's
+    ``decode_s``) is its dispatch and read-back, not the lane's work that
+    stood between them, so no stall is raised."""
+    from _fake_clock import TickClock
+
+    cfg, model, params, eng = setup
+    clock = TickClock()
+    srv = ServingEngine(eng, {
+        "slots": 3, "max_len": M, "prefill_chunk": 16, "page_size": 8,
+        "pool_pages": 11, "host_pool_bytes": 8 << 20, "greedy": True,
+        "spans": True, "watchdog_s": 0.5}, clock=clock)
+    rng = np.random.default_rng(16)
+    old = [rng.integers(0, 256, (32,)).astype(np.int32) for _ in range(4)]
+    for p in old:                   # the first prompt's blocks go to the host
+        srv.serve_batch([p], 8)
+    assert srv.hostkv.snapshot()["pages"] >= 4
+    restore = srv._restore_dispatch
+
+    def slow(cache, alloc):
+        clock.advance(5.0)
+        return restore(cache, alloc)
+
+    srv._restore_dispatch = slow
+    srv.submit(rng.integers(0, 256, (9,)).astype(np.int32), 16)
+    srv.submit(old[0], 8)
+    srv.step()              # the first seated; the restore behind the step
+    admit = [e for e in srv.spans.events() if e.kind == "srv.admit"][-1]
+    assert admit.meta["ahead"] == 1 and admit.t1 - admit.t0 > 5.0
+    assert srv._prefill[0].page_alloc.restored == 4
+    step = [e for e in srv.spans.events() if e.kind == "decode_step"][-1]
+    assert step.t1 - step.t0 > 5.0          # the span holds its children
+    assert srv._last_step_s < 0.1
+    srv.drain()
+    assert srv.metrics_snapshot()["watchdog_stalls"] == 0
+
+
+def test_one_prefill_cache_at_a_time(setup):
+    """An admission behind a step that follows a seat does not take its
+    batch-1 cache while the seated one still waits for its insert: the
+    insert has run and its cache's buffers are gone before ``init_cache``
+    is dispatched. Where nothing is admitted behind the step the seated
+    cache is let go with the iteration."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 4, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20})
+    seated, alive = [], []
+    prog = srv._prog
+
+    def spy(key, build):
+        fn = prog(key, build)
+
+        def call(*args):
+            if key == "insert":
+                seated.append(args[2].cache)
+            else:
+                alive.append([not x.is_deleted() for c in seated
+                              for x in jax.tree_util.tree_leaves(c)])
+            return fn(*args)
+        return call if key in ("insert", "init_cache") else fn
+
+    srv._prog = spy
+    rng = np.random.default_rng(17)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), 10, 40 + i)
+            for i, P in enumerate([5, 7, 6, 4])]
+    rids = [srv.submit(p, n, seed=s) for p, n, s in reqs]
+    srv.step()
+    assert srv._seated is None and srv._ahead is not None
+    srv.drain()
+    assert len(seated) == 4 and [len(a) > 0 for a in alive] == [
+        False, True, True, True]
+    assert not any(x for a in alive for x in a)
+    assert srv._seated is None
+    _check_parity(model, params, reqs,
+                  [srv.pop_result(r).tokens for r in rids])
+
+
+@pytest.mark.parametrize("seed", [0, 2 ** 31 - 1, 2 ** 31 + 5,
+                                  4380000011, -3])
+def test_lane_key_is_the_eager_key(setup, seed):
+    """The request's key comes out of the admission's one program
+    (``init_cache``) and is ``per_request_keys([seed])`` bit for bit, at
+    seeds beyond 32 bits and below 0 too: what solo ``generate()`` folds."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 2, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20})
+    srv.submit(np.arange(1, 20, dtype=np.int32), 4, seed=seed)
+    srv.step()                       # chunk 0 of 3: the lane holds the key
+    key = srv._prefill[4]
+    want = per_request_keys([seed])
+    assert key.dtype == want.dtype and key.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(key), np.asarray(want))
+
+
+def test_lane_programs_take_no_device_scalars(setup):
+    """What the lane hands its programs beside params, caches and the
+    request's key — token ids, positions, the slot, page rows, restored
+    tiles — goes in as numpy: an argument of the program, never a device
+    program of its own in front of it (``jnp.int32(x)`` is a
+    ``convert_element_type`` dispatch)."""
+    cfg, model, params, eng = setup
+    for extra in ({}, {"page_size": 8, "pool_pages": 12,
+                       "host_pool_bytes": 8 << 20}):
+        srv = ServingEngine(eng, {"slots": 2, "max_len": M,
+                                  "prefill_chunk": 8, "greedy": True,
+                                  **extra})
+        seen = set()
+        prog = srv._prog
+
+        def spy(key, build):
+            fn = prog(key, build)
+            name = key[0] if isinstance(key, tuple) else key
+
+            def call(*args):
+                seen.add(name)
+                for i, a in enumerate(args):
+                    # params, caches and carries are containers; a bare
+                    # array is the key (uint32) or came from the host
+                    assert not isinstance(a, jax.Array) \
+                        or a.dtype == np.uint32, (name, i, a)
+                    if name == "restore" and isinstance(a, dict):
+                        assert all(isinstance(v, np.ndarray)
+                                   for v in a.values()), (name, i)
+                return fn(*args)
+            return call if name in ("chunk", "final", "insert", "hydrate",
+                                    "restore") else fn
+
+        srv._prog = spy
+        rng = np.random.default_rng(14)
+        shared = rng.integers(0, 256, (16,)).astype(np.int32)
+        for r in range(3):          # the shared prefix hydrates; 12 pages
+            srv.serve_batch(        # for two requests evict and restore
+                [np.concatenate([shared, rng.integers(0, 256, (n,)).astype(
+                    np.int32)]) for n in (5, 9)], 4, seeds=[1, 2])
+            srv.serve_batch([rng.integers(0, 256, (30,)).astype(np.int32)
+                             for _ in range(2)], 4, seeds=[3, 4])
+        assert seen == {"chunk", "final", "insert"} | (
+            {"hydrate", "restore"} if extra else set())
+
+
 def test_slot_reuse_no_stale_kv(setup):
     """One slot, sequential requests: the second and third requests reuse
     the retired slot and must still match their solo runs — and the insert
