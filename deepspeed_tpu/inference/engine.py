@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability import spans as _spans
 from ..platform.mesh import MeshSpec, build_mesh, fit_specs
 from ..utils.logging import log_dist
 from .config import InferenceConfig
@@ -51,6 +52,7 @@ def model_with_dtype(model, dtype):
 class InferenceEngine:
     """Owns sharded params + compiled prefill/decode/generate."""
 
+    @_spans.timed_init("inference")
     def __init__(self, model, params, config: InferenceConfig | dict | None = None,
                  mesh: Optional[Mesh] = None):
         self.config = InferenceConfig.from_any(config)

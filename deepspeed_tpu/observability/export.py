@@ -47,6 +47,8 @@ from .sinks import JsonlSink
 # pids in the exported trace: one "process" per engine kind.
 PID_SERVING = 1
 PID_TRAIN = 2
+PID_PROCESS = 3     # the process's lifecycle (spans.lifecycle()): one track,
+                    # an engine's build with its programs' stages nested
 
 # merged fleet traces: the router/handoff ring fronts the trace, replicas
 # follow in fleet order (pid 10 + i, each named after its replica).
@@ -102,7 +104,8 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
     if origin is None:
         origin = min(e.t0 for e in evs)
     out: list[dict] = []
-    used_tids: dict[int, set] = {PID_SERVING: set(), PID_TRAIN: set()}
+    used_tids: dict[int, set] = {PID_SERVING: set(), PID_TRAIN: set(),
+                                 PID_PROCESS: set()}
     train_tids = dict(_TRAIN_TIDS)
     session_tids: dict[str, int] = {}    # residency tracks, first-seen
 
@@ -189,6 +192,13 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
         elif e.kind == S.COMM_EXPOSED:
             add(PID_TRAIN, _TID_COMM_EXPOSED, "X", "exposed", ts,
                 dur or 0.0, args)
+        elif e.kind == S.COMPILE:
+            add(PID_PROCESS, 1, "X",
+                f"{e.meta.get('stage')}:{e.meta.get('program')}", ts,
+                dur or 0.0, args)
+        elif e.kind == S.INIT:
+            add(PID_PROCESS, 1, "X", f"init.{e.meta.get('phase')}", ts,
+                dur or 0.0, args)
         else:   # unknown kind: keep it visible rather than dropping it
             add(PID_SERVING, _TID_MARKERS, "i", f"event:{e.kind}", ts,
                 None, args)
@@ -226,6 +236,9 @@ def to_chrome_trace(events: Iterable[S.SpanEvent],
                         (_TID_COMM_EXPOSED, "comm-exposed")):
             if tid in used_tids[PID_TRAIN]:
                 thread_meta(PID_TRAIN, tid, nm)
+    if used_tids[PID_PROCESS]:
+        name_meta(PID_PROCESS, f"{job_name}:process")
+        thread_meta(PID_PROCESS, 1, "lifecycle")
     return {"traceEvents": meta + out, "displayTimeUnit": "ms",
             "otherData": {"job": job_name}}
 

@@ -28,17 +28,34 @@ set ``spans: true``, and, while a ``jax.profiler`` capture is live, both a
 timeline, beside the device's) and the process's capture ring, which
 :func:`captured` hands out afterwards. With neither, a site costs one
 ``TraceAnnotation.is_enabled()``.
+
+Three kinds are the process's and not an iteration's: ``COMPILE`` (a
+program traced, lowered, or compiled or loaded by the backend; made here
+from ``jax.monitoring``'s own events, which carry the function's name),
+``INIT`` (an engine built, the package imported) and ``RETRACE``. By
+construction none occurs on a steady hot path (a compile inside a
+measured window already makes the run invalid), so they are ALWAYS
+recorded, into one bounded process-level ring that :func:`lifecycle`
+hands out, whatever ``ring`` and the capture say (and into those too).
+That ring has one clock, ``time.perf_counter``, whatever clock an owner
+fakes: what a replica's cold start was made of is read from it
+(docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 import threading
 import time
 from collections import deque
 from typing import Callable, Optional
 
+from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation
+
+from .metrics import get_registry
 
 # ------------------------------------------------------------- event kinds
 # Serving request lifecycle (rid-carrying):
@@ -98,13 +115,25 @@ COMM_EXPOSED = "comm_exposed"      # span: an exposed gap — collective
                                    # time NOT hidden behind compute
 RETRACE = "retrace"                # instant: a built serving program met a
                                    # new argument signature (meta:
-                                   # program, signatures). Not a MARKER:
-                                   # warm-up has them and is no incident
+                                   # program, signatures, module, why).
+                                   # Not a MARKER: warm-up has them and
+                                   # is no incident
+# The process's lifecycle (always recorded, see ``lifecycle``):
+COMPILE = "compile"                # span: one stage of one program's way
+                                   # to an executable (meta: program =
+                                   # the module's name, ``jit__step_impl``;
+                                   # stage = trace | lower | backend; on a
+                                   # backend span cache_hit, retrieval_s)
+INIT = "init"                      # span: the package imported, an engine
+                                   # built (meta: phase = import |
+                                   # inference | serving | train |
+                                   # compile_train_step)
 # Cross-cutting:
 MARKER = "marker"                  # instant: SLO burn, anomaly, watchdog,
                                    # compile storm — the "why" of a dump
 
 _COUNTER_KINDS = frozenset({OCCUPANCY})
+_LIFECYCLE_KINDS = frozenset({COMPILE, INIT, RETRACE})
 _INSTANT_KINDS = frozenset({PLACED, RETIRED, MARKER, ROUTE, REQUEUE})
 
 
@@ -235,6 +264,29 @@ def _capturing() -> bool:
     return live
 
 
+# The process's lifecycle: every COMPILE, INIT and RETRACE since the package
+# was imported, spans on or off, capture or none. A GPT-2 serving set-up
+# leaves some 120 events here and the widest cell some 330; a process that
+# compiles on without end keeps the newest 4096.
+_LIFECYCLE = SpanRecorder(capacity=4096)
+
+
+def now() -> float:
+    """The lifecycle ring's clock: ``time.perf_counter`` unless a test set
+    another on the ring. Every site that stamps a lifecycle kind reads
+    this one, whatever clock its engine was given."""
+    return _LIFECYCLE.clock()
+
+
+def lifecycle() -> list[SpanEvent]:
+    """Every ``COMPILE``, ``INIT`` and ``RETRACE`` of this process, in the
+    order they closed, on ``time.perf_counter``: what was traced, lowered,
+    compiled or loaded under which name, and which engine's build it fell
+    into (by time: a ``COMPILE`` span lies inside the ``INIT`` span that
+    caused it)."""
+    return _LIFECYCLE.events()
+
+
 def captured() -> list[SpanEvent]:
     """What the seam recorded during the newest profiler capture, in the
     order the spans closed. A capture that starts and stops between two
@@ -246,8 +298,9 @@ def emit(ring: Optional[SpanRecorder], kind: str, t0: float,
          t1: Optional[float] = None, **fields) -> None:
     """A span from stamps the caller already holds (a request's lifecycle,
     the watchdog's window), or an instant or counter: to ``ring`` if there
-    is one, and to the capture ring while a capture is live. No
-    annotation: one cannot be opened in the past."""
+    is one, to the capture ring while a capture is live, and a lifecycle
+    kind to the lifecycle ring always. No annotation: one cannot be opened
+    in the past."""
     _record(ring, _capturing(), kind, t0, t1, fields)
 
 
@@ -259,6 +312,11 @@ def _record(ring, live, kind, t0, t1, fields) -> Optional[SpanEvent]:
             _CAPTURE.append(ev)
     elif live:
         ev = _CAPTURE.emit(kind, t0, t1, **fields)
+    if kind in _LIFECYCLE_KINDS:
+        if ev is None:
+            ev = _LIFECYCLE.emit(kind, t0, t1, **fields)
+        else:
+            _LIFECYCLE.append(ev)
     return ev
 
 
@@ -266,9 +324,10 @@ def instant(ring: Optional[SpanRecorder], clock: Callable[[], float],
             kind: str, **fields) -> None:
     """An instant or a counter sample (OCCUPANCY, RETRACE) stamped now.
     The clock is read only when something records: an engine on a
-    counting test clock keeps its stamps with spans off."""
+    counting test clock keeps its stamps with spans off. A lifecycle kind
+    always records; its site passes :func:`now`."""
     live = _capturing()
-    if ring is not None or live:
+    if ring is not None or live or kind in _LIFECYCLE_KINDS:
         _record(ring, live, kind, clock(), None, fields)
 
 
@@ -337,10 +396,160 @@ def span(ring: Optional[SpanRecorder], clock: Callable[[], float],
     capture is live wraps the block in ``TraceAnnotation("ds.<name>")``
     (``name`` defaults to ``kind``). ``fields`` are ``rid`` / ``slot`` /
     ``step`` and meta. With no ring and no capture it returns a shared
-    no-op."""
+    no-op, unless ``kind`` is a lifecycle kind."""
     live = _capturing()
-    if ring is None and not live:
+    if ring is None and not live and kind not in _LIFECYCLE_KINDS:
         return _OFF
     return _Span(ring, clock, kind,
                  TraceAnnotation("ds." + (name or kind)) if live else None,
                  fields)
+
+
+# ------------------------------------------------------- the process's life
+def timed_init(phase: str):
+    """Decorator for an engine's ``__init__`` (or any build that happens
+    once): the call is an ``INIT`` span ``phase`` in the lifecycle ring,
+    annotated ``ds.init.<phase>`` in a live capture. Stamped with the
+    ring's clock (:func:`now`) whatever clock the engine is given, as the
+    ``COMPILE`` spans that fall inside it are."""
+    def wrap(build):
+        @functools.wraps(build)
+        def timed(*args, **kwargs):
+            with span(None, now, INIT, name="init." + phase, phase=phase):
+                return build(*args, **kwargs)
+        return timed
+    return wrap
+
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_STAGES = {_TRACE: "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "backend"}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_NOT_IN_A_MODULE_NAME = re.compile(r"[^\w.-]")
+_traces = 0                        # trace events of the process (traces())
+
+
+class _Open(threading.local):
+    """Per thread: how many stage events are open, and what the
+    persistent cache said inside the backend event that is."""
+    depth = 0
+    cache: dict = {}
+
+
+_open = _Open()
+
+
+def traces() -> int:
+    """How many functions this process has traced for a ``jax.jit`` so far.
+    Every new signature of a built program starts with one, so a reader of
+    the programs' own caches (``ServingEngine._count_retraces``) need look
+    only when this has moved."""
+    return _traces
+
+
+def module_name(fun_name: str, stage: str) -> str:
+    """One spelling for a program in every stage: the compiled module's,
+    as the device trace prints it and ``benchmark/reduce.py``'s program
+    patterns match it (``jit__step_impl``). JAX names the trace by the
+    function (``_step_impl``) and the lowering and the backend by
+    ``jit(_step_impl)``, which it cleans the same way for the module."""
+    if stage == "trace":
+        fun_name = f"jit({fun_name})"
+    return _NOT_IN_A_MODULE_NAME.sub("_", fun_name).rstrip("_")
+
+
+def _stage_opens(event: str, *_, **__) -> None:
+    if event in _STAGES:
+        _open.depth += 1
+        if event == _TRACE:
+            global _traces
+            _traces += 1
+
+
+def _cache_said(event: str, **_) -> None:
+    if event == _CACHE_HIT or event == _CACHE_MISS:
+        _open.cache = dict(_open.cache, cache_hit=event == _CACHE_HIT)
+        if event == _CACHE_MISS:
+            get_registry().counter("Compile/cache_misses").inc()
+
+
+def _cache_read_took(event: str, secs: float, **_) -> None:
+    if event == _CACHE_READ:
+        _open.cache = dict(_open.cache, retrieval_s=float(secs))
+
+
+def _stage_closes(event: str, start: float, end: float, *,
+                  fun_name: str = "?", **_) -> None:
+    """A ``COMPILE`` span from one of JAX's own stage events. **The
+    clock:** JAX stamps ``start`` and ``end`` with the wall clock
+    (``time.time``); the seam, the engines and a benchmark's process start
+    are on ``time.perf_counter``. This callback runs as the stage closes,
+    so the span ends at the ring's clock read here (:func:`now`) and starts
+    ``end - start`` before it: no offset between the two clocks is kept,
+    and a wall clock that is stepped meanwhile moves nothing
+    (``test_compile_spans_lie_on_perf_counter`` holds a span inside a
+    ``perf_counter`` bracket around its call). A trace or a lowering that
+    happens inside another stage (the ``jnp`` functions a traced function
+    calls are traced for a ``jit`` of their own) is part of that stage's
+    time and leaves no span; a backend event always does."""
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    _open.depth = max(0, _open.depth - 1)
+    if stage != "backend" and _open.depth:
+        return
+    t1 = now()
+    seconds = float(end) - float(start)
+    meta = {}
+    reg = get_registry()
+    if stage == "backend":
+        meta, _open.cache = _open.cache, {}
+        reg.counter("Compile/programs").inc()
+        reg.counter("Compile/backend_s").inc(seconds)
+    else:
+        reg.counter("Compile/trace_lower_s").inc(seconds)
+    emit(None, COMPILE, t1 - seconds, t1,
+         program=module_name(fun_name, stage), stage=stage, **meta)
+
+
+def compiled_since(t: float, program: str) -> str:
+    """What the stages after a trace did for ``program`` since ``t``, in
+    words, for a ``RETRACE``'s ``why``: JAX 0.9 says which argument made a
+    signature new only through ``jax_explain_cache_misses``' log, which
+    costs at every miss; what the new signature COST is here for nothing.
+    A trace alone means the types were known and only the argument's
+    placement (committed-ness, sharding, a numpy array for a device one)
+    was new to the call's fast path."""
+    mine = [e for e in _LIFECYCLE.events() if e.kind == COMPILE
+            and e.t0 >= t and e.meta.get("program") == program]
+    if not mine:
+        return "no stage event under this name"
+    said = []
+    for e in mine:
+        part = f"{e.meta['stage']} {e.duration:.3f} s"
+        if "cache_hit" in e.meta:
+            part += " (loaded from the cache)" if e.meta["cache_hit"] \
+                else " (compiled: a cache miss)"
+        said.append(part)
+    if all(e.meta["stage"] == "trace" for e in mine):
+        said.append("no lowering: an executable it had")
+    return ", ".join(said)
+
+
+def _listen() -> None:
+    """Once, when this module is first imported (the package imports it):
+    JAX has no way to ask whether a listener is registered, and a second
+    set would double every span."""
+    _monitoring.register_scalar_listener(_stage_opens)
+    _monitoring.register_event_listener(_cache_said)
+    _monitoring.register_event_duration_secs_listener(_cache_read_took)
+    _monitoring.register_event_time_span_listener(_stage_closes)
+    for name in ("Compile/programs", "Compile/cache_misses",
+                 "Compile/trace_lower_s", "Compile/backend_s"):
+        get_registry().counter(name)     # readable at 0: none, not unkept
+
+
+_listen()

@@ -105,6 +105,7 @@ def _remat_policy(cfg: Config):
 class Engine:
     """Owns mesh, sharded state, and the compiled train/eval steps."""
 
+    @_spans.timed_init("train")
     def __init__(self, config: Config | dict | str | None, model,
                  mesh: Optional[Mesh] = None, seed: Optional[int] = None,
                  params=None, abstract_state: bool = False):
@@ -1407,6 +1408,7 @@ class Engine:
                                     params, iters=4)
         return float(eig)
 
+    @_spans.timed_init("compile_train_step")
     def _compiled_step(self, batch: dict):
         """AOT-lower/compile the step program that ``train_batch`` would
         run for this batch's shapes, WITHOUT executing it — nothing

@@ -111,6 +111,7 @@ class ServingEngine:
     time in tests.
     """
 
+    @_spans.timed_init("serving")
     def __init__(self, engine: InferenceEngine,
                  serving: ServingConfig | dict | None = None,
                  registry=None, clock=None, programs=None, rid_source=None,
@@ -531,7 +532,11 @@ class ServingEngine:
         # return value is ignored. None (default) costs one `is not
         # None` per placement.
         self.on_placed = None
-        self.compiles = 0        # program builds — bounded in steady state
+        # ``_prog`` builds: ``jax.jit`` wrappers made, bounded in steady
+        # state. Not compilations: a wrapper compiles once per argument
+        # signature it meets (Serve/retraces counts those beyond the first;
+        # the COMPILE spans name every one)
+        self.compiles = 0
         # finished requests awaiting pickup, BOUNDED (oldest evicted): a
         # server whose caller consumes step()'s return values — or
         # pop_result() — never grows this; one that ignores results still
@@ -558,6 +563,10 @@ class ServingEngine:
         self._last_step_s = 0.0
         self._last_stall_iter: Optional[int] = None
         self._iterations = 0
+        # _count_retraces: the process's trace count at its last walk of
+        # the programs, and when that was
+        self._traces_seen = -1
+        self._retraces_looked = _spans.now()
         # readable process-wide at 0 too (see _count_retraces; the second
         # counts in forward_with_cache, where a step program is traced)
         get_registry().counter("Serve/retraces")
@@ -650,8 +659,9 @@ class ServingEngine:
 
     # ----------------------------------------------------------- programs
     def _prog(self, key, build):
-        """InferenceEngine._cached's bounded LRU + a compile counter
-        (every build is one XLA compilation — the smoke test asserts the
+        """InferenceEngine._cached's bounded LRU + a build counter (one
+        ``jax.jit`` wrapper made per key; what it compiles is per argument
+        signature, see ``_count_retraces`` — the smoke test asserts the
         count freezes after warmup)."""
         def counted():
             self.compiles += 1
@@ -665,10 +675,18 @@ class ServingEngine:
         for beyond its first. ``compiles`` counts ``_prog`` builds; a
         ``jax.jit`` handed an argument of another type, layout or
         placement traces and compiles again inside the same build, and
-        only its own cache shows that. Read at the end of every
-        iteration, counted always (warm-up is where retraces happen), in
-        this engine's registry and the process-wide one; a RETRACE
-        instant names the program when a ring records."""
+        only its own cache shows that. Counted always (warm-up is where
+        retraces happen), in this engine's registry and the process-wide
+        one, and a RETRACE instant in the lifecycle ring names the program
+        and says what the new signature cost (``why``). Every new
+        signature starts with a trace, so the programs are walked only at
+        the end of an iteration in which the process traced something
+        (``_spans.traces()``); steady state pays one comparison."""
+        traced = _spans.traces()
+        if traced == self._traces_seen:
+            return
+        self._traces_seen = traced
+        looked, self._retraces_looked = self._retraces_looked, _spans.now()
         for key, fn in self._programs.items():
             n = fn._cache_size()
             seen = _SIGNATURES.get(fn, 1)     # the first is the build's
@@ -677,9 +695,12 @@ class ServingEngine:
             _SIGNATURES[fn] = n
             for reg in (self.stats.registry, get_registry()):
                 reg.counter("Serve/retraces").inc(n - seen)
-            _spans.instant(self.spans, self.stats.clock, _spans.RETRACE,
-                           program=str(key), signatures=n,
-                           step=self._iterations)
+            module = _spans.module_name(
+                getattr(fn, "__name__", "?"), "trace")
+            _spans.instant(self.spans, _spans.now, _spans.RETRACE,
+                           program=str(key), signatures=n, new=n - seen,
+                           step=self._iterations, module=module,
+                           why=_spans.compiled_since(looked, module))
 
     def _chunk_impl(self, params, cache, ids, start):
         """Intermediate prefill chunk: extend the request cache; the head

@@ -841,11 +841,19 @@ def test_serving_spans_add_no_programs_and_keep_outputs():
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 256, (9,)).astype(np.int32)
                for _ in range(4)]
+    from deepspeed_tpu.observability import get_registry
+
+    backend = get_registry().counter("Compile/programs")
+    b0 = backend.value
     plain = ds.ServingEngine(eng, scfg)
     base = plain.serve_batch(prompts, 6, seeds=list(range(4)))
+    b1 = backend.value
     spanned = ds.ServingEngine(eng, {**scfg, "spans": True})
     got = spanned.serve_batch(prompts, 6, seeds=list(range(4)))
     assert spanned.compiles == plain.compiles      # zero new programs
+    # nor executables: JAX's own backend events, which the lifecycle ring
+    # counts whether or not an engine has a ring of its own
+    assert backend.value - b1 == b1 - b0 > 0
     for w, g in zip(base, got):
         np.testing.assert_array_equal(w, g)        # bit-identical tokens
     kinds = {e.kind for e in spanned.spans.events()}
@@ -863,13 +871,17 @@ def test_serving_spans_add_no_programs_and_keep_outputs():
     # build no program
     assert plain.spans is None and not spans_mod.TraceAnnotation.is_enabled()
     held = len(spans_mod.captured())
-    programs = plain.compiles
+    life, traced = spans_mod._LIFECYCLE.emitted, spans_mod.traces()
+    programs, executables = plain.compiles, backend.value
     for p in prompts:
         plain.submit(p, 6)
     for _ in range(20):
         plain.step()
     assert len(spans_mod.captured()) == held
-    assert plain.compiles == programs
+    assert plain.compiles == programs and backend.value == executables
+    # the lifecycle ring is always on and has nothing to say of a warm loop
+    assert spans_mod._LIFECYCLE.emitted == life
+    assert spans_mod.traces() == traced
 
 
 @pytest.fixture(scope="module")
@@ -1063,6 +1075,202 @@ def test_retraces_counts_new_signatures_of_a_built_program(tiny_server,
     assert mine == counted
     marks = [e.meta for e in srv.spans.events() if e.kind == "retrace"]
     assert [m["program"] for m in marks] == ["probe"] * counted
+
+
+# ------------------------------------------------- the lifecycle ring
+def _compiles_of(program):
+    return [e for e in spans_mod.lifecycle()
+            if e.kind == "compile" and e.meta["program"] == program]
+
+
+def _life_mark():
+    """Events the lifecycle ring has ever taken: its length stops growing
+    once a long test process has filled it."""
+    return spans_mod._LIFECYCLE.emitted
+
+
+def _life_since(mark):
+    n = _life_mark() - mark
+    return spans_mod.lifecycle()[-n:] if n else []
+
+
+def test_compile_spans_lie_on_perf_counter():
+    """A fresh ``jax.jit`` leaves its trace, its lowering and its backend
+    compile in ``lifecycle()`` under the module's name, with ``spans``
+    unset and no capture; JAX stamps them with ``time.time()`` and they
+    come out on ``time.perf_counter()``, inside a bracket of it taken
+    around the call."""
+    import time
+
+    import jax.numpy as jnp
+
+    def lifecycle_probe(x):
+        return jnp.tanh(x) * 3 + 1      # jnp.tanh: a trace inside the trace
+
+    assert not spans_mod.TraceAnnotation.is_enabled()
+    x = jnp.ones(7)
+    reg = ds.observability.get_registry().snapshot()["counters"]
+    traced = spans_mod.traces()
+    a = time.perf_counter()
+    jax.jit(lifecycle_probe)(x)
+    b = time.perf_counter()
+    mine = _compiles_of("jit_lifecycle_probe")
+    # one span a stage: what the function traces for its own callees is
+    # inside its trace, not beside it
+    assert [e.meta["stage"] for e in mine] == ["trace", "lower", "backend"]
+    assert spans_mod.traces() > traced
+    stamps = [t for e in mine for t in (e.t0, e.t1)]
+    assert stamps == sorted(stamps) and a <= stamps[0] and stamps[-1] <= b
+    now = ds.observability.get_registry().snapshot()["counters"]
+    assert now["Compile/programs"] - reg["Compile/programs"] == 1
+    lowered = sum(e.duration for e in mine[:2])
+    assert now["Compile/trace_lower_s"] - reg["Compile/trace_lower_s"] \
+        == pytest.approx(lowered)
+    assert now["Compile/backend_s"] - reg["Compile/backend_s"] \
+        == pytest.approx(mine[2].duration)
+    # the same call again is no event
+    held = _life_mark()
+    jax.jit(lifecycle_probe)(x)
+    assert _life_mark() == held
+
+
+@pytest.mark.parametrize("said, meta", [
+    ("hit", {"cache_hit": True, "retrieval_s": 0.25}),
+    ("miss", {"cache_hit": False}),
+    ("nothing", {})])
+def test_what_the_cache_said_lands_on_the_backend_span(said, meta):
+    """A hit, a miss and the retrieval's seconds fire inside a backend
+    event with no name of their own: they go onto that span, and a miss
+    onto ``Compile/cache_misses``."""
+    import time
+
+    from jax import monitoring
+
+    backend = "/jax/core/compile/backend_compile_duration"
+    misses = ds.observability.get_registry().counter("Compile/cache_misses")
+    m0 = misses.value
+    t = time.time()
+    monitoring.record_scalar(backend, t, fun_name="jit(cache_probe)")
+    if said == "hit":
+        monitoring.record_event("/jax/compilation_cache/cache_hits")
+        monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+    elif said == "miss":
+        monitoring.record_event("/jax/compilation_cache/cache_misses")
+    monitoring.record_event_time_span(backend, t, t + 0.5,
+                                      fun_name="jit(cache_probe)")
+    ev = _compiles_of("jit_cache_probe")[-1]
+    assert ev.meta == {"program": "jit_cache_probe", "stage": "backend",
+                       **meta}
+    assert ev.duration == pytest.approx(0.5)
+    assert misses.value - m0 == (said == "miss")
+
+
+def test_a_retrace_is_in_the_lifecycle_ring_and_a_warm_loop_walks_nothing(
+        tiny_server):
+    """With ``spans`` unset a second signature of a built program leaves a
+    RETRACE in ``lifecycle()`` that says what it cost, and moves
+    ``Serve/retraces`` by one. After that, 50 iterations with work in them
+    append nothing and never look at a program's cache: the walk waits for
+    the process's trace count to move."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.observability import get_registry
+
+    eng, prompts = tiny_server
+    srv = ds.ServingEngine(eng, {"slots": 2, "max_len": 48,
+                                 "prefill_chunk": 16})
+    assert srv.spans is None
+    for _ in range(2):                   # every program and signature met
+        srv.serve_batch(prompts, 4, seeds=[1, 2, 3, 4])
+    counter = get_registry().counter("Serve/retraces")
+    before = counter.value
+
+    def retrace_probe(x):
+        return x + 1
+
+    prog = srv._prog("probe", lambda: jax.jit(retrace_probe))
+    prog(jnp.zeros(3, jnp.float32))
+    srv.step()
+    prog(jnp.zeros(3, jnp.int32))
+    srv.step()
+    assert counter.value - before == 1
+    (mark,) = [e for e in spans_mod.lifecycle() if e.kind == "retrace"
+               and e.meta["module"] == "jit_retrace_probe"]
+    assert mark.meta["program"] == "probe" and mark.meta["new"] == 1
+    assert mark.meta["signatures"] == 2
+    # another type: traced, lowered and compiled again, each with seconds
+    assert [w.split()[0] for w in mark.meta["why"].split(", ")] \
+        == ["trace", "lower", "backend"]
+
+    class Watched:
+        looked = 0
+
+        def _cache_size(self):
+            Watched.looked += 1
+            return 1
+
+    srv._programs["watched"] = Watched()
+    held, traced = _life_mark(), spans_mod.traces()
+    for p in prompts:
+        srv.submit(p, 12)
+    for _ in range(50):
+        srv.step()
+    assert srv.sched.idle
+    assert spans_mod.traces() == traced
+    assert _life_mark() == held and Watched.looked == 0
+    # a trace anywhere in the process and the next iteration looks again
+    jax.jit(lambda x: x - 1)(jnp.zeros(2))
+    srv.step()
+    assert Watched.looked == 1
+
+
+def test_an_init_span_covers_the_compiles_of_its_engine_s_build(tiny_server):
+    """``ServingEngine.__init__`` is an INIT span of phase ``serving``, and
+    the ``init_slots`` program it builds lies inside it by time, on the one
+    clock both are stamped with, fake engine clock or not."""
+    eng, _ = tiny_server
+    ticks = iter(range(10 ** 6))
+    held = _life_mark()
+    ds.ServingEngine(eng, {"slots": 5, "max_len": 40, "prefill_chunk": 8},
+                     clock=lambda: float(next(ticks)))
+    new = _life_since(held)
+    (init,) = [e for e in new if e.kind == "init"]
+    assert init.meta == {"phase": "serving"} and init.duration > 0
+    inside = [e for e in new if e.kind == "compile"]
+    assert {e.meta["stage"] for e in inside} == {"trace", "lower", "backend"}
+    assert all(init.t0 <= e.t0 and e.t1 <= init.t1 for e in inside)
+    assert next(ticks) == 0              # the engine's clock was not read
+    # an operator reads it through the exporter every ring goes through:
+    # the build and, nested in it, its programs' stages, on one track
+    trace = to_chrome_trace(new)
+    assert validate_chrome_trace(trace) == []
+    track = [(e["name"], e["ts"], e["ts"] + e["dur"])
+             for e in trace["traceEvents"] if e.get("ph") == "X"]
+    assert track[0][0] == "init.serving"
+    assert {n.split(":")[0] for n, _, _ in track[1:]} \
+        == {"trace", "lower", "backend"}
+    # Perfetto's microseconds are rounded to a thousandth
+    assert all(track[0][1] <= a and b <= track[0][2] + 2e-3
+               for _, a, b in track[1:])
+    # and an inference engine's build is one of phase ``inference``
+    held = _life_mark()
+    ds.init_inference(eng.model, eng.model.init(jax.random.PRNGKey(1)),
+                      {"dtype": "float32"})
+    assert [e.meta["phase"] for e in _life_since(held)
+            if e.kind == "init"] == ["inference"]
+
+
+def test_the_lifecycle_ring_is_bounded(monkeypatch):
+    small = SpanRecorder(capacity=8)
+    monkeypatch.setattr(spans_mod, "_LIFECYCLE", small)
+    for i in range(20):
+        spans_mod.emit(None, spans_mod.INIT, float(i), i + 0.5, phase=str(i))
+    assert [e.meta["phase"] for e in spans_mod.lifecycle()] \
+        == [str(i) for i in range(12, 20)]
+    assert small.emitted == 20 and spans_mod._LIFECYCLE.capacity == 8
+    # a kind that is not the process's stays out
+    spans_mod.emit(None, spans_mod.QUEUED, 0.0, 1.0, rid=1)
+    assert len(spans_mod.lifecycle()) == 8 and small.emitted == 20
 
 
 # ----------------------------------------------------------- doctor CLI
