@@ -890,17 +890,23 @@ class TransformerLM:
         cfg = self.cfg
         p = layer_params
         # Remat-policy anchors (reference cpu_checkpointing,
-        # activation_checkpointing/checkpointing.py:1036): under the
-        # engine's "offload_dots" policy these two names — the residual
-        # stream entering the layer and the projected attention output —
-        # are offloaded to pinned host memory during the forward and
-        # fetched back in the backward, instead of being kept in HBM
-        # (dots_saveable) or recomputed (full remat: for attn_out that
-        # means redoing the whole S^2 attention). Under any other policy
-        # checkpoint_name is an identity.
+        # activation_checkpointing/checkpointing.py:1036): the residual
+        # stream entering the layer and the attention's output, in the
+        # cheapest form that spares the backward the S^2 work. The engine's
+        # names policies keep these in HBM (save_names, save_names_mlp) or
+        # park them in pinned host memory during the forward and fetch them
+        # back in the backward (offload_dots); under any other policy
+        # checkpoint_name is an identity. An attention function that names
+        # its own residuals (names_residuals: the flash kernel's flash_o and
+        # flash_lse, ops/flash_attention.py) has said what its backward
+        # needs: the projected output stays untagged and the backward redoes
+        # one wo product from the saved o. Without that (dense, latent,
+        # ring / Ulysses, sparse) the projected attn_out is the tag:
+        # recomputing it would redo the whole S^2 attention.
         x = checkpoint_name(x, "layer_in")
         o = self._attention_block(x, p, positions, attn_mask)
-        o = checkpoint_name(o, "attn_out")
+        if not getattr(self.attention_fn, "names_residuals", False):
+            o = checkpoint_name(o, "attn_out")
         if cfg.post_ln:
             # BERT block: norms AFTER each residual; FFN input is the
             # post-attention-LN output directly

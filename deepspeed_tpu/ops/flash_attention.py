@@ -12,6 +12,10 @@ Design (standard flash attention 2, MXU-shaped):
 - backward: two kernels — dq (grid over q blocks, streams k/v) and dk/dv
   (grid over k blocks, streams q/dO), both recomputing probabilities from
   the saved logsumexp; ``delta = rowsum(dO * O)`` precomputed outside.
+- residuals: the one ``custom_vjp`` takes and returns the model's
+  (B, S, H, hd) layout and names what it saves beside q/k/v — ``flash_o``
+  as (B, S, H*hd) and ``flash_lse`` as one (B, H, S) row — so a remat
+  policy that keeps those two names never runs the forward kernel again.
 - GQA: kv heads are repeated to H with ``jnp.repeat`` *outside* the
   custom_vjp, so the head-group sum in dk/dv falls out of autodiff.
 - dtype: matmul OPERANDS stay in their storage dtype (bf16 runs the MXU
@@ -32,7 +36,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import PartitionSpec as P
 
 BIG_NEG = -2.0 ** 30
@@ -339,16 +345,25 @@ def _make_bwd_dkv_kernel(block: int, scale: float, causal: bool, masked: bool,
     return kernel
 
 
-def _bwd_call(q, k, v, o, lse, do, mask, bias, *, block: int, causal: bool,
-              interpret: bool, grad_bias: bool = False, alibi=None):
+def _row_operand(x):
+    """(B, H, S) fp32 rows (lse, delta) → the (B, H, SUBLANES, S) sublane
+    tile the backward kernels read."""
+    B, H, S = x.shape
+    return jnp.broadcast_to(x[:, :, None, :], (B, H, SUBLANES, S))
+
+
+def _bwd_call(q, k, v, lse, delta, do, mask, bias, *, block: int,
+              causal: bool, interpret: bool, grad_bias: bool = False,
+              alibi=None):
+    """q/k/v/do (B, H, S, hd); lse and delta = rowsum(dO * O) one (B, H, S)
+    row each, replicated to the sublane tile here."""
     B, H, S, hd = q.shape
     if bias is None and _use_streamed(S, hd, q.dtype.itemsize):
-        return _bwd_call_streamed(q, k, v, o, lse, do, mask, block=block,
+        return _bwd_call_streamed(q, k, v, lse, delta, do, mask, block=block,
                                   causal=causal, interpret=interpret,
                                   alibi=alibi)
     scale = 1.0 / math.sqrt(hd)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None, :], (B, H, SUBLANES, S))
+    lse, delta = _row_operand(lse), _row_operand(delta)
     grid = (B, H, S // block)
     masked, biased = mask is not None, bias is not None
     # dbias tiles are plain writes (one owner per grid step): only valid
@@ -636,14 +651,13 @@ def _make_bwd_dkv_kernel_streamed(block: int, scale: float, causal: bool,
     return kernel
 
 
-def _bwd_call_streamed(q, k, v, o, lse, do, mask, *, block: int, causal: bool,
-                       interpret: bool, alibi=None):
+def _bwd_call_streamed(q, k, v, lse, delta, do, mask, *, block: int,
+                       causal: bool, interpret: bool, alibi=None):
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, hd = q.shape
     scale = 1.0 / math.sqrt(hd)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[:, :, None, :], (B, H, SUBLANES, S))
+    lse, delta = _row_operand(lse), _row_operand(delta)
     nq = nk = S // block
     masked = mask is not None
     q_blk = pl.BlockSpec((None, None, block, hd),
@@ -701,108 +715,77 @@ def _bwd_call_streamed(q, k, v, o, lse, do, mask, *, block: int, causal: bool,
 
 
 # ------------------------------------------------------------- custom VJP
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _flash(block, causal, interpret, q, k, v):
-    o, _ = _fwd_call(q, k, v, None, None, block=block, causal=causal,
-                     interpret=interpret)
-    return o
+# What the forward rule hands the backward beside q/k/v, by the name a remat
+# policy may keep (runtime/engine.py OFFLOAD_ACTIVATION_NAMES): a trunk that
+# saves these two spares its backward the kernel's forward, the S^2 work.
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 
-def _flash_fwd(block, causal, interpret, q, k, v):
-    o, lse = _fwd_call(q, k, v, None, None, block=block, causal=causal,
-                       interpret=interpret)
-    return o, (q, k, v, o, lse)
-
-
-def _flash_bwd(block, causal, interpret, res, g):
-    q, k, v, o, lse = res
-    dq, dk, dv, _ = _bwd_call(q, k, v, o, lse, g, None, None, block=block,
-                              causal=causal, interpret=interpret)
-    return dq, dk, dv
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _flash_masked(block, causal, interpret, q, k, v, mask):
-    o, _ = _fwd_call(q, k, v, mask, None, block=block, causal=causal,
-                     interpret=interpret)
-    return o
-
-
-def _flash_masked_fwd(block, causal, interpret, q, k, v, mask):
-    o, lse = _fwd_call(q, k, v, mask, None, block=block, causal=causal,
-                       interpret=interpret)
-    return o, (q, k, v, o, lse, mask)
-
-
-def _flash_masked_bwd(block, causal, interpret, res, g):
-    q, k, v, o, lse, mask = res
-    dq, dk, dv, _ = _bwd_call(q, k, v, o, lse, g, mask, None, block=block,
-                              causal=causal, interpret=interpret)
-    return dq, dk, dv, jnp.zeros_like(mask)   # mask is {0,1} data, no grad
-
-
-_flash_masked.defvjp(_flash_masked_fwd, _flash_masked_bwd)
+def _fwd_rows(block, causal, interpret, q, k, v, bias, slopes, mask):
+    """The forward kernel on (B, S, H, hd) operands. Returns o as the
+    output projection reads it, (B, S, H*hd) with the lanes full (the
+    kernel's own (B, H, S, hd) may be stored with hd = 64 padded to 128
+    lanes), and lse as ONE (B, H, S) row of the SUBLANES equal ones the
+    kernel writes."""
+    B, S, H, hd = q.shape
+    o, lse = _fwd_call(*(x.swapaxes(1, 2) for x in (q, k, v)), mask, bias,
+                       block=block, causal=causal, interpret=interpret,
+                       alibi=slopes)
+    return o.swapaxes(1, 2).reshape(B, S, H * hd), lse[:, :, 0]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _flash_biased(block, causal, interpret, grad_bias, q, k, v, bias, mask):
-    o, _ = _fwd_call(q, k, v, mask, bias, block=block, causal=causal,
-                     interpret=interpret)
-    return o
+def _flash(block, causal, interpret, grad_bias, q, k, v, bias, slopes, mask):
+    """q/k/v (B, S, H, hd) in and o out, the layout the model holds them
+    in: the transposes to the kernels' (B, H, S, hd) are inside the rule,
+    so what it saves needs none. ``bias`` (4D), ``slopes`` ((1, H)
+    operand) and ``mask`` ((B, SUBLANES, S) operand) may each be None."""
+    o, _ = _fwd_rows(block, causal, interpret, q, k, v, bias, slopes, mask)
+    return o.reshape(q.shape)
 
 
-def _flash_biased_fwd(block, causal, interpret, grad_bias, q, k, v, bias,
-                      mask):
-    o, lse = _fwd_call(q, k, v, mask, bias, block=block, causal=causal,
-                       interpret=interpret)
-    return o, (q, k, v, o, lse, bias, mask)
+def _flash_fwd(block, causal, interpret, grad_bias, q, k, v, bias, slopes,
+               mask):
+    o, lse = _fwd_rows(block, causal, interpret, q, k, v, bias, slopes, mask)
+    if o.shape[-1] % 128 == 0:
+        # Held row-major where that fills the lanes, as the projection and
+        # delta read it. Left to itself the compiler stacked a 36-layer
+        # scan's saved o with the positions on the lanes, (L, B, D, S), and
+        # the backward copied each layer's slice back for the wo product and
+        # once more in float32 for delta: 18 ms of an 888 ms step and 80 MiB
+        # (GPT-2 774M on a v5e, PERF.md "PR 41"). A width off the lanes
+        # (1.5B's 1600) is the compiler's to lay out: it puts the positions
+        # on the lanes everywhere, and row-major there is one more copy.
+        o = with_layout_constraint(o, Layout(major_to_minor=(0, 1, 2)))
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
+    return o.reshape(q.shape), (q, k, v, o, lse, bias, slopes, mask)
 
 
-def _flash_biased_bwd(block, causal, interpret, grad_bias, res, g):
-    q, k, v, o, lse, bias, mask = res
-    dq, dk, dv, dbias = _bwd_call(q, k, v, o, lse, g, mask, bias,
-                                  block=block, causal=causal,
-                                  interpret=interpret, grad_bias=grad_bias)
-    if dbias is None:
+def _flash_bwd(block, causal, interpret, grad_bias, res, g):
+    q, k, v, o, lse, bias, slopes, mask = res
+    # o serves delta = rowsum(dO * O) alone: reduced in the layout it was
+    # saved in, and only the (B, S, H) result transposed
+    delta = jnp.sum(g.astype(jnp.float32)
+                    * o.reshape(q.shape).astype(jnp.float32), axis=-1)
+    dq, dk, dv, dbias = _bwd_call(
+        *(x.swapaxes(1, 2) for x in (q, k, v)), lse, delta.swapaxes(1, 2),
+        g.swapaxes(1, 2), mask, bias, block=block, causal=causal,
+        interpret=interpret, grad_bias=grad_bias, alibi=slopes)
+    if bias is not None and dbias is None:
         # Broadcast-shaped biases (ALiBi slopes x positions, padding
         # biases) are positional constants: a zero cotangent is correct
         # and DCE'd under jit. Learned biases must come in full-shape
         # (B, H, S, S) to get a real dbias (enforced in flash_attention).
         dbias = jnp.zeros_like(bias)
+    # slopes are deterministic positional constants and the mask is {0,1}
+    # data: zero cotangents
+    dslopes = None if slopes is None else jnp.zeros_like(slopes)
     dmask = None if mask is None else jnp.zeros_like(mask)
-    return dq, dk, dv, dbias, dmask
+    return (*(x.swapaxes(1, 2) for x in (dq, dk, dv)), dbias, dslopes, dmask)
 
 
-_flash_biased.defvjp(_flash_biased_fwd, _flash_biased_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _flash_alibi(block, causal, interpret, q, k, v, slopes, mask):
-    o, _ = _fwd_call(q, k, v, mask, None, block=block, causal=causal,
-                     interpret=interpret, alibi=slopes)
-    return o
-
-
-def _flash_alibi_fwd(block, causal, interpret, q, k, v, slopes, mask):
-    o, lse = _fwd_call(q, k, v, mask, None, block=block, causal=causal,
-                       interpret=interpret, alibi=slopes)
-    return o, (q, k, v, o, lse, slopes, mask)
-
-
-def _flash_alibi_bwd(block, causal, interpret, res, g):
-    q, k, v, o, lse, slopes, mask = res
-    dq, dk, dv, _ = _bwd_call(q, k, v, o, lse, g, mask, None, block=block,
-                              causal=causal, interpret=interpret,
-                              alibi=slopes)
-    dmask = None if mask is None else jnp.zeros_like(mask)
-    # slopes are deterministic positional constants: zero cotangent
-    return dq, dk, dv, jnp.zeros_like(slopes), dmask
-
-
-_flash_alibi.defvjp(_flash_alibi_fwd, _flash_alibi_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ------------------------------------------------------------- public API
@@ -904,7 +887,11 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
 
         if alibi_slopes is not None:
             bias = alibi_bias(alibi_slopes, S)
-        return causal_attention(q, k, v, mask=mask, causal=causal, bias=bias)
+        o = causal_attention(q, k, v, mask=mask, causal=causal, bias=bias)
+        # a trunk built on this function tags no attn_out of its own: the
+        # demoted output stands under the kernel's name for it
+        return checkpoint_name(o.reshape(B, S, H * hd),
+                               RESIDUAL_NAMES[0]).reshape(o.shape)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     KV = k.shape[2]
@@ -934,13 +921,8 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
     if KV != H:  # GQA: differentiable repeat — dk/dv group-sum via autodiff
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)
-    # (B, S, H, hd) -> (B, H, S, hd)
-    qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
-    if alibi_slopes is not None:
-        o = _flash_alibi(blk, causal, interpret, qt, kt, vt,
-                         _slopes_operand(alibi_slopes),
-                         _mask_operand(mask, S) if mask is not None else None)
-    elif bias is not None:
+    grad_bias = False
+    if bias is not None:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
         if bias.shape[:2] != (B, H):
             if bias_is_constant:
@@ -951,15 +933,10 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
                 # the round-4 review's finding #1)
                 bias = jnp.broadcast_to(bias, (B, H) + bias.shape[2:])
         grad_bias = bias.shape[:2] == (B, H)
-        o = _flash_biased(blk, causal, interpret, grad_bias, qt, kt, vt,
-                          bias, _mask_operand(mask, S) if mask is not None
-                          else None)
-    elif mask is not None:
-        o = _flash_masked(blk, causal, interpret, qt, kt, vt,
-                          _mask_operand(mask, S))
-    else:
-        o = _flash(blk, causal, interpret, qt, kt, vt)
-    return o.swapaxes(1, 2)
+    return _flash(blk, causal, interpret, grad_bias, q, k, v, bias,
+                  _slopes_operand(alibi_slopes)
+                  if alibi_slopes is not None else None,
+                  _mask_operand(mask, S) if mask is not None else None)
 
 
 def make_flash_attention(block: int = 512, interpret: Optional[bool] = None,
@@ -985,4 +962,8 @@ def make_flash_attention(block: int = 512, interpret: Optional[bool] = None,
     attn.accepts_bias = True
     attn.bias_is_constant = bias_is_constant
     attn.accepts_alibi_slopes = True  # in-kernel ramp: no (H,S,S) operand
+    # the forward rule names what it hands the backward (RESIDUAL_NAMES):
+    # the trunk leaves its projected attn_out untagged, and a names policy
+    # keeps the kernel's o and lse in its place
+    attn.names_residuals = True
     return attn

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import Config
 from ..observability import spans as _spans
 from ..observability.spans import TRAIN_PHASE, TRAIN_STEP
+from ..ops.flash_attention import RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES
 from ..platform.accelerator import get_accelerator
 from ..platform.mesh import (BATCH_AXES, MeshSpec, build_mesh, dp_world_size,
                              fit_specs)
@@ -61,13 +62,18 @@ class TrainState(NamedTuple):
 
 # Activation names the trunk tags with jax.ad_checkpoint.checkpoint_name
 # (models/transformer.py _layer, models/t5.py): the residual stream entering
-# each layer and the projected attention output. The offload policy below
-# moves exactly these to pinned host memory during the forward — the TPU
-# shape of the reference's cpu_checkpointing + contiguous_checkpointing
+# each layer and the attention's output in one of two forms, whichever the
+# trunk produced — the projected attn_out where the attention function names
+# nothing itself (dense, latent, ring / Ulysses, sparse, T5), or the flash
+# kernel's own flash_o (B, S, H*hd) and flash_lse (B, H, S), which spare the
+# backward the kernel's forward and cost it one wo product. A name that does
+# not occur in a trace costs nothing. The offload policy below moves exactly
+# these to pinned host memory during the forward — the TPU shape of the
+# reference's cpu_checkpointing + contiguous_checkpointing
 # (activation_checkpointing/checkpointing.py:1036): HBM holds ~one layer's
 # activations while host RAM holds the rest, and XLA's latency-hiding
 # scheduler overlaps the D2H/H2D streams with layer compute.
-OFFLOAD_ACTIVATION_NAMES = ("layer_in", "attn_out")
+OFFLOAD_ACTIVATION_NAMES = ("layer_in", "attn_out", *FLASH_RESIDUAL_NAMES)
 
 
 def _remat_policy(cfg: Config):
@@ -81,7 +87,9 @@ def _remat_policy(cfg: Config):
         "save_nothing": cp.nothing_saveable,
         "dots_saveable": cp.dots_saveable,
         # Save ONLY the tagged layer-boundary activations (the residual
-        # stream entering each layer + the projected attention output) and
+        # stream entering each layer + the attention's output: the flash
+        # kernel's o and lse, so the backward never runs its forward again,
+        # or the projected attn_out under any other attention) and
         # recompute everything else in the backward. Under flash attention
         # this is ~4x less saved HBM per layer than dots_saveable (which
         # keeps every projection/MLP dot output) — the policy that lets a

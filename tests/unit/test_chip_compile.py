@@ -88,6 +88,118 @@ def test_flash_attention(one_chip, width, grad):
     _compile(fn, one_chip, qkv, qkv, qkv)
 
 
+def _trunk_grad(model, policy):
+    """Gradient of a scanned trunk (x and the stacked layers) with the
+    layer body remat'd under ``policy``; None for no remat."""
+    def loss(x, layers):
+        B, S, _ = x.shape
+        y, _ = model._scan_layers(x, layers, model._positions(B, S), None,
+                                  policy)
+        return y.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1))
+
+
+def _save_names():
+    from deepspeed_tpu.config import Config
+    from deepspeed_tpu.runtime.engine import _remat_policy
+
+    return _remat_policy(Config.from_any({
+        "train_batch_size": 1,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+        "remat": {"enabled": True, "policy": "save_names"}}))
+
+
+_TILED = re.compile(r"\b(bf16|f32)\[([\d,]+)\]\{([\d,]+):T\(8,128\)")
+
+
+def _stacked(text, L, B):
+    """{(dtype, dims): bytes as stored} of the compiled text's buffers
+    shaped (L, B, ...): what a scan over L layers keeps of a micro-batch of
+    B for its backward. The tile pads the two minor-most dims in memory
+    order, to 128 and to 8 rows of 32 bits (16 of bf16)."""
+    out = {}
+    for dtype, dims, order in _TILED.findall(text):
+        dims = [int(d) for d in dims.split(",")]
+        order = [int(d) for d in order.split(",")]
+        if len(dims) < 3 or dims[:2] != [L, B] or len(order) != len(dims):
+            continue
+        item = 2 if dtype == "bf16" else 4
+        stored = list(dims)
+        stored[order[0]] += -stored[order[0]] % 128
+        stored[order[1]] += -stored[order[1]] % (32 // item)
+        n = item
+        for d in stored:
+            n *= d
+        out[dtype, tuple(dims)] = max(n, out.get((dtype, tuple(dims)), 0))
+    return out
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one-chip", "2x2-data4"])
+def test_save_names_keeps_the_flash_kernels_own_residuals(topo, chips):
+    """The gradient of a scanned trunk at GPT-2 774M width, seq 1024, under
+    save_names, the micro-batch of 16 a chip of the train cells: ONE
+    ``flash_attention_fwd`` in the compiled module (two before the kernel
+    named its residuals: the remat ran it again for o and lse), and what
+    the scan stacks for the backward is the layer's input, the kernel's o
+    as (B, S, H*hd) with no lane of padding, and lse as one (B, H, S) row —
+    not the kernel's (B, H, S, 64) padded to 128 lanes, nor its eight equal
+    sublanes of lse. Eight layers: a scan compiles one body whatever its
+    length, and eight rows fill a tile whichever way the compiler lays the
+    stack. On the 2x2 host the batch is split over ``data`` and the kernel
+    runs in a ``shard_map``: the names inside it are kept all the same."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.ops.flash_attention import make_flash_attention
+    from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
+
+    _, D, H, _, hd, _, _ = GPT2_774M
+    L, B = 8, 16
+    cfg = gpt2("774m", n_layer=L, max_seq=SEQ, dtype=jnp.bfloat16)
+    model = build_model(cfg, attention_fn=make_flash_attention(
+        interpret=False))
+    mesh = build_mesh(MeshSpec(data=chips), devices=topo.devices[:chips])
+
+    def arg(a, spec):
+        return jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    layers = jax.tree.map(
+        lambda a: arg(a, P()),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0))["layers"])
+    x = arg(jax.ShapeDtypeStruct((B * chips, SEQ, D), jnp.bfloat16),
+            P("data"))
+    with mesh:
+        text = jax.jit(_trunk_grad(model, _save_names())).lower(
+            x, layers).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    kernels = sorted(re.search(r"%(flash_attention_\w+?)[.\d]* = ", ln).group(1)
+                     for ln in calls)
+    assert kernels == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                       "flash_attention_fwd"], kernels
+    assert _stacked(text, L, B) == {
+        ("bf16", (L, B, SEQ, D)): L * B * SEQ * D * 2,   # layer_in, flash_o
+        ("f32", (L, B, H, SEQ)): L * B * H * SEQ * 4,    # flash_lse
+    }
+
+
+def test_save_names_gradients_equal_no_remat_under_flash():
+    """The interpret-mode twin of the compile above: the same gradient at
+    unit-test width, against no remat at all."""
+    from deepspeed_tpu.models import tiny_test
+    from deepspeed_tpu.ops.flash_attention import make_flash_attention
+
+    cfg = tiny_test(n_layer=3, dtype=jnp.float32)
+    model = build_model(cfg, attention_fn=make_flash_attention(block=16))
+    layers = model.init(jax.random.PRNGKey(0))["layers"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
+    want = _trunk_grad(model, None)(x, layers)
+    got = _trunk_grad(model, _save_names())(x, layers)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert jnp.allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("width", WIDTHS)
 def test_decode_attention(one_chip, width):
     _, _, H, KV, hd, _, _ = width

@@ -96,16 +96,67 @@ def test_bf16_grad_accum_matches_fp32(devices):
     assert np.isfinite(run_steps(eng, n_steps=1)[0])
 
 
+def _attention(kind):
+    if kind == "dense":
+        return None
+    from deepspeed_tpu.ops.flash_attention import make_flash_attention
+
+    return make_flash_attention(block=16)     # interpreted on the CPU
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
 @pytest.mark.parametrize("policy", ["save_names", "save_names_mlp"])
-def test_save_names_remat_policies_match_dense(devices, policy):
+def test_save_names_remat_policies_match_dense(devices, policy, attention):
     """save_names / save_names_mlp change WHAT is stored, never the math:
-    trajectory must match the no-remat baseline tightly."""
-    base = run_steps(ds.initialize(make_config(stage=1),
-                                   build_model(tiny_test())), n_steps=3)
+    trajectory must match the no-remat baseline tightly — with the dense
+    attention (the projected attn_out is kept) and with the flash kernel
+    (its own flash_o / flash_lse are, under a shard_map over the mesh)."""
+    base = run_steps(ds.initialize(
+        make_config(stage=1),
+        build_model(tiny_test(), attention_fn=_attention(attention))),
+        n_steps=3)
     got = run_steps(ds.initialize(
         make_config(stage=1, remat={"enabled": True, "policy": policy}),
-        build_model(tiny_test())), n_steps=3)
+        build_model(tiny_test(), attention_fn=_attention(attention))),
+        n_steps=3)
     np.testing.assert_allclose(got, base, rtol=1e-4)
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_save_names_keeps_what_the_attention_names(attention):
+    """What one remat'd trunk layer saves under save_names. An attention
+    function that names its residuals (the flash kernel) has its own o,
+    lane-dense (B, S, H*hd), and lse, one (B, H, S) row, kept, and the
+    projected attn_out goes untagged: the backward redoes one wo product,
+    not the kernel's forward. Any other attention keeps attn_out."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from deepspeed_tpu.config import Config
+    from deepspeed_tpu.runtime.engine import _remat_policy
+
+    cfg = tiny_test(dtype=jnp.float32)
+    model = build_model(cfg, attention_fn=_attention(attention))
+    B, S, D, H = 2, 32, cfg.d_model, cfg.n_head
+    layer = jax.tree.map(lambda a: a[0],
+                         model.init(jax.random.PRNGKey(0))["layers"])
+    policy = _remat_policy(Config.from_any(make_config(
+        remat={"enabled": True, "policy": "save_names"})))
+    body = jax.checkpoint(
+        lambda x, p: model._layer(x, p, model._positions(B, S), None)[0],
+        policy=policy, prevent_cse=False)
+    saved = [(tuple(aval.shape), why) for aval, why in saved_residuals(
+        body, jnp.ones((B, S, D), jnp.float32), layer)
+        if "from the argument" not in why]
+    shapes = sorted(shape for shape, _ in saved)
+    in_kernel = [shape for shape, why in saved if "flash_attention" in why]
+    if attention == "flash":
+        assert shapes == [(B, H, S), (B, S, D), (B, S, D)], saved
+        assert sorted(in_kernel) == [(B, H, S), (B, S, D)], saved
+        assert any("flash_lse" in why for _, why in saved), saved
+    else:
+        assert shapes == [(B, S, D), (B, S, D)] and not in_kernel, saved
 
 
 def test_tensor_parallel_trains(devices):

@@ -280,12 +280,15 @@ def test_nvme_param_offload_master_on_disk(tmp_path):
 
 
 # -------------------------------------------------- activation offload (r4)
-def test_activation_offload_policy_saves_to_host():
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_activation_offload_policy_saves_to_host(attention):
     """The offload_dots remat knob is REAL (round-3 verdict: it silently
     degraded to full remat because no checkpoint_name tags existed): the
     trunk tags layer_in/attn_out (transformer.py _layer) and the policy
     offloads exactly those — visible as <host>-space residuals of the
-    rematted loss. Reference analog: cpu_checkpointing
+    rematted loss. Under the flash kernel, which names its own residuals,
+    what is parked on the host is flash_o / flash_lse in attn_out's place.
+    Reference analog: cpu_checkpointing
     (activation_checkpointing/checkpointing.py:1036)."""
     import contextlib
     import io
@@ -295,7 +298,13 @@ def test_activation_offload_policy_saves_to_host():
     from deepspeed_tpu.runtime.engine import _remat_policy
     from deepspeed_tpu.config import Config
 
-    model = build_model(tiny_test(n_layer=2, dtype=jnp.float32))
+    attn = None
+    if attention == "flash":
+        from deepspeed_tpu.ops.flash_attention import make_flash_attention
+
+        attn = make_flash_attention(block=16)
+    model = build_model(tiny_test(n_layer=2, dtype=jnp.float32),
+                        attention_fn=attn)
     params = model.init(jax.random.PRNGKey(0))
     ids = jnp.zeros((2, 16), jnp.int32)
 
@@ -315,6 +324,15 @@ def test_activation_offload_policy_saves_to_host():
     full = residuals("save_nothing")
     assert "<host>" in offl, offl          # named activations go to host
     assert "<host>" not in full, full      # full remat keeps nothing
+    on_host = [ln for ln in offl.splitlines() if "<host>" in ln]
+    # stacked over the 2 layers: a layer's input, and the attention's output
+    # in the form its function names — the kernel's o (B, S, D) and lse
+    # (B, H, S), or the projection (B, S, D)
+    shapes = sorted(ln.split()[0] for ln in on_host)
+    want = ["f32<host>[2,2,16,64]"] * 2
+    if attention == "flash":
+        want = ["f32<host>[2,2,16,64]"] * 2 + ["f32<host>[2,2,4,16]"]
+    assert shapes == sorted(want), on_host
 
 
 def test_activation_offload_engine_matches_dots_saveable():
