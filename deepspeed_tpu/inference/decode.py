@@ -118,6 +118,24 @@ class HybridCache(NamedTuple):
     length: jnp.ndarray      # as KVCache.length
 
 
+class WindowedCache(NamedTuple):
+    """The cache of a trunk of window layers beside full ones
+    (``cfg.attn_pattern``, ``models/windowed.py``): planes for the FULL
+    layers only, laid out as :class:`KVCache`'s with values ``v_dim`` wide
+    beside keys of ``head_dim``; and for each WINDOW layer a ring of
+    ``ring_len(cfg)`` positions a slot (two 128-lane blocks for a window of
+    128), position ``p`` at ``p % ring``, with that kind's KV heads: it
+    stops growing where a plane goes on. Every buffer has the slot second,
+    so ``serving/slots.py`` seats a request by overwriting the slot's whole
+    extent of each: a successor never reads its predecessor's ring."""
+
+    k: jnp.ndarray           # (full layers, B, KV, hd, max_len)
+    v: jnp.ndarray           # (full layers, B, KV, vd, max_len)
+    wk: jnp.ndarray          # (window layers, B, window KV, hd, ring)
+    wv: jnp.ndarray          # (window layers, B, window KV, vd, ring)
+    length: jnp.ndarray      # as KVCache.length
+
+
 def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                  dtype=None, *, page_size: int = 0, pages: int = 0) -> tuple:
     """(shape, dtype) of one cache buffer (K or V; for latent attention
@@ -141,6 +159,17 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
     apart from every other pass's: ``L`` = ``n_layer x loop_steps`` planes,
     pass ``r``'s layer ``l`` at ``r * n_layer + l``, contiguous only."""
     # (duck-typed configs of other trunks have no attention kinds: K/V)
+    if getattr(cfg, "attn_pattern", ""):
+        # window layers beside full ones: planes for the full layers only
+        # (V's are value_shape()'s); the window layers' rings are
+        # state_layout()'s
+        if page_size > 0:
+            raise NotImplementedError(
+                "the paged pool holds pages of one K/V width for every "
+                "layer; full-layer planes beside window rings are "
+                "contiguous only")
+        return ((cfg.attn_pattern.count("G"), batch, cfg.kv_heads,
+                 cfg.head_dim, max_len), dtype or cfg.dtype)
     pattern = getattr(cfg, "block_pattern", "")
     if pattern:
         # one mixer a layer: planes for the attention layers only; what the
@@ -170,10 +199,27 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
              max_len), dtype or cfg.dtype)
 
 
+def value_shape(cfg: TransformerConfig, shape: tuple) -> tuple:
+    """The V buffer's shape beside a contiguous K buffer of ``shape``
+    (:func:`cache_layout`): the same, but for a model whose values are
+    another width than its keys (``v_dim``)."""
+    if len(shape) != 5 or getattr(cfg, "latent_dim", 0):
+        return shape
+    return shape[:3] + (getattr(cfg, "v_dim", shape[3]),) + shape[4:]
+
+
 def state_layout(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
     """{name: (shape, dtype)} of what a cache holds per slot whatever the
     position — a ``block_pattern`` trunk's Mamba-2 layers' SSM state and
-    conv window (:class:`HybridCache`); {} for every other trunk."""
+    conv window (:class:`HybridCache`), an ``attn_pattern`` trunk's window
+    layers' rings (:class:`WindowedCache`); {} for every other trunk."""
+    if getattr(cfg, "attn_pattern", ""):
+        from ..models.windowed import ring_len
+
+        n = cfg.attn_pattern.count("S")
+        kv, ring = cfg.attn_kv_heads("S"), ring_len(cfg)
+        return {"wk": ((n, batch, kv, cfg.head_dim, ring), dtype or cfg.dtype),
+                "wv": ((n, batch, kv, cfg.v_dim, ring), dtype or cfg.dtype)}
     pattern = getattr(cfg, "block_pattern", "")
     if "M" not in pattern:
         return {}
@@ -202,7 +248,10 @@ def cache_bytes_per_token(cfg: TransformerConfig, dtype=None) -> int:
     """Bytes one cached position costs over all layers, from
     :func:`cache_layout`."""
     shape, dt = cache_layout(cfg, 1, 1, dtype)
-    return cache_buffers(shape) * math.prod(shape) * jnp.dtype(dt).itemsize
+    if cache_buffers(shape) == 1:
+        return math.prod(shape) * jnp.dtype(dt).itemsize
+    return (math.prod(shape) + math.prod(value_shape(cfg, shape))) \
+        * jnp.dtype(dt).itemsize
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
@@ -213,13 +262,16 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     shape, dtype = cache_layout(cfg, batch, max_len, dtype)
     length = jnp.zeros(length_shape, jnp.int32)
     if state:
-        return HybridCache(
-            k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+        kind = WindowedCache if "wk" in state else HybridCache
+        return kind(
+            k=jnp.zeros(shape, dtype),
+            v=jnp.zeros(value_shape(cfg, shape), dtype),
             length=length, **{name: jnp.zeros(sh, dt)
                               for name, (sh, dt) in state.items()})
     if cache_buffers(shape) == 1:
         return LatentCache(c=jnp.zeros(shape, dtype), length=length)
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+    return KVCache(k=jnp.zeros(shape, dtype),
+                   v=jnp.zeros(value_shape(cfg, shape), dtype),
                    length=length)
 
 
@@ -628,7 +680,7 @@ def _latent_layer_step(model, x, p, cache_c, length, positions, fused: bool,
     if "router" in p and cfg.moe_router == "sigmoid":
         out, stats, idx = model.experts(y2, p, banks=banks, layer=bank_layer)
     else:
-        out, stats = model._mlp_block(y2, p)[0], jnp.zeros((3,), jnp.float32)
+        out, stats = model._mlp_block(y2, p)[0], jnp.zeros((4,), jnp.float32)
         idx = jnp.zeros((B, T, 0), jnp.int32)
     return x + out, cache_c, (stats, idx)
 
@@ -768,6 +820,173 @@ def _forward_hybrid(model, params, x, cache: HybridCache, new_len, valid,
             if stats else None)
 
 
+def _ring_update(ring, new, layer, start, end):
+    """Layer ``layer`` of the ring buffer ``(L, B, KV, w, R)`` after a chunk
+    wrote positions ``start .. end - 1`` (``new`` (B, T, KV, w) holds
+    ``start .. start + T - 1``; what lies at or behind ``end`` is padding):
+    ring place ``r`` holds the last position < ``end`` that is ``r`` mod
+    ``R`` — the chunk's, where the chunk reaches that far back, else what
+    it held."""
+    R, T = ring.shape[4], new.shape[1]
+    r = jnp.arange(R, dtype=jnp.int32)
+    pos = end - 1 - (end - 1 - r) % R
+    old = lax.dynamic_index_in_dim(ring, layer, keepdims=False)
+    took = jnp.take(new.transpose(0, 2, 3, 1).astype(ring.dtype),
+                    jnp.clip(pos - start, 0, T - 1), axis=3)
+    slab = jnp.where((pos >= start) & (pos >= 0), took, old)
+    return lax.dynamic_update_slice(ring, slab[None], (layer, 0, 0, 0, 0))
+
+
+def _ring_before(ring, layer, start, n: int):
+    """The ``n`` positions before ``start`` out of layer ``layer`` of the
+    ring, in order: ``(B, KV, w, n)`` (what lies before position 0 is
+    whatever the ring holds there: the caller masks it)."""
+    R = ring.shape[4]
+    at = (start - n + jnp.arange(n, dtype=jnp.int32)) % R
+    return jnp.take(lax.dynamic_index_in_dim(ring, layer, keepdims=False),
+                    at, axis=3)
+
+
+def _ring_attend(q, rk, rv, length, window: int, sink):
+    """The T = 1 read of a ring in plain XLA: ``q`` (B, 1, H, hd) over one
+    layer's ring (B, KV, ., R) of a slot at ``length`` (B,) after the
+    append. Ring place ``r`` holds position ``length - 1 - (length - 1 - r)
+    % R``; the window keeps ``length - window .. length - 1``."""
+    B, _, H, hd = q.shape
+    KV, R = rk.shape[1], rk.shape[3]
+    n = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
+    r = jnp.arange(R, dtype=jnp.int32)[None]
+    pos = n[:, None] - 1 - (n[:, None] - 1 - r) % R
+    keep = ((pos >= 0) & (pos >= n[:, None] - window))[:, None, None]
+    qg = q[:, 0].reshape(B, KV, H // KV, hd)
+    s = jnp.einsum("bkgd,bkdr->bkgr", qg, rk.astype(q.dtype),
+                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+    s = jnp.where(keep, s, BIG_NEG)
+    top = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, KV, H // KV, 1)
+        top = jnp.maximum(top, sk)
+    pr = jnp.where(keep, jnp.exp(s - top), 0.0)
+    den = jnp.sum(pr, axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sk - top)
+    o = jnp.einsum("bkgr,bkvr->bkgv", pr.astype(rv.dtype), rv,
+                   preferred_element_type=jnp.float32)
+    return (o / jnp.maximum(den, 1e-30)).astype(q.dtype).reshape(
+        B, 1, H, rv.shape[2])
+
+
+def _forward_windowed(model, params, x, cache: WindowedCache, new_len,
+                      positions, valid, flash_decode: bool):
+    """The layer loop of an ``attn_pattern`` trunk (``models/windowed.py``):
+    each run of layers equal in (attention kind, FFN kind) over its own
+    stacked weights, all of them carrying the cache's four buffers, a layer
+    touching only its kind's two. The T == 1 step runs ``decode_attention``
+    under two names: over a full layer's live blocks
+    (``full_decode_attention``), over the one or two ring blocks a window
+    layer's last ``window`` positions lie in, the sink in the sum
+    (``window_decode_attention``); both append in place. T > 1 (a chunk of
+    ONE request, or rows that advance together) appends with XLA's update —
+    into the ring the last ``ring`` of the chunk's REAL positions (``valid``
+    of T, traced or None: a right-padded final chunk) — and attends in
+    blocks: a full layer over the live key blocks of its plane, a window
+    layer over the chunk itself and the ``window - 1`` positions the ring
+    held before it. Returns (x, cache, (stats (expert layers, 4), routing
+    (expert layers, B, T, k)) or None)."""
+    from ..models import windowed
+    from ..ops.decode_attention import decode_attention
+
+    cfg = model.cfg
+    B, T, _ = x.shape
+    per_slot = getattr(new_len, "ndim", 0) == 1
+    if per_slot and T > 1:
+        raise NotImplementedError(
+            "a ring takes one token a slot (T == 1) or a chunk of rows that "
+            "advance together (scalar length): no multi-token verify forward")
+    fused = _decode_kernel_ok(flash_decode, T, cache.k.shape[4], x.dtype,
+                              cache.k.dtype, cache.v.dtype)
+    if T == 1 and not fused:
+        from ..observability.metrics import get_registry
+
+        get_registry().counter("Serve/decode_fallback_builds").inc()
+    ring = cache.wk.shape[4]
+    start = None if per_slot else new_len - T
+    end = None if per_slot else start + (T if valid is None else valid)
+
+    def layer_fn(carry, p, idx, local, kind, banks):
+        x, ck, cv, wk, wv = carry
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        q, k, v = windowed.project(cfg, y, p, positions, kind)
+        sink = p.get("sink")
+        if kind == "G":
+            if fused:
+                o, ck, cv = decode_attention(
+                    q, ck, cv, new_len, k=k, v=v, layer=idx,
+                    name="full_decode_attention")
+            elif T == 1:
+                slab_k, ck = _dense_append(ck, k, idx, new_len)
+                slab_v, cv = _dense_append(cv, v, idx, new_len)
+                o = _cache_attend(q, slab_k, slab_v, new_len)
+            else:
+                # the chunk into the carried planes, and the read block by
+                # block out of them, by layer — no slab is sliced out
+                ck, cv = (lax.dynamic_update_slice(
+                    c, n.transpose(0, 2, 3, 1)[None].astype(c.dtype),
+                    (idx, 0, 0, 0, start)) for c, n in ((ck, k), (cv, v)))
+                o = windowed.attend_blocks(q, ck, cv, positions, new_len,
+                                           layer=idx)
+        elif fused:
+            o, wk, wv = decode_attention(
+                q, wk, wv, new_len, k=k, v=v, layer=idx, window=cfg.window,
+                sink=sink, name="window_decode_attention")
+        elif T == 1:
+            # the new column at its ring place, then the ring densely
+            at = jnp.where(new_len > 0, (new_len - 1) % ring + 1, 0)
+            slab_k, wk = _dense_append(wk, k, idx, at)
+            slab_v, wv = _dense_append(wv, v, idx, at)
+            o = _ring_attend(q, slab_k, slab_v, new_len, cfg.window, sink)
+        else:
+            before = windowed.prev_len(cfg)
+            o = windowed.attend_window(
+                q, k, v, _ring_before(wk, idx, start, before),
+                _ring_before(wv, idx, start, before), start, cfg.window, sink)
+            wk = _ring_update(wk, k, idx, start, end)
+            wv = _ring_update(wv, v, idx, start, end)
+        x = x + matmul_any(o.reshape(B, T, cfg.n_head * cfg.v_dim), p["wo"],
+                           use_kernel=False)
+        y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
+        if "router" in p:
+            out, stats, chose = model.experts(y2, p, banks=banks, layer=local)
+        else:
+            out, stats = model._mlp_block(y2, p)[0], jnp.zeros((4,),
+                                                                jnp.float32)
+            chose = jnp.zeros((B, T, 0), jnp.int32)
+        return (x + out, ck, cv, wk, wv), (stats, chose)
+
+    carry = (x, cache.k, cache.v, cache.wk, cache.wv)
+    seen = {"G": 0, "S": 0}
+    stats = []
+    for (ffn, n), kind, seg in zip(cfg.segments, cfg.segment_attn,
+                                   model.segment_params(params["layers"])):
+        # the expert banks stay out of the loop's xs (see _forward_latent)
+        names = getattr(model, "BANKS", ()) if ffn == "moe" else ()
+        banks = {k: seg[k] for k in names} or None
+        rest = {k: v for k, v in seg.items() if k not in names}
+        with jax.named_scope("decode_layer"):
+            carry, out = lax.scan(
+                lambda c, xs, kind=kind, banks=banks: layer_fn(
+                    c, *xs, kind, banks), carry,
+                (rest, jnp.arange(seen[kind], seen[kind] + n, dtype=jnp.int32),
+                 jnp.arange(n, dtype=jnp.int32)))
+        seen[kind] += n
+        if ffn == "moe":
+            stats.append(out)
+    x, k, v, wk, wv = carry
+    return (x, WindowedCache(k=k, v=v, wk=wk, wv=wv, length=new_len),
+            tuple(jnp.concatenate(part) for part in zip(*stats))
+            if stats else None)
+
+
 def _embed_rows(table, ids, dtype):
     """Row gather from a dense or int8/int4-stored embedding table — a
     quantized table reads int8 bytes for exactly the batch's tokens."""
@@ -883,7 +1102,11 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
                   cfg.norm, cfg.norm_eps)
 
     stats = passes = None
-    if isinstance(cache, HybridCache):
+    if isinstance(cache, WindowedCache):
+        x, new_cache, stats = _forward_windowed(
+            model, params, x, cache, new_len, positions,
+            None if last_index is None else last_index + 1, flash_decode)
+    elif isinstance(cache, HybridCache):
         x, new_cache, stats = _forward_hybrid(
             model, params, x, cache, new_len,
             None if last_index is None else last_index + 1, flash_decode)

@@ -34,7 +34,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import ssm
-from .moe import MoETransformerLM
+from .moe import MoETransformerLM, held_layout
 from .transformer import TransformerLM, _norm
 
 KINDS = "ME*"
@@ -152,36 +152,13 @@ class HybridLM(TransformerLM):
         cfg = self.cfg
         B, T, d = y.shape
         k, Eh = cfg.moe_top_k, cfg.held_experts
-        N, M = B * T, B * T * cfg.moe_top_k
+        N = B * T
         yt = y.reshape(N, d)
         idx, w = self.route(yt, p)
         u = yt @ p["w_dn"].astype(y.dtype) if "w_dn" in p else yt
         bm = block_rows(y.dtype)
-        # pairs that chose an expert held elsewhere sort behind every held
-        # one and get no row
-        e = idx.reshape(M) - cfg.moe_first_held
-        e = jnp.where((e >= 0) & (e < Eh), e, Eh)
-        order = jnp.argsort(e, stable=True)
-        e_sorted = e[order]
-        held = e_sorted < Eh
-        es = jnp.minimum(e_sorted, Eh - 1)
-        counts = jnp.bincount(e, length=Eh + 1)[:Eh].astype(jnp.int32)
-        padded = (counts + bm - 1) // bm * bm
-        p_end = jnp.cumsum(padded)
-        first = jnp.cumsum(counts) - counts
-        R = -(-(M + min(Eh, M) * (bm - 1)) // bm) * bm       # every case fits
-        dest = jnp.where(held, (p_end - padded)[es]
-                         + jnp.arange(M, dtype=jnp.int32) - first[es], R)
-        row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
-            (order // k).astype(jnp.int32), mode="drop")
-        pair_row = jnp.zeros((M,), jnp.int32).at[order].set(
-            jnp.where(held, dest, 0))
-        pair_held = jnp.zeros((M,), bool).at[order].set(held)
-        used = p_end[-1] // bm
-        blocks = jnp.arange(R // bm, dtype=jnp.int32)
-        block_expert = jnp.minimum(jnp.searchsorted(
-            p_end, jnp.maximum(jnp.minimum(blocks, used - 1), 0) * bm,
-            side="right"), Eh - 1)
+        row_token, pair_row, pair_held, block_expert, used, counts = \
+            held_layout(idx, Eh, cfg.moe_first_held, bm)
         out = experts_relu2(u[row_token], p["w1"], p["w2"], block_expert,
                             used, bm=bm)
         # a row no block wrote is never read: pairs held elsewhere add 0

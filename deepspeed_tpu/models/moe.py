@@ -111,6 +111,43 @@ def topk_gating(logits: jnp.ndarray, top_k: int, capacity: int):
     return combine, dispatch, aux_loss
 
 
+def held_layout(idx, held: int, first_held: int, bm: int):
+    """The sorted layout of the rows a device multiplies when it holds
+    ``held`` experts from ``first_held`` on: ``idx`` (N, k) are the experts
+    chosen, of ALL the router's. A pair that chose an expert held elsewhere
+    sorts behind every held one and gets no row. Returns (row_token (R,) the
+    token of every row, pair_row (M,) a pair's row, pair_held (M,) whether
+    it has one, block_expert (R / bm,) the held expert of every block of
+    ``bm`` rows, used: the blocks that hold rows, counts (held,) the rows of
+    each held expert)."""
+    N, k = idx.shape
+    M = N * k
+    e = idx.reshape(M) - first_held
+    e = jnp.where((e >= 0) & (e < held), e, held)
+    order = jnp.argsort(e, stable=True)
+    e_sorted = e[order]
+    is_held = e_sorted < held
+    es = jnp.minimum(e_sorted, held - 1)
+    counts = jnp.bincount(e, length=held + 1)[:held].astype(jnp.int32)
+    padded = (counts + bm - 1) // bm * bm
+    p_end = jnp.cumsum(padded)
+    first = jnp.cumsum(counts) - counts
+    R = -(-(M + min(held, M) * (bm - 1)) // bm) * bm         # every case fits
+    dest = jnp.where(is_held, (p_end - padded)[es]
+                     + jnp.arange(M, dtype=jnp.int32) - first[es], R)
+    row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    pair_row = jnp.zeros((M,), jnp.int32).at[order].set(
+        jnp.where(is_held, dest, 0))
+    pair_held = jnp.zeros((M,), bool).at[order].set(is_held)
+    used = p_end[-1] // bm
+    blocks = jnp.arange(R // bm, dtype=jnp.int32)
+    block_expert = jnp.minimum(jnp.searchsorted(
+        p_end, jnp.maximum(jnp.minimum(blocks, used - 1), 0) * bm,
+        side="right"), held - 1)
+    return row_token, pair_row, pair_held, block_expert, used, counts
+
+
 class MoETransformerLM(TransformerLM):
     """TransformerLM with the dense FFN replaced by an expert-parallel MoE
     bank in every layer (Mixtral-style; the reference interleaves dense/MoE
@@ -272,53 +309,52 @@ class MoETransformerLM(TransformerLM):
         of ``p``'s own — a layer loop passes them so that no layer's bank is
         sliced out (``ops/moe_matmul.py``).
 
+        **Told which experts it holds** (``moe_experts_held`` from
+        ``moe_first_held`` on; the banks then hold those alone) the layer
+        routes over all ``num_experts``, sorts and multiplies only the rows
+        that chose a held one, weights them by the weights normalised over
+        ALL k chosen and adds nothing for the absent: its part of the sum
+        (the model-configs guide's chip's share).
+
         Returns (out (B, T, d), stats, idx): ``stats`` f32 [most rows one
-        expert got, experts touched, rows multiplied (padding included)] —
-        the counters the serving spans carry (rows routed = B·T·k is
-        static); ``idx`` (B, T, k) i32 the experts chosen."""
+        expert got, experts touched, rows multiplied (padding included),
+        rows that chose a held expert] — the counters the serving spans
+        carry (rows routed = B·T·k is static); ``idx`` (B, T, k) i32 the
+        experts chosen."""
         from ..ops.moe_matmul import block_rows, experts_swiglu
 
         cfg = self.cfg
         B, T, d = y.shape
-        E, k = cfg.num_experts, cfg.moe_top_k
-        N, M = B * T, B * T * k
+        N, k = B * T, cfg.moe_top_k
         yt = y.reshape(N, d)
         idx, w = self.route(yt, p)
+        bank = banks if banks is not None else p
         with jax.named_scope("moe_experts"):
             bm = block_rows(y.dtype)
-            e = idx.reshape(M)
-            order = jnp.argsort(e, stable=True)
-            e_sorted = e[order]
-            counts = jnp.bincount(e, length=E).astype(jnp.int32)
-            padded = (counts + bm - 1) // bm * bm
-            p_end = jnp.cumsum(padded)
-            first = jnp.cumsum(counts) - counts
-            # row of sorted pair i: its expert's padded start + its rank
-            dest = (p_end - padded)[e_sorted] \
-                + jnp.arange(M, dtype=jnp.int32) - first[e_sorted]
-            R = -(-(M + min(E, M) * (bm - 1)) // bm) * bm   # every case fits
-            row_token = jnp.zeros((R,), jnp.int32).at[dest].set(
-                (order // k).astype(jnp.int32))
-            pair_row = jnp.zeros((M,), jnp.int32).at[order].set(dest)
-            used = p_end[-1] // bm
-            blocks = jnp.arange(R // bm, dtype=jnp.int32)
-            block_expert = jnp.minimum(jnp.searchsorted(
-                p_end, jnp.minimum(blocks, used - 1) * bm, side="right"),
-                E - 1)
-            bank = banks if banks is not None else p
+            # every expert held (held_experts = E from 0 on) is the same
+            # layout: every pair has a row
+            row_token, pair_row, pair_held, block_expert, used, counts = \
+                held_layout(idx, cfg.held_experts, cfg.moe_first_held, bm)
             out = experts_swiglu(yt[row_token], bank["w_gate"], bank["w_in"],
                                  bank["w_out"], block_expert, used, bm=bm,
                                  layer=layer)
-            routed = jnp.sum(out[pair_row].reshape(N, k, d).astype(jnp.float32)
+            # a row no block wrote is never read: pairs held elsewhere add 0
+            rows = jnp.where(pair_held[:, None], out[pair_row], 0)
+            routed = jnp.sum(rows.reshape(N, k, d).astype(jnp.float32)
                              * w[..., None], axis=1).astype(y.dtype)
         stats = jnp.stack([jnp.max(counts), jnp.sum(counts > 0),
-                           used * bm]).astype(jnp.float32)
-        if cfg.moe_shared_d_ff:
-            with jax.named_scope("moe_shared"):
-                u = jax.nn.silu(yt @ p["ws_gate"].astype(y.dtype)) \
-                    * (yt @ p["ws_in"].astype(y.dtype))
-                routed = routed + u @ p["ws_out"].astype(y.dtype)
-        return routed.reshape(B, T, d), stats, idx.reshape(B, T, k)
+                           used * bm, jnp.sum(counts)]).astype(jnp.float32)
+        return self._with_shared(routed, yt, p).reshape(B, T, d), stats, \
+            idx.reshape(B, T, k)
+
+    def _with_shared(self, routed, yt, p):
+        """``routed`` (N, d) with the shared MLP's rows added, if any."""
+        if not self.cfg.moe_shared_d_ff:
+            return routed
+        with jax.named_scope("moe_shared"):
+            u = jax.nn.silu(yt @ p["ws_gate"].astype(yt.dtype)) \
+                * (yt @ p["ws_in"].astype(yt.dtype))
+            return routed + u @ p["ws_out"].astype(yt.dtype)
 
     def _fold_aux(self, aux):
         """Aux losses add up; the sigmoid router's aux is its routing
@@ -343,6 +379,7 @@ class MoETransformerLM(TransformerLM):
         params = super().init(rng)
         cfg = self.cfg
         d, f, E = cfg.d_model, cfg.expert_dim, cfg.num_experts
+        Eh = cfg.held_experts       # the banks hold this device's share
         depth = cfg.n_layer
         segs = self.segment_params(params["layers"])
 
@@ -355,15 +392,15 @@ class MoETransformerLM(TransformerLM):
             # base init skips the dense FFN of an expert segment
             k = iter(jax.random.split(jax.random.fold_in(rng, 1 + i), 8))
             layers["router"] = dense(next(k), (L, d, E), 0.02)
-            layers["w_in"] = dense(next(k), (L, E, d, f), 1.0 / math.sqrt(d))
-            layers["w_out"] = dense(next(k), (L, E, f, d),
+            layers["w_in"] = dense(next(k), (L, Eh, d, f), 1.0 / math.sqrt(d))
+            layers["w_out"] = dense(next(k), (L, Eh, f, d),
                                     1.0 / math.sqrt(2 * depth * f))
             if cfg.is_glu:
-                layers["w_gate"] = dense(next(k), (L, E, d, f),
+                layers["w_gate"] = dense(next(k), (L, Eh, d, f),
                                          1.0 / math.sqrt(d))
             if cfg.use_bias:
-                layers["b_in"] = jnp.zeros((L, E, f), jnp.float32)
-                layers["b_out"] = jnp.zeros((L, E, d), jnp.float32)
+                layers["b_in"] = jnp.zeros((L, Eh, f), jnp.float32)
+                layers["b_out"] = jnp.zeros((L, Eh, d), jnp.float32)
             if cfg.moe_router == "sigmoid":
                 # a trained model's selection bias is small and not zero;
                 # drawn so, that a path which drops it chooses differently

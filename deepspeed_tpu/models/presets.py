@@ -191,6 +191,38 @@ def bloom(size: str = "560m", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def mimo_v2_flash(size: str = "tiny", **overrides) -> TransformerConfig:
+    """MiMo-V2-Flash family (``model_type: mimo_v2_flash``): window layers
+    (a sink a head, their own KV heads and theta) beside full ones
+    (``attn_pattern``, models/windowed.py), keys and queries wider than
+    values, rope on the leading third of a head, a dense SwiGLU layer then
+    sigmoid-routed experts with no shared one. ``"flash"`` is the published
+    309B-A15B configuration (48 layers: layer 0 full, then ``SSSSG`` once
+    and ``SSSSSG`` seven times)."""
+    table = {
+        "tiny": dict(attn_pattern="GSSGS", n_layer=5, n_head=4, n_kv_head=1,
+                     window_kv_heads=2, d_model=64, qk_head_dim=24,
+                     v_head_dim=16, rotary_dim=8, window=128, d_ff=128,
+                     num_experts=8, moe_top_k=2, moe_d_ff=32,
+                     vocab_size=256, max_seq=1024),
+        "flash": dict(attn_pattern="G" + "SSSSG" + 7 * "SSSSSG", n_layer=48,
+                      n_head=64, n_kv_head=4, window_kv_heads=8,
+                      d_model=4096, qk_head_dim=192, v_head_dim=128,
+                      rotary_dim=64, window=128, d_ff=16384,
+                      num_experts=256, moe_top_k=8, moe_d_ff=2048,
+                      vocab_size=152576, max_seq=262144),
+    }
+    base = dict(pos_embedding="rope", norm="rmsnorm", norm_eps=1e-5,
+                activation="silu_glu", use_bias=False, tie_embeddings=False,
+                rope_theta=5e6, window_rope_theta=1e4, rope_halves=True,
+                attn_sink=True, attn_value_scale=0.707,
+                moe_router="sigmoid", moe_norm_topk=True,
+                moe_routed_scale=1.0, moe_first_dense=1)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def tiny_test(**overrides) -> TransformerConfig:
     """Unit-test sized config (analog of the reference tests' SimpleModel)."""
     base = dict(vocab_size=256, n_layer=2, n_head=4, d_model=64, d_ff=128,

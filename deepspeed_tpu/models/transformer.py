@@ -156,6 +156,27 @@ class TransformerConfig:
     # part of the result (the model-configs guide's chip's share)
     moe_experts_held: int = 0
     moe_first_held: int = 0
+    # two kinds of attention layer in one trunk (MiMo-V2-Flash,
+    # ``model_type: mimo_v2_flash``; models/windowed.py): layer i is
+    # ``attn_pattern[i]``, "G" full causal attention (``n_kv_head`` KV
+    # heads, ``rope_theta``) | "S" a sliding window of ``window`` positions
+    # (query i sees keys i - window + 1 .. i) with ``window_kv_heads`` KV
+    # heads, ``window_rope_theta`` and, with ``attn_sink``, a learned sink
+    # logit a head in the softmax's denominator. Both kinds: heads
+    # ``qk_head_dim`` wide for q and k over ``v_head_dim`` for v, V times
+    # ``attn_value_scale``. "" is one kind of layer, as every other family
+    # has. The pattern decides cache_layout(): planes for the "G" layers
+    # beside a ring of 2 x 128 positions a slot for each "S" layer
+    attn_pattern: str = ""
+    window: int = 0
+    window_kv_heads: Optional[int] = None
+    window_rope_theta: Optional[float] = None
+    attn_sink: bool = False
+    qk_head_dim: int = 0                  # 0: d_model / n_head
+    attn_value_scale: float = 1.0
+    # the rotation pairs dim i with i + rotary_dim / 2 (HF ``rotate_half``)
+    # instead of 2i with 2i + 1
+    rope_halves: bool = False
 
     @property
     def held_experts(self) -> int:
@@ -166,12 +187,23 @@ class TransformerConfig:
         """A head's query/key width."""
         if self.attention == "mla":
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_head
+        return self.qk_head_dim or self.d_model // self.n_head
 
     @property
     def v_dim(self) -> int:
-        """A head's value width (MLA's differs from its query/key width)."""
-        return self.v_head_dim if self.attention == "mla" else self.head_dim
+        """A head's value width (MLA's, and a ``v_head_dim`` given to MHA,
+        differ from the query/key width)."""
+        return self.v_head_dim or self.head_dim
+
+    def attn_kv_heads(self, kind: str = "") -> int:
+        """KV heads of an attention layer of ``kind`` ("S": a window
+        layer's; else the trunk's)."""
+        return (self.window_kv_heads if kind == "S" else None) \
+            or self.kv_heads
+
+    def attn_theta(self, kind: str = "") -> float:
+        return (self.window_rope_theta if kind == "S" else None) \
+            or self.rope_theta
 
     @property
     def latent_dim(self) -> int:
@@ -188,17 +220,30 @@ class TransformerConfig:
         """The trunk as an ordered list of (FFN kind, layers): each segment
         is one block kind scanned over its own stacked weights
         (``params["layers"]``: the stacked tree of a one-segment trunk, a
-        tuple of them otherwise). The attention kind is the model's. With a
+        tuple of them otherwise). The attention kind is the model's, or
+        with an ``attn_pattern`` the segment's (:attr:`segment_attn`). With a
         ``block_pattern`` the kind is the layer's one mixer ("M" | "E" |
         "*"), a segment a run of equal letters."""
         if self.block_pattern:
             return tuple((kind, len(list(run)))
                          for kind, run in groupby(self.block_pattern))
-        if self.num_experts == 1:
-            return (("dense", self.n_layer),)
-        k = min(self.moe_first_dense, self.n_layer)
-        return tuple(s for s in (("dense", k), ("moe", self.n_layer - k))
-                     if s[1] > 0)
+        return tuple((ffn, n) for (_, ffn), n in self._layer_runs())
+
+    @property
+    def segment_attn(self) -> tuple:
+        """Each segment's attention kind ("G" | "S" with an
+        ``attn_pattern``, else ""): a segment is a run of layers equal in
+        attention kind AND FFN kind."""
+        if self.block_pattern:
+            return ("",) * len(self.segments)
+        return tuple(attn for (attn, _), _ in self._layer_runs())
+
+    def _layer_runs(self) -> list:
+        k = min(self.moe_first_dense, self.n_layer) if self.num_experts > 1 \
+            else self.n_layer
+        kinds = [((self.attn_pattern[i] if self.attn_pattern else ""),
+                  "dense" if i < k else "moe") for i in range(self.n_layer)]
+        return [(kind, len(list(run))) for kind, run in groupby(kinds)]
 
     @property
     def kv_heads(self) -> int:
@@ -229,8 +274,12 @@ class TransformerConfig:
         # scores + values: 2 * S * H * (qk width + v width) forward, x3
         n_attn = self.block_pattern.count("*") if self.block_pattern \
             else self.n_layer
-        attn = 6 * self.loop_steps * n_attn * self.n_head * (
-            self.head_dim + self.v_dim) * self.max_seq
+        # a window layer's query sees at most ``window`` keys
+        n_win = self.attn_pattern.count("S")
+        keys = (n_attn - n_win) * self.max_seq \
+            + n_win * min(self.max_seq, self.window)
+        attn = 6 * self.loop_steps * self.n_head * (
+            self.head_dim + self.v_dim) * keys
         head = (0 if self.objective == "feature"
                 else 6 * self.d_model * self.vocab_size)
         return 6 * n_params + attn + head
@@ -244,19 +293,19 @@ class TransformerConfig:
         if (kind or self.segments[-1][0]) == "dense":
             return d * self.ffn_dim * mats
         router = d * E
-        mult = min(self.moe_top_k, E) if active_only else E
+        mult = min(self.moe_top_k, E) if active_only else self.held_experts
         return (router + mult * d * self.expert_dim * mats
                 + d * self.moe_shared_d_ff * mats)
 
-    def _attn_params_per_layer(self) -> int:
+    def _attn_params_per_layer(self, kind: str = "") -> int:
         d, h = self.d_model, self.n_head
         if self.attention == "mla":
             r = self.kv_lora_rank
             return (d * h * self.head_dim + d * self.latent_dim
                     + r * h * (self.qk_nope_head_dim + self.v_dim)
                     + h * self.v_dim * d)
-        kv, hd = self.kv_heads, self.head_dim
-        return d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d
+        kv, hd, vd = self.attn_kv_heads(kind), self.head_dim, self.v_dim
+        return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d
 
     def _mixer_params_per_layer(self, kind: str, active_only: bool) -> int:
         """Matmul parameters of one ``block_pattern`` layer of ``kind``."""
@@ -284,9 +333,10 @@ class TransformerConfig:
             total = sum(n * self._mixer_params_per_layer(kind, active_only)
                         for kind, n in self.segments)
         else:
-            total = sum(n * (self._attn_params_per_layer()
+            total = sum(n * (self._attn_params_per_layer(attn)
                              + self._ffn_params_per_layer(active_only, kind))
-                        for kind, n in self.segments)
+                        for (kind, n), attn in zip(self.segments,
+                                                   self.segment_attn))
         total += emb if not non_embedding else 0
         if (not self.tie_embeddings and not non_embedding
                 and self.objective != "feature"):
@@ -309,8 +359,10 @@ def _norm(x, scale, bias, kind: str, eps: float = 1e-5):
     return y.astype(x.dtype)
 
 
-def _rope(q, k, positions, theta: float, rotary_dim: int | None = None):
-    """Rotary embeddings on (B, S, H, hd) q/k (interleaved-pair basis).
+def _rope(q, k, positions, theta: float, rotary_dim: int | None = None,
+          halves: bool = False):
+    """Rotary embeddings on (B, S, H, hd) q/k (interleaved-pair basis; with
+    ``halves`` dim i pairs with i + rotary_dim / 2, HF's ``rotate_half``).
 
     ``rotary_dim`` < head_dim rotates only the leading dims of each head
     (GPT-J's ``rotary_dim``, NeoX's ``rotary_pct``); the tail passes through.
@@ -324,10 +376,12 @@ def _rope(q, k, positions, theta: float, rotary_dim: int | None = None):
 
     def rot(x):
         xr, xp = x[..., :rd], x[..., rd:]
-        x1, x2 = xr[..., ::2], xr[..., 1::2]
+        x1, x2 = (xr[..., :rd // 2], xr[..., rd // 2:]) if halves \
+            else (xr[..., ::2], xr[..., 1::2])
         xr1 = x1 * cos - x2 * sin
         xr2 = x2 * cos + x1 * sin
-        out = jnp.stack([xr1, xr2], axis=-1).reshape(xr.shape)
+        out = jnp.concatenate([xr1, xr2], axis=-1) if halves \
+            else jnp.stack([xr1, xr2], axis=-1).reshape(xr.shape)
         return jnp.concatenate([out, xp], axis=-1) if rd < hd else out
 
     return (rot(q.astype(jnp.float32)).astype(q.dtype),
@@ -563,6 +617,19 @@ class TransformerLM:
                     "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
         elif config.attention != "mha":
             raise ValueError(f"unknown attention kind {config.attention!r}")
+        if config.attn_pattern:
+            from .windowed import check_config
+
+            check_config(config, attention_fn)
+        if config.moe_experts_held and not config.block_pattern:
+            E, held = config.num_experts, config.moe_experts_held
+            if (config.moe_router != "sigmoid" or held > E
+                    or config.moe_first_held % held
+                    or config.moe_first_held >= E):
+                raise ValueError(
+                    "moe_experts_held is the sigmoid router's (models/moe.py "
+                    "experts): moe_experts_held of num_experts from a "
+                    "multiple of that on")
         if config.loop_steps < 1 or (config.exit_gate
                                      and config.loop_steps == 1):
             raise ValueError("loop_steps counts the trunk's passes (>= 1); "
@@ -590,14 +657,15 @@ class TransformerLM:
 
         segs = cfg.segments
         if len(segs) == 1:
-            layers = self._init_segment(k, dense, segs[0][0], L, L)
+            layers = self._init_segment(k, dense, segs[0][0], L, L,
+                                        cfg.segment_attn[0])
         else:
             # block kinds: every segment draws from its own key, so a
             # segment's weights do not depend on what stands beside it
             layers = tuple(
                 self._init_segment(
                     iter(jax.random.split(jax.random.fold_in(rng, 100 + i), 16)),
-                    dense, kind, n, L)
+                    dense, kind, n, L, cfg.segment_attn[i])
                 for i, (kind, n) in enumerate(segs))
         params = {
             "tok_embed": jax.random.normal(next(k), (cfg.vocab_size, d), jnp.float32) * 0.02,
@@ -630,14 +698,15 @@ class TransformerLM:
         return params
 
 
-    def _init_segment(self, k, dense, kind: str, n: int, depth: int) -> dict:
-        """Stacked weights of ``n`` layers of one block kind (the model's
-        attention kind x FFN ``kind``); ``depth`` is the whole trunk's, for
-        the residual projections' scale. "moe" segments get their expert
-        banks from the MoE trunk (models/moe.py)."""
+    def _init_segment(self, k, dense, kind: str, n: int, depth: int,
+                      attn: str = "") -> dict:
+        """Stacked weights of ``n`` layers of one block kind (attention
+        kind ``attn``, "" the model's one, x FFN ``kind``); ``depth`` is the
+        whole trunk's, for the residual projections' scale. "moe" segments
+        get their expert banks from the MoE trunk (models/moe.py)."""
         cfg = self.cfg
         d, f, L = cfg.d_model, cfg.ffn_dim, n
-        h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+        h, kv, hd = cfg.n_head, cfg.attn_kv_heads(attn), cfg.head_dim
         dense_ffn = kind == "dense"
         two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
         layers = {"ln1_scale": jnp.ones((L, d), jnp.float32)}
@@ -656,10 +725,17 @@ class TransformerLM:
             layers.update({
                 "wq": dense(next(k), (L, d, h * hd)),
                 "wk": dense(next(k), (L, d, kv * hd)),
-                "wv": dense(next(k), (L, d, kv * hd)),
-                "wo": dense(next(k), (L, h * hd, d),
+                "wv": dense(next(k), (L, d, kv * cfg.v_dim)),
+                "wo": dense(next(k), (L, h * cfg.v_dim, d),
                             scale=1.0 / math.sqrt(2 * depth * d)),
             })
+            if attn == "S" and cfg.attn_sink:
+                from .windowed import SINK_INIT
+
+                # a trained sink takes a real share of a head's softmax;
+                # drawn so, that a path which drops it reads differently
+                layers["sink"] = SINK_INIT[0] + SINK_INIT[1] \
+                    * jax.random.normal(next(k), (L, h), jnp.float32)
         if two_ln:
             layers["ln2_scale"] = jnp.ones((L, d), jnp.float32)
         if cfg.sandwich_norm:
@@ -703,7 +779,8 @@ class TransformerLM:
         """TP (Megatron-style) sharding over the ``model`` axis:
         qkv/w_in column-split, wo/w_out row-split, embeddings vocab-split."""
         cfg = self.cfg
-        layers = tuple(self._segment_specs(kind) for kind, _ in cfg.segments)
+        layers = tuple(self._segment_specs(kind, attn) for (kind, _), attn
+                       in zip(cfg.segments, cfg.segment_attn))
         if len(layers) == 1:
             layers = layers[0]
         specs = {
@@ -734,7 +811,7 @@ class TransformerLM:
             specs["exit_gate_b"] = P()
         return specs
 
-    def _segment_specs(self, kind: str) -> dict:
+    def _segment_specs(self, kind: str, attn: str = "") -> dict:
         cfg = self.cfg
         dense_ffn = kind == "dense"
         two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
@@ -754,6 +831,8 @@ class TransformerLM:
                 "wv": P(None, None, "model"),
                 "wo": P(None, "model", None),
             })
+            if attn == "S" and cfg.attn_sink:
+                layers["sink"] = P(None, "model")
         if two_ln:
             layers["ln2_scale"] = P(None, None)
         if cfg.sandwich_norm:
@@ -791,13 +870,22 @@ class TransformerLM:
         return y + p[name].astype(y.dtype) if self.cfg.use_bias and name in p else y
 
     @jax.named_scope("attn")
-    def _attention_block(self, x, p, positions, attn_mask):
-        """Shared attention half of a layer (dense and MoE trunks)."""
+    def _attention_block(self, x, p, positions, attn_mask, attn: str = ""):
+        """Shared attention half of a layer (dense and MoE trunks);
+        ``attn`` the segment's attention kind under an ``attn_pattern``."""
         cfg = self.cfg
         B, S, d = x.shape
         h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
         y = x if cfg.post_ln else _norm(x, p["ln1_scale"], p.get("ln1_bias"),
                                         cfg.norm, cfg.norm_eps)
+        if attn:
+            from . import windowed
+
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "window and full layers side by side take no padding "
+                    "mask yet")
+            return windowed.attention_block(cfg, y, p, positions, attn)
         if cfg.attention == "mla":
             if attn_mask is not None:
                 raise NotImplementedError(
@@ -886,7 +974,7 @@ class TransformerLM:
         out = self._maybe_bias(self._proj(u, p, "w_out"), p, "b_out")
         return out, jnp.float32(0.0)
 
-    def _layer(self, x, layer_params, positions, attn_mask):
+    def _layer(self, x, layer_params, positions, attn_mask, attn: str = ""):
         cfg = self.cfg
         p = layer_params
         # Remat-policy anchors (reference cpu_checkpointing,
@@ -904,7 +992,7 @@ class TransformerLM:
         # ring / Ulysses, sparse) the projected attn_out is the tag:
         # recomputing it would redo the whole S^2 attention.
         x = checkpoint_name(x, "layer_in")
-        o = self._attention_block(x, p, positions, attn_mask)
+        o = self._attention_block(x, p, positions, attn_mask, attn)
         if not getattr(self.attention_fn, "names_residuals", False):
             o = checkpoint_name(o, "attn_out")
         if cfg.post_ln:
@@ -963,8 +1051,10 @@ class TransformerLM:
                       cfg.norm, cfg.norm_eps)
         return constrain(x, P(B_AXES, "seq", None)), positions
 
-    def _scan_layers(self, x, layers, positions, attn_mask, remat_policy):
-        """Scan the (remat-wrapped) layer body over a stacked layer pytree.
+    def _scan_layers(self, x, layers, positions, attn_mask, remat_policy,
+                     attn: str = ""):
+        """Scan the (remat-wrapped) layer body over a stacked layer pytree
+        (``attn``: the segment's attention kind under an ``attn_pattern``).
 
         ``layers`` may be the full stack or (under pipeline shard_map) the
         local stage's slice. Returns (x, summed aux losses).
@@ -976,7 +1066,8 @@ class TransformerLM:
         latency-hiding scheduler overlaps the next slice's DMA with the
         current layer's compute, so HBM only ever holds ~2 layers of weights.
         """
-        body = partial(self._layer, positions=positions, attn_mask=attn_mask)
+        body = partial(self._layer, positions=positions, attn_mask=attn_mask,
+                       attn=attn)
         if remat_policy is not None:
             body = jax.checkpoint(body, policy=remat_policy, prevent_cse=False)
         stream = getattr(self, "params_on_host", False)
@@ -1101,9 +1192,12 @@ class TransformerLM:
 
         def stack(x):
             auxes = []
-            for seg in self.segment_params(params["layers"]):
+            for seg, attn in zip(self.segment_params(params["layers"]),
+                                 self.cfg.segment_attn):
+                # (the schedules that override _scan_layers take no kind)
                 x, a = self._scan_layers(x, seg, positions, attn_mask,
-                                         remat_policy)
+                                         remat_policy,
+                                         **({"attn": attn} if attn else {}))
                 auxes.append(a)
             return x, self._join_aux(auxes)
 

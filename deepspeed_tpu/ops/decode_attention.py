@@ -104,18 +104,27 @@ def _with_column(old_ref, new_ref, b, r):
 
 
 def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
-                   append: bool):
+                   append: bool, window: int = 0, sink: bool = False):
     """One program: a slot's ``hb`` KV heads (``q_ref`` (hb, rows, hd), the
     group's query rows padded to the 8 sublanes) over that slot's live
     blocks, copied out of the cache in HBM by the kernel itself. With
     ``append`` the step's new K/V (``new_k`` / ``new_v`` (hb, hd, 128),
     slots on the lanes) go onto lane ``(L - 1) % block`` of the slot's last
     live block once it has landed in VMEM; the products read the patched
-    buffers and one copy takes them back to the aliased cache."""
+    buffers and one copy takes them back to the aliased cache.
+
+    ``window`` > 0: the cache is a RING, position ``p`` in block
+    ``(p // block) % (S // block)``; the slot's live positions are
+    ``L - window .. L - 1`` and only the blocks that hold them are fetched
+    (positions outside the window are masked). ``sink``: the running max and
+    sum start at ``(sink_h, 1)`` in place of ``(-inf, 0)``: one more column
+    of the softmax that carries no value. K and V may differ in width (the
+    accumulator and the output have V's)."""
     from jax.experimental.pallas import tpu as pltpu
 
     len_ref, layer_ref, *refs = refs
     slopes_ref = refs.pop(0) if alibi else None
+    sink_ref = refs.pop(0) if sink else None
     if append:
         (q_ref, new_k, new_v, k_hbm, v_hbm, o_ref, k_out, v_out,
          k_buf, v_buf, sem, ahead) = refs
@@ -125,13 +134,27 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
     n_slots, n_groups = pl.num_programs(0), pl.num_programs(1)
     hb, rows, _ = q_ref.shape
     S = k_hbm.shape[4]
-    # a caller's length may lie past the cache (a scalar that counts on):
-    # never past it (the append clamps the same way). A serving slot that
-    # is not running stands at 0: no fetch, no products, no write-back
-    L = jnp.minimum(len_ref[b], S)
-    nb = (L + block - 1) // block                        # only live blocks
+    ring = S // block            # a window's cache: blocks that come round
+
+    def live(n):
+        """Of a slot at length ``n``: (the length the kernel works with,
+        its first live block, its live blocks). Block numbers count on
+        through a ring; ``at`` folds them."""
+        if window:
+            return n, jnp.maximum(n - window, 0) // block, \
+                (n + block - 1) // block - jnp.maximum(n - window, 0) // block
+        # a caller's length may lie past the cache (a scalar that counts
+        # on): never past it (the append clamps the same way). A serving
+        # slot that is not running stands at 0: no fetch, no products, no
+        # write-back
+        n = jnp.minimum(n, S)
+        return n, 0, (n + block - 1) // block
+
+    L, j0, nb = live(len_ref[b])                         # only live blocks
 
     def at(slot, heads, j):
+        if window:
+            j = j % ring
         return (layer_ref[0], slot, pl.ds(heads * hb, hb), slice(None),
                 pl.ds(pl.multiple_of(j * block, block), block))
 
@@ -182,47 +205,42 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
 
     @pl.when((nb > 0) & (ahead[1] == 0))
     def _():
-        fetch(first, b, g, 0)
+        fetch(first, b, g, j0)
 
     # the program after this one, and whether it has a block to fetch
     wraps = g == n_groups - 1
     b_next = jnp.where(wraps, b + 1, b)
     g_next = jnp.where(wraps, 0, g + 1)
-    next_live = (b_next < n_slots) & (
-        len_ref[jnp.minimum(b_next, n_slots - 1)] > 0)
+    len_next = len_ref[jnp.minimum(b_next, n_slots - 1)]
+    next_live = (b_next < n_slots) & (len_next > 0)
+    j0_next = live(len_next)[1]
 
     q = q_ref[...]
-    slope = None
-    if alibi:
-        # (hb, rows, 1) of per-query-head slopes, from SMEM scalars
-        head = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 0)
-        row = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 1)
-        slope = jnp.zeros((hb, rows, 1), jnp.float32)
-        for i in range(hb):
-            for r in range(group):
-                slope = jnp.where((head == i) & (row == r),
-                                  slopes_ref[(g * hb + i) * group + r], slope)
+    # (hb, rows, 1) of per-query-head slopes, from SMEM scalars
+    slope = _per_head(slopes_ref, g, hb, rows, group, 0.0) if alibi else None
+
+    end = j0 + nb                # one past the slot's last live block
 
     def body(j, carry):
         m, l, acc = carry
-        buf = (first + j) % 2
+        buf = (first + j - j0) % 2
 
         # behind this block's products: the slot's next block, or after
         # its last the first block of the next program
-        @pl.when(j + 1 < nb)
+        @pl.when(j + 1 < end)
         def _():
             fetch(1 - buf, b, g, j + 1)
 
-        @pl.when((j + 1 == nb) & next_live)
+        @pl.when((j + 1 == end) & next_live)
         def _():
-            fetch(1 - buf, b_next, g_next, 0)
+            fetch(1 - buf, b_next, g_next, j0_next)
 
         for copy in copies(buf, b, g, j):
             copy.wait()
         if append:
             # the last live block holds position L - 1: patched in VMEM,
             # attended to from there, written back once
-            @pl.when(j + 1 == nb)
+            @pl.when(j + 1 == end)
             def _():
                 patch(buf)
                 for copy in writes(buf, j):
@@ -239,6 +257,8 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
             # step; Bloom's positional signal costs SMEM scalars here)
             s = s + slope * (col - (L - 1)).astype(jnp.float32)
         keep = col < L
+        if window:
+            keep = keep & (col >= L - window)
         s = jnp.where(keep, s, BIG_NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
@@ -251,8 +271,12 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
 
     m0 = jnp.full((hb, rows, 1), BIG_NEG, jnp.float32)
     l0 = jnp.zeros((hb, rows, 1), jnp.float32)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, nb, body, (m0, l0, acc0))
+    if sink:
+        # the sink column stands in the sum before any key: (sink_h, 1)
+        m0 = _per_head(sink_ref, g, hb, rows, group, BIG_NEG)
+        l0 = jnp.where(m0 > BIG_NEG, 1.0, 0.0)
+    acc0 = jnp.zeros(o_ref.shape, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(j0, end, body, (m0, l0, acc0))
     if append:
         # the write runs behind the last block's products, the next
         # program's first fetch (which is in the other buffer) and the turn
@@ -268,6 +292,20 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
     ahead[0] = (first + nb) % 2
     ahead[1] = ((nb > 0) & next_live).astype(jnp.int32)
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def _per_head(ref, g, hb: int, rows: int, group: int, rest: float):
+    """(hb, rows, 1) float32 of one SMEM scalar a query head (``ref`` (H,)):
+    program ``g``'s KV head ``i``, row ``r`` is head ``(g hb + i) group +
+    r``; the rows that pad a group to the sublane tile get ``rest``."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hb, rows, 1), 1)
+    out = jnp.full((hb, rows, 1), rest, jnp.float32)
+    for i in range(hb):
+        for r in range(group):
+            out = jnp.where((head == i) & (row == r),
+                            ref[(g * hb + i) * group + r], out)
+    return out
 
 
 def _shard_axes(ck, H):
@@ -299,7 +337,8 @@ def _slots_on_lanes(x, dtype):
 
 def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
                      alibi_slopes=None, block: int = LANES,
-                     interpret: Optional[bool] = None):
+                     interpret: Optional[bool] = None, window: int = 0,
+                     sink=None, name: str = "decode_attention"):
     """q: (B, 1, H, hd) current-token queries; ck/cv: the cache
     ``(L, B, KV, hd, max_len)`` with ``layer`` (traced i32) the layer to
     attend over, or one layer's ``(B, KV, hd, max_len)``; ``length`` scalar
@@ -322,7 +361,17 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     and the heads a program takes follow from ``(KV, hd, max_len, dtype)``,
     never from ``B``.
 
-    Returns (B, 1, H, hd); with ``k`` / ``v`` also the two caches."""
+    ``cv`` may hold values of another width than the keys (``(..., vd,
+    max_len)``): the result then has V's. ``window`` > 0: the caches are
+    RINGS of ``max_len`` positions (whole blocks, at least ``window`` and
+    one block more), position ``p`` at ``p % max_len``; ``length`` counts
+    on past the ring, the slot attends to positions ``length - window ..
+    length - 1`` and fetches only the blocks that hold them. ``sink`` (H,)
+    float32: a logit a head that joins the softmax's denominator and carries
+    no value. ``name``: the ``pallas_call``'s, which a trace tells kernels
+    apart by (a caller with another count of bytes a call gives its own).
+
+    Returns (B, 1, H, vd); with ``k`` / ``v`` also the two caches."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, hd = q.shape
@@ -332,13 +381,16 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     if slab:
         ck, cv, layer = ck[None], cv[None], 0
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    KV, S = ck.shape[2], ck.shape[4]
+    KV, S, vd = ck.shape[2], ck.shape[4], cv.shape[3]
     blk = min(block, S)
     if S % blk != 0:
         raise ValueError(f"cache length {S} not divisible by block {blk}")
     if append and blk != LANES:
         raise ValueError(f"appending takes blocks of {LANES} positions, "
                          f"not {blk}")
+    if window and S < (-(-window // blk) + 1) * blk:
+        raise ValueError(f"a ring of {S} positions does not hold a window "
+                         f"of {window} and the block being written")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     group = H // KV
@@ -346,6 +398,7 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     lengths = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
     alibi = alibi_slopes is not None
     slopes = (jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()
+    slopes += (jnp.asarray(sink, jnp.float32),) if sink is not None else ()
     news = (k, v) if append else ()
 
     axes = _shard_axes(ck, H)
@@ -356,12 +409,18 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
         mesh, b_ax, h_ax, cache = axes
         rows = P(b_ax, None, h_ax, None)
 
+        if window or sink is not None:
+            raise NotImplementedError(
+                "a ring and a sink run on one device: no shard_map rule for "
+                "them is under a test")
+
         def per_shard(q, ck, cv, n, layer, *rest):
             k, v = rest[:2] if append else (None, None)
             slopes = rest[len(news):]
             return decode_attention(q, ck, cv, n, k=k, v=v, layer=layer[0],
                                     block=block, interpret=interpret,
-                                    alibi_slopes=slopes[0] if slopes else None)
+                                    alibi_slopes=slopes[0] if slopes else None,
+                                    name=name)
 
         return jax.shard_map(
             per_shard, mesh=mesh,
@@ -377,30 +436,36 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     rows = -(-group // SUBLANES) * SUBLANES
     qs = jnp.pad(q.reshape(B, KV, group, hd).astype(ck.dtype),
                  ((0, 0), (0, 0), (0, rows - group), (0, 0)))
-    q_spec = pl.BlockSpec((None, hb, rows, hd),
-                          lambda b, g, *pre: (b, g, 0, 0))
-    new_spec = pl.BlockSpec((hb, hd, LANES),
+    def rows_spec(width):
+        return pl.BlockSpec((None, hb, rows, width),
+                            lambda b, g, *pre: (b, g, 0, 0))
+
+    def new_spec(width):
+        return pl.BlockSpec((hb, width, LANES),
                             lambda b, g, *pre: (g, 0, b // LANES))
+
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     n_pre = 2 + len(slopes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre,
         grid=(B, KV // hb),
-        in_specs=[q_spec] + [new_spec] * len(news) + [in_hbm, in_hbm],
-        out_specs=[q_spec] + [in_hbm] * len(news),
+        in_specs=[rows_spec(hd)] + [new_spec(hd), new_spec(vd)][:len(news)]
+        + [in_hbm, in_hbm],
+        out_specs=[rows_spec(vd)] + [in_hbm] * len(news),
         # two buffers of K and of V; a semaphore a buffer for the fetches
         # and one more pair for the write-back; ``ahead`` (see the kernel)
         scratch_shapes=[pltpu.VMEM((2, hb, hd, blk), ck.dtype),
-                        pltpu.VMEM((2, hb, hd, blk), cv.dtype),
+                        pltpu.VMEM((2, hb, vd, blk), cv.dtype),
                         pltpu.SemaphoreType.DMA((2 + append, 2)),
                         pltpu.SMEM((2 + append,), jnp.int32)],
     )
     out, *caches = pl.pallas_call(
         partial(_decode_kernel, block=blk, scale=scale, alibi=alibi,
-                group=group, append=append),
-        name="decode_attention",
+                group=group, append=append, window=window,
+                sink=sink is not None),
+        name=name,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, KV, rows, hd), q.dtype)]
+        out_shape=[jax.ShapeDtypeStruct((B, KV, rows, vd), q.dtype)]
         + ([jax.ShapeDtypeStruct(c.shape, c.dtype) for c in (ck, cv)]
            if append else []),
         # the caches come back where they were
@@ -412,7 +477,7 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
         interpret=interpret,
     )(lengths, layer, *slopes, qs,
       *(_slots_on_lanes(x, ck.dtype) for x in news), ck, cv)
-    out = out[:, :, :group].reshape(B, 1, H, hd)
+    out = out[:, :, :group].reshape(B, 1, H, vd)
     if not append:
         return out
     return (out,) + tuple(c[0] if slab else c for c in caches)

@@ -42,6 +42,14 @@ def _tile(n: int, most: int) -> int:
     return n
 
 
+def _fits(k_in: int, weights: int, dtype, most: int) -> int:
+    """``most`` columns of a weight tile, or as many fewer as keep the
+    tiles of ``weights`` (k_in, columns) operands, two buffers each, inside
+    half the 16 MiB of scoped VMEM (MiMo-V2's 4096-wide rows: 256)."""
+    room = (8 << 20) // (2 * weights * k_in * jnp.dtype(dtype).itemsize)
+    return max(LANES, min(most, room - room % LANES))
+
+
 def _up_kernel(be_ref, used_ref, _, x_ref, wg_ref, wi_ref, o_ref):
     @pl.when(pl.program_id(1) < used_ref[0])
     def _():
@@ -102,10 +110,10 @@ def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     h = _call(_up_kernel, "moe_experts_up", xs,
               (w_gate.astype(xs.dtype), w_in.astype(xs.dtype)), be, used,
-              layer, bm, f, _tile(f, 512), interpret)
+              layer, bm, f, _tile(f, _fits(d, 2, xs.dtype, 512)), interpret)
     return _call(_down_kernel, "moe_experts_down", h,
                  (w_out.astype(xs.dtype),), be, used, layer, bm, d,
-                 _tile(d, 1024), interpret)
+                 _tile(d, _fits(f, 1, xs.dtype, 1024)), interpret)
 
 
 def _up_relu2_kernel(be_ref, used_ref, _, x_ref, w_ref, o_ref):

@@ -196,6 +196,12 @@ class Engine:
                 "a trunk of one mixer a layer (block_pattern) is served, not "
                 "trained here: the chunked scan's backward and the held "
                 "experts' exchange are not written")
+        if getattr(getattr(model, "cfg", None), "attn_pattern", ""):
+            raise ValueError(
+                "window layers beside full ones (attn_pattern) are served, "
+                "not trained here: the blocked attention has no backward "
+                "that skips the blocks outside a window, and the held "
+                "experts' exchange is not written")
         mcfg = self.config.moe
         if mcfg.enabled:
             # ds_config moe section overrides the model's MoE knobs

@@ -46,6 +46,7 @@ from ..observability import spans as _spans
 from ..observability.export import request_record
 from ..observability.metrics import get_registry
 from ..observability.tracing import ServingStats
+from ..models.windowed import KEY_BLOCK
 from ..ops.decode_attention import LANES
 from ..resilience.chaos import ChaosMonkey
 from ..resilience.guards import QueueFullError, RequestStatus
@@ -142,8 +143,9 @@ class ServingEngine:
             and getattr(mcfg, "moe_router", "") == "sigmoid" \
             and any(kind == "moe" for kind, _ in mcfg.segments)
         self._hybrid = bool(getattr(mcfg, "block_pattern", ""))
+        self._windowed = bool(getattr(mcfg, "attn_pattern", ""))
         if (self._latent or getattr(mcfg, "moe_router", "") == "sigmoid") \
-                and not self._hybrid:       # (its own list is below)
+                and not (self._hybrid or self._windowed):   # (own lists below)
             refused = [name for name, on in (
                 ("the paged pool (page_size)", self.cfg.page_size > 0),
                 ("an int8 KV cache (kv_quant_bits)",
@@ -185,6 +187,37 @@ class ServingEngine:
                     f"{mcfg.block_pattern!r}) does not yet compose with "
                     + "; ".join(refused))
             self._moe_stats = "E" in mcfg.block_pattern
+        # window layers beside full ones (models/windowed.py attn_pattern):
+        # planes for the full layers beside a ring a slot for each window
+        # layer, keys wider than values, some of every expert layer's
+        # experts held here
+        if self._windowed:
+            refused = [why for why, on in (
+                ("the paged pool and prefix sharing (page_size): a page "
+                 "holds one K/V width for every layer, and a ring has no "
+                 "pages; a shared prefix would need the rings as they stood "
+                 "at the prefix's end", self.cfg.page_size > 0),
+                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
+                 "pool", bool(self.cfg.kv_quant_bits)),
+                ("speculation: a rejected draft would have to take its "
+                 "columns back out of the rings, and the model's own "
+                 "drafting layers (MTP) are not held",
+                 self.cfg.speculation is not None
+                 and self.cfg.speculation.enabled),
+                ("tiered / host KV (host_pool_bytes): it moves pages",
+                 self.cfg.host_pool_bytes > 0),
+                ("weight-only quantization: the two kinds' projections "
+                 "take dense weights", bool(engine.config.quantize)),
+                ("a mesh of several devices: the ring kernel has no "
+                 "shard_map rule and the experts held are told by the "
+                 "configuration, no axis exchanges rows yet",
+                 engine.mesh.size > 1)) if on]
+            if refused:
+                raise ValueError(
+                    f"window layers beside full ones (attn_pattern="
+                    f"{mcfg.attn_pattern!r}) do not yet compose with "
+                    + "; ".join(refused))
+            self._moe_stats = any(kind == "moe" for kind, _ in mcfg.segments)
         # a looped trunk (models/transformer.py loop_steps): its passes'
         # n_layer x loop_steps cache planes are contiguous bf16/fp only
         self._loops = int(getattr(mcfg, "loop_steps", 1))
@@ -226,9 +259,11 @@ class ServingEngine:
         self._chunk_routing: list = []   # (rid, start, device routing, real)
         self._cache_bytes_per_token = cache_bytes_per_token(
             mcfg, engine.compute_dtype) \
-            if self._latent or self._hybrid or self._loops > 1 else None
+            if self._latent or self._hybrid or self._windowed \
+            or self._loops > 1 else None
         self._state_bytes_per_slot = state_bytes_per_slot(
-            mcfg, engine.compute_dtype) if self._hybrid else 0
+            mcfg, engine.compute_dtype) \
+            if self._hybrid or self._windowed else 0
         if self._loops > 1:
             # what a looped program reads of the weights, from the served
             # tree's shapes: the layers once a pass, and the head (with the
@@ -521,7 +556,7 @@ class ServingEngine:
                                total_deadline_s=self.cfg.total_deadline_s,
                                spans=self.spans, pages=self.pool,
                                rid_source=rid_source,
-                               recurrent=self._hybrid)
+                               recurrent=self._hybrid or self._windowed)
         self._programs: OrderedDict = \
             programs if programs is not None else OrderedDict()
         # disaggregated-serving hook (serving/fleet.py): a side-effecting
@@ -1102,6 +1137,8 @@ class ServingEngine:
         and multiplied like any other."""
         if self._hybrid:
             return self._hybrid_counts(moe, pending)
+        if self._windowed:
+            return self._windowed_counts(moe, pending)
         if not moe:          # no expert trunk, or the chaos build's step
             return {}
         k = self.model.cfg.moe_top_k
@@ -1136,21 +1173,70 @@ class ServingEngine:
         ``ssm_state_step`` nothing is no field here: the host could only
         assert it. ``benchmark/kinds/backlog_hybrid.py`` holds the idle
         slots' state to bit-equality on every run.)"""
-        meta = self._hybrid_meta()
+        return {**self._hybrid_meta(), **self._held_counts(moe, pending)}
+
+    def _held_counts(self, moe: list, pending: list) -> dict:
+        """Of a step whose expert layers hold a share (counters of one row
+        a layer: most rows a held expert got, held experts touched, rows
+        multiplied, rows that chose a held expert): ``held_rows`` and
+        ``experts_touched`` (means over the layers), ``held_rows_share`` of
+        the slots x k rows routed, the load over the held experts; the
+        chunks' first two go onto their own ``prefill_chunk`` spans. {}
+        where the step brought no counters."""
         if not moe:
-            return meta
+            return {}
         k, held = self.model.cfg.moe_top_k, self.model.cfg.held_experts
         for (chunk_span, _, size), st in zip(pending, moe[1:]):
             chunk_span.amend(held_rows=float(st[:, 3].mean()),
                              experts_touched=float(st[:, 1].mean()))
         st = moe[0]
         held_rows = float(st[:, 3].mean())
-        rows = max(held_rows, 1.0)
-        meta.update(
+        return dict(
             held_rows=held_rows,
             held_rows_share=held_rows / (self.cfg.slots * k),
             experts_touched=float(st[:, 1].mean()),
-            moe_load_max_over_mean=float(st[:, 0].max() * held / rows))
+            moe_load_max_over_mean=float(
+                st[:, 0].max() * held / max(held_rows, 1.0)))
+
+    def _windowed_meta(self, chunk=None) -> dict:
+        """What every ``decode_step`` and ``prefill_chunk`` span of an
+        ``attn_pattern`` trunk says beside its times: what a cached token
+        costs (the full layers' planes alone) and what a slot's rings cost
+        whatever its length (``cache_layout()``, ``state_layout()``). A
+        chunk's also ``key_blocks_walked_over_live``: the key blocks
+        (``windowed.KEY_BLOCK``) a full layer's queries walk (every block up to the chunk's end, for
+        every row of the chunk) over those that hold a key some row of the
+        chunk may see — 1: the walk stops at the live length."""
+        meta = {"cache_bytes_per_token": self._cache_bytes_per_token,
+                "window_bytes_per_slot": self._state_bytes_per_slot}
+        if chunk is not None:
+            walked = -(-(chunk.start + chunk.size) // KEY_BLOCK)
+            real = chunk.last_index + 1 if chunk.final else chunk.size
+            meta["key_blocks_walked_over_live"] = \
+                walked / -(-(chunk.start + real) // KEY_BLOCK)
+        return meta
+
+    def _windowed_counts(self, moe: list, pending: list) -> dict:
+        """Meta of an ``attn_pattern`` trunk's ``decode_step`` span:
+        :meth:`_windowed_meta`; ``window_fetched_over_live`` (the positions
+        the window layers' kernel fetches — the one or two ring blocks of
+        128 that hold a running slot's last ``window`` positions — over the
+        positions inside the running slots' windows: 1 ideal, 2 with both
+        ring blocks, length / 128 if a full-length plane were read); and of
+        the step's expert layers (``MoETransformerLM.experts``' counters,
+        one row a layer) what :meth:`_hybrid_counts` says of a held
+        share."""
+        meta = self._windowed_meta()
+        if self._slot_len is not None:
+            n = self._slot_len[self._slot_len > 0]
+            w = self.model.cfg.window
+            blocks = -(-n // LANES) - np.maximum(n - w, 0) // LANES
+            inside = int(np.minimum(n, w).sum())
+            # the lengths the kernels' rooflines are reckoned from
+            meta.update(live_positions=int(n.sum()), window_live=inside,
+                        window_fetched_over_live=float(
+                            LANES * blocks.sum() / max(inside, 1)))
+        meta.update(self._held_counts(moe, pending))
         return meta
 
     def _loop_meta(self, tokens: int, head: bool = True) -> dict:
@@ -1605,6 +1691,7 @@ class ServingEngine:
                                            else ch.size, head=ch.final)
                            if self._loops > 1 else {}),
                         **(self._hybrid_meta() if self._hybrid else {}),
+                        **(self._windowed_meta(ch) if self._windowed else {}),
                         **self.sched._attempt_meta(req)) as chunk_span:
             ids = ch.ids[None]
             if not ch.final:

@@ -5,7 +5,9 @@ state. TPU-native translation: instead of a paged block table (dynamic
 indirection is hostile to XLA's static shapes), the serving state is ONE
 ``(L, slots, KV, hd, max_len)`` cache (``(L, slots, rank + rope, max_len)``
 for latent attention; for a trunk of one mixer a layer K/V planes for its
-attention layers only beside a recurrent state a slot, ``HybridCache``) —
+attention layers only beside a recurrent state a slot, ``HybridCache``; for
+window layers beside full ones planes for the full layers beside a ring a
+slot for each window layer, ``WindowedCache``) —
 the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
 positions on the lanes, so the buffer is compact in HBM at any head size
 and the decode step's kernel appends to it and reads it where it lies
@@ -83,7 +85,8 @@ def insert_request(state: GenCarry, slot, pf: GenCarry,
     before the new occupant's first decode step."""
     kc = state.cache
     # every buffer of the cache (K and V; the latents; a recurrent state
-    # beside K/V) has the slot second
+    # or the window layers' rings beside K/V) has the slot second: a
+    # successor never reads its predecessor's ring
     buffers = {
         name: lax.dynamic_update_slice(
             buf, getattr(pf.cache, name).astype(buf.dtype),

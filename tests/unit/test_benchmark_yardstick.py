@@ -34,10 +34,12 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_reducers", "benchmark.tests.test_traffic",
     "benchmark.tests.test_window", "benchmark.tests.test_deepseek_v3",
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
+    "benchmark.tests.test_mimo_v2_flash",
     "benchmark.tests.test_program_lifecycle")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
+from benchmark.tests.test_mimo_v2_flash import *  # noqa: E402,F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ouro import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_lifecycle import *  # noqa: E402,F401,F403

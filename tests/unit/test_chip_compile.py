@@ -698,3 +698,106 @@ def test_hybrid_trunk_keeps_the_state_in_place(one_chip, monkeypatch,
         "ssm_state_step": 5 if batch > 1 else 0,
         "latent_experts_up": experts, "latent_experts_down": experts,
         "decode_attention": 1 if batch > 1 else 0, "moe_experts_up": 0}, count
+
+
+# ------------------------------------- window layers beside full ones
+@pytest.mark.parametrize("program", ["slot step", "chunk", "final chunk"])
+def test_windowed_trunk_keeps_planes_and_rings_in_place(one_chip, monkeypatch,
+                                                        program, capsys):
+    """MiMo-V2-Flash's share (layers GSSSSGS, 16 of 256 experts,
+    ``benchmark/configs/mimo-v2-flash-l7-e16.json``) at the cell's 32 slots
+    x 32 768, chunks of 512: the cache's four buffers enter donated and
+    leave aliased; the decode kernel lowers for the chip with K blocks of
+    192 beside V blocks of 128 under its two new names; a chunk's attention
+    stands nowhere as a (64, 512, 32 768) score tensor (4.3 GB: temporaries
+    stay under 1.5 GiB); the step's live set leaves the chip room beside the
+    batch-1 prefill cache."""
+    import json
+    import time
+
+    from benchmark.models import mimo_v2_flash as fam
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 32, 32768, 512
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2-flash-l7-e16.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+    keep = set(model.fp32_param_names())
+
+    def served(path, a):
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype if name in keep else jnp.bfloat16,
+            sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = jax.tree_util.tree_map_with_path(
+        served, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, slots, max_len, jnp.bfloat16)))
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        batch = slots
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, max_len, jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True)[final ^ 1:],
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        batch = 1
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[windowed {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB]")
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 5120
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 6553600
+    held = batch * (6553600 + max_len * 5120)
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    # (the step's: XLA stages one buffer of rings, 84 MB, through VMEM)
+    assert mem.temp_size_in_bytes < (128 if batch > 1 else 1536) * 2 ** 20, \
+        mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    prefill_cache = 6553600 + max_len * 5120
+    assert live + (prefill_cache if batch > 1 else 0) \
+        < (15.75 - 1.0) * 2 ** 30, live
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "full_decode_attention", "window_decode_attention",
+        "decode_attention", "moe_experts_up")}
+    # one call a run of layers (G | SSSS | G | S), the experts' three runs
+    # (a chunk with no head drops its last layer's experts: nothing reads
+    # them; its attention stays, for the ring it writes)
+    assert count == {
+        "full_decode_attention": 2 if batch > 1 else 0,
+        "window_decode_attention": 2 if batch > 1 else 0,
+        "decode_attention": 0,
+        "moe_experts_up": 2 if program == "chunk" else 3}, count
+    # no copy of a projection's weights re-laid out for heads of 192
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if " copy(" in ln and "4096,12288]{1,2,0" in ln]

@@ -89,7 +89,9 @@ def test_chunked_prefill_then_slot_decode_against_the_reference(tiny, flash):
                 with_stats=True)
             np.testing.assert_allclose(np.asarray(lg[1, 0]), want[P + t],
                                        atol=2e-4)
-        assert stats.shape == (2, 3) and float(stats[:, 2].min()) >= 6
+        assert stats.shape == (2, 4) and float(stats[:, 2].min()) >= 6
+        # every expert is held here: the held rows are the rows routed
+        assert float(stats[:, 3].min()) == 3 * cfg.moe_top_k
 
 
 def test_absorbed_step_equals_the_expanded_path_on_the_same_cache(tiny):
