@@ -7,11 +7,32 @@ into XLA fusion and the one kernel worth hand-writing is blockwise attention.
 
 Design (standard flash attention 2, MXU-shaped):
 - forward: grid (B, H, S/blk); per q-block online-softmax stream over k/v
-  blocks (``fori_loop`` with a traced causal upper bound), accumulators in
-  fp32 carries, saves per-row logsumexp for the backward.
+  blocks, accumulators in fp32 carries, saves per-row logsumexp for the
+  backward.
 - backward: two kernels — dq (grid over q blocks, streams k/v) and dk/dv
   (grid over k blocks, streams q/dO), both recomputing probabilities from
   the saved logsumexp; ``delta = rowsum(dO * O)`` precomputed outside.
+- the tile schedule (PR 43; times are device ms a call at (16, 20, 1024, 64)
+  bf16 on a v5e, PERF.md "PR 43"): under a causal mask a program's loop
+  runs over the tiles UNDER the diagonal, which take no mask arithmetic at
+  all (no iota, compare or select, and no second select behind the exp:
+  every row keeps its own position), and the tile the diagonal crosses is a
+  statically shaped step of its own. The forward (1.55 -> 0.91) and the
+  dk/dv kernel (2.03 -> 1.52) hold a score tile KEYS FIRST, (keys, queries):
+  the forward's running max / sum / correction are then (1, blk) rows on
+  the lanes (4 registers an op where a (blk, 1) column takes 64) and its
+  reductions run down the sublanes; in dk/dv lse and delta broadcast down
+  the sublanes as stored and all four products are NN / NT forms (no
+  (blk, blk) transpose). Both backward kernels (dq 1.30 -> 1.14) cut the
+  diagonal tile into strips of 128 queries, each against the keys up to its
+  own, so only the strip's last (128, 128) square is masked and what lies
+  over the diagonal is not computed (``scores_computed_over_needed``: 1.50
+  -> 1.12 at S 1024 / block 512). The forward keeps its diagonal tile
+  whole: every strip form lost there (0.91 whole; 1.04-1.38 in strips by
+  keys or by queries, 128 or 256 wide). 1/sqrt(hd) is folded into the
+  operand a program holds for all its tiles (q; k in dk/dv) where that is
+  exact, a power of two. A key mask keeps its select on every tile, a bias
+  or ALiBi ramp is added on every tile.
 - residuals: the one ``custom_vjp`` takes and returns the model's
   (B, S, H, hd) layout and names what it saves beside q/k/v — ``flash_o``
   as (B, S, H*hd) and ``flash_lse`` as one (B, H, S) row — so a remat
@@ -21,7 +42,7 @@ Design (standard flash attention 2, MXU-shaped):
 - dtype: matmul OPERANDS stay in their storage dtype (bf16 runs the MXU
   at full rate; pre-casting to f32 forces multi-pass emulation — round-5
   profile finding) with fp32 accumulation (``preferred_element_type``);
-  softmax math in fp32; the 1/√hd scale applies to the f32 product.
+  softmax math in fp32.
 
 On non-TPU backends the kernels run in Pallas interpret mode (tests), and
 inputs that the kernel doesn't cover (padding masks, non-divisible shapes)
@@ -46,6 +67,92 @@ SUBLANES = 8  # fp32 sublane tile: lse/delta rows replicated to (8, S)
 # fallback notices warn once per process via utils.logging.warning_once
 
 
+# ------------------------------------------------------------ tile helpers
+def _dot_nt(a, b):
+    """a (m, d) against b (n, d) over d: (m, n) in float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _fold_scale(x, scale):
+    """The 1/sqrt(hd) factor of the scores, folded into the operand a program
+    holds for all its tiles where that is exact in any float storage type (a
+    power of two: hd 16, 64, 256), else left for the float32 product.
+    Returns (operand, the factor still to apply or None)."""
+    if math.frexp(scale)[0] == 0.5:
+        return x * jnp.asarray(scale, x.dtype), None
+    return x, scale
+
+
+def _diag_chunk(block: int) -> int:
+    """Width of the strips the backward kernels cut a diagonal tile into: one
+    lane tile where the block is a larger multiple of it, else the block
+    whole (nothing narrower than 128 slices on the lanes)."""
+    return 128 if block > 128 and block % 128 == 0 else block
+
+
+def scores_computed_over_needed(seq: int, block: int, sub=None,
+                                causal: bool = True) -> float:
+    """Score elements a resident kernel computes over the pairs attention
+    needs, S(S+1)/2 under a causal mask: whole tiles under the diagonal, and
+    of each diagonal tile the strips of ``sub`` at or under it (``None``: the
+    tile whole, what the forward kernel computes). 1.50 at (1024, 512), 1.12
+    with strips of 128 (both backward kernels)."""
+    if not causal:
+        return 1.0
+    sub = sub or block
+    nq, n = seq // block, block // sub
+    computed = block * block * nq * (nq - 1) // 2 \
+        + nq * sub * sub * n * (n + 1) // 2
+    return computed / (seq * (seq + 1) // 2)
+
+
+def _tile_scores(s, post, bias_tile, h_slope, q0, k0, keys_first=False):
+    """What is added to a raw float32 product before any mask: the scale
+    where it was not folded, a bias tile, the ALiBi ramp of a tile whose
+    first query is position q0 and first key k0."""
+    if post is not None:
+        s = s * post
+    if bias_tile is not None:
+        s = s + bias_tile.astype(jnp.float32)
+    if h_slope is not None:
+        s = s + h_slope * _alibi_rel(q0, k0, s.shape, keys_first)
+    return s
+
+
+def _mask_tile(s, keys_first: bool, crosses: bool, mk):
+    """Force what a score tile may not see to BIG_NEG before any exp.
+    ``crosses``: the causal diagonal runs through the tile's trailing square
+    (the last keys are the last queries' own; whatever lies before the
+    square is wholly visible). ``mk``: the key mask of the tile's keys,
+    broadcastable to it, or None. Returns (s, keep); keep is None where
+    every row keeps a finite score (its own position), so that exp needs no
+    second select: with no key mask only the square the diagonal crosses is
+    touched, and a tile under the diagonal not at all."""
+    if mk is None and not crosses:
+        return s, None
+    lead, trail = (0, 1) if keys_first else (1, 0)   # keys' axis, queries'
+    before = s.shape[lead] - s.shape[trail]          # keys before the square
+
+    def visible(shape, offset):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, lead) <= \
+            jax.lax.broadcasted_iota(jnp.int32, shape, trail) + offset
+
+    if mk is not None:
+        keep = jnp.broadcast_to(mk, s.shape)
+        if crosses:
+            keep = keep & visible(s.shape, before)
+        return jnp.where(keep, s, BIG_NEG), keep
+    keep = visible((s.shape[trail],) * 2, 0)
+    if keys_first:
+        sq = jnp.where(keep, s[before:], BIG_NEG)
+        s = jnp.concatenate([s[:before], sq], 0) if before else sq
+    else:
+        sq = jnp.where(keep, s[:, before:], BIG_NEG)
+        s = jnp.concatenate([s[:, :before], sq], 1) if before else sq
+    return s, None
+
+
 # ---------------------------------------------------------------- forward
 def _fwd_kernel(*refs, block: int, scale: float, causal: bool, masked: bool,
                 biased: bool, alibi: bool = False):
@@ -62,44 +169,53 @@ def _fwd_kernel(*refs, block: int, scale: float, causal: bool, masked: bool,
     o_ref, lse_ref = refs[i:]
     iq = pl.program_id(2)
     h_slope = slopes_ref[0, 0] if slopes_ref is not None else None
-    q = q_ref[...]                                      # (blk, hd) bf16
+    q, post = _fold_scale(q_ref[...], scale)            # (blk, hd) bf16
+    hd = q.shape[1]
     nkb = k_ref.shape[0] // block
 
-    def body(jk, carry):
+    def step(jk, carry, crosses=False):
+        # the tile is held keys first, (blk keys, blk queries): the running
+        # max, sum and correction are then (1, blk) rows on the lanes, 4
+        # registers an op where a (blk, 1) column takes 64, and the two
+        # reductions run down the sublanes on the VPU
         m, l, acc = carry
-        k = k_ref[pl.ds(jk * block, block), :]
-        v = v_ref[pl.ds(jk * block, block), :]
+        keys = pl.ds(pl.multiple_of(jk * block, block), block)
+        k, v = k_ref[keys, :], v_ref[keys, :]
         # additive score bias tile (blk, blk), streamed from the (blk, S)
         # row slice this q-block owns — never a full (S, S)
-        # materialization; key-padding mask row for this k block
-        bias_tile = (bias_ref[:, pl.ds(jk * block, block)]
-                     if bias_ref is not None else None)
-        mk = (mask_ref[0, pl.ds(jk * block, block)] > 0.5
+        # materialization; key-padding mask of this k block
+        s = _tile_scores(
+            _dot_nt(k, q), post,
+            bias_ref[:, keys].astype(jnp.float32).T
+            if bias_ref is not None else None, h_slope,
+            iq * block, jk * block, True)
+        mk = (mask_ref[0, keys][:, None] > 0.5
               if mask_ref is not None else None)
-        s, keep = _masked_scores(q, k, iq, jk, block, causal, mk, h_slope,
-                                 scale=scale, bias_tile=bias_tile)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        s, keep = _mask_tile(s, True, crosses, mk)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
         corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p.astype(v.dtype), v,
-                                   preferred_element_type=jnp.float32)
+        l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # (hd, blk)
         return m_new, l, acc
 
-    m0 = jnp.full((block, 1), BIG_NEG, jnp.float32)
-    l0 = jnp.zeros((block, 1), jnp.float32)
-    acc0 = jnp.zeros(q.shape, jnp.float32)
-    ub = iq + 1 if causal else nkb
-    m, l, acc = jax.lax.fori_loop(0, ub, body, (m0, l0, acc0))
+    carry = (jnp.full((1, block), BIG_NEG, jnp.float32),
+             jnp.zeros((1, block), jnp.float32),
+             jnp.zeros((hd, block), jnp.float32))
+    # the tiles under the diagonal carry no mask arithmetic at all; the one
+    # the diagonal crosses is a step of its own behind the loop
+    carry = jax.lax.fori_loop(0, iq if causal else nkb, step, carry)
+    m, l, acc = step(iq, carry, True) if causal else carry
     # l == 0 only for rows whose keys are ALL masked (e.g. left-padded
     # queries); clamp so o is 0, not NaN (their loss contribution is masked)
     l_safe = jnp.maximum(l, jnp.float32(1e-30))
-    o_ref[...] = (acc / l_safe).astype(o_ref.dtype)
+    o_ref[...] = (acc / l_safe).T.astype(o_ref.dtype)
     # (8, blk): replicated across sublanes to satisfy TPU (8, 128) tiling
-    lse_ref[...] = jnp.broadcast_to((m[:, 0] + jnp.log(l_safe[:, 0]))[None, :],
-                                    (SUBLANES, block))
+    lse_ref[...] = jnp.broadcast_to(m + jnp.log(l_safe), (SUBLANES, block))
 
 
 def _mask_operand(mask, S):
@@ -108,35 +224,34 @@ def _mask_operand(mask, S):
     return jnp.broadcast_to(m, (mask.shape[0], SUBLANES, S))
 
 
-def _alibi_rel(iq, jk, block):
-    """(blk, blk) signed key−query distance for q block iq vs k block jk —
-    the ALiBi ramp built IN-kernel, so long sequences never materialize an
-    (H, S, S) bias operand (at 64k seq that operand alone would be 100+
-    GB; the decode kernel does the same from the live length)."""
-    q_pos = iq * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
-    k_pos = jk * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+def _alibi_rel(q0, k0, shape, keys_first: bool = False):
+    """Signed key−query distance of a score tile whose first query is
+    position q0 and first key k0 — the ALiBi ramp built IN-kernel, so long
+    sequences never materialize an (H, S, S) bias operand (at 64k seq that
+    operand alone would be 100+ GB; the decode kernel does the same from
+    the live length)."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, int(keys_first))
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                          int(not keys_first))
     return (k_pos - q_pos).astype(jnp.float32)
 
 
-def _masked_scores(q, k, iq, jk, block, causal, mk, h_slope, *, scale,
-                   bias_tile=None):
-    """Shared (blk, blk) score tile for ALL six kernels (baseline and
-    streamed, fwd and bwd): s = scale·q·kᵀ (+bias tile) (+ALiBi ramp),
-    with causal / key-padding positions forced to BIG_NEG BEFORE any exp
-    (for all-masked rows lse ~ BIG_NEG and a raw exp(s − lse) would
-    overflow to inf — the round-4 fix, now in exactly one place).
+def _masked_scores(q, k, iq, jk, block, causal, mk, h_slope, *, scale):
+    """The (blk, blk) score tile of the three streamed kernels, whose grid
+    step cannot tell a diagonal tile from one under it: s = scale·q·kᵀ
+    (+ALiBi ramp), with causal / key-padding positions forced to BIG_NEG
+    BEFORE any exp (for all-masked rows lse ~ BIG_NEG and a raw
+    exp(s − lse) would overflow to inf — the round-4 fix).
 
     q/k arrive in their STORAGE dtype (bf16 in practice): the MXU runs
     bf16×bf16→f32 at full rate but emulates f32×f32 matmuls in multiple
     passes — pre-casting operands to f32 (the round-5 profile's finding)
-    halves attention-matmul throughput. The 1/√hd scale therefore applies
-    to the f32 product, not the operands (also exact for any hd). Returns
-    (s, keep) where keep is None when nothing is masked."""
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if bias_tile is not None:
-        s = s + bias_tile.astype(jnp.float32)
+    halves attention-matmul throughput. Returns (s, keep) where keep is
+    None when nothing is masked."""
+    s = _dot_nt(q, k) * scale
     if h_slope is not None:
-        s = s + h_slope * _alibi_rel(iq, jk, block)
+        s = s + h_slope * _alibi_rel(iq * block, jk * block,
+                                     (block, block))
     keep = None
     if causal:
         q_pos = iq * block + jax.lax.broadcasted_iota(
@@ -153,7 +268,7 @@ def _masked_scores(q, k, iq, jk, block, causal, mk, h_slope, *, scale,
 
 def _probs_from_lse(s, keep, lse):
     """Backward-pass probabilities recomputed from the saved logsumexp,
-    masked positions zeroed — shared by all four backward kernels."""
+    masked positions zeroed — shared by the streamed backward kernels."""
     p = jnp.exp(s - lse[:, None])
     return jnp.where(keep, p, 0.0) if keep is not None else p
 
@@ -236,6 +351,7 @@ def _fwd_call(q, k, v, mask, bias, *, block: int, causal: bool,
 def _make_bwd_dq_kernel(block: int, scale: float, causal: bool, masked: bool,
                         biased: bool = False, grad_bias: bool = False,
                         alibi: bool = False):
+    sub = _diag_chunk(block)
 
     def kernel(*refs):
         refs = list(refs)
@@ -252,39 +368,59 @@ def _make_bwd_dq_kernel(block: int, scale: float, causal: bool, masked: bool,
         dq_ref = refs[i]; i += 1
         if grad_bias:
             dbias_ref = refs[i]
-            # causal bias rows never visit jk > iq: zero-fill so the
-            # untouched upper triangle doesn't carry garbage
+            # causal bias rows never visit what lies over the diagonal:
+            # zero-fill so the untouched part doesn't carry garbage
             dbias_ref[...] = jnp.zeros(dbias_ref.shape, dbias_ref.dtype)
         iq = pl.program_id(2)
-        q = q_ref[...]                                   # storage dtype
+        q, post = _fold_scale(q_ref[...], scale)         # storage dtype
         do = do_ref[...]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
         nkb = k_ref.shape[0] // block
 
-        def body(jk, dq):
-            k = k_ref[pl.ds(jk * block, block), :]
-            v = v_ref[pl.ds(jk * block, block), :]
-            bias_tile = (bias_ref[:, pl.ds(jk * block, block)]
-                         if bias_ref is not None else None)
-            mk = (mask_ref[0, pl.ds(jk * block, block)] > 0.5
+        def tile(rows, k0, n, lse, delta, crosses=False):
+            """dq of the block's query ``rows`` (a static slice) from the
+            ``n`` keys at ``k0``; lse / delta their (len(rows), 1) columns."""
+            keys = pl.ds(k0, n)
+            k, v = k_ref[keys, :], v_ref[keys, :]
+            s = _tile_scores(
+                _dot_nt(q[rows], k), post,
+                bias_ref[rows, keys] if bias_ref is not None else None,
+                h_slope, iq * block + rows.start, k0)
+            mk = (mask_ref[0:1, keys] > 0.5
                   if mask_ref is not None else None)
-            s, keep = _masked_scores(q, k, iq, jk, block, causal, mk,
-                                     h_slope, scale=scale,
-                                     bias_tile=bias_tile)
-            p = _probs_from_lse(s, keep, lse)
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None])
+            s, keep = _mask_tile(s, False, crosses, mk)
+            p = jnp.exp(s - lse)
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
+            ds = p * (_dot_nt(do[rows], v) - delta)
             if dbias_ref is not None:
-                # d(bias) == d(scores): each (iq, jk) tile is owned by
-                # exactly one grid step, so this is a plain write
-                dbias_ref[:, pl.ds(jk * block, block)] = ds.astype(
-                    dbias_ref.dtype)
-            return dq + jnp.dot(ds.astype(k.dtype), k,
-                                preferred_element_type=jnp.float32)
+                # d(bias) == d(scores): each element is owned by exactly
+                # one grid step, so this is a plain write
+                dbias_ref[rows, keys] = ds.astype(dbias_ref.dtype)
+            return jnp.dot(ds.astype(k.dtype), k,
+                           preferred_element_type=jnp.float32)
 
-        ub = iq + 1 if causal else nkb
-        dq = jax.lax.fori_loop(0, ub, body, jnp.zeros(q.shape, jnp.float32))
+        whole = slice(0, block)
+
+        def body(jk, dq):
+            # the (blk,) lane rows become columns tile by tile: held as
+            # (blk, 1) columns across the loop they cost a quarter more
+            return dq + tile(whole, pl.multiple_of(jk * block, block), block,
+                             lse_ref[0][:, None], delta_ref[0][:, None])
+
+        dq = jax.lax.fori_loop(0, iq if causal else nkb, body,
+                               jnp.zeros(q.shape, jnp.float32))
+        if causal:
+            # the diagonal tile in strips of `sub` query rows, each against
+            # the keys up to its own: what lies over the diagonal is not
+            # computed, and only a strip's last square is masked
+            k0 = pl.multiple_of(iq * block, block)
+            lse, delta = lse_ref[0][:, None], delta_ref[0][:, None]
+            strips = []
+            for r in range(0, block, sub):
+                rows = slice(r, r + sub)
+                strips.append(dq[rows] + tile(rows, k0, r + sub, lse[rows],
+                                              delta[rows], True))
+            dq = jnp.concatenate(strips, 0)
         dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
     return kernel
@@ -292,6 +428,8 @@ def _make_bwd_dq_kernel(block: int, scale: float, causal: bool, masked: bool,
 
 def _make_bwd_dkv_kernel(block: int, scale: float, causal: bool, masked: bool,
                          biased: bool = False, alibi: bool = False):
+    sub = _diag_chunk(block)
+
     def kernel(*refs):
         refs = list(refs)
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
@@ -306,39 +444,57 @@ def _make_bwd_dkv_kernel(block: int, scale: float, causal: bool, masked: bool,
         dk_ref, dv_ref = refs[i:]
         h_slope = slopes_ref[0, 0] if slopes_ref is not None else None
         jk = pl.program_id(2)
-        k = k_ref[...]                                   # (blk, hd) storage
+        k, post = _fold_scale(k_ref[...], scale)         # (blk, hd) storage
         v = v_ref[...]
         nqb = q_ref.shape[0] // block
         mk = None
-        if mask_ref is not None:
-            mk = mask_ref[0, pl.ds(jk * block, block)] > 0.5  # this k block
+        if mask_ref is not None:                         # this k block's
+            mk = mask_ref[0, pl.ds(jk * block, block)][:, None] > 0.5
 
-        def body(iq, carry):
-            dk, dv = carry
-            q = q_ref[pl.ds(iq * block, block), :]
-            do = do_ref[pl.ds(iq * block, block), :]
-            lse = lse_ref[0, pl.ds(iq * block, block)]
-            delta = delta_ref[0, pl.ds(iq * block, block)]
-            # (S, blk) column slice of the bias: rows iq-block
-            bias_tile = (bias_ref[pl.ds(iq * block, block), :]
-                         if bias_ref is not None else None)
-            s, keep = _masked_scores(q, k, iq, jk, block, causal, mk,
-                                     h_slope, scale=scale,
-                                     bias_tile=bias_tile)
-            p = _probs_from_lse(s, keep, lse)
-            dv = dv + jnp.dot(p.astype(do.dtype).T, do,
-                              preferred_element_type=jnp.float32)
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None])
-            dk = dk + jnp.dot(ds.astype(q.dtype).T, q,
-                              preferred_element_type=jnp.float32)
+        def tile(q0, w, n, crosses=False):
+            """(dk, dv) of the block's first ``n`` keys from the ``w``
+            queries at ``q0``. The tile is held keys first, (n, w): lse and
+            delta broadcast down the sublanes as they are stored, and all
+            four products are plain NN / NT forms — no (blk, blk) transpose."""
+            qs = pl.ds(q0, w)
+            q, do = q_ref[qs, :], do_ref[qs, :]
+            # (S, blk) column slice of the bias: rows are the queries
+            s = _tile_scores(
+                _dot_nt(k[:n], q), post,
+                bias_ref[qs, :n].astype(jnp.float32).T
+                if bias_ref is not None else None, h_slope,
+                q0, jk * block, True)
+            s, keep = _mask_tile(s, True, crosses,
+                                 mk[:n] if mk is not None else None)
+            p = jnp.exp(s - lse_ref[0:1, qs])
+            if keep is not None:
+                p = jnp.where(keep, p, 0.0)
+            dv = jnp.dot(p.astype(do.dtype), do,
+                         preferred_element_type=jnp.float32)
+            ds = p * (_dot_nt(v[:n], do) - delta_ref[0:1, qs])
+            dk = jnp.dot(ds.astype(q.dtype), q,
+                         preferred_element_type=jnp.float32)
             return dk, dv
 
-        lb = jk if causal else 0
+        def body(iq, carry):
+            dk, dv = tile(pl.multiple_of(iq * block, block), block, block)
+            return carry[0] + dk, carry[1] + dv
+
         z = jnp.zeros(k.shape, jnp.float32)
-        dk, dv = jax.lax.fori_loop(lb, nqb, body, (z, z))
+        dk, dv = z, z
+        if causal:
+            # the diagonal tile in strips of `sub` queries, each against
+            # the keys up to its own (see the dq kernel)
+            for r in range(0, block, sub):
+                n = r + sub
+                a, b = tile(pl.multiple_of(jk * block + r, sub), sub, n, True)
+                dk, dv = (x + y if n == block else
+                          jnp.concatenate([x[:n] + y, x[n:]], 0)
+                          for x, y in ((dk, a), (dv, b)))
+        dk, dv = jax.lax.fori_loop(jk + 1 if causal else 0, nqb, body,
+                                   (dk, dv))
         # dk accumulated against UNSCALED q: apply the 1/√hd chain-rule
-        # factor once at the end (q used to arrive pre-scaled)
+        # factor once at the end
         dk_ref[...] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -824,10 +980,13 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
     seq would be 100+ GB; slopes cost H floats). Mutually exclusive with
     ``bias``.
 
-    ``block`` default 512 (round-5 A/B on a v5e, 1B decoder seq 1024:
-    block 128 → 421.5 ms/step, 256 → 334.9, 512 → 305.5 — wider tiles
-    feed the MXU 512-wide dots and cut the kv-loop trips 4×; a (512,
-    512) f32 score tile is ~1 MiB of VMEM, comfortably under budget).
+    ``block`` default 512. The three kernels' device ms a call at
+    (16, 20, 1024, 64) bf16 on a v5e with this schedule (PERF.md "PR 43"):
+    block 128 → 4.33 / 3.86 / 4.09 (fwd / dq / dkv), 256 → 1.82 / 1.74 /
+    2.49, 512 → 0.91 / 1.14 / 1.52 — wider tiles feed the MXU 512-wide
+    dots and cut the loop trips 4×; a (512, 512) f32 score tile is 1 MiB of
+    VMEM. What a wide diagonal tile wastes over the diagonal the backward
+    kernels cut away in strips of 128 inside (``_diag_chunk``; no argument).
     Shapes not divisible by the block clamp it to S (single tile), then
     shrink toward the largest power-of-two divisor of S ≥ 128 (512 → 256
     → 128, one-shot warning) so S = 768/1152/1920 stay fused.
@@ -845,7 +1004,7 @@ def flash_attention(q, k, v, *, mask: Optional[jnp.ndarray] = None,
         # and must stay fused — the dense fallback materializes
         # (B, H, S, S) scores. Candidates derive from blk (a 1024 caller
         # block still tries 512 first), wider-first because wider tiles
-        # feed the MXU better (the 512-vs-256 A/B in the docstring).
+        # feed the MXU better (the block sweep in the docstring).
         cand = blk // 2
         while cand >= 128:
             if S % cand == 0:

@@ -70,13 +70,32 @@ def _compile(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "kernel absent from the compiled text"
+    return text
 
 
-@pytest.mark.parametrize("width", WIDTHS)
+def _flash_kernels(text):
+    """The flash kernels among the compiled text's Mosaic calls, by the
+    name their ``pallas_call`` gave them, sorted: one entry an instruction."""
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    return sorted(m.group(1) for m in (
+        re.search(r"%\w*?(flash_attention_(?:fwd|bwd_dq|bwd_dkv))[_.\d]* = ",
+                  ln) for ln in calls) if m)
+
+
+GPT2_1_5B = ("gpt2-1.5b", 1600, 25, 25, 64, 6400, 50257)
+# the micro-batch of 16 a chip of both train cells; the kernels lower for
+# real (no interpreter) at (16, 20 | 25, 1024, 64)
+FLASH_SHAPES = [pytest.param(GPT2_774M, 16, id="gpt2-774m-cell"),
+                pytest.param(GPT2_1_5B, 16, id="gpt2-1.5b-cell"),
+                pytest.param(LLAMA2_7B, 2, id="llama2-7b")]
+
+
+@pytest.mark.parametrize("width,batch", FLASH_SHAPES)
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
-def test_flash_attention(one_chip, width, grad):
+def test_flash_attention(one_chip, width, batch, grad):
     _, _, H, KV, hd, _, _ = width
-    qkv = ((2, SEQ, H, hd), jnp.bfloat16)
+    qkv = ((batch, SEQ, H, hd), jnp.bfloat16)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=False)
@@ -85,7 +104,10 @@ def test_flash_attention(one_chip, width, grad):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    _compile(fn, one_chip, qkv, qkv, qkv)
+    text = _compile(fn, one_chip, qkv, qkv, qkv)
+    assert _flash_kernels(text) == (
+        ["flash_attention_bwd_dkv", "flash_attention_bwd_dq"] if grad
+        else []) + ["flash_attention_fwd"]
 
 
 def _trunk_grad(model, policy):
@@ -135,27 +157,31 @@ def _stacked(text, L, B):
     return out
 
 
+@pytest.mark.parametrize("size", ["774m", "1.5b"])
 @pytest.mark.parametrize("chips", [1, 4], ids=["one-chip", "2x2-data4"])
-def test_save_names_keeps_the_flash_kernels_own_residuals(topo, chips):
-    """The gradient of a scanned trunk at GPT-2 774M width, seq 1024, under
-    save_names, the micro-batch of 16 a chip of the train cells: ONE
-    ``flash_attention_fwd`` in the compiled module (two before the kernel
-    named its residuals: the remat ran it again for o and lse), and what
-    the scan stacks for the backward is the layer's input, the kernel's o
-    as (B, S, H*hd) with no lane of padding, and lse as one (B, H, S) row —
-    not the kernel's (B, H, S, 64) padded to 128 lanes, nor its eight equal
-    sublanes of lse. Eight layers: a scan compiles one body whatever its
-    length, and eight rows fill a tile whichever way the compiler lays the
-    stack. On the 2x2 host the batch is split over ``data`` and the kernel
-    runs in a ``shard_map``: the names inside it are kept all the same."""
+def test_save_names_keeps_the_flash_kernels_own_residuals(topo, chips, size):
+    """The gradient of a scanned trunk at the two train cells' widths (GPT-2
+    774M: 20 heads; 1.5B: 25 heads, a width off the lanes), seq 1024, under
+    save_names, the micro-batch of 16 a chip of the train cells: exactly the
+    three flash kernels, ONE instruction each, in the compiled module (two
+    ``flash_attention_fwd`` before the kernel named its residuals: the remat
+    ran it again for o and lse). At 774M what the scan stacks for the
+    backward is the layer's input, the kernel's o as (B, S, H*hd) with no
+    lane of padding, and lse as one (B, H, S) row — not the kernel's
+    (B, H, S, 64) padded to 128 lanes, nor its eight equal sublanes of lse
+    (at 1600 the layouts are the compiler's: PERF.md "PR 41"). Eight
+    layers: a scan compiles one body whatever its length, and eight rows
+    fill a tile whichever way the compiler lays the stack. On the 2x2 host
+    the batch is split over ``data`` and the kernel runs in a
+    ``shard_map``: the names inside it are kept all the same."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.ops.flash_attention import make_flash_attention
     from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
 
-    _, D, H, _, hd, _, _ = GPT2_774M
     L, B = 8, 16
-    cfg = gpt2("774m", n_layer=L, max_seq=SEQ, dtype=jnp.bfloat16)
+    cfg = gpt2(size, n_layer=L, max_seq=SEQ, dtype=jnp.bfloat16)
+    D, H = cfg.d_model, cfg.n_head
     model = build_model(cfg, attention_fn=make_flash_attention(
         interpret=False))
     mesh = build_mesh(MeshSpec(data=chips), devices=topo.devices[:chips])
@@ -172,16 +198,14 @@ def test_save_names_keeps_the_flash_kernels_own_residuals(topo, chips):
     with mesh:
         text = jax.jit(_trunk_grad(model, _save_names())).lower(
             x, layers).compile().as_text()
-    calls = [ln for ln in text.splitlines()
-             if "tpu_custom_call" in ln and "custom-call(" in ln]
-    kernels = sorted(re.search(r"%(flash_attention_\w+?)[.\d]* = ", ln).group(1)
-                     for ln in calls)
+    kernels = _flash_kernels(text)
     assert kernels == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
                        "flash_attention_fwd"], kernels
-    assert _stacked(text, L, B) == {
-        ("bf16", (L, B, SEQ, D)): L * B * SEQ * D * 2,   # layer_in, flash_o
-        ("f32", (L, B, H, SEQ)): L * B * H * SEQ * 4,    # flash_lse
-    }
+    if size == "774m":
+        assert _stacked(text, L, B) == {
+            ("bf16", (L, B, SEQ, D)): L * B * SEQ * D * 2,   # layer_in, flash_o
+            ("f32", (L, B, H, SEQ)): L * B * H * SEQ * 4,    # flash_lse
+        }
 
 
 def test_save_names_gradients_equal_no_remat_under_flash():

@@ -96,6 +96,107 @@ def _padded_mask(B, S, lengths):
     return jnp.asarray(m)
 
 
+# ------------------------------------------- the tile schedule (PR 43)
+# The resident kernels split their loop at the diagonal: tiles under it take
+# no mask arithmetic, the forward holds its tiles keys first (row statistics
+# on the lanes), the backward kernels cut the diagonal tile into strips of
+# 128 queries. A strip needs a block over 128, so these run at real widths.
+_SCHEDULES = {"two-q-blocks": (1024, 512), "four-q-blocks": (1024, 256),
+              "clamped-768-to-256": (768, 512)}
+_FEATURES = ("plain", "key_mask", "alibi", "bias_dbias", "gqa")
+_SCHEDULE_CASES = [(sched, feat, "f32", 64) for sched in _SCHEDULES
+                   for feat in _FEATURES] + [
+    # bf16 as the train cells run it; hd 80 / 96: 1/sqrt(hd) is no power of
+    # two, the scale stays a float32 multiply on the product
+    ("two-q-blocks", "plain", "bf16", 64),
+    ("two-q-blocks", "key_mask", "bf16", 64),
+    ("clamped-768-to-256", "gqa", "bf16", 64),
+    ("two-q-blocks", "plain", "f32", 80),
+    ("four-q-blocks", "alibi", "bf16", 80),
+    ("clamped-768-to-256", "bias_dbias", "f32", 96),
+    ("two-q-blocks", "key_mask", "f32", 96),
+]
+
+
+@pytest.mark.parametrize("sched,feature,dtype,hd", _SCHEDULE_CASES)
+def test_tile_schedule_matches_dense(sched, feature, dtype, hd):
+    """Forward and q/k/v (and bias) gradients against the dense reference
+    through the split loop, the keys-first forward and the strips."""
+    from deepspeed_tpu.models.transformer import alibi_slopes
+    from deepspeed_tpu.ops.flash_attention import _diag_chunk
+
+    S, block = _SCHEDULES[sched]
+    B, H = 1, 2
+    dt = jnp.bfloat16 if dtype == "bf16" else jnp.float32
+    q, k, v = _qkv(B=B, S=S, H=H, KV=1 if feature == "gqa" else None, hd=hd,
+                   seed=S + hd, dtype=dt)
+    clamped = block if S % block == 0 else 256
+    assert _diag_chunk(clamped) == 128 and S // clamped >= 2
+    kw, valid, bias = {}, S, None
+    if feature == "key_mask":
+        valid = S - 200                       # right padding: rows stay live
+        kw["mask"] = _padded_mask(B, S, [valid])
+    if feature == "alibi":
+        kw["alibi_slopes"] = alibi_slopes(H)
+        rel = jnp.arange(S)[None, :] - jnp.arange(S)[:, None]
+        bias = (kw["alibi_slopes"][:, None, None]
+                * rel[None].astype(jnp.float32))[None]
+    if feature == "bias_dbias":
+        bias = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (B, H, S, S)), jnp.float32)
+    learned = feature == "bias_dbias"
+    live = jnp.asarray(np.arange(S) < valid, jnp.float32)[None, :, None, None]
+
+    def dense(qq, kk, vv, bb):
+        f32 = [x.astype(jnp.float32) for x in (qq, kk, vv)]
+        if bb is None:
+            return causal_attention(*f32, mask=kw.get("mask"))
+        return _dense_biased(*f32, bb, mask=kw.get("mask"))
+
+    def flash(qq, kk, vv, bb):
+        extra = dict(kw, bias=bb) if learned else kw
+        return flash_attention(qq, kk, vv, block=block, interpret=True,
+                               **extra).astype(jnp.float32)
+
+    def loss(f):
+        return lambda *a: jnp.sum(jnp.square(f(*a) * live))
+
+    argnums = (0, 1, 2, 3) if learned else (0, 1, 2)
+    want_o = dense(q, k, v, bias)
+    got_o, got_g = jax.jit(lambda *a: (
+        flash(*a), jax.grad(loss(flash), argnums=argnums)(*a)))(
+            q, k, v, bias if learned else None)
+    want_g = jax.grad(loss(dense), argnums=argnums)(q, k, v, bias)
+    tol = 3e-2 if dtype == "bf16" else 2e-4
+    np.testing.assert_allclose(np.asarray(got_o * live),
+                               np.asarray(want_o * live), rtol=tol, atol=tol)
+    for g, w, name in zip(got_g, want_g, ("q", "k", "v", "bias")):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.all(np.isfinite(g)), name
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * np.abs(w).max(),
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_scores_computed_over_needed():
+    """The schedule's static figure: score elements computed over the pairs
+    a causal mask needs. Whole diagonal tiles (the forward, and every kernel
+    before PR 43) 1.50 at S 1024 / block 512; strips of 128 (both backward
+    kernels) 1.12."""
+    from deepspeed_tpu.ops.flash_attention import (
+        _diag_chunk, scores_computed_over_needed)
+
+    assert scores_computed_over_needed(1024, 512) == pytest.approx(1.50, abs=2e-3)
+    assert scores_computed_over_needed(1024, 512, _diag_chunk(512)) \
+        == pytest.approx(1.124, abs=2e-3)
+    assert scores_computed_over_needed(1024, 512, 256) \
+        == pytest.approx(1.249, abs=2e-3)
+    assert scores_computed_over_needed(1024, 128, _diag_chunk(128)) \
+        == pytest.approx(1.124, abs=2e-3)
+    assert scores_computed_over_needed(1024, 512, causal=False) == 1.0
+    assert (_diag_chunk(512), _diag_chunk(256), _diag_chunk(128),
+            _diag_chunk(96), _diag_chunk(16)) == (128, 128, 128, 96, 16)
+
+
 @pytest.mark.parametrize("block", [16, 32])
 def test_masked_forward_matches_and_stays_fused(block, monkeypatch):
     """Padding masks must run IN the kernel — the round-1 silent fallback to
