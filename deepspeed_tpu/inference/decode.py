@@ -136,6 +136,24 @@ class WindowedCache(NamedTuple):
     length: jnp.ndarray      # as KVCache.length
 
 
+class CCACache(NamedTuple):
+    """The cache of compressed convolutional attention (``cfg.attention ==
+    "cca"``, ``models/cca.py``): K/V planes laid out as :class:`KVCache`'s —
+    the attention runs in the latent, so they are ``n_kv_head x head_dim``
+    wide, an eighth of the model — beside, per layer and slot, the **tail**
+    the two convolutions and the value shift reach back into: the last rows
+    of ``z`` and ``z1`` and of the shifted value's projection
+    (``cca.tail_width`` values whatever the length). The slot is second in
+    every buffer, so ``serving/slots.py`` seats a request by overwriting the
+    slot's whole extent of each: a successor never reads its predecessor's
+    last positions."""
+
+    k: jnp.ndarray           # (L, B, KV, hd, max_len)
+    v: jnp.ndarray           # (L, B, KV, hd, max_len)
+    tail: jnp.ndarray        # (L, B, cca.tail_width)
+    length: jnp.ndarray      # as KVCache.length
+
+
 def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                  dtype=None, *, page_size: int = 0, pages: int = 0) -> tuple:
     """(shape, dtype) of one cache buffer (K or V; for latent attention
@@ -180,6 +198,10 @@ def cache_layout(cfg: TransformerConfig, batch: int, max_len: int,
                 "beside them has no pages: contiguous only")
         return ((pattern.count("*"), batch, cfg.kv_heads, cfg.head_dim,
                  max_len), dtype or cfg.dtype)
+    if getattr(cfg, "attention", "") == "cca" and page_size > 0:
+        raise NotImplementedError(
+            "the paged pool holds pages of K and V; the conv tail a slot "
+            "beside them has no pages: contiguous only")
     loops = getattr(cfg, "loop_steps", 1)
     if loops > 1 and page_size > 0:
         raise NotImplementedError(
@@ -212,7 +234,13 @@ def state_layout(cfg: TransformerConfig, batch: int, dtype=None) -> dict:
     """{name: (shape, dtype)} of what a cache holds per slot whatever the
     position — a ``block_pattern`` trunk's Mamba-2 layers' SSM state and
     conv window (:class:`HybridCache`), an ``attn_pattern`` trunk's window
-    layers' rings (:class:`WindowedCache`); {} for every other trunk."""
+    layers' rings (:class:`WindowedCache`), a ``cca`` trunk's conv tails
+    (:class:`CCACache`); {} for every other trunk."""
+    if getattr(cfg, "attention", "") == "cca":
+        from ..models.cca import tail_width
+
+        return {"tail": ((cfg.n_layer, batch, tail_width(cfg)),
+                         dtype or cfg.dtype)}
     if getattr(cfg, "attn_pattern", ""):
         from ..models.windowed import ring_len
 
@@ -262,7 +290,8 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int,
     shape, dtype = cache_layout(cfg, batch, max_len, dtype)
     length = jnp.zeros(length_shape, jnp.int32)
     if state:
-        kind = WindowedCache if "wk" in state else HybridCache
+        kind = WindowedCache if "wk" in state else \
+            CCACache if "tail" in state else HybridCache
         return kind(
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(value_shape(cfg, shape), dtype),
@@ -621,11 +650,12 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         out, _aux = mlp(y2, p)
         x = x + o + out
     else:
-        x = x + model._post_norm(o, p, "ln1")      # sandwich norms, if any
+        # (sandwich norms and residual scales, if any)
+        x = model._residual(x, model._post_norm(o, p, "ln1"), p, 0)
         y2 = _norm(x, p["ln2_scale"], p.get("ln2_bias"), cfg.norm,
                    cfg.norm_eps)
         out, _aux = mlp(y2, p)
-        x = x + model._post_norm(out, p, "ln2")
+        x = model._residual(x, model._post_norm(out, p, "ln2"), p, 1)
     if paged is not None:
         return x, cache_k, cache_v, scale_k, scale_v
     return x, cache_k, cache_v
@@ -987,6 +1017,83 @@ def _forward_windowed(model, params, x, cache: WindowedCache, new_len,
             if stats else None)
 
 
+def _forward_cca(model, params, x, cache: CCACache, new_len, positions,
+                 valid, flash_decode: bool):
+    """The layer loop of an ``attention='cca'`` trunk (``models/cca.py``,
+    the zaya router of ``models/moe.py``): one scan over the stacked
+    weights carrying ``(x, s)`` — the stream and the router's state, which
+    layer l's router reads of layer l - 1 at the same token — and the K/V
+    planes; each layer's tail goes in and comes out beside its weights. The
+    T == 1 step runs ``decode_attention`` under the name
+    ``cca_decode_attention`` (append in place, the live blocks of 2 KV
+    heads) and writes the tail back for live slots only. T > 1 (a chunk of
+    ONE request, or rows that advance together) appends with XLA's update
+    and attends densely over the layer's slab; its tail is what the last
+    REAL token leaves (``valid`` of T, traced or None: a right-padded final
+    chunk).
+    Returns (x, cache, (stats (layers, 5), routing (layers, B, T, 1))):
+    ``MoETransformerLM.experts``' four counters and the mean weight p of
+    the layer's choices."""
+    from ..models import cca
+    from ..ops.decode_attention import decode_attention
+
+    cfg = model.cfg
+    B, T, _ = x.shape
+    per_slot = getattr(new_len, "ndim", 0) == 1
+    if per_slot and T > 1:
+        raise NotImplementedError(
+            "a conv tail takes one token a slot (T == 1) or a chunk of rows "
+            "that advance together (scalar length): no multi-token verify "
+            "forward")
+    fused = _decode_kernel_ok(flash_decode, T, cache.k.shape[4], x.dtype,
+                              cache.k.dtype, cache.v.dtype)
+    if T == 1 and not fused:
+        from ..observability.metrics import get_registry
+
+        get_registry().counter("Serve/decode_fallback_builds").inc()
+    # a slot at length 0 is not running: its tail stays as it is
+    live = jnp.broadcast_to(new_len > 0, (B,))[:, None]
+    seg = params["layers"]
+    # the expert banks stay out of the loop's xs (see _forward_latent)
+    banks = {k: seg[k] for k in model.BANKS}
+    rest = {k: v for k, v in seg.items() if k not in banks}
+
+    def layer_fn(carry, xs):
+        (x, s, ck, cv), (p, tail, idx) = carry, xs
+        y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+        q, k, v, new_tail = cca.front(cfg, p, y, tail, positions, valid)
+        if fused:
+            o, ck, cv = decode_attention(q, ck, cv, new_len, k=k, v=v,
+                                         layer=idx,
+                                         name="cca_decode_attention")
+        else:
+            # a chunk, or a step the gate declines: XLA's update, then the
+            # layer's slab densely (2 KV heads: (8, T, max_len) scores)
+            slab_k, ck = _dense_append(ck, k, idx, new_len)
+            slab_v, cv = _dense_append(cv, v, idx, new_len)
+            o = _cache_attend(q, slab_k, slab_v, new_len)
+        o = matmul_any(o.reshape(B, T, cfg.n_head * cfg.head_dim), p["wo"],
+                       use_kernel=False)
+        x = model._residual(x, o, p, 0)
+        y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
+        chose, w, s = model.route(y2.reshape(B * T, -1), p,
+                                  s.reshape(B * T, -1))
+        out, stats, chose = model.experts(y2, p, banks=banks, layer=idx,
+                                          routed=(chose, w))
+        x = model._residual(x, out, p, 1)
+        return (x, s.reshape(B, T, -1), ck, cv), (
+            jnp.where(live, new_tail, tail),
+            jnp.concatenate([stats, jnp.mean(w)[None]]), chose)
+
+    s0 = jnp.zeros((B, T, cfg.router_hidden), jnp.float32)
+    with jax.named_scope("decode_layer"):
+        (x, _, k, v), (tails, stats, routing) = lax.scan(
+            layer_fn, (x, s0, cache.k, cache.v),
+            (rest, cache.tail, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+    return (x, CCACache(k=k, v=v, tail=tails, length=new_len),
+            (stats, routing))
+
+
 def _embed_rows(table, ids, dtype):
     """Row gather from a dense or int8/int4-stored embedding table — a
     quantized table reads int8 bytes for exactly the batch's tokens."""
@@ -1102,7 +1209,11 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
                   cfg.norm, cfg.norm_eps)
 
     stats = passes = None
-    if isinstance(cache, WindowedCache):
+    if isinstance(cache, CCACache):
+        x, new_cache, stats = _forward_cca(
+            model, params, x, cache, new_len, positions,
+            None if last_index is None else last_index + 1, flash_decode)
+    elif isinstance(cache, WindowedCache):
         x, new_cache, stats = _forward_windowed(
             model, params, x, cache, new_len, positions,
             None if last_index is None else last_index + 1, flash_decode)

@@ -154,7 +154,7 @@ class HybridLM(TransformerLM):
         k, Eh = cfg.moe_top_k, cfg.held_experts
         N = B * T
         yt = y.reshape(N, d)
-        idx, w = self.route(yt, p)
+        idx, w, _ = self.route(yt, p)
         u = yt @ p["w_dn"].astype(y.dtype) if "w_dn" in p else yt
         bm = block_rows(y.dtype)
         row_token, pair_row, pair_held, block_expert, used, counts = \

@@ -1314,6 +1314,48 @@ def _mimo_v2_convert(sd: _SDict, cfg: TransformerConfig) -> dict:
         "a different model under this one's name")
 
 
+# ----------------------------------------------------------- family: zaya
+def _zaya_config(hf: dict) -> TransformerConfig:
+    """ZAYA1's ``config.json`` → the native configuration: every layer
+    ``hybrid`` (an attention sub-layer in a compressed latent behind two
+    causal convolutions, then top-1 SwiGLU experts behind an MLP router with
+    a carried state), a tied head."""
+    from .presets import zaya
+
+    for key, only in (("hidden_act", "silu"), ("attention_bias", False),
+                      ("lm_head_bias", False), ("tie_word_embeddings", True),
+                      ("sliding_window", None), ("num_experts_per_tok", 1)):
+        if hf.get(key, only) != only:
+            raise ValueError(f"zaya with {key}={hf[key]!r}: the native trunk "
+                             f"runs {only!r}")
+    L = hf["num_hidden_layers"]
+    if list(hf["layer_types"]) != ["hybrid"] * L:
+        raise ValueError("zaya: layer_types names num_hidden_layers hybrid "
+                         "layers (a sliding layer is the larger sibling's)")
+    rope = hf["rope_parameters"]["hybrid"]
+    return zaya(
+        "tiny", n_layer=L, n_head=hf["num_attention_heads"],
+        n_kv_head=hf["num_key_value_heads"], d_model=hf["hidden_size"],
+        qk_head_dim=hf["head_dim"],
+        rotary_dim=int(hf["head_dim"] * rope["partial_rotary_factor"]),
+        rope_theta=float(rope["rope_theta"]),
+        cca_conv=(hf["cca_time0"], hf["cca_time1"]),
+        num_experts=hf["num_experts"], moe_d_ff=hf["moe_intermediate_size"],
+        router_hidden=hf["router_hidden_size"], norm_eps=hf["rms_norm_eps"],
+        vocab_size=hf["vocab_size"], max_seq=hf["max_position_embeddings"])
+
+
+def _zaya_convert(sd: _SDict, cfg: TransformerConfig) -> dict:
+    raise NotImplementedError(
+        "zaya: config.json maps to the native configuration "
+        "(config_from_hf), the checkpoint's tensors do not yet: the names "
+        "and layouts of its weights (the convs' taps, the value shift's two "
+        "projections, the router's MLP and its depth-averaging coefficient, "
+        "the residual scales, the experts' banks) are not in config.json "
+        "and were not to hand when the family was written; a guessed key map "
+        "would load a different model under this one's name")
+
+
 _FAMILIES: dict[str, tuple[Callable, Callable, tuple[str, ...]]] = {
     # model_type → (config_fn, convert_fn, state-dict prefixes to strip)
     "gpt2": (_gpt2_config, _gpt2_convert, ("transformer.",)),
@@ -1344,6 +1386,7 @@ _FAMILIES: dict[str, tuple[Callable, Callable, tuple[str, ...]]] = {
                          ("model.language_model.", "language_model.")),
     # the configuration alone: the converter refuses with its reason
     "mimo_v2_flash": (_mimo_v2_config, _mimo_v2_convert, ("model.",)),
+    "zaya": (_zaya_config, _zaya_convert, ("model.",)),
 }
 
 

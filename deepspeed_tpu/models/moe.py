@@ -16,11 +16,14 @@ Design differences that make this TPU-idiomatic:
   are sharded over the ``expert`` mesh axis; constraining the dispatched
   activations ``(B, E, C, d)`` to the same axis makes GSPMD emit exactly
   the all-to-all the reference hand-codes (``sharded_moe.py:_AllToAll``).
-- **Two routers.** ``moe_router="gshard"`` is the capacity dispatch above.
+- **Three routers.** ``moe_router="gshard"`` is the capacity dispatch above.
   ``"sigmoid"`` (DeepSeek-V3) has no capacity at any T: :meth:`experts`
   sorts the routed rows by expert and multiplies each expert's rows by its
   own weights (``ops/moe_matmul.py``), training forward, prefill and the
   decode step alike, with a shared MLP on every token beside them.
+  ``"zaya"`` (ZAYA1) feeds the same sorted rows from a softmax top-1 chosen
+  by an MLP over a state that the trunk carries from layer to layer
+  (:meth:`route`).
 - **Gating in fp32**: router weights are exempted from the engine's bf16
   compute cast (``fp32_param_names``) so near-tie routing decisions don't
   flap across bf16 rounding, matching ``sharded_moe.py:top1gating``.
@@ -35,9 +38,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..platform.mesh import BATCH_AXES, constrain
-from .transformer import TransformerConfig, TransformerLM, _activation
+from .transformer import (TransformerConfig, TransformerLM, _activation,
+                          _norm)
 
 B_AXES = BATCH_AXES
+# the zaya router's last matrix at init, over the trunk's 1 / sqrt(fan-in)
+ROUTER_OUT_GAIN = 4.0
 
 
 def _capacity(tokens_per_group: int, num_experts: int, capacity_factor: float,
@@ -156,15 +162,23 @@ class MoETransformerLM(TransformerLM):
 
     # ------------------------------------------------------------- MoE MLP
     @jax.named_scope("moe_mlp")
-    def _mlp_block(self, y, p):
+    def _mlp_block(self, y, p, state=None):
         """y: (B, S, d) post-norm activations. Groups = batch rows.
 
         A segment's FFN kind is what its stacked weights hold: a layer
         without a router is a dense layer of this trunk
-        (``moe_first_dense``)."""
+        (``moe_first_dense``). ``state`` (B, S, router_hidden): the zaya
+        router's, from the layer before; the result then has a third part,
+        the state this layer leaves."""
         cfg = self.cfg
         if "router" not in p:
             return super()._mlp_block(y, p)
+        if cfg.moe_router == "zaya":
+            B, S, d = y.shape
+            idx, w, state = self.route(y.reshape(B * S, d), p,
+                                       state.reshape(B * S, -1))
+            out, _, idx = self.experts(y, p, routed=(idx, w))
+            return out, idx, state.reshape(B, S, -1)
         if cfg.moe_router == "sigmoid":
             # no aux loss to give (noaux_tc): the layer's aux is its
             # routing, the chosen experts (B, S, k) — see _fold_aux
@@ -247,7 +261,7 @@ class MoETransformerLM(TransformerLM):
         experts top-6 that is 21x). The sigmoid router does not come here:
         :meth:`experts` multiplies routed rows only, at any T. Prefill
         (T>1) keeps the training per-row dispatch."""
-        if "router" not in p or self.cfg.moe_router == "sigmoid":
+        if "router" not in p or self.cfg.moe_router != "gshard":
             return self._mlp_block(y, p)
         cfg = self.cfg
         B, T, d = y.shape
@@ -278,13 +292,25 @@ class MoETransformerLM(TransformerLM):
 
     # --------------------------------------------- routed rows, no capacity
     @jax.named_scope("moe_route")
-    def route(self, yt, p):
-        """DeepSeek-V3 ``noaux_tc`` routing of (N, d) tokens, in float32 up
-        to the chosen weights: sigmoid scores; the top-k of score +
-        ``router_bias`` are chosen (one group); a chosen expert's weight is
-        its UNBIASED score, normalised over the chosen and scaled.
-        Returns (idx (N, k) i32, weights (N, k) f32)."""
+    def route(self, yt, p, state=None):
+        """Routing of (N, d) tokens, in float32 up to the chosen weights.
+        Returns (idx (N, k) i32, weights (N, k) f32, the router's state).
+
+        ``"sigmoid"`` (DeepSeek-V3 ``noaux_tc``): sigmoid scores; the top-k
+        of score + ``router_bias`` are chosen (one group); a chosen expert's
+        weight is its UNBIASED score, normalised over the chosen and scaled.
+        No state: None in, None out.
+
+        ``"zaya"`` (ZAYA1, arXiv:2511.17127): ``r = y Wd + bd`` into
+        ``router_hidden``; **depth averaging** ``s = r + gamma * state``,
+        ``state`` (N, router_hidden) float32 what the layer before left at
+        the SAME token (zeros before the first); ``p = softmax(W3 gelu(W2
+        gelu(W1 rmsnorm(s))))``; the expert chosen is the argmax of p +
+        ``router_bias`` (a balancing bias, selection only) and its weight
+        its own ``p``, unnormalised. Returns ``s`` as the state."""
         cfg = self.cfg
+        if cfg.moe_router == "zaya":
+            return self._route_zaya(yt, p, state)
         score = jax.nn.sigmoid(jnp.dot(
             yt.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
@@ -293,12 +319,30 @@ class MoETransformerLM(TransformerLM):
         w = jnp.take_along_axis(score, idx, axis=-1)
         if cfg.moe_norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return idx.astype(jnp.int32), w * cfg.moe_routed_scale
+        return idx.astype(jnp.int32), w * cfg.moe_routed_scale, None
+
+    def _route_zaya(self, yt, p, state):
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+
+        def dot(a, name):
+            return jnp.dot(a, p[name].astype(f32), precision=hi)
+
+        s = dot(yt.astype(f32), "router") + p["router_bd"].astype(f32) \
+            + p["router_gamma"].astype(f32) * state
+        t = _norm(s, p["router_norm"], None, "rmsnorm", self.cfg.norm_eps)
+        t = _activation(dot(_activation(dot(t, "router_w1"), "gelu_exact"),
+                            "router_w2"), "gelu_exact")
+        prob = jax.nn.softmax(dot(t, "router_w3"), axis=-1)
+        idx = jnp.argmax(prob + p["router_bias"].astype(f32), axis=-1)[:, None]
+        return (idx.astype(jnp.int32),
+                jnp.take_along_axis(prob, idx, axis=-1), s)
 
     BANKS = ("w_gate", "w_in", "w_out")
 
-    def experts(self, y, p, banks=None, layer=None):
-        """The sigmoid-routed expert layer on (B, T, d): every token's k
+    def experts(self, y, p, banks=None, layer=None, routed=None):
+        """The routed expert layer on (B, T, d) (``routed``: the (idx, w) a
+        caller that threads a router's state took from :meth:`route` itself;
+        None: the stateless sigmoid router's, here): every token's k
         rows sorted by expert, each expert's rows padded to whole blocks of
         the dtype's sublane tile and multiplied by that expert alone
         (``ops/moe_matmul.py``), the weighted rows gathered back per token,
@@ -327,7 +371,7 @@ class MoETransformerLM(TransformerLM):
         B, T, d = y.shape
         N, k = B * T, cfg.moe_top_k
         yt = y.reshape(N, d)
-        idx, w = self.route(yt, p)
+        idx, w = routed if routed is not None else self.route(yt, p)[:2]
         bank = banks if banks is not None else p
         with jax.named_scope("moe_experts"):
             bm = block_rows(y.dtype)
@@ -391,7 +435,10 @@ class MoETransformerLM(TransformerLM):
                 continue
             # base init skips the dense FFN of an expert segment
             k = iter(jax.random.split(jax.random.fold_in(rng, 1 + i), 8))
-            layers["router"] = dense(next(k), (L, d, E), 0.02)
+            if cfg.moe_router == "zaya":
+                layers.update(self._init_zaya_router(next(k), L))
+            else:
+                layers["router"] = dense(next(k), (L, d, E), 0.02)
             layers["w_in"] = dense(next(k), (L, Eh, d, f), 1.0 / math.sqrt(d))
             layers["w_out"] = dense(next(k), (L, Eh, f, d),
                                     1.0 / math.sqrt(2 * depth * f))
@@ -414,6 +461,39 @@ class MoETransformerLM(TransformerLM):
                                          1.0 / math.sqrt(2 * depth * fs))
         return params
 
+    def _init_zaya_router(self, key, L: int) -> dict:
+        """A layer's zaya router. At the trunk's normal scales a random
+        router is flat (every p near 1 / E) and its top-1 a coin toss
+        between near-ties; a trained one is decided and, by its balancing
+        bias, even. The last matrix is drawn ``ROUTER_OUT_GAIN`` wide so
+        that the softmax spreads; the two matrices behind a gelu are drawn
+        with columns that sum to 0, so that what every token shares (the
+        gelu's positive mean) is no expert's fixed advantage (on the chip,
+        48 rows 512 positions deep: 9.8 of 16 experts touched a layer
+        against 7.8 without; 14.7 with the residual biases small too,
+        ``transformer.RES_SCALE_SD``); ``gamma`` and the balancing bias so
+        that a path which drops either chooses differently."""
+        cfg = self.cfg
+        d, R, E = cfg.d_model, cfg.router_hidden, cfg.num_experts
+        k = iter(jax.random.split(key, 8))
+
+        def normal(shape, sd, centred=False):
+            w = sd * jax.random.normal(next(k), shape, jnp.float32)
+            # a gelu's output has a positive mean, the same for every
+            # token; a matrix whose columns sum to 0 over its inputs hands
+            # none of it on as one expert's fixed advantage
+            return w - w.mean(axis=-2, keepdims=True) if centred else w
+
+        return {"router": normal((L, d, R), 1.0 / math.sqrt(d)),
+                "router_bd": normal((L, R), 0.1),
+                "router_gamma": 0.5 + normal((L, 1), 0.1),
+                "router_norm": jnp.ones((L, R), jnp.float32),
+                "router_w1": normal((L, R, R), 1.0 / math.sqrt(R)),
+                "router_w2": normal((L, R, R), 1.0 / math.sqrt(R), True),
+                "router_w3": normal((L, R, E),
+                                    ROUTER_OUT_GAIN / math.sqrt(R), True),
+                "router_bias": normal((L, E), 0.02)}
+
     # ---------------------------------------------------------------- specs
     def param_specs(self) -> dict:
         specs = super().param_specs()
@@ -423,6 +503,11 @@ class MoETransformerLM(TransformerLM):
             if kind != "moe":
                 continue
             layers["router"] = P(None, None, None)
+            if cfg.moe_router == "zaya":
+                layers.update(
+                    router_bd=P(None, None), router_gamma=P(None, None),
+                    router_norm=P(None, None), router_bias=P(None, None),
+                    **{f"router_w{i}": P(None, None, None) for i in (1, 2, 3)})
             layers["w_in"] = P(None, "expert", None, "model")
             layers["w_out"] = P(None, "expert", "model", None)
             if cfg.is_glu:
@@ -441,4 +526,8 @@ class MoETransformerLM(TransformerLM):
     def fp32_param_names(self) -> tuple[str, ...]:
         """Leaf names kept in fp32 by the engine's compute cast (router
         precision governs tie-breaking stability)."""
-        return ("router", "router_bias")
+        from .cca import FP32_NAMES as cca
+
+        return ("router", "router_bias", "router_bd", "router_gamma",
+                "router_norm", "router_w1", "router_w2", "router_w3") \
+            + (cca if self.cfg.attention == "cca" else ())

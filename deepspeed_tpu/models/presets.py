@@ -223,6 +223,33 @@ def mimo_v2_flash(size: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def zaya(size: str = "tiny", **overrides) -> TransformerConfig:
+    """ZAYA1 family (``model_type: zaya``): every layer an attention
+    sub-layer in a compressed latent behind two causal convolutions
+    (``attention="cca"``, models/cca.py) then top-1 SwiGLU experts behind an
+    MLP router whose state is carried from layer to layer
+    (``moe_router="zaya"``, models/moe.py), a scale and a bias a channel on
+    each side of each sub-layer (``residual_scale``), a tied head. ``"8b"``
+    is the published ZAYA1-8B (40 layers, 8.3 B outside the embedding)."""
+    table = {
+        "tiny": dict(n_layer=3, n_head=4, n_kv_head=2, d_model=64,
+                     qk_head_dim=8, rotary_dim=4, num_experts=4, moe_d_ff=32,
+                     router_hidden=16, vocab_size=256, max_seq=1024),
+        "8b": dict(n_layer=40, n_head=8, n_kv_head=2, d_model=2048,
+                   qk_head_dim=128, rotary_dim=64, num_experts=16,
+                   moe_d_ff=2048, router_hidden=256, vocab_size=262272,
+                   max_seq=131072),
+    }
+    base = dict(pos_embedding="rope", norm="rmsnorm", norm_eps=1e-5,
+                activation="silu_glu", use_bias=False, tie_embeddings=True,
+                rope_theta=5e6, rope_halves=True, attention="cca",
+                cca_conv=(2, 2), residual_scale=True, moe_router="zaya",
+                moe_top_k=1, moe_norm_topk=False, fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def tiny_test(**overrides) -> TransformerConfig:
     """Unit-test sized config (analog of the reference tests' SimpleModel)."""
     base = dict(vocab_size=256, n_layer=2, n_head=4, d_model=64, d_ff=128,

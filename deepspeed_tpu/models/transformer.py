@@ -101,7 +101,12 @@ class TransformerConfig:
     # "sigmoid" scores each expert with a sigmoid, chooses the top-k of
     # score + a learned selection bias (``router_bias``; the weight is the
     # unbiased score), never drops a token and has no aux loss.
-    moe_router: str = "gshard"            # "gshard" (softmax, capacity) | "sigmoid"
+    # "zaya" (ZAYA1, arXiv:2511.17127): an MLP router over a state carried
+    # from layer to layer at the same token (``router_hidden`` wide), softmax
+    # over the experts, the top-1 of p + a balancing bias chosen and weighted
+    # by its own p, unnormalised (``moe_norm_topk`` would make it 1)
+    moe_router: str = "gshard"            # "gshard" (softmax, capacity) | "sigmoid" | "zaya"
+    router_hidden: int = 0                # the zaya router's state and MLP width
     moe_d_ff: Optional[int] = None        # an expert's width (default ffn_dim)
     moe_shared_d_ff: int = 0              # shared experts, as ONE MLP of this
                                           # width on every token (0: none)
@@ -111,8 +116,17 @@ class TransformerConfig:
                                           # width ffn_dim before the expert layers
     # attention kind: "mha" (MHA/GQA/MQA over cached K and V) | "mla"
     # (latent attention, models/mla.py: the cache holds kv_lora_rank +
-    # qk_rope_head_dim values a token). The kind decides cache_layout().
+    # qk_rope_head_dim values a token) | "cca" (compressed convolutional
+    # attention, models/cca.py: the whole attention in a latent of
+    # ``n_head x head_dim`` behind two causal convolutions over positions,
+    # kernels ``cca_conv``; K/V planes beside a conv tail a slot). The kind
+    # decides cache_layout().
     attention: str = "mha"
+    cca_conv: tuple = (2, 2)              # depthwise taps, then grouped taps
+    # a scale and a bias a channel on each side of each sub-layer (ZAYA1):
+    # x <- (a_res x + b_res) + (a_out F(norm(x)) + b_out); ``res_scale``
+    # (L, 2, 4, d) = [attention | FFN][a_res, b_res, a_out, b_out]
+    residual_scale: bool = False
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -292,7 +306,9 @@ class TransformerConfig:
         mats = 3 if self.is_glu else 2
         if (kind or self.segments[-1][0]) == "dense":
             return d * self.ffn_dim * mats
-        router = d * E
+        R = self.router_hidden
+        router = d * R + 2 * R * R + R * E if self.moe_router == "zaya" \
+            else d * E
         mult = min(self.moe_top_k, E) if active_only else self.held_experts
         return (router + mult * d * self.expert_dim * mats
                 + d * self.moe_shared_d_ff * mats)
@@ -305,7 +321,11 @@ class TransformerConfig:
                     + r * h * (self.qk_nope_head_dim + self.v_dim)
                     + h * self.v_dim * d)
         kv, hd, vd = self.attn_kv_heads(kind), self.head_dim, self.v_dim
-        return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d
+        # cca: the grouped conv's one hd x hd matrix a head a tap (the
+        # depthwise taps, like norms and biases, are left out)
+        conv = self.cca_conv[1] * (h + kv) * hd * hd \
+            if self.attention == "cca" else 0
+        return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d + conv
 
     def _mixer_params_per_layer(self, kind: str, active_only: bool) -> int:
         """Matmul parameters of one ``block_pattern`` layer of ``kind``."""
@@ -342,6 +362,13 @@ class TransformerConfig:
                 and self.objective != "feature"):
             total += emb
         return total
+
+
+# residual_scale at init: sd of [a_res, b_res, a_out, b_out] about 1, 0, 1, 0.
+# The biases are small: each is one vector added to EVERY token at every
+# sub-layer, and at sd 0.01 their sum was the direction all hidden states
+# shared 512 positions deep (a router then sends most rows to one expert)
+RES_SCALE_SD = (0.05, 0.001, 0.1, 0.001)
 
 
 # ------------------------------------------------------------------ helpers
@@ -615,8 +642,25 @@ class TransformerLM:
                     "qk_rope_head_dim, no biases, pre-norm, its own blocked "
                     "attention (no attention_fn), and kv_lora_rank / "
                     "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
+        elif config.attention == "cca":
+            from .cca import check_config as check_cca
+
+            check_cca(config, attention_fn)
         elif config.attention != "mha":
             raise ValueError(f"unknown attention kind {config.attention!r}")
+        if config.moe_router == "zaya" and (
+                config.attention != "cca" or config.num_experts < 2
+                or config.router_hidden < 1 or config.moe_top_k != 1
+                or config.moe_experts_held or config.moe_shared_d_ff
+                or not config.is_glu):
+            raise ValueError(
+                "moe_router='zaya' is the ZAYA1 expert sub-layer: beside "
+                "attention='cca', router_hidden set, top-1 of SwiGLU experts "
+                "all held here, no shared expert")
+        if config.residual_scale and (config.post_ln
+                                      or config.parallel_residual):
+            raise ValueError("residual_scale scales the two sides of a "
+                             "pre-norm two-hop block")
         if config.attn_pattern:
             from .windowed import check_config
 
@@ -721,6 +765,10 @@ class TransformerLM:
                 "wo": dense(next(k), (L, h * cfg.v_dim, d),
                             scale=1.0 / math.sqrt(2 * depth * d)),
             })
+        elif cfg.attention == "cca":
+            from .cca import init_params as init_cca
+
+            layers.update(init_cca(cfg, k, dense, L, depth))
         else:
             layers.update({
                 "wq": dense(next(k), (L, d, h * hd)),
@@ -747,6 +795,12 @@ class TransformerLM:
             # largest (PERF.md, PR 34)
             post = jnp.full((L, d), 1.0 / math.sqrt(2 * depth), jnp.float32)
             layers["ln1_post_scale"] = layers["ln2_post_scale"] = post
+        if cfg.residual_scale:
+            # a trained model's scales lie near 1 and its biases near 0;
+            # drawn so, that a path which drops them reads differently
+            sd = jnp.asarray(RES_SCALE_SD, jnp.float32)[:, None]
+            layers["res_scale"] = jnp.asarray([1.0, 0.0, 1.0, 0.0])[:, None] \
+                + sd * jax.random.normal(next(k), (L, 2, 4, d), jnp.float32)
         if dense_ffn:
             layers["w_in"] = dense(next(k), (L, d, f))
             layers["w_out"] = dense(next(k), (L, f, d),
@@ -824,6 +878,10 @@ class TransformerLM:
                 "kv_norm_scale": P(None, None),
                 "wkv_b": P(None, None, "model"), "wo": P(None, "model", None),
             })
+        elif cfg.attention == "cca":
+            from .cca import param_specs as cca_specs
+
+            layers.update(cca_specs())
         else:
             layers.update({
                 "wq": P(None, None, "model"),
@@ -838,6 +896,8 @@ class TransformerLM:
         if cfg.sandwich_norm:
             layers["ln1_post_scale"] = P(None, None)
             layers["ln2_post_scale"] = P(None, None)
+        if cfg.residual_scale:
+            layers["res_scale"] = P(None, None, None, None)
         if dense_ffn:
             layers["w_in"] = P(None, None, "model")
             layers["w_out"] = P(None, "model", None)
@@ -886,6 +946,14 @@ class TransformerLM:
                     "window and full layers side by side take no padding "
                     "mask yet")
             return windowed.attention_block(cfg, y, p, positions, attn)
+        if cfg.attention == "cca":
+            if attn_mask is not None:
+                raise NotImplementedError(
+                    "the convolutions over positions take no padding mask")
+            from . import cca
+
+            return cca.attention_block(cfg, y, p, positions,
+                                       self.attention_fn)
         if cfg.attention == "mla":
             if attn_mask is not None:
                 raise NotImplementedError(
@@ -991,6 +1059,8 @@ class TransformerLM:
         # one wo product from the saved o. Without that (dense, latent,
         # ring / Ulysses, sparse) the projected attn_out is the tag:
         # recomputing it would redo the whole S^2 attention.
+        # a zaya router's state rides beside x, layer to layer (_trunk)
+        x, s = x if isinstance(x, tuple) else (x, None)
         x = checkpoint_name(x, "layer_in")
         o = self._attention_block(x, p, positions, attn_mask, attn)
         if not getattr(self.attention_fn, "names_residuals", False):
@@ -1014,12 +1084,27 @@ class TransformerLM:
             out, aux = self._mlp_block(y, p)
             x = x + o + out
         else:
-            x = x + self._post_norm(o, p, "ln1")
+            x = self._residual(x, self._post_norm(o, p, "ln1"), p, 0)
             y = _norm(x, p["ln2_scale"], p.get("ln2_bias"),
                       cfg.norm, cfg.norm_eps)
-            out, aux = self._mlp_block(y, p)
-            x = x + self._post_norm(out, p, "ln2")
-        return constrain(x, P(B_AXES, "seq", None)), aux
+            if s is None:
+                out, aux = self._mlp_block(y, p)
+            else:
+                out, aux, s = self._mlp_block(y, p, state=s)
+            x = self._residual(x, self._post_norm(out, p, "ln2"), p, 1)
+        x = constrain(x, P(B_AXES, "seq", None))
+        return (x if s is None else (x, s)), aux
+
+    @staticmethod
+    def _residual(x, out, p, side: int):
+        """A sub-layer's output joining the stream: ``x + out``, or with
+        ``residual_scale`` (a ``res_scale`` leaf) a scale and a bias a
+        channel on each: ``(a_res x + b_res) + (a_out out + b_out)``;
+        ``side`` 0 the attention's, 1 the FFN's."""
+        if "res_scale" not in p:
+            return x + out
+        a_res, b_res, a_out, b_out = p["res_scale"][side].astype(x.dtype)
+        return (a_res * x + b_res) + (a_out * out + b_out)
 
     def _post_norm(self, y, p, ln: str):
         """A sub-layer's output on its way into the residual stream: normed
@@ -1189,6 +1274,11 @@ class TransformerLM:
         norm is then none) and, as aux, what :meth:`loop_passes` says of
         the passes."""
         x, positions = self._embed(params, input_ids)
+        zaya = self.cfg.moe_router == "zaya"
+        if zaya:
+            # s_{-1} = 0: the router's state, carried from layer to layer
+            x = (x, jnp.zeros(x.shape[:2] + (self.cfg.router_hidden,),
+                              jnp.float32))
 
         def stack(x):
             auxes = []
@@ -1205,7 +1295,8 @@ class TransformerLM:
             x, _, passes = self.loop_passes(
                 params, x, None, lambda x, _, r: (stack(x)[0], None))
             return x, passes
-        return stack(x)
+        x, aux = stack(x)
+        return (x[0] if zaya else x), aux
 
     def apply(self, params, input_ids, *, attn_mask=None, remat_policy=None,
               return_aux: bool = False):

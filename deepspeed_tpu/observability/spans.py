@@ -67,7 +67,14 @@ RETIRED = "retired"                # instant: terminal status lands
 # Serving engine cadence (no rid):
 DECODE_STEP = "decode_step"        # span: one slot decode step (all slots):
                                    # dispatch + read-back, the watchdog's
-                                   # window (meta: slots, queue)
+                                   # window (meta: slots, queue; by cache
+                                   # kind what serving/engine.py's
+                                   # _moe_counts adds — a cca trunk's:
+                                   # cache_bytes_per_token,
+                                   # state_bytes_per_slot, live_positions,
+                                   # experts_touched, moe_rows_over_routed,
+                                   # moe_rows_routed, moe_load_max_over_mean,
+                                   # router_top_p)
 OCCUPANCY = "occupancy"            # counter: slots occupied / queue depth
 # One serving iteration and its phases (``step`` = the iteration; the
 # phases are disjoint and lie inside SRV_STEP, so the iteration's self

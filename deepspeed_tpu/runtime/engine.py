@@ -202,6 +202,12 @@ class Engine:
                 "not trained here: the blocked attention has no backward "
                 "that skips the blocks outside a window, and the held "
                 "experts' exchange is not written")
+        if getattr(getattr(model, "cfg", None), "attention", "") == "cca":
+            raise ValueError(
+                "compressed convolutional attention behind the zaya router "
+                "(attention='cca') is served, not trained here: the sorted "
+                "expert rows have no backward, and the router's carried "
+                "state and the convs' have none under a test")
         mcfg = self.config.moe
         if mcfg.enabled:
             # ds_config moe section overrides the model's MoE knobs

@@ -144,6 +144,7 @@ class ServingEngine:
             and any(kind == "moe" for kind, _ in mcfg.segments)
         self._hybrid = bool(getattr(mcfg, "block_pattern", ""))
         self._windowed = bool(getattr(mcfg, "attn_pattern", ""))
+        self._cca = getattr(mcfg, "attention", "") == "cca"
         if (self._latent or getattr(mcfg, "moe_router", "") == "sigmoid") \
                 and not (self._hybrid or self._windowed):   # (own lists below)
             refused = [name for name, on in (
@@ -218,6 +219,33 @@ class ServingEngine:
                     f"{mcfg.attn_pattern!r}) do not yet compose with "
                     + "; ".join(refused))
             self._moe_stats = any(kind == "moe" for kind, _ in mcfg.segments)
+        # compressed convolutional attention and the zaya router
+        # (models/cca.py, models/moe.py): K/V planes beside a conv tail a
+        # slot a layer, a router state carried through the layer loop
+        if self._cca:
+            refused = [why for why, on in (
+                ("the paged pool and prefix sharing (page_size): a conv "
+                 "tail has no pages, and a shared prefix would need the "
+                 "tail as it stood at the prefix's end",
+                 self.cfg.page_size > 0),
+                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
+                 "pool", bool(self.cfg.kv_quant_bits)),
+                ("speculation: a rejected draft would have to roll the "
+                 "tails back, and the verify forward is many tokens a slot",
+                 self.cfg.speculation is not None
+                 and self.cfg.speculation.enabled),
+                ("tiered / host KV (host_pool_bytes): it moves pages",
+                 self.cfg.host_pool_bytes > 0),
+                ("weight-only quantization: the latent's projections and "
+                 "the router take dense weights", bool(engine.config.quantize)),
+                ("a mesh of several devices: the convs' channels and the "
+                 "sorted expert rows have no sharding rule under a test",
+                 engine.mesh.size > 1)) if on]
+            if refused:
+                raise ValueError(
+                    "compressed convolutional attention (attention='cca') "
+                    "does not yet compose with " + "; ".join(refused))
+            self._moe_stats = True
         # a looped trunk (models/transformer.py loop_steps): its passes'
         # n_layer x loop_steps cache planes are contiguous bf16/fp only
         self._loops = int(getattr(mcfg, "loop_steps", 1))
@@ -259,11 +287,11 @@ class ServingEngine:
         self._chunk_routing: list = []   # (rid, start, device routing, real)
         self._cache_bytes_per_token = cache_bytes_per_token(
             mcfg, engine.compute_dtype) \
-            if self._latent or self._hybrid or self._windowed \
+            if self._latent or self._hybrid or self._windowed or self._cca \
             or self._loops > 1 else None
         self._state_bytes_per_slot = state_bytes_per_slot(
             mcfg, engine.compute_dtype) \
-            if self._hybrid or self._windowed else 0
+            if self._hybrid or self._windowed or self._cca else 0
         if self._loops > 1:
             # what a looped program reads of the weights, from the served
             # tree's shapes: the layers once a pass, and the head (with the
@@ -556,7 +584,8 @@ class ServingEngine:
                                total_deadline_s=self.cfg.total_deadline_s,
                                spans=self.spans, pages=self.pool,
                                rid_source=rid_source,
-                               recurrent=self._hybrid or self._windowed)
+                               recurrent=self._hybrid or self._windowed
+                               or self._cca)
         self._programs: OrderedDict = \
             programs if programs is not None else OrderedDict()
         # disaggregated-serving hook (serving/fleet.py): a side-effecting
@@ -1139,8 +1168,14 @@ class ServingEngine:
             return self._hybrid_counts(moe, pending)
         if self._windowed:
             return self._windowed_counts(moe, pending)
+        if self._cca:
+            return self._cca_counts(moe, pending)
         if not moe:          # no expert trunk, or the chaos build's step
             return {}
+        return self._routed_counts(moe, pending)
+
+    def _routed_counts(self, moe: list, pending: list) -> dict:
+        """:meth:`_moe_counts` of a trunk whose every expert is held."""
         k = self.model.cfg.moe_top_k
         for (chunk_span, _, size), st in zip(pending, moe[1:]):
             chunk_span.amend(moe_rows_over_routed=float(
@@ -1154,10 +1189,33 @@ class ServingEngine:
                 "experts_touched": float(st[:, 1].mean()),
                 "cache_bytes_per_token": self._cache_bytes_per_token}
 
+    def _cca_counts(self, moe: list, pending: list) -> dict:
+        """Meta of a ``cca`` trunk's ``decode_step`` span:
+        :meth:`_hybrid_meta` (what a cached token and a slot's conv tails
+        cost); ``live_positions``, the kernel's count beside the span's own
+        ``slots``; of the step's expert layers (``_forward_cca``'s counters,
+        one row a layer: ``MoETransformerLM.experts``' four, then the mean
+        weight p of the layer's choices) what :meth:`_moe_counts` says of
+        every routed trunk (every expert is held), ``moe_rows_routed`` (slots
+        x 1) and ``router_top_p``: 1 / num_experts says the router is flat,
+        near 1 that it is saturated. A chunk's ``router_top_p`` goes onto
+        its own ``prefill_chunk`` span beside its rows over routed."""
+        meta = self._hybrid_meta()
+        if self._slot_len is not None:
+            meta["live_positions"] = int(self._slot_len.sum())
+        if not moe:
+            return meta
+        meta.update(self._routed_counts(moe, pending))
+        for (chunk_span, _, _), st in zip(pending, moe[1:]):
+            chunk_span.amend(router_top_p=float(st[:, 4].mean()))
+        meta.update(moe_rows_routed=self.cfg.slots,
+                    router_top_p=float(moe[0][:, 4].mean()))
+        return meta
+
     def _hybrid_meta(self) -> dict:
         """What every ``decode_step`` and ``prefill_chunk`` span of a
-        ``block_pattern`` trunk says beside its times: what a cached token
-        and a slot's recurrent state cost (``cache_layout()``,
+        ``block_pattern`` or ``cca`` trunk says beside its times: what a
+        cached token and a slot's fixed-size state cost (``cache_layout()``,
         ``state_layout()``)."""
         return {"cache_bytes_per_token": self._cache_bytes_per_token,
                 "state_bytes_per_slot": self._state_bytes_per_slot}
@@ -1690,7 +1748,8 @@ class ServingEngine:
                         **(self._loop_meta(ch.last_index + 1 if ch.final
                                            else ch.size, head=ch.final)
                            if self._loops > 1 else {}),
-                        **(self._hybrid_meta() if self._hybrid else {}),
+                        **(self._hybrid_meta() if self._hybrid or self._cca
+                           else {}),
                         **(self._windowed_meta(ch) if self._windowed else {}),
                         **self.sched._attempt_meta(req)) as chunk_span:
             ids = ch.ids[None]

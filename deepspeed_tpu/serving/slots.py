@@ -7,7 +7,8 @@ indirection is hostile to XLA's static shapes), the serving state is ONE
 for latent attention; for a trunk of one mixer a layer K/V planes for its
 attention layers only beside a recurrent state a slot, ``HybridCache``; for
 window layers beside full ones planes for the full layers beside a ring a
-slot for each window layer, ``WindowedCache``) —
+slot for each window layer, ``WindowedCache``; for compressed convolutional
+attention K/V planes beside a conv tail a slot a layer, ``CCACache``) —
 the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
 positions on the lanes, so the buffer is compact in HBM at any head size
 and the decode step's kernel appends to it and reads it where it lies

@@ -13,9 +13,15 @@ PR appends a metric (which the contract says goes at the end), whatever that
 metric lists. The ``nh_spec`` fixture below therefore leaves out what was
 appended after PR 37's last entry AND DOES NOT LIST THE NEMOTRON CELL: the
 test still reads the committed file for every metric that names its cell, so
-one that wrongly lists it fails both of its assertions. The Ouro test has no
+one that wrongly lists it fails both of its assertions. ``test_mimo_v2_flash.py``
+pins likewise that its configuration, its cell and its five metrics are the
+LAST of their lists: the ``mm_spec`` fixture below hands it ``BENCHMARK.json``
+without the configurations and cells appended since PR 42 and, of the metrics
+appended since, with those alone that list the MiMo cell.
+``test_zaya.py`` pins no position. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
-``benchmark`` PR should loosen the ``[-5:]`` pin and take this fixture away.
+``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
+fixtures away.
 """
 
 import json
@@ -34,7 +40,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_reducers", "benchmark.tests.test_traffic",
     "benchmark.tests.test_window", "benchmark.tests.test_deepseek_v3",
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
-    "benchmark.tests.test_mimo_v2_flash",
+    "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
     "benchmark.tests.test_program_lifecycle")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
@@ -47,6 +53,7 @@ from benchmark.tests.test_reduce import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reducers import *  # noqa: E402,F401,F403
 from benchmark.tests.test_traffic import *  # noqa: E402,F401,F403
 from benchmark.tests.test_window import *  # noqa: E402,F401,F403
+from benchmark.tests.test_zaya import *  # noqa: E402,F401,F403
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +63,25 @@ def nh_spec():  # noqa: F811
         spec = json.load(f)
     names = [m["name"] for m in spec["per_layer"]]
     cut = names.index("ssm.state_bytes_per_slot") + 1
+    spec["per_layer"] = spec["per_layer"][:cut] + [
+        m for m in spec["per_layer"][cut:]
+        if cell in m.get("workloads", [cell])]
+    return spec
+
+
+@pytest.fixture(scope="module")
+def mm_spec():  # noqa: F811
+    name = "mimo-v2-flash-l7-e16"
+    cell = name + ".serve-backlog-mixedlen"
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+
+    def upto(entries, last):
+        return entries[:[e["name"] for e in entries].index(last) + 1]
+
+    spec["configs"] = upto(spec["configs"], name)
+    spec["workloads"] = upto(spec["workloads"], cell)
+    cut = len(upto(spec["per_layer"], "cache.window_bytes_per_slot"))
     spec["per_layer"] = spec["per_layer"][:cut] + [
         m for m in spec["per_layer"][cut:]
         if cell in m.get("workloads", [cell])]

@@ -825,3 +825,95 @@ def test_windowed_trunk_keeps_planes_and_rings_in_place(one_chip, monkeypatch,
     # no copy of a projection's weights re-laid out for heads of 192
     assert not [ln for ln in compiled.as_text().splitlines()
                 if " copy(" in ln and "4096,12288]{1,2,0" in ln]
+
+
+# ---------------------- compressed convolutional attention, the zaya router
+@pytest.mark.parametrize("program", ["slot step", "chunk", "final chunk"])
+def test_cca_trunk_keeps_planes_and_tails_in_place(one_chip, monkeypatch,
+                                                   program, capsys):
+    """ZAYA1-8B's first pipeline stage (20 of 40 layers, every expert, the
+    whole vocabulary, ``benchmark/configs/zaya1-8b-l20.json``) at the cell's
+    48 slots x 4096, chunks of 512: the planes and the tails enter donated
+    and leave aliased; the decode kernel lowers for the chip at 8 : 2 heads of
+    128 under its own name, once (one scan over the 20 layers); the step's
+    live set leaves the chip room beside the batch-1 prefill cache."""
+    import json
+    import time
+
+    from benchmark.models import zaya as fam
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 48, 4096, 512
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "zaya1-8b-l20.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, dtype or a.dtype, sharding=one_chip), tree)
+
+    # the serving tree: every leaf in the served type
+    # (benchmark/kinds/_serving.py build), q, k and v one matrix
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     jnp.bfloat16)
+    layers = dict(params["layers"])
+    d = cfg.d_model
+    qkv = sum(layers.pop(k).shape[-1] for k in ("wq", "wk", "wv"))
+    layers["wqkv"] = jax.ShapeDtypeStruct((cfg.n_layer, d, qkv), jnp.bfloat16,
+                                          sharding=one_chip)
+    params = {**params, "layers": layers}
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, slots, max_len, jnp.bfloat16)))
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        batch = slots
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, max_len, jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True)[final ^ 1:],
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        batch = 1
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[cca {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB]")
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 20480
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 107520
+    held = batch * (107520 + max_len * 20480)
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert mem.temp_size_in_bytes < (256 if batch > 1 else 1024) * 2 ** 20, \
+        mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live + (held // slots if batch > 1 else 0) \
+        < (15.75 - 1.0) * 2 ** 30, live
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "cca_decode_attention", "decode_attention", "moe_experts_up",
+        "moe_experts_down")}
+    assert count == {"cca_decode_attention": int(batch > 1),
+                     "decode_attention": 0, "moe_experts_up": 1,
+                     "moe_experts_down": 1}, count

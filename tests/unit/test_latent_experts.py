@@ -162,9 +162,9 @@ def test_router_and_expert_layer(tiny, case):
         if case == "bias":
             # a bias that lifts expert 5 into every choice changes WHO is
             # chosen; the weight is still the unbiased score
-            idx0, _ = model.route(yt, dict(p, router_bias=jnp.zeros(8)))
+            idx0, _, _ = model.route(yt, dict(p, router_bias=jnp.zeros(8)))
             p["router_bias"] = jnp.zeros(8).at[5].set(10.0)
-            idx, w = model.route(yt, p)
+            idx, w, _ = model.route(yt, p)
             assert (np.asarray(idx) == 5).any(axis=1).all()
             assert not (np.asarray(idx0) == 5).any(axis=1).all()
             chosen = np.take_along_axis(score, np.asarray(idx), 1)
@@ -172,7 +172,7 @@ def test_router_and_expert_layer(tiny, case):
                 np.asarray(w), 2.448 * chosen / chosen.sum(1, keepdims=True),
                 rtol=1e-5)
         elif case == "normalised":
-            idx, w = model.route(yt, p)
+            idx, w, _ = model.route(yt, p)
             np.testing.assert_allclose(np.asarray(w).sum(1), 2.448, rtol=1e-5)
             g, biased, _ = ref.router(yt, jax.tree.map(
                 lambda a: jnp.asarray(a, jnp.float32), p), PUBLISHED)
@@ -190,7 +190,7 @@ def test_router_and_expert_layer(tiny, case):
             np.testing.assert_allclose(
                 np.asarray(full - zeroed).reshape(24, -1), np.asarray(shared),
                 atol=1e-5)
-            idx, w = model.route(yt, p)
+            idx, w, _ = model.route(yt, p)
             np.testing.assert_allclose(
                 np.asarray(zeroed).reshape(24, -1),
                 plain_experts(cfg, p, np.asarray(yt), np.asarray(idx),
@@ -200,7 +200,7 @@ def test_router_and_expert_layer(tiny, case):
             p["router_bias"] = jnp.zeros(8).at[0].set(10.0)
             p["ws_out"] = 0 * p["ws_out"]
             out, stats, chosen = model.experts(yt[None], p)
-            idx, w = model.route(yt, p)
+            idx, w, _ = model.route(yt, p)
             assert (np.asarray(idx)[:, 0] == 0).all()
             assert float(stats[0]) == 24            # expert 0 got every token
             np.testing.assert_allclose(
