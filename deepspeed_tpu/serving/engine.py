@@ -14,6 +14,10 @@ static-shape world. The engine owns three device assets:
 - one insert program that writes a finished prefill into its slot
   (donated ``dynamic_update_slice`` — in place, full slot extent).
 
+The host loop dispatches step N + 1 before it reads step N back: the books
+run one step behind the device, which never waits for a read-back
+(``ServingEngine._iterate``; docs/SERVING.md, "The host loop").
+
 Steady state therefore compiles a BOUNDED program set — decode step +
 insert + (2 x bucket count) prefill programs — and ``compiles`` counts
 every build so the bench smoke test can assert no compilation happens
@@ -26,6 +30,7 @@ static path scans.
 
 from __future__ import annotations
 
+import dataclasses
 import weakref
 from collections import OrderedDict
 from typing import Optional
@@ -71,6 +76,22 @@ _MAX_RESULTS = 4096
 # stall, then recovers — one slow step during warmup must not mark the
 # replica unhealthy forever (the cumulative stall COUNT never resets).
 _DEGRADED_WINDOW = 64
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A slot step dispatched and not yet read back: what the host needs to
+    book it when it is (``ServingEngine._launch`` / ``_book``)."""
+
+    step: int            # the iteration that dispatched it
+    t0: float            # ... and when
+    rows: dict           # slot -> request it ran, by the books of then
+    queue: int           # the queue's depth then
+    ahead: int           # 1: it went out before the step before was read
+    read: tuple          # device: tok, done, ok and the trunk's counters
+    lens: Optional[np.ndarray]      # the mirror of the lengths it leaves
+    chunk_stats: list    # counters of the chunks that ran in front of it
+    chunk_routing: list  # ... and their tapped routing
 
 
 def _mean_exit_pdf(passes: dict, real):
@@ -617,6 +638,17 @@ class ServingEngine:
         # it read): an admission behind the iteration's step waits for the
         # one and frees the other (_admit)
         self._seated = None
+        # the step dispatched and not yet read back (a _Flight): the books
+        # run one step behind the device (docs/SERVING.md, "The host loop")
+        self._inflight: Optional[_Flight] = None
+        # (request, its final chunk's batch-1 carry): a first token the
+        # host has yet to read, this iteration
+        self._first = None
+        # what a settle retired between two iterations: the next step()
+        # hands it back
+        self._late: list = []
+        self._read_t = float("-inf")    # when the last step was read back
+        self._stall_excess = 0.0
         # resilience state: chaos only exists when explicitly enabled —
         # disabled serving carries a single `is not None` check per step
         self.chaos: Optional[ChaosMonkey] = None
@@ -800,23 +832,31 @@ class ServingEngine:
         return (pf, stats, routing) if self._moe_stats else pf
 
     def _step_impl(self, params, carry):
-        # logit_guard: the (B,) per-row finiteness flags ride the step's
-        # existing fused read-back — the guard costs zero extra host syncs
-        # ... and neither do the expert layers' counters (moe_stats: a
-        # third result on that same read-back, for the decode_step span;
-        # exit_pdf: a looped trunk's exit distribution a slot, likewise)
-        return decode_step(self.model, params, carry, sampler=self._sampler,
-                           eos_token_id=self._eos, flash_decode=self._flash,
-                           logit_guard=True, moe_stats=self._moe_stats,
-                           exit_pdf=self._exit_gate)
+        """The slot step: ``(carry, read)``. ``read`` is what the host
+        reads of a step — the tokens, the ``done`` flags, the (B,) per-row
+        logit finiteness (logit_guard) and, where the trunk has them, the
+        expert layers' counters and choices (moe_stats) or a looped
+        trunk's exit distribution a slot (exit_pdf) — as outputs of their
+        own: the carry they would otherwise be read from goes into the
+        next step, donated, before the host reads this one
+        (:meth:`_launch`). One fused read-back a step, whatever it
+        carries."""
+        out, *read = decode_step(
+            self.model, params, carry, sampler=self._sampler,
+            eos_token_id=self._eos, flash_decode=self._flash,
+            logit_guard=True, moe_stats=self._moe_stats,
+            exit_pdf=self._exit_gate)
+        return out, (out.tok, out.done, *read)
 
     def _step_chaos_impl(self, params, carry, poison_row):
         """Chaos build of the step: identical program + a traced poison-row
         scalar (-1 = clean; `where` on a false mask is bit-exact), so one
         compiled program covers every iteration of a chaos run."""
-        return decode_step(self.model, params, carry, sampler=self._sampler,
-                           eos_token_id=self._eos, flash_decode=self._flash,
-                           logit_guard=True, poison_row=poison_row)
+        out, ok = decode_step(
+            self.model, params, carry, sampler=self._sampler,
+            eos_token_id=self._eos, flash_decode=self._flash,
+            logit_guard=True, poison_row=poison_row)
+        return out, (out.tok, out.done, ok)
 
     # --------------------------------------------------- self-speculation
     def _spec_verify_impl(self, params, carry, drafts):
@@ -1112,7 +1152,9 @@ class ServingEngine:
         """Cancel a request wherever it currently lives — queue, prefill
         lane, or decode slot. Returns the request (status ``CANCELLED``,
         also placed in ``results``) or None if it already finished / is
-        unknown."""
+        unknown (a request the step in flight ended is finished: the
+        settle books it first)."""
+        self._settle()
         if self._prefill is not None and self._prefill[0].rid == rid:
             req = self._prefill[0]
             self._prefill = None
@@ -1129,22 +1171,30 @@ class ServingEngine:
     def step(self) -> list[Request]:
         """One serving iteration: deadline sweep + <= 1 prefill chunk + 1
         decode step over the occupied slots. Returns requests that
-        finished this iteration — normally (status ``OK``) or through a
-        guard (``TIMEOUT`` / ``NONFINITE``); all are also kept in
-        ``results``. Chaos disabled adds nothing to the device work and
-        no host syncs beyond the step's one fused read-back.
+        finished — normally (status ``OK``) or through a guard
+        (``TIMEOUT`` / ``NONFINITE``); all are also kept in ``results``.
+        **The books run one step behind the device** (docs/SERVING.md,
+        "The host loop"): this call dispatches its step BEFORE it reads
+        the step the call before dispatched, so what it returns are the
+        requests that step ended (and those that ended at prefill, and
+        whatever a settle retired since). Chaos disabled adds nothing to
+        the device work and no host syncs beyond a step's one fused
+        read-back.
 
         The iteration is one ``srv.step`` span and its phases are its
         children, disjoint and in this order: ``deadlines``, ``admit``,
-        ``prefill_chunk``, ``prefill_readback``, ``place``,
-        ``decode_dispatch``, ``decode_readback``, ``retire``, ``tail``
-        (observability/spans.py; the span less the children is the
-        loop's own time). Where a request was waiting with a slot free,
-        or the lane had a further chunk, when the step before went out,
-        ``admit`` and ``prefill_chunk`` (``ahead`` 1) stand between that
-        iteration's ``decode_dispatch`` and ``decode_readback`` instead
-        (``_lane_dispatch``); in front of the step they are the fallback
-        for a request that arrived, or a slot that was freed, since."""
+        ``prefill_chunk``, ``place`` (the seat, behind its chunk),
+        ``decode_dispatch``, ``decode_readback`` and ``retire`` (of the
+        step before), ``prefill_readback`` (the seat's first token),
+        ``tail`` (observability/spans.py; the span less the children is
+        the loop's own time). Where a request was waiting with a slot
+        free, or the lane had a further chunk, when the step went out,
+        ``admit`` and ``prefill_chunk`` (``ahead`` 1) stand between
+        ``decode_dispatch`` and ``decode_readback`` (``_lane_dispatch``);
+        in front of the step they are the fallback for a request that
+        arrived, or a slot that was freed, since. A serial engine
+        (``_serial``) reads its step at once, and its seat follows its
+        ``prefill_readback`` as it always did."""
         with self._span(_spans.SRV_STEP, step=self._iterations):
             return self._iterate()
 
@@ -1154,7 +1204,7 @@ class ServingEngine:
         here."""
         return _spans.span(self.spans, self.stats.clock, kind, **fields)
 
-    def _moe_counts(self, moe: list, pending: list) -> dict:
+    def _moe_counts(self, moe: list, pending: list, lens=None) -> dict:
         """What the decode read-back brought beside the tokens, as meta of
         the ``decode_step`` span: of the step's expert layers
         (``MoETransformerLM.experts``' counters, one row a layer) the most
@@ -1163,13 +1213,14 @@ class ServingEngine:
         touched (mean over layers); and what a cached token costs. The
         chunks' counters go onto their own ``prefill_chunk`` spans. Rows
         routed count the whole slot batch: an idle slot's token is routed
-        and multiplied like any other."""
+        and multiplied like any other. ``lens``: the mirror of the device's
+        lengths as the step had them (``_Flight.lens``)."""
         if self._hybrid:
             return self._hybrid_counts(moe, pending)
         if self._windowed:
-            return self._windowed_counts(moe, pending)
+            return self._windowed_counts(moe, pending, lens)
         if self._cca:
-            return self._cca_counts(moe, pending)
+            return self._cca_counts(moe, pending, lens)
         if not moe:          # no expert trunk, or the chaos build's step
             return {}
         return self._routed_counts(moe, pending)
@@ -1189,7 +1240,7 @@ class ServingEngine:
                 "experts_touched": float(st[:, 1].mean()),
                 "cache_bytes_per_token": self._cache_bytes_per_token}
 
-    def _cca_counts(self, moe: list, pending: list) -> dict:
+    def _cca_counts(self, moe: list, pending: list, lens) -> dict:
         """Meta of a ``cca`` trunk's ``decode_step`` span:
         :meth:`_hybrid_meta` (what a cached token and a slot's conv tails
         cost); ``live_positions``, the kernel's count beside the span's own
@@ -1201,8 +1252,8 @@ class ServingEngine:
         near 1 that it is saturated. A chunk's ``router_top_p`` goes onto
         its own ``prefill_chunk`` span beside its rows over routed."""
         meta = self._hybrid_meta()
-        if self._slot_len is not None:
-            meta["live_positions"] = int(self._slot_len.sum())
+        if lens is not None:
+            meta["live_positions"] = int(lens.sum())
         if not moe:
             return meta
         meta.update(self._routed_counts(moe, pending))
@@ -1274,7 +1325,7 @@ class ServingEngine:
                 walked / -(-(chunk.start + real) // KEY_BLOCK)
         return meta
 
-    def _windowed_counts(self, moe: list, pending: list) -> dict:
+    def _windowed_counts(self, moe: list, pending: list, lens) -> dict:
         """Meta of an ``attn_pattern`` trunk's ``decode_step`` span:
         :meth:`_windowed_meta`; ``window_fetched_over_live`` (the positions
         the window layers' kernel fetches — the one or two ring blocks of
@@ -1285,8 +1336,8 @@ class ServingEngine:
         one row a layer) what :meth:`_hybrid_counts` says of a held
         share."""
         meta = self._windowed_meta()
-        if self._slot_len is not None:
-            n = self._slot_len[self._slot_len > 0]
+        if lens is not None:
+            n = lens[lens > 0]
             w = self.model.cfg.window
             blocks = -(-n // LANES) - np.maximum(n - w, 0) // LANES
             inside = int(np.minimum(n, w).sum())
@@ -1312,7 +1363,8 @@ class ServingEngine:
 
     def _loop_counts(self, pdfs: list, pending: list, running) -> dict:
         """Meta of a looped trunk's ``decode_step`` span: :meth:`_loop_meta`
-        over the requests running at dispatch and, where the trunk has its
+        over the rows the step ran (``running``, their slots) and, where the
+        trunk has its
         gate, ``exit_pdf``: the mean over their slots of the distribution
         over exit passes, which the read-back brought beside the tokens
         (``pdfs[0]``, (slots, passes)). The chunks' means (``pdfs[1:]``) go
@@ -1320,50 +1372,61 @@ class ServingEngine:
         for (chunk_span, _, _), pdf in zip(pending, pdfs[1:]):
             chunk_span.amend(exit_pdf=pdf.tolist())
         meta = self._loop_meta(len(running))
-        if pdfs:
+        if pdfs and running:
             meta["exit_pdf"] = pdfs[0][running].mean(0).tolist()
         return meta
 
-    def _attn_counts(self) -> dict:
-        """Of the step just dispatched, two ratios in which 1 is ideal,
-        from the mirror of the device's lengths.
-        ``attn_fetched_over_live``: the positions ``decode_attention``
-        fetches (every slot's length rounded up to the kernel's block)
-        over those the running requests attend to.
-        ``append_moved_over_new``: the bytes the kernel moves between HBM
-        and VMEM to append (the block of 128 positions it writes back for
-        every slot whose length is over 0; the block's read is the
-        attention's own fetch) over the bytes of the running requests' new
-        K/V: a ratio of positions, since both are K and V of every head
-        and layer. And ``idle_fetched``: the positions of those fetched
-        that belong to no running request, 0 while every row that is not
-        running stands at length 0."""
-        fetched = -(-self._slot_len // LANES) * LANES
-        running = self.sched.running
-        live = sum(req.prompt_len + len(req.tokens)
-                   for req in running.values())
-        written = LANES * np.count_nonzero(self._slot_len)
+    def _attn_counts(self, fl: "_Flight") -> dict:
+        """Of a step, two ratios in which 1 is ideal, from the mirror of
+        the device's lengths as the step had them (``fl.lens``, with
+        ``fl.rows`` the rows it ran). ``attn_fetched_over_live``: the
+        positions ``decode_attention`` fetches (every slot's length
+        rounded up to the kernel's block) over those the running requests
+        attend to. ``append_moved_over_new``: the bytes the kernel moves
+        between HBM and VMEM to append (the block of 128 positions it
+        writes back for every slot whose length is over 0; the block's
+        read is the attention's own fetch) over the bytes of the running
+        requests' new K/V: a ratio of positions, since both are K and V of
+        every head and layer. And ``idle_fetched``: the positions of those
+        fetched that belong to no running request, 0 while every row that
+        is not running stands at length 0. {} of a step that ran no row
+        (the device had retired them all before the host knew)."""
+        ran = list(fl.rows)
+        if not ran:
+            return {}
+        fetched = -(-fl.lens // LANES) * LANES
+        written = LANES * np.count_nonzero(fl.lens)
         total = fetched.sum()
-        return {"attn_fetched_over_live": float(total / live),
-                "append_moved_over_new": float(written / len(running)),
-                "idle_fetched": int(total - fetched[list(running)].sum())}
+        return {"attn_fetched_over_live": float(total / fl.lens[ran].sum()),
+                "append_moved_over_new": float(written / len(ran)),
+                "idle_fetched": int(total - fetched[ran].sum())}
 
-    def _log_routing(self, step, tapped: list, chunks: list) -> None:
+    def _log_routing(self, step, tapped: list, chunks: list,
+                     rows: dict) -> None:
         """Into ``routing_log``: the chunks' choices (their real tokens,
         at the positions they wrote), then this step's, one position for
-        every running request: that of the token it was fed."""
+        every request it is booked to (``rows``, before the booking):
+        that of the token it was fed."""
         log = self.routing_log
         for (rid, start, _, real), routing in zip(tapped, chunks):
             log.setdefault(rid, []).append((start, routing[:, 0, :real]))
-        for slot, req in self.sched.running.items():
+        for slot, req in rows.items():
             log.setdefault(req.rid, []).append(
                 (req.prompt_len + len(req.tokens) - 1, step[:, slot]))
+
+    @property
+    def _serial(self) -> bool:
+        """Whether every step is read back before anything else goes out
+        (docs/SERVING.md, "The host loop"): with speculation the next
+        step's drafts are made from this step's tokens on the host, and
+        chaos chooses its poison row and its hang a step at a time."""
+        return self._spec is not None or self.chaos is not None
 
     def _iterate(self) -> list[Request]:
         n_it = self._iterations
         finished: list[Request] = []
-        ran_chunk = ran_decode = False
-        stall_excess = 0.0
+        ran_chunk = ran_decode = read_step = False
+        self._stall_excess = 0.0
         gp = self.goodput
         if gp is not None:
             # the iteration window: two clock reads (entry/exit) — the
@@ -1399,147 +1462,53 @@ class ServingEngine:
             # decode lane: every occupied slot advances one token — or,
             # with speculation on, up to max_draft + 1 through one
             # fixed-shape verify forward (chaos keeps the plain step:
-            # poison-row semantics are per-token)
+            # poison-row semantics are per-token). The plain step goes out
+            # BEFORE the step in front of it is read (docs/SERVING.md,
+            # "The host loop"; _serial engines read at once)
+            due, lane_s = None, 0.0
             if self.sched.running:
                 t0 = self.stats.clock()
-                n_slots = len(self.sched.running)
-                plan = spec_out = None
-                moe: list = []      # the step's expert counters, if any
-                counts: dict = {}   # what rides on the decode_step span
+                plan = fl = None
                 with self._span(_spans.SRV_DECODE_DISPATCH, step=n_it):
                     if chaos is not None:
                         chaos.maybe_hang(it)
-                        poison = chaos.poison_slot(
-                            self.sched.running.keys())
-                        step = self._prog("step_chaos", lambda: jax.jit(
-                            self._step_chaos_impl, donate_argnums=(1,)))
-                        self._state, ok = step(self.engine.params,
-                                               self._state,
-                                               jnp.int32(poison))
+                        fl = self._launch(
+                            n_it, t0, "step_chaos", self._step_chaos_impl,
+                            jnp.int32(chaos.poison_slot(
+                                self.sched.running.keys())))
                     else:
                         if self._spec is not None:
                             plan = self._spec_plan()
                         if plan is None:
-                            step = self._prog("step", lambda: jax.jit(
-                                self._step_impl, donate_argnums=(1,)))
-                            self._state, ok, *moe = step(self.engine.params,
-                                                         self._state)
-                lane_s = 0.0
+                            fl = self._launch(n_it, t0, "step",
+                                              self._step_impl)
                 if plan is None and chaos is None:
                     # next iteration's admission and chunk, behind the step
                     lane_t0 = self.stats.clock()
                     self._lane_dispatch(n_it, ahead=1)
                     lane_s = self.stats.clock() - lane_t0
-                if self._slot_len is not None:
-                    # a running row appends, then attends; the others stay
-                    self._slot_len[self._slot_len > 0] += 1
-                    counts = self._attn_counts()
-                with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
-                    if plan is not None:
-                        # verify + host acceptance + commit, all inside
-                        # the watchdog window; scheduler effects deferred
-                        # below
-                        spec_out = self._spec_verify_commit(plan)
-                    else:
-                        # ONE fused host read-back per iteration (tok +
-                        # done + per-row logit finiteness together): the
-                        # per-iteration sync is the scheduler's steering
-                        # cost — don't pay it twice, and don't let the
-                        # guard add a second one
-                        # ... nor the expert layers' counters (this
-                        # step's, and those of the chunks dispatched since
-                        # the last read-back) a third
-                        pending, self._chunk_stats = self._chunk_stats, []
-                        tapped, self._chunk_routing = self._chunk_routing, []
-                        if self.routing_log is None:
-                            moe = moe[:1]        # the step's routing stays
-                        toks, dones, oks, *moe = jax.device_get(
-                            (self._state.tok, self._state.done, ok, *moe,
-                             *(st for _, st, _ in pending),
-                             *(r for _, _, r, _ in tapped)))
-                        if self.routing_log is not None and moe:
-                            self._log_routing(
-                                moe.pop(1), tapped,
-                                [moe.pop() for _ in tapped][::-1])
-                        counts.update(
-                            self._loop_counts(moe, pending,
-                                              list(self.sched.running))
-                            if self._loops > 1
-                            else self._moe_counts(moe, pending))
-                t1 = self.stats.clock()
-                # the step's dispatch and read-back: the lane's host work
-                # behind the step (a tree match, a tiered restore's tiles)
-                # is not the step's, however long it takes
-                self._last_step_s = t1 - t0 - lane_s
-                # the parent of the decode pair, from the t0/t1 the
-                # watchdog measures anyway; the counts at this boundary
-                # (slots decoding, requests waiting; an expert trunk's
-                # rows and a latent cache's bytes) ride on it
-                _spans.emit(self.spans, _spans.DECODE_STEP, t0, t1,
-                            step=n_it, slots=n_slots,
-                            queue=self.sched.queue_depth,
-                            **({"spec": True} if plan is not None else counts))
-                wd = self.cfg.watchdog_s
-                if wd and self._last_step_s > wd:
-                    # rising edge: the previous iteration was healthy. A
-                    # stall STORM (every step slow — threshold too low, or
-                    # a degraded device) must not burn the max_dumps
-                    # budget that a later terminal post-mortem (SIGTERM,
-                    # nonfinite halt) will need — dump once per episode,
-                    # mark every stall.
-                    new_episode = self._last_stall_iter != \
-                        self._iterations - 1
-                    self._last_stall_iter = self._iterations
-                    stall_excess = self._last_step_s - wd
-                    self.stats.on_watchdog_stall(self._last_step_s, wd)
-                    warning_once(
-                        f"serving watchdog: a decode step exceeded "
-                        f"{wd:.3f}s (see Serve/last_stall_s for the "
-                        "latest measurement; further stalls only count)")
-                    if self.flight is not None:
-                        # the black box IS the post-mortem: stamp why,
-                        # then freeze the last-N events + snapshots
-                        self.flight.note("watchdog_stall", t=t1,
-                                         step_s=self._last_step_s,
-                                         threshold_s=wd,
-                                         iteration=self._iterations)
-                        if new_episode:
-                            self.flight.dump("watchdog_stall")
-                if self._step_anomaly is not None \
-                        and self._step_anomaly.observe(self._last_step_s):
-                    r = self.stats.registry
-                    r.counter("Serve/step_time_regressions").inc()
-                    med, mad = self._step_anomaly.stats()
-                    r.gauge("Serve/step_time_baseline_s").set(med)
-                    if self.flight is not None:
-                        self.flight.note("step_time_regression", t=t1,
-                                         step_s=self._last_step_s,
-                                         median_s=med, mad_s=mad,
-                                         iteration=self._iterations)
-                with self._span(_spans.SRV_RETIRE, step=n_it):
-                    if spec_out is not None:
-                        finished += self._spec_resolve(spec_out)
-                    else:
-                        if not oks.all():
-                            # retire ONLY the poisoned rows, before
-                            # on_step can append their garbage tokens;
-                            # every other slot's bookkeeping (and output
-                            # bits) is untouched
-                            bad = [s for s in np.nonzero(~oks)[0]
-                                   if int(s) in self.sched.running]
-                            finished += self.sched.retire_nonfinite(bad)
-                            self._unseat(bad)
-                        self._decode_slot_steps += n_slots
-                        self._decode_emitted += len(self.sched.running)
-                        ended = self.sched.on_step(toks, dones)
-                        if ended and self._slot_len is not None:
-                            # at eos or out of tokens: the step has put
-                            # these rows at length 0 itself
-                            self._slot_len[[r.slot for r in ended]] = 0
-                        finished += ended
+                if plan is not None:
+                    finished += self._spec_step(plan, n_it, t0)
+                    read_step = True
+                elif self._serial:
+                    due = fl
+                else:
+                    # the books run one step behind: what is read now is
+                    # the step that was in flight when this one went out
+                    due, self._inflight = self._inflight, fl
                 ran_decode = True
+            else:
+                # nothing left running by the books: the last step out, if
+                # one is, ran rows the device had retired already
+                due, self._inflight = self._inflight, None
+            if due is not None:
+                finished += self._book(due, n_it, lane_s)
+                read_step = True
+            if self._first is not None:
+                finished += self._first_token(n_it)
         with self._span(_spans.SRV_TAIL, step=n_it):
-            self._seated = None     # inserted long since: let its cache go
+            if self._seated is not None and self._seated[0].is_ready():
+                self._seated = None     # inserted: let its cache go
             if self._demote_ahead is not None:
                 # background demotion lane: stage idle tree-held pages
                 # into the tier BEFORE pressure (the staged gathers drain
@@ -1576,14 +1545,206 @@ class ServingEngine:
             if gp is not None:
                 gp.on_serving_iteration(
                     gp_t0, gp.clock(),
-                    decode_s=self._last_step_s if ran_decode else 0.0,
-                    ran_decode=ran_decode, ran_chunk=ran_chunk,
+                    # the step this iteration READ: the device's time
+                    decode_s=self._last_step_s if read_step else 0.0,
+                    ran_decode=ran_decode or read_step, ran_chunk=ran_chunk,
                     compiled=self.compiles > gp_compiles0,
-                    stall_excess_s=stall_excess, draining=self._draining,
+                    stall_excess_s=self._stall_excess,
+                    draining=self._draining,
                     idle=self.sched.idle and self._prefill is None)
             for req in finished:
                 self._store_result(req)
+            # what a settle between two iterations retired (stored then)
+            late, self._late = self._late, []
+        return late + finished
+
+    def _fetched(self, read: tuple) -> tuple:
+        """Of a step's outputs (tok, done, ok, then the trunk's counters
+        and, last, its routing) what the host fetches: the routing stays
+        on the device unless ``routing_log`` taps it."""
+        return read if self.routing_log is not None else read[:4]
+
+    def _launch(self, n_it: int, t0: float, key: str, impl,
+                *extra) -> "_Flight":
+        """Dispatch one slot step (``key`` names its program) on the state
+        the last program left, and say what the host will need to book it:
+        the rows it runs, by the books of this moment (a snapshot: by the
+        time the step is read a slot may hold a successor), the queue's
+        depth, the mirror of the lengths it leaves, and the counters of
+        the chunks that ran since the step before (they are ready when
+        this step is). The outputs the host reads start their way to the
+        host now."""
+        step = self._prog(key, lambda: jax.jit(impl, donate_argnums=(1,)))
+        self._state, read = step(self.engine.params, self._state, *extra)
+        for out in self._fetched(read):
+            out.copy_to_host_async()
+        lens = None
+        if self._slot_len is not None:
+            # a running row appends, then attends; the others stay
+            self._slot_len[self._slot_len > 0] += 1
+            lens = self._slot_len.copy()
+        ahead = int(self._inflight is not None)
+        if ahead:
+            self.stats.registry.counter("Serve/decode_steps_ahead").inc()
+        fl = _Flight(step=n_it, t0=t0, rows=dict(self.sched.running),
+                     queue=self.sched.queue_depth, ahead=ahead, read=read,
+                     lens=lens, chunk_stats=self._chunk_stats,
+                     chunk_routing=self._chunk_routing)
+        self._chunk_stats, self._chunk_routing = [], []
+        return fl
+
+    def _book(self, fl: "_Flight", n_it: int,
+              lane_s: float = 0.0) -> list[Request]:
+        """Read one step back and book it: its tokens to the requests its
+        dispatch ran and the host has not retired since, its counters onto
+        its ``decode_step`` span, its time to the watchdog. Returns the
+        requests that ended with it."""
+        finished: list[Request] = []
+        running = self.sched.running
+        with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
+            # ONE fused host read-back a step (tok + done + per-row
+            # logit finiteness together): the per-step sync is the
+            # scheduler's steering cost — don't pay it twice, and don't
+            # let the guard add a second one
+            # ... nor the expert layers' counters (this step's, and
+            # those of the chunks dispatched since the step before) a
+            # third
+            pending = fl.chunk_stats
+            tapped = fl.chunk_routing if self.routing_log is not None else []
+            toks, dones, oks, *moe = jax.device_get(
+                (*self._fetched(fl.read), *(st for _, st, _ in pending),
+                 *(r for _, _, r, _ in tapped)))
+            # a slot the device retired and the host seated again, a row
+            # the host retired meanwhile: not this step's to book
+            rows = {slot: req for slot, req in fl.rows.items()
+                    if running.get(slot) is req}
+            if self.routing_log is not None and moe:
+                self._log_routing(
+                    moe.pop(1), tapped,
+                    [moe.pop() for _ in tapped][::-1], rows)
+            counts = self._attn_counts(fl) if fl.lens is not None else {}
+            counts.update(
+                self._loop_counts(moe, pending, list(fl.rows))
+                if self._loops > 1
+                else self._moe_counts(moe, pending, fl.lens))
+        self._time_step(fl.t0, lane_s, step=fl.step, slots=len(fl.rows),
+                        queue=fl.queue, ahead=fl.ahead, **counts)
+        with self._span(_spans.SRV_RETIRE, step=n_it):
+            if not oks.all():
+                # retire ONLY the poisoned rows, before on_step can
+                # append their garbage tokens; every other slot's
+                # bookkeeping (and output bits) is untouched
+                bad = [int(s) for s in np.nonzero(~oks)[0] if int(s) in rows]
+                finished += self.sched.retire_nonfinite(bad)
+                self._unseat(bad)
+                for slot in bad:
+                    del rows[slot]
+            self._decode_slot_steps += len(fl.rows)
+            self._decode_emitted += len(rows)
+            ended = self.sched.on_step(toks, dones, rows)
+            # at eos or out of tokens: the step has put these rows at
+            # length 0 itself, and the step behind it ran them so
+            self._retired_on_device([r.slot for r in ended])
+            finished += ended
         return finished
+
+    def _retired_on_device(self, slots: list) -> None:
+        """Rows the host has just learned the device retired itself (eos,
+        the budget): the mirror follows, and the step in flight, which
+        went out before the host knew, is corrected to what the device ran
+        — these rows at length 0, not running."""
+        if not slots:
+            return
+        fl = self._inflight
+        if self._slot_len is not None:
+            self._slot_len[slots] = 0
+            if fl is not None:
+                fl.lens[slots] = 0
+        if fl is not None:
+            for slot in slots:
+                fl.rows.pop(slot, None)
+
+    def _time_step(self, t0: float, lane_s: float, **fields) -> None:
+        """A step has been read: its ``decode_step`` span and the
+        watchdog's measurement. The span runs from the read-back in front
+        of it (from the step's dispatch where that came later: the first
+        step, a serial engine) to its own, which is when the device ran
+        it; ``_last_step_s`` is that less the lane's host work in between
+        (a tree match, a tiered restore's tiles: not the step's, however
+        long it takes)."""
+        t1 = self.stats.clock()
+        t0 = max(t0, self._read_t)
+        self._read_t = t1
+        self._last_step_s = t1 - t0 - lane_s
+        # the counts at the step's dispatch (slots decoding, requests
+        # waiting; an expert trunk's rows and a latent cache's bytes) ride
+        # on the span
+        _spans.emit(self.spans, _spans.DECODE_STEP, t0, t1, **fields)
+        wd = self.cfg.watchdog_s
+        if wd and self._last_step_s > wd:
+            # rising edge: the previous iteration was healthy. A
+            # stall STORM (every step slow — threshold too low, or
+            # a degraded device) must not burn the max_dumps
+            # budget that a later terminal post-mortem (SIGTERM,
+            # nonfinite halt) will need — dump once per episode,
+            # mark every stall.
+            new_episode = self._last_stall_iter != \
+                self._iterations - 1
+            self._last_stall_iter = self._iterations
+            self._stall_excess = self._last_step_s - wd
+            self.stats.on_watchdog_stall(self._last_step_s, wd)
+            warning_once(
+                f"serving watchdog: a decode step exceeded "
+                f"{wd:.3f}s (see Serve/last_stall_s for the "
+                "latest measurement; further stalls only count)")
+            if self.flight is not None:
+                # the black box IS the post-mortem: stamp why,
+                # then freeze the last-N events + snapshots
+                self.flight.note("watchdog_stall", t=t1,
+                                 step_s=self._last_step_s,
+                                 threshold_s=wd,
+                                 iteration=self._iterations)
+                if new_episode:
+                    self.flight.dump("watchdog_stall")
+        if self._step_anomaly is not None \
+                and self._step_anomaly.observe(self._last_step_s):
+            r = self.stats.registry
+            r.counter("Serve/step_time_regressions").inc()
+            med, mad = self._step_anomaly.stats()
+            r.gauge("Serve/step_time_baseline_s").set(med)
+            if self.flight is not None:
+                self.flight.note("step_time_regression", t=t1,
+                                 step_s=self._last_step_s,
+                                 median_s=med, mad_s=mad,
+                                 iteration=self._iterations)
+
+    def _spec_step(self, plan, n_it: int, t0: float) -> list[Request]:
+        """The speculative lane's step: verify + host acceptance + commit,
+        all inside the watchdog window; the scheduler's half after the
+        timing bookkeeping, exactly where the plain lane books."""
+        with self._span(_spans.SRV_DECODE_READBACK, step=n_it):
+            spec_out = self._spec_verify_commit(plan)
+        self._time_step(t0, 0.0, step=n_it,
+                        slots=len(self.sched.running),
+                        queue=self.sched.queue_depth, ahead=0, spec=True)
+        with self._span(_spans.SRV_RETIRE, step=n_it):
+            return self._spec_resolve(spec_out)
+
+    def _settle(self) -> None:
+        """Read the step in flight back and book it, outside the loop's
+        own order: whoever reads or changes slot state from the host's
+        books between two iterations (``cancel``, a deadline's or a
+        hand-off's ``_unseat``, ``export_request`` / ``import_request``,
+        ``drain``, ``close``) calls this first, so that the books are the
+        device's. What it retires is stored, and handed back by the next
+        ``step()``. Nothing in flight: nothing happens."""
+        fl, self._inflight = self._inflight, None
+        if fl is None:
+            return
+        retired = self._book(fl, self._iterations)
+        for req in retired:
+            self._store_result(req)
+        self._late += retired
 
     def _admit(self) -> None:
         """Start the head-of-queue request's prefill: pop it, tell the
@@ -1685,6 +1846,12 @@ class ServingEngine:
     def _expire_deadlines(self) -> list[Request]:
         """One deadline sweep over queue + slots + the prefill lane."""
         now = self.stats.clock()
+        if self._inflight is not None and any(
+                r.deadline_total is not None and now >= r.deadline_total
+                for r in self.sched.running.values()):
+            # a row is about to be taken out of its slot: book the step
+            # in flight first (it may have ended the request in time)
+            self._settle()
         expired = self.sched.expire_deadlines(now)
         self._unseat([req.slot for req in expired])
         if self._prefill is not None:
@@ -1786,14 +1953,48 @@ class ServingEngine:
             return []
         pf = out
         self._prefill = None
+        for arr in (pf.tok, pf.done):
+            arr.copy_to_host_async()
+        self._first = req, pf
+        if req.max_new == 1 or self._serial or self.on_placed is not None:
+            # one token asked for: it is never seated. A serial engine
+            # plans its next step from the token, and a hand-off takes the
+            # request before a step can run it: read first, seat after
+            return self._first_token(n_it)
+        # the seat goes out right behind the chunk, with the slot the host
+        # chooses now; the token it carries is read behind the step
+        with self._span(_spans.SRV_PLACE, step=n_it):
+            self._place(req, pf)
+        return []
+
+    def _first_token(self, n_it: int) -> list[Request]:
+        """Read the first token the lane's final chunk sampled and book
+        it. Of a request seated already (``_prefill_advance``) the chunk
+        ran before the step now running, so the read waits for nothing
+        the device is not doing anyway; where it ended the request (eos),
+        the insert has seated the row as one that is not running and the
+        host gives the slot back. Otherwise (one token asked for, a serial
+        engine, a hand-off) the seat follows the read, as it always did."""
+        (req, pf), self._first = self._first, None
         with self._span(_spans.SRV_PREFILL_READBACK, step=n_it):
             tok, done = jax.device_get((pf.tok, pf.done))
             first_tok = int(tok[0])
             ended = req.max_new == 1 or bool(done[0])
-        if ended:
+        if req.slot < 0:
+            if ended:
+                return [self.sched.complete_at_prefill(req, first_tok)]
+            with self._span(_spans.SRV_PLACE, step=n_it):
+                self._place(req, pf, first_tok)
+        elif ended:
+            slot = req.slot
+            self.sched.unseat(req)
+            if self._paged:
+                self._table[slot] = 0
+                self._table_dirty = True
+            self._retired_on_device([slot])
             return [self.sched.complete_at_prefill(req, first_tok)]
-        with self._span(_spans.SRV_PLACE, step=n_it):
-            self._place(req, first_tok, pf)
+        else:
+            self.sched.first_token(req, first_tok)
         return []
 
     def _insert_impl(self, state, slot, *rest):
@@ -1804,10 +2005,14 @@ class ServingEngine:
             state, slot, *rest)
         return state, state.done[slot]
 
-    def _place(self, req: Request, first_tok: int, pf) -> None:
+    def _place(self, req: Request, pf, first_tok: Optional[int] = None):
         """Seat a prefilled request: take a slot, dispatch the insert of
-        its cache into the slot state."""
-        slot = self.sched.place(req, first_tok)
+        its cache into the slot state. ``first_tok``: the token ``pf``
+        carries, where the host has read it already (a seat in the serial
+        order, with its hand-off)."""
+        slot = self.sched.seat(req)
+        if first_tok is not None:
+            self.sched.first_token(req, first_tok)
         # donate only the slot state: the batch-1 prefill buffers have
         # different shapes and could never alias the slot cache anyway
         ins = self._prog("insert", lambda: jax.jit(
@@ -1833,11 +2038,13 @@ class ServingEngine:
             if self._slot_len is not None:
                 self._slot_len[slot] = req.prompt_len
         self._seated = inserted, pf.cache
-        if self.on_placed is not None:
+        if self.on_placed is not None and first_tok is not None:
             # disaggregated handoff: the fleet may export the freshly
             # seated request and release the slot before this very
             # iteration's decode lane runs — a prefill replica never
-            # spends a decode step on a handed-off request
+            # spends a decode step on a handed-off request. The hook reads
+            # slot state: the step in flight is booked first
+            self._settle()
             self.on_placed(req, slot)
 
     def _unseat(self, slots) -> None:
@@ -2067,6 +2274,9 @@ class ServingEngine:
                 raise RuntimeError(
                     f"serving failed to drain in {max_iterations} "
                     "iterations — scheduler stuck?")
+        # the last step out ran nothing the books still hold: read it, so
+        # that a drained engine has nothing in flight
+        self._settle()
         return self.results
 
     def pop_result(self, rid: int) -> Optional[Request]:
@@ -2084,6 +2294,7 @@ class ServingEngine:
         if not self._paged:
             raise RuntimeError("export_request needs the paged KV cache "
                                "(set serving.page_size)")
+        self._settle()       # the books (its tokens) as the device has them
         if req.slot < 0 or self.sched.running.get(req.slot) is not req:
             raise ValueError(f"request {req.rid} is not slot-resident here")
         with self.engine.mesh:
@@ -2128,6 +2339,7 @@ class ServingEngine:
         if not self._paged:
             raise RuntimeError("import_request needs the paged KV cache "
                                "(set serving.page_size)")
+        self._settle()       # a slot the step in flight freed counts
         if not self.sched.free:
             return False
         if self.tenantscope is not None:
@@ -2793,8 +3005,10 @@ class ServingEngine:
 
     def close(self) -> None:
         """Teardown: stop the telemetry server's listener thread (when
-        one is running). Safe to call more than once; the engine remains
-        usable for serving afterwards."""
+        one is running) and read back the step in flight, if any. Safe to
+        call more than once; the engine remains usable for serving
+        afterwards."""
+        self._settle()
         if self.telemetry is not None:
             self.telemetry.close()
             self.telemetry = None

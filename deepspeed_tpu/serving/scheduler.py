@@ -356,15 +356,34 @@ class Scheduler:
             self.pages.release(req.rid)
 
     def place(self, req: Request, first_tok: int) -> int:
-        """Prefill finished: record the first token, occupy a slot."""
-        req.first_token_t = self.stats.on_first_token(req.submit_t)
-        req.tokens.append(int(first_tok))
+        """Prefill finished: record the first token, occupy a slot (the
+        serial order: the token was read before the seat)."""
+        slot = self.seat(req)
+        self.first_token(req, first_tok)
+        return slot
+
+    def seat(self, req: Request) -> int:
+        """Occupy a slot for a prefilled request whose first token the
+        host may not have read yet (``first_token`` follows)."""
         slot = self.free.pop(0)
         req.slot = slot
         self.running[slot] = req
-        _spans.emit(self.spans, _spans.PLACED, req.first_token_t,
-                    rid=req.rid, slot=slot, **self._attempt_meta(req))
         return slot
+
+    def first_token(self, req: Request, first_tok: int) -> None:
+        """The first token of a seated request, as the host reads it."""
+        req.first_token_t = self.stats.on_first_token(req.submit_t)
+        req.tokens.append(int(first_tok))
+        _spans.emit(self.spans, _spans.PLACED, req.first_token_t,
+                    rid=req.rid, slot=req.slot, **self._attempt_meta(req))
+
+    def unseat(self, req: Request) -> None:
+        """Give back the slot of a request seated ahead of its first
+        token, which then ended it (``complete_at_prefill`` follows): the
+        slot is the next one taken again, and the request held none."""
+        del self.running[req.slot]
+        self.free.insert(0, req.slot)
+        req.slot = -1
 
     def adopt(self, req: Request) -> int:
         """Seat an ALREADY-prefilled request into a free slot without
@@ -462,12 +481,17 @@ class Scheduler:
                     **self._attempt_meta(req))
 
     # -------------------------------------------------------------- decode
-    def on_step(self, toks: np.ndarray, dones: np.ndarray) -> list:
+    def on_step(self, toks: np.ndarray, dones: np.ndarray,
+                rows: Optional[dict] = None) -> list:
         """Account one slot decode step: per-slot next tokens + done flags
-        (device read-back). Returns the requests retired this step."""
+        (device read-back). ``rows`` (slot -> request) are the rows the
+        step's tokens belong to — the engine's snapshot of the step's
+        dispatch less what was retired since; None: everything running.
+        Returns the requests retired this step."""
+        rows = self.running if rows is None else rows
         finished = []
-        for slot in sorted(self.running):
-            req = self.running[slot]
+        for slot in sorted(rows):
+            req = rows[slot]
             req.tokens.append(int(toks[slot]))
             if bool(dones[slot]) or len(req.tokens) >= req.max_new:
                 req.status = RequestStatus.OK

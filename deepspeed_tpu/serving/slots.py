@@ -58,10 +58,15 @@ def init_slots(cfg, slots: int, max_len: int, dtype=None) -> GenCarry:
 def seat_row(state: GenCarry, slot, *, tok, rng, done, length, left):
     """The per-slot vectors of ``state`` with row ``slot`` (traced i32)
     taking a request's (1,)-shaped values: (tok, rng, done, length, left).
-    ``left`` None: :data:`NO_BUDGET`. Shared by every program that seats a
-    request (here, and the paged pool's insert and import)."""
+    ``left`` None: :data:`NO_BUDGET`. A request whose first token ended it
+    (``done``: eos out of the final chunk) is seated as every row that is
+    not running stands, at length 0: the serving loop seats a request
+    before it has read that token (docs/SERVING.md, "The host loop").
+    Shared by every program that seats a request (here, and the paged
+    pool's insert and import)."""
     left = jnp.full((1,), NO_BUDGET, jnp.int32) if left is None \
         else jnp.asarray(left, jnp.int32).reshape(1)
+    length = jnp.where(done.reshape(1), 0, length.reshape(1))
 
     def put(vec, row):
         return lax.dynamic_update_slice(

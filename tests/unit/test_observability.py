@@ -917,10 +917,13 @@ def iteration_spans(tiny_server):
     return evs, steps
 
 
-PHASE_ORDER = ["srv.deadlines", "srv.admit", "prefill_chunk",
-               "srv.prefill_readback", "srv.place", "srv.decode_dispatch",
-               "srv.admit.ahead", "prefill_chunk.ahead",
-               "srv.decode_readback", "srv.retire", "srv.tail"]
+# the plain engine's order (docs/SERVING.md, "The host loop"): the seat goes
+# out behind its chunk and the step behind that; then the step BEFORE is
+# read back and booked, and last the seat's first token
+PHASE_ORDER = ["srv.deadlines", "srv.admit", "prefill_chunk", "srv.place",
+               "srv.decode_dispatch", "srv.admit.ahead",
+               "prefill_chunk.ahead", "srv.decode_readback", "srv.retire",
+               "srv.prefill_readback", "srv.tail"]
 
 
 def _phase(e):
@@ -975,14 +978,29 @@ def test_serving_iteration_spans(iteration_spans, check):
         finals = [e.meta["final"] for e in evs if e.kind == "prefill_chunk"]
         assert finals.count(True) == 4 and finals.count(False) == 3
     elif check == "decode_step_is_the_parent_of_the_decode_pair":
-        decode = {e.step: e for e in evs if e.kind == "decode_step"}
-        assert decode
-        for step, d in decode.items():
-            pair = [k for k in steps[step][1] if k.kind in (
-                "srv.decode_dispatch", "srv.decode_readback")]
-            assert len(pair) == 2
-            assert d.t0 < pair[0].t0 and pair[1].t1 < d.t1
-            assert 1 <= d.meta["slots"] <= 2 and d.meta["queue"] >= 0
+        # a step is dispatched in one iteration and read back in the
+        # next: its span runs from the read-back before it (from its own
+        # dispatch where nothing was in flight) to its own, so the spans
+        # tile the time the device spent on each
+        decode = sorted((e for e in evs if e.kind == "decode_step"),
+                        key=lambda e: e.t1)
+        reads = sorted((e for e in evs if e.kind == "srv.decode_readback"),
+                       key=lambda e: e.t1)
+        assert decode and len(decode) == len(reads)
+        before = None
+        for d, read in zip(decode, reads):
+            (dispatch,) = [k for k in steps[d.step][1]
+                           if k.kind == "srv.decode_dispatch"]
+            assert read.step == d.step + 1 and read.t1 < d.t1
+            if d.meta["ahead"]:
+                assert d.t0 == before.t1 and dispatch.t1 < before.t1
+            else:
+                assert d.t0 < dispatch.t0
+                assert before is None or before.t1 < d.t0
+            assert 0 <= d.meta["slots"] <= 2 and d.meta["queue"] >= 0
+            before = d
+        assert [d.meta["ahead"] for d in decode].count(1) >= len(decode) - 2
+        assert [d.step for d in decode] == sorted(d.step for d in decode)
     elif check == "readers_kinds_and_meta":
         # what export.py, flight.py, capacity.py, _decode_rate and
         # _prefill_rate read, under the names they read it by

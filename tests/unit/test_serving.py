@@ -163,12 +163,14 @@ def _chunks(srv):
 
 
 def _between_the_decode_pair(srv, span):
-    """``span`` lies after ``srv.decode_dispatch`` and before
-    ``srv.decode_readback`` of the iteration it carries."""
+    """``span`` lies after ``srv.decode_dispatch`` of the iteration it
+    carries and before its ``srv.decode_readback`` (of the step before:
+    an iteration whose step is the first out reads nothing back)."""
     pair = {e.kind: e for e in srv.spans.events() if e.step == span.step
             and e.kind in ("srv.decode_dispatch", "srv.decode_readback")}
     return pair["srv.decode_dispatch"].t1 <= span.t0 \
-        and span.t1 <= pair["srv.decode_readback"].t0
+        and ("srv.decode_readback" not in pair
+             or span.t1 <= pair["srv.decode_readback"].t0)
 
 
 @pytest.mark.parametrize("prompts", [(5, 7, 6, 4, 8), (5, 21, 7, 37, 12)],
@@ -237,10 +239,299 @@ def test_arrival_after_the_dispatch_is_admitted_in_front(setup):
                     and e.kind == "srv.decode_dispatch")
     assert chunk.t1 <= dispatch.t0
     assert {r.rid for r in srv.sched.running.values()} == {first, late}
-    assert len(srv.sched.running[1].tokens) == 2    # seated, then stepped
+    # seated and its first token read; its step is out, read a step late
+    assert len(srv.sched.running[1].tokens) == 1
+    assert late in {r.rid for r in srv._inflight.rows.values()}
+    srv.step()
+    assert len(srv.sched.running[1].tokens) == 2
     srv.drain()
     _check_parity(model, params, reqs,
                   [srv.pop_result(r).tokens for r in (first, late)])
+
+
+# ------------------------------------------- the books run one step behind
+def _steps(srv):
+    return [e for e in srv.spans.events() if e.kind == "decode_step"]
+
+
+def test_step_dispatched_before_its_predecessor_is_read(setup, monkeypatch):
+    """The plain engine's order: step k + 1 goes out, on the carry step k
+    returned, BEFORE the host reads step k back (``jax.device_get`` of
+    step k's own outputs), so the device never waits for a read-back; the
+    first step out has nothing in front of it (``ahead`` 0), every later
+    one has (``ahead`` 1, ``Serve/decode_steps_ahead``), and ``step()``
+    hands a step's tokens back one call late."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 2, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20,
+                              "spans": True})
+    order, outs, kept = [], {}, []
+    prog, get = srv._prog, jax.device_get
+
+    def spy(key, build):
+        fn = prog(key, build)
+
+        def call(*args):
+            state, read = fn(*args)
+            kept.append(read[0])        # alive: its id names the step
+            outs[id(read[0])] = len(outs)
+            order.append(("out", outs[id(read[0])]))
+            return state, read
+        return call if key == "step" else fn
+
+    def spy_get(tree):
+        first = tree[0] if isinstance(tree, tuple) else None
+        if id(first) in outs:
+            order.append(("read", outs[id(first)]))
+        return get(tree)
+
+    srv._prog = spy
+    monkeypatch.setattr(jax, "device_get", spy_get)
+    rng = np.random.default_rng(21)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), 9, 60 + i)
+            for i, P in enumerate([5, 7])]
+    rids = [srv.submit(p, n, seed=s) for p, n, s in reqs]
+    srv.step()
+    assert order == [("out", 0)] and srv._inflight is not None
+    # seated with its first token; the step's own is in flight
+    assert [len(r.tokens) for r in srv.sched.running.values()] == [1]
+    srv.step()
+    assert order == [("out", 0), ("out", 1), ("read", 0)]
+    assert sorted(len(r.tokens) for r in srv.sched.running.values()) == [1, 2]
+    srv.drain()
+    n = len(outs)
+    assert n >= 9
+    assert order == [("out", 0)] + [x for k in range(1, n) for x in (
+        ("out", k), ("read", k - 1))] + [("read", n - 1)]
+    assert [e.meta["ahead"] for e in _steps(srv)] == [0] + [1] * (n - 1)
+    reg = srv.stats.registry
+    assert reg.counter("Serve/decode_steps_ahead").value == n - 1
+    assert reg.counter("Serve/decode_steps").value == n
+    assert srv._inflight is None            # drain leaves nothing in flight
+    _check_parity(model, params, reqs,
+                  [srv.pop_result(r).tokens for r in rids])
+
+
+def _solo_with(eng, prompt, max_new, seed):
+    return np.asarray(eng.generate(
+        jnp.asarray(prompt[None], jnp.int32), max_new, temperature=0.8,
+        top_k=20, request_seeds=[seed], cache_len=M))[0]
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_turnover_with_the_books_a_step_behind(setup, paged):
+    """A seeded mix that turns three slots over while every step goes out
+    ahead: a request whose first token is eos (seated before the host has
+    read that token, so the insert seats it as a row that is not running
+    and the slot comes back), one that asks for one token (never seated),
+    a ``cancel`` and a deadline that fall while a step is in flight. Every
+    request that ran to its end has solo ``generate()``'s tokens, the two
+    that were cut a prefix of them, and no slot or page is lost."""
+    from _fake_clock import TickClock
+
+    cfg, model, params, _ = setup
+    rng = np.random.default_rng(22)
+    shapes = [(5, 9), (9, 6), (20, 1), (7, 12), (13, 7), (6, 30), (11, 30),
+              (8, 5), (17, 4)]
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), N, 300 + i)
+            for i, (P, N) in enumerate(shapes)]
+    # the first token of request 1 becomes the eos
+    eos = int(_solo(model, params, *reqs[1])[0])
+    eng = ds.init_inference(model, params,
+                            {"dtype": "float32", "eos_token_id": eos})
+    clock = TickClock()
+    srv = ServingEngine(eng, {"slots": 3, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20,
+                              "spans": True,
+                              **({"page_size": 8} if paged else {})},
+                        clock=clock)
+    rids = [srv.submit(p, n, seed=s,
+                       total_deadline_s=40.0 if i == 6 else None)
+            for i, (p, n, s) in enumerate(reqs)]
+    cancelled, ended, handed = None, {}, {}
+    for it in range(400):
+        for req in srv.step():
+            ended[req.rid] = req
+        for req in srv.sched.running.values():
+            # what a streaming caller has seen of each request so far
+            handed[req.rid] = list(req.tokens)
+        running = {r.rid for r in srv.sched.running.values()}
+        if cancelled is None and rids[5] in running \
+                and len(srv.results.get(rids[5], srv._find_request(
+                    rids[5])).tokens) >= 3:
+            assert srv._inflight is not None        # a step is in flight
+            cancelled = srv.cancel(rids[5])
+            assert srv._inflight is None            # ... and was settled
+            ended[cancelled.rid] = cancelled
+        if rids[6] in running and len(handed[rids[6]]) >= 4 \
+                and clock.t < 40.0:
+            assert srv._inflight is not None
+            clock.advance(60.0)
+        if len(ended) == len(reqs):
+            break
+    assert set(ended) == set(rids)
+    status = {i: ended[r].status.name for i, r in enumerate(rids)}
+    assert status == {**{i: "OK" for i in range(len(reqs))},
+                      5: "CANCELLED", 6: "TIMEOUT"}
+    for i, ((p, n, s), rid) in enumerate(zip(reqs, rids)):
+        got, want = ended[rid].tokens, _solo_with(eng, p, n, s)
+        np.testing.assert_array_equal(got, want[:len(got)])
+        if i in (5, 6):
+            assert 3 <= len(got) < n
+            # nothing a caller was handed is taken back
+            assert got[:len(handed[rid])] == handed[rid]
+        else:
+            assert np.all(want[len(got):] == eos)
+            assert len(got) == n or got[-1] == eos
+    assert ended[rids[1]].tokens == [eos] and ended[rids[1]].slot == -1
+    assert len(ended[rids[2]].tokens) == 1 and ended[rids[2]].slot == -1
+    srv.drain()
+    assert sorted(srv.sched.free) == [0, 1, 2] and srv._inflight is None
+    assert not np.asarray(srv._state.cache.length).any()
+    assert np.asarray(srv._state.done).all()
+    steps = _steps(srv)
+    assert sum(e.meta["ahead"] for e in steps) >= len(steps) - 6
+    if paged:
+        snap = srv.pool.snapshot()
+        assert snap["free_pages"] + snap["tree_held_pages"] \
+            == snap["usable_pages"]
+        assert snap["live_requests"] == 0
+
+
+def test_reseated_slot_is_not_given_the_step_in_flight(setup):
+    """One slot, two requests: the step that went out before the host
+    knew the first had ended ran its row at length 0, and by the time it
+    is read the slot holds the second. That step's token is nobody's: the
+    second request's tokens are solo ``generate()``'s from the first on,
+    and the step's span says it ran no row."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 1, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20,
+                              "spans": True})
+    rng = np.random.default_rng(23)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), N, 500 + i)
+            for i, (P, N) in enumerate([(6, 4), (7, 6), (5, 3)])]
+    rids = [srv.submit(p, n, seed=s) for p, n, s in reqs]
+    seen = []
+    while not all(r in srv.results for r in rids):
+        srv.step()
+        fl = srv._inflight
+        if fl is not None and 0 in fl.rows:
+            seen.append((fl.rows[0].rid, srv.sched.running[0].rid))
+    # a step in flight is booked to the request that holds the slot now
+    assert seen and all(a == b for a, b in seen)
+    srv.drain()
+    _check_parity(model, params, reqs,
+                  [srv.pop_result(r).tokens for r in rids])
+    empty = [e for e in _steps(srv) if e.meta["slots"] == 0]
+    assert len(empty) == 3          # one a request: the step behind its last
+    assert all(set(e.meta) == {"slots", "queue", "ahead"} for e in empty)
+
+
+def test_hand_off_and_export_settle_first(setup):
+    """What reads slot state from the host's books reads the step in
+    flight back first: ``export_request`` (its payload is the request as
+    the device has it, the token of the step that was in flight booked),
+    an ``on_placed`` hand-off (the first token is read before the seat,
+    as it always was, and the hook finds nothing in flight), ``drain``."""
+    cfg, model, params, eng = setup
+    conf = {"slots": 2, "max_len": M, "prefill_chunk": 8, "page_size": 8,
+            "temperature": 0.8, "top_k": 20}
+    srv, dst = ServingEngine(eng, conf), ServingEngine(eng, conf)
+    rng = np.random.default_rng(24)
+    reqs = [(rng.integers(0, 256, (P,)).astype(np.int32), 10, 600 + i)
+            for i, P in enumerate([9, 6])]
+    stay = srv.submit(*reqs[0][:2], seed=reqs[0][2])
+    for _ in range(4):
+        srv.step()
+    req = srv.sched.running[0]
+    assert srv._inflight is not None and len(req.tokens) == 3
+    payload = srv.export_request(req)
+    assert srv._inflight is None and len(req.tokens) == 4
+    assert int(payload["length"][0]) == 9 + 4 - 1
+    assert int(payload["left"][0]) == 10 - 4
+    assert int(payload["tok"][0]) == req.tokens[-1]
+    srv.release_request(req)
+    assert dst.import_request(req, payload)
+    # ... and a request handed off as it is placed, with another running
+    moved = []
+
+    def hand_off(r, slot):
+        assert srv._inflight is None and len(r.tokens) == 1
+        moved.append((r, srv.export_request(r)))
+        srv.release_request(r)
+
+    keep = srv.submit(*reqs[0][:2], seed=reqs[0][2])
+    for _ in range(3):
+        srv.step()
+    srv.on_placed = hand_off
+    srv.submit(*reqs[1][:2], seed=reqs[1][2])
+    while not moved:
+        srv.step()
+    srv.on_placed = None
+    (r2, p2), = moved
+    assert int(p2["length"][0]) == 6 and int(p2["left"][0]) == 9
+    assert dst.import_request(r2, p2)
+    dst.drain()
+    srv.drain()
+    assert srv._inflight is None and dst._inflight is None
+    _check_parity(model, params, [reqs[0], reqs[1], reqs[0]],
+                  [req.tokens, r2.tokens, srv.pop_result(keep).tokens])
+    assert stay == req.rid
+
+
+@pytest.mark.parametrize("which", ["chaos", "speculation"])
+def test_serial_engines_read_every_step_at_once(setup, which):
+    """An engine with chaos (its poison row and its hang are chosen a
+    step at a time) or speculation (the next step's drafts are made from
+    this step's tokens, on the host) keeps the serial order through the
+    same code: every step is read before ``step()`` returns, ``ahead`` 0,
+    and a step's tokens are handed back by the call that dispatched it."""
+    cfg, model, params, eng = setup
+    extra = {"chaos": {"enabled": True, "seed": 0}} if which == "chaos" \
+        else {"greedy": True,
+              "speculation": {"enabled": True, "ngram": 2, "max_draft": 3}}
+    if which == "speculation":
+        eng = ds.init_inference(model, params, {
+            "dtype": "float32", "eos_token_id": EOS, "flash_decode": False})
+    srv = ServingEngine(eng, {"slots": 2, "max_len": M, "prefill_chunk": 8,
+                              "spans": True, **extra})
+    rng = np.random.default_rng(25)
+    base = rng.integers(0, 256, (6,)).astype(np.int32)
+    for p in (np.tile(base, 3), rng.integers(0, 256, (7,)).astype(np.int32)):
+        srv.submit(p, 12, seed=3)
+    for _ in range(40):
+        before = {r.rid: len(r.tokens) for r in srv.sched.running.values()}
+        srv.step()
+        assert srv._inflight is None
+        for r in srv.sched.running.values():
+            assert len(r.tokens) > before.get(r.rid, 0)
+        if srv.sched.idle and srv._prefill is None:
+            break
+    steps = _steps(srv)
+    assert len(steps) >= 8 and {e.meta["ahead"] for e in steps} == {0}
+    assert srv.stats.registry.counter("Serve/decode_steps_ahead").value == 0
+    assert srv.metrics_snapshot()["retired"] == 2
+
+
+def test_the_programs_are_the_ones_built_before(setup):
+    """The order of the loop changed, the programs did not: an engine
+    builds the same set under the same names, the step under ``step``
+    (``jit__step_impl`` is what the benchmark's reducers find it by)."""
+    cfg, model, params, eng = setup
+    srv = ServingEngine(eng, {"slots": 2, "max_len": M, "prefill_chunk": 8,
+                              "temperature": 0.8, "top_k": 20})
+    rng = np.random.default_rng(26)
+    rids = [srv.submit(rng.integers(0, 256, (P,)).astype(np.int32), 4,
+                       seed=P) for P in (5, 21, 9)]
+    srv.step()
+    srv.cancel(rids[0])
+    srv.drain()
+    assert set(srv._programs) == {
+        "init_slots", "init_cache", ("chunk", 8), ("final", 8), "insert",
+        "step", "retire"}
+    assert srv._programs["step"].__name__ == "_step_impl"
+    assert srv.compiles == 7
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])

@@ -76,14 +76,30 @@ def _prompts(cfg, lengths, seed=0):
             for n in lengths]
 
 
-def _check(srv):
+def _check(srv, settle=True):
     """The device's lengths against the scheduler's own account (a running
     request has all but its newest token cached, every other row stands at
-    0 and is ``done``) and, where the engine keeps one, against the mirror."""
+    0 and is ``done``) and, where the engine keeps one, against the mirror.
+    The books run one step behind the device (docs/SERVING.md, "The host
+    loop"), so the step in flight is read back first; ``settle`` False
+    leaves it out and holds the account to what it can know meanwhile: a
+    row the books have running is one position further on the device, or
+    at 0 where the step in flight ended it, and the mirror says the
+    former."""
+    if settle:
+        srv._settle()
     dev = np.asarray(srv._state.cache.length)
     want = np.zeros_like(dev)
+    flying = srv._inflight is not None
     for slot, req in srv.sched.running.items():
-        want[slot] = req.prompt_len + len(req.tokens) - 1
+        want[slot] = req.prompt_len + len(req.tokens) - 1 + flying
+    if flying:
+        assert ((dev == want) | (dev == 0)).all()
+        np.testing.assert_array_equal(np.asarray(srv._state.done), dev == 0)
+        if srv._slot_len is not None:
+            np.testing.assert_array_equal(srv._slot_len, want)
+            np.testing.assert_array_equal(srv._inflight.lens, want)
+        return
     np.testing.assert_array_equal(dev, want)
     np.testing.assert_array_equal(np.asarray(srv._state.done), want == 0)
     if srv._slot_len is not None:
@@ -91,16 +107,20 @@ def _check(srv):
 
 
 # ---------------------------------------------- (a) the mirror and the device
+@pytest.mark.parametrize("books", ["settled", "a_step_behind"])
 @pytest.mark.parametrize("reason", ["max_new", "eos", "cancel", "deadline",
                                     "nonfinite"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_device_lengths_follow_every_retirement(kind, reason):
+def test_device_lengths_follow_every_retirement(kind, reason, books):
     """Place two requests into two slots, step, retire one of them for
     ``reason``, place a third into the slot that came free, drain: after
     EVERY iteration the device's ``length`` is the scheduler's account of
     it, the mirror (the contiguous cache on the kernels keeps one) equals
     it, and no ``decode_step`` span saw a position fetched for a row that
-    was not running."""
+    was not running. ``settled``: the step in flight is read back before
+    every look; ``a_step_behind``: the loop runs as it does in service,
+    each step out before the one in front of it is read."""
+    settle = books == "settled"
     cfg, model, params = _model(kind)
     a, b, c = _prompts(cfg, (20, 9, 13))
     eos, extra = None, {}
@@ -126,12 +146,13 @@ def test_device_lengths_follow_every_retirement(kind, reason):
     for it in range(200):
         for req in srv.step():
             ended[req.rid] = req
-        _check(srv)
-        if it == 4:
+        if it == 2:
             assert len(srv.sched.running) == 2, "both seated by now"
+        _check(srv, settle)
+        if it == 4:
             if reason == "cancel":
                 ended[ra] = srv.cancel(ra)
-                _check(srv)
+                _check(srv, settle)
             if reason == "deadline":
                 clock.advance(10.0)
         if rc is None and len(ended) == 1:
@@ -153,9 +174,17 @@ def test_device_lengths_follow_every_retirement(kind, reason):
     if reason == "max_new":
         assert len(first.tokens) == 4
     # everything has ended: every row stands at 0
+    _check(srv)
     assert not np.asarray(srv._state.cache.length).any()
+    assert not settle or not srv.stats.registry.counter(
+        "Serve/decode_steps_ahead").value
     steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
     assert steps
+    assert {e.meta["ahead"] for e in steps} == (
+        {0} if settle or reason == "nonfinite" else {0, 1})
+    # (the last step out, which went before the host knew that nothing was
+    # left running, ran no row and says nothing of fetches)
+    steps = [e for e in steps if e.meta["slots"]]
     if kind == "contiguous":
         assert [e.meta["idle_fetched"] for e in steps] == [0] * len(steps)
         # a block a running request and for no other row, in every step
