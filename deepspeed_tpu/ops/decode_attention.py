@@ -28,19 +28,33 @@ Layout notes:
   of 64 at ``hd`` 128): derived from the shapes, never from the batch, so
   a slot's bits do not depend on its neighbours. The cache stays in HBM
   (``pl.ANY``); the kernel copies ``ceil(length / block)`` blocks of K and
-  of V into two VMEM buffers each with ``make_async_copy``, the next block
+  of V into two VMEM buffers each with ``make_async_copy``, the next turn
   in flight behind the current one's products, and after a slot's last
-  block the first block of the NEXT program (which buffer, and whether
-  that copy was started, ride in SMEM scratch: the grid runs in order).
+  turn the first turn of the NEXT program (which buffer, and whether
+  those copies were started, ride in SMEM scratch: the grid runs in order).
   Positions behind the live length are neither fetched nor multiplied.
+- a loop TURN is ``W`` consecutive live blocks (``blocks_per_turn``): as
+  many blocks of the program's heads as fit ``_ATTEND_TURN_BYTES``, from
+  ``(KV, hd, vd, max_len, dtype)`` like ``hb``. A turn is a chain — two
+  waits, an 8-row product, max, exp, sum, a second product, the carry —
+  whose latency the next turn's bytes have to hide. ``hb`` fills the turn
+  by taking more heads: 20 heads of 64 are 320 KB of K a block, 16 of 128
+  are 512 KB, and W is 1. 2 KV heads of 128 (compressed attention, a GQA
+  layer of a hybrid trunk) are 64 KB a block, which the chip moves in a
+  third of the chain's time: there W is 8, the buffers are ``W block``
+  lanes wide, a turn starts one copy a LIVE block into adjacent lane
+  ranges (fewer where the slot has fewer left: nothing behind the last
+  live block is fetched) and runs its products once over all of them. At
+  W = 1 the kernel is, op for op, the one it was before turns had a width.
 - the step APPENDS INSIDE the same kernel: called with the step's new K/V
   (``k=``, ``v=``) the caches are aliased outputs and ``length`` is the
   length after the append. The new position ``length - 1`` lies in the
   slot's last live block, which the kernel fetches anyway: once that block
   has landed, the program that owns it (never the one that fetched it
   ahead) puts the slot's new column on lane ``(length - 1) % 128`` of both
-  buffers, takes the products from the patched buffers and copies them
-  back to the same block of the cache, once. A column alone cannot be
+  buffers (of that block's lane range in a wider turn), takes the products
+  from the patched buffers and copies that block back to the same block of
+  the cache, once. A column alone cannot be
   copied: every (sublanes x 128) HBM tile of the block holds part of it
   and Mosaic refuses a slice narrower than the tile ("Slice shape along
   dimension 3 must be aligned to tiling (128), but is 1"); so a step
@@ -88,6 +102,11 @@ _APPEND_TILE_BYTES = 512 * 1024
 # of each, the scores and the accumulator stay under a third of the 16 MiB
 # of scoped VMEM
 _ATTEND_BLOCK_BYTES = 1024 * 1024
+# a loop turn of decode_attention takes as many blocks as fit this (of its
+# wider operand): where the heads are few a block is small, and a turn's
+# chain of waits and products costs more than the block's bytes
+# (``blocks_per_turn``; the sweep is PERF.md §6 "PR 46")
+_ATTEND_TURN_BYTES = 512 * 1024
 
 
 def _with_column(old_ref, new_ref, b, r):
@@ -103,23 +122,42 @@ def _with_column(old_ref, new_ref, b, r):
     return jnp.where(col == r, new.astype(old_ref.dtype), old_ref[...])
 
 
-def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
-                   append: bool, window: int = 0, sink: bool = False):
+def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
+                   group: int, append: bool, window: int = 0,
+                   sink: bool = False):
     """One program: a slot's ``hb`` KV heads (``q_ref`` (hb, rows, hd), the
     group's query rows padded to the 8 sublanes) over that slot's live
-    blocks, copied out of the cache in HBM by the kernel itself. With
-    ``append`` the step's new K/V (``new_k`` / ``new_v`` (hb, hd, 128),
-    slots on the lanes) go onto lane ``(L - 1) % block`` of the slot's last
-    live block once it has landed in VMEM; the products read the patched
-    buffers and one copy takes them back to the aliased cache.
+    blocks, copied out of the cache in HBM by the kernel itself, ``width``
+    (W) consecutive live blocks a loop turn. With ``append`` the step's new
+    K/V (``new_k`` / ``new_v`` (hb, hd, 128), slots on the lanes) go onto
+    lane ``(L - 1) % block`` of the slot's last live block once it has
+    landed in VMEM; the products read the patched buffers and one copy
+    takes that block back to the aliased cache.
+
+    A turn is W copies of one block each into adjacent ``block``-lane
+    ranges of a buffer ``(hb, hd, W block)``, fewer where the slot has fewer
+    blocks left (never a block behind the live length), and ONE chain over
+    all its lanes: a product, a max, an exp, a sum, a second product. Turns
+    count from the slot's first live block, so which positions share a
+    turn follows from the slot's own length. The lanes of a turn's blocks
+    that were not fetched hold what an earlier turn left there: their
+    scores are masked (``keep``), a NaN among the stale K with them, but
+    ``p = 0`` against a NaN or Inf among the stale V is NaN in the MXU. The
+    V buffers are scratch, which nothing outside the kernel can write: the
+    first program zeroes them, and everything copied in afterwards is a
+    live block of the cache, as finite as the cache's own values. At one
+    block a turn no lane is stale and nothing is zeroed: every expression
+    that ``W`` enters is then the one it was before turns had a width
+    (``turns``, ``part``, ``j``, ``end``), and the program is that program.
 
     ``window`` > 0: the cache is a RING, position ``p`` in block
     ``(p // block) % (S // block)``; the slot's live positions are
     ``L - window .. L - 1`` and only the blocks that hold them are fetched
-    (positions outside the window are masked). ``sink``: the running max and
-    sum start at ``(sink_h, 1)`` in place of ``(-inf, 0)``: one more column
-    of the softmax that carries no value. K and V may differ in width (the
-    accumulator and the output have V's)."""
+    (positions outside the window are masked), each folded into the ring
+    on its own. ``sink``: the running max and sum start at ``(sink_h, 1)``
+    in place of ``(-inf, 0)``: one more column of the softmax that carries
+    no value. K and V may differ in width (the accumulator and the output
+    have V's)."""
     from jax.experimental.pallas import tpu as pltpu
 
     len_ref, layer_ref, *refs = refs
@@ -134,6 +172,7 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
     n_slots, n_groups = pl.num_programs(0), pl.num_programs(1)
     hb, rows, _ = q_ref.shape
     S = k_hbm.shape[4]
+    W = width
     ring = S // block            # a window's cache: blocks that come round
 
     def live(n):
@@ -150,7 +189,14 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
         n = jnp.minimum(n, S)
         return n, 0, (n + block - 1) // block
 
+    def turns(n):
+        """The loop turns ``n`` live blocks take."""
+        return n if W == 1 else (n + W - 1) // W
+
     L, j0, nb = live(len_ref[b])                         # only live blocks
+    # one past the slot's last live block: what bounds a turn's copies
+    # (a turn of one block never asks)
+    end = None if W == 1 else j0 + nb
 
     def at(slot, heads, j):
         if window:
@@ -158,33 +204,60 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
         return (layer_ref[0], slot, pl.ds(heads * hb, hb), slice(None),
                 pl.ds(pl.multiple_of(j * block, block), block))
 
-    def copies(buf, slot, heads, j):
-        return (pltpu.make_async_copy(k_hbm.at[at(slot, heads, j)],
-                                      k_buf.at[buf], sem.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[at(slot, heads, j)],
-                                      v_buf.at[buf], sem.at[1, buf]))
+    def part(c_buf, buf, i):
+        """The lanes of buffer ``buf`` that a turn's block ``i`` lands on."""
+        if W == 1:
+            return c_buf.at[buf]
+        lane = i * block if isinstance(i, int) else \
+            pl.multiple_of(i * block, block)
+        return c_buf.at[buf, :, :, pl.ds(lane, block)]
 
-    def writes(buf, j):
-        """The copies of this program's patched block ``j`` back to the
-        cache (to wait on one only the semaphore and the size matter)."""
-        return (pltpu.make_async_copy(k_buf.at[buf],
+    def plus(j, i):
+        """``j + i``, and no op at all for a turn's first block."""
+        return j if isinstance(i, int) and i == 0 else j + i
+
+    def copies(buf, slot, heads, j, i):
+        """Block ``i`` of the turn that starts at block ``j``."""
+        j = plus(j, i)
+        return (pltpu.make_async_copy(k_hbm.at[at(slot, heads, j)],
+                                      part(k_buf, buf, i), sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[at(slot, heads, j)],
+                                      part(v_buf, buf, i), sem.at[1, buf]))
+
+    def writes(buf, i, j):
+        """The copies of this program's patched block ``j``, block ``i`` of
+        its turn, back to the cache (to wait on one only the semaphore and
+        the size matter)."""
+        return (pltpu.make_async_copy(part(k_buf, buf, i),
                                       k_out.at[at(b, g, j)], sem.at[2, 0]),
-                pltpu.make_async_copy(v_buf.at[buf],
+                pltpu.make_async_copy(part(v_buf, buf, i),
                                       v_out.at[at(b, g, j)], sem.at[2, 1]))
 
-    def patch(buf):
-        """Lane ``(L - 1) % block`` of both buffers takes the slot's new
-        column."""
+    def patch(buf, i):
+        """Lane ``(L - 1) % block`` of the turn's block ``i`` takes the
+        slot's new column, in both buffers."""
         for new_ref, c_buf in ((new_k, k_buf), (new_v, v_buf)):
-            c_buf[buf] = _with_column(c_buf.at[buf], new_ref, b,
-                                      (L - 1) % block)
+            tile = part(c_buf, buf, i)
+            tile[...] = _with_column(tile, new_ref, b, (L - 1) % block)
 
-    def fetch(buf, slot, heads, j):
-        for copy in copies(buf, slot, heads, j):
-            copy.start()
+    def over_turn(buf, slot, heads, j, stop, act):
+        """``act`` (a copy's start, or its wait) on the copies of every
+        block ``j + i`` before ``stop`` of the turn that starts at ``j``
+        (its first is one: there is no empty turn)."""
+        def block(i):
+            for copy in copies(buf, slot, heads, j, i):
+                act(copy)
 
-    # ``ahead``: which buffer this program's first block goes to, whether
-    # the program before already started that copy and, appending, whether
+        block(0)
+        for i in range(1, W):
+            pl.when(j + i < stop)(partial(block, i))
+
+    def fetch(buf, slot, heads, j, stop):
+        over_turn(buf, slot, heads, j, stop, lambda copy: copy.start())
+
+    # ``ahead``: which buffer this program's first turn goes to, whether
+    # the program before already started its copies (as many as the turn
+    # has: both reckon them from the slot's length) and, appending, whether
     # it left a write-back in flight
     @pl.when((b == 0) & (g == 0))
     def _():
@@ -192,20 +265,22 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
         ahead[1] = 0
         if append:
             ahead[2] = 0
+        if W > 1:
+            v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
 
     first = ahead[0]
 
     if append:
         # the program before left its patched block on its way back to the
-        # cache, out of the buffer this program's second block goes to
+        # cache, out of the buffer this program's second turn goes to
         @pl.when(ahead[2] == 1)
         def _():
-            for copy in writes(0, 0):
+            for copy in writes(0, 0, 0):
                 copy.wait()
 
     @pl.when((nb > 0) & (ahead[1] == 0))
     def _():
-        fetch(first, b, g, j0)
+        fetch(first, b, g, j0, end)
 
     # the program after this one, and whether it has a block to fetch
     wraps = g == n_groups - 1
@@ -213,40 +288,42 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
     g_next = jnp.where(wraps, 0, g + 1)
     len_next = len_ref[jnp.minimum(b_next, n_slots - 1)]
     next_live = (b_next < n_slots) & (len_next > 0)
-    j0_next = live(len_next)[1]
+    _, j0_next, nb_next = live(len_next)
+    end_next = None if W == 1 else j0_next + nb_next
 
     q = q_ref[...]
     # (hb, rows, 1) of per-query-head slopes, from SMEM scalars
     slope = _per_head(slopes_ref, g, hb, rows, group, 0.0) if alibi else None
 
-    end = j0 + nb                # one past the slot's last live block
+    stop = j0 + turns(nb)        # turns count on from the first live block
 
-    def body(j, carry):
+    def body(u, carry):
         m, l, acc = carry
-        buf = (first + j - j0) % 2
+        buf = (first + u - j0) % 2
+        j = u if W == 1 else j0 + (u - j0) * W       # the turn's first block
 
-        # behind this block's products: the slot's next block, or after
-        # its last the first block of the next program
-        @pl.when(j + 1 < end)
+        # behind this turn's products: the slot's next turn, or after
+        # its last the first turn of the next program
+        @pl.when(u + 1 < stop)
         def _():
-            fetch(1 - buf, b, g, j + 1)
+            fetch(1 - buf, b, g, j + W, end)
 
-        @pl.when((j + 1 == end) & next_live)
+        @pl.when((u + 1 == stop) & next_live)
         def _():
-            fetch(1 - buf, b_next, g_next, j0_next)
+            fetch(1 - buf, b_next, g_next, j0_next, end_next)
 
-        for copy in copies(buf, b, g, j):
-            copy.wait()
+        over_turn(buf, b, g, j, end, lambda copy: copy.wait())
         if append:
             # the last live block holds position L - 1: patched in VMEM,
             # attended to from there, written back once
-            @pl.when(j + 1 == end)
+            @pl.when(u + 1 == stop)
             def _():
-                patch(buf)
-                for copy in writes(buf, j):
+                i = 0 if W == 1 else end - 1 - j
+                patch(buf, i)
+                for copy in writes(buf, i, plus(j, i)):
                     copy.start()
-        k, v = k_buf[buf], v_buf[buf]                    # (hb, hd, blk)
-        s = jax.lax.dot_general(                         # (hb, rows, blk)
+        k, v = k_buf[buf], v_buf[buf]                    # (hb, hd, W blk)
+        s = jax.lax.dot_general(                         # (hb, rows, W blk)
             q, k, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
         col = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -276,20 +353,20 @@ def _decode_kernel(*refs, block: int, scale: float, alibi: bool, group: int,
         m0 = _per_head(sink_ref, g, hb, rows, group, BIG_NEG)
         l0 = jnp.where(m0 > BIG_NEG, 1.0, 0.0)
     acc0 = jnp.zeros(o_ref.shape, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(j0, end, body, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(j0, stop, body, (m0, l0, acc0))
     if append:
-        # the write runs behind the last block's products, the next
+        # the write runs behind the last turn's products, the next
         # program's first fetch (which is in the other buffer) and the turn
         # of the programs: the next one waits for it, the last one here
         last = (b == n_slots - 1) & wraps
 
         @pl.when((nb > 0) & last)
         def _():
-            for copy in writes(0, 0):
+            for copy in writes(0, 0, 0):
                 copy.wait()
 
         ahead[2] = ((nb > 0) & jnp.logical_not(last)).astype(jnp.int32)
-    ahead[0] = (first + nb) % 2
+    ahead[0] = (first + turns(nb)) % 2
     ahead[1] = ((nb > 0) & next_live).astype(jnp.int32)
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -327,6 +404,20 @@ def _heads_per_program(KV: int, hd: int, blk: int, dtype) -> int:
         <= _ATTEND_BLOCK_BYTES))
 
 
+def blocks_per_turn(KV: int, hd: int, vd: int, max_len: int, dtype,
+                    blk: int = LANES) -> int:
+    """The live blocks a loop turn of ``decode_attention`` takes (W): as
+    many blocks of a program's ``hb`` heads, at the wider of K and V, as fit
+    ``_ATTEND_TURN_BYTES``; at least 1, at most the cache's own. 8 for 2 KV
+    heads of 128 (64 KB a block), 2 for 4 heads with keys of 192; 1 where
+    the heads fill a turn already: GPT-2 774M's 20 of 64 (320 KB), 16 of
+    128 (512 KB), 8 with keys of 192. From the shapes, never from the
+    batch: a slot's bits do not depend on its neighbours."""
+    hb = _heads_per_program(KV, hd, blk, dtype)
+    one = hb * max(hd, vd) * blk * jnp.dtype(dtype).itemsize
+    return max(1, min(_ATTEND_TURN_BYTES // one, max_len // blk))
+
+
 def _slots_on_lanes(x, dtype):
     """(B, 1, KV, hd) → (KV, hd, slots): ``hd`` on the sublanes as in the
     cache, the slots on the lanes, padded to whole tiles (a few KiB; a
@@ -357,9 +448,9 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     every other position keeps every bit. Without them the kernel only
     reads (a caller that has appended already: the paged view's slab).
 
-    A slot's result depends on that slot's row and length alone: the block
-    and the heads a program takes follow from ``(KV, hd, max_len, dtype)``,
-    never from ``B``.
+    A slot's result depends on that slot's row and length alone: the block,
+    the heads a program takes and the blocks a loop turn takes follow from
+    ``(KV, hd, vd, max_len, dtype)``, never from ``B``.
 
     ``cv`` may hold values of another width than the keys (``(..., vd,
     max_len)``): the result then has V's. ``window`` > 0: the caches are
@@ -430,6 +521,7 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
             check_vma=False)(q, ck, cv, lengths, layer, *news, *slopes)
 
     hb = _heads_per_program(KV, hd, blk, ck.dtype)
+    W = blocks_per_turn(KV, hd, vd, S, ck.dtype, blk)
     # (B, 1, H, hd) → (B, KV, rows, hd): a KV head's ``group`` query rows,
     # as the cache is stored, padded to the sublane tile — the GQA mapping
     # is this reshape, K/V are never repeated
@@ -452,15 +544,17 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
         in_specs=[rows_spec(hd)] + [new_spec(hd), new_spec(vd)][:len(news)]
         + [in_hbm, in_hbm],
         out_specs=[rows_spec(vd)] + [in_hbm] * len(news),
-        # two buffers of K and of V; a semaphore a buffer for the fetches
-        # and one more pair for the write-back; ``ahead`` (see the kernel)
-        scratch_shapes=[pltpu.VMEM((2, hb, hd, blk), ck.dtype),
-                        pltpu.VMEM((2, hb, vd, blk), cv.dtype),
+        # two buffers of K and of V, a turn's blocks side by side on the
+        # lanes; a semaphore a buffer for the fetches (a turn's copies
+        # count on one) and one more pair for the write-back; ``ahead``
+        # (see the kernel)
+        scratch_shapes=[pltpu.VMEM((2, hb, hd, W * blk), ck.dtype),
+                        pltpu.VMEM((2, hb, vd, W * blk), cv.dtype),
                         pltpu.SemaphoreType.DMA((2 + append, 2)),
                         pltpu.SMEM((2 + append,), jnp.int32)],
     )
     out, *caches = pl.pallas_call(
-        partial(_decode_kernel, block=blk, scale=scale, alibi=alibi,
+        partial(_decode_kernel, block=blk, width=W, scale=scale, alibi=alibi,
                 group=group, append=append, window=window,
                 sink=sink is not None),
         name=name,

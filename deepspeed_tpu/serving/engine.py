@@ -33,6 +33,7 @@ from __future__ import annotations
 import dataclasses
 import weakref
 from collections import OrderedDict
+from functools import cached_property
 from typing import Optional
 
 import jax
@@ -52,7 +53,7 @@ from ..observability.export import request_record
 from ..observability.metrics import get_registry
 from ..observability.tracing import ServingStats
 from ..models.windowed import KEY_BLOCK
-from ..ops.decode_attention import LANES
+from ..ops.decode_attention import LANES, blocks_per_turn
 from ..resilience.chaos import ChaosMonkey
 from ..resilience.guards import QueueFullError, RequestStatus
 from ..utils.logging import warning_once
@@ -1389,17 +1390,33 @@ class ServingEngine:
         requests' new K/V: a ratio of positions, since both are K and V of
         every head and layer. And ``idle_fetched``: the positions of those
         fetched that belong to no running request, 0 while every row that
-        is not running stands at length 0. {} of a step that ran no row
-        (the device had retired them all before the host knew)."""
+        is not running stands at length 0. ``attn_blocks_per_turn``: the
+        live blocks the slots fetch over the loop turns the kernel takes
+        for them (``blocks_per_turn`` of a turn, fewer of a slot's last):
+        1 where the heads fill a turn with one block. {} of a step that
+        ran no row (the device had retired them all before the host
+        knew)."""
         ran = list(fl.rows)
         if not ran:
             return {}
-        fetched = -(-fl.lens // LANES) * LANES
+        blocks = -(-fl.lens // LANES)
+        fetched = blocks * LANES
         written = LANES * np.count_nonzero(fl.lens)
         total = fetched.sum()
+        turns = -(-blocks // self._blocks_per_turn)
         return {"attn_fetched_over_live": float(total / fl.lens[ran].sum()),
                 "append_moved_over_new": float(written / len(ran)),
-                "idle_fetched": int(total - fetched[ran].sum())}
+                "idle_fetched": int(total - fetched[ran].sum()),
+                "attn_blocks_per_turn": float(blocks.sum() / turns.sum())}
+
+    @cached_property
+    def _blocks_per_turn(self) -> int:
+        """The kernel's own W (``ops/decode_attention.py``) at the shapes
+        it sees: a shard's of the cache's K and V planes (of a trunk with
+        two kinds of attention layers, the full layers')."""
+        k, v = self._state.cache.k, self._state.cache.v
+        _, _, KV, hd, S = k.sharding.shard_shape(k.shape)
+        return blocks_per_turn(KV, hd, v.shape[3], S, k.dtype)
 
     def _log_routing(self, step, tapped: list, chunks: list,
                      rows: dict) -> None:

@@ -283,6 +283,41 @@ def test_decode_attention_appending(one_chip, width):
     assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("B,H,KV,hd,vd,L,S,W,name", [
+    pytest.param(48, 8, 2, 128, 128, 20, 4096, 8, "cca_decode_attention",
+                 id="zaya-cell"),
+    pytest.param(32, 64, 4, 192, 128, 2, 32768, 2, "full_decode_attention",
+                 id="mimo-full-layers-cell"),
+])
+def test_decode_attention_appending_with_wide_turns(one_chip, B, H, KV, hd,
+                                                    vd, L, S, W, name):
+    """Where the heads are few a loop turn takes several live blocks
+    (``blocks_per_turn``): 8 for ZAYA's 2 KV heads of 128, 2 for MiMo's
+    full layers' 4 with keys of 192 over values of 128. The appending call
+    at the cells' shapes: buffers ``W`` blocks wide, ``W`` copies a turn,
+    the patched block written back from a traced 128-lane range — one
+    Mosaic call under the name the benchmark's roofline finds it by, the
+    caches in place."""
+    from deepspeed_tpu.ops.decode_attention import blocks_per_turn
+
+    assert blocks_per_turn(KV, hd, vd, S, jnp.bfloat16) == W
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, 1, H, hd), jnp.bfloat16), ((L, B, KV, hd, S), jnp.bfloat16),
+        ((L, B, KV, vd, S), jnp.bfloat16), ((B,), jnp.int32),
+        ((), jnp.int32), ((B, 1, KV, hd), jnp.bfloat16),
+        ((B, 1, KV, vd), jnp.bfloat16))]
+    compiled = jax.jit(
+        lambda q, ck, cv, n, layer, k, v: decode_attention(
+            q, ck, cv, n, k=k, v=v, layer=layer, name=name, interpret=False),
+        donate_argnums=(1, 2)).lower(*args).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and f"/{name}/pallas_call" in calls[0], calls
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * B * KV * (hd + vd) * S * 2
+    assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
+
+
 def test_decode_attention_appending_under_a_model_axis(topo):
     """``model`` = 4 over the described host's four chips: the kernel runs
     per shard of 5 KV heads inside a ``shard_map`` with the caches in its

@@ -603,3 +603,245 @@ def test_decode_step_span_says_append_moved_over_new():
     # whatever runs: a block for each running request and for no other slot
     for e in steps:
         assert e.meta["append_moved_over_new"] == 128
+
+
+# ------------------------------------------- several live blocks a loop turn
+# Where the heads are few a turn takes W consecutive live blocks (PR 46):
+# W copies of one block each into adjacent lane ranges of the turn's buffer,
+# one chain of products over them. W follows from (KV, hd, vd, max_len,
+# dtype) as the heads a program takes do; the cases below are float32, whose
+# blocks are twice bfloat16's: 2 KV heads of 64 are 64 KB a block (W = 8, as
+# 2 of 128 in bfloat16), 4 of 128 are 256 KB (W = 2).
+def test_blocks_per_turn_follow_the_shapes():
+    """No knob: the blocks a turn takes are those of the program's heads
+    (the wider of K and V) that fit the per-turn target, whatever the
+    batch; never more than the cache has, never 0. The serving cells whose
+    heads fill a turn keep one block a turn: the parent's program."""
+    from deepspeed_tpu.ops.decode_attention import blocks_per_turn
+
+    bf16 = jnp.bfloat16
+    assert blocks_per_turn(2, 128, 128, 4096, bf16) == 8    # ZAYA, Nemotron
+    assert blocks_per_turn(4, 192, 128, 4096, bf16) == 2    # MiMo, full
+    assert blocks_per_turn(8, 192, 128, 256, bf16) == 1     # MiMo, window
+    assert blocks_per_turn(20, 64, 64, 1024, bf16) == 1     # GPT-2 774M
+    assert blocks_per_turn(16, 128, 128, 2048, bf16) == 1   # Ouro-2.6B
+    assert blocks_per_turn(16, 128, 128, 4096, bf16) == 1   # 7B, a TP-2 shard
+    assert blocks_per_turn(32, 128, 128, 4096, bf16) == 1   # 7B whole: 1 MiB
+    assert blocks_per_turn(5, 64, 64, 1024, bf16) == 6      # GPT-2, TP-4 shard
+    assert blocks_per_turn(2, 128, 128, 128, bf16) == 1     # the cache's own
+    assert blocks_per_turn(2, 128, 128, 256, bf16) == 2
+    assert blocks_per_turn(2, 64, 64, 4096, jnp.float32) == 8
+    assert blocks_per_turn(7, 4096, 4096, 1024, jnp.float32) == 1  # never 0
+    # the wider of K and V: values of 256 beside keys of 128 halve the turn
+    assert blocks_per_turn(2, 128, 256, 4096, bf16) == 4
+
+
+def _dense(q, ck, cv, n, window=0, sink=None):
+    """The plain expression in float64: q (B, 1, H, hd) over a layer's
+    caches (B, KV, ., S) at lengths ``n`` (after the append); a ring where
+    ``window``; zeros for a slot at 0."""
+    q, ck, cv = (np.asarray(x, np.float64) for x in (q, ck, cv))
+    B, _, H, hd = q.shape
+    KV, S = ck.shape[1], ck.shape[3]
+    out = np.zeros((B, 1, H, cv.shape[2]))
+    for b, L in enumerate(np.asarray(n)):
+        L = int(L) if window else min(int(L), S)
+        if not L:
+            continue
+        pos = np.arange(max(L - window, 0) if window else 0, L) % S
+        for h in range(H):
+            kv = h // (H // KV)
+            s = q[b, 0, h] @ ck[b, kv][:, pos] / np.sqrt(hd)
+            m = s.max() if sink is None else max(s.max(), sink[h])
+            e = np.exp(s - m)
+            den = e.sum() + (0 if sink is None else np.exp(sink[h] - m))
+            out[b, 0, h] = cv[b, kv][:, pos] @ e / den
+    return out
+
+
+# H, KV, hd, vd, max_len: what the width rule gives is asserted in the test
+TURNS = {
+    "gqa-group4-w8": (8, 2, 64, 64, 2048, 8),
+    "group1-w8": (2, 2, 64, 64, 2048, 8),
+    "keys-wider-w5": (8, 2, 96, 64, 2048, 5),
+    "4x128-w2": (4, 4, 128, 128, 512, 2),
+}
+
+
+def _turn_case(shape, layers=2, seed=0):
+    """Lengths 0, 1, 127, 128, 129, W 128 - 1, W 128, W 128 + 1, a length
+    inside the second turn and ``max_len`` in one batch, idle slots among
+    them."""
+    from deepspeed_tpu.ops.decode_attention import blocks_per_turn
+
+    H, KV, hd, vd, S, W = TURNS[shape]
+    assert blocks_per_turn(KV, hd, vd, S, jnp.float32) == W
+    lens = [0, 1, 127, 128, 129, W * 128 - 1, W * 128, W * 128 + 1, 0,
+            min(W * 128 + 300, S), S]
+    rng = np.random.default_rng(seed)
+    B = len(lens)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
+    ck = jnp.asarray(rng.standard_normal((layers, B, KV, hd, S)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((layers, B, KV, vd, S)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, 1, KV, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, 1, KV, vd)), jnp.float32)
+    return q, ck, cv, k, v, np.asarray(lens, np.int32)
+
+
+def _set(cache, layer, new, lens):
+    """``cache`` with ``new`` at position ``length - 1`` of every running
+    slot of ``layer``, as numpy."""
+    out = np.array(cache)
+    S = out.shape[-1]
+    for b, n in enumerate(lens):
+        if n:
+            out[layer, b, :, :, (n - 1) % S] = np.asarray(new)[b, 0]
+    return out
+
+
+@pytest.mark.parametrize("append", [True, False], ids=["appending", "reading"])
+@pytest.mark.parametrize("shape", list(TURNS))
+def test_wide_turns_match_the_dense_reference(shape, append):
+    """Turns of 8, 5 and 2 blocks against the plain expression at every
+    edge of a block and of a turn: GQA groups of 4 and of 1, keys wider
+    than values; appending (the caches bit-equal to ``.at[].set``, the new
+    column attended to) and read-only."""
+    q, ck, cv, k, v, lens = _turn_case(shape)
+    n = jnp.asarray(lens)
+    with jax.default_matmul_precision("highest"):
+        if append:
+            out, gk, gv = jax.jit(partial(decode_attention, layer=1,
+                                          interpret=True))(q, ck, cv, n,
+                                                           k=k, v=v)
+            want_k, want_v = _set(ck, 1, k, lens), _set(cv, 1, v, lens)
+            np.testing.assert_array_equal(np.asarray(gk), want_k)
+            np.testing.assert_array_equal(np.asarray(gv), want_v)
+        else:
+            out = decode_attention(q, ck, cv, n, layer=1, interpret=True)
+            want_k, want_v = np.asarray(ck), np.asarray(cv)
+    want = _dense(q, want_k[1], want_v[1], lens)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out)[lens == 0], 0.0)
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["ring", "ring+sink"])
+@pytest.mark.parametrize("window", [384, 300])
+def test_wide_turns_over_a_ring_that_wraps(window, sink):
+    """A ring of four blocks under turns of two: windows that lie inside
+    the first blocks, that end on a block's edge, whose first turn crosses
+    the ring's wrap (blocks 3, 0) and whose second does (2, 3 | 0, 1), with
+    and without a sink; every block folded into the ring on its own, only
+    the slot's own column written."""
+    from deepspeed_tpu.ops.decode_attention import blocks_per_turn
+
+    H, KV, hd, R = 8, 4, 128, 512
+    assert blocks_per_turn(KV, hd, hd, R, jnp.float32) == 2
+    lens = np.asarray([5, 300, 384, 0, 512, 513, 641, 770, 900, 1025, 1408],
+                      np.int32)
+    rng = np.random.default_rng(7)
+    B = len(lens)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
+    ck, cv = (jnp.asarray(rng.standard_normal((2, B, KV, hd, R)), jnp.float32)
+              for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((B, 1, KV, hd)), jnp.float32)
+            for _ in range(2))
+    sk = rng.standard_normal((H,)).astype(np.float32) + 2.0 if sink else None
+    with jax.default_matmul_precision("highest"):
+        out, gk, gv = jax.jit(lambda *a: decode_attention(
+            a[0], a[1], a[2], jnp.asarray(lens), k=a[3], v=a[4],
+            layer=jnp.int32(0), window=window,
+            sink=None if sk is None else jnp.asarray(sk),
+            interpret=True))(q, ck, cv, k, v)
+    want_k, want_v = _set(ck, 0, k, lens), _set(cv, 0, v, lens)
+    np.testing.assert_array_equal(np.asarray(gk), want_k)
+    np.testing.assert_array_equal(np.asarray(gv), want_v)
+    want = _dense(q, want_k[0], want_v[0], lens, window, sk)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf],
+                         ids=["nan", "inf", "-inf"])
+@pytest.mark.parametrize("shape", ["gqa-group4-w8", "4x128-w2"])
+def test_blocks_behind_the_live_length_are_never_fetched(shape, poison):
+    """NaN and Inf in every block behind a slot's last live one (and in
+    the whole row of a slot at 0): a turn starts copies for its live blocks
+    alone and the lanes of the rest hold what the zeroed scratch or an
+    earlier turn's real K/V left, so the outputs are finite, bit-equal to
+    a clean cache's, and every position of the returned caches but the
+    appended column keeps its bits. (Inside the last live block the kernel
+    multiplies whole lanes, so there the probe is large and finite, as in
+    ``test_garbage_behind_the_live_length_is_never_read``.)"""
+    q, ck, cv, k, v, lens = _turn_case(shape)
+    S = ck.shape[-1]
+    at = np.arange(S)
+    edge = (-(-lens // 128) * 128).reshape(-1, 1, 1, 1)
+    tail = (at >= lens.reshape(-1, 1, 1, 1)) & (at < edge)
+    dk = jnp.where(at >= edge, poison, jnp.where(tail, 1e4, ck))
+    dv = jnp.where(at >= edge, -poison, jnp.where(tail, -1e4, cv))
+    run = jax.jit(partial(decode_attention, layer=1, interpret=True))
+    clean = run(q, ck, cv, jnp.asarray(lens), k=k, v=v)
+    dirty = run(q, dk, dv, jnp.asarray(lens), k=k, v=v)
+    assert np.isfinite(np.asarray(dirty[0])).all()
+    np.testing.assert_array_equal(np.asarray(dirty[0]), np.asarray(clean[0]))
+    for got, old, new in ((dirty[1], dk, k), (dirty[2], dv, v)):
+        np.testing.assert_array_equal(np.asarray(got), _set(old, 1, new, lens))
+
+
+@pytest.mark.parametrize("append", [True, False], ids=["appending", "reading"])
+@pytest.mark.parametrize("slot", [1, 5, 7, 9, 10])
+def test_a_slot_of_wide_turns_is_bit_equal_whatever_its_neighbours(slot,
+                                                                   append):
+    """A slot's output among eleven slots of every length against the same
+    row alone at batch 1 with a scalar length, and against the same row
+    among neighbours at other lengths (so its first turn is fetched ahead
+    into the other buffer, by a program whose own turns differ): the same
+    bits. Turns count from the slot's own first live block."""
+    q, ck, cv, k, v, lens = _turn_case("gqa-group4-w8")
+    new = dict(k=k, v=v) if append else {}
+
+    def one(x):
+        return x[slot:slot + 1]
+
+    def run(q, ck, cv, n, **new):
+        out = decode_attention(q, ck, cv, n, layer=1, interpret=True, **new)
+        return np.asarray(out[0] if new else out)
+
+    batch = run(q, ck, cv, jnp.asarray(lens), **new)
+    alone = run(one(q), ck[:, slot:slot + 1], cv[:, slot:slot + 1],
+                jnp.int32(lens[slot]), **{a: one(x) for a, x in new.items()})
+    np.testing.assert_array_equal(batch[slot], alone[0])
+    others = np.where(np.arange(len(lens)) == slot, lens,
+                      np.roll(lens, 3) // 2 + 64)
+    moved = run(q, ck, cv, jnp.asarray(others.astype(np.int32)), **new)
+    np.testing.assert_array_equal(batch[slot], moved[slot])
+
+
+def test_decode_step_span_says_blocks_per_turn():
+    """``attn_blocks_per_turn`` on the ``decode_step`` span: the live
+    blocks the step's slots fetch over the loop turns the kernel takes for
+    them, with the kernel's own width at the cache's shapes (2 KV heads of
+    64 in float32 over a cache of two blocks: W = 2). 1.0 while every
+    running slot holds one block; 2.0 when the one slot running holds two,
+    fetched in one turn; between them when slots of one block run beside
+    it."""
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.ops.decode_attention import blocks_per_turn
+
+    cfg, model, params = _family("mha-hd64")
+    assert blocks_per_turn(2, 64, 64, S, jnp.float32) == 2
+    eng = ds.init_inference(model, params, {"dtype": "float32",
+                                            "eos_token_id": 7,
+                                            "flash_decode": True})
+    srv = ds.ServingEngine(eng, {"slots": 3, "max_len": S, "greedy": True,
+                                 "prefill_chunk": 64, "spans": True})
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(8, 256, (P,)).astype(np.int32)
+               for P in (120, 9, 60, 130)]
+    srv.serve_batch(prompts, [12, 3, 4, 5], [1, 2, 3, 4])
+    steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
+    ratios = [e.meta["attn_blocks_per_turn"] for e in steps]
+    assert len(ratios) == len(steps) > 0
+    assert ratios[0] == 1.0                  # 121 positions: a block, a turn
+    # one or two blocks a slot, a turn each: 3/2 with one slot of each
+    assert set(ratios) <= {1.0, 4 / 3, 1.5, 5 / 3, 2.0}
+    assert 2.0 in ratios and min(ratios) == 1.0
