@@ -22,7 +22,7 @@ normed input at position t, ``C = (n_head + n_kv_head) head_dim`` channels =
 5. Rope on the first ``rotary_dim`` dims of q and k, then causal softmax of
    ``q k^T / sqrt(hd)`` and ``o = concat(heads) Wo`` (``n_head x head_dim`` ->
    ``d_model``): the trunk's own attention over K and V, which the cache
-   holds as any GQA model's (``inference/decode.py`` ``CCACache``).
+   holds as any GQA model's (``inference/kinds/cca.py`` ``CCACache``).
 
 **What a position needs of those before it** beside K and V: the last
 ``K0 - 1`` rows of ``z``, the last ``K1 - 1`` rows of ``z1`` and the last row

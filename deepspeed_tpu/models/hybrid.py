@@ -21,7 +21,7 @@ x))``, no FFN beside it; then the final norm and an untied head.
 
 Segments are runs of equal letters (``TransformerConfig.segments``), each
 scanned over its own stacked weights; ``params["layers"]`` is the tuple of
-them. The cache path is ``inference/decode.py`` ``_forward_hybrid``.
+them. The cache path is ``inference/kinds/hybrid.py``.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ Per token a layer keeps ``c`` (``kv_lora_rank`` values, after its RMSNorm)
 and ``k_rope`` (``qk_rope_head_dim`` values, after rope, one for all heads)
 instead of every head's K and V. The *latents* travel as one array
 ``(B, rank + rope, S)``, positions on the lanes, which is how the cache
-holds them (``inference/decode.py``); two paths read them:
+holds them (``inference/kinds/latent.py``); two paths read them:
 
 - :func:`attend_expanded` (T > 1: the full forward and prefill): expands
   ``k_nope`` and ``v`` from a block of latents with ``wkv_b`` and attends as

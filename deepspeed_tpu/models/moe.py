@@ -44,6 +44,16 @@ from .transformer import (TransformerConfig, TransformerLM, _activation,
 B_AXES = BATCH_AXES
 # the zaya router's last matrix at init, over the trunk's 1 / sqrt(fan-in)
 ROUTER_OUT_GAIN = 4.0
+# What the sorted expert rows of a sigmoid router do not compose with yet
+# when served (``inference/kinds``): (subject and verb, how the reasons are
+# joined, feature -> reason). The latent cache's list too: it names both.
+SERVED = ("a latent (MLA) cache and sigmoid-routed expert layers do not yet "
+          "compose with", ", ", {
+              "paged": "the paged pool (page_size)",
+              "kv_quant": "an int8 KV cache (kv_quant_bits)",
+              "speculation": "speculation",
+              "quantize": "weight-only quantization",
+              "mesh": "a mesh of several devices (tensor/expert parallel)"})
 
 
 def _capacity(tokens_per_group: int, num_experts: int, capacity_factor: float,

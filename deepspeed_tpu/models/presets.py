@@ -273,3 +273,33 @@ def build_model(cfg, attention_fn=None):
 
         return MoETransformerLM(cfg, attention_fn=attention_fn)
     return TransformerLM(cfg, attention_fn=attention_fn)
+
+
+# (what tells a trunk that is served here and not trained, why)
+_SERVED_NOT_TRAINED = (
+    (lambda cfg: getattr(cfg, "loop_steps", 1) > 1,
+     "a looped trunk (loop_steps > 1) is served, not trained here: its "
+     "objective is the expected loss over the exit distribution with an "
+     "entropy term (arXiv:2510.25741), and a next-token loss on the last "
+     "pass under the model's name would be a guess"),
+    (lambda cfg: getattr(cfg, "block_pattern", ""),
+     "a trunk of one mixer a layer (block_pattern) is served, not trained "
+     "here: the chunked scan's backward and the held experts' exchange are "
+     "not written"),
+    (lambda cfg: getattr(cfg, "attn_pattern", ""),
+     "window layers beside full ones (attn_pattern) are served, not trained "
+     "here: the blocked attention has no backward that skips the blocks "
+     "outside a window, and the held experts' exchange is not written"),
+    (lambda cfg: getattr(cfg, "attention", "") == "cca",
+     "compressed convolutional attention behind the zaya router "
+     "(attention='cca') is served, not trained here: the sorted expert rows "
+     "have no backward, and the router's carried state and the convs' have "
+     "none under a test"),
+)
+
+
+def why_not_trained(cfg):
+    """Why a model of ``cfg`` is served here and not trained, or None: the
+    one lookup the trainer makes (``runtime/engine.py``)."""
+    return next((why for tells, why in _SERVED_NOT_TRAINED if tells(cfg)),
+                None)

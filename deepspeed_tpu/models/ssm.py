@@ -87,7 +87,7 @@ def step_kernel_ok(cfg, fused: bool) -> bool:
     """Whether the one-token step moves the state with the Pallas kernel
     (``ops/ssm_step.py``: in place, running rows only) rather than with the
     XLA form below: where the decode kernels run (``fused``, the answer of
-    ``inference/decode.py`` ``_decode_kernel_ok``) and the shapes fit."""
+    ``inference/kinds/steps.py`` ``_decode_kernel_ok``) and the shapes fit."""
     from ..ops.ssm_step import kernel_fits
 
     return fused and kernel_fits(cfg.ssm_heads, cfg.ssm_groups,

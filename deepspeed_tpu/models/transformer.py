@@ -120,7 +120,7 @@ class TransformerConfig:
     # attention, models/cca.py: the whole attention in a latent of
     # ``n_head x head_dim`` behind two causal convolutions over positions,
     # kernels ``cca_conv``; K/V planes beside a conv tail a slot). The kind
-    # decides cache_layout().
+    # decides the cache (inference/kinds).
     attention: str = "mha"
     cca_conv: tuple = (2, 2)              # depthwise taps, then grouped taps
     # a scale and a bias a channel on each side of each sub-layer (ZAYA1):
@@ -135,7 +135,7 @@ class TransformerConfig:
     # layers is applied loop_steps times to every token over the SAME
     # weights, the final norm closing every pass (the last included); a
     # pass has its own keys and values, so the cache holds n_layer x
-    # loop_steps planes (inference/decode.py cache_layout)
+    # loop_steps planes (inference/kinds/dense.py)
     loop_steps: int = 1
     # a norm AFTER each sub-layer as well as before it: x + norm(attn(
     # norm(x))), x + norm(mlp(norm(x))) (ln1_post_scale, ln2_post_scale)
@@ -150,8 +150,7 @@ class TransformerConfig:
     # ``block_pattern[i]`` alone, x + mixer(norm(x)) with no FFN beside it —
     # "M" a Mamba-2 mixer (models/ssm.py), "E" latent experts, "*" attention
     # with no position code (models/hybrid.py). "" is the attention + FFN
-    # block of every other family. The pattern decides cache_layout(): K/V
-    # planes for the "*" layers only, beside a recurrent state a slot
+    # block of every other family. The cache: inference/kinds/hybrid.py
     block_pattern: str = ""
     ssm_heads: int = 0                    # Mamba-2: heads H ...
     ssm_head_dim: int = 0                 # ... of P channels (d_inner = H P)
@@ -179,8 +178,7 @@ class TransformerConfig:
     # logit a head in the softmax's denominator. Both kinds: heads
     # ``qk_head_dim`` wide for q and k over ``v_head_dim`` for v, V times
     # ``attn_value_scale``. "" is one kind of layer, as every other family
-    # has. The pattern decides cache_layout(): planes for the "G" layers
-    # beside a ring of 2 x 128 positions a slot for each "S" layer
+    # has. The cache: inference/kinds/windowed.py
     attn_pattern: str = ""
     window: int = 0
     window_kv_heads: Optional[int] = None
@@ -1020,7 +1018,7 @@ class TransformerLM:
     def _mlp_block(self, y, p):
         """FFN half. Returns (out, aux_loss); MoE trunks override this.
 
-        NOTE: ``inference/decode.py _mlp_tp_quant`` mirrors this math
+        NOTE: ``inference/kinds/steps.py _mlp_tp_quant`` mirrors this math
         with the w_out psum quantized (tp_comm_quant) — a change to the
         activation/gate/bias sequence here must be mirrored there or the
         quantized-TP greedy-parity oracle breaks for knob-on users."""

@@ -25,7 +25,7 @@ Nothing here materialises a ``(heads, T, S)`` score tensor: a full layer's
 queries walk the LIVE key blocks with a running max and sum
 (:func:`attend_blocks`), a window layer's see their own block and the
 ``window`` positions before it (:func:`attend_window`). The cache path is
-``inference/decode.py`` ``_forward_windowed``: planes for the ``G`` layers
+``inference/kinds/windowed.py``: planes for the ``G`` layers
 beside a ring of :func:`ring_len` positions a slot for each ``S`` layer.
 """
 

@@ -78,9 +78,11 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
                    page_size: int = 0, pool_pages: int = 0,
                    kv_quant_bits: int = 0) -> dict:
     """KV-cache byte breakdown for the slot engine's ONE persistent cache,
-    from the same :func:`~..inference.decode.cache_layout` the allocator
-    uses: K and V buffers, or the one buffer of a latent cache
-    (``per_token_bytes`` then follows the layout's rank + rope values).
+    summed over the buffers the cache's kind declares (``inference/kinds``,
+    the allocator's own source): each buffer that grows with the position
+    at its own width (``per_token_bytes``), and what a slot holds whatever
+    its length — a recurrent state, window rings, conv tails — as
+    ``state_bytes``, which ``total_bytes`` and ``per_slot_bytes`` include.
 
     ``page_size > 0`` accounts the pooled page layout instead: the
     resident total is the pool (+ the fp32 scale planes when the pool is
@@ -89,7 +91,8 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
     the operator sizes the pool in (docs/OPERATIONS.md)."""
     import jax.numpy as jnp
 
-    from ..inference.decode import cache_buffers, cache_layout
+    from ..inference.decode import cache_layout
+    from ..inference.kinds import kind_of
 
     if page_size > 0:
         shape, dt = cache_layout(model_cfg, slots, max_len, dtype,
@@ -113,13 +116,15 @@ def kv_cache_bytes(model_cfg, slots: int, max_len: int, dtype, *,
                 "page_size": page_size, "pool_pages": pool_pages,
                 "page_bytes": page_bytes, "scale_bytes": scale_bytes,
                 "kv_quant_bits": kv_quant_bits}
-    shape, dt = cache_layout(model_cfg, slots, max_len, dtype)
-    itemsize = jnp.dtype(dt).itemsize
-    total = cache_buffers(shape) * int(math.prod(shape)) * itemsize
-    per_slot = total // slots
-    return {"total_bytes": total, "per_slot_bytes": per_slot,
-            "per_token_bytes": per_slot // max_len,
-            "itemsize": itemsize, "slots": slots, "max_len": max_len,
+    kind = kind_of(model_cfg)
+    shape, dt = next(iter(kind.buffers(slots, max_len, dtype).values()))
+    per_token = kind.bytes_per_token(dtype)
+    state = kind.state_bytes_per_slot(dtype)
+    per_slot = max_len * per_token + state
+    return {"total_bytes": slots * per_slot, "per_slot_bytes": per_slot,
+            "per_token_bytes": per_token, "state_bytes": slots * state,
+            "itemsize": jnp.dtype(dt).itemsize, "slots": slots,
+            "max_len": max_len,
             "shape": list(shape), "dtype": str(jnp.dtype(dt)),
             "page_size": 0, "pool_pages": 0, "page_bytes": 0,
             "scale_bytes": 0, "kv_quant_bits": 0}

@@ -67,9 +67,9 @@ RETIRED = "retired"                # instant: terminal status lands
 # Serving engine cadence (no rid):
 DECODE_STEP = "decode_step"        # span: one slot decode step (all slots):
                                    # dispatch + read-back, the watchdog's
-                                   # window (meta: slots, queue; by cache
-                                   # kind what serving/engine.py's
-                                   # _moe_counts adds — a cca trunk's:
+                                   # window (meta: slots, queue; what the
+                                   # cache kind's ``step_meta`` adds,
+                                   # inference/kinds — a cca trunk's:
                                    # cache_bytes_per_token,
                                    # state_bytes_per_slot, live_positions,
                                    # experts_touched, moe_rows_over_routed,

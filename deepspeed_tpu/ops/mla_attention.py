@@ -2,7 +2,7 @@
 head against a slot's cached latents, and the in-place append.
 
 The cache is ``(L, B, rank + rope, max_len)``: per position the normed
-``c`` and the roped ``k_rope`` that ALL heads share (``inference/decode.py``
+``c`` and the roped ``k_rope`` that ALL heads share (``inference/kinds/latent.py``
 ``LatentCache``), positions on the lanes.
 
 - ``mla_decode_attention``: in ``ops/decode_attention.py`` every KV head

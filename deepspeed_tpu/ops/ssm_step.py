@@ -1,5 +1,5 @@
 """Pallas one-token step of the Mamba-2 recurrence on a batch of slots, in
-place in the carried state (``models/ssm.py``, ``inference/decode.py``).
+place in the carried state (``models/ssm.py``, ``inference/kinds/hybrid.py``).
 
     S[b, h] <- exp(dt[b, h] A[h]) S[b, h] + dt[b, h] x[b, h] (x) B[b, g]
     y[b, h]  = S[b, h] C[b, g]                       (P x N a head, float32)

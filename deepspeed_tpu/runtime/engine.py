@@ -184,30 +184,11 @@ class Engine:
                 "reduction order is compiler-managed (no pre-allreduce "
                 "division point exists), and fp16 overflow is handled by "
                 "dynamic loss scaling — remove the flag")
-        if getattr(getattr(model, "cfg", None), "loop_steps", 1) > 1:
-            raise ValueError(
-                "a looped trunk (loop_steps > 1) is served, not trained "
-                "here: its objective is the expected loss over the exit "
-                "distribution with an entropy term (arXiv:2510.25741), and "
-                "a next-token loss on the last pass under the model's name "
-                "would be a guess")
-        if getattr(getattr(model, "cfg", None), "block_pattern", ""):
-            raise ValueError(
-                "a trunk of one mixer a layer (block_pattern) is served, not "
-                "trained here: the chunked scan's backward and the held "
-                "experts' exchange are not written")
-        if getattr(getattr(model, "cfg", None), "attn_pattern", ""):
-            raise ValueError(
-                "window layers beside full ones (attn_pattern) are served, "
-                "not trained here: the blocked attention has no backward "
-                "that skips the blocks outside a window, and the held "
-                "experts' exchange is not written")
-        if getattr(getattr(model, "cfg", None), "attention", "") == "cca":
-            raise ValueError(
-                "compressed convolutional attention behind the zaya router "
-                "(attention='cca') is served, not trained here: the sorted "
-                "expert rows have no backward, and the router's carried "
-                "state and the convs' have none under a test")
+        from ..models import why_not_trained
+
+        why = why_not_trained(getattr(model, "cfg", None))
+        if why:
+            raise ValueError(why)
         mcfg = self.config.moe
         if mcfg.enabled:
             # ds_config moe section overrides the model's MoE knobs

@@ -41,18 +41,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..inference.config import ServingConfig
-from ..inference.decode import (GenCarry, cache_bytes_per_token,
-                                state_bytes_per_slot,
-                                decode_step, forward_with_cache,
+from ..inference.decode import (GenCarry, decode_step, forward_with_cache,
                                 init_cache)
 from ..inference.engine import InferenceEngine
+from ..inference.kinds import kind_of
 from ..inference.sampling import per_request_keys, split_keys
 from ..inference.speculation import NGramTable
 from ..observability import spans as _spans
 from ..observability.export import request_record
 from ..observability.metrics import get_registry
 from ..observability.tracing import ServingStats
-from ..models.windowed import KEY_BLOCK
 from ..ops.decode_attention import LANES, blocks_per_turn
 from ..resilience.chaos import ChaosMonkey
 from ..resilience.guards import QueueFullError, RequestStatus
@@ -158,136 +156,21 @@ class ServingEngine:
             raise ValueError(
                 f"serving max_len={self.cfg.max_len} exceeds the model's "
                 f"learned-position table (max_seq={mcfg.max_seq})")
-        # cache kinds and expert layers (docs/SERVING.md): what does not
-        # compose with them yet fails here, never falls back
-        self._latent = bool(getattr(mcfg, "latent_dim", 0))
-        self._moe_stats = self._latent \
-            and getattr(mcfg, "moe_router", "") == "sigmoid" \
-            and any(kind == "moe" for kind, _ in mcfg.segments)
-        self._hybrid = bool(getattr(mcfg, "block_pattern", ""))
-        self._windowed = bool(getattr(mcfg, "attn_pattern", ""))
-        self._cca = getattr(mcfg, "attention", "") == "cca"
-        if (self._latent or getattr(mcfg, "moe_router", "") == "sigmoid") \
-                and not (self._hybrid or self._windowed):   # (own lists below)
-            refused = [name for name, on in (
-                ("the paged pool (page_size)", self.cfg.page_size > 0),
-                ("an int8 KV cache (kv_quant_bits)",
-                 bool(self.cfg.kv_quant_bits)),
-                ("speculation", self.cfg.speculation is not None
-                 and self.cfg.speculation.enabled),
-                ("weight-only quantization", bool(engine.config.quantize)),
-                ("a mesh of several devices (tensor/expert parallel)",
-                 engine.mesh.size > 1)) if on]
-            if refused:
-                raise ValueError(
-                    "a latent (MLA) cache and sigmoid-routed expert layers "
-                    "do not yet compose with " + ", ".join(refused))
-        # one mixer a layer (models/hybrid.py block_pattern): a recurrent
-        # state a slot beside K/V planes for the attention layers only,
-        # some of every expert layer's experts held here
-        if self._hybrid:
-            refused = [why for why, on in (
-                ("the paged pool and prefix sharing (page_size): a recurrent "
-                 "state has no pages, and a shared prefix would need the "
-                 "state as it stood at the prefix's end",
-                 self.cfg.page_size > 0),
-                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
-                 "pool", bool(self.cfg.kv_quant_bits)),
-                ("speculation: a rejected draft would have to roll the "
-                 "recurrent state back, and the model's own drafting head "
-                 "is not held", self.cfg.speculation is not None
-                 and self.cfg.speculation.enabled),
-                ("tiered / host KV (host_pool_bytes): it moves pages",
-                 self.cfg.host_pool_bytes > 0),
-                ("weight-only quantization: the mixers' projections take "
-                 "dense weights", bool(engine.config.quantize)),
-                ("a mesh of several devices: the experts held are told by "
-                 "the configuration, no axis exchanges rows yet",
-                 engine.mesh.size > 1)) if on]
-            if refused:
-                raise ValueError(
-                    f"a trunk of one mixer a layer (block_pattern="
-                    f"{mcfg.block_pattern!r}) does not yet compose with "
-                    + "; ".join(refused))
-            self._moe_stats = "E" in mcfg.block_pattern
-        # window layers beside full ones (models/windowed.py attn_pattern):
-        # planes for the full layers beside a ring a slot for each window
-        # layer, keys wider than values, some of every expert layer's
-        # experts held here
-        if self._windowed:
-            refused = [why for why, on in (
-                ("the paged pool and prefix sharing (page_size): a page "
-                 "holds one K/V width for every layer, and a ring has no "
-                 "pages; a shared prefix would need the rings as they stood "
-                 "at the prefix's end", self.cfg.page_size > 0),
-                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
-                 "pool", bool(self.cfg.kv_quant_bits)),
-                ("speculation: a rejected draft would have to take its "
-                 "columns back out of the rings, and the model's own "
-                 "drafting layers (MTP) are not held",
-                 self.cfg.speculation is not None
-                 and self.cfg.speculation.enabled),
-                ("tiered / host KV (host_pool_bytes): it moves pages",
-                 self.cfg.host_pool_bytes > 0),
-                ("weight-only quantization: the two kinds' projections "
-                 "take dense weights", bool(engine.config.quantize)),
-                ("a mesh of several devices: the ring kernel has no "
-                 "shard_map rule and the experts held are told by the "
-                 "configuration, no axis exchanges rows yet",
-                 engine.mesh.size > 1)) if on]
-            if refused:
-                raise ValueError(
-                    f"window layers beside full ones (attn_pattern="
-                    f"{mcfg.attn_pattern!r}) do not yet compose with "
-                    + "; ".join(refused))
-            self._moe_stats = any(kind == "moe" for kind, _ in mcfg.segments)
-        # compressed convolutional attention and the zaya router
-        # (models/cca.py, models/moe.py): K/V planes beside a conv tail a
-        # slot a layer, a router state carried through the layer loop
-        if self._cca:
-            refused = [why for why, on in (
-                ("the paged pool and prefix sharing (page_size): a conv "
-                 "tail has no pages, and a shared prefix would need the "
-                 "tail as it stood at the prefix's end",
-                 self.cfg.page_size > 0),
-                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
-                 "pool", bool(self.cfg.kv_quant_bits)),
-                ("speculation: a rejected draft would have to roll the "
-                 "tails back, and the verify forward is many tokens a slot",
-                 self.cfg.speculation is not None
-                 and self.cfg.speculation.enabled),
-                ("tiered / host KV (host_pool_bytes): it moves pages",
-                 self.cfg.host_pool_bytes > 0),
-                ("weight-only quantization: the latent's projections and "
-                 "the router take dense weights", bool(engine.config.quantize)),
-                ("a mesh of several devices: the convs' channels and the "
-                 "sorted expert rows have no sharding rule under a test",
-                 engine.mesh.size > 1)) if on]
-            if refused:
-                raise ValueError(
-                    "compressed convolutional attention (attention='cca') "
-                    "does not yet compose with " + "; ".join(refused))
-            self._moe_stats = True
-        # a looped trunk (models/transformer.py loop_steps): its passes'
-        # n_layer x loop_steps cache planes are contiguous bf16/fp only
-        self._loops = int(getattr(mcfg, "loop_steps", 1))
-        self._exit_gate = self._loops > 1 and mcfg.exit_gate
-        if self._loops > 1:
-            refused = [why for why, on in (
-                ("the paged pool (page_size): it holds one plane a layer",
-                 self.cfg.page_size > 0),
-                ("an int8 KV cache (kv_quant_bits): it lives in the paged "
-                 "pool", bool(self.cfg.kv_quant_bits)),
-                ("speculation: its verify forward has no pass loop under a "
-                 "test", self.cfg.speculation is not None
-                 and self.cfg.speculation.enabled),
-                ("a mesh of several devices: no sharding of n_layer x "
-                 "loop_steps planes is under a test", engine.mesh.size > 1))
-                if on]
-            if refused:
-                raise ValueError(
-                    f"a looped trunk (loop_steps={self._loops}) does not "
-                    "yet compose with " + "; ".join(refused))
+        # the cache kind (inference/kinds; docs/SERVING.md, "Cache kinds"):
+        # what does not compose yet with it, or with the trunk's sorted
+        # expert rows, fails here, in ONE check, and never falls back
+        self.kind = kind_of(mcfg, self.cfg.slots, engine.compute_dtype,
+                            engine.params)
+        refused = self.kind.refusal([feature for feature, on in (
+            ("paged", self.cfg.page_size > 0),
+            ("kv_quant", bool(self.cfg.kv_quant_bits)),
+            ("speculation", self.cfg.speculation is not None
+             and self.cfg.speculation.enabled),
+            ("host_kv", self.cfg.host_pool_bytes > 0),
+            ("quantize", bool(engine.config.quantize)),
+            ("mesh", engine.mesh.size > 1)) if on])
+        if refused:
+            raise ValueError(refused)
         self._flash = engine.config.flash_decode_resolved()
         if self._flash and self.cfg.max_len % 128 != 0:
             raise ValueError(
@@ -307,24 +190,6 @@ class ServingEngine:
         # (benchmark/kinds/backlog_routed.py). None: nothing is fetched.
         self.routing_log: Optional[dict] = None
         self._chunk_routing: list = []   # (rid, start, device routing, real)
-        self._cache_bytes_per_token = cache_bytes_per_token(
-            mcfg, engine.compute_dtype) \
-            if self._latent or self._hybrid or self._windowed or self._cca \
-            or self._loops > 1 else None
-        self._state_bytes_per_slot = state_bytes_per_slot(
-            mcfg, engine.compute_dtype) \
-            if self._hybrid or self._windowed or self._cca else 0
-        if self._loops > 1:
-            # what a looped program reads of the weights, from the served
-            # tree's shapes: the layers once a pass, and the head (with the
-            # closing norm and the gate) once
-            layers, whole = (sum(a.nbytes for a in jax.tree.leaves(tree))
-                             for tree in (engine.params["layers"],
-                                          engine.params))
-            self._loop_weight_bytes = (
-                self._loops * layers,
-                whole - layers - engine.params["tok_embed"].nbytes
-                * (not mcfg.tie_embeddings))
         self._eos = engine.config.eos_token_id
         self._sampler = engine._sampler(self.cfg.temperature, self.cfg.top_k,
                                         self.cfg.top_p, self.cfg.greedy)
@@ -459,7 +324,8 @@ class ServingEngine:
         # span says how far past the live positions the kernel fetched. No
         # device read
         self._slot_len = np.zeros(self.cfg.slots, np.int64) \
-            if self._flash and not (self._paged or self._latent) else None
+            if self._flash and not self._paged \
+            and self.kind.planes == ("k", "v") else None
         self.pool: Optional[PagePool] = None
         self._table = None
         self._table_dirty = False
@@ -606,8 +472,7 @@ class ServingEngine:
                                total_deadline_s=self.cfg.total_deadline_s,
                                spans=self.spans, pages=self.pool,
                                rid_source=rid_source,
-                               recurrent=self._hybrid or self._windowed
-                               or self._cca)
+                               recurrent=self.kind.recurrent)
         self._programs: OrderedDict = \
             programs if programs is not None else OrderedDict()
         # disaggregated-serving hook (serving/fleet.py): a side-effecting
@@ -807,9 +672,9 @@ class ServingEngine:
         _, cache, stats, routing, passes = forward_with_cache(
             self.model, mat(params), ids, cache, with_stats=True,
             with_routing=True, with_passes=True)
-        if self._exit_gate:
+        if self.kind.exit_pdf:
             return cache, _mean_exit_pdf(passes, ids.shape[1]), None
-        return (cache, stats, routing) if self._moe_stats else cache
+        return (cache, stats, routing) if self.kind.moe_stats else cache
 
     def _final_impl(self, params, cache, ids, start, last_index, true_len,
                     rng):
@@ -828,9 +693,9 @@ class ServingEngine:
             else jnp.zeros(tok.shape, bool)
         pf = GenCarry(tok=tok, cache=cache._replace(length=true_len),
                       rng=rng, done=done)
-        if self._exit_gate:
+        if self.kind.exit_pdf:
             return pf, _mean_exit_pdf(passes, last_index + 1), None
-        return (pf, stats, routing) if self._moe_stats else pf
+        return (pf, stats, routing) if self.kind.moe_stats else pf
 
     def _step_impl(self, params, carry):
         """The slot step: ``(carry, read)``. ``read`` is what the host
@@ -845,8 +710,8 @@ class ServingEngine:
         out, *read = decode_step(
             self.model, params, carry, sampler=self._sampler,
             eos_token_id=self._eos, flash_decode=self._flash,
-            logit_guard=True, moe_stats=self._moe_stats,
-            exit_pdf=self._exit_gate)
+            logit_guard=True, moe_stats=self.kind.moe_stats,
+            exit_pdf=self.kind.exit_pdf)
         return out, (out.tok, out.done, *read)
 
     def _step_chaos_impl(self, params, carry, poison_row):
@@ -1205,178 +1070,6 @@ class ServingEngine:
         here."""
         return _spans.span(self.spans, self.stats.clock, kind, **fields)
 
-    def _moe_counts(self, moe: list, pending: list, lens=None) -> dict:
-        """What the decode read-back brought beside the tokens, as meta of
-        the ``decode_step`` span: of the step's expert layers
-        (``MoETransformerLM.experts``' counters, one row a layer) the most
-        rows any expert got over the mean, the rows the expert products
-        multiplied (padding included) over the rows routed, the experts
-        touched (mean over layers); and what a cached token costs. The
-        chunks' counters go onto their own ``prefill_chunk`` spans. Rows
-        routed count the whole slot batch: an idle slot's token is routed
-        and multiplied like any other. ``lens``: the mirror of the device's
-        lengths as the step had them (``_Flight.lens``)."""
-        if self._hybrid:
-            return self._hybrid_counts(moe, pending)
-        if self._windowed:
-            return self._windowed_counts(moe, pending, lens)
-        if self._cca:
-            return self._cca_counts(moe, pending, lens)
-        if not moe:          # no expert trunk, or the chaos build's step
-            return {}
-        return self._routed_counts(moe, pending)
-
-    def _routed_counts(self, moe: list, pending: list) -> dict:
-        """:meth:`_moe_counts` of a trunk whose every expert is held."""
-        k = self.model.cfg.moe_top_k
-        for (chunk_span, _, size), st in zip(pending, moe[1:]):
-            chunk_span.amend(moe_rows_over_routed=float(
-                st[:, 2].sum() / (len(st) * size * k)))
-        st = moe[0]
-        routed = self.cfg.slots * k
-        return {"moe_load_max_over_mean": float(
-                    st[:, 0].max() * self.model.cfg.num_experts / routed),
-                "moe_rows_over_routed": float(
-                    st[:, 2].sum() / (len(st) * routed)),
-                "experts_touched": float(st[:, 1].mean()),
-                "cache_bytes_per_token": self._cache_bytes_per_token}
-
-    def _cca_counts(self, moe: list, pending: list, lens) -> dict:
-        """Meta of a ``cca`` trunk's ``decode_step`` span:
-        :meth:`_hybrid_meta` (what a cached token and a slot's conv tails
-        cost); ``live_positions``, the kernel's count beside the span's own
-        ``slots``; of the step's expert layers (``_forward_cca``'s counters,
-        one row a layer: ``MoETransformerLM.experts``' four, then the mean
-        weight p of the layer's choices) what :meth:`_moe_counts` says of
-        every routed trunk (every expert is held), ``moe_rows_routed`` (slots
-        x 1) and ``router_top_p``: 1 / num_experts says the router is flat,
-        near 1 that it is saturated. A chunk's ``router_top_p`` goes onto
-        its own ``prefill_chunk`` span beside its rows over routed."""
-        meta = self._hybrid_meta()
-        if lens is not None:
-            meta["live_positions"] = int(lens.sum())
-        if not moe:
-            return meta
-        meta.update(self._routed_counts(moe, pending))
-        for (chunk_span, _, _), st in zip(pending, moe[1:]):
-            chunk_span.amend(router_top_p=float(st[:, 4].mean()))
-        meta.update(moe_rows_routed=self.cfg.slots,
-                    router_top_p=float(moe[0][:, 4].mean()))
-        return meta
-
-    def _hybrid_meta(self) -> dict:
-        """What every ``decode_step`` and ``prefill_chunk`` span of a
-        ``block_pattern`` or ``cca`` trunk says beside its times: what a
-        cached token and a slot's fixed-size state cost (``cache_layout()``,
-        ``state_layout()``)."""
-        return {"cache_bytes_per_token": self._cache_bytes_per_token,
-                "state_bytes_per_slot": self._state_bytes_per_slot}
-
-    def _hybrid_counts(self, moe: list, pending: list) -> dict:
-        """Meta of a ``block_pattern`` trunk's ``decode_step`` span. Of the
-        step's expert layers (``HybridLM.latent_experts``' counters, one row
-        a layer: most rows a held expert got, held experts touched, rows
-        multiplied, rows that chose a held expert): ``held_rows`` and
-        ``experts_touched``, means over the layers; ``held_rows_share`` of
-        the slots x k rows routed; the load as :meth:`_moe_counts` has it,
-        over the held experts. (That a slot at length 0 costs the step's
-        ``ssm_state_step`` nothing is no field here: the host could only
-        assert it. ``benchmark/kinds/backlog_hybrid.py`` holds the idle
-        slots' state to bit-equality on every run.)"""
-        return {**self._hybrid_meta(), **self._held_counts(moe, pending)}
-
-    def _held_counts(self, moe: list, pending: list) -> dict:
-        """Of a step whose expert layers hold a share (counters of one row
-        a layer: most rows a held expert got, held experts touched, rows
-        multiplied, rows that chose a held expert): ``held_rows`` and
-        ``experts_touched`` (means over the layers), ``held_rows_share`` of
-        the slots x k rows routed, the load over the held experts; the
-        chunks' first two go onto their own ``prefill_chunk`` spans. {}
-        where the step brought no counters."""
-        if not moe:
-            return {}
-        k, held = self.model.cfg.moe_top_k, self.model.cfg.held_experts
-        for (chunk_span, _, size), st in zip(pending, moe[1:]):
-            chunk_span.amend(held_rows=float(st[:, 3].mean()),
-                             experts_touched=float(st[:, 1].mean()))
-        st = moe[0]
-        held_rows = float(st[:, 3].mean())
-        return dict(
-            held_rows=held_rows,
-            held_rows_share=held_rows / (self.cfg.slots * k),
-            experts_touched=float(st[:, 1].mean()),
-            moe_load_max_over_mean=float(
-                st[:, 0].max() * held / max(held_rows, 1.0)))
-
-    def _windowed_meta(self, chunk=None) -> dict:
-        """What every ``decode_step`` and ``prefill_chunk`` span of an
-        ``attn_pattern`` trunk says beside its times: what a cached token
-        costs (the full layers' planes alone) and what a slot's rings cost
-        whatever its length (``cache_layout()``, ``state_layout()``). A
-        chunk's also ``key_blocks_walked_over_live``: the key blocks
-        (``windowed.KEY_BLOCK``) a full layer's queries walk (every block up to the chunk's end, for
-        every row of the chunk) over those that hold a key some row of the
-        chunk may see — 1: the walk stops at the live length."""
-        meta = {"cache_bytes_per_token": self._cache_bytes_per_token,
-                "window_bytes_per_slot": self._state_bytes_per_slot}
-        if chunk is not None:
-            walked = -(-(chunk.start + chunk.size) // KEY_BLOCK)
-            real = chunk.last_index + 1 if chunk.final else chunk.size
-            meta["key_blocks_walked_over_live"] = \
-                walked / -(-(chunk.start + real) // KEY_BLOCK)
-        return meta
-
-    def _windowed_counts(self, moe: list, pending: list, lens) -> dict:
-        """Meta of an ``attn_pattern`` trunk's ``decode_step`` span:
-        :meth:`_windowed_meta`; ``window_fetched_over_live`` (the positions
-        the window layers' kernel fetches — the one or two ring blocks of
-        128 that hold a running slot's last ``window`` positions — over the
-        positions inside the running slots' windows: 1 ideal, 2 with both
-        ring blocks, length / 128 if a full-length plane were read); and of
-        the step's expert layers (``MoETransformerLM.experts``' counters,
-        one row a layer) what :meth:`_hybrid_counts` says of a held
-        share."""
-        meta = self._windowed_meta()
-        if lens is not None:
-            n = lens[lens > 0]
-            w = self.model.cfg.window
-            blocks = -(-n // LANES) - np.maximum(n - w, 0) // LANES
-            inside = int(np.minimum(n, w).sum())
-            # the lengths the kernels' rooflines are reckoned from
-            meta.update(live_positions=int(n.sum()), window_live=inside,
-                        window_fetched_over_live=float(
-                            LANES * blocks.sum() / max(inside, 1)))
-        meta.update(self._held_counts(moe, pending))
-        return meta
-
-    def _loop_meta(self, tokens: int, head: bool = True) -> dict:
-        """What a looped trunk's ``decode_step`` and ``prefill_chunk`` spans
-        say beside their times, all host arithmetic: the passes, the cache
-        planes and bytes a token costs (``cache_layout()``), and the bytes
-        of weights the program reads — the layers once a pass, the head —
-        for each of the ``tokens`` it works on."""
-        layer_bytes, head_bytes = self._loop_weight_bytes
-        return {"loop_steps": self._loops,
-                "cache_planes": self.model.cfg.n_layer * self._loops,
-                "cache_bytes_per_token": self._cache_bytes_per_token,
-                "weight_bytes_per_token":
-                    (layer_bytes + head * head_bytes) / max(tokens, 1)}
-
-    def _loop_counts(self, pdfs: list, pending: list, running) -> dict:
-        """Meta of a looped trunk's ``decode_step`` span: :meth:`_loop_meta`
-        over the rows the step ran (``running``, their slots) and, where the
-        trunk has its
-        gate, ``exit_pdf``: the mean over their slots of the distribution
-        over exit passes, which the read-back brought beside the tokens
-        (``pdfs[0]``, (slots, passes)). The chunks' means (``pdfs[1:]``) go
-        onto their own ``prefill_chunk`` spans."""
-        for (chunk_span, _, _), pdf in zip(pending, pdfs[1:]):
-            chunk_span.amend(exit_pdf=pdf.tolist())
-        meta = self._loop_meta(len(running))
-        if pdfs and running:
-            meta["exit_pdf"] = pdfs[0][running].mean(0).tolist()
-        return meta
-
     def _attn_counts(self, fl: "_Flight") -> dict:
         """Of a step, two ratios in which 1 is ideal, from the mirror of
         the device's lengths as the step had them (``fl.lens``, with
@@ -1641,9 +1334,7 @@ class ServingEngine:
                     [moe.pop() for _ in tapped][::-1], rows)
             counts = self._attn_counts(fl) if fl.lens is not None else {}
             counts.update(
-                self._loop_counts(moe, pending, list(fl.rows))
-                if self._loops > 1
-                else self._moe_counts(moe, pending, fl.lens))
+                self.kind.step_meta(moe, pending, fl.lens, fl.rows))
         self._time_step(fl.t0, lane_s, step=fl.step, slots=len(fl.rows),
                         queue=fl.queue, ahead=fl.ahead, **counts)
         with self._span(_spans.SRV_RETIRE, step=n_it):
@@ -1929,12 +1620,7 @@ class ServingEngine:
         with self._span(_spans.PREFILL_CHUNK, name="srv.prefill_chunk",
                         rid=req.rid, step=n_it, chunk=idx, size=ch.size,
                         final=ch.final, ahead=ahead,
-                        **(self._loop_meta(ch.last_index + 1 if ch.final
-                                           else ch.size, head=ch.final)
-                           if self._loops > 1 else {}),
-                        **(self._hybrid_meta() if self._hybrid or self._cca
-                           else {}),
-                        **(self._windowed_meta(ch) if self._windowed else {}),
+                        **self.kind.chunk_meta(ch),
                         **self.sched._attempt_meta(req)) as chunk_span:
             ids = ch.ids[None]
             if not ch.final:
@@ -1954,7 +1640,7 @@ class ServingEngine:
     def _prefill_advance(self, n_it: int) -> list[Request]:
         req, plan, idx, _, rng = self._prefill
         (ch, out, chunk_span), self._ahead = self._ahead, None
-        if self._moe_stats or self._exit_gate:
+        if self.kind.moe_stats or self.kind.exit_pdf:
             # the chunk's expert counters (a looped trunk's: its mean exit
             # distribution) stay on the device until the next decode
             # read-back fetches them beside its own

@@ -3,7 +3,7 @@
 Reference analog: vLLM's PagedAttention block manager and the
 DeepSpeed-MII/FastGen blocked KV cache, rebuilt static-shape-native. The
 device state is ONE ``(L, pages, KV, page_size, hd)`` pool (per K and V,
-via the shared :func:`~..inference.decode.cache_layout`) plus integer
+the paged kind's layout, :func:`~..inference.decode.cache_layout`) plus integer
 per-slot page tables in the decode carry; the attention read gathers
 over page ids, so page indirection is DATA — traffic churn changes table
 contents, never a compiled program.
@@ -38,7 +38,7 @@ The host half lives here too:
 
 Pool page 0 is reserved scratch: idle slots' table rows point there, and
 the insert scatter redirects shared-page entries there; a row that is not
-running (length 0) writes nowhere at all (``_paged_append`` drops it) — a
+running (length 0) writes nowhere at all (``paged_append`` drops it) — a
 retired slot or a shared prefix can never be written by construction.
 
 Metrics land in the serving registry (``Serve/page_*``); ``snapshot()``

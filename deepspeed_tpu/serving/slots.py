@@ -3,13 +3,9 @@
 Reference analog: DeepSpeed-MII / FastGen's blocked-KV "ragged batching"
 state. TPU-native translation: instead of a paged block table (dynamic
 indirection is hostile to XLA's static shapes), the serving state is ONE
-``(L, slots, KV, hd, max_len)`` cache (``(L, slots, rank + rope, max_len)``
-for latent attention; for a trunk of one mixer a layer K/V planes for its
-attention layers only beside a recurrent state a slot, ``HybridCache``; for
-window layers beside full ones planes for the full layers beside a ring a
-slot for each window layer, ``WindowedCache``; for compressed convolutional
-attention K/V planes beside a conv tail a slot a layer, ``CCACache``) —
-the same layout ``init_cache`` allocates, via the shared :func:`~..inference.decode.cache_layout`:
+``(L, slots, KV, hd, max_len)`` cache, or whatever buffers the model's cache
+kind declares (``inference/kinds``: every buffer of every kind has the slot
+second) — the same layout ``init_cache`` allocates:
 positions on the lanes, so the buffer is compact in HBM at any head size
 and the decode step's kernel appends to it and reads it where it lies
 (``ops/decode_attention.py``) — plus per-slot ``length`` / ``tok`` /
@@ -45,9 +41,7 @@ def init_slots(cfg, slots: int, max_len: int, dtype=None) -> GenCarry:
     The carry is a plain :class:`~..inference.decode.GenCarry` whose cache
     ``length`` is a (slots,) vector — the decode stack's per-slot paths key
     off that shape, so the same ``decode_step`` serves both worlds. The
-    cache is of the model's kind (K and V, latents, or K/V beside a
-    recurrent state), from ``cache_layout`` / ``state_layout``: what follows
-    treats its buffers alike."""
+    cache is of the model's kind: what follows treats its buffers alike."""
     cache = init_cache(cfg, slots, max_len, dtype, length_shape=(slots,))
     return GenCarry(tok=jnp.zeros((slots,), jnp.int32), cache=cache,
                     rng=jnp.zeros((slots, 2), jnp.uint32),

@@ -359,11 +359,11 @@ def test_the_spans_carry_the_cache_and_state_counts(served):
     eng = engine(model, params)
     srv = ds.ServingEngine(eng, {"slots": 3, "max_len": 64,
                                  "prefill_chunk": 16, "greedy": True})
-    meta = srv._hybrid_meta()
+    meta = srv.kind.chunk_meta(None)
     assert meta == {"cache_bytes_per_token": cache_bytes_per_token(cfg, F32),
                     "state_bytes_per_slot": state_bytes_per_slot(cfg, F32)}
-    counts = srv._hybrid_counts([np.array([[3., 4., 16., 7.],
-                                            [2., 3., 8., 5.]])], [])
+    counts = srv.kind.step_meta([np.array([[3., 4., 16., 7.],
+                                           [2., 3., 8., 5.]])], [], None, [])
     assert counts["held_rows"] == 6.0 and counts["experts_touched"] == 3.5
     assert counts["held_rows_share"] == 6.0 / (3 * cfg.moe_top_k)
     # no field that nothing reads, none the host could only assert
