@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 from ..models.transformer import TransformerConfig, _norm
 from ..platform.mesh import BATCH_AXES, constrain
 from .kinds import (CCACache, HybridCache, KVCache, LatentCache,  # noqa: F401
-                    PagedKVCache, WindowedCache, kind_of)
+                    PagedKVCache, ParallelCache, WindowedCache, kind_of)
 from .kinds.steps import (_cache_attend, _decode_kernel_ok,  # noqa: F401
                           dequantize_kv, quantize_kv)
 from .quantization import (QuantizedTensor, dequant_rows, woq_dot,
@@ -117,6 +117,8 @@ def _decode_head(model, params, x):
         logits = lax.dot_general(
             x, w.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+    if cfg.mup.head != 1.0:
+        logits = logits * jnp.asarray(cfg.mup.head, logits.dtype)
     if cfg.lm_head_bias:
         logits = logits + params["lm_head_bias"].astype(logits.dtype)
     return constrain(logits, P(BATCH_AXES, None, "model"))
@@ -163,6 +165,8 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
         positions = base + jnp.broadcast_to(
             jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     x = _embed_rows(params["tok_embed"], input_ids, cfg.dtype)
+    if cfg.mup.embed != 1.0:
+        x = x * jnp.asarray(cfg.mup.embed, x.dtype)
     if cfg.pos_embedding == "learned":
         if per_slot:   # rows sit at different positions: per-row gather
             x = x + _embed_rows(params["pos_embed"], positions, cfg.dtype)

@@ -8,12 +8,14 @@ from .cca import CCA, CCACache
 from .dense import Dense, KVCache, PagedKVCache
 from .hybrid import Hybrid, HybridCache
 from .latent import Latent, LatentCache
+from .parallel import ParallelCache, ParallelHybrid
 from .windowed import Windowed, WindowedCache
 
-KINDS = (Dense, Latent, Hybrid, Windowed, CCA)
+KINDS = (Dense, Latent, Hybrid, Windowed, CCA, ParallelHybrid)
 
 __all__ = ["KINDS", "FEATURES", "Kind", "kind_of", "KVCache", "PagedKVCache",
-           "LatentCache", "HybridCache", "WindowedCache", "CCACache"]
+           "LatentCache", "HybridCache", "WindowedCache", "CCACache",
+           "ParallelCache"]
 
 
 def kind_of(cfg, *serving) -> Kind:

@@ -48,6 +48,7 @@ class Hybrid(Kind):
     @staticmethod
     def matches(cfg) -> bool:
         return bool(getattr(cfg, "block_pattern", "")) \
+            and "P" not in cfg.block_pattern \
             and not getattr(cfg, "attn_pattern", "") \
             and getattr(cfg, "attention", "mha") == "mha"
 

@@ -19,6 +19,16 @@ x))``, no FFN beside it; then the final norm and an untied head.
   model-configs guide's chip's share; ``tests/unit/test_hybrid_trunk.py``
   adds the shares up to the whole layer).
 
+``P`` (Falcon-H1, ``model_type: falcon_h1``; every layer of the trunk, or
+none) is another block: the Mamba-2 mixer AND rotary GQA attention on the
+same normed input, both added to the stream, then a gated FFN behind a norm
+of its own, every branch times its ``cfg.mup`` scalar where the model
+publishes it (:func:`parallel_qkv`, :func:`parallel_close`; the cache path is
+``inference/kinds/parallel.py``):
+
+    y = RMS_1(x);  x = x + Attn(y m_ai) m_ao + Mamba2(y) m_so
+    x = x + W_down(silu(W_gate RMS_2(x) m_g) * W_up RMS_2(x)) m_d
+
 Segments are runs of equal letters (``TransformerConfig.segments``), each
 scanned over its own stacked weights; ``params["layers"]`` is the tuple of
 them. The cache path is ``inference/kinds/hybrid.py``.
@@ -35,9 +45,51 @@ from jax.sharding import PartitionSpec as P
 
 from . import ssm
 from .moe import MoETransformerLM, held_layout
-from .transformer import TransformerLM, _norm
+from .transformer import TransformerLM, _norm, _rope
 
-KINDS = "ME*"
+KINDS = "ME*P"
+
+
+def parallel_qkv(cfg, y, p, positions):
+    """A ``P`` layer's q (B, T, H, hd), k, v (B, T, KV, hd) from the normed
+    input ``y``: the input times ``attn_in``, the keys times ``key``, then
+    the rotation over the whole head (halves paired, float32 angles)."""
+    B, T, _ = y.shape
+    m = cfg.mup
+    u = y * jnp.asarray(m.attn_in, y.dtype) if m.attn_in != 1.0 else y
+    q, k, v = (u @ p[name].astype(u.dtype) for name in ("wq", "wk", "wv"))
+    if m.key != 1.0:
+        k = k * jnp.asarray(m.key, k.dtype)
+    q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
+    k = k.reshape(B, T, cfg.kv_heads, cfg.head_dim)
+    q, k = _rope(q, k, positions, cfg.rope_theta, cfg.rotary_dim,
+                 halves=cfg.rope_halves)
+    return q, k, v.reshape(B, T, cfg.kv_heads, cfg.head_dim)
+
+
+@jax.named_scope("mlp")
+def gated_ffn(cfg, y2, p):
+    """A ``P`` layer's FFN on its own normed input: the gate's
+    pre-activation times ``mlp_gate``, the output times ``mlp_down``."""
+    m = cfg.mup
+    u = (y2 @ p["w_gate"].astype(y2.dtype)) * jnp.asarray(m.mlp_gate,
+                                                          y2.dtype)
+    u = jax.nn.silu(u) * (y2 @ p["w_up"].astype(y2.dtype))
+    return (u @ p["w_down"].astype(y2.dtype)) * jnp.asarray(m.mlp_down,
+                                                           y2.dtype)
+
+
+def parallel_close(cfg, x, o, mixed, p):
+    """A ``P`` layer behind its two mixers: the attention's heads ``o`` (B,
+    T, H, hd) through ``wo`` and the mixer's output ``mixed`` (B, T, d) onto
+    the stream, each times its multiplier, then the FFN."""
+    B, T, _ = x.shape
+    m = cfg.mup
+    o = o.reshape(B, T, -1) @ p["wo"].astype(x.dtype)
+    x = x + o * jnp.asarray(m.attn_out, x.dtype) \
+        + mixed * jnp.asarray(m.ssm_out, x.dtype)
+    y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
+    return x + gated_ffn(cfg, y2, p)
 
 
 class HybridLM(TransformerLM):
@@ -53,8 +105,15 @@ class HybridLM(TransformerLM):
             raise ValueError(
                 f"block_pattern {pat!r} has to name each of the {c.n_layer} "
                 f"layers' mixer, one of {KINDS!r}")
+        if "P" in pat and (set(pat) != {"P"} or c.pos_embedding != "rope"
+                           or not c.rope_halves or not c.is_glu):
+            raise ValueError(
+                "'P' is the Falcon-H1 block, every layer of its trunk: "
+                "rotary attention (pos_embedding='rope', rope_halves) beside "
+                "the Mamba-2 mixer, a gated FFN (activation='silu_glu')")
         if (c.use_bias or c.norm != "rmsnorm" or not c.causal
-                or c.pos_embedding != "none" or c.objective != "clm"
+                or c.pos_embedding != ("rope" if "P" in pat else "none")
+                or c.objective != "clm"
                 or c.loop_steps > 1 or c.sandwich_norm or c.post_ln
                 or c.parallel_residual or c.tie_embeddings
                 or c.attention != "mha" or attention_fn is not None):
@@ -62,10 +121,11 @@ class HybridLM(TransformerLM):
                 "a block_pattern trunk is the NemotronH block: a causal LM, "
                 "RMSNorm before each layer's one mixer, no biases, no "
                 "position code (pos_embedding='none'), an untied head")
-        if "M" in pat and (min(c.ssm_heads, c.ssm_head_dim, c.ssm_state) <= 0
-                           or c.ssm_heads % c.ssm_groups or c.ssm_conv < 2):
-            raise ValueError("'M' layers need ssm_heads / ssm_head_dim / "
-                             "ssm_state, ssm_groups dividing the heads")
+        if set(pat) & set("MP") and (
+                min(c.ssm_heads, c.ssm_head_dim, c.ssm_state) <= 0
+                or c.ssm_heads % c.ssm_groups or c.ssm_conv < 2):
+            raise ValueError("'M' / 'P' layers need ssm_heads / ssm_head_dim "
+                             "/ ssm_state, ssm_groups dividing the heads")
         if "E" in pat:
             E, held = c.num_experts, c.held_experts
             if (c.moe_router != "sigmoid" or c.moe_top_k > E or held > E
@@ -80,38 +140,60 @@ class HybridLM(TransformerLM):
         d, depth = cfg.d_model, cfg.n_layer
         k_embed, k_head = jax.random.split(rng)
         return {
+            # (a table and a head are drawn over their multipliers, as
+            # every branch is: _init_run)
             "tok_embed": jax.random.normal(k_embed, (cfg.vocab_size, d),
-                                           jnp.float32) * 0.02,
+                                           jnp.float32)
+            * (0.02 / cfg.mup.embed),
             "layers": tuple(
                 self._init_run(jax.random.fold_in(rng, 100 + i), kind, n,
                                depth)
                 for i, (kind, n) in enumerate(cfg.segments)),
             "lnf_scale": jnp.ones((d,), jnp.float32),
             "lm_head": jax.random.normal(k_head, (d, cfg.vocab_size),
-                                         jnp.float32) * 0.02,
+                                         jnp.float32)
+            * (0.02 / cfg.mup.head),
         }
 
     def _init_run(self, key, kind: str, n: int, depth: int) -> dict:
         """Stacked weights of a run of ``n`` layers of ``kind``. Every
         layer adds ONE branch to the stream, so an output projection is
-        scaled by 1 / sqrt(depth) (``rescale_prenorm_residual``)."""
+        scaled by 1 / sqrt(depth) (``rescale_prenorm_residual``); a ``P``
+        layer adds three, so by 1 / sqrt(3 depth). **Every matrix is drawn
+        over the ``cfg.mup`` scalars that multiply what it gives**, so that
+        a branch behind its multiplier adds to the stream what it adds in a
+        family without them (and the scores are not flat under a key
+        multiplier of 2^-6.5): with the usual draw the multipliers would
+        shrink every branch to nothing beside the residual, and a path that
+        dropped a mixer would agree with one that kept it."""
         cfg = self.cfg
-        d = cfg.d_model
+        d, m = cfg.d_model, cfg.mup
         if kind == "M":
             return ssm.init_params(cfg, key, n, depth)
         k = iter(jax.random.split(key, 10))
 
-        def dense(shape, fan_in, branch: bool = False):
+        def dense(shape, fan_in, branch: bool = False, over: float = 1.0):
             return jax.random.normal(next(k), shape, jnp.float32) \
-                / math.sqrt(fan_in * (depth if branch else 1))
+                / (math.sqrt(fan_in * (depth if branch else 1)) * over)
 
+        if kind == "P":
+            depth *= 3
         out = {"ln1_scale": jnp.ones((n, d), jnp.float32)}
-        if kind == "*":
+        if kind in "*P":
             h, kv, hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
-            out.update(wq=dense((n, d, h * hd), d),
-                       wk=dense((n, d, kv * hd), d),
-                       wv=dense((n, d, kv * hd), d),
-                       wo=dense((n, h * hd, d), h * hd, True))
+            out.update(wq=dense((n, d, h * hd), d, over=m.attn_in),
+                       wk=dense((n, d, kv * hd), d, over=m.attn_in * m.key),
+                       wv=dense((n, d, kv * hd), d, over=m.attn_in),
+                       wo=dense((n, h * hd, d), h * hd, True, m.attn_out))
+        if kind == "*":
+            return out
+        if kind == "P":
+            f = cfg.ffn_dim
+            out.update(ssm.init_params(cfg, next(k), n, depth),
+                       ln2_scale=jnp.ones((n, d), jnp.float32),
+                       w_gate=dense((n, d, f), d, over=m.mlp_gate),
+                       w_up=dense((n, d, f), d),
+                       w_down=dense((n, f, d), f, True, m.mlp_down))
             return out
         E, held = cfg.num_experts, cfg.held_experts
         lat, f, fs = cfg.moe_latent_dim or d, cfg.expert_dim, \
@@ -189,8 +271,13 @@ class HybridLM(TransformerLM):
         empty = {name: jnp.zeros(shape, jnp.float32 if name == "ssm"
                                  else x.dtype)
                  for name, shape in ssm.state_shapes(cfg, B).items()}
-        return x + ssm.mix_chunk(cfg, p, y, empty["ssm"],
-                                 empty["conv"])[0], None
+        mixed = ssm.mix_chunk(cfg, p, y, empty["ssm"], empty["conv"])[0]
+        if kind == "M":
+            return x + mixed, None
+        with jax.named_scope("parallel_mixers"):
+            o = self.attention_fn(*parallel_qkv(cfg, y, p, positions),
+                                  mask=None)
+        return parallel_close(cfg, x, o, mixed, p), None
 
     def _trunk(self, params, input_ids, attn_mask, remat_policy):
         """Embed + the layers: (B, S) -> ((B, S, d) before the final norm,

@@ -1356,6 +1356,61 @@ def _zaya_convert(sd: _SDict, cfg: TransformerConfig) -> dict:
         "would load a different model under this one's name")
 
 
+# ------------------------------------------------------ family: falcon_h1
+def _falcon_h1_config(hf: dict) -> TransformerConfig:
+    """Falcon-H1's ``config.json`` → the native configuration: every layer a
+    Mamba-2 mixer and rotary GQA attention side by side, then a gated FFN,
+    each branch times its published multiplier (``MuP``)."""
+    from .presets import falcon_h1
+    from .transformer import MuP
+
+    for key, only in (("hidden_act", "silu"), ("attention_bias", False),
+                      ("mlp_bias", False), ("projectors_bias", False),
+                      ("mamba_proj_bias", False), ("mamba_conv_bias", True),
+                      ("mamba_rms_norm", True), ("mamba_use_mlp", True),
+                      ("mamba_norm_before_gate", False),
+                      ("attn_layer_indices", None), ("rope_scaling", None),
+                      ("tie_word_embeddings", False)):
+        if hf.get(key, only) != only:
+            raise ValueError(f"falcon_h1 with {key}={hf[key]!r}: the native "
+                             f"trunk runs {only!r}")
+    heads, hd = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if heads * hd != hf["mamba_d_ssm"]:
+        raise ValueError("falcon_h1: mamba_d_ssm is mamba_n_heads heads of "
+                         "mamba_d_head")
+    gate, down = hf["mlp_multipliers"]
+    return falcon_h1(
+        "tiny", n_layer=hf["num_hidden_layers"],
+        n_head=hf["num_attention_heads"],
+        n_kv_head=hf["num_key_value_heads"], d_model=hf["hidden_size"],
+        qk_head_dim=hf["head_dim"], d_ff=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"], max_seq=hf["max_position_embeddings"],
+        norm_eps=hf["rms_norm_eps"], rope_theta=float(hf["rope_theta"]),
+        ssm_heads=heads, ssm_head_dim=hd, ssm_groups=hf["mamba_n_groups"],
+        ssm_state=hf["mamba_d_state"], ssm_conv=hf["mamba_d_conv"],
+        ssm_chunk=hf["mamba_chunk_size"],
+        mup=MuP(embed=float(hf["embedding_multiplier"]),
+                head=float(hf["lm_head_multiplier"]),
+                attn_in=float(hf["attention_in_multiplier"]),
+                attn_out=float(hf["attention_out_multiplier"]),
+                key=float(hf["key_multiplier"]),
+                ssm_in=float(hf["ssm_in_multiplier"]),
+                ssm_out=float(hf["ssm_out_multiplier"]),
+                ssm=tuple(float(v) for v in hf["ssm_multipliers"]),
+                mlp_gate=float(gate), mlp_down=float(down)))
+
+
+def _falcon_h1_convert(sd: _SDict, cfg: TransformerConfig) -> dict:
+    raise NotImplementedError(
+        "falcon_h1: config.json maps to the native configuration "
+        "(config_from_hf), the checkpoint's tensors do not yet: the names "
+        "and layouts of its weights (the mixer's fused in_proj and its "
+        "column order, the conv's taps, whether a multiplier is already "
+        "folded into a stored matrix) are not in config.json and were not "
+        "to hand when the family was written; a guessed key map would load "
+        "a different model under this one's name")
+
+
 _FAMILIES: dict[str, tuple[Callable, Callable, tuple[str, ...]]] = {
     # model_type → (config_fn, convert_fn, state-dict prefixes to strip)
     "gpt2": (_gpt2_config, _gpt2_convert, ("transformer.",)),
@@ -1387,6 +1442,7 @@ _FAMILIES: dict[str, tuple[Callable, Callable, tuple[str, ...]]] = {
     # the configuration alone: the converter refuses with its reason
     "mimo_v2_flash": (_mimo_v2_config, _mimo_v2_convert, ("model.",)),
     "zaya": (_zaya_config, _zaya_convert, ("model.",)),
+    "falcon_h1": (_falcon_h1_config, _falcon_h1_convert, ("model.",)),
 }
 
 

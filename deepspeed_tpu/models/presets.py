@@ -7,7 +7,7 @@ by the same trunk with ``causal=False`` planned, Mixtral via ``num_experts``.
 
 from __future__ import annotations
 
-from .transformer import TransformerConfig, TransformerLM
+from .transformer import MuP, TransformerConfig, TransformerLM
 
 
 def gpt2(size: str = "125m", **overrides) -> TransformerConfig:
@@ -250,6 +250,47 @@ def zaya(size: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def falcon_h1(size: str = "tiny", **overrides) -> TransformerConfig:
+    """Falcon-H1 family (``model_type: falcon_h1``): every layer a Mamba-2
+    mixer AND rotary GQA attention on the same normed input, both added to
+    the stream, then a gated FFN (``block_pattern`` of ``P``,
+    models/hybrid.py), every branch times a muP scalar (``mup``), an untied
+    head. ``"34b"`` is tiiuae/Falcon-H1-34B-Instruct's ``config.json``;
+    ``"tiny"`` keeps what the kernels' layouts turn on at unit-test size: 4
+    heads a group, a head dim off the state size, 5 query heads a KV head,
+    every multiplier off 1."""
+    table = {
+        "tiny": dict(
+            n_layer=3, n_head=10, n_kv_head=2, d_model=64, qk_head_dim=8,
+            d_ff=96, vocab_size=251, max_seq=256, ssm_heads=8,
+            ssm_head_dim=16, ssm_groups=2, ssm_state=32, ssm_chunk=8,
+            rope_theta=1e4,
+            mup=MuP(embed=2.5, head=0.125, attn_in=0.5, attn_out=0.2,
+                    key=0.25, ssm_in=0.5, ssm_out=0.3,
+                    ssm=(0.35, 0.25, 0.18, 0.5, 0.7), mlp_gate=0.4,
+                    mlp_down=0.15)),
+        "34b": dict(
+            n_layer=72, n_head=20, n_kv_head=4, d_model=5120,
+            qk_head_dim=128, d_ff=21504, vocab_size=261120, max_seq=262144,
+            ssm_heads=32, ssm_head_dim=128, ssm_groups=2, ssm_state=256,
+            ssm_chunk=128, rope_theta=1e11,
+            mup=MuP(embed=5.656854249492381, head=0.0078125, attn_in=1.0,
+                    attn_out=0.0375, key=0.011048543456039804, ssm_in=0.25,
+                    ssm_out=0.08838834764831845,
+                    ssm=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+                    mlp_gate=0.1767766952966369,
+                    mlp_down=0.011160714285714284)),
+    }
+    base = dict(pos_embedding="rope", rope_halves=True, norm="rmsnorm",
+                norm_eps=1e-5, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    base.setdefault("block_pattern", "P" * base["n_layer"])
+    return TransformerConfig(**base)
+
+
 def tiny_test(**overrides) -> TransformerConfig:
     """Unit-test sized config (analog of the reference tests' SimpleModel)."""
     base = dict(vocab_size=256, n_layer=2, n_head=4, d_model=64, d_ff=128,
@@ -282,6 +323,10 @@ _SERVED_NOT_TRAINED = (
      "objective is the expected loss over the exit distribution with an "
      "entropy term (arXiv:2510.25741), and a next-token loss on the last "
      "pass under the model's name would be a guess"),
+    (lambda cfg: "P" in getattr(cfg, "block_pattern", ""),
+     "a trunk of a Mamba-2 mixer and attention side by side in every layer "
+     "(block_pattern 'P') is served, not trained here: the chunked scan has "
+     "no backward"),
     (lambda cfg: getattr(cfg, "block_pattern", ""),
      "a trunk of one mixer a layer (block_pattern) is served, not trained "
      "here: the chunked scan's backward and the held experts' exchange are "

@@ -48,13 +48,29 @@ def state_shapes(cfg, batch: int) -> dict:
             "conv": (batch, cfg.ssm_conv - 1, dims(cfg)["conv"])}
 
 
+def in_multipliers(cfg):
+    """``cfg.mup.ssm`` spread over w_in's output columns [z | x | B | C |
+    dt], float32 (in,); None where the model publishes none."""
+    if not cfg.mup.ssm:
+        return None
+    w = dims(cfg)
+    return jnp.repeat(
+        jnp.asarray(cfg.mup.ssm, jnp.float32),
+        jnp.asarray([w["inner"], w["inner"], w["bc"], w["bc"],
+                     cfg.ssm_heads]), total_repeat_length=w["in"])
+
+
 def init_params(cfg, key, n: int, depth: int) -> dict:
     """Stacked weights of ``n`` Mamba-2 layers. ``dt_bias`` is the inverse
     softplus of a dt drawn log-uniform in ``ssm_dt_init`` (min, max), floored;
     ``A_log = log U(1, 16)``; ``D = 1``; the conv as ``nn.Conv1d`` draws it;
-    the output projection scaled down by the depth (one branch a layer)."""
+    the output projection scaled down by the depth (one branch a layer).
+    Under ``cfg.mup`` a projection is drawn over its multipliers, so that
+    what reaches the conv, the gate, dt and the stream is what it is
+    without them."""
     d, H, K = cfg.d_model, cfg.ssm_heads, cfg.ssm_conv
     w = dims(cfg)
+    mup, cols = cfg.mup, in_multipliers(cfg)
     k = iter(jax.random.split(key, 6))
     lo, hi, floor = cfg.ssm_dt_init
     dt = jnp.exp(jax.random.uniform(next(k), (n, H), jnp.float32)
@@ -64,7 +80,7 @@ def init_params(cfg, key, n: int, depth: int) -> dict:
     return {
         "ln1_scale": jnp.ones((n, d), jnp.float32),
         "w_in": jax.random.normal(next(k), (n, d, w["in"]), jnp.float32)
-        / math.sqrt(d),
+        / (math.sqrt(d) * mup.ssm_in * (1.0 if cols is None else cols)),
         "conv_w": jax.random.uniform(next(k), (n, w["conv"], K), jnp.float32,
                                      -bound, bound),
         "conv_b": jax.random.uniform(next(k), (n, w["conv"]), jnp.float32,
@@ -75,7 +91,7 @@ def init_params(cfg, key, n: int, depth: int) -> dict:
         "D": jnp.ones((n, H), jnp.float32),
         "ssm_norm_scale": jnp.ones((n, w["inner"]), jnp.float32),
         "w_out": jax.random.normal(next(k), (n, w["inner"], d), jnp.float32)
-        / math.sqrt(depth * w["inner"]),
+        / (math.sqrt(depth * w["inner"]) * mup.ssm_out),
     }
 
 
@@ -98,7 +114,12 @@ def _project(cfg, p, y):
     """y (B, T, d) -> z (B, T, inner), xBC (B, T, conv) before the conv,
     dt (B, T, H) float32 after the softplus."""
     w = dims(cfg)
+    if cfg.mup.ssm_in != 1.0:
+        y = y * jnp.asarray(cfg.mup.ssm_in, y.dtype)
     u = y @ p["w_in"].astype(y.dtype)
+    cols = in_multipliers(cfg)
+    if cols is not None:
+        u = (u.astype(jnp.float32) * cols).astype(u.dtype)
     z, xbc, dt = jnp.split(u, [w["inner"], w["inner"] + w["conv"]], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32)
                          + p["dt_bias"].astype(jnp.float32))

@@ -39,6 +39,24 @@ B_AXES = BATCH_AXES  # ("data", "zero", "expert")
 
 
 @dataclasses.dataclass(frozen=True)
+class MuP:
+    """The scalars a muP-parametrised model (Falcon-H1, ``model_type:
+    falcon_h1``) multiplies its branches by, each applied where the model
+    publishes it; 1 everywhere is every other family."""
+
+    embed: float = 1.0        # the embedding's rows
+    head: float = 1.0         # the logits
+    attn_in: float = 1.0      # the attention's normed input
+    attn_out: float = 1.0     # the attention's output, behind wo
+    key: float = 1.0          # the keys, before the rotation
+    ssm_in: float = 1.0       # the Mamba-2 mixer's normed input
+    ssm_out: float = 1.0      # the mixer's output, behind w_out
+    ssm: tuple = ()           # w_in's output by segment [z, x, B, C, dt]
+    mlp_gate: float = 1.0     # the gate's pre-activation
+    mlp_down: float = 1.0     # the FFN's output, behind w_out
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
     n_layer: int = 12
@@ -150,8 +168,13 @@ class TransformerConfig:
     # ``block_pattern[i]`` alone, x + mixer(norm(x)) with no FFN beside it —
     # "M" a Mamba-2 mixer (models/ssm.py), "E" latent experts, "*" attention
     # with no position code (models/hybrid.py). "" is the attention + FFN
-    # block of every other family. The cache: inference/kinds/hybrid.py
+    # block of every other family. The cache: inference/kinds/hybrid.py.
+    # "P" (Falcon-H1, ``model_type: falcon_h1``; every layer, or none): a
+    # Mamba-2 mixer AND rotary GQA attention on the same normed input, both
+    # added to the stream, then a gated FFN behind a norm of its own, every
+    # branch times its ``mup`` scalar. The cache: inference/kinds/parallel.py
     block_pattern: str = ""
+    mup: MuP = MuP()
     ssm_heads: int = 0                    # Mamba-2: heads H ...
     ssm_head_dim: int = 0                 # ... of P channels (d_inner = H P)
     ssm_groups: int = 1                   # B and C are shared by H / G heads
@@ -284,8 +307,8 @@ class TransformerConfig:
         n_params = self.loop_steps * self.param_count(
             non_embedding=True, active_only=True)   # every pass multiplies
         # scores + values: 2 * S * H * (qk width + v width) forward, x3
-        n_attn = self.block_pattern.count("*") if self.block_pattern \
-            else self.n_layer
+        n_attn = sum(map(self.block_pattern.count, "*P")) \
+            if self.block_pattern else self.n_layer
         # a window layer's query sees at most ``window`` keys
         n_win = self.attn_pattern.count("S")
         keys = (n_attn - n_win) * self.max_seq \
@@ -330,10 +353,14 @@ class TransformerConfig:
         d = self.d_model
         if kind == "*":
             return self._attn_params_per_layer()
+        inner = self.ssm_heads * self.ssm_head_dim
+        bc = 2 * self.ssm_groups * self.ssm_state
+        mamba = d * (2 * inner + bc + self.ssm_heads) + inner * d
         if kind == "M":
-            inner = self.ssm_heads * self.ssm_head_dim
-            bc = 2 * self.ssm_groups * self.ssm_state
-            return d * (2 * inner + bc + self.ssm_heads) + inner * d
+            return mamba
+        if kind == "P":
+            return mamba + self._attn_params_per_layer() \
+                + self._ffn_params_per_layer(kind="dense")
         lat = self.moe_latent_dim or d
         experts = min(self.moe_top_k, self.num_experts) if active_only \
             else self.held_experts
@@ -659,6 +686,11 @@ class TransformerLM:
                                       or config.parallel_residual):
             raise ValueError("residual_scale scales the two sides of a "
                              "pre-norm two-hop block")
+        if config.mup != MuP() and "P" not in config.block_pattern:
+            raise ValueError(
+                "mup: the branch multipliers are the Falcon-H1 block's "
+                "(block_pattern 'P', models/hybrid.py); no other block "
+                "applies them")
         if config.attn_pattern:
             from .windowed import check_config
 
@@ -1125,6 +1157,8 @@ class TransformerLM:
         cfg = self.cfg
         B, S = input_ids.shape
         x = self._tok_lookup(params["tok_embed"].astype(cfg.dtype), input_ids)
+        if cfg.mup.embed != 1.0:
+            x = x * jnp.asarray(cfg.mup.embed, x.dtype)
         positions = self._positions(B, S)
         if cfg.pos_embedding == "learned":
             x = x + params["pos_embed"].astype(cfg.dtype)[positions[0]][None]
@@ -1253,6 +1287,8 @@ class TransformerLM:
             logits = tiled_matmul(x, w, cfg.tiled_head)
         else:
             logits = x @ w
+        if cfg.mup.head != 1.0:
+            logits = logits * jnp.asarray(cfg.mup.head, logits.dtype)
         if cfg.lm_head_bias:
             logits = logits + params["lm_head_bias"].astype(logits.dtype)
         return constrain(logits, P(B_AXES, "seq", "model"))

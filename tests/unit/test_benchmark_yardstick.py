@@ -18,7 +18,13 @@ pins likewise that its configuration, its cell and its five metrics are the
 LAST of their lists: the ``mm_spec`` fixture below hands it ``BENCHMARK.json``
 without the configurations and cells appended since PR 42 and, of the metrics
 appended since, with those alone that list the MiMo cell.
-``test_zaya.py`` pins no position. The Ouro test has no
+Since PR 48 two of the Nemotron cell's five (``ssm_state_step_roofline``,
+``ssm.state_bytes_per_slot``) list a second cell, Falcon-H1's, which runs the
+same kernel under the same reader: ``nh_spec`` also takes out of every list
+the cells that ``workloads`` appends after the Nemotron cell, so that the pin
+"the metrics that list the Nemotron cell ALONE are the last five" reads the
+file as PR 37 left it. ``test_zaya.py`` and ``test_falcon_h1.py`` pin no
+position. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -41,10 +47,12 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_window", "benchmark.tests.test_deepseek_v3",
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
+    "benchmark.tests.test_falcon_h1",
     "benchmark.tests.test_program_lifecycle")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
+from benchmark.tests.test_falcon_h1 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_mimo_v2_flash import *  # noqa: E402,F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ouro import *  # noqa: E402,F401,F403
@@ -66,6 +74,11 @@ def nh_spec():  # noqa: F811
     spec["per_layer"] = spec["per_layer"][:cut] + [
         m for m in spec["per_layer"][cut:]
         if cell in m.get("workloads", [cell])]
+    cells = [w["name"] for w in spec["workloads"]]
+    later = set(cells[cells.index(cell) + 1:])
+    for m in spec["per_layer"] + spec["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] = [w for w in m["workloads"] if w not in later]
     return spec
 
 

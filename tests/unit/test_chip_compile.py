@@ -952,3 +952,107 @@ def test_cca_trunk_keeps_planes_and_tails_in_place(one_chip, monkeypatch,
     assert count == {"cca_decode_attention": int(batch > 1),
                      "decode_attention": 0, "moe_experts_up": 1,
                      "moe_experts_down": 1}, count
+
+
+# ------------- a Mamba-2 mixer and attention side by side in every layer
+def test_ssm_state_step_at_blocks_of_two_mebibytes(one_chip):
+    """``ssm_state_step`` at Falcon-H1's shapes — a group's block is (16,
+    128, 256) float32 = 2 MiB, in and out double-buffered 8 MiB of a core's
+    16 MiB of scoped VMEM — lowers for the chip as it stands (no split over a
+    group's heads), with Nemotron's (16, 64, 128) = 512 KiB beside it."""
+    from deepspeed_tpu.ops.ssm_step import kernel_fits, ssm_state_step
+
+    f32 = jnp.float32
+    for L, B, H, G, P, N in ((6, 96, 32, 2, 128, 256),
+                             (5, 64, 128, 8, 64, 128)):
+        assert kernel_fits(H, G, P, N)
+        text = _compile(
+            lambda S, lay, x, dt, A, Bv, Cv, n: ssm_state_step(
+                S, lay, x, dt, A, Bv, Cv, n, interpret=False), one_chip,
+            ((L, B, H, P, N), f32), ((), jnp.int32), ((B, H, P), f32),
+            ((B, H), f32), ((H,), f32), ((B, G, N), f32), ((B, G, N), f32),
+            ((B,), jnp.int32))
+        assert "ssm_state_step" in text
+
+
+@pytest.mark.parametrize("program", ["slot step", "chunk", "final chunk"])
+def test_parallel_trunk_keeps_planes_and_state_in_place(one_chip, monkeypatch,
+                                                        program, capsys):
+    """Falcon-H1-34B's stage (6 of 72 layers, the embedding and the head,
+    ``benchmark/configs/falcon-h1-34b-l6.json``) at the cell's 96 slots x
+    1536, chunks of 256: the planes, the state and the window enter donated
+    and leave aliased; ``ssm_state_step`` and the decode kernel (under its
+    own name, at five query heads a KV head) lower for the chip once each
+    (one scan over the six layers); the step's live set stands under 15.0
+    GiB, which is what keeps the mix at 96 slots and not 80."""
+    import json
+    import time
+
+    from benchmark.models import falcon_h1 as fam
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_len, chunk = 96, 1536, 256
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "falcon-h1-34b-l6.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, dtype or a.dtype, sharding=one_chip), tree)
+
+    # every leaf in the served type (benchmark/kinds/_serving.py build)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                     jnp.bfloat16)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, chunk), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, slots, max_len, jnp.bfloat16)))
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        batch = slots
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, max_len, jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=final,
+                last_index=last if final else None)[final ^ 1:],
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        batch = 1
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n[parallel {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB]")
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 12288
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 25350144
+    held = batch * (25350144 + max_len * 12288)
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert mem.temp_size_in_bytes < (256 if batch > 1 else 1024) * 2 ** 20, \
+        mem.temp_size_in_bytes
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live + (held // slots if batch > 1 else 0) < 15.0 * 2 ** 30, live
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "ssm_state_step", "gqa_decode_attention", "decode_attention")}
+    assert count == {"ssm_state_step": int(batch > 1),
+                     "gqa_decode_attention": int(batch > 1),
+                     "decode_attention": 0}, count
