@@ -94,6 +94,24 @@ class Kind:
         layers or None, a looped trunk's passes or None)."""
         raise NotImplementedError
 
+    def deferred_rows(self, dtype=None) -> int:
+        """The positions a slot's newest K/V wait in a tail of rows before
+        their block is written (``kinds/dense.py``): 0 where the T == 1
+        step writes the block back every time."""
+        return 0
+
+    def rewound(self, cache, length):
+        """``cache`` standing at ``length``, at most where a forward left it
+        (a right-padded final chunk's real tokens), as the T == 1 step takes
+        it over."""
+        return cache._replace(length=length)
+
+    def settled(self, cache):
+        """``cache`` with every live position where whatever is not the
+        T == 1 step's kernel reads it: as it stands, for a kind whose step
+        defers nothing."""
+        return cache
+
     def refusal(self, on) -> Optional[str]:
         """The sentence ``ServingEngine`` refuses to be built with, of the
         features ``on``, or None: the kind's own list, behind the sorted

@@ -4,11 +4,13 @@ contiguous, or in a pool of pages shared by the serving slots
 (``serving/pages.py``), chosen by a ``page_size`` and not by the model."""
 
 from collections import namedtuple
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ...ops.decode_attention import LANES, tail_rows
 from .base import IN_POOL, Kind
 from .steps import _layer_step
 
@@ -22,7 +24,23 @@ from .steps import _layer_step
 # each kernel call (PERF.md F10). Heads-major, so a slot's heads over 128
 # positions are one block. ``length``: i32 tokens cached, scalar (all rows
 # advance together) or (B,) per-slot (serving/slots.py).
-KVCache = namedtuple("KVCache", "k v length")
+# ``tail`` (L, B, KV, T, hd + vd), or None where the kind keeps none: the
+# DEFERRED TAIL, T positions ON THE SUBLANES (T one sublane tile of the
+# dtype: 16 rows of bf16), K beside V on the lanes. With positions on the
+# lanes one position is one lane of every tile of its block, which no copy
+# can write alone: the T == 1 step's kernel had to write the whole block of
+# 128 back to append one. With a tail it writes position ``p`` onto row
+# ``p % T`` of the slot's tile, and the block once the group of T is
+# complete (``ops/decode_attention.py``). So between two steps the blocks
+# hold every position before the current group, ``[0, (length - 1) // T *
+# T)``, and the tail's rows ``0 .. (length - 1) % T`` the group itself;
+# what the blocks hold of that group, and the tile's rows behind the
+# length, is not read. Everything but that kernel reads and writes the
+# blocks: ``Dense.settled`` puts the group into them, ``Dense.rewound``
+# fills the tail from them (what ``Dense.forward`` does behind every T > 1
+# forward), and the seat copies the tail with the rest
+# (``serving/slots.py``).
+KVCache = namedtuple("KVCache", "k v length tail", defaults=(None,))
 # Page-pool KV state for the serving slot batch (``serving/pages.py`` has the
 # pool's allocator, prefix tree and scratch page 0): fixed-size pages shared
 # by all slots, each slot mapping its positions onto pool pages through its
@@ -51,6 +69,8 @@ class Dense(Kind):
         super().__init__(cfg, slots, dtype)
         self.loops = int(getattr(cfg, "loop_steps", 1))
         self.layers = cfg.n_layer * self.loops
+        # K beside V, as a row of the deferred tail has them
+        self.row_width = cfg.head_dim + getattr(cfg, "v_dim", cfg.head_dim)
         self.exit_pdf = self.loops > 1 and cfg.exit_gate
         if self.loops > 1:
             self.what = (f"a looped trunk (loop_steps={self.loops}) does not "
@@ -83,6 +103,93 @@ class Dense(Kind):
         return getattr(cfg, "attention", "mha") == "mha" \
             and not getattr(cfg, "block_pattern", "") \
             and not getattr(cfg, "attn_pattern", "")
+
+    def deferred_rows(self, dtype=None):
+        """T of the deferred tail (``KVCache``): a sublane tile's rows where
+        K beside V fill whole lane tiles (``hd + vd`` a multiple of 128:
+        GPT-2's 128, Ouro's 256) — a narrower row would be padding in HBM,
+        and no copy takes part of a lane tile: such a cache has no tail and
+        keeps the block's write-back."""
+        return 0 if self.row_width % LANES \
+            else tail_rows(dtype or self.cfg.dtype)
+
+    def state(self, batch, dtype=None):
+        rows = self.deferred_rows(dtype)
+        if not rows:
+            return {}
+        return {"tail": ((self.layers, batch, self.cfg.kv_heads, rows,
+                          self.row_width), dtype or self.cfg.dtype)}
+
+    @staticmethod
+    def _group(cache):
+        """Of a contiguous cache with a tail, a slot: where the block of up
+        to 128 lanes that holds its current group — that of position
+        ``length - 1`` — starts (B,), the block's width, and ``(B, T,
+        width)`` bool: tail row r is the block's lane s and a live position.
+        (One-hot: a product with it moves rows onto lanes, or back, exactly,
+        and reads whole lane tiles where a slice of T lanes would be padded
+        to them; what it leaves out is zeroed first, since 0 x NaN is NaN
+        and nothing behind the live length is read.)"""
+        T, S = cache.tail.shape[3], cache.k.shape[4]
+        n = jnp.broadcast_to(jnp.minimum(cache.length, S),
+                             (cache.k.shape[1],))
+        first = jnp.maximum(n - 1, 0) // T * T
+        wide = min(LANES, S)
+        at = jnp.minimum(first // wide * wide, S - wide)
+        row = (first[:, None] + jnp.arange(T))[:, :, None]       # (B, T, 1)
+        lane = (at[:, None] + jnp.arange(wide))[:, None, :]      # (B, 1, wide)
+        return at, wide, (row == lane) & (row < n[:, None, None])
+
+    @staticmethod
+    def _blocks(plane, at, wide: int):
+        """``wide`` lanes of every slot's planes from the slot's ``at``."""
+        return jax.vmap(partial(lax.dynamic_slice_in_dim, slice_size=wide,
+                                axis=3), (1, 0), 1)(plane, at)
+
+    @staticmethod
+    def _moved(x, onto, spec, keep):
+        """``x``, zero where ``keep`` is not, times the one-hot ``onto``:
+        one non-zero term a sum."""
+        return jnp.einsum(spec, jnp.where(keep, x, 0), onto.astype(x.dtype),
+                          precision=lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+
+    def settled(self, cache):
+        """``cache`` with every live position in its planes: the current
+        group's live rows go from the tail onto their lanes. What reads a
+        slot's K/V anywhere but in the T == 1 step's kernel takes the cache
+        through here first (the tail stays true). A cache without a tail is
+        settled as it stands."""
+        if getattr(cache, "tail", None) is None:
+            return cache
+        at, wide, live = self._group(cache)
+        hd = cache.k.shape[3]
+
+        def put(plane, rows):
+            new = jnp.where(live.any(1)[None, :, None, None],
+                            self._moved(rows, live, "lbhrd,brs->lbhds",
+                                        live.any(2)[None, :, None, :, None]),
+                            self._blocks(plane, at, wide))
+            return jax.vmap(partial(lax.dynamic_update_slice_in_dim, axis=3),
+                            (1, 1, 0), 1)(plane, new, at)
+
+        return cache._replace(k=put(cache.k, cache.tail[..., :hd]),
+                              v=put(cache.v, cache.tail[..., hd:]))
+
+    def rewound(self, cache, length=None):
+        """A SETTLED ``cache`` standing at ``length`` (its own if None; a
+        right-padded final chunk's real tokens), as the T == 1 step takes it
+        over: the tail filled from the planes with the group of position
+        ``length - 1`` (its rows behind the length: zeros)."""
+        if length is not None:
+            cache = cache._replace(length=length)
+        if getattr(cache, "tail", None) is None:
+            return cache
+        at, wide, live = self._group(cache)
+        return cache._replace(tail=jnp.concatenate(
+            [self._moved(self._blocks(plane, at, wide), live,
+                         "lbhds,brs->lbhrd", live.any(1)[None, :, None, None])
+             for plane in (cache.k, cache.v)], -1))
 
     def buffers(self, batch, max_len, dtype=None, page_size=0, pages=0):
         if not page_size:
@@ -117,11 +224,14 @@ class Dense(Kind):
         # the cache is ONE buffer carried through the layer loop and
         # indexed by layer: as the loop's xs/ys every layer's slab is sliced
         # out and written back, and the whole cache copied around the loop
+        # (the deferred tail rides with the planes where the cache has one:
+        # the T == 1 step's kernel alone reads and writes it)
         def scan_fn(carry, layer_in):
-            x, ck, cv = carry
+            x, ck, cv, *tails = carry
             lp, layer = layer_in
             return _layer_step(model, x, lp, ck, cv, new_len, positions,
-                               flash_decode=fused, layer=layer), None
+                               flash_decode=fused, layer=layer,
+                               tail=tails[0] if tails else None), None
 
         def stack(carry, plane0=None):
             """Every layer once; ``plane0`` (traced): the cache plane of
@@ -137,18 +247,25 @@ class Dense(Kind):
             return carry
 
         passes = None
+        kv = (cache.k, cache.v) + (() if cache.tail is None
+                                   else (cache.tail,))
         if self.loops > 1:
             # the passes are a loop of the program too (one layer body):
             # each appends to and reads from its own n_layer planes
             def one_pass(x, kv, r):
-                x, ck, cv = stack((x, *kv), r * cfg.n_layer)
-                return x, (ck, cv)
+                x, *kv = stack((x, *kv), r * cfg.n_layer)
+                return x, tuple(kv)
 
-            x, (ck, cv), passes = model.loop_passes(
-                params, x, (cache.k, cache.v), one_pass)
+            x, kv, passes = model.loop_passes(params, x, kv, one_pass)
         else:
-            x, ck, cv = stack((x, cache.k, cache.v))
-        return x, KVCache(k=ck, v=cv, length=new_len), None, passes
+            x, *kv = stack((x, *kv))
+        new = cache._replace(**dict(zip(("k", "v", "tail"), kv)),
+                             length=new_len)
+        # a T > 1 forward wrote the planes: the tail follows them, for the
+        # step to take over (a T == 1 step off the kernels leaves the tail
+        # alone: it is the kernels', which this cache's steps do not run)
+        return (x, new if fused or x.shape[1] == 1 else self.rewound(new),
+                None, passes)
 
     def _loop_meta(self, tokens: int, head: bool = True) -> dict:
         """What a looped trunk's spans say beside their times: the passes,

@@ -181,17 +181,23 @@ def _dense_append(cache, new, layer, length):
 
 
 def _append_attend(q, ck, cv, k, v, layer, length, fused: bool, alibi=None,
-                   name: str = "decode_attention"):
+                   name: str = "decode_attention", tail=None):
     """The T new positions ``k`` / ``v`` into layer ``layer`` of the carried
     planes, and ``q`` over that layer: where the gate said so (``fused``)
     the decode kernel, which appends and attends in place, under ``name``;
-    else XLA's update and the layer's slab densely. Returns (o, ck, cv)."""
+    else XLA's update and the layer's slab densely. ``tail``: the deferred
+    tail of a kind that keeps one (``kinds/dense.py``), which the kernel
+    alone reads and writes: the dense path needs the planes settled and
+    leaves them so (the kind fills the tail again behind its layer loop).
+    Returns (o, ck, cv), and the tail behind them where one came."""
+    tails = () if tail is None else (tail,)
     if fused:
         return da.decode_attention(q, ck, cv, length, k=k, v=v, layer=layer,
-                                   alibi_slopes=alibi, name=name)
+                                   alibi_slopes=alibi, name=name, tail=tail)
     slab_k, ck = _dense_append(ck, k, layer, length)
     slab_v, cv = _dense_append(cv, v, layer, length)
-    return _cache_attend(q, slab_k, slab_v, length, alibi=alibi), ck, cv
+    return (_cache_attend(q, slab_k, slab_v, length, alibi=alibi), ck, cv,
+            *tails)
 
 
 def _tp_quant_eligible(model, p, T: int) -> int:
@@ -259,7 +265,8 @@ def _qkv_proj(model, y, p):
 
 @jax.named_scope("decode_layer")
 def _layer_step(model, x, p, cache_k, cache_v, length, positions,
-                flash_decode: bool = False, paged=None, layer=None):
+                flash_decode: bool = False, paged=None, layer=None,
+                tail=None):
     """One transformer layer over x: (B, T, d), reading/writing the cache.
 
     Returns (x_out, new_cache_k, new_cache_v) — plus the new scale pools
@@ -278,7 +285,8 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     carried ``(L, B, KV, hd, max_len)`` cache, ``layer`` (traced i32) this
     layer's index in it and ``flash_decode`` the gate's answer
     (:func:`_decode_kernel_ok`): the decode kernel appends and attends in
-    place, by layer index.
+    place, by layer index. ``tail``: the cache's deferred tail where its
+    kind keeps one (:func:`_append_attend`), returned last.
     """
     cfg = model.cfg
     B, T, d = x.shape
@@ -297,8 +305,9 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         alibi = alibi_slopes(h)
     scale_k = scale_v = None
     if paged is None:
-        o, cache_k, cache_v = _append_attend(
-            q, cache_k, cache_v, k, v, layer, length, flash_decode, alibi)
+        o, cache_k, cache_v, *tails = _append_attend(
+            q, cache_k, cache_v, k, v, layer, length, flash_decode, alibi,
+            tail=tail)
     else:
         page_table, scale_k, scale_v = paged
         cache_k, cache_v, scale_k, scale_v = paged_append(
@@ -340,7 +349,7 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
         x = model._residual(x, model._post_norm(out, p, "ln2"), p, 1)
     if paged is not None:
         return x, cache_k, cache_v, scale_k, scale_v
-    return x, cache_k, cache_v
+    return (x, cache_k, cache_v, *tails)
 
 
 def _out_ffn(model, x, o, p, banks, layer, sorted_rows: bool = True):

@@ -63,6 +63,32 @@ Layout notes:
   body only reads. An XLA-level update of one position lets the compiler
   pick a layout FOR THE UPDATE and convert the whole cache to it; inside
   an aliased kernel nothing can.
+- the DEFERRED TAIL (``tail=``; what ``inference/kinds/dense.py`` keeps
+  beside K and V): a block of 128 written back to append one position is
+  128 times the new bytes, a quarter to a third of all the kernel moved at
+  GPT-2's and Ouro's shapes. A column cannot be written, a ROW can: the
+  tail is ``(L, B, KV, T, hd + vd)``, T positions ON THE SUBLANES — T one
+  sublane tile of the dtype (``tail_rows``: 16 of bf16), K beside V on the
+  lanes (128 or 256 wide: no lane is padding) — and position ``p`` of a
+  slot stands on row ``p % T`` of the slot's tile until its group of T is
+  complete. A step fetches the tile behind the slot's first turn (82 KB at
+  GPT-2's shape; the program before starts it with the blocks it fetches
+  ahead), puts the new row on it and writes the tile back (a whole tile at
+  a tile-aligned offset), and turns the tile's rows into the columns of
+  their group in the last live block, in VMEM: the tile lands on rows
+  ``[(L - 1) % 128 // T * T, + T)`` of a zeroed ``(hb, 128, hd + vd)``
+  buffer, and an identity times that buffer in the kernel's own NT form
+  (one non-zero term a sum: exact) is ``(hb, hd + vd, 128)`` with the
+  group's columns in their lanes — no roll, no transpose op. That product
+  reads no block, so it runs in front of the last turn's wait, behind the
+  copies in flight. The products over the patched block are the ones
+  without a tail: same turns, masks and carry, bit-equal output. The block
+  goes back only when ``L % T == 0``: 2 T + 128 / T positions moved a
+  position appended (40 at T = 16) where it was 128. Between steps the
+  blocks lack the current group: whatever else reads the planes settles
+  the tail into them first (``Dense.settled``). With ``tail=None`` every
+  expression is the one it was (the other kinds' write-back is under 1%
+  of their step).
 - the GQA head group mapping is a reshape of q to ``(B, KV, group, hd)``:
   a KV head's query rows are real rows of one product, padded to the 8
   sublanes, and there is no repeated-KV materialization at all
@@ -124,7 +150,7 @@ def _with_column(old_ref, new_ref, b, r):
 
 def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
                    group: int, append: bool, window: int = 0,
-                   sink: bool = False):
+                   sink: bool = False, tailed: bool = False):
     """One program: a slot's ``hb`` KV heads (``q_ref`` (hb, rows, hd), the
     group's query rows padded to the 8 sublanes) over that slot's live
     blocks, copied out of the cache in HBM by the kernel itself, ``width``
@@ -157,13 +183,35 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
     on its own. ``sink``: the running max and sum start at ``(sink_h, 1)``
     in place of ``(-inf, 0)``: one more column of the softmax that carries
     no value. K and V may differ in width (the accumulator and the output
-    have V's)."""
+    have V's).
+
+    ``tailed`` (with ``append``): the slot's newest positions stand in its
+    TAIL TILE ``t_hbm[layer, slot]`` (hb, T, hd + vd), position ``p`` on row
+    ``p % T``, K beside V on the lanes, until their group of T is complete
+    (the module's layout notes). The tile comes in behind the slot's first
+    turn (``t_buf``, one of two: the program before fetches it ahead with
+    the blocks, and with it the step's new rows ``new_hbm[slot, g]``, a
+    head a sublane, into ``n_buf``: a copy of the kernel's own, so a slot
+    that is not running costs none); in the last turn each head's new row
+    goes onto row ``(L - 1) % T`` and the tile goes back, whole.
+    Its rows are then turned into columns: the tile lands on rows ``[(L -
+    1) % block // T * T, + T)`` of ``z_buf`` (hb, block, hd + vd), zero
+    everywhere else, and an identity times ``z_buf`` in the NT form (one
+    non-zero term a sum: exact) is ``(hb, hd + vd, block)`` with the
+    group's columns in their lanes and zeros beside them; those T lanes
+    replace the block's, in both buffers, and the products run as ever. The
+    rows behind the live length land on lanes the mask drops. The block
+    goes back to the cache only when ``L % T == 0``."""
     from jax.experimental.pallas import tpu as pltpu
 
     len_ref, layer_ref, *refs = refs
     slopes_ref = refs.pop(0) if alibi else None
     sink_ref = refs.pop(0) if sink else None
-    if append:
+    if tailed:
+        (q_ref, new_hbm, k_hbm, v_hbm, t_hbm, o_ref, k_out, v_out, t_out,
+         k_buf, v_buf, t_buf, n_buf, z_buf, c_buf, sem, ahead) = refs
+        T = t_buf.shape[2]
+    elif append:
         (q_ref, new_k, new_v, k_hbm, v_hbm, o_ref, k_out, v_out,
          k_buf, v_buf, sem, ahead) = refs
     else:
@@ -240,6 +288,61 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
             tile = part(c_buf, buf, i)
             tile[...] = _with_column(tile, new_ref, b, (L - 1) % block)
 
+    def tile_in(t, slot, heads):
+        """The copies of a program's tail tile into ``t_buf[t]`` and of its
+        new row into ``n_buf[t]`` (they count on one semaphore)."""
+        return (pltpu.make_async_copy(
+            t_hbm.at[layer_ref[0], slot, pl.ds(heads * hb, hb)],
+            t_buf.at[t], sem.at[3, t]),
+                pltpu.make_async_copy(
+            new_hbm.at[slot, heads], n_buf.at[t], sem.at[3, t]))
+
+    def tile_out(t):
+        """This program's tile back to the cache (to wait on one only the
+        semaphore and the size matter)."""
+        return pltpu.make_async_copy(
+            t_buf.at[t], t_out.at[layer_ref[0], b, pl.ds(g * hb, hb)],
+            sem.at[4, 0])
+
+    def tail_columns(t):
+        """The step's new row into the tile ``t_buf[t]``, the tile on its
+        way back, and its rows as columns in ``c_buf`` (hb, hd + vd,
+        block): the group's in their lanes, zeros beside them. Nothing here
+        reads a block: it runs in front of the last turn's wait, behind
+        that turn's copies."""
+        for copy in tile_in(t, b, g):
+            copy.wait()
+        row = jax.lax.broadcasted_iota(jnp.int32, t_buf.shape[1:], 1)
+        # (a head's row off the sublanes: through float32, exactly)
+        new = n_buf[t, :hb].astype(jnp.float32)[:, None, :].astype(
+            t_buf.dtype)
+        tile = jnp.where(row == (L - 1) % T, new, t_buf[t])
+        t_buf[t] = tile
+        tile_out(t).start()
+        off = pl.multiple_of((L - 1) % block // T * T, T)
+        z_buf[:, pl.ds(off, T), :] = tile
+        C = z_buf.shape[2]
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+        c_buf[...] = jax.lax.dot_general(                # (hb, C, block)
+            jnp.broadcast_to(eye.astype(z_buf.dtype), (hb, C, C)),
+            z_buf[...], (((2,), (2,)), ((0,), (0,))),
+            precision=(jax.lax.Precision.HIGHEST
+                       if z_buf.dtype == jnp.float32 else None),
+            preferred_element_type=jnp.float32).astype(c_buf.dtype)
+        z_buf[:, pl.ds(off, T), :] = jnp.zeros_like(tile)
+
+    def from_tail(buf, i):
+        """The group's columns out of ``c_buf`` onto their T lanes of the
+        turn's block ``i``, in both buffers."""
+        off = (L - 1) % block // T * T
+        hd = k_buf.shape[2]
+        for kv_buf, rows in ((k_buf, slice(0, hd)), (v_buf, slice(hd, None))):
+            blk = part(kv_buf, buf, i)
+            lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 2)
+            blk[...] = jnp.where((lane >= off) & (lane < off + T),
+                                 c_buf[:, rows, :], blk[...])
+
     def over_turn(buf, slot, heads, j, stop, act):
         """``act`` (a copy's start, or its wait) on the copies of every
         block ``j + i`` before ``stop`` of the turn that starts at ``j``
@@ -265,12 +368,27 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
         ahead[1] = 0
         if append:
             ahead[2] = 0
+        if tailed:
+            ahead[3] = 0
+            z_buf[...] = jnp.zeros(z_buf.shape, z_buf.dtype)
         if W > 1:
             v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
 
     first = ahead[0]
+    mine = ahead[3] if tailed else None      # which of t_buf is this slot's
 
-    if append:
+    if tailed:
+        # the program before left its tile (1), and its block where its
+        # group was complete (2), on their way back to the cache
+        @pl.when(ahead[2] >= 1)
+        def _():
+            tile_out(0).wait()
+
+        @pl.when(ahead[2] == 2)
+        def _():
+            for copy in writes(0, 0, 0):
+                copy.wait()
+    elif append:
         # the program before left its patched block on its way back to the
         # cache, out of the buffer this program's second turn goes to
         @pl.when(ahead[2] == 1)
@@ -281,6 +399,9 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
     @pl.when((nb > 0) & (ahead[1] == 0))
     def _():
         fetch(first, b, g, j0, end)
+        if tailed:
+            for copy in tile_in(mine, b, g):
+                copy.start()
 
     # the program after this one, and whether it has a block to fetch
     wraps = g == n_groups - 1
@@ -311,9 +432,27 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
         @pl.when((u + 1 == stop) & next_live)
         def _():
             fetch(1 - buf, b_next, g_next, j0_next, end_next)
+            if tailed:
+                for copy in tile_in(1 - mine, b_next, g_next):
+                    copy.start()
 
+        if tailed:
+            pl.when(u + 1 == stop)(partial(tail_columns, mine))
         over_turn(buf, b, g, j, end, lambda copy: copy.wait())
-        if append:
+        if tailed:
+            # the last live block holds the tail's group: its columns come
+            # from the tile in VMEM, the new row with them, and the block
+            # goes back once the group is complete
+            @pl.when(u + 1 == stop)
+            def _():
+                i = 0 if W == 1 else end - 1 - j
+                from_tail(buf, i)
+
+                @pl.when(L % T == 0)
+                def _():
+                    for copy in writes(buf, i, plus(j, i)):
+                        copy.start()
+        elif append:
             # the last live block holds position L - 1: patched in VMEM,
             # attended to from there, written back once
             @pl.when(u + 1 == stop)
@@ -354,7 +493,23 @@ def _decode_kernel(*refs, block: int, width: int, scale: float, alibi: bool,
         l0 = jnp.where(m0 > BIG_NEG, 1.0, 0.0)
     acc0 = jnp.zeros(o_ref.shape, jnp.float32)
     _, l, acc = jax.lax.fori_loop(j0, stop, body, (m0, l0, acc0))
-    if append:
+    if tailed:
+        # as below, of the tile and, where it went, the block
+        last = (b == n_slots - 1) & wraps
+        wrote = jnp.where(nb > 0, 1 + (L % T == 0).astype(jnp.int32), 0)
+
+        @pl.when(last & (wrote >= 1))
+        def _():
+            tile_out(0).wait()
+
+        @pl.when(last & (wrote == 2))
+        def _():
+            for copy in writes(0, 0, 0):
+                copy.wait()
+
+        ahead[2] = jnp.where(last, 0, wrote)
+        ahead[3] = 1 - mine
+    elif append:
         # the write runs behind the last turn's products, the next
         # program's first fetch (which is in the other buffer) and the turn
         # of the programs: the next one waits for it, the last one here
@@ -426,8 +581,15 @@ def _slots_on_lanes(x, dtype):
     return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % LANES)))
 
 
-def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
-                     alibi_slopes=None, block: int = LANES,
+def tail_rows(dtype) -> int:
+    """The positions a cache of ``dtype`` keeps in a slot's tail (T): the
+    rows of one sublane tile, 16 of bf16, 8 of float32: the least a copy
+    can write at a traced row offset."""
+    return 4 * SUBLANES // jnp.dtype(dtype).itemsize
+
+
+def decode_attention(q, ck, cv, length, *, k=None, v=None, tail=None,
+                     layer=None, alibi_slopes=None, block: int = LANES,
                      interpret: Optional[bool] = None, window: int = 0,
                      sink=None, name: str = "decode_attention"):
     """q: (B, 1, H, hd) current-token queries; ck/cv: the cache
@@ -448,6 +610,18 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     every other position keeps every bit. Without them the kernel only
     reads (a caller that has appended already: the paged view's slab).
 
+    ``tail`` ``(L, B, KV, T, hd + vd)`` (with ``k`` / ``v``; one layer's
+    beside a slab): the cache's deferred tail, a third aliased output. Where
+    the caller keeps one, position ``p`` of a slot stands on row ``p % T``
+    of the slot's tile, K beside V on the lanes, until its group of T is
+    complete: rows ``0 .. (length - 2) % T`` hold the group's earlier
+    positions when the call comes (the blocks hold every group before it;
+    what they hold of this one is not read). The step writes its row and
+    the tile, attends to the block with the group's columns taken from the
+    tile, and writes the block back only when ``length % T == 0``: the
+    result is bit for bit the one without a tail over a cache that holds
+    the same positions, at a third of the bytes moved to append.
+
     A slot's result depends on that slot's row and length alone: the block,
     the heads a program takes and the blocks a loop turn takes follow from
     ``(KV, hd, vd, max_len, dtype)``, never from ``B``.
@@ -462,15 +636,18 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     no value. ``name``: the ``pallas_call``'s, which a trace tells kernels
     apart by (a caller with another count of bytes a call gives its own).
 
-    Returns (B, 1, H, vd); with ``k`` / ``v`` also the two caches."""
+    Returns (B, 1, H, vd); with ``k`` / ``v`` also the two caches, and the
+    tail behind them where one came."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, hd = q.shape
     assert T == 1, "decode kernel is single-token; use flash_attention for prefill"
     append = k is not None
+    tailed = tail is not None
     slab = ck.ndim == 4         # a layer's slab: a cache of that one layer
     if slab:
         ck, cv, layer = ck[None], cv[None], 0
+        tail = tail[None] if tailed else None
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     KV, S, vd = ck.shape[2], ck.shape[4], cv.shape[3]
     blk = min(block, S)
@@ -479,6 +656,13 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     if append and blk != LANES:
         raise ValueError(f"appending takes blocks of {LANES} positions, "
                          f"not {blk}")
+    if tailed and (not append or window or LANES % tail.shape[3]
+                   or tail.shape[4] != hd + vd
+                   or not tail.dtype == ck.dtype == cv.dtype):
+        raise ValueError(
+            f"a tail {tail.shape} {tail.dtype} goes with the step's new "
+            f"K/V, rows that divide {LANES}, K beside V ({hd} + {vd}) in the "
+            "planes' one dtype, and no ring")
     if window and S < (-(-window // blk) + 1) * blk:
         raise ValueError(f"a ring of {S} positions does not hold a window "
                          f"of {window} and the block being written")
@@ -491,6 +675,7 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
     slopes = (jnp.asarray(alibi_slopes, jnp.float32),) if alibi else ()
     slopes += (jnp.asarray(sink, jnp.float32),) if sink is not None else ()
     news = (k, v) if append else ()
+    tails = (tail,) if tailed else ()
 
     axes = _shard_axes(ck, H)
     if axes is not None:
@@ -507,18 +692,24 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
 
         def per_shard(q, ck, cv, n, layer, *rest):
             k, v = rest[:2] if append else (None, None)
-            slopes = rest[len(news):]
-            return decode_attention(q, ck, cv, n, k=k, v=v, layer=layer[0],
+            tail = rest[2] if tailed else None
+            slopes = rest[len(news) + len(tails):]
+            return decode_attention(q, ck, cv, n, k=k, v=v, tail=tail,
+                                    layer=layer[0],
                                     block=block, interpret=interpret,
                                     alibi_slopes=slopes[0] if slopes else None,
                                     name=name)
 
+        # (the tail's slots and heads shard as the planes')
         return jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(rows, cache, cache, P(b_ax), P())
-            + (rows,) * len(news) + ((P(h_ax),) if alibi else ()),
-            out_specs=(rows, cache, cache) if append else rows,
-            check_vma=False)(q, ck, cv, lengths, layer, *news, *slopes)
+            + (rows,) * len(news) + (cache,) * len(tails)
+            + ((P(h_ax),) if alibi else ()),
+            out_specs=(rows,) + (cache,) * (2 + len(tails)) if append
+            else rows,
+            check_vma=False)(q, ck, cv, lengths, layer, *news, *tails,
+                             *slopes)
 
     hb = _heads_per_program(KV, hd, blk, ck.dtype)
     W = blocks_per_turn(KV, hd, vd, S, ck.dtype, blk)
@@ -538,39 +729,57 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, layer=None,
 
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     n_pre = 2 + len(slopes)
+    if tailed:
+        # the step's new K beside its V, a row a KV head as the tail has them
+        rows_t = tail.shape[3]
+        # (it stays in HBM, a program's heads on the sublanes, padded to
+        # whole tiles: a program copies its slot's rows with the tile)
+        pad = -hb % rows_t
+        news = (jnp.pad(
+            jnp.concatenate([k, v], -1).astype(ck.dtype).reshape(
+                B, KV // hb, hb, hd + vd), ((0, 0),) * 2 + ((0, pad), (0, 0))),)
+        new_specs = [in_hbm]
+    else:
+        news = tuple(_slots_on_lanes(x, ck.dtype) for x in news)
+        new_specs = [new_spec(hd), new_spec(vd)][:len(news)]
+    carried = (ck, cv) * append + tails       # the aliased outputs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pre,
         grid=(B, KV // hb),
-        in_specs=[rows_spec(hd)] + [new_spec(hd), new_spec(vd)][:len(news)]
-        + [in_hbm, in_hbm],
-        out_specs=[rows_spec(vd)] + [in_hbm] * len(news),
+        in_specs=[rows_spec(hd)] + new_specs
+        + [in_hbm] * (2 + len(tails)),
+        out_specs=[rows_spec(vd)] + [in_hbm] * len(carried),
         # two buffers of K and of V, a turn's blocks side by side on the
-        # lanes; a semaphore a buffer for the fetches (a turn's copies
-        # count on one) and one more pair for the write-back; ``ahead``
-        # (see the kernel)
+        # lanes; with a tail two tiles, two new rows, the block the rows
+        # land in and the columns they are turned into; a
+        # semaphore a buffer for the fetches (a turn's copies count on one)
+        # and one more pair for the write-back, a tile's two fetches and
+        # its write-back; ``ahead`` (see the kernel)
         scratch_shapes=[pltpu.VMEM((2, hb, hd, W * blk), ck.dtype),
-                        pltpu.VMEM((2, hb, vd, W * blk), cv.dtype),
-                        pltpu.SemaphoreType.DMA((2 + append, 2)),
-                        pltpu.SMEM((2 + append,), jnp.int32)],
+                        pltpu.VMEM((2, hb, vd, W * blk), cv.dtype)]
+        + ([pltpu.VMEM((2, hb, rows_t, hd + vd), ck.dtype),
+            pltpu.VMEM((2, hb + pad, hd + vd), ck.dtype),
+            pltpu.VMEM((hb, blk, hd + vd), ck.dtype),
+            pltpu.VMEM((hb, hd + vd, blk), ck.dtype)] if tailed else [])
+        + [pltpu.SemaphoreType.DMA((2 + append + 2 * tailed, 2)),
+           pltpu.SMEM((2 + append + tailed,), jnp.int32)],
     )
     out, *caches = pl.pallas_call(
         partial(_decode_kernel, block=blk, width=W, scale=scale, alibi=alibi,
                 group=group, append=append, window=window,
-                sink=sink is not None),
+                sink=sink is not None, tailed=tailed),
         name=name,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KV, rows, vd), q.dtype)]
-        + ([jax.ShapeDtypeStruct(c.shape, c.dtype) for c in (ck, cv)]
-           if append else []),
+        + [jax.ShapeDtypeStruct(c.shape, c.dtype) for c in carried],
         # the caches come back where they were
         input_output_aliases={n_pre + 1 + len(news) + i: 1 + i
-                              for i in range(len(news))},
+                              for i in range(len(carried))},
         # a program starts the next one's first copy: the grid runs in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(lengths, layer, *slopes, qs,
-      *(_slots_on_lanes(x, ck.dtype) for x in news), ck, cv)
+    )(lengths, layer, *slopes, qs, *news, ck, cv, *tails)
     out = out[:, :, :group].reshape(B, 1, H, vd)
     if not append:
         return out

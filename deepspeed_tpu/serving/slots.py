@@ -84,14 +84,16 @@ def insert_request(state: GenCarry, slot, pf: GenCarry,
     is what guarantees a retired request's stale KV is fully overwritten
     before the new occupant's first decode step."""
     kc = state.cache
-    # every buffer of the cache (K and V; the latents; a recurrent state
-    # or the window layers' rings beside K/V) has the slot second: a
-    # successor never reads its predecessor's ring
+    # every buffer of the cache (K and V and the tail of their newest
+    # positions; the latents; a recurrent state or the window layers'
+    # rings beside K/V) has the slot second: a successor never reads its
+    # predecessor's ring (None: a buffer this cache does not keep)
     buffers = {
         name: lax.dynamic_update_slice(
             buf, getattr(pf.cache, name).astype(buf.dtype),
             (0, slot) + (0,) * (buf.ndim - 2))
-        for name, buf in kc._asdict().items() if name != "length"}
+        for name, buf in kc._asdict().items()
+        if name != "length" and buf is not None}
     tok, rng, done, length, left = seat_row(
         state, slot, tok=pf.tok, rng=pf.rng, done=pf.done,
         length=pf.cache.length, left=left)

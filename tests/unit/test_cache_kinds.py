@@ -28,11 +28,15 @@ from deepspeed_tpu.serving.slots import init_slots
 
 F32 = jnp.float32
 PAGE, PAGES = 16, 24
-# every kind of the tuple with its tiny preset (the plain K/V kind three
+# every kind of the tuple with its tiny preset (the plain K/V kind four
 # times: a looped trunk is that kind with a plane a pass, a page pool its
-# other layout)
+# other layout, and where K beside V fill whole lane tiles — one head of 64
+# here — it keeps the deferred tail, which the narrower heads' caches leave
+# None: a field a cache does not keep is no buffer of it)
 CASES = {
     "dense": (Dense, lambda: tiny_test(max_seq=256, dtype=F32)),
+    "dense-tail": (Dense, lambda: tiny_test(max_seq=256, dtype=F32,
+                                            n_head=1)),
     "looped": (Dense, lambda: ouro("tiny", dtype=F32)),
     "paged": (Dense, lambda: tiny_test(max_seq=256, dtype=F32)),
     "latent": (Latent, lambda: deepseek_v3("tiny", dtype=F32)),
@@ -70,7 +74,8 @@ def test_a_cache_has_the_buffers_its_kind_declares(name):
             lambda: kind.empty(SLOTS, MAX_LEN, F32, (SLOTS,)))
         declared = {**kind.buffers(SLOTS, MAX_LEN, F32),
                     **kind.state(SLOTS, F32)}
-        assert set(declared) | {"length"} == set(cache._fields)
+        assert set(declared) | {"length"} == _kept(cache)
+        assert ("tail" in declared) == (name in ("dense-tail", "cca"))
         assert tuple(kind.buffers(SLOTS, MAX_LEN, F32)) == kind.planes
         for make in (lambda: init_cache(cfg, SLOTS, MAX_LEN, F32, (SLOTS,)),
                      lambda: init_slots(cfg, SLOTS, MAX_LEN, F32).cache):
@@ -80,6 +85,10 @@ def test_a_cache_has_the_buffers_its_kind_declares(name):
         buf = getattr(cache, field)
         assert (buf.shape, buf.dtype) == (shape, jnp.dtype(dtype)), field
     assert cache.length.shape == (SLOTS,)
+
+
+def _kept(cache) -> set:
+    return {f for f, b in cache._asdict().items() if b is not None}
 
 
 # ------------------------------------------------ (b) what the bytes count
@@ -94,7 +103,7 @@ def test_the_byte_figures_are_the_arrays_own(name, dtype):
     cache = jax.eval_shape(lambda: init_cache(cfg, SLOTS, MAX_LEN, dtype))
     planes = [getattr(cache, f) for f in kind.planes]
     state = [getattr(cache, f) for f in kind.state(SLOTS, dtype)]
-    assert len(planes) + len(state) + 1 == len(cache)
+    assert len(planes) + len(state) + 1 == len(_kept(cache))
     assert cache_bytes_per_token(cfg, dtype) * SLOTS * MAX_LEN \
         == _nbytes(planes)
     assert state_bytes_per_slot(cfg, dtype) * SLOTS == _nbytes(state)
@@ -110,7 +119,8 @@ def test_the_capacity_ledger_sums_the_kind_s_buffers(name):
     cache = jax.eval_shape(
         lambda: init_cache(cfg, SLOTS, MAX_LEN, jnp.bfloat16))
     kv = kv_cache_bytes(cfg, SLOTS, MAX_LEN, jnp.bfloat16)
-    held = _nbytes(b for f, b in cache._asdict().items() if f != "length")
+    held = _nbytes(b for f, b in cache._asdict().items()
+                   if f != "length" and b is not None)
     assert kv["total_bytes"] == held == SLOTS * kv["per_slot_bytes"]
     assert kv["state_bytes"] == SLOTS * kind.state_bytes_per_slot(jnp.bfloat16)
     assert kv["per_token_bytes"] == kind.bytes_per_token(jnp.bfloat16)
@@ -198,7 +208,7 @@ def test_the_trainer_refuses_the_kinds_that_say_why(models, name):
     conf = {"train_batch_size": 8,
             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}}
     why = why_not_trained(kind.cfg)
-    assert (why is None) == (name in ("dense", "latent"))
+    assert (why is None) == (name in ("dense", "dense-tail", "latent"))
     if why is None:
         if name == "dense":
             # (a trunk of several segments does not pass the trainer's

@@ -318,10 +318,47 @@ def test_decode_attention_appending_with_wide_turns(one_chip, B, H, KV, hd,
     assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
 
 
-def test_decode_attention_appending_under_a_model_axis(topo):
+@pytest.mark.parametrize("B,H,KV,hd,L,S", [
+    pytest.param(48, 20, 20, 64, 36, 1024, id="gpt2-774m-cells"),
+    pytest.param(12, 16, 16, 128, 192, 384, id="ouro-2.6b-cell"),
+    pytest.param(16, 32, 8, 128, 4, 2048, id="gqa-two-blocks-a-turn"),
+])
+def test_decode_attention_with_a_tail(one_chip, B, H, KV, hd, L, S):
+    """The call the ``Dense`` kind's step makes (``tail=``) at the serving
+    cells' shapes: the slot's tile of 16 rows copied in and out at a traced
+    slot and head offset, its rows landed at a traced 16-row offset of the
+    buffer the identity product turns, the block written back under a
+    condition — one Mosaic call under the one name, the planes and the tail
+    donated and handed back in place."""
+    from deepspeed_tpu.ops.decode_attention import tail_rows
+
+    T = tail_rows(jnp.bfloat16)
+    assert T == 16
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((B, 1, H, hd), jnp.bfloat16), ((L, B, KV, hd, S), jnp.bfloat16),
+        ((L, B, KV, hd, S), jnp.bfloat16), ((B,), jnp.int32),
+        ((), jnp.int32), ((B, 1, KV, hd), jnp.bfloat16),
+        ((B, 1, KV, hd), jnp.bfloat16),
+        ((L, B, KV, T, 2 * hd), jnp.bfloat16))]
+    compiled = jax.jit(
+        lambda q, ck, cv, n, layer, k, v, tail: decode_attention(
+            q, ck, cv, n, k=k, v=v, tail=tail, layer=layer, interpret=False),
+        donate_argnums=(1, 2, 7)).lower(*args).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "/decode_attention/pallas_call" in calls[0], \
+        calls
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * L * B * KV * hd * (S + T) * 2
+    assert mem.temp_size_in_bytes < 2 ** 20, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("tailed", [False, True], ids=["block", "tail"])
+def test_decode_attention_appending_under_a_model_axis(topo, tailed):
     """``model`` = 4 over the described host's four chips: the kernel runs
     per shard of 5 KV heads inside a ``shard_map`` with the caches in its
-    ``out_specs`` (GSPMD cannot partition a Mosaic kernel)."""
+    ``out_specs`` (GSPMD cannot partition a Mosaic kernel); the deferred
+    tail's heads shard as the planes'."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
@@ -341,14 +378,21 @@ def test_decode_attention_appending_under_a_model_axis(topo):
             arg((B,), jnp.int32, P()), arg((), jnp.int32, P()),
             arg((B, 1, KV, hd), jnp.bfloat16, rows),
             arg((B, 1, KV, hd), jnp.bfloat16, rows))
+    fn, donated = _appending, (1, 2)
+    if tailed:
+        args += (arg((L, B, KV, 16, 2 * hd), jnp.bfloat16, cache),)
+        fn, donated = (lambda q, ck, cv, n, layer, k, v, tail:
+                       decode_attention(q, ck, cv, n, k=k, v=v, tail=tail,
+                                        layer=layer, interpret=False)), \
+            (1, 2, 7)
     with mesh:
-        compiled = jax.jit(_appending, donate_argnums=(1, 2)).lower(
+        compiled = jax.jit(fn, donate_argnums=donated).lower(
             *args).compile()
     text = compiled.as_text()
     assert "decode_attention" in text and "tpu_custom_call" in text
     # a shard's caches: 5 of 20 heads, donated and handed back in place
     assert compiled.memory_analysis().alias_size_in_bytes \
-        >= 2 * L * B * (KV // 4) * hd * SEQ * 2
+        >= 2 * L * B * (KV // 4) * hd * (SEQ + 16 * tailed) * 2
 
 
 @pytest.mark.parametrize("width", WIDTHS)
@@ -447,7 +491,9 @@ def test_slot_step_keeps_the_cache_in_place(one_chip, monkeypatch, name,
     # 0.009 GiB at 48 slots of GPT-2 774M: the kernels' operands are the
     # cache itself, a query row and a new position a slot
     assert mem.temp_size_in_bytes < 0.02 * 2 ** 30, mem.temp_size_in_bytes
-    assert mem.alias_size_in_bytes >= cache_bytes      # donated, in place
+    # donated, in place: the planes, and the tail of 16 positions beside
+    # them that the kernel writes in rows
+    assert mem.alias_size_in_bytes >= cache_bytes * (S + 16) // S
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert any("decode_attention" in ln for ln in calls)

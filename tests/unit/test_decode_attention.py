@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.inference.decode import _cache_attend
-from deepspeed_tpu.ops.decode_attention import decode_attention
+from deepspeed_tpu.ops.decode_attention import decode_attention, tail_rows
 
 
 def _setup(B=2, S=128, H=4, KV=2, hd=32, length=77, seed=0):
@@ -138,6 +138,7 @@ FAMILIES = {
     "mha-hd64": lambda: _tiny(n_head=2, d_model=128),
     "gqa-hd64": lambda: _tiny(n_head=4, n_kv_head=2, d_model=256),
     "mha-hd128": lambda: _tiny(n_head=2, d_model=256),
+    "mha-hd16": lambda: _tiny(n_head=4, d_model=64),
     "alibi-hd64": lambda: _tiny(n_head=2, d_model=128, pos_embedding="alibi"),
     "rope-gqa-hd64": lambda: _tiny(n_head=4, n_kv_head=2, d_model=256,
                                    pos_embedding="rope"),
@@ -166,8 +167,11 @@ def _family(name):
 
 def _slot_cache(cfg, lens, seed=0, stale=0.0):
     """A slot cache whose rows hold ``lens`` live positions of noise and
-    ``stale`` behind them (what a retired occupant leaves)."""
+    ``stale`` behind them (what a retired occupant leaves), as the T == 1
+    step takes it over: the kind's tail, where it keeps one, filled from
+    the planes."""
     from deepspeed_tpu.inference.decode import KVCache, cache_layout
+    from deepspeed_tpu.inference.kinds import kind_of
 
     lens = np.asarray(lens, np.int32)
     shape, _ = cache_layout(cfg, len(lens), S)
@@ -175,8 +179,11 @@ def _slot_cache(cfg, lens, seed=0, stale=0.0):
     live = np.arange(S) < lens.reshape(-1, 1, 1, 1)           # (B,1,1,S)
     k, v = (np.where(live, rng.standard_normal(shape), sign * stale)
             for sign in (1, -1))
-    return KVCache(k=jnp.asarray(k, jnp.float32),
-                   v=jnp.asarray(v, jnp.float32), length=jnp.asarray(lens))
+    tail = kind_of(cfg).state(len(lens), jnp.float32).get("tail")
+    return kind_of(cfg).rewound(KVCache(
+        k=jnp.asarray(k, jnp.float32), v=jnp.asarray(v, jnp.float32),
+        length=jnp.asarray(lens),
+        tail=tail and jnp.zeros(*tail)))
 
 
 @pytest.mark.parametrize("lengths", ["ragged", "scalar-127", "scalar-128"])
@@ -190,6 +197,7 @@ def test_step_kernels_match_dense_path(family, lengths):
     previous occupant left past the live length is never read (1e9 there
     would swamp any sum it entered)."""
     from deepspeed_tpu.inference.decode import forward_with_cache
+    from deepspeed_tpu.inference.kinds import kind_of
 
     cfg, model, params = _family(family)
     if lengths == "ragged":
@@ -206,6 +214,8 @@ def test_step_kernels_match_dense_path(family, lengths):
                   static_argnames=("flash_decode",))
     want, dense = fwd(params, tok, cache, flash_decode=False)
     got, fused = fwd(params, tok, cache, flash_decode=True)
+    # (where the kind defers the block's write, the planes once settled)
+    fused = kind_of(cfg).settled(fused)
     run = before > 0        # a row that is not running: its logits are nobody's
     np.testing.assert_allclose(np.asarray(got)[run], np.asarray(want)[run],
                                rtol=2e-4, atol=2e-4)
@@ -579,30 +589,61 @@ def test_decode_step_span_says_fetched_over_live():
     assert [e.meta["idle_fetched"] for e in steps] == [0] * len(steps)
 
 
-def test_decode_step_span_says_append_moved_over_new():
-    """``append_moved_over_new`` on the ``decode_step`` span: the block of
-    128 positions the kernel writes back for every slot whose length is
-    over 0 (the running ones alone: a row that is not running stands at 0)
-    over the one new position of each running request."""
+def _moved_over_new(lens, ran, T):
+    """What a step moves to append over the positions it appends, from
+    the lengths it leaves: a block of 128 a live slot where the cache has
+    no tail; with a tail of T rows the slot's tile in and out, and the block
+    for the slots whose group of T the step completed."""
+    live = lens > 0
+    if not T:
+        return 128 * live.sum() / ran
+    return (2 * T * live.sum() + 128 * (live & (lens % T == 0)).sum()) / ran
+
+
+@pytest.mark.parametrize("family,T", [("mha-hd64", 8), ("mha-hd128", 8),
+                                      ("mha-hd16", 0)])
+def test_decode_step_span_says_append_moved_over_new(family, T):
+    """``append_moved_over_new`` on the ``decode_step`` span: over the one
+    new position of each running request, what the kernel moves to append
+    it. Where the cache keeps a deferred tail (K beside V fill whole lane
+    tiles: heads of 64 and of 128; T = 8 rows of float32) a tile of T
+    positions in and out for every slot whose length is over 0 (the
+    running ones alone: a row that is not running stands at 0) and the
+    block of 128 for those whose group the step completed; with heads of
+    16 there is no tail and the block goes back every step: 128."""
     import deepspeed_tpu as ds
 
-    cfg, model, params = _family("mha-hd64")
+    cfg, model, params = _family(family)
     eng = ds.init_inference(model, params, {"dtype": "float32",
                                             "eos_token_id": 7,
                                             "flash_decode": True})
     srv = ds.ServingEngine(eng, {"slots": 3, "max_len": S, "greedy": True,
                                  "prefill_chunk": 64, "spans": True})
+    assert srv.kind.deferred_rows(jnp.float32) == T
+    assert (srv._state.cache.tail is not None) == bool(T)
+    seen, counts = [], srv._attn_counts
+    srv._attn_counts = lambda fl: seen.append(fl) or counts(fl)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(8, 256, (P,)).astype(np.int32)
                for P in (120, 9, 60, 130)]
     srv.serve_batch(prompts, [12, 3, 4, 5], [1, 2, 3, 4])
-    steps = [e for e in srv.spans.events() if e.kind == "decode_step"]
-    assert len(steps) > 0
+    steps = [e for e in srv.spans.events() if e.kind == "decode_step"
+             and e.meta["slots"]]
+    seen = [fl for fl in seen if fl.rows]
+    assert len(steps) == len(seen) > 0
     running = {e.meta["slots"] for e in steps}
     assert running >= {1, 2, 3}
-    # whatever runs: a block for each running request and for no other slot
-    for e in steps:
-        assert e.meta["append_moved_over_new"] == 128
+    # whatever runs: a tile (or a block) for each running request and for
+    # no other slot, a block more where a group of T ended
+    for e, fl in zip(steps, seen):
+        assert e.meta["append_moved_over_new"] == pytest.approx(
+            _moved_over_new(fl.lens, len(fl.rows), T))
+    moved = {e.meta["append_moved_over_new"] for e in steps}
+    if T:
+        # between a tile in and out, and that with a block a slot
+        assert min(moved) == 2 * T and 2 * T < max(moved) <= 2 * T + 128
+    else:
+        assert moved == {128.0}
 
 
 # ------------------------------------------- several live blocks a loop turn
@@ -845,3 +886,464 @@ def test_decode_step_span_says_blocks_per_turn():
     # one or two blocks a slot, a turn each: 3/2 with one slot of each
     assert set(ratios) <= {1.0, 4 / 3, 1.5, 5 / 3, 2.0}
     assert 2.0 in ratios and min(ratios) == 1.0
+
+
+# ------------------------------------------------------- the deferred tail
+# A dense cache's newest positions stand in a tail of T rows a slot and KV
+# head, K beside V on the lanes (PR 49): the step's kernel writes the new
+# position's ROW and the slot's tile, takes the current group's columns
+# from the tile, and writes the block of 128 back only where the step
+# completed the group. The kernel without a tail, on a cache that holds the
+# same positions in its blocks, is the oracle: bit for bit.
+TAILED = {                                    # B, H, KV, hd, dtype
+    "mha-hd64": (9, 4, 4, 64, jnp.float32),
+    "mha-hd64-bf16": (9, 4, 4, 64, jnp.bfloat16),
+    "gqa-group4-hd64-w3": (9, 8, 2, 64, jnp.float32),
+    "gqa-group2-hd128": (9, 4, 2, 128, jnp.bfloat16),
+    "mha-hd128": (10, 2, 2, 128, jnp.float32),
+}
+
+
+def _tail_lengths(B, T):
+    """Lengths AFTER the append: 0, 1, T - 1, T, T + 1, 127, 128, 129 and
+    max_len in one batch (then a few in the middle of groups)."""
+    return ([0, 1, T - 1, T, T + 1, 127, 128, 129, S3] + [300, 77])[:B]
+
+
+def _group_start(n, T):
+    return max(int(n) - 1, 0) // T * T
+
+
+def _np_tail(ck, cv, before, T):
+    """The tail a cache at lengths ``before`` stands with, from planes that
+    hold every position: the group of position ``before - 1`` a slot."""
+    ck, cv = np.asarray(ck), np.asarray(cv)
+    L, B, KV, hd, _ = ck.shape
+    tail = np.zeros((L, B, KV, T, hd + cv.shape[3]), ck.dtype)
+    for b in range(B):
+        g = _group_start(before[b], T)
+        tail[:, b, :, :, :hd] = ck[:, b, :, :, g:g + T].swapaxes(-1, -2)
+        tail[:, b, :, :, hd:] = cv[:, b, :, :, g:g + T].swapaxes(-1, -2)
+    return tail
+
+
+def _np_settled(ck, cv, tail, n, T, layer):
+    """Layer ``layer``'s planes with the current group's live rows taken
+    from the tail (what ``Dense.settled`` does, by hand)."""
+    ck, cv, tail = (np.array(a) for a in (ck, cv, tail))
+    hd = ck.shape[3]
+    for b, m in enumerate(np.minimum(np.asarray(n), ck.shape[-1])):
+        g = _group_start(m, T)
+        for r in range(m - g):
+            ck[layer, b, :, :, g + r] = tail[layer, b, :, r, :hd]
+            cv[layer, b, :, :, g + r] = tail[layer, b, :, r, hd:]
+    return ck, cv
+
+
+def _tail_case(shape, before=None, seed=0, layers=2, B=None):
+    """(q, planes that hold everything, the same planes with what the
+    blocks hold of each slot's current group overwritten with noise — the
+    tail is the truth there —, the tail, the lengths before the step)."""
+    slots, H, KV, hd, dtype = TAILED[shape]
+    B = B or slots
+    T = tail_rows(dtype)
+    if before is None:
+        before = [max(n - 1, 0) for n in _tail_lengths(B, T)]
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), dtype)
+    ck, cv = (jnp.asarray(rng.standard_normal((layers, B, KV, hd, S3)), dtype)
+              for _ in range(2))
+    tail = _np_tail(ck, cv, before, T)
+    holes = [np.array(ck), np.array(cv)]
+    for b, m in enumerate(before):
+        if m % T:          # an incomplete group: the blocks may hold anything
+            for plane in holes:
+                plane[:, b, :, :, _group_start(m, T):m] = 99.0
+    return (q, (ck, cv), tuple(jnp.asarray(p) for p in holes),
+            jnp.asarray(tail), np.asarray(before), T)
+
+
+def _step_lengths(before):
+    return jnp.asarray(np.where(before > 0, np.minimum(before + 1, S3), 0),
+                       jnp.int32)
+
+
+def _raw(x):
+    x = np.asarray(x)
+    return x.view(np.uint16 if x.dtype.itemsize == 2 else np.uint32)
+
+
+@pytest.mark.parametrize("shape", list(TAILED))
+def test_tailed_step_is_bit_equal_to_the_step_without_a_tail(shape):
+    """Lengths 0, 1, T - 1, T, T + 1, 127, 128, 129 and max_len in one
+    batch, MHA and GQA, heads of 64 and 128, one and three blocks a turn:
+    the output bit for bit that of the kernel without a tail over planes
+    that hold every position; the planes, once the tail's live rows are
+    settled into them, bit for bit its planes; and nothing written but the
+    slot's own tile (its one new row) and, where the step completed a
+    group of T, the group's block."""
+    B, H, KV, hd, dtype = TAILED[shape]
+    q, full, holes, tail, before, T = _tail_case(shape)
+    k, v = _new(B, KV, hd, dtype)
+    n = _step_lengths(before)
+    want_o, want_k, want_v = decode_attention(
+        q, *full, n, k=k, v=v, layer=1, interpret=True)
+    got_o, got_k, got_v, got_t = decode_attention(
+        q, *holes, n, k=k, v=v, tail=tail, layer=1, interpret=True)
+    np.testing.assert_array_equal(_raw(got_o), _raw(want_o))
+    # against the dense path too, a row at a time
+    ref = _dense_rows(q, want_k[1], want_v[1], n)
+    live = np.asarray(n) > 0
+    np.testing.assert_allclose(
+        _bits(got_o)[live], _bits(ref)[live],
+        **(dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16
+           else dict(rtol=2e-5, atol=2e-5)))
+    sk, sv = _np_settled(got_k, got_v, got_t, n, T, 1)
+    # (the noise in the blocks lies inside the groups the tail settles)
+    np.testing.assert_array_equal(_raw(sk[1]), _raw(want_k[1]))
+    np.testing.assert_array_equal(_raw(sv[1]), _raw(want_v[1]))
+    n = np.asarray(n)
+    for got, old in ((got_k, holes[0]), (got_v, holes[1])):
+        changed = (_raw(got) != _raw(old)).any(axis=(2, 3))     # (L, B, S)
+        assert not changed[0].any(), "another layer's planes moved"
+        for b in range(B):
+            at, = np.nonzero(changed[1, b])
+            if n[b] == 0 or n[b] % T:
+                assert not len(at), (b, n[b], at)     # the block stayed
+            else:           # the group's columns, in the block that went
+                assert set(at) <= set(range(n[b] - T, n[b])), (b, n[b], at)
+    moved = (_raw(got_t) != _raw(tail)).any(axis=(2, 4))        # (L, B, T)
+    assert not moved[0].any(), "another layer's tail moved"
+    for b in range(B):
+        rows, = np.nonzero(moved[1, b])
+        assert set(rows) <= ({(n[b] - 1) % T} if n[b] else set()), (b, rows)
+        if n[b]:
+            hd_ = k.shape[-1]
+            np.testing.assert_array_equal(
+                _raw(got_t[1, b, :, (n[b] - 1) % T]),
+                _raw(jnp.concatenate([k[b, 0], v[b, 0]], -1).astype(dtype)))
+
+
+@pytest.mark.parametrize("slot", [1, 3, 4, 8])
+@pytest.mark.parametrize("shape", ["mha-hd64-bf16", "gqa-group4-hd64-w3"])
+def test_a_tailed_slot_is_bit_equal_whatever_its_neighbours(shape, slot):
+    """A slot's output, tile and planes out of a batch of nine equal the
+    same slot stepped alone: the tile a program takes, and which of the two
+    tile buffers, follow the grid; the bits do not."""
+    B, H, KV, hd, dtype = TAILED[shape]
+    q, _, holes, tail, before, T = _tail_case(shape, seed=slot)
+    k, v = _new(B, KV, hd, dtype)
+    n = _step_lengths(before)
+    many = decode_attention(q, *holes, n, k=k, v=v, tail=tail, layer=1,
+                            interpret=True)
+    one = slice(slot, slot + 1)
+    alone = decode_attention(
+        q[one], holes[0][:, one], holes[1][:, one], n[one], k=k[one],
+        v=v[one], tail=tail[:, one], layer=1, interpret=True)
+    np.testing.assert_array_equal(_raw(many[0][one]), _raw(alone[0]))
+    for got, want in zip(many[1:], alone[1:]):
+        np.testing.assert_array_equal(_raw(got[:, one]), _raw(want))
+
+
+@pytest.mark.parametrize("start", [0, 5, 127 - 16])
+@pytest.mark.parametrize("shape", ["mha-hd64", "gqa-group2-hd128"])
+def test_tailed_steps_in_a_row_across_a_groups_end(shape, start):
+    """T + 3 steps in a row from the middle of a group (``start`` rows of
+    it live; the last case crosses a block's end too): every step's output
+    and the settled planes bit-equal to the kernel's without a tail, the
+    block written exactly once, by the step that completed the group."""
+    B, (_, H, KV, hd, dtype) = 3, TAILED[shape]
+    T = tail_rows(dtype)
+    before = np.asarray([T + start, 2 * T + start + 1, 0])
+    q, full, holes, tail, before, _ = _tail_case(shape, before=before, B=B)
+    plain = jax.jit(partial(decode_attention, layer=1, interpret=True))
+    a, b = full, (*holes, tail)
+    n = jnp.asarray(before, jnp.int32)
+    rng = np.random.default_rng(3)
+    writes = np.zeros(B, int)
+    for _ in range(T + 3):
+        q, k, v = (jnp.asarray(rng.standard_normal(s), dtype) for s in (
+            (B, 1, H, hd), (B, 1, KV, hd), (B, 1, KV, hd)))
+        n = jnp.where(n > 0, n + 1, 0)
+        o1, *a = plain(q, *a, n, k=k, v=v)
+        was = b[0]
+        o2, *b = plain(q, b[0], b[1], n, k=k, v=v, tail=b[2])
+        np.testing.assert_array_equal(_raw(o1), _raw(o2))
+        wrote = (_raw(b[0]) != _raw(was)).any(axis=(0, 2, 3, 4))
+        np.testing.assert_array_equal(wrote, (np.asarray(n) > 0)
+                                      & (np.asarray(n) % T == 0))
+        writes += wrote
+        sk, sv = _np_settled(b[0], b[1], b[2], n, T, 1)
+        np.testing.assert_array_equal(_raw(sk[1]), _raw(a[0][1]))
+        np.testing.assert_array_equal(_raw(sv[1]), _raw(a[1][1]))
+    # (the groups that ended in the lengths the steps passed)
+    np.testing.assert_array_equal(
+        writes, np.where(before > 0, (before + T + 3) // T - before // T, 0))
+
+
+def test_tail_rows_are_one_sublane_tile():
+    assert [tail_rows(d) for d in (jnp.float32, jnp.bfloat16)] \
+        == [8, 16]
+
+
+@pytest.mark.parametrize("bad", ["no new K/V", "a ring", "rows of 24",
+                                 "another width", "another dtype"])
+def test_a_tail_the_kernel_cannot_take_is_refused(bad):
+    q, (ck, cv), _, tail, before, T = _tail_case("mha-hd64")
+    k, v = _new(9, 4, 64)
+    kw = dict(k=k, v=v, tail=tail, layer=1, interpret=True)
+    if bad == "no new K/V":
+        kw.update(k=None, v=None)
+    elif bad == "a ring":
+        kw.update(window=128)
+    elif bad == "rows of 24":
+        kw.update(tail=jnp.zeros(tail.shape[:3] + (24, 128), tail.dtype))
+    elif bad == "another width":
+        kw.update(tail=tail[..., :64])
+    else:
+        kw.update(tail=tail.astype(jnp.bfloat16))
+    with pytest.raises(ValueError, match="a tail"):
+        decode_attention(q, ck, cv, _step_lengths(before), **kw)
+
+
+def _tail_state(family, lens, seed=0):
+    """A slot state of ``family`` whose rows hold ``lens`` positions."""
+    from deepspeed_tpu.inference.decode import GenCarry
+
+    cfg, model, params = _family(family)
+    B = len(lens)
+    return cfg, model, params, GenCarry(
+        tok=jnp.asarray(np.random.default_rng(seed).integers(8, 256, (B,)),
+                        jnp.int32),
+        cache=_slot_cache(cfg, lens, seed=seed),
+        rng=jnp.zeros((B, 2), jnp.uint32), done=jnp.asarray(lens) == 0,
+        left=jnp.full((B,), 1000, jnp.int32))
+
+
+def _greedy_step(model, flash):
+    from deepspeed_tpu.inference.decode import decode_step
+    from deepspeed_tpu.inference.sampling import sample_logits
+
+    return jax.jit(partial(
+        decode_step, model, flash_decode=flash,
+        sampler=partial(sample_logits, greedy=True, temperature=1.0,
+                        top_k=0, top_p=1.0)))
+
+
+@pytest.mark.parametrize("family", ["mha-hd64", "gqa-hd64", "mha-hd128",
+                                    "mha-hd16"])
+def test_kind_settles_and_refills_its_tail(family):
+    """The kind's two helpers around the step (``Dense.settled``,
+    ``Dense.rewound``): T + 2 steps on the kernels leave the planes behind
+    the tail by up to T - 1 positions; settled, they hold what the dense
+    path's steps leave (the same tokens chosen on the way), and a tail
+    filled again from the settled planes is the tail the steps left, row for
+    live row. Heads of 16: no tail, and both helpers hand the cache back."""
+    from deepspeed_tpu.inference.kinds import kind_of
+
+    lens = [5, 0, 127, 200]
+    cfg, model, params, carry = _tail_state(family, lens)
+    kind = kind_of(cfg)
+    T = kind.deferred_rows(jnp.float32)
+    assert T == (0 if family == "mha-hd16" else 8)
+    assert (carry.cache.tail is None) == (T == 0)
+    fused, dense = carry, carry
+    for _ in range((T or 8) + 2):
+        fused = _greedy_step(model, True)(params, fused)
+        dense = _greedy_step(model, False)(params, dense)
+        # (the row that is not running: its token is nobody's)
+        np.testing.assert_array_equal(np.asarray(fused.tok)[[0, 2, 3]],
+                                      np.asarray(dense.tok)[[0, 2, 3]])
+    n = np.asarray(fused.cache.length)
+    np.testing.assert_array_equal(n, [5 + T + 2 if T else 15, 0,
+                                      127 + (T or 8) + 2, 200 + (T or 8) + 2])
+    settled = kind.settled(fused.cache)
+    live = np.arange(S) < n.reshape(-1, 1, 1, 1)
+    if T:
+        behind = np.asarray(fused.cache.k) != np.asarray(settled.k)
+        assert behind.any(), "the planes were not behind their tail"
+        assert not (behind & ~live).any()
+    else:
+        assert settled is fused.cache and kind.rewound(settled) is settled
+    for got, want in ((settled.k, dense.cache.k), (settled.v, dense.cache.v)):
+        np.testing.assert_allclose(np.where(live, got, 0),
+                                   np.where(live, want, 0),
+                                   rtol=2e-4, atol=2e-4)
+    if T:
+        again = kind.rewound(settled).tail
+        rows = np.arange(T).reshape(-1, 1) <= (
+            (n - 1) % T).reshape(-1, 1, 1, 1)         # (B, 1, T, 1)
+        rows = rows & (n > 0).reshape(-1, 1, 1, 1)
+        np.testing.assert_array_equal(
+            np.where(rows, again, 0), np.where(rows, fused.cache.tail, 0))
+
+
+@pytest.mark.parametrize("prompt", [3, 8, 21, 125, 128, 131])
+def test_a_seat_in_the_middle_of_a_group_steps_like_the_dense_path(prompt):
+    """A request prefilled by T > 1 forwards (the second right-padded, as a
+    final chunk is) and seated beside two running rows: its tail is the
+    prompt's partial group (none live at a group's start, all T at its
+    end), and the steps on the kernels choose the dense path's tokens."""
+    from deepspeed_tpu.inference.decode import (GenCarry, forward_with_cache,
+                                                init_cache)
+    from deepspeed_tpu.inference.kinds import kind_of
+    from deepspeed_tpu.serving.slots import insert_request
+
+    cfg, model, params, state = _tail_state("mha-hd64", [40, 0, 77], seed=2)
+    kind = kind_of(cfg)
+    ids = jnp.asarray(np.random.default_rng(prompt).integers(
+        8, 256, (1, prompt + 5)), jnp.int32)
+    cache = init_cache(cfg, 1, S)
+    head = prompt // 2
+    if head:
+        _, cache = forward_with_cache(model, params, ids[:, :head], cache)
+    # the rest in a padded bucket: five tokens behind the last real one
+    logits, cache = forward_with_cache(
+        model, params, ids[:, head:], cache, last_token_head=True,
+        last_index=jnp.int32(prompt - head - 1))
+    pf = GenCarry(tok=jnp.argmax(logits[:, -1], -1).astype(jnp.int32),
+                  cache=kind.rewound(cache, jnp.int32(prompt)),
+                  rng=jnp.zeros((1, 2), jnp.uint32),
+                  done=jnp.zeros((1,), bool))
+    T, g = 8, (prompt - 1) // 8 * 8
+    np.testing.assert_array_equal(
+        np.asarray(pf.cache.tail[:, 0, :, :prompt - g, :64]),
+        np.asarray(pf.cache.k[:, 0, :, :, g:prompt]).swapaxes(-1, -2))
+    fused = dense = insert_request(state, jnp.int32(1), pf, jnp.int32(50))
+    for _ in range(T + 1):
+        fused = _greedy_step(model, True)(params, fused)
+        dense = _greedy_step(model, False)(params, dense)
+        np.testing.assert_array_equal(np.asarray(fused.tok),
+                                      np.asarray(dense.tok))
+    np.testing.assert_array_equal(np.asarray(fused.cache.length),
+                                  [40 + T + 1, prompt + T + 1, 77 + T + 1])
+    np.testing.assert_array_equal(np.asarray(dense.cache.length),
+                                  np.asarray(fused.cache.length))
+
+
+@pytest.mark.parametrize("steps", [1, 5, 8])
+def test_export_import_step(steps):
+    """What reads a slot's K/V outside the step goes through
+    ``Dense.settled``: a slot stepped ``steps`` times on the kernels, its
+    settled planes and length taken out (one slot's extent, as an export
+    gathers it), seated in another engine's state at another slot with the
+    tail filled from them (``Dense.rewound``), steps on there bit for bit
+    as it does where it was."""
+    from deepspeed_tpu.inference.decode import GenCarry
+    from deepspeed_tpu.inference.kinds import kind_of
+    from deepspeed_tpu.serving.slots import insert_request
+
+    cfg, model, params, here = _tail_state("mha-hd64", [0, 61, 130], seed=4)
+    _, _, _, there = _tail_state("mha-hd64", [9, 0, 0, 33], seed=5)
+    kind = kind_of(cfg)
+    step = _greedy_step(model, True)
+    for _ in range(steps):
+        here = step(params, here)
+    out = kind.settled(here.cache)
+    one = slice(1, 2)
+    payload = GenCarry(
+        tok=here.tok[one], rng=here.rng[one], done=here.done[one],
+        cache=kind.rewound(out._replace(
+            k=out.k[:, one], v=out.v[:, one], tail=out.tail[:, one] * 0,
+            length=out.length[one])))
+    there = insert_request(there, jnp.int32(2), payload, jnp.int32(100))
+    for _ in range(8 + 2):
+        here, there = step(params, here), step(params, there)
+        assert int(here.tok[1]) == int(there.tok[2])
+    a, b = kind.settled(here.cache), kind.settled(there.cache)
+    n = int(a.length[1])
+    assert n == int(b.length[2]) == 61 + steps + 10
+    np.testing.assert_array_equal(_raw(a.k[:, 1, :, :, :n]),
+                                  _raw(b.k[:, 2, :, :, :n]))
+    np.testing.assert_array_equal(_raw(a.v[:, 1, :, :, :n]),
+                                  _raw(b.v[:, 2, :, :, :n]))
+
+
+@pytest.mark.parametrize("preset,names,tailed", [
+    ("gpt2-hd64", {"decode_attention"}, True),
+    ("ouro", {"decode_attention"}, False),              # heads of 16
+    ("nemotron_h", {"decode_attention"}, False),
+    ("mimo_v2_flash", {"full_decode_attention",
+                       "window_decode_attention"}, False),
+    ("zaya", {"cca_decode_attention"}, False),
+    ("falcon_h1", {"gqa_decode_attention"}, False),
+])
+def test_only_the_dense_kind_hands_the_kernel_a_tail(monkeypatch, preset,
+                                                     names, tailed):
+    """The tail is an operand a kind passes or does not: tracing the T == 1
+    step of each kind's unit-test preset, every call of the kernel comes
+    without one (``tail=None``: the parent's program op for op) except the
+    ``Dense`` kind's over heads that fill whole lane tiles."""
+    from deepspeed_tpu import models
+    from deepspeed_tpu.inference.decode import decode_step
+    from deepspeed_tpu.inference.sampling import sample_logits
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.ops import decode_attention as da
+    from deepspeed_tpu.serving.slots import init_slots
+
+    cfg = _tiny(n_head=2, d_model=128) if preset == "gpt2-hd64" else \
+        getattr(models, preset)("tiny", dtype=jnp.float32, max_seq=S)
+    model = build_model(cfg)
+    seen, real = [], da.decode_attention
+
+    def spy(*a, tail=None, name="decode_attention", **kw):
+        seen.append((name, tail))
+        return real(*a, tail=tail, name=name, **kw)
+
+    monkeypatch.setattr(da, "decode_attention", spy)
+    jax.eval_shape(
+        lambda p, c: decode_step(
+            model, p, c, flash_decode=True,
+            sampler=partial(sample_logits, greedy=True, temperature=1.0,
+                            top_k=0, top_p=1.0)),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: init_slots(cfg, 3, S)))
+    assert {name for name, _ in seen} == names
+    assert all((tail is not None) == tailed for _, tail in seen), seen
+    if tailed:
+        assert seen[0][1].shape == (cfg.n_layer, 3, cfg.kv_heads, 8,
+                                    2 * cfg.head_dim)
+
+
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+def test_the_kinds_helpers_read_nothing_behind_the_live_length(poison):
+    """``Dense.rewound`` fills the tail and ``Dense.settled`` the planes
+    through one-hot products, and 0 x NaN is NaN: what lies behind a
+    slot's live length — in the planes, in the tail's rows — is zeroed
+    before it is multiplied, so none of it reaches a live position. A cache
+    of 200 positions (no whole lane tiles) keeps its groups in place too."""
+    from deepspeed_tpu.inference.decode import KVCache
+    from deepspeed_tpu.inference.kinds import kind_of
+
+    cfg, _, _ = _family("mha-hd64")
+    kind = kind_of(cfg)
+    L, B, KV, hd, S_, T = 2, 4, 2, 64, 200, 8
+    lens = np.asarray([5, 0, 197, 200])
+    rng = np.random.default_rng(0)
+    live = np.arange(S_) < lens.reshape(-1, 1, 1, 1)
+    k, v = (np.where(live, rng.standard_normal((L, B, KV, hd, S_)),
+                     poison).astype(np.float32) for _ in range(2))
+    cache = kind.rewound(KVCache(
+        k=jnp.asarray(k), v=jnp.asarray(v), length=jnp.asarray(lens),
+        tail=jnp.full((L, B, KV, T, 2 * hd), poison, jnp.float32)))
+    tail = np.asarray(cache.tail)
+    assert np.isfinite(tail).all()
+    for b, n in enumerate(lens):
+        g = _group_start(n, T)
+        np.testing.assert_array_equal(
+            tail[:, b, :, :n - g, :hd], k[:, b, :, :, g:n].swapaxes(-1, -2))
+        np.testing.assert_array_equal(
+            tail[:, b, :, :n - g, hd:], v[:, b, :, :, g:n].swapaxes(-1, -2))
+        assert not tail[:, b, :, n - g:].any()
+    # the planes behind their tail, and poison in the tail's dead rows
+    dead = np.arange(T).reshape(-1, 1) >= (lens - [
+        _group_start(n, T) for n in lens]).reshape(-1, 1, 1, 1)
+    holes = [a.copy() for a in (k, v)]
+    for b, n in enumerate(lens):
+        for plane in holes:
+            plane[:, b, :, :, _group_start(n, T):n] = 77.0
+    out = kind.settled(cache._replace(
+        k=jnp.asarray(holes[0]), v=jnp.asarray(holes[1]),
+        tail=jnp.asarray(np.where(dead, poison, tail))))
+    np.testing.assert_array_equal(np.asarray(out.k), k)
+    np.testing.assert_array_equal(np.asarray(out.v), v)

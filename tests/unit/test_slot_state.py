@@ -29,18 +29,24 @@ from deepspeed_tpu.serving.slots import (init_slots, insert_request,
 
 S = 128                 # a slot's positions: one lane tile
 PS = 8                  # page size of the paged kind
-KINDS = ["contiguous", "latent", "paged"]
+# ("contiguous": heads of 16, whose step writes the block back every time;
+# "contiguous-tail": one head of 64, K beside V a whole lane tile, whose
+# cache keeps the deferred tail of ``inference/kinds/dense.py``)
+KINDS = ["contiguous", "contiguous-tail", "latent", "paged"]
+TAIL_ROWS = 8           # of float32
 _BUILT = {}
 
 
 def _model(kind):
     """(cfg, model, params) of a kind's tiny model, built once a process."""
-    name = "latent" if kind.startswith("latent") else "dense"
+    name = "latent" if kind.startswith("latent") else \
+        "tail" if "-tail" in kind else "dense"
     if name not in _BUILT:
         cfg = (deepseek_v3("tiny", dtype=jnp.float32, max_seq=S)
                if name == "latent" else
                tiny_test(n_layer=2, vocab_size=256, max_seq=S, d_ff=128,
-                         dtype=jnp.float32))
+                         dtype=jnp.float32,
+                         **({"n_head": 1} if name == "tail" else {})))
         model = build_model(cfg)
         _BUILT[name] = (cfg, model, model.init(jax.random.PRNGKey(0)))
     return _BUILT[name]
@@ -136,7 +142,11 @@ def test_device_lengths_follow_every_retirement(kind, reason, books):
         extra["chaos"] = {"enabled": True, "seed": 0,
                           "nonfinite_decode_step": 2}
     srv = _serving(kind, _engine(kind, eos), clock=clock, **extra)
-    assert (srv._slot_len is not None) == (kind == "contiguous")
+    assert (srv._slot_len is not None) == kind.startswith("contiguous")
+    assert (getattr(srv._state.cache, "tail", None) is not None) == (
+        kind == "contiguous-tail")
+    lens, counts = [], srv._attn_counts
+    srv._attn_counts = lambda fl: lens.append(fl.lens) or counts(fl)
     _check(srv)
     ra = srv.submit(a, 8 if reason != "max_new" else 4, seed=1,
                     total_deadline_s=5.0 if reason == "deadline" else None)
@@ -185,10 +195,21 @@ def test_device_lengths_follow_every_retirement(kind, reason, books):
     # (the last step out, which went before the host knew that nothing was
     # left running, ran no row and says nothing of fetches)
     steps = [e for e in steps if e.meta["slots"]]
-    if kind == "contiguous":
+    if kind.startswith("contiguous"):
         assert [e.meta["idle_fetched"] for e in steps] == [0] * len(steps)
-        # a block a running request and for no other row, in every step
-        assert {e.meta["append_moved_over_new"] for e in steps} == {128.0}
+        # from the lengths each step left: a block a running request and
+        # for no other row, in every step; with a tail of T rows the
+        # request's tile in and out, and the block where its group ended
+        T = TAIL_ROWS if kind == "contiguous-tail" else 0
+        lens = [n for n in lens if n.any()]
+        assert len(lens) == len(steps)
+        for e, n in zip(steps, lens):
+            live = n > 0
+            moved = 128 * live.sum() if not T else \
+                2 * T * live.sum() + 128 * (live & (n % T == 0)).sum()
+            assert e.meta["append_moved_over_new"] == moved / e.meta["slots"]
+        assert T or {e.meta["append_moved_over_new"] for e in steps} \
+            == {128.0}
     # what the device cannot foresee costs one small program where it
     # happens; what it can (eos, the budget) costs none
     assert ("retire" in srv._programs) == (
@@ -280,7 +301,7 @@ def _seated(kind, rows, idle=()):
         for slot, pf in rows.items():
             state = insert_request(state, jnp.int32(slot), pf, np.int32(50))
         bufs = {name: buf for name, buf in state.cache._asdict().items()
-                if name != "length"}
+                if name != "length" and buf is not None}
         for slot in idle:
             bufs = {name: buf.at[:, slot].set(_noise(buf[:, slot], slot))
                     for name, buf in bufs.items()}
@@ -298,11 +319,13 @@ def _row_buffers(kind, cache, slot):
             cache.k, cache.v, cache.k_scale, cache.v_scale)
             if buf is not None]
     return [np.asarray(buf)[:, slot] for name, buf in
-            cache._asdict().items() if name != "length"]
+            cache._asdict().items() if name != "length" and buf is not None]
 
 
 @pytest.mark.parametrize("kind,T", [
-    ("contiguous-kernels", 1), ("contiguous-xla", 1), ("latent-kernels", 1),
+    ("contiguous-kernels", 1), ("contiguous-xla", 1),
+    ("contiguous-tail-kernels", 1), ("contiguous-tail-xla", 3),
+    ("latent-kernels", 1),
     ("latent-xla", 1), ("paged", 1), ("paged-int8", 1),
     ("contiguous-xla", 3), ("paged", 3)],
     ids=lambda v: f"verify{v}" if isinstance(v, int) and v > 1 else
@@ -348,8 +371,9 @@ def test_a_row_that_is_not_running_touches_nothing(kind, T):
                     np.asarray(getattr(want, name)))
 
 
-@pytest.mark.parametrize("kind", ["contiguous-kernels", "latent-kernels",
-                                  "paged"])
+@pytest.mark.parametrize("kind", ["contiguous-kernels",
+                                  "contiguous-tail-kernels",
+                                  "latent-kernels", "paged"])
 def test_the_step_counts_a_row_down_and_parks_it(kind):
     """``decode_step`` on a slot state: a running row's ``left`` falls by one
     a step; at 0 the row is ``done`` at length 0 and stays there, its
@@ -379,7 +403,7 @@ def test_the_step_counts_a_row_down_and_parks_it(kind):
     # decode_attention leaves a parked row alone (the pool: bit for bit
     # above; the latent append writes position 0 of the row's own extent,
     # which the next insert overwrites whole)
-    if kind == "contiguous-kernels":
+    if kind.startswith("contiguous"):
         for g, w in zip(_row_buffers(kind, state.cache, 0), parked):
             np.testing.assert_array_equal(g, w)
     state = jax.jit(retire_slots)(state, np.asarray([False, False, True]))
@@ -388,18 +412,19 @@ def test_the_step_counts_a_row_down_and_parks_it(kind):
 
 
 # --------------------------- (c) served == solo with idle rows between them
-@pytest.mark.parametrize("kind", ["contiguous", "contiguous-xla", "latent",
-                                  "paged"])
+@pytest.mark.parametrize("kind", ["contiguous", "contiguous-xla",
+                                  "contiguous-tail", "latent", "paged"])
 def test_three_of_eight_slots_running_equal_solo_generate(kind):
     """Sampled requests through an engine of 8 slots of which never more
     than 3 run (5 stand at length 0 between and around them, and a slot is
     re-used after its first occupant ended), bit-identical to solo
     ``generate()`` of each."""
-    base = kind.split("-")[0]
+    base = kind.removesuffix("-xla")
     cfg, model, params = _model(base)
     eng = ds.init_inference(
         model, params, {"dtype": "float32", "eos_token_id": None,
-                        "flash_decode": kind in ("contiguous", "latent")},
+                        "flash_decode": kind in (
+                            "contiguous", "contiguous-tail", "latent")},
         mesh=_one_device_mesh())
     conf = {"slots": 8, "max_len": S, "prefill_chunk": 16,
             "temperature": 0.9, "top_k": 30}
