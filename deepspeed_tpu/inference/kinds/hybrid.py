@@ -122,6 +122,11 @@ class Hybrid(Kind):
                 stacked(stats), None)
 
     def step_meta(self, read, pending, lens, running):
-        """:meth:`sizes`, and :func:`held_counts` of the step's expert
+        """:meth:`sizes`, ``ssm_block_bytes`` (the state one program of the
+        step's kernel takes), and :func:`held_counts` of the step's expert
         layers (``HybridLM.latent_experts``' counters)."""
-        return {**self.sizes(), **held_counts(self, read, pending)}
+        from ...models import ssm
+
+        return {**self.sizes(),
+                "ssm_block_bytes": ssm.step_block_bytes(self.cfg),
+                **held_counts(self, read, pending)}

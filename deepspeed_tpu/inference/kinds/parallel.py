@@ -115,12 +115,16 @@ class ParallelHybrid(Kind):
                 None, None)
 
     def step_meta(self, read, pending, lens, running):
-        """:meth:`sizes`, and what the step has to move: ``state_bytes_step``
+        """:meth:`sizes`, ``ssm_block_bytes`` (the state one program of the
+        step's kernel takes), and what the step has to move: ``state_bytes_step``
         (the running slots' state of every layer, in and out),
         ``kv_bytes_step`` (``live_positions``, the kernel's count, x the
         bytes a token), ``weight_bytes_step`` (the layers') with the head
         apart (``head_bytes_step``), and the state's share of their sum."""
-        meta = self.sizes()
+        from ...models import ssm
+
+        meta = {**self.sizes(),
+                "ssm_block_bytes": ssm.step_block_bytes(self.cfg)}
         if lens is None:
             return meta
         live = int(lens.sum())

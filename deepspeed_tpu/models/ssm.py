@@ -110,6 +110,17 @@ def step_kernel_ok(cfg, fused: bool) -> bool:
                                  cfg.ssm_head_dim, cfg.ssm_state)
 
 
+def step_block_bytes(cfg) -> int:
+    """The float32 bytes of state one program of the step's kernel takes
+    (``ops/ssm_step.py`` ``groups_per_program``): ``ssm_block_bytes`` of the
+    ``decode_step`` spans. From the configuration, no device read."""
+    from ..ops.ssm_step import groups_per_program
+
+    H, G, P, N = (cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
+                  cfg.ssm_state)
+    return groups_per_program(H, G, P, N) * (H // G) * P * N * 4
+
+
 def _project(cfg, p, y):
     """y (B, T, d) -> z (B, T, inner), xBC (B, T, conv) before the conv,
     dt (B, T, H) float32 after the softplus."""

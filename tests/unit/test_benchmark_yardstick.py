@@ -24,7 +24,12 @@ same kernel under the same reader: ``nh_spec`` also takes out of every list
 the cells that ``workloads`` appends after the Nemotron cell, so that the pin
 "the metrics that list the Nemotron cell ALONE are the last five" reads the
 file as PR 37 left it. ``test_zaya.py`` and ``test_falcon_h1.py`` pin no
-position. The Ouro test has no
+position. Since PR 50 one metric appended behind them all lists BOTH cells
+that run ``ssm_state_step`` (``ssm.block_bytes_per_program``, ``LATER``
+below): the two tests pin their cell's set of metrics as it stood the day
+the cell was added, so ``nh_spec`` and ``fh_spec`` hand them the file
+without it, and ``test_the_block_a_program_takes_is_read_in_both_cells``
+below holds the entry itself. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -64,11 +69,53 @@ from benchmark.tests.test_window import *  # noqa: E402,F401,F403
 from benchmark.tests.test_zaya import *  # noqa: E402,F401,F403
 
 
-@pytest.fixture(scope="module")
-def nh_spec():  # noqa: F811
-    cell = "nemotron-3-super-l11-e128.serve-backlog-think"
+NEMOTRON_CELL = "nemotron-3-super-l11-e128.serve-backlog-think"
+FALCON_CELL = "falcon-h1-34b-l6.serve-backlog-shortchat"
+# appended since the two cells' tests pinned their sets, and listing them
+LATER = {"ssm.block_bytes_per_program"}
+
+
+def _spec_without_later():
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
+    spec["per_layer"] = [m for m in spec["per_layer"]
+                         if m["name"] not in LATER]
+    return spec
+
+
+@pytest.fixture(scope="module")
+def fh_spec():  # noqa: F811
+    return _spec_without_later()
+
+
+def test_the_block_a_program_takes_is_read_in_both_cells():
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    entry = next(m for m in spec["per_layer"]
+                 if m["name"] == "ssm.block_bytes_per_program")
+    assert entry == {
+        "name": "ssm.block_bytes_per_program", "unit": "B",
+        "better": "higher", "source": "program_span", "layer": "kernels",
+        "moves": "serve_tokens_per_s",
+        "workloads": [NEMOTRON_CELL, FALCON_CELL]}
+    # the cells whose configuration has a Mamba-2 mixer, and no other
+    assert entry["workloads"] == next(
+        m["workloads"] for m in spec["per_layer"]
+        if m["name"] == "ssm_state_step_roofline")
+    with open(os.path.join(_ROOT, "benchmark", "layer_metrics",
+                           "ssm.block_bytes_per_program.json")) as f:
+        reader = json.load(f)
+    assert (reader["reducer"], reader["args"]) == ("program_span", {
+        "parent": "decode_step", "meta": "ssm_block_bytes",
+        "statistic": "mean"})
+    assert {k: reader[k] for k in ("name", "unit", "layer", "moves")} == {
+        k: entry[k] for k in ("name", "unit", "layer", "moves")}
+
+
+@pytest.fixture(scope="module")
+def nh_spec():  # noqa: F811
+    cell = NEMOTRON_CELL
+    spec = _spec_without_later()
     names = [m["name"] for m in spec["per_layer"]]
     cut = names.index("ssm.state_bytes_per_slot") + 1
     spec["per_layer"] = spec["per_layer"][:cut] + [

@@ -1002,16 +1002,20 @@ def test_cca_trunk_keeps_planes_and_tails_in_place(one_chip, monkeypatch,
 
 # ------------- a Mamba-2 mixer and attention side by side in every layer
 def test_ssm_state_step_at_blocks_of_two_mebibytes(one_chip):
-    """``ssm_state_step`` at Falcon-H1's shapes — a group's block is (16,
-    128, 256) float32 = 2 MiB, in and out double-buffered 8 MiB of a core's
-    16 MiB of scoped VMEM — lowers for the chip as it stands (no split over a
-    group's heads), with Nemotron's (16, 64, 128) = 512 KiB beside it."""
-    from deepspeed_tpu.ops.ssm_step import kernel_fits, ssm_state_step
+    """``ssm_state_step`` at both cells' shapes lowers for the chip at a
+    block of 2 MiB of float32 state a program — Falcon-H1's one group of
+    (16, 128, 256), Nemotron's four groups of 16 heads as (64, 64, 128) — in
+    and out double-buffered 8 MiB of a core's 16 MiB of scoped VMEM, with the
+    batch's decays in SMEM beside it."""
+    from deepspeed_tpu.ops.ssm_step import (groups_per_program, kernel_fits,
+                                            ssm_state_step)
 
     f32 = jnp.float32
-    for L, B, H, G, P, N in ((6, 96, 32, 2, 128, 256),
-                             (5, 64, 128, 8, 64, 128)):
+    for L, B, H, G, P, N, gb in ((6, 96, 32, 2, 128, 256, 1),
+                                 (5, 64, 128, 8, 64, 128, 4)):
         assert kernel_fits(H, G, P, N)
+        assert groups_per_program(H, G, P, N) == gb
+        assert gb * (H // G) * P * N * 4 == 2 << 20
         text = _compile(
             lambda S, lay, x, dt, A, Bv, Cv, n: ssm_state_step(
                 S, lay, x, dt, A, Bv, Cv, n, interpret=False), one_chip,

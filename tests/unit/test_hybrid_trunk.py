@@ -366,10 +366,15 @@ def test_the_spans_carry_the_cache_and_state_counts(served):
                                            [2., 3., 8., 5.]])], [], None, [])
     assert counts["held_rows"] == 6.0 and counts["experts_touched"] == 3.5
     assert counts["held_rows_share"] == 6.0 / (3 * cfg.moe_top_k)
+    # the state one program of the step's kernel takes: both of the tiny
+    # trunk's groups (2 x 4 heads of 16 x 16 float32), far under 2 MiB
+    assert counts["ssm_block_bytes"] == ssm.step_block_bytes(cfg) \
+        == cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
     # no field that nothing reads, none the host could only assert
     assert set(counts) == set(meta) | {"held_rows", "held_rows_share",
                                        "experts_touched",
-                                       "moe_load_max_over_mean"}
+                                       "moe_load_max_over_mean",
+                                       "ssm_block_bytes"}
 
 
 # ------------------------------------------------------------ the sizes

@@ -375,10 +375,15 @@ def test_the_spans_say_what_a_step_has_to_move(served):
     moved = {"state_bytes_step": 2 * 2 * sizes["state_bytes_per_slot"],
              "kv_bytes_step": 49 * sizes["cache_bytes_per_token"],
              "weight_bytes_step": layers, "head_bytes_step": head}
-    assert meta == {**sizes, "live_positions": 49, **moved,
+    # the state one program of ``ssm_state_step`` takes: the tiny trunk's
+    # two groups at once; Falcon-H1-34B's one group of 16 heads, 2 MiB
+    block = {"ssm_block_bytes":
+             cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4}
+    assert ssm.step_block_bytes(falcon_h1("34b")) == 2 << 20
+    assert meta == {**sizes, **block, "live_positions": 49, **moved,
                     "state_share_of_step_bytes":
                         moved["state_bytes_step"] / sum(moved.values())}
-    assert kind.step_meta([], [], None, {}) == sizes
+    assert kind.step_meta([], [], None, {}) == {**sizes, **block}
 
 
 def test_param_count_is_the_models_name():
