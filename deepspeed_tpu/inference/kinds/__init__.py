@@ -9,13 +9,14 @@ from .dense import Dense, KVCache, PagedKVCache
 from .hybrid import Hybrid, HybridCache
 from .latent import Latent, LatentCache
 from .parallel import ParallelCache, ParallelHybrid
+from .sparse_latent import SparseLatent, SparseLatentCache
 from .windowed import Windowed, WindowedCache
 
-KINDS = (Dense, Latent, Hybrid, Windowed, CCA, ParallelHybrid)
+KINDS = (Dense, Latent, SparseLatent, Hybrid, Windowed, CCA, ParallelHybrid)
 
 __all__ = ["KINDS", "FEATURES", "Kind", "kind_of", "KVCache", "PagedKVCache",
            "LatentCache", "HybridCache", "WindowedCache", "CCACache",
-           "ParallelCache"]
+           "ParallelCache", "SparseLatentCache"]
 
 
 def kind_of(cfg, *serving) -> Kind:

@@ -36,6 +36,9 @@ class Kind:
     # the read-backs the step and the chunks carry beside the tokens: the
     # expert layers' counters and choices, a looped trunk's exit pdf
     moe_stats = exit_pdf = False
+    # the serving engine keeps a host mirror of the slots' lengths for
+    # :meth:`step_meta` (``lens``) whatever the planes are called
+    mirrors_lengths = False
     # what the kind is called where it refuses (with its verb), how its
     # reasons are joined, feature -> reason; why it has no pages
     what, sep, refuses = "", "; ", {}

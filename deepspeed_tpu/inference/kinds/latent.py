@@ -84,7 +84,10 @@ class Latent(Kind):
 
     @staticmethod
     def matches(cfg) -> bool:
-        return getattr(cfg, "attention", "") == "mla"
+        # (with an index_pattern the latents lie a position a row:
+        # kinds/sparse_latent.py)
+        return getattr(cfg, "attention", "") == "mla" \
+            and not getattr(cfg, "index_pattern", "")
 
     def buffers(self, batch, max_len, dtype=None):
         cfg = self.cfg

@@ -1,0 +1,216 @@
+"""Learned sparse attention over latents (DeepSeek-V3.2's sparse attention
+with GLM-5.2's ``indexer_types``; ``model_type: glm_moe_dsa``): the
+mathematics the full forward and the cache path share.
+
+Layer ``i`` is ``cfg.index_pattern[i]``:
+
+- ``F`` (published ``"full"``) has an **indexer**, a second, small attention
+  whose only product is a choice. From the query's normed latent ``cq``
+  (``mla.query_latent``) it projects ``index_heads`` queries of
+  ``index_head_dim``; from the layer's normed input ``y`` ONE key of that
+  width a position (behind a LayerNorm) and a weight a head; rope turns the
+  first ``qk_rope_head_dim`` dims of queries and key (interleaved pairs).
+  ``I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])`` and ``S_t`` = the
+  ``index_topk`` positions ``s <= t`` of largest ``I[t, s]`` (all of them
+  while ``t < index_topk``). The layer's latent attention (``models/mla.py``)
+  reads the positions of ``S_t`` and no other.
+- ``s`` (``"shared"``) has no indexer and caches no indexer key: its ``S_t``
+  is the ``S_t`` of the last ``F`` layer before it.
+
+So the layer loop carries ``(x, selection)``. The indexers' weights are a
+stacked tree of their own (``params["indexer"]``, one entry an ``F`` layer):
+the layers' own stacks stay uniform, runs of FFN kinds as in every trunk.
+
+A selection travels in two forms. ``idx`` (B, T, K) i32, K = min(index_topk,
+keys), -1 where a query has fewer positions than K: what the T == 1 step's
+kernel fetches by (``ops/sparse_mla_attention.py``) and what a comparison with
+a reference follows. ``mask`` (B, T, S) bool: what T > 1 applies to the walk
+over the live blocks (``mla.attend_expanded(selected=)``) — exact, the work of
+dense attention (ROADMAP R9: a chunk that attends over the selection alone).
+Both come from ONE ``lax.top_k`` (a sort) of the masked scores: the mask is
+``score > the K-th largest`` and, of the scores equal to it, the lowest
+positions, which is ``top_k``'s own order among equals.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import PartitionSpec as P
+
+from ..ops.sparse_mla_attention import einsum_f32
+from .transformer import _norm, _rope
+
+KINDS = "Fs"
+KEY_BLOCK = 1024     # keys a step of a chunk's score walk
+
+
+def check_config(c) -> None:
+    """Refuse what an ``index_pattern`` trunk does not run."""
+    pat = c.index_pattern
+    if len(pat) != c.n_layer or set(pat) - set(KINDS) or pat[0] != "F":
+        raise ValueError(
+            f"index_pattern {pat!r} has to name each of the {c.n_layer} "
+            f"layers, one of {KINDS!r}, the first an 'F': a layer that "
+            "takes a selection over needs one before it")
+    if min(c.q_lora_rank, c.index_topk, c.index_heads,
+           c.index_head_dim) <= 0 or c.index_head_dim < c.qk_rope_head_dim:
+        raise ValueError(
+            "an indexer reads the query's latent (q_lora_rank) and needs "
+            "index_topk, index_heads and index_head_dim >= qk_rope_head_dim")
+    if c.moe_router not in ("gshard", "sigmoid") or c.loop_steps > 1 \
+            or c.block_pattern or c.attn_pattern:
+        raise ValueError("index_pattern is the glm_moe_dsa block: latent "
+                         "attention beside a dense FFN or sigmoid-routed "
+                         "experts")
+
+
+def runs(cfg) -> list:
+    """The trunk as runs of layers equal in (FFN kind, indexer kind): (the
+    segment's index, the run's first layer inside it, layers, "F" | "s", its
+    first layer in the trunk, its first indexer)."""
+    out, layer, full = [], 0, 0
+    for seg, (_, n) in enumerate(cfg.segments):
+        at = 0
+        while at < n:
+            kind = cfg.index_pattern[layer]
+            m = 1
+            while at + m < n and cfg.index_pattern[layer + m] == kind:
+                m += 1
+            out.append((seg, at, m, kind, layer, full))
+            full += m if kind == "F" else 0
+            at, layer = at + m, layer + m
+    return out
+
+
+def init_indexers(cfg, key, dense) -> dict:
+    """The ``F`` layers' indexers, stacked. The key's LayerNorm has a bias;
+    scales at 1 and biases at 0 as a fresh norm's."""
+    n = cfg.index_pattern.count("F")
+    d, ql, H, D = (cfg.d_model, cfg.q_lora_rank, cfg.index_heads,
+                   cfg.index_head_dim)
+    k = iter(jax.random.split(key, 3))
+    return {"wq_b": dense(next(k), (n, ql, H * D)),
+            "wk": dense(next(k), (n, d, D)),
+            "k_norm_scale": jnp.ones((n, D), jnp.float32),
+            "k_norm_bias": jnp.zeros((n, D), jnp.float32),
+            "weights_proj": dense(next(k), (n, d, H))}
+
+
+def indexer_specs() -> dict:
+    # small beside the attention it steers: replicated, as the latent
+    # projection is
+    return {"wq_b": P(None, None, None), "wk": P(None, None, None),
+            "k_norm_scale": P(None, None), "k_norm_bias": P(None, None),
+            "weights_proj": P(None, None, None)}
+
+
+def index_keys(cfg, y, ip, positions):
+    """``kI = rope(LayerNorm(y wk))`` (B, T, index_head_dim): what an ``F``
+    layer caches beside its latents, one for all of the indexer's heads."""
+    k = _norm(y @ ip["wk"].astype(y.dtype), ip["k_norm_scale"],
+              ip["k_norm_bias"], "layernorm", cfg.norm_eps)
+    return _rope(k[:, :, None], k[:, :, None], positions, cfg.rope_theta,
+                 cfg.qk_rope_head_dim)[1][:, :, 0]
+
+
+def index_queries(cfg, y, cq, ip, positions):
+    """(``qI`` (B, T, heads, index_head_dim) roped, ``w`` (B, T, heads) f32 =
+    ``y weights_proj`` times heads^-1/2 index_head_dim^-1/2)."""
+    B, T, _ = y.shape
+    H, D = cfg.index_heads, cfg.index_head_dim
+    q = (cq @ ip["wq_b"].astype(cq.dtype)).reshape(B, T, H, D)
+    q = _rope(q, q, positions, cfg.rope_theta, cfg.qk_rope_head_dim)[0]
+    w = einsum_f32("btd,dh->bth", y, ip["weights_proj"].astype(y.dtype))
+    return q, w * (1.0 / math.sqrt(H * D))
+
+
+def scores(q, w, keys, n_keys=None, block: int = KEY_BLOCK):
+    """``I[t, s] = sum_j w[t, j] ReLU(q[t, j] . keys[s])`` (B, T, S) f32 of
+    ``keys`` (B, index_head_dim, S), positions on the lanes. One product for
+    a step (T == 1); T > 1 walks blocks of ``block`` keys up to ``n_keys``
+    (traced, or None: all), so that no (heads, T, S) array stands — what
+    lies behind is left at 0 and masked by the caller's causal rule."""
+    B, T = q.shape[:2]
+    S = keys.shape[-1]
+
+    def part(k):
+        s = einsum_f32("bthd,bds->bhts", q, k.astype(q.dtype))
+        return jnp.einsum("bth,bhts->bts", w, jnp.maximum(s, 0.0))
+
+    if T == 1 or S % block or S == block:
+        return part(keys)
+    nb = S // block if n_keys is None \
+        else jnp.minimum((n_keys + block - 1) // block, S // block)
+
+    def body(j, out):
+        k = lax.dynamic_slice_in_dim(keys, j * block, block, axis=2)
+        return lax.dynamic_update_slice_in_dim(out, part(k), j * block,
+                                               axis=2)
+
+    return lax.fori_loop(0, nb, body, jnp.zeros((B, T, S), jnp.float32))
+
+
+def select(score, q_pos, topk: int, want_mask: bool = True):
+    """The selection of T queries at positions ``q_pos`` (B, T) from their
+    scores (B, T, S) over positions 0..S-1: (``idx`` (B, T, K) i32, K =
+    min(topk, S), -1 where the query has fewer candidates; ``mask`` (B, T,
+    S) bool or None). A candidate is a position <= the query's."""
+    S = score.shape[-1]
+    causal = jnp.arange(S, dtype=jnp.int32)[None, None] <= q_pos[..., None]
+    masked = jnp.where(causal, score, -jnp.inf)
+    vals, idx = lax.top_k(masked, min(topk, S))
+    idx = jnp.where(vals > -jnp.inf, idx, -1).astype(jnp.int32)
+    if not want_mask:
+        return idx, None
+    # the same set as a mask: above the K-th largest, and of those equal to
+    # it the lowest positions, as many as top_k took (its order among
+    # equals; a ReLU makes exact ties, all heads at 0)
+    thr = vals[..., -1:]
+    above, tied = masked > thr, (masked == thr) & causal
+    room = min(topk, S) - jnp.sum(above, axis=-1, keepdims=True)
+    return idx, above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
+
+
+# ------------------------------------------------------------ full forward
+def trunk(model, params, x, positions, with_selection: bool = False):
+    """The layer stack on a whole sequence, no cache: a Python loop over the
+    layers (this trunk is served; the forward is what tests and
+    ``InferenceEngine.forward`` compare with). Returns (x, routing (expert
+    layers, B, S, k) i32 — the sigmoid router's aux, as every such trunk's —
+    or a zero aux), and with ``with_selection`` the ``F`` layers' ``idx``
+    (F layers, B, S, K) behind them."""
+    from . import mla
+
+    cfg = model.cfg
+    B, S, _ = x.shape
+    segs = model.segment_params(params["layers"])
+    ix = params["indexer"]
+    routing, picks, mask = [], [], None
+    for seg, at, n, kind, _, full in runs(cfg):
+        for i in range(n):
+            p = jax.tree.map(lambda a: a[at + i], segs[seg])
+            y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+            q_nope, q_rope, new = mla.project(cfg, y, p, positions)
+            lat = new.transpose(0, 2, 1)
+            if kind == "F":
+                ip = jax.tree.map(lambda a: a[full + i], ix)
+                qi, w = index_queries(cfg, y, mla.query_latent(cfg, y, p),
+                                      ip, positions)
+                keys = index_keys(cfg, y, ip, positions).transpose(0, 2, 1)
+                idx, mask = select(scores(qi, w, keys), positions,
+                                   cfg.index_topk)
+                picks.append(idx)
+            o = mla.attend_expanded(cfg, p, q_nope, q_rope, lat, positions,
+                                    S, selected=mask)
+            x = x + o.reshape(B, S, -1) @ p["wo"].astype(x.dtype)
+            y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
+            out, aux = model._mlp_block(y2, p)
+            x = x + out
+            if jnp.issubdtype(aux.dtype, jnp.integer):
+                routing.append(aux)
+    aux = jnp.stack(routing) if routing else jnp.float32(0.0)
+    return (x, aux, jnp.stack(picks)) if with_selection else (x, aux)

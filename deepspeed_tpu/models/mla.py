@@ -36,13 +36,29 @@ def project(cfg, y, p, positions):
     B, T, _ = y.shape
     H, nope, rd, r = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.kv_lora_rank)
-    q = (y @ p["wq"].astype(y.dtype)).reshape(B, T, H, nope + rd)
+    if cfg.q_lora_rank:
+        # heads of nope + rope are split off whole lane tiles (192 | 64):
+        # behind the barrier the split re-lays out the rows, where XLA would
+        # otherwise re-lay out the weights to suit it (a copy of wq_b, 64 MB
+        # a layer a step; models/windowed.py)
+        q = lax.optimization_barrier(
+            query_latent(cfg, y, p) @ p["wq_b"].astype(y.dtype))
+    else:
+        q = y @ p["wq"].astype(y.dtype)
+    q = q.reshape(B, T, H, nope + rd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     kva = y @ p["wkv_a"].astype(y.dtype)                     # (B, T, r + rd)
     c = _norm(kva[..., :r], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps)
     q_rope, k_rope = _rope(q_rope, kva[..., None, r:], positions,
                            cfg.rope_theta)
     return q_nope, q_rope, jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)
+
+
+def query_latent(cfg, y, p):
+    """``cq = RMSNorm(y wq_a)`` (B, T, q_lora_rank): what a low-rank query is
+    projected from, and what an indexer's queries read (models/dsa.py)."""
+    return _norm(y @ p["wq_a"].astype(y.dtype), p["q_norm_scale"], None,
+                 "rmsnorm", cfg.norm_eps)
 
 
 def _wkv_b(cfg, p, dtype):
@@ -57,7 +73,7 @@ def softmax_scale(cfg) -> float:
 
 @jax.named_scope("mla_attend")
 def attend_expanded(cfg, p, q_nope, q_rope, latents, q_pos, n_keys,
-                    block: int = 512, layer=None):
+                    block: int = 512, layer=None, selected=None):
     """Causal attention of T queries at absolute positions ``q_pos`` (B, T)
     over the latents ``(B, rank+rope, S)`` of positions 0..S-1, expanding K
     and V block by block. ``n_keys`` bounds the blocks visited: a Python int
@@ -66,9 +82,18 @@ def attend_expanded(cfg, p, q_nope, q_rope, latents, q_pos, n_keys,
     attended iff its position <= the query's, which also hides whatever a
     cache holds behind the live prefix. With ``layer`` (traced i32)
     ``latents`` is the whole cache ``(L, B, rank+rope, S)`` and every block
-    is read out of that layer of it. Returns (B, T, H, v)."""
+    is read out of that layer of it. ``latents`` may also be ``(read, S)``:
+    ``read(j, blk)`` gives block ``j`` as ``(B, rank+rope, blk)`` out of a
+    cache laid out otherwise. ``selected`` (B, T, S) bool: the keys a query
+    may see beside the causal rule (a learned selection, models/dsa.py): a
+    mask over the same walk. Returns (B, T, H, v)."""
     B, T, H, nope = q_nope.shape
-    r, S = cfg.kv_lora_rank, latents.shape[-1]
+    read = None
+    if isinstance(latents, tuple):
+        read, S = latents
+    else:
+        S = latents.shape[-1]
+    r = cfg.kv_lora_rank
     vd = cfg.v_dim
     blk = block if S % block == 0 else S
     w = _wkv_b(cfg, p, q_nope.dtype)
@@ -76,7 +101,9 @@ def attend_expanded(cfg, p, q_nope, q_rope, latents, q_pos, n_keys,
 
     def body(j, carry):
         m, l, acc = carry
-        if layer is None:
+        if read is not None:
+            lat = read(j, blk)
+        elif layer is None:
             lat = lax.dynamic_slice_in_dim(latents, j * blk, blk, axis=2)
         else:
             lat = lax.dynamic_slice(
@@ -89,6 +116,9 @@ def attend_expanded(cfg, p, q_nope, q_rope, latents, q_pos, n_keys,
         s = s.astype(jnp.float32) * scale
         k_pos = j * blk + jnp.arange(blk, dtype=jnp.int32)
         keep = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]
+        if selected is not None:
+            keep &= lax.dynamic_slice_in_dim(selected, j * blk, blk,
+                                             axis=2)[:, None]
         s = jnp.where(keep, s, BIG_NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         pr = jnp.where(keep, jnp.exp(s - m_new), 0.0)

@@ -84,6 +84,44 @@ def deepseek_v3(size: str = "kanana-2-30b-a3b", **overrides) -> TransformerConfi
     return TransformerConfig(**base)
 
 
+def glm_moe_dsa(size: str = "tiny", **overrides) -> TransformerConfig:
+    """GLM-5.2 family (``model_type: glm_moe_dsa``): the DeepSeek-V3 block
+    with a low-rank query (``q_lora_rank``) whose attention reads only the
+    ``index_topk`` positions a learned indexer picks (``index_pattern``,
+    models/dsa.py: ``F`` layers have an indexer, ``s`` layers take the pick
+    of the last ``F`` before them). ``"5.2"`` is zai-org/GLM-5.2's
+    ``config.json`` (78 layers: ``FFF`` then ``sssF`` eighteen times and
+    ``sss``; three dense layers, then 256 experts top-8 beside a shared
+    one). ``"tiny"`` keeps what the cache's layout turns on at unit-test
+    size: a whole period ``F s s s F s s`` behind one dense layer, a
+    selection smaller than a test's contexts, an odd number of indexer
+    heads."""
+    table = {
+        "tiny": dict(index_pattern="FsssFss", n_layer=7, n_head=4,
+                     d_model=64, d_ff=128, vocab_size=251, max_seq=256,
+                     q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, index_topk=16,
+                     index_heads=3, index_head_dim=16, num_experts=8,
+                     moe_top_k=2, moe_d_ff=32, moe_shared_d_ff=32,
+                     moe_first_dense=1),
+        "5.2": dict(index_pattern="FFF" + 18 * "sssF" + "sss", n_layer=78,
+                    n_head=64, d_model=6144, d_ff=12288, vocab_size=154880,
+                    max_seq=1048576, q_lora_rank=2048, kv_lora_rank=512,
+                    qk_nope_head_dim=192, qk_rope_head_dim=64,
+                    v_head_dim=256, index_topk=2048, index_heads=32,
+                    index_head_dim=128, num_experts=256, moe_top_k=8,
+                    moe_d_ff=2048, moe_shared_d_ff=2048, moe_first_dense=3),
+    }
+    base = dict(attention="mla", pos_embedding="rope", norm="rmsnorm",
+                norm_eps=1e-5, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, moe_router="sigmoid",
+                moe_norm_topk=True, moe_routed_scale=2.5, rope_theta=8e6,
+                fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def nemotron_h(size: str = "3-super-120b-a12b", **overrides) -> TransformerConfig:
     """The NemotronH block (``model_type: nemotron_h``): every layer ONE
     mixer, its kind a letter of the published ``hybrid_override_pattern`` —
@@ -318,6 +356,12 @@ def build_model(cfg, attention_fn=None):
 
 # (what tells a trunk that is served here and not trained, why)
 _SERVED_NOT_TRAINED = (
+    (lambda cfg: getattr(cfg, "index_pattern", ""),
+     "a trunk whose attention reads an indexer's selection (index_pattern) "
+     "is served, not trained here: the selection has no gradient of its own "
+     "(the indexer is trained by a loss against the attention's scores, "
+     "arXiv:2512.02556), and a next-token loss through a frozen top-k under "
+     "the model's name would be a guess"),
     (lambda cfg: getattr(cfg, "loop_steps", 1) > 1,
      "a looped trunk (loop_steps > 1) is served, not trained here: its "
      "objective is the expected loss over the exit distribution with an "

@@ -212,6 +212,22 @@ class TransformerConfig:
     # the rotation pairs dim i with i + rotary_dim / 2 (HF ``rotate_half``)
     # instead of 2i with 2i + 1
     rope_halves: bool = False
+    # latent attention's query through a latent of its own (DeepSeek-V2/V3
+    # ``q_lora_rank``): cq = RMSNorm(y wq_a), q = cq wq_b (0: one matrix wq)
+    q_lora_rank: int = 0
+    # learned sparse attention over a latent cache (DeepSeek-V3.2's, with
+    # GLM-5.2's ``indexer_types``; ``model_type: glm_moe_dsa``,
+    # models/dsa.py): layer i is ``index_pattern[i]`` — "F" has an indexer
+    # (``index_heads`` heads of ``index_head_dim`` over the query's latent
+    # and ONE key of that width a position, rope on the first
+    # ``qk_rope_head_dim`` of both) whose ``index_topk`` best positions are
+    # all its attention reads; "s" reads what the last "F" before it chose
+    # and has no indexer. "" is attention over every cached position. The
+    # cache: inference/kinds/sparse_latent.py
+    index_pattern: str = ""
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
 
     @property
     def held_experts(self) -> int:
@@ -337,8 +353,9 @@ class TransformerConfig:
     def _attn_params_per_layer(self, kind: str = "") -> int:
         d, h = self.d_model, self.n_head
         if self.attention == "mla":
-            r = self.kv_lora_rank
-            return (d * h * self.head_dim + d * self.latent_dim
+            r, ql = self.kv_lora_rank, self.q_lora_rank
+            q = ql * (d + h * self.head_dim) if ql else d * h * self.head_dim
+            return (q + d * self.latent_dim
                     + r * h * (self.qk_nope_head_dim + self.v_dim)
                     + h * self.v_dim * d)
         kv, hd, vd = self.attn_kv_heads(kind), self.head_dim, self.v_dim
@@ -382,6 +399,11 @@ class TransformerConfig:
                              + self._ffn_params_per_layer(active_only, kind))
                         for (kind, n), attn in zip(self.segments,
                                                    self.segment_attn))
+        # an indexer: its queries off the query's latent, one key and the
+        # heads' weights off the stream
+        total += self.index_pattern.count("F") * (
+            self.q_lora_rank * self.index_heads * self.index_head_dim
+            + d * (self.index_head_dim + self.index_heads))
         total += emb if not non_embedding else 0
         if (not self.tie_embeddings and not non_embedding
                 and self.objective != "feature"):
@@ -667,12 +689,19 @@ class TransformerLM:
                     "qk_rope_head_dim, no biases, pre-norm, its own blocked "
                     "attention (no attention_fn), and kv_lora_rank / "
                     "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
+            if config.index_pattern:
+                from .dsa import check_config as check_dsa
+
+                check_dsa(config)
         elif config.attention == "cca":
             from .cca import check_config as check_cca
 
             check_cca(config, attention_fn)
         elif config.attention != "mha":
             raise ValueError(f"unknown attention kind {config.attention!r}")
+        if config.index_pattern and config.attention != "mla":
+            raise ValueError("index_pattern selects positions of a latent "
+                             "(attention='mla') cache")
         if config.moe_router == "zaya" and (
                 config.attention != "cca" or config.num_experts < 2
                 or config.router_hidden < 1 or config.moe_top_k != 1
@@ -745,6 +774,11 @@ class TransformerLM:
             "tok_embed": jax.random.normal(next(k), (cfg.vocab_size, d), jnp.float32) * 0.02,
             "layers": layers,
         }
+        if cfg.index_pattern:
+            from .dsa import init_indexers
+
+            params["indexer"] = init_indexers(
+                cfg, jax.random.fold_in(rng, 99), dense)
         if not cfg.post_ln:
             params["lnf_scale"] = jnp.ones((d,), jnp.float32)
         if cfg.pos_embedding == "learned":
@@ -785,9 +819,13 @@ class TransformerLM:
         two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
         layers = {"ln1_scale": jnp.ones((L, d), jnp.float32)}
         if cfg.attention == "mla":
-            r = cfg.kv_lora_rank
+            r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
             layers.update({
-                "wq": dense(next(k), (L, d, h * hd)),
+                "wq_a": dense(next(k), (L, d, ql)),
+                "q_norm_scale": jnp.ones((L, ql), jnp.float32),
+                "wq_b": dense(next(k), (L, ql, h * hd)),
+            } if ql else {"wq": dense(next(k), (L, d, h * hd))})
+            layers.update({
                 "wkv_a": dense(next(k), (L, d, cfg.latent_dim)),
                 "kv_norm_scale": jnp.ones((L, r), jnp.float32),
                 "wkv_b": dense(next(k), (
@@ -871,6 +909,10 @@ class TransformerLM:
             "tok_embed": P("model", None),
             "layers": layers,
         }
+        if cfg.index_pattern:
+            from .dsa import indexer_specs
+
+            specs["indexer"] = indexer_specs()
         if not cfg.post_ln:
             specs["lnf_scale"] = P(None)
         if cfg.pos_embedding == "learned":
@@ -904,7 +946,11 @@ class TransformerLM:
             # heads column-split as wq/wo are; the latent projection and
             # its norm are shared by all heads and stay replicated
             layers.update({
-                "wq": P(None, None, "model"), "wkv_a": P(None, None, None),
+                "wq_a": P(None, None, None), "q_norm_scale": P(None, None),
+                "wq_b": P(None, None, "model"),
+            } if cfg.q_lora_rank else {"wq": P(None, None, "model")})
+            layers.update({
+                "wkv_a": P(None, None, None),
                 "kv_norm_scale": P(None, None),
                 "wkv_b": P(None, None, "model"), "wo": P(None, "model", None),
             })
@@ -1308,6 +1354,16 @@ class TransformerLM:
         norm is then none) and, as aux, what :meth:`loop_passes` says of
         the passes."""
         x, positions = self._embed(params, input_ids)
+        if self.cfg.index_pattern:
+            from .dsa import trunk
+
+            # the selection rides beside x from a layer with an indexer to
+            # the layers that take it over: its own loop (models/dsa.py)
+            if attn_mask is not None or remat_policy is not None:
+                raise NotImplementedError(
+                    "a trunk that selects positions (index_pattern) is "
+                    "served, not trained: no padding mask, no remat")
+            return trunk(self, params, x, positions)
         zaya = self.cfg.moe_router == "zaya"
         if zaya:
             # s_{-1} = 0: the router's state, carried from layer to layer

@@ -786,18 +786,22 @@ def decode_attention(q, ck, cv, length, *, k=None, v=None, tail=None,
     return (out,) + tuple(c[0] if slab else c for c in caches)
 
 
-def _append_kernel(pos_ref, _, *refs):
-    """``refs``: n new-value blocks, n cache tiles, n output tiles."""
+def _append_kernel(pos_ref, _, *refs, keep_idle: bool = False):
+    """``refs``: n new-value blocks, n cache tiles, n output tiles. With
+    ``keep_idle`` a position of -1 (a slot that is not running) takes no
+    lane: its tile goes back as it came."""
     n = len(refs) // 3
     b = pl.program_id(0)
     r = pos_ref[b] % LANES                  # the position's lane in its tile
+    if keep_idle:
+        r = jnp.where(pos_ref[b] >= 0, r, -1)
     for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
                                          refs[2 * n:]):
         out_ref[...] = _with_column(old_ref, new_ref, b, r)
 
 
 def append_in_place(caches: tuple, news: tuple, lengths, layer, *, name: str,
-                    interpret: bool):
+                    interpret: bool, keep_idle: bool = False):
     """An append alone, for a cache that :func:`decode_attention` does not
     read (the one buffer of a latent cache, ``ops/mla_attention.py``): any
     number of buffers ``(L, B, KV, hd, max_len)`` written at the same
@@ -806,13 +810,17 @@ def append_in_place(caches: tuple, news: tuple, lengths, layer, *, name: str,
     as ``dynamic_update_slice`` clamps), ``layer`` (1,) i32. A
     read-modify-write of the one 128-lane tile that holds the position.
     Returns the caches, outputs aliased to the inputs, every other
-    position bit-untouched."""
+    position bit-untouched. A slot at length 0 gets its position 0 written
+    (the next insert overwrites it whole) unless ``keep_idle``: then its
+    tile goes back bit-equal."""
     from jax.experimental.pallas import tpu as pltpu
 
     ck = caches[0]
     _, B, KV, hd, S = ck.shape
     n = len(caches)
     pos = jnp.clip(lengths - 1, 0, S - 1)
+    if keep_idle:
+        pos = jnp.where(lengths > 0, pos, -1)
     news = tuple(_slots_on_lanes(x, c.dtype) for x, c in zip(news, caches))
     kvb = max(d for d in range(1, KV + 1) if KV % d == 0 and (
         d == 1 or d * hd * LANES * ck.dtype.itemsize <= _APPEND_TILE_BYTES))
@@ -821,12 +829,14 @@ def append_in_place(caches: tuple, news: tuple, lengths, layer, *, name: str,
         return (g, 0, b // LANES)
 
     def tile(b, g, pos, layer):
-        return (layer[0], b, g, 0, pos[b] // LANES)
+        at = jnp.maximum(pos[b], 0) if keep_idle else pos[b]
+        return (layer[0], b, g, 0, at // LANES)
 
     new_spec = pl.BlockSpec((kvb, hd, LANES), new_block)
     tile_spec = pl.BlockSpec((None, None, kvb, hd, LANES), tile)
     return pl.pallas_call(
-        _append_kernel,
+        partial(_append_kernel, keep_idle=True) if keep_idle
+        else _append_kernel,
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

@@ -131,11 +131,12 @@ def mla_decode_attention(q, cache, length, *, layer, rank: int, scale: float,
 
 
 def latent_append(cache, new, length, *, layer,
-                  interpret: Optional[bool] = None):
+                  interpret: Optional[bool] = None, keep_idle: bool = False):
     """Write this step's latents ``new`` (B, rank + rope) into layer
     ``layer`` of ``cache`` (L, B, rank + rope, max_len) at position
     ``length - 1`` of every slot, in place (output aliased to the input,
-    every other position bit-untouched)."""
+    every other position bit-untouched). ``keep_idle``: a slot at length 0
+    keeps its position 0 too (``append_in_place``)."""
     _refuse_mesh("latent_append")
     B = new.shape[0]
     if cache.shape[3] % LANES:
@@ -146,5 +147,5 @@ def latent_append(cache, new, length, *, layer,
     (out,) = append_in_place(
         (cache[:, :, None],), (new[:, None, None],), _lengths(length, B),
         jnp.asarray(layer, jnp.int32).reshape(1), name="mla_cache_append",
-        interpret=interpret)
+        interpret=interpret, keep_idle=keep_idle)
     return out[:, :, 0]

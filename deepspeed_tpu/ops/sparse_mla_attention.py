@@ -1,0 +1,313 @@
+"""The latent (MLA) decode step over SELECTED positions: one absorbed query
+per head against the latents of the positions a learned indexer chose
+(``models/dsa.py``), fetched one position at a time, and the in-place
+append of the step's own latents, in one Pallas kernel.
+
+**The layout, and why.** ``ops/mla_attention.py`` keeps positions on the
+lanes (``(L, B, rank + rope, max_len)``): a block of 128 neighbours is one
+tile column, and one position alone is the worst thing to fetch. Here ONE
+position has to come without its neighbours, so the cache is ``(L, B,
+max_len, 1, words)``: a position is a leading index and its latents the
+whole of the two minor dimensions, which XLA tiles ``(1, 128)`` — no
+sublane padding, and a DMA of ``cache[layer, slot, position]`` is a whole
+number of tiles (Mosaic refuses a slice that is not: one row of an ``(8,
+128)``-tiled ``(max_len, 576)`` plane cannot be copied alone). The minor
+dimension is a whole number of 128-word tiles for the same reason. A 2-byte
+cache packs two values a 32-bit word (``uint32``: value ``i`` in the low
+half beside value ``i + words`` in the high half, so that both halves
+unpack, by a shift and a mask, into lane-aligned operands): 576 bf16 values
+are 288 words in a row of 384, 1536 B a position a layer where 1152 are
+used (``fetched_over_selected`` 1.33: the price of the tile). A 4-byte cache
+holds its values as they are, padded likewise.
+
+``sparse_mla_decode_attention``: grid (slots,). A program writes its slot's
+new latents at ``length - 1`` (DMA, waited for: the position may be among
+the selected), then walks its ``n = min(length, K)`` selected positions in
+groups of ``group``: the group's DMAs go out while the group before is
+multiplied (two buffers), ``s = q . lat^T`` for all heads at once, the
+running softmax, ``o += p . c``. A slot at length 0 writes, fetches and
+multiplies nothing.
+
+``index_scores`` (kernel ``dsa_index_score``): the indexer's weighted ReLU
+score of a slot's live keys for the step, block by block, nothing behind the
+live length fetched. (In XLA the same product re-laid the whole key buffer
+out batch-minor, 2 GB of padding a step.)
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+
+from .decode_attention import BIG_NEG, LANES
+from .mla_attention import _lengths, _refuse_mesh
+
+GROUP = 256          # positions a buffer holds (two of them in VMEM)
+
+
+def einsum_f32(spec: str, a, b):
+    """``einsum`` of two arrays of the compute type into float32: the
+    MXU's own accumulation on the chip; on the CPU, whose runtime has no
+    bf16 x bf16 -> f32 batched product, the operands widened first."""
+    if jax.default_backend() == "tpu":
+        return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+    return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
+
+
+def row_layout(values: int, dtype) -> tuple:
+    """(words in a position's row, the words' dtype, values a word) of a
+    cache of ``dtype`` holding ``values`` a position."""
+    packed = 2 if jnp.dtype(dtype).itemsize == 2 else 1
+    words = -(-values // (packed * LANES)) * LANES
+    return words, (jnp.uint32 if packed == 2 else jnp.dtype(dtype)), packed
+
+
+def pack_rows(lat, dtype):
+    """``lat`` (..., values) as rows of the cache ``(..., 1, words)``."""
+    words, _, packed = row_layout(lat.shape[-1], dtype)
+    lat = lat.astype(dtype)
+    lat = jnp.pad(lat, [(0, 0)] * (lat.ndim - 1)
+                  + [(0, packed * words - lat.shape[-1])])
+    if packed == 1:
+        return lat[..., None, :]
+    bits = lax.bitcast_convert_type(lat, jnp.uint16).astype(jnp.uint32)
+    return (bits[..., :words] | (bits[..., words:] << 16))[..., None, :]
+
+
+def unpack_rows(rows, values: int, dtype):
+    """The inverse: ``rows`` (..., 1, words) -> (..., values) of ``dtype``."""
+    rows = rows[..., 0, :]
+    if rows.dtype != jnp.uint32:
+        return rows[..., :values].astype(dtype)
+    lo = lax.bitcast_convert_type((rows & 0xffff).astype(jnp.uint16), dtype)
+    hi = lax.bitcast_convert_type((rows >> 16).astype(jnp.uint16), dtype)
+    return jnp.concatenate([lo, hi], axis=-1)[..., :values]
+
+
+def _halves(w, dtype):
+    """A loaded (G, words) block as the operands of its products: the low
+    and the high halves of packed words, or the block itself."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if w.dtype != jnp.uint32:
+        return (w,)
+    return (pltpu.bitcast(w << 16, jnp.float32).astype(dtype),
+            pltpu.bitcast(w & jnp.uint32(0xffff0000),
+                          jnp.float32).astype(dtype))
+
+
+def _kernel(idx_ref, n_ref, len_ref, layer_ref, q_ref, new_ref, cache_ref,
+            o_ref, out_cache_ref, buf, sem, wsem, m_ref, l_ref, acc_ref, *,
+            group: int, rank: int, scale: float, dtype):
+    from jax.experimental.pallas import tpu as pltpu
+
+    del cache_ref                       # aliased: out_cache_ref is the cache
+    b = pl.program_id(0)
+    n, layer = n_ref[b], layer_ref[0]
+    G, W = group, buf.shape[-1]
+    ng = (n + G - 1) // G
+    parts = 2 if buf.dtype == jnp.uint32 else 1
+
+    m_ref[...] = jnp.full(m_ref.shape, BIG_NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(len_ref[b] > 0)
+    def _():
+        write = pltpu.make_async_copy(
+            new_ref, out_cache_ref.at[layer, b, pl.ds(len_ref[b] - 1, 1)], wsem)
+        write.start()
+        write.wait()
+
+    def copy(pos, slot, i):
+        return pltpu.make_async_copy(out_cache_ref.at[layer, b, pos],
+                                     buf.at[slot, i], sem.at[slot])
+
+    def each(g, fn):
+        def one(i, _):
+            @pl.when(g * G + i < n)
+            def _():
+                fn(i)
+            return 0
+        lax.fori_loop(0, G, one, 0)
+
+    def fetch(g, slot):
+        each(g, lambda i: copy(idx_ref[b, g * G + i], slot, i).start())
+
+    @pl.when(ng > 0)
+    def _():
+        fetch(0, 0)
+
+    def body(g, _):
+        slot = g % 2
+
+        @pl.when(g + 1 < ng)
+        def _():
+            fetch(g + 1, 1 - slot)
+
+        each(g, lambda i: copy(0, slot, i).wait())
+        lat = _halves(buf[slot].reshape(G, W), dtype)
+        q = q_ref[...]                                   # (H, parts * W)
+        nt = (((1,), (1,)), ((), ()))
+        s = sum(lax.dot_general(q[:, i * W:(i + 1) * W], lat[i], nt,
+                                preferred_element_type=jnp.float32)
+                for i in range(parts)) * scale
+        keep = g * G + lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
+        s = jnp.where(keep, s, BIG_NEG)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        # a row no DMA wrote holds whatever the buffer held: 0 x NaN is NaN
+        live = g * G + lax.broadcasted_iota(jnp.int32, (G, 1), 0) < n
+        for i in range(parts):
+            acc_ref[:, i * W:(i + 1) * W] = \
+                acc_ref[:, i * W:(i + 1) * W] * corr + jnp.dot(
+                    p.astype(dtype), jnp.where(live, lat[i], 0),
+                    preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return 0
+
+    lax.fori_loop(0, ng, body, 0)
+    o_ref[...] = (acc_ref[:, :rank] / jnp.maximum(l_ref[...], 1e-30)
+                  ).astype(o_ref.dtype)
+
+
+def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
+                                rank: int, scale: float, group: int = GROUP,
+                                interpret: Optional[bool] = None):
+    """``q`` (B, H, rank + rope): the absorbed queries, in the order the
+    latents lie; ``cache`` (L, B, max_len, 1, words) (:func:`pack_rows`),
+    ``layer`` (traced i32) the layer read and written; ``new`` (B, rank +
+    rope): this step's latents, written at ``length - 1`` of every slot
+    first, in place (the cache comes back aliased, every other position
+    bit-untouched); ``idx`` (B, K) i32: the positions selected, the first
+    ``min(length, K)`` of a row valid; ``length`` (B,) AFTER the append.
+    Returns (``o_lat`` (B, H, rank) = softmax(q . lat . scale) . c over the
+    selected positions, the cache)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _refuse_mesh("sparse_mla_decode_attention")
+    B, H, D = q.shape
+    W = cache.shape[-1]
+    K = idx.shape[1]
+    dtype = q.dtype
+    parts = 2 if cache.dtype == jnp.uint32 else 1
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    lengths = jnp.minimum(_lengths(length, B), cache.shape[2])
+    n = jnp.minimum(lengths, K)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, parts * W - D)))
+    G = min(group, -(-K // 8) * 8)
+    o, cache = pl.pallas_call(
+        partial(_kernel, group=G, rank=rank, scale=scale, dtype=dtype),
+        name="sparse_mla_decode_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, parts * W),
+                                   lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, H, rank), lambda b, *_: (b, 0, 0)),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM((2, G, 1, W), cache.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA(()),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, parts * W), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, rank), dtype),
+                   jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.maximum(idx, 0).astype(jnp.int32), n, lengths,
+      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(dtype),
+      pack_rows(new, dtype), cache)
+    return o, cache
+
+
+def _score_kernel(len_ref, _, q_ref, w_ref, k_ref, o_ref, *, block: int):
+    b, j = pl.program_id(0), pl.program_id(1)
+    L = len_ref[b]
+
+    @pl.when(j * block < L)
+    def _():
+        s = jnp.dot(q_ref[...], k_ref[...],
+                    preferred_element_type=jnp.float32)       # (heads, blk)
+        r = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0, keepdims=True)
+        col = j * block + lax.broadcasted_iota(jnp.int32, r.shape, 1)
+        o_ref[...] = jnp.where(col < L, r, -jnp.inf)
+
+    @pl.when(j * block >= L)
+    def _():
+        o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, jnp.float32)
+
+
+def index_scores(q, w, keys, length, *, layer, block: int = 2048,
+                 interpret: Optional[bool] = None):
+    """An indexer's score of every live key of every slot, for the T == 1
+    step: ``I[b, s] = sum_j w[b, j] ReLU(q[b, j] . keys[b, :, s])`` for ``s <
+    length[b]``, ``-inf`` behind. ``q`` (B, heads, D), ``w`` (B, heads) f32,
+    ``keys`` (F, B, D, max_len) positions on the lanes, ``layer`` (traced
+    i32) the indexer read. Grid (slots, key blocks); the index map clamps
+    the block to the slot's last live one, so keys behind the live length
+    are not fetched. Returns (B, max_len) f32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _refuse_mesh("dsa_index_score")
+    B, H, D = q.shape
+    S = keys.shape[3]
+    blk = next((t for t in range(min(block, S) // LANES * LANES, 0, -LANES)
+                if S % t == 0), S)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    lengths = jnp.minimum(_lengths(length, B), S)
+
+    def key_block(b, j, n, layer):
+        last = jnp.maximum(n[b] - 1, 0) // blk
+        return (layer[0], b, 0, jnp.minimum(j, last))
+
+    out = pl.pallas_call(
+        partial(_score_kernel, block=blk),
+        name="dsa_index_score",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, S // blk),
+            in_specs=[pl.BlockSpec((None, H, D), lambda b, j, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, H, 1), lambda b, j, *_: (b, 0, 0)),
+                      pl.BlockSpec((None, None, D, blk), key_block)],
+            out_specs=pl.BlockSpec((None, 1, blk),
+                                   lambda b, j, *_: (b, 0, j))),
+        out_shape=jax.ShapeDtypeStruct((B, 1, S), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(lengths, jnp.asarray(layer, jnp.int32).reshape(1),
+      q.astype(keys.dtype), w.astype(jnp.float32)[..., None], keys)
+    return out[:, 0]
+
+
+def attend_selected(q, lat, idx, length, *, rank: int, scale: float):
+    """The same read in plain ``jax.numpy``: ``q`` (B, H, rank + rope) over
+    ``lat`` (B, max_len, rank + rope), the first ``min(length, K)`` of
+    ``idx`` (B, K) attended. What a step traced without the kernels pays,
+    and what the kernel's test holds it to."""
+    B, K = idx.shape
+    rows = jnp.take_along_axis(lat, jnp.maximum(idx, 0)[..., None], axis=1)
+    s = einsum_f32("bhd,bkd->bhk", q, rows.astype(q.dtype)) * scale
+    keep = jnp.arange(K, dtype=jnp.int32)[None, None] \
+        < jnp.minimum(_lengths(length, B), K)[:, None, None]
+    s = jnp.where(keep, s, BIG_NEG)
+    p = jnp.where(keep, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhk,bkr->bhr", p.astype(q.dtype),
+                      rows[..., :rank].astype(q.dtype))

@@ -323,9 +323,10 @@ class ServingEngine:
         # runs, 0 from its retirement on), from which the ``decode_step``
         # span says how far past the live positions the kernel fetched. No
         # device read
+        self._kv_counts = self._flash and not self._paged \
+            and self.kind.planes == ("k", "v")
         self._slot_len = np.zeros(self.cfg.slots, np.int64) \
-            if self._flash and not self._paged \
-            and self.kind.planes == ("k", "v") else None
+            if self._kv_counts or self.kind.mirrors_lengths else None
         self.pool: Optional[PagePool] = None
         self._table = None
         self._table_dirty = False
@@ -1129,11 +1130,14 @@ class ServingEngine:
         every request it is booked to (``rows``, before the booking):
         that of the token it was fed."""
         log = self.routing_log
+        # (a kind's routing may be several arrays, each (layers, B, T, .))
         for (rid, start, _, real), routing in zip(tapped, chunks):
-            log.setdefault(rid, []).append((start, routing[:, 0, :real]))
+            log.setdefault(rid, []).append((start, jax.tree.map(
+                lambda r: r[:, 0, :real], routing)))
         for slot, req in rows.items():
             log.setdefault(req.rid, []).append(
-                (req.prompt_len + len(req.tokens) - 1, step[:, slot]))
+                (req.prompt_len + len(req.tokens) - 1,
+                 jax.tree.map(lambda r: r[:, slot], step)))
 
     @property
     def _serial(self) -> bool:
@@ -1297,7 +1301,7 @@ class ServingEngine:
         host now."""
         step = self._prog(key, lambda: jax.jit(impl, donate_argnums=(1,)))
         self._state, read = step(self.engine.params, self._state, *extra)
-        for out in self._fetched(read):
+        for out in jax.tree.leaves(self._fetched(read)):
             out.copy_to_host_async()
         lens = None
         if self._slot_len is not None:
@@ -1343,7 +1347,7 @@ class ServingEngine:
                 self._log_routing(
                     moe.pop(1), tapped,
                     [moe.pop() for _ in tapped][::-1], rows)
-            counts = self._attn_counts(fl) if fl.lens is not None else {}
+            counts = self._attn_counts(fl) if self._kv_counts else {}
             counts.update(
                 self.kind.step_meta(moe, pending, fl.lens, fl.rows))
         self._time_step(fl.t0, lane_s, step=fl.step, slots=len(fl.rows),

@@ -29,7 +29,12 @@ that run ``ssm_state_step`` (``ssm.block_bytes_per_program``, ``LATER``
 below): the two tests pin their cell's set of metrics as it stood the day
 the cell was added, so ``nh_spec`` and ``fh_spec`` hand them the file
 without it, and ``test_the_block_a_program_takes_is_read_in_both_cells``
-below holds the entry itself. The Ouro test has no
+below holds the entry itself. Since PR 51 the GLM-5.2 cell joins
+``moe.held_rows_share`` (one of the five the Nemotron test counts as listing
+its cell alone) and ``moe.load_max_over_mean`` (which the Nemotron and MiMo
+tests read): a cell ``workloads`` appends after theirs, which ``nh_spec`` and
+``mm_spec`` take out as they take out every later one;
+``test_glm_moe_dsa.py`` pins no position. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -52,12 +57,13 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_window", "benchmark.tests.test_deepseek_v3",
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
-    "benchmark.tests.test_falcon_h1",
+    "benchmark.tests.test_falcon_h1", "benchmark.tests.test_glm_moe_dsa",
     "benchmark.tests.test_program_lifecycle")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_falcon_h1 import *  # noqa: E402,F401,F403
+from benchmark.tests.test_glm_moe_dsa import *  # noqa: E402,F401,F403
 from benchmark.tests.test_mimo_v2_flash import *  # noqa: E402,F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ouro import *  # noqa: E402,F401,F403

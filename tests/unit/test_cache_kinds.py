@@ -17,10 +17,11 @@ from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
                                             state_bytes_per_slot)
 from deepspeed_tpu.inference.kinds import (CCA, FEATURES, KINDS, Dense,
                                            Hybrid, Latent, PagedKVCache,
-                                           ParallelHybrid, Windowed, kind_of)
-from deepspeed_tpu.models import (deepseek_v3, falcon_h1, mimo_v2_flash,
-                                  nemotron_h, ouro, presets, tiny_test,
-                                  why_not_trained, zaya)
+                                           ParallelHybrid, SparseLatent,
+                                           Windowed, kind_of)
+from deepspeed_tpu.models import (deepseek_v3, falcon_h1, glm_moe_dsa,
+                                  mimo_v2_flash, nemotron_h, ouro, presets,
+                                  tiny_test, why_not_trained, zaya)
 from deepspeed_tpu.observability.capacity import kv_cache_bytes
 from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
 from deepspeed_tpu.serving.pages import init_paged_slots
@@ -44,6 +45,8 @@ CASES = {
     "windowed": (Windowed, lambda: mimo_v2_flash("tiny", dtype=F32)),
     "cca": (CCA, lambda: zaya("tiny", dtype=F32)),
     "parallel": (ParallelHybrid, lambda: falcon_h1("tiny", dtype=F32)),
+    "sparse-latent": (SparseLatent, lambda: glm_moe_dsa(
+        "tiny", dtype=F32, moe_experts_held=2)),
 }
 CONTIGUOUS = [name for name in CASES if name != "paged"]
 SLOTS, MAX_LEN = 2, 128
@@ -230,7 +233,9 @@ PRESETS = [(fn, size) for fn, sizes in (
     (presets.ouro, ("tiny", "2.6b")), (presets.bert, ("base",)),
     (presets.opt, ("125m",)), (presets.bloom, ("560m",)),
     (presets.mimo_v2_flash, ("tiny", "flash")), (presets.zaya, ("tiny",)),
-    (presets.falcon_h1, ("tiny", "34b")), (presets.tiny_test, (None,))) for size in sizes]
+    (presets.falcon_h1, ("tiny", "34b")),
+    (presets.glm_moe_dsa, ("tiny", "5.2")),
+    (presets.tiny_test, (None,))) for size in sizes]
 
 
 @pytest.mark.parametrize("fn,size", PRESETS,

@@ -1106,3 +1106,190 @@ def test_parallel_trunk_keeps_planes_and_state_in_place(one_chip, monkeypatch,
     assert count == {"ssm_state_step": int(batch > 1),
                      "gqa_decode_attention": int(batch > 1),
                      "decode_attention": 0}, count
+
+
+# ----------------------- latents a position a row: an indexer's selection
+GLM = dict(slots=10, max_len=32768, chunk=512, L=7, words=384, K=2048)
+
+
+def _glm(one_chip):
+    """(cfg, model, abstract served params) of GLM-5.2's share
+    (``benchmark/configs/glm-5.2-l7-e16.json``: layers F s s s F s s, 16 of
+    256 experts) at the published widths."""
+    import json
+
+    from benchmark.models import glm_moe_dsa as fam
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.2-l7-e16.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+    keep = set(model.fp32_param_names())
+
+    def served(path, a):
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype if name in keep else jnp.bfloat16,
+            sharding=one_chip)
+
+    return cfg, model, jax.tree_util.tree_map_with_path(
+        served, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+
+@pytest.mark.parametrize("program", ["slot step", "chunk", "final chunk"])
+def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
+        one_chip, monkeypatch, program, capsys):
+    """GLM-5.2's share at the cell's 10 slots x 32 768, chunks of 512: both
+    buffers enter donated and leave aliased; every program's live set beside
+    what else stands on the chip (the slots' cache beside a chunk, the
+    batch-1 prefill cache beside the step) stays under 15.0 GiB — ISSUE 51's
+    limit, which 12 slots break; no (64, 512, 32 768) float32 score array
+    stands in a chunk; the step holds four calls of the sparse read (one a
+    run of layers), two of the score and of the key append, and NO other
+    operation touches the latents' buffer: a layer's live latents are read
+    by no one, the selected rows by the kernel's own DMAs."""
+    import time
+
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    g = GLM
+    cfg, model, params = _glm(one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 7 * 1536 + 2 * 256
+    a_slot = g["max_len"] * 11264
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, g["chunk"]), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(lambda: init_slots(
+            cfg, g["slots"], g["max_len"], jnp.bfloat16)))
+        assert state.cache.c.shape == (7, 10, 32768, 1, 384) \
+            and state.cache.c.dtype == jnp.uint32
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        held, beside = g["slots"] * a_slot, a_slot
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, g["max_len"], jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start),
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True)[final ^ 1:],
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        held, beside = a_slot, g["slots"] * a_slot
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\n[sparse latent {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB; with "
+              f"what stands beside it {(live + beside) / 2 ** 30:.2f} GiB]")
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert live + beside < 15.0 * 2 ** 30, (live + beside) / 2 ** 30
+    # ... which 12 slots would not: two more slots' cache
+    assert live + beside + 2 * a_slot > 15.0 * 2 ** 30 or program != \
+        "final chunk"
+    text = compiled.as_text()
+    assert not re.search(r"f32\[(1,)?64,512,32768\]", text)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "sparse_mla_decode_attention", "dsa_index_score", "mla_cache_append",
+        "mla_decode_attention", "moe_experts_up")}
+    step = program == "slot step"
+    assert count == {
+        "sparse_mla_decode_attention": 4 if step else 0,
+        "dsa_index_score": 2 if step else 0,
+        "mla_cache_append": 2 if step else 0, "mla_decode_attention": 0,
+        # (a chunk with no head drops its last layer's experts)
+        "moe_experts_up": 3}, count
+    if step:
+        latents = "u32[7,10,32768,1,384]"
+        passes = ("custom-call(", "parameter(", "get-tuple-element(",
+                  " tuple(", "while(", "bitcast(")
+        touched = [ln for ln in text.splitlines()
+                   if ln.lstrip().startswith(("%", "ROOT")) and " = " in ln
+                   and latents in ln and not any(p in ln for p in passes)]
+        assert not touched, touched[:3]
+
+
+def test_held_experts_take_rows_of_6144(one_chip):
+    """``experts_swiglu`` at GLM-5.2's shapes: rows 6144 wide into 16 held
+    experts of 2048, the step's 80 pairs and a chunk's 4096."""
+    from deepspeed_tpu.ops.moe_matmul import experts_swiglu
+
+    bf, i32 = jnp.bfloat16, jnp.int32
+    bank = ((16, 6144, 2048), bf)
+    for tokens in (GLM["slots"], GLM["chunk"]):
+        rows = -(-(tokens * 8 + 16 * 15) // 16) * 16
+        _compile(lambda xs, wg, wi, wo, be, used: experts_swiglu(
+            xs, wg, wi, wo, be, used[0], bm=16, interpret=False), one_chip,
+            ((rows, 6144), bf), bank, bank, ((16, 2048, 6144), bf),
+            ((rows // 16,), i32), ((1,), i32))
+
+
+# sha256 of the lowered text with every kernel's serialized body taken out
+# (a body carries its source's path and lines), taken at the parent of PR 51
+# (commit 2545243) by this very function
+KANANA_TEXT = {"slot step": "c8fe8b56f27a7bb2", "chunk": "81451999b8e18582"}
+
+
+@pytest.mark.parametrize("program", ["slot step", "chunk"])
+def test_the_latent_kind_s_programs_are_the_parent_s(one_chip, monkeypatch,
+                                                     program):
+    """Kanana's step and chunk, which share ``mla.project``,
+    ``attend_expanded``, ``latent_append`` and the sorted expert rows with
+    the new kind, lower to the text they lowered to before it."""
+    import hashlib
+
+    from deepspeed_tpu.inference.decode import forward_with_cache, init_cache
+    from deepspeed_tpu.models import deepseek_v3
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = deepseek_v3("kanana-2-30b-a3b", n_layer=7, dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda key: jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), model.init(key)),
+        jax.random.PRNGKey(0)))
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(
+            lambda: init_slots(cfg, 48, 8192, jnp.bfloat16)))
+        lowered = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state)
+    else:
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, 8192, jnp.bfloat16)))
+        lowered = jax.jit(
+            lambda p, c, ids, start: forward_with_cache(
+                model, p, ids, c._replace(length=start), with_stats=True,
+                with_routing=True)[1:], donate_argnums=(1,)).lower(
+            params, cache,
+            jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", lowered.as_text())
+    assert text.count("BODY") == (6 if program == "slot step" else 2)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == KANANA_TEXT[program]

@@ -1,0 +1,349 @@
+"""Latent attention that reads an indexer's selection (``models/dsa.py``,
+``inference/kinds/sparse_latent.py``, ``ops/sparse_mla_attention.py``), at a
+tiny size on the CPU against the plain reference
+(``benchmark/reference/glm_moe_dsa.py``): the full forward, chunked prefill
+and decode through the cache under, at and over ``index_topk``, the shared
+selection, the chip's share, the controls that have to fail, a slot at
+length 0, and the sparse kernel in interpret mode."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.kinds.backlog_sparse import CONTROLS, control
+from benchmark.reference import glm_moe_dsa as ref
+from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                            forward_with_cache, init_cache)
+from deepspeed_tpu.inference.kinds import Latent, SparseLatent, kind_of
+from deepspeed_tpu.models import build_model, dsa, glm_moe_dsa, mla
+from deepspeed_tpu.ops import sparse_mla_attention as sparse
+from deepspeed_tpu.serving.slots import init_slots
+
+F32 = jnp.float32
+
+
+TOPK = 16
+HELD = dict(moe_experts_held=2, moe_first_held=2)
+
+
+def published(cfg) -> dict:
+    """The published keys the reference reads, of a native config."""
+    L = cfg.n_layer
+    return {
+        "num_attention_heads": cfg.n_head, "rms_norm_eps": cfg.norm_eps,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "kv_lora_rank": cfg.kv_lora_rank,
+        "q_lora_rank": cfg.q_lora_rank, "index_topk": cfg.index_topk,
+        "index_n_heads": cfg.index_heads,
+        "index_head_dim": cfg.index_head_dim,
+        "indexer_types": ["full" if k == "F" else "shared"
+                          for k in cfg.index_pattern],
+        "mlp_layer_types": ["dense"] * cfg.moe_first_dense
+        + ["sparse"] * (L - cfg.moe_first_dense),
+        "num_hidden_layers": L, "num_experts_per_tok": cfg.moe_top_k,
+        "norm_topk_prob": cfg.moe_norm_topk,
+        "routed_scaling_factor": cfg.moe_routed_scale,
+        "rope_parameters": {"rope_theta": cfg.rope_theta,
+                            "rope_type": "default"},
+        "first_expert_held": cfg.moe_first_held,
+        "scoring_func": "sigmoid", "topk_method": "noaux_tc"}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = glm_moe_dsa("tiny", dtype=F32, **HELD)
+    assert (cfg.index_pattern, cfg.index_topk, cfg.index_heads) \
+        == ("FsssFss", TOPK, 3)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    ref.configure(published(cfg))
+    return cfg, model, params
+
+
+def ids_of(cfg, n, rows=1, seed=1):
+    return jax.random.randint(jax.random.PRNGKey(seed), (rows, n), 0,
+                              cfg.vocab_size)
+
+
+def close(a, b, tol=2e-5):
+    return float(jnp.abs(a - b).max()) <= tol * float(jnp.abs(b).max())
+
+
+# -------------------------------------------------------- the full forward
+def test_the_forward_is_the_reference_s(tiny):
+    cfg, model, params = tiny
+    ids = ids_of(cfg, 70, rows=2)
+    got, routing = model.apply(params, ids, return_aux=True)
+    assert routing.shape == (6, 2, 70, cfg.moe_top_k)
+    assert close(got, ref.run_highest(ref.logits, params, ids))
+
+
+def through_the_cache(model, params, ids, cuts, flash, max_len=128):
+    """Prefill ``ids`` in chunks ending at ``cuts`` and decode the rest a
+    token at a time: (logits of every position, the last read-backs)."""
+    cfg = model.cfg
+    cache = init_cache(cfg, ids.shape[0], max_len, F32)
+    # one program a chunk shape and one for the step (run op by op, a test
+    # is a thousand one-op programs in a worker that holds them all)
+    fwd = jax.jit(lambda p, ids, cache: forward_with_cache(
+        model, p, ids, cache, flash_decode=flash, with_routing=True))
+    out, at, chose = [], 0, None
+    for cut in (*cuts, *range(cuts[-1] + 1, ids.shape[1] + 1)):
+        lg, cache, chose = fwd(params, ids[:, at:cut], cache)
+        out.append(lg)
+        at = cut
+    return jnp.concatenate(out, 1), chose, cache
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("cuts,n", [
+    ((8,), 14),              # under index_topk everywhere
+    ((16,), 20),             # a chunk ending AT index_topk, steps over it
+    ((13, 29), 36),          # chunks 2-3 tokens behind 16 and 32, steps over
+    ((32, 64), 70),          # aligned chunks, several times index_topk
+])
+def test_chunks_then_steps_through_the_cache_are_the_reference_s(
+        tiny, cuts, n, flash):
+    cfg, model, params = tiny
+    ids = ids_of(cfg, n, seed=n)
+    got, chose, _ = through_the_cache(model, params, ids, cuts, flash)
+    assert close(got, ref.run_highest(ref.logits, params, ids))
+    routing, picks = chose
+    assert routing.shape == (6, 1, 1, cfg.moe_top_k)
+    # the selection comes back for the F layers alone, whole past index_topk
+    assert picks.shape == (2, 1, 1, TOPK)
+    assert int((picks >= 0).sum(-1).min()) == min(n, TOPK)
+
+
+def test_under_index_topk_it_is_the_latent_kind_on_the_same_weights(tiny):
+    """Everything is selected while ``length <= index_topk``: the latent
+    kind (the same trunk with no index_pattern) reads the same."""
+    cfg, model, params = tiny
+    plain = dataclasses.replace(cfg, index_pattern="", index_topk=0,
+                                index_heads=0, index_head_dim=0)
+    assert type(kind_of(plain)) is Latent \
+        and type(kind_of(cfg)) is SparseLatent
+    other = build_model(plain)
+    theirs = {k: v for k, v in params.items() if k != "indexer"}
+    ids = ids_of(cfg, TOPK, seed=5)
+    for flash in (False, True):
+        got, _, _ = through_the_cache(model, params, ids, (7,), flash)
+        want, _, _ = through_the_cache(other, theirs, ids, (7,), flash)
+        assert close(got, want, 1e-5)
+    # ... and past it they part
+    ids = ids_of(cfg, 3 * TOPK, seed=6)
+    got, _, _ = through_the_cache(model, params, ids, (40,), False)
+    want, _, _ = through_the_cache(other, theirs, ids, (40,), False)
+    assert not close(got, want, 1e-2)
+
+
+# ------------------------------------------------------ the shared selection
+def test_a_shared_layer_reads_the_full_layer_s_selection(tiny, monkeypatch):
+    """Eagerly, the trunk's own loop: the mask a shared layer hands its
+    attention IS the object the full layer before it made; the cache holds
+    an indexer key for the two full layers alone."""
+    cfg, model, params = tiny
+    seen = []
+    real = mla.attend_expanded
+
+    def spy(*a, selected=None, **kw):
+        seen.append(selected)
+        return real(*a, selected=selected, **kw)
+
+    monkeypatch.setattr(mla, "attend_expanded", spy)
+    x, pos = model._embed(params, ids_of(cfg, 40))
+    _, _, picks = dsa.trunk(model, params, x, pos, with_selection=True)
+    assert len(seen) == 7 and picks.shape == (2, 1, 40, TOPK)
+    assert all(seen[i] is seen[0] for i in (1, 2, 3))
+    assert all(seen[i] is seen[4] for i in (5, 6))
+    assert seen[4] is not seen[0] \
+        and not np.array_equal(np.asarray(seen[4]), np.asarray(seen[0]))
+    # bit-equal indices: the mask holds exactly the positions picked
+    for full, mask in ((0, seen[0]), (1, seen[4])):
+        for t in (3, TOPK - 1, TOPK, 39):
+            idx = np.asarray(picks[full, 0, t])
+            assert sorted(idx[idx >= 0]) == list(
+                np.nonzero(np.asarray(mask[0, t]))[0])
+    kind = kind_of(cfg)
+    assert kind.buffers(2, 128, F32)["ik"][0] == (2, 2, 16, 128)
+    assert kind.buffers(2, 128, F32)["c"][0][0] == 7
+    assert "wq_b" in params["indexer"] \
+        and params["indexer"]["wk"].shape[0] == 2
+    assert not any("weights_proj" in seg
+                   for seg in model.segment_params(params["layers"]))
+
+
+# -------------------------------------------------------------- the controls
+@pytest.mark.parametrize("name", CONTROLS)
+def test_every_control_fails(tiny, name):
+    """What a wrong system would compute reads far from the system: the
+    reference under each control, following the system at its near-ties as
+    the benchmark's comparison does, against the system's own logits."""
+    cfg, model, params = tiny
+    ids = ids_of(cfg, 48)
+    got, routing, picks = jax.jit(lambda p, ids: (lambda x, pos: (
+        lambda xs, r, s: (model._head(p, xs), r, s))(*dsa.trunk(
+            model, p, x, pos, with_selection=True)))(*model._embed(p, ids)))(
+                params, ids)
+
+    def compare():
+        want, took = ref.run_highest(
+            lambda p, i, r, s: ref.logits(p, i, follow=(r, s), gap=1e-4,
+                                          select_gap=1e-4),
+            params, ids, routing, picks)
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max()), took
+
+    sound, took = compare()
+    assert sound < 2e-5 and float(took[2]) <= 1e-4
+    with control(name, ref):
+        wrong, _ = compare()
+    assert not ref.CONTROL and ref.ROUND is None
+    # by a wide margin: thousands of times the sound reading
+    assert wrong > (0.02 if name == "weights-8bit" else 0.2), (name, wrong)
+
+
+def test_the_reference_follows_a_selection_at_its_near_ties_only():
+    """The near-tie rule on a hand case: scores 5, 4, 3.001, 3, 1 with K = 3.
+    The system took {0, 1, 3} where the reference takes {0, 1, 2}: the sets
+    differ in positions 2 and 3, 0.001 and 0 from the threshold 3.001. A
+    system that took position 4 (2.001 away) is not followed."""
+    score = jnp.asarray([[5.0, 4.0, 3.001, 3.0, 1.0]])
+    own, thr = ref.top_mask(score, 3)
+    assert own.tolist() == [[True, True, True, False, False]]
+    assert float(thr[0, 0]) == pytest.approx(3.001)
+    tied, _ = ref.top_mask(jnp.asarray([[2.0, 1.0, 1.0, 1.0, 0.5]]), 2)
+    assert tied.tolist() == [[True, True, False, False, False]]  # the lowest
+
+    def far(theirs):
+        sys = np.zeros(5, bool)
+        sys[theirs] = True
+        differ = sys != np.asarray(own[0])
+        return float(np.abs(np.asarray(score[0]) - 3.001)[differ].max())
+
+    assert far([0, 1, 3]) == pytest.approx(0.001, abs=1e-5)   # followed
+    assert far([0, 1, 4]) == pytest.approx(2.001, abs=1e-5)   # never
+
+
+# ------------------------------------------------------------ the chip's share
+def test_the_shares_sum_to_the_uncut_layer(tiny):
+    """The parts all four two-expert shares of an expert layer give, the
+    shared expert counted once, add up to what the uncut reference gives for
+    the whole layer (the model-configs guide's test of the cut)."""
+    cfg, _, _ = tiny
+    whole_cfg = dataclasses.replace(cfg, moe_experts_held=0, moe_first_held=0)
+    whole = build_model(whole_cfg)
+    params = whole.init(jax.random.PRNGKey(3))
+    layer = jax.tree.map(lambda a: a[1],
+                         whole.segment_params(params["layers"])[1])
+    y = jax.random.normal(jax.random.PRNGKey(4), (1, 24, cfg.d_model), F32)
+    c = dict(published(whole_cfg), first_held=0)
+    want, _ = ref.run_highest(lambda y, w: ref.experts(y, w, c), y[0], layer)
+    shared = whole._with_shared(jnp.zeros((24, cfg.d_model), F32), y[0],
+                                layer)
+    parts = []
+    for first in range(0, cfg.num_experts, 2):
+        held = build_model(dataclasses.replace(cfg, moe_first_held=first))
+        mine = {k: v[first:first + 2] if k in held.BANKS else v
+                for k, v in layer.items()}
+        out, stats, idx = held.experts(y, mine)
+        parts.append(out[0] - shared)
+        assert idx.max() < cfg.num_experts and stats[3] <= 24 * cfg.moe_top_k
+    assert close(sum(parts) + shared, want)
+    # and attention is whole on every share: nothing of it is cut
+    assert cache_bytes_per_token(cfg, F32) \
+        == cache_bytes_per_token(whole_cfg, F32)
+
+
+# ------------------------------------------------------- a slot at length 0
+def test_a_slot_at_length_0_keeps_its_rows_and_keys(tiny):
+    cfg, model, params = tiny
+    state = init_slots(cfg, 3, 128, F32)
+    key = jax.random.PRNGKey(7)
+    cache = state.cache._replace(
+        c=jax.random.normal(key, state.cache.c.shape, F32),
+        ik=jax.random.normal(key, state.cache.ik.shape, F32),
+        length=jnp.asarray([20, 0, 5], jnp.int32))
+    lg, new = forward_with_cache(model, params, ids_of(cfg, 1, rows=3),
+                                 cache, flash_decode=True)
+    assert new.length.tolist() == [21, 0, 6]
+    for name in ("c", "ik"):
+        a, b = np.asarray(getattr(cache, name)), np.asarray(getattr(new, name))
+        assert np.array_equal(a[:, 1], b[:, 1]), name       # bit-equal
+        assert not np.array_equal(a[:, 0], b[:, 0]), name
+    assert bool(jnp.isfinite(lg).all())
+
+
+def test_the_bytes_are_the_arrays_own(tiny):
+    cfg, _, _ = tiny
+    for dtype, per_token in ((jnp.bfloat16, 7 * 512 + 2 * 32),
+                             (F32, 7 * 512 + 2 * 64)):
+        cache = jax.eval_shape(lambda: init_cache(cfg, 2, 128, dtype))
+        held = sum(np.prod(b.shape) * b.dtype.itemsize
+                   for b in (cache.c, cache.ik))
+        assert cache_bytes_per_token(cfg, dtype) == per_token
+        assert per_token * 2 * 128 == held
+    # at the published widths: a row of 384 words a layer, 128 values a key
+    big = glm_moe_dsa("5.2")
+    assert sparse.row_layout(big.latent_dim, jnp.bfloat16)[0] == 384
+    assert cache_bytes_per_token(
+        dataclasses.replace(big, n_layer=7, index_pattern="FsssFss"),
+        jnp.bfloat16) == 7 * 1536 + 2 * 256
+
+
+# ------------------------------------------------------------- the kernels
+@pytest.mark.parametrize("dtype,tol", [(F32, 1e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+def test_the_sparse_kernel_reads_what_plain_jnp_reads(dtype, tol):
+    """Interpret mode against ``attend_selected`` on ragged lengths, indices
+    that repeat and rows padded with -1; the append lands where it should
+    and nowhere else."""
+    L, B, S, D, H, K, rank = 2, 4, 256, 40, 4, 48, 32
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
+    lat = jax.random.normal(next(keys), (L, B, S, D), F32).astype(dtype)
+    cache = sparse.pack_rows(lat, dtype)
+    assert np.array_equal(
+        np.asarray(sparse.unpack_rows(cache, D, dtype), np.float32),
+        np.asarray(lat, np.float32))
+    q = jax.random.normal(next(keys), (B, H, D), F32).astype(dtype)
+    new = jax.random.normal(next(keys), (B, D), F32).astype(dtype)
+    length = jnp.asarray([0, 30, 200, 256], jnp.int32)
+    rows = []
+    for b, n in enumerate(length.tolist()):
+        if n >= K:      # any K live positions, some of them twice
+            row = jax.random.randint(jax.random.PRNGKey(9 + b), (K,), 0, n)
+        else:           # all of them, the rest padding
+            row = jnp.pad(jnp.arange(n), (0, K - n), constant_values=-1)
+        rows.append(row)
+    idx = jnp.stack(rows).astype(jnp.int32)
+    o, after = sparse.sparse_mla_decode_attention(
+        q, cache, new, idx, length, layer=jnp.int32(1), rank=rank, scale=0.3,
+        group=16, interpret=True)
+    want = np.asarray(lat, np.float32).copy()
+    for b, n in enumerate(length.tolist()):
+        if n:
+            want[1, b, n - 1] = np.asarray(new[b], np.float32)
+    assert np.array_equal(
+        np.asarray(sparse.unpack_rows(after, D, dtype), np.float32), want)
+    ref_o = sparse.attend_selected(q, jnp.asarray(want[1]).astype(dtype), idx,
+                                   length, rank=rank, scale=0.3)
+    assert float(jnp.abs(o.astype(F32) - ref_o.astype(F32)).max()) <= tol
+    assert float(jnp.abs(o[0]).max()) == 0.0        # length 0: nothing read
+
+
+def test_the_score_kernel_scores_the_live_keys_alone():
+    B, H, D, S = 3, 3, 16, 256
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 4))
+    q = jax.random.normal(next(keys), (B, H, D), F32)
+    w = jax.random.normal(next(keys), (B, H), F32)
+    ik = jax.random.normal(next(keys), (2, B, D, S), F32)
+    length = jnp.asarray([0, 77, 256], jnp.int32)
+    got = sparse.index_scores(q, w, ik, length, layer=jnp.int32(1), block=128,
+                              interpret=True)
+    want = dsa.scores(q[:, None], w[:, None], ik[1])[:, 0]
+    live = jnp.arange(S)[None] < length[:, None]
+    assert bool((got == -jnp.inf)[~live].all())
+    assert float(jnp.abs(jnp.where(live, got - want, 0.0)).max()) < 1e-5
