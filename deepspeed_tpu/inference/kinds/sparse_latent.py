@@ -12,13 +12,14 @@
 
 The T == 1 step, a layer: append ``c`` (inside the sparse kernel) and, in
 an ``F`` layer, ``kI`` (``mla_cache_append``), both in place; in an ``F``
-layer score the slot's live keys (``dsa_index_score``) and select (XLA's
-``lax.top_k``, a sort); fetch the selected positions' latents and attend
-absorbed (``sparse_mla_decode_attention``). An ``s`` layer takes the
-selection the carry holds. T > 1 (a chunk, a solo prefill) writes with
-XLA's update and walks the live blocks with the selection as a mask
-(``mla.attend_expanded(selected=)``: exact, the work of dense attention), in
-blocks of :data:`QUERY_BLOCK` queries so that no (T, max_len) float32 array
+layer score the slot's live keys (``dsa_index_score``) and select
+(``dsa.select``: a threshold by bisection over the scores' bits, no sort);
+fetch the selected positions' latents and attend absorbed
+(``sparse_mla_decode_attention``). An ``s`` layer takes the selection the
+carry holds. T > 1 (a chunk, a solo prefill) writes with XLA's update,
+selects likewise and walks the live blocks with the selection as a mask
+(``mla.attend_expanded(selected=)``: exact, the work of dense attention),
+in blocks of :data:`QUERY_BLOCK` queries so that no (T, max_len) float32 array
 stands for T in the thousands.
 """
 
@@ -139,9 +140,9 @@ class SparseLatent(Kind):
                                             layer=full)
                 return dsa.select(score[:, None], pos, K, want_mask=False)
             keys = lax.dynamic_index_in_dim(ik, full, keepdims=False)
-            return dsa.select(
-                dsa.scores(qi, w, keys, None if per_slot else new_len), pos,
-                K, want_mask=T > 1)
+            live = None if per_slot else new_len
+            return dsa.select(dsa.scores(qi, w, keys, live), pos, K,
+                              want_mask=T > 1, n_keys=live)
 
         def read_block(c, layer):
             def read(j, blk):

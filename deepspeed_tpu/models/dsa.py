@@ -26,10 +26,18 @@ keys), -1 where a query has fewer positions than K: what the T == 1 step's
 kernel fetches by (``ops/sparse_mla_attention.py``) and what a comparison with
 a reference follows. ``mask`` (B, T, S) bool: what T > 1 applies to the walk
 over the live blocks (``mla.attend_expanded(selected=)``) — exact, the work of
-dense attention (ROADMAP R9: a chunk that attends over the selection alone).
-Both come from ONE ``lax.top_k`` (a sort) of the masked scores: the mask is
-``score > the K-th largest`` and, of the scores equal to it, the lowest
-positions, which is ``top_k``'s own order among equals.
+dense attention (ROADMAP R10: a chunk that attends over the selection alone).
+
+The mask defines the set: ``score > the K-th largest`` and, of the scores
+equal to it, the lowest positions (``lax.top_k``'s own order among equals),
+-0.0 and +0.0 one value. NO sort finds it (:func:`select`): the K-th largest
+is counting passes over the scores' bits (a float's bits, a negative's
+flipped, order as the floats do; two bits a pass), a chunk's walking only the
+blocks of keys that are live, and ``idx`` is counted out of the mask
+(:func:`_positions`: block counts, a one-hot product, a rank inside the
+block), ascending — so the two forms cannot disagree. A ``lax.top_k`` of a
+chunk's 512 x 32 768 scores took 16 ms an ``F`` layer and of the step's
+10 x 32 768 1.2 ms, where this takes a few ms and 0.2 (PERF.md §6 "PR 52").
 """
 
 from __future__ import annotations
@@ -46,6 +54,10 @@ from .transformer import _norm, _rope
 
 KINDS = "Fs"
 KEY_BLOCK = 1024     # keys a step of a chunk's score walk
+SELECT_BLOCK = 4096  # keys a step of the selection's counting walk
+SELECT_BITS = 2      # bits of the threshold a counting pass settles
+LANE_BLOCK = 256     # positions a block of the indices' counting-out
+POSITION_ROWS = 64   # queries a tile of it
 
 
 def check_config(c) -> None:
@@ -154,25 +166,126 @@ def scores(q, w, keys, n_keys=None, block: int = KEY_BLOCK):
     return lax.fori_loop(0, nb, body, jnp.zeros((B, T, S), jnp.float32))
 
 
-def select(score, q_pos, topk: int, want_mask: bool = True):
+def _order_keys(score, causal):
+    """int32 keys whose order is the float32 scores' own (a negative
+    float's magnitude bits flipped), -0.0 as +0.0 and what is no candidate
+    as -inf, the lowest."""
+    x = jnp.where(causal, jnp.where(score == 0, 0.0, score), -jnp.inf)
+    bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+_NO_CANDIDATE = -0x7F800001     # _order_keys of -inf
+
+
+def _kth_largest(key, k: int, n_keys=None, block: int = SELECT_BLOCK,
+                 bits: int = SELECT_BITS):
+    """The ``k``-th largest of each row of ``key`` (B, T, S) i32, (B, T, 1):
+    bisection over the 32 bits from the top, ``bits`` a pass — a pass counts
+    the row's keys >= each of its ``2**bits - 1`` candidates in one reading
+    and keeps the largest that still has ``k``. With ``n_keys`` (traced) a
+    pass walks the blocks of ``block`` keys that hold a position under it;
+    what lies behind is no candidate, so the result is floored at that key,
+    which is what counting it would give."""
+    S = key.shape[-1]
+    walk = n_keys is not None and S % block == 0 and S > block
+    rows = key.shape[:-1] + (1,)
+
+    def counts(part, cands):
+        return tuple(jnp.sum(part >= c, axis=-1, keepdims=True,
+                             dtype=jnp.int32) for c in cands)
+
+    def narrow(i, thr):
+        shift = 32 - bits * (i + 1)
+        cands = [thr + lax.shift_left(jnp.int32(d), shift)
+                 for d in range(1, 1 << bits)]    # ascending: the counts fall
+        if walk:
+            found = lax.fori_loop(
+                0, jnp.minimum((n_keys + block - 1) // block, S // block),
+                lambda j, n: tuple(a + b for a, b in zip(n, counts(
+                    lax.dynamic_slice_in_dim(key, j * block, block, axis=2),
+                    cands))),
+                (jnp.zeros(rows, jnp.int32),) * len(cands))
+        else:
+            found = counts(key, cands)
+        for cand, n in zip(cands, found):
+            thr = jnp.where(n >= k, cand, thr)
+        return thr
+
+    lowest = jnp.full(rows, jnp.iinfo(jnp.int32).min, jnp.int32)
+    return jnp.maximum(lax.fori_loop(0, 32 // bits, narrow, lowest),
+                       _NO_CANDIDATE)
+
+
+def _positions(mask, k: int):
+    """The positions a ``mask`` (B, T, S) holds, at most ``k`` a row,
+    ascending, -1 behind the last: (B, T, k) i32. Compares and small
+    products, no sort and no scatter: a row is blocks of :data:`LANE_BLOCK`
+    positions; the blocks' running counts give output ``j`` its block and
+    its rank inside it, a one-hot product fetches that block's lanes, each
+    marked with its rank among the block's selected (a triangular product;
+    0 / 1 and ranks up to 256 are exact in bf16), and the lane that carries
+    the rank is the position. In tiles of :data:`POSITION_ROWS` rows, so
+    that no (rows, k, blocks) one-hot stands for more."""
+    B, T, S = mask.shape
+    lanes, tile_rows = LANE_BLOCK, POSITION_ROWS
+    nb = -(-S // lanes)
+    rows = jnp.pad(mask.reshape(B * T, S), ((0, 0), (0, nb * lanes - S)))
+    upper = jnp.triu(jnp.ones((lanes, lanes), jnp.bfloat16))
+    j = jnp.arange(k, dtype=jnp.int32)[None, :, None]
+
+    def tile(m):
+        m = m.reshape(m.shape[0], nb, lanes)
+        rank = jnp.einsum("rnl,lm->rnm", m.astype(jnp.bfloat16), upper,
+                          preferred_element_type=jnp.float32)
+        ends = jnp.cumsum(rank[..., -1].astype(jnp.int32), axis=-1)[:, None]
+        before = ends <= j                                   # (rows, k, nb)
+        blk = jnp.sum(before, axis=-1, dtype=jnp.int32)
+        start = jnp.max(jnp.where(before, ends, 0), axis=-1)
+        hot = jnp.arange(nb, dtype=jnp.int32) == blk[..., None]
+        got = jnp.einsum("rkn,rnl->rkl", hot.astype(jnp.bfloat16),
+                         jnp.where(m, rank, 0.0).astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)
+        want = (j[..., 0] - start + 1).astype(jnp.float32)[..., None]
+        lane = jnp.sum(jnp.where(got == want,
+                                 jnp.arange(lanes, dtype=jnp.int32), 0), -1)
+        return jnp.where(blk < nb, blk * lanes + lane, -1)
+
+    n = B * T
+    if n <= tile_rows:
+        return tile(rows).reshape(B, T, k)
+    nt = -(-n // tile_rows)
+    rows = jnp.pad(rows, ((0, nt * tile_rows - n), (0, 0)))
+    out = lax.map(tile, rows.reshape(nt, tile_rows, nb * lanes))
+    return out.reshape(nt * tile_rows, k)[:n].reshape(B, T, k)
+
+
+def select(score, q_pos, topk: int, want_mask: bool = True, n_keys=None):
     """The selection of T queries at positions ``q_pos`` (B, T) from their
     scores (B, T, S) over positions 0..S-1: (``idx`` (B, T, K) i32, K =
-    min(topk, S), -1 where the query has fewer candidates; ``mask`` (B, T,
-    S) bool or None). A candidate is a position <= the query's."""
+    min(topk, S), ascending, -1 where the query has fewer candidates;
+    ``mask`` (B, T, S) bool or None). A candidate is a position <= the
+    query's; ``n_keys`` (traced, or None) promises that none stands at or
+    behind it. No sort: the K-th largest score by bisection, the mask from
+    it, the indices counted out of the mask."""
     S = score.shape[-1]
+    k = min(topk, S)
     causal = jnp.arange(S, dtype=jnp.int32)[None, None] <= q_pos[..., None]
-    masked = jnp.where(causal, score, -jnp.inf)
-    vals, idx = lax.top_k(masked, min(topk, S))
-    idx = jnp.where(vals > -jnp.inf, idx, -1).astype(jnp.int32)
-    if not want_mask:
-        return idx, None
-    # the same set as a mask: above the K-th largest, and of those equal to
-    # it the lowest positions, as many as top_k took (its order among
-    # equals; a ReLU makes exact ties, all heads at 0)
-    thr = vals[..., -1:]
-    above, tied = masked > thr, (masked == thr) & causal
-    room = min(topk, S) - jnp.sum(above, axis=-1, keepdims=True)
-    return idx, above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
+    with jax.named_scope("dsa_select"):
+        key = _order_keys(score, causal)
+        thr = _kth_largest(key, k, n_keys)
+        # above the K-th largest, and of those equal to it the lowest
+        # positions, as many as there is room for (``lax.top_k``'s order
+        # among equals; a ReLU makes exact ties, all heads at 0)
+        above, tied = key > thr, (key == thr) & causal
+        room = k - jnp.sum(above, axis=-1, keepdims=True)
+        # the running sum over S is three passes of XLA's scan: taken only
+        # where some row has more ties than room
+        mask = lax.cond(
+            jnp.any(jnp.sum(tied, axis=-1, keepdims=True) > room),
+            lambda: above | (tied & (jnp.cumsum(tied, axis=-1) <= room)),
+            lambda: above | tied)
+        return _positions(mask, k), mask if want_mask else None
 
 
 # ------------------------------------------------------------ full forward

@@ -1146,8 +1146,9 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
     what else stands on the chip (the slots' cache beside a chunk, the
     batch-1 prefill cache beside the step) stays under 15.0 GiB — ISSUE 51's
     limit, which 12 slots break; no (64, 512, 32 768) float32 score array
-    stands in a chunk; the step holds four calls of the sparse read (one a
-    run of layers), two of the score and of the key append, and NO other
+    stands in a chunk, and no program sorts its scores (512 x 32 768 a chunk,
+    10 x 32 768 the step); the step holds four calls of the sparse read (one
+    a run of layers), two of the score and of the key append, and NO other
     operation touches the latents' buffer: a layer's live latents are read
     by no one, the selected rows by the kernel's own DMAs."""
     import time
@@ -1213,6 +1214,11 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
         "sparse_mla_decode_attention", "dsa_index_score", "mla_cache_append",
         "mla_decode_attention", "moe_experts_up")}
     step = program == "slot step"
+    # a selection is a threshold and counts (PR 52): no sort of a chunk's
+    # 512 x 32 768 scores, none of the step's 10 x 32 768
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    assert not any(re.search(r"\[(1,)?(512|10),(1,)?32768\]", ln)
+                   for ln in sorts), sorts
     assert count == {
         "sparse_mla_decode_attention": 4 if step else 0,
         "dsa_index_score": 2 if step else 0,

@@ -177,6 +177,99 @@ def test_a_shared_layer_reads_the_full_layer_s_selection(tiny, monkeypatch):
                    for seg in model.segment_params(params["layers"]))
 
 
+# ------------------------------------------------- the selection, no sort
+def sorted_select(score, q_pos, topk):
+    """``dsa.select`` as it stood until PR 52, kept as the oracle: one
+    ``lax.top_k`` (a sort) and the mask from its K-th value."""
+    S = score.shape[-1]
+    causal = jnp.arange(S, dtype=jnp.int32)[None, None] <= q_pos[..., None]
+    masked = jnp.where(causal, score, -jnp.inf)
+    vals, idx = jax.lax.top_k(masked, min(topk, S))
+    idx = jnp.where(vals > -jnp.inf, idx, -1).astype(jnp.int32)
+    thr = vals[..., -1:]
+    above, tied = masked > thr, (masked == thr) & causal
+    room = min(topk, S) - jnp.sum(above, axis=-1, keepdims=True)
+    return idx, above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
+
+
+def _scores(rng, B, T, S):
+    return rng.standard_normal((B, T, S)).astype(np.float32)
+
+
+def _relu(zeros):               # what an indexer gives: many exact zeros
+    return lambda rng, *s: np.where(rng.random(s) < zeros, 0.0,
+                                    np.abs(_scores(rng, *s)))
+
+
+def _signed_zeros(rng, B, T, S):
+    x = np.where(rng.random((B, T, S)) < 0.5, -0.0, 0.0).astype(np.float32)
+    return np.where(rng.random((B, T, S)) < 0.1, _scores(rng, B, T, S), x)
+
+
+# name: (scores, S, topk, the queries' positions (None: any), n_keys)
+SELECTIONS = {
+    "fewer candidates than K": (_scores, 300, 64, (0, 40), None),
+    "K over S": (_scores, 70, 100, None, None),
+    "K is S": (_scores, 128, 128, None, None),
+    "ties at the threshold": (_relu(0.95), 300, 32, (200, 300), None),
+    "rows of zeros": (lambda rng, *s: np.zeros(s, np.float32), 300, 16,
+                      None, None),
+    "-0.0 beside +0.0": (_signed_zeros, 260, 24, (100, 260), None),
+    "negative scores": (lambda rng, *s: -np.abs(_scores(rng, *s)) - 1.0, 300,
+                        16, None, None),
+    "all candidates equal": (lambda rng, *s: np.full(s, -2.5, np.float32),
+                             256, 16, None, None),
+    "a walk over the live blocks": (_relu(0.999), 4 * dsa.SELECT_BLOCK, 48,
+                                    (dsa.SELECT_BLOCK + 7,
+                                     2 * dsa.SELECT_BLOCK + 100),
+                                    2 * dsa.SELECT_BLOCK + 100),
+    "a walk that ends under K": (_scores, 2 * dsa.SELECT_BLOCK, 64, (0, 50),
+                                 50),
+}
+
+
+@pytest.mark.parametrize("T", [1, 5], ids=["a step", "a chunk"])
+@pytest.mark.parametrize("case", SELECTIONS)
+def test_the_selection_is_the_sort_s(case, T):
+    """The threshold found by bisection gives the mask one ``lax.top_k`` and
+    the tie rule gave, bit for bit; ``idx`` holds the mask's positions and
+    no other, ascending, -1 behind them: min(K, candidates) a row."""
+    make, S, topk, where, n_keys = SELECTIONS[case]
+    B = 2
+    rng = np.random.default_rng(len(case) + T)
+    score = jnp.asarray(make(rng, B, T, S))
+    lo, hi = where or (0, S)
+    q_pos = jnp.asarray(rng.integers(lo, hi, (B, T)), jnp.int32)
+    K = min(topk, S)
+    idx, mask = jax.jit(lambda s, p: dsa.select(
+        s, p, topk, n_keys=None if n_keys is None else jnp.int32(n_keys)))(
+            score, q_pos)
+    _, want = sorted_select(score, q_pos, topk)
+    assert idx.shape == (B, T, K) and idx.dtype == jnp.int32
+    assert np.array_equal(np.asarray(mask), np.asarray(want))
+    for row, m, at in zip(np.asarray(idx).reshape(-1, K),
+                          np.asarray(mask).reshape(-1, S),
+                          np.asarray(q_pos).reshape(-1)):
+        held = np.flatnonzero(m)
+        assert len(held) == min(K, at + 1)
+        assert np.array_equal(row[:len(held)], held) \
+            and (row[len(held):] == -1).all()
+
+
+def test_a_step_wants_no_mask_and_gets_the_same_indices():
+    """``want_mask=False`` (the T == 1 step, whose kernel fetches by index):
+    the same path, the same ``idx``, no mask handed back."""
+    rng = np.random.default_rng(7)
+    score = jnp.asarray(_relu(0.6)(rng, 3, 1, 300) + 0.5)
+    q_pos = jnp.asarray([[10], [150], [299]], jnp.int32)
+    idx, none = dsa.select(score, q_pos, 32, want_mask=False)
+    want, _ = dsa.select(score, q_pos, 32)
+    assert none is None and np.array_equal(np.asarray(idx), np.asarray(want))
+    by_sort, _ = sorted_select(score, q_pos, 32)
+    assert np.array_equal(np.sort(np.asarray(by_sort), -1),
+                          np.sort(np.asarray(idx), -1))
+
+
 # -------------------------------------------------------------- the controls
 @pytest.mark.parametrize("name", CONTROLS)
 def test_every_control_fails(tiny, name):
