@@ -29,6 +29,19 @@ timeline, beside the device's) and the process's capture ring, which
 :func:`captured` hands out afterwards. With neither, a site costs one
 ``TraceAnnotation.is_enabled()``.
 
+One thing is ALWAYS on beside them: a serving iteration's row
+(:class:`Iteration`, :func:`iterations`, :func:`explain`): wall, the
+thread's CPU seconds, the seconds inside its blocking waits on the device,
+the collector's passes, compiles and what it dispatched, one fixed-width
+row a ``step()`` in a preallocated ring of the process. It costs four clock
+reads, a bracket around each wait and one row assignment an iteration:
+2.6 us where the thread's CPU clock is cheap; where that clock is a system
+call into a sandbox's kernel (the chip machine's host: 6 us a read in a
+bare loop, 30-40 us behind a wait, in ticks of 10 ms) its two reads are the
+whole cost, 15.7 us in a bare loop and 60-90 us in the serving loop
+(``examples/iteration_record_microbench.py``; PERF.md, PR 53). A live span
+costs ~5.8 us for each of an iteration's fifteen sites.
+
 Three kinds are the process's and not an iteration's: ``COMPILE`` (a
 program traced, lowered, or compiled or loaded by the backend; made here
 from ``jax.monitoring``'s own events, which carry the function's name),
@@ -46,12 +59,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import re
 import threading
 import time
 from collections import deque
 from typing import Callable, Optional
 
+import numpy as np
 from jax import monitoring as _monitoring
 from jax.profiler import TraceAnnotation
 
@@ -412,6 +427,272 @@ def span(ring: Optional[SpanRecorder], clock: Callable[[], float],
                  fields)
 
 
+# ------------------------------------------------- the iteration's record
+# One row a ``ServingEngine.step()``, always (spans on or off, capture or
+# none), so that a long iteration can say why after the fact: a window of
+# 40 s holds a handful of them and a traced tail of 4 s most often none.
+# Process-wide, as the capture and lifecycle rings are (a benchmark's reducer
+# has no handle on the engine); the newest rows win. On the lifecycle
+# ring's clock (:func:`now`) whatever clock the engine was given: the
+# fake-clock suites count the reads of theirs.
+ROW = np.dtype([
+    ("step", "i8"),        # the iteration's number
+    ("t0", "f8"),          # beside the ``srv.step`` span's own stamps: one
+    ("t1", "f8"),          # clock read inside them
+    ("cpu_s", "f8"),       # the serving thread's CPU seconds
+                           # (``time.thread_time``): wall less this is time
+                           # the thread did not run
+    ("wait_s", "f8"),      # inside the blocking waits on the device (the
+                           # fused read-back, the first token's read, the
+                           # seat's wait in admission, speculation's read,
+                           # the demote drain's)
+    ("gc_s", "f8"),        # the collector's passes that fell inside it,
+    ("gc_gen", "i1"),      # and the highest generation among them (-1: none)
+    ("compiles", "i2"),    # functions traced by the process + programs the
+                           # engine built inside it
+    ("chunks", "i2"),      # dispatched: chunk programs that are not final,
+    ("finals", "i2"),      # final ones,
+    ("seats", "i2"),       # inserts,
+    ("stepped", "i1"),     # a decode step (0/1),
+    ("ahead", "i1"),       # which went out with one in flight (0/1)
+    ("read_step", "i1"),   # read: a step's fused read-back (0/1),
+    ("read_first", "i1"),  # a first token (0/1)
+    ("slots", "i4"),       # running at the step's dispatch (at the end of
+                           # an iteration that dispatched none)
+    ("queue", "i4"),       # waiting at the end
+    ("tokens", "i4"),      # booked to requests by this call
+])
+ROWS = 1 << 14             # ~57 s of the steady chat cell's 3.47 ms
+_rows = np.zeros(ROWS, ROW)
+_rows_written = 0          # ever; the newest is at (_rows_written - 1) % ROWS
+_thread_time = time.thread_time
+
+# The collector, from one pair of entries in ``gc.callbacks``: seconds in
+# passes, and the passes weighted by generation (1 a pass of generation 0,
+# 2**16 one of 1, 2**32 one of 2), so that one difference over an iteration
+# says the highest generation that ran in it. The callbacks only add.
+_gc_s = 0.0
+_gc_weighted = 0
+_gc_began = 0.0
+_gc_counters: list = []    # Host/gc_s, Host/gc_passes_gen2 of the registry
+CAUSES = ("compile", "gc", "prefill", "on_cpu", "device_wait", "off_cpu")
+PROGRAM = ("compile", "gc", "on_cpu")     # what a change can take away
+MACHINE = ("device_wait", "off_cpu")      # what none moves
+
+
+def _gc_starts(phase: str, info: dict) -> None:
+    if phase == "start":
+        global _gc_began
+        _gc_began = now()
+
+
+def _gc_stops(phase: str, info: dict) -> None:
+    if phase == "stop":
+        global _gc_s, _gc_weighted
+        seconds = now() - _gc_began
+        _gc_s += seconds
+        _gc_weighted += 1 << (16 * info["generation"])
+        _gc_counters[0].inc(seconds)
+        if info["generation"] == 2:
+            _gc_counters[1].inc()
+
+
+_gc_starts.of_the_seam = _gc_stops.of_the_seam = True
+
+
+def _watch_gc() -> None:
+    """The start stamp FIRST in ``gc.callbacks`` and the stop stamp LAST, so
+    that what other callbacks do at both ends of a pass lies inside the
+    pass: JAX hangs one there when it is imported (``_xla_gc_callback``:
+    ``collect_garbage()`` at the start and the stop of every pass of every
+    generation), and that is the collector's cost to the loop too. Whatever
+    pair an earlier import of this module left is taken out first; the
+    other entries keep their places."""
+    global _gc_counters
+    _gc_counters = [get_registry().counter(name)     # readable at 0
+                    for name in ("Host/gc_s", "Host/gc_passes_gen2")]
+    gc.callbacks[:] = [cb for cb in gc.callbacks
+                       if not getattr(cb, "of_the_seam", False)]
+    gc.callbacks.insert(0, _gc_starts)
+    gc.callbacks.append(_gc_stops)
+
+
+class Iteration:
+    """One engine's open iteration: what ``step()`` counts while it runs,
+    and, closed, one row of the ring. An engine keeps one and opens it
+    again every iteration, so nothing is allocated but the row's tuple."""
+
+    __slots__ = ("step", "t0", "t1", "wait_s", "chunks", "finals", "seats",
+                 "stepped", "ahead", "read_step", "read_first", "slots",
+                 "_cpu0", "_cpu1", "_gc_s", "_gc_weighted", "_built",
+                 "_emitted")
+
+    def __init__(self):
+        self.open(0, 0, 0)
+
+    def open(self, step: int, built: int, emitted: int) -> None:
+        """Right behind the ``srv.step`` span's first stamp. ``built``: the
+        engine's ``compiles``; ``emitted``: its count of tokens booked."""
+        self.t0 = _LIFECYCLE.clock()
+        self.step = step
+        self.wait_s = 0.0
+        self.chunks = self.finals = self.seats = self.stepped = self.ahead \
+            = self.read_step = self.read_first = self.slots = 0
+        self._built, self._emitted = built + _traces, emitted
+        self._gc_s, self._gc_weighted = _gc_s, _gc_weighted
+        self._cpu0 = _thread_time()
+
+    def wait(self, fetch, on):
+        """``fetch(on)``, a blocking wait on the device
+        (``jax.device_get``, ``jax.block_until_ready``), timed into
+        ``wait_s``."""
+        t = _LIFECYCLE.clock()
+        out = fetch(on)
+        self.wait_s += _LIFECYCLE.clock() - t
+        return out
+
+    def close(self) -> None:
+        """Right before the ``srv.step`` span's last stamp."""
+        self._cpu1 = _thread_time()
+        self.t1 = _LIFECYCLE.clock()
+
+    def _row(self, built: int, running: int, queue: int,
+             emitted: int) -> tuple:
+        passes = _gc_weighted - self._gc_weighted
+        return (self.step, self.t0, self.t1, self._cpu1 - self._cpu0,
+                self.wait_s, _gc_s - self._gc_s,
+                -1 if not passes else 2 if passes >> 32
+                else 1 if passes >> 16 else 0,
+                min(built + _traces - self._built, 32767),
+                self.chunks, self.finals, self.seats, self.stepped,
+                self.ahead, self.read_step, self.read_first,
+                self.slots if self.stepped else running, queue,
+                emitted - self._emitted + self.read_first)
+
+    def write(self, built: int, running: int, queue: int,
+              emitted: int) -> None:
+        """The closed iteration's row into the ring, outside the span: what
+        the assignment costs is not the iteration's."""
+        global _rows_written
+        n = _rows_written
+        _rows[n % ROWS] = self._row(built, running, queue, emitted)
+        _rows_written = n + 1
+
+    def cause(self, built: int) -> str:
+        """Why the open iteration has been long so far, by :func:`explain`'s
+        precedence against the ring's rows: for a note written inside it
+        (the watchdog's). Stamps the row as of now; ``close`` does again."""
+        self.close()
+        row = np.array([self._row(built, 0, 0, self._emitted)], ROW)
+        rows = np.concatenate([iterations(), row])
+        return _causes(rows, [len(rows) - 1])[0]
+
+
+def iterations(t0: Optional[float] = None,
+               t1: Optional[float] = None) -> np.ndarray:
+    """The ring's rows, oldest first, as a structured array (:data:`ROW`;
+    a copy): those that began in ``t0 <= row.t0 <= t1`` where given, on
+    :func:`now`'s clock, which is a benchmark window's too."""
+    n = _rows_written
+    rows = _rows[:n].copy() if n <= ROWS \
+        else np.roll(_rows, -(n % ROWS))
+    if t0 is not None:
+        rows = rows[rows["t0"] >= t0]
+    if t1 is not None:
+        rows = rows[rows["t0"] <= t1]
+    return rows
+
+
+def _causes(rows: np.ndarray, which) -> list:
+    """One of :data:`CAUSES` for each row of ``rows`` at an index in
+    ``which``, each decided by the row alone against the rows' medians
+    (:func:`explain` has the precedence)."""
+    wall = rows["t1"] - rows["t0"]
+    own = rows["cpu_s"] - rows["gc_s"]
+    median, median_own, median_wait = (
+        float(np.median(v)) for v in (wall, own, rows["wait_s"]))
+    # the thread clock's grain as the rows show it, the smallest step
+    # between two readings: nanoseconds as a rule, 10 ms where the kernel
+    # charges CPU time by the tick (the chip machine's host, PERF.md PR 53);
+    # a reading says no more than that
+    steps = np.diff(np.unique(np.round(rows["cpu_s"], 9)))
+    grain = float(steps.min()) if len(steps) else 0.0
+    fed = rows["chunks"] + rows["finals"] > 0
+    fed[1:] |= fed[:-1].copy()
+    # what a wait behind a chunk comes to, nineteen times in twenty
+    usual = float(np.percentile(rows["wait_s"][fed], 95)) if fed.any() \
+        else 0.0
+    out = []
+    for i in which:
+        half, r = (wall[i] - median) / 2, rows[i]
+        out.append(
+            "compile" if r["compiles"] > 0
+            else "gc" if r["gc_s"] >= half
+            else "prefill" if fed[i] and half <= r["wait_s"] < usual + half
+            else "on_cpu" if own[i] - median_own - grain >= half
+            else "device_wait" if r["wait_s"] - median_wait >= half
+            else "off_cpu")
+    return out
+
+
+def explain(rows: np.ndarray, over: float = 2.0) -> dict:
+    """Every row longer than ``over`` x the rows' median in exactly one
+    cause, whole. In this order, each by the row alone; the excess is the
+    row's wall less the median:
+
+    1. ``compile``      ``compiles`` > 0;
+    2. ``gc``           ``gc_s`` >= half the excess;
+    3. ``prefill``      the wait was for more than a step: a chunk went out
+                        in this row or the one before it (with a step in
+                        flight the chunk behind it is read an iteration
+                        later) and ``wait_s`` >= half the excess, but not
+                        half the excess over what nineteen in twenty of the
+                        waits behind a chunk come to (a freeze inside such a
+                        wait is not the chunk's). Sound;
+    4. ``on_cpu``       ``cpu_s - gc_s`` over its own median by >= half
+                        the excess and the thread clock's grain: the
+                        loop's own Python;
+    5. ``device_wait``  ``wait_s`` over its median by >= half the excess
+                        with nothing sound in front to wait for: the
+                        device or the runtime stood, or the thread inside
+                        the wait;
+    6. ``off_cpu``      the rest: outside every wait, no CPU, no pass: the
+                        thread was not running.
+
+    ``program_ms`` = compile + gc + on_cpu (what a change can take away),
+    ``machine_ms`` = device_wait + off_cpu; with ``prefill`` they add up
+    to ``long_ms``. ``longest``: the five longest of them, every field."""
+    out = {"rows": len(rows), "over": over, "median_ms": 0.0, "long": 0,
+           "long_ms": 0.0, "program_ms": 0.0, "machine_ms": 0.0,
+           "causes": {c: {"ms": 0.0, "count": 0} for c in CAUSES},
+           "longest": []}
+    if not len(rows):
+        return out
+    wall = rows["t1"] - rows["t0"]
+    median = float(np.median(wall))
+    long = np.nonzero(wall > over * median)[0]
+    why = _causes(rows, long)
+    for i, cause in zip(long, why):
+        out["causes"][cause]["ms"] += 1e3 * float(wall[i])
+        out["causes"][cause]["count"] += 1
+    out["median_ms"], out["long"] = 1e3 * median, len(long)
+    for key, names in (("long_ms", CAUSES), ("program_ms", PROGRAM),
+                       ("machine_ms", MACHINE)):
+        out[key] = sum(out["causes"][c]["ms"] for c in names)
+    for k in np.argsort(-wall[long])[:5]:
+        i = long[k]
+        out["longest"].append({
+            **{name: rows[name][i].item() for name in ROW.names},
+            "ms": 1e3 * float(wall[i]), "cause": why[k]})
+    return out
+
+
+def long_iterations() -> dict:
+    """:func:`explain` of the ring as it stands: a flight recorder's
+    ``long_iterations`` (a watchdog dump then says which cause fired it)."""
+    return explain(iterations())
+
+
 # ------------------------------------------------------- the process's life
 def timed_init(phase: str):
     """Decorator for an engine's ``__init__`` (or any build that happens
@@ -560,3 +841,4 @@ def _listen() -> None:
 
 
 _listen()
+_watch_gc()

@@ -256,7 +256,8 @@ class ServingEngine:
             self.flight = FlightRecorder(
                 self.cfg.flight_dir, spans=self.spans,
                 snapshots={"serving": self.metrics_snapshot,
-                           "health": self.health},
+                           "health": self.health,
+                           "long_iterations": _spans.long_iterations},
                 max_dumps=self.cfg.flight_max_dumps,
                 clock=self.stats.clock, job_name="serving",
                 registry=self.stats.registry)
@@ -526,6 +527,8 @@ class ServingEngine:
         self._last_step_s = 0.0
         self._last_stall_iter: Optional[int] = None
         self._iterations = 0
+        # the open iteration's row of the seam's record (always on)
+        self._row = _spans.Iteration()
         # _count_retraces: the process's trace count at its last walk of
         # the programs, and when that was
         self._traces_seen = -1
@@ -835,7 +838,8 @@ class ServingEngine:
             self._spec_verify_impl, donate_argnums=(1,)))
         m_dev, ok_dev, self._state = ver(self.engine.params, self._state,
                                          jnp.asarray(drafts))
-        m, vok = jax.device_get((m_dev, ok_dev))
+        m, vok = self._row.wait(jax.device_get, (m_dev, ok_dev))
+        self._row.read_step = 1
         eos = self._eos
         B = self.cfg.slots
         # rows: active, new_len, new_tok, new_done, new_left — one packed
@@ -1063,7 +1067,15 @@ class ServingEngine:
         (``_serial``) reads its step at once, and its seat follows its
         ``prefill_readback`` as it always did."""
         with self._span(_spans.SRV_STEP, step=self._iterations):
-            return self._iterate()
+            # the iteration's row (observability/spans.py, always on): its
+            # stamps lie one clock read inside the span's own
+            self._row.open(self._iterations, self.compiles,
+                           self._decode_emitted)
+            done = self._iterate()
+            self._row.close()
+        self._row.write(self.compiles, len(self.sched.running),
+                        self.sched.queue_depth, self._decode_emitted)
+        return done
 
     def _span(self, kind, **fields):
         """The seam (observability/spans.py) on this engine's ring and
@@ -1194,6 +1206,8 @@ class ServingEngine:
             if self.sched.running:
                 t0 = self.stats.clock()
                 plan = fl = None
+                self._row.stepped = 1
+                self._row.slots = len(self.sched.running)
                 with self._span(_spans.SRV_DECODE_DISPATCH, step=n_it):
                     if chaos is not None:
                         chaos.maybe_hang(it)
@@ -1308,7 +1322,7 @@ class ServingEngine:
             # a running row appends, then attends; the others stay
             self._slot_len[self._slot_len > 0] += 1
             lens = self._slot_len.copy()
-        ahead = int(self._inflight is not None)
+        self._row.ahead = ahead = int(self._inflight is not None)
         if ahead:
             self.stats.registry.counter("Serve/decode_steps_ahead").inc()
         fl = _Flight(step=n_it, t0=t0, rows=dict(self.sched.running),
@@ -1336,9 +1350,10 @@ class ServingEngine:
             # third
             pending = fl.chunk_stats
             tapped = fl.chunk_routing if self.routing_log is not None else []
-            toks, dones, oks, *moe = jax.device_get(
-                (*self._fetched(fl.read), *(st for _, st, _ in pending),
-                 *(r for _, _, r, _ in tapped)))
+            toks, dones, oks, *moe = self._row.wait(jax.device_get, (
+                *self._fetched(fl.read), *(st for _, st, _ in pending),
+                *(r for _, _, r, _ in tapped)))
+            self._row.read_step = 1
             # a slot the device retired and the host seated again, a row
             # the host retired meanwhile: not this step's to book
             rows = {slot: req for slot, req in fl.rows.items()
@@ -1426,6 +1441,7 @@ class ServingEngine:
                 self.flight.note("watchdog_stall", t=t1,
                                  step_s=self._last_step_s,
                                  threshold_s=wd,
+                                 cause=self._row.cause(self.compiles),
                                  iteration=self._iterations)
                 if new_episode:
                     self.flight.dump("watchdog_stall")
@@ -1438,6 +1454,7 @@ class ServingEngine:
             if self.flight is not None:
                 self.flight.note("step_time_regression", t=t1,
                                  step_s=self._last_step_s,
+                                 cause=self._row.cause(self.compiles),
                                  median_s=med, mad_s=mad,
                                  iteration=self._iterations)
 
@@ -1498,7 +1515,7 @@ class ServingEngine:
             # meanwhile, so the wait is short and costs the device nothing
             inserted, old = self._seated
             self._seated = None
-            jax.block_until_ready(inserted)
+            self._row.wait(jax.block_until_ready, inserted)
             for buf in jax.tree_util.tree_leaves(old):
                 buf.delete()
         # the batch-1 cache and the request's key from ONE program: the
@@ -1649,6 +1666,10 @@ class ServingEngine:
                           np.int32(ch.last_index), np.int32(ch.true_len),
                           rng)
         self._ahead = ch, out, chunk_span
+        if ch.final:
+            self._row.finals += 1
+        else:
+            self._row.chunks += 1
         # the program took the lane's cache (donated)
         self._prefill = (req, plan, idx, None, rng)
 
@@ -1695,7 +1716,8 @@ class ServingEngine:
         engine, a hand-off) the seat follows the read, as it always did."""
         (req, pf), self._first = self._first, None
         with self._span(_spans.SRV_PREFILL_READBACK, step=n_it):
-            tok, done = jax.device_get((pf.tok, pf.done))
+            tok, done = self._row.wait(jax.device_get, (pf.tok, pf.done))
+            self._row.read_first = 1
             first_tok = int(tok[0])
             ended = req.max_new == 1 or bool(done[0])
         if req.slot < 0:
@@ -1756,6 +1778,7 @@ class ServingEngine:
             if self._slot_len is not None:
                 self._slot_len[slot] = req.prompt_len
         self._seated = inserted, pf.cache
+        self._row.seats += 1
         if self.on_placed is not None and first_tok is not None:
             # disaggregated handoff: the fleet may export the freshly
             # seated request and release the slot before this very
@@ -1901,7 +1924,7 @@ class ServingEngine:
         pressured = False
         for out, batch, pressure in pending:
             t0 = self.stats.clock() if pressure else None
-            tiles = jax.device_get(out)
+            tiles = self._row.wait(jax.device_get, out)
             if pressure:
                 self.demote_wait_s += max(0.0, self.stats.clock() - t0)
                 pressured = True

@@ -58,7 +58,8 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
     "benchmark.tests.test_falcon_h1", "benchmark.tests.test_glm_moe_dsa",
-    "benchmark.tests.test_program_lifecycle")
+    "benchmark.tests.test_program_lifecycle",
+    "benchmark.tests.test_program_iterations")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
@@ -67,6 +68,7 @@ from benchmark.tests.test_glm_moe_dsa import *  # noqa: E402,F401,F403
 from benchmark.tests.test_mimo_v2_flash import *  # noqa: E402,F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: E402,F401,F403
 from benchmark.tests.test_ouro import *  # noqa: E402,F401,F403
+from benchmark.tests.test_program_iterations import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_lifecycle import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reduce import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reducers import *  # noqa: E402,F401,F403
