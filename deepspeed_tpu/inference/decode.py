@@ -189,6 +189,12 @@ def forward_with_cache(model, params, input_ids, cache: KVCache,
             # counted where a step program is built (a trace, not a call):
             # 0 says every step program of this process runs the kernels
             get_registry().counter("Serve/decode_fallback_builds").inc()
+    if T > 1:
+        # the step's gate keeps T == 1; a kind with a kernel for a chunk's
+        # attention answers for it
+        fused = bool(planes) and kind.chunk_fused(
+            flash_decode, T, planes[0].shape[-1], x.dtype,
+            *(p.dtype for p in planes))
     # a right-padded final chunk tells a state that is never rewound how
     # many of its tokens are real
     valid = last_index + 1 \

@@ -43,6 +43,9 @@ class Kind:
     # reasons are joined, feature -> reason; why it has no pages
     what, sep, refuses = "", "; ", {}
     contiguous_only = ""
+    # the serving engine's, set once it is built: whether its programs take
+    # the kernels (``flash_decode``) and the positions a slot's cache holds
+    flash, max_len = False, 0
 
     def __init__(self, cfg, slots: int = 1, dtype=None, params=None):
         self.cfg, self.slots, self.dtype = cfg, slots, dtype
@@ -96,6 +99,14 @@ class Kind:
         gate's answer. Returns (x, cache, (counters, routing) of the expert
         layers or None, a looped trunk's passes or None)."""
         raise NotImplementedError
+
+    def chunk_fused(self, flash_decode: bool, T: int, max_len: int,
+                    *dtypes) -> bool:
+        """What :meth:`forward` is told as ``fused`` for T > 1 tokens over a
+        cache of ``max_len`` positions and ``dtypes`` (the activations',
+        the planes'): whether a kernel of the kind's own attends a chunk.
+        Asked where a program is traced; none has one but says so."""
+        return False
 
     def deferred_rows(self, dtype=None) -> int:
         """The positions a slot's newest K/V wait in a tail of rows before

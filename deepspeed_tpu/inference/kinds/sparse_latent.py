@@ -17,10 +17,15 @@ layer score the slot's live keys (``dsa_index_score``) and select
 fetch the selected positions' latents and attend absorbed
 (``sparse_mla_decode_attention``). An ``s`` layer takes the selection the
 carry holds. T > 1 (a chunk, a solo prefill) writes with XLA's update,
-selects likewise and walks the live blocks with the selection as a mask
-(``mla.attend_expanded(selected=)``: exact, the work of dense attention),
-in blocks of :data:`QUERY_BLOCK` queries so that no (T, max_len) float32 array
-stands for T in the thousands.
+selects likewise and walks the live blocks with the selection as a mask:
+exact, the work of dense attention, in blocks of :data:`QUERY_BLOCK` queries
+so that no (T, max_len) float32 array stands for T in the thousands. Where
+the step's kernels run and the kernel tiles the widths
+(:meth:`SparseLatent.chunk_kernel`) that walk is ONE kernel a layer,
+``sparse_mla_chunk_attention``, whose scores, probabilities and accumulators
+stay in VMEM; everywhere else ``mla.attend_expanded(selected=)``, the same
+mathematics in plain ``jnp`` (``Serve/chunk_attention_fallback_builds``
+counts the chunk programs traced onto it with the kernels on).
 """
 
 from collections import namedtuple
@@ -36,7 +41,7 @@ from ...models.transformer import _norm
 from ...ops import mla_attention
 from ...ops import sparse_mla_attention as sparse
 from .base import IN_POOL, MOVES_PAGES, Kind, held_counts, split_banks
-from .steps import _dense_append, _out_ffn
+from .steps import _decode_kernel_ok, _dense_append, _out_ffn
 
 SparseLatentCache = namedtuple("SparseLatentCache", "ik c length")
 QUERY_BLOCK = 512
@@ -80,6 +85,30 @@ class SparseLatent(Kind):
     @staticmethod
     def matches(cfg) -> bool:
         return bool(getattr(cfg, "index_pattern", ""))
+
+    def chunk_kernel(self, flash_decode, T, max_len, *dtypes) -> bool:
+        """Whether T > 1 queries over a cache of ``max_len`` attend in
+        ``sparse_mla_chunk_attention``: where the step's kernels would run
+        (``_decode_kernel_ok``: the switch, no float16, whole lane blocks)
+        and the kernel tiles the widths. Any T: the kernel pads a bucket's
+        queries to a tile, and more than :data:`QUERY_BLOCK` come to it in
+        blocks of that."""
+        cfg = self.cfg
+        return T > 1 and _decode_kernel_ok(flash_decode, 1, max_len, *dtypes) \
+            and sparse.chunk_kernel_fits(
+                max_len, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.kv_lora_rank, cfg.v_dim)
+
+    def chunk_fused(self, flash_decode, T, max_len, *dtypes) -> bool:
+        fused = self.chunk_kernel(flash_decode, T, max_len, *dtypes)
+        if flash_decode and not fused:
+            from ...observability.metrics import get_registry
+
+            # counted where a chunk's program is built (a trace, not a
+            # call), as Serve/decode_fallback_builds counts the step's
+            get_registry().counter(
+                "Serve/chunk_attention_fallback_builds").inc()
+        return fused
 
     def buffers(self, batch, max_len, dtype=None):
         cfg, dt = self.cfg, dtype or self.cfg.dtype
@@ -135,14 +164,16 @@ class SparseLatent(Kind):
             """An F layer's selection for the queries ``y`` at ``pos``."""
             qi, w = dsa.index_queries(cfg, y, mla.query_latent(cfg, y, p),
                                       ip, pos)
-            if fused:
+            if fused and T == 1:
                 score = sparse.index_scores(qi[:, 0], w[:, 0], ik, new_len,
                                             layer=full)
                 return dsa.select(score[:, None], pos, K, want_mask=False)
             keys = lax.dynamic_index_in_dim(ik, full, keepdims=False)
             live = None if per_slot else new_len
-            return dsa.select(dsa.scores(qi, w, keys, live), pos, K,
-                              want_mask=T > 1, n_keys=live)
+            idx, mask = dsa.select(dsa.scores(qi, w, keys, live), pos, K,
+                                   want_mask=T > 1, n_keys=live)
+            # the kernel's DMAs take bytes; the layers behind share them
+            return idx, mask.astype(jnp.int8) if fused and T > 1 else mask
 
         def read_block(c, layer):
             def read(j, blk):
@@ -188,11 +219,19 @@ class SparseLatent(Kind):
                 c = lax.dynamic_update_slice(
                     c, sparse.pack_rows(new, dt)[None],
                     (layer, 0, new_len - T, 0, 0))
-                o = blocks(
-                    lambda qn, qr, pos, mask: mla.attend_expanded(
-                        cfg, p, qn, qr, (read_block(c, layer), S), pos,
-                        new_len, selected=mask),
-                    q_nope, q_rope, positions, sel[1])
+                if fused:
+                    w = mla._wkv_b(cfg, p, dt)
+                    o = blocks(
+                        lambda qn, qr, keep: sparse.sparse_mla_chunk_attention(
+                            qn, qr, w, c, keep, new_len, layer=layer,
+                            rank=cfg.kv_lora_rank, scale=scale),
+                        q_nope, q_rope, sel[1])
+                else:
+                    o = blocks(
+                        lambda qn, qr, pos, mask: mla.attend_expanded(
+                            cfg, p, qn, qr, (read_block(c, layer), S), pos,
+                            new_len, selected=mask),
+                        q_nope, q_rope, positions, sel[1])
             else:
                 q = mla.absorb_q(cfg, p, q_nope, q_rope)
                 if fused:
@@ -222,7 +261,7 @@ class SparseLatent(Kind):
             sel = jnp.zeros((B, K), jnp.int32)
         else:
             sel = (jnp.zeros((B, T, K), jnp.int32),
-                   jnp.zeros((B, T, S), bool))
+                   jnp.zeros((B, T, S), jnp.int8 if fused else bool))
         carry = (x, cache.c, cache.ik, sel)
         counters, routing, picks = [], [], []
         for seg, at, n, kind, first, full in dsa.runs(cfg):
@@ -277,8 +316,12 @@ class SparseLatent(Kind):
     def chunk_meta(self, chunk):
         real = chunk.last_index + 1 if chunk.final else chunk.size
         meta = self._dsa(chunk.start + 1 + np.arange(real))
+        dt = self.dtype or self.cfg.dtype
         return {"dsa_selected_over_live": meta["dsa_selected_over_live"],
-                "cache_bytes_per_token": self.token_bytes}
+                "cache_bytes_per_token": self.token_bytes,
+                "attn_live_keys": chunk.start + chunk.size,
+                "attn_kernel": self.chunk_kernel(self.flash, chunk.size,
+                                                 self.max_len, dt)}
 
     def step_meta(self, read, pending, lens, running):
         from ...observability.metrics import get_registry

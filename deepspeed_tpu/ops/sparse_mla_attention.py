@@ -32,6 +32,21 @@ multiplies nothing.
 score of a slot's live keys for the step, block by block, nothing behind the
 live length fetched. (In XLA the same product re-laid the whole key buffer
 out batch-minor, 2 GB of padding a step.)
+
+``sparse_mla_chunk_attention``: T > 1 queries (a chunk, a final bucket) over
+the live blocks of :data:`KEY_BLOCK` positions with the selection as a mask:
+the published, expanded form (``mla.attend_expanded(selected=)``), dense work
+under a mask. Grid (rows, groups of :data:`HEADS` heads). A program walks the
+live blocks in a loop of its own (two buffers: the next block's rows and its
+block of the mask come while this one is multiplied), and for each of its
+heads expands the block's ``k_nope`` and ``v`` with the head's slice of
+``wkv_b``, ``s = q . k`` in float32, the running softmax, ``acc += p . v``:
+scores, probabilities, the expanded block and the accumulators never leave
+VMEM, where XLA's walk carried ``acc`` (33 MB a layer), ``p`` and the
+expanded block through HBM every block. ``k_rope`` rides in the lanes that
+pad ``k_nope``'s last tile (the query's columns permuted to match), so one
+product of a whole number of tiles gives ``q_nope . k_nope + q_rope .
+k_rope``.
 """
 
 from __future__ import annotations
@@ -48,6 +63,11 @@ from .decode_attention import BIG_NEG, LANES
 from .mla_attention import _lengths, _refuse_mesh
 
 GROUP = 256          # positions a buffer holds (two of them in VMEM)
+KEY_BLOCK = 512      # keys a turn of a chunk's walk (a divisor of max_len)
+HEADS = 4            # heads a program of the chunk's kernel
+MASK_TILE = 32       # queries its int8 mask's sublane tile holds
+VMEM_LIMIT = 64 * 2 ** 20   # of a core's 128 MiB; the chunk's kernel holds ~20
+FLOOR = -2.0 ** 20   # under every score, over BIG_NEG: exp(BIG_NEG - FLOOR) = 0
 
 
 def einsum_f32(spec: str, a, b):
@@ -235,6 +255,13 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
     return o, cache
 
 
+def _key_block(S: int, block: int) -> int:
+    """The largest whole number of lane tiles <= ``block`` that divides
+    ``S`` positions, or all of them."""
+    return next((t for t in range(min(block, S) // LANES * LANES, 0, -LANES)
+                 if S % t == 0), S)
+
+
 def _score_kernel(len_ref, _, q_ref, w_ref, k_ref, o_ref, *, block: int):
     b, j = pl.program_id(0), pl.program_id(1)
     L = len_ref[b]
@@ -266,8 +293,7 @@ def index_scores(q, w, keys, length, *, layer, block: int = 2048,
     _refuse_mesh("dsa_index_score")
     B, H, D = q.shape
     S = keys.shape[3]
-    blk = next((t for t in range(min(block, S) // LANES * LANES, 0, -LANES)
-                if S % t == 0), S)
+    blk = _key_block(S, block)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lengths = jnp.minimum(_lengths(length, B), S)
@@ -294,6 +320,179 @@ def index_scores(q, w, keys, length, *, layer, block: int = 2048,
     )(lengths, jnp.asarray(layer, jnp.int32).reshape(1),
       q.astype(keys.dtype), w.astype(jnp.float32)[..., None], keys)
     return out[:, 0]
+
+
+def _chunk_kernel(nb_ref, layer_ref, q_ref, wk_ref, wv_ref, keep_ref,
+                  cache_ref, o_ref, rows, keep, sem, m_ref, l_ref, acc_ref, *,
+                  block: int, rank: int, rope: int, split: int, scale: float,
+                  dtype):
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    nb, layer = nb_ref[0], layer_ref[0]
+    heads, vd = acc_ref.shape[0], acc_ref.shape[-1]
+
+    # no score is under FLOOR and a masked one stands at BIG_NEG: a row with
+    # nothing to see yet keeps m = FLOOR and exp(BIG_NEG - FLOOR) is 0, so
+    # what is masked adds nothing without a select a score
+    m_ref[...] = jnp.full(m_ref.shape, FLOOR, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def copies(j, slot):
+        at = pl.ds(j * block, block)
+        # a position's row into a buffer of whole (8, 128) tiles: the DMA
+        # re-tiles it, where a reshape in VMEM cost a seventh of the kernel
+        return (pltpu.make_async_copy(cache_ref.at[layer, b, at, 0],
+                                      rows.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(keep_ref.at[b, :, at], keep.at[slot],
+                                      sem.at[1, slot]))
+
+    @pl.when(nb > 0)
+    def _():
+        for copy in copies(0, 0):
+            copy.start()
+
+    def body(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < nb)
+        def _():
+            for copy in copies(j + 1, 1 - slot):
+                copy.start()
+
+        for copy in copies(j, slot):
+            copy.wait()
+        lat = jnp.concatenate(_halves(rows[slot], dtype), axis=1)
+        c = lat[:, :rank].astype(dtype)
+        # k_rope in the first lanes of a tile of its own, 0 behind it
+        tail = lat[:, rank:rank + LANES].astype(jnp.float32)
+        if tail.shape[1] < LANES:
+            tail = jnp.pad(tail, ((0, 0), (0, LANES - tail.shape[1])))
+        tail = jnp.where(
+            lax.broadcasted_iota(jnp.int32, tail.shape, 1) < rope, tail, 0.0)
+        bias = (keep[slot].astype(jnp.float32) - 1.0) * -BIG_NEG
+        nt = (((1,), (1,)), ((), ()))
+        for h in range(heads):
+            k = jnp.dot(c, wk_ref[h], preferred_element_type=jnp.float32)
+            k = jnp.concatenate([k[:, :split], k[:, split:] + tail],
+                                axis=1).astype(dtype)
+            v = jnp.dot(c, wv_ref[h],
+                        preferred_element_type=jnp.float32).astype(dtype)
+            s = lax.dot_general(q_ref[h], k, nt,
+                                preferred_element_type=jnp.float32)
+            s = s * scale + bias
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p.astype(dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return 0
+
+    lax.fori_loop(0, nb, body, 0)
+    for h in range(heads):
+        o_ref[:, h * vd:(h + 1) * vd] = (
+            acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)).astype(o_ref.dtype)
+
+
+def _chunk_heads(H: int, vd: int, heads: int) -> int:
+    """Heads a program of the chunk's kernel takes: the most under ``heads``
+    that divide ``H`` and whose outputs fill whole lane tiles, or all."""
+    return next((n for n in range(min(heads, H), 0, -1)
+                 if H % n == 0 and n * vd % LANES == 0), H)
+
+
+def chunk_kernel_fits(max_len: int, nope: int, rope: int, rank: int,
+                      vd: int) -> bool:
+    """Whether :func:`sparse_mla_chunk_attention` takes a cache of
+    ``max_len`` at these widths: whole key blocks, ``k_rope`` beside what is
+    left of ``k_nope``'s last lane tile and, where Mosaic compiles it,
+    latents and values of whole lane tiles. (Any number of queries: they are
+    padded to the mask's tile.)"""
+    return (max_len % LANES == 0 and nope % LANES + rope <= LANES
+            and (jax.default_backend() != "tpu"
+                 or rank % LANES == vd % LANES == 0))
+
+
+def sparse_mla_chunk_attention(q_nope, q_rope, wkv_b, cache, keep, n_keys, *,
+                               layer, rank: int, scale: float,
+                               heads: int = HEADS, block: int = KEY_BLOCK,
+                               interpret: Optional[bool] = None):
+    """Causal attention of T queries over the selected of the first
+    ``n_keys`` positions, as published (``mla.attend_expanded(selected=)``).
+    ``q_nope`` (B, T, H, nope), ``q_rope`` (B, T, H, rope); ``wkv_b`` (rank,
+    H, nope + v): a head's ``[k_nope | v]`` out of ``c``; ``cache`` (L, B,
+    max_len, 1, words) (:func:`pack_rows`), ``layer`` (traced i32) the layer
+    read, the chunk's own latents written already; ``keep`` (B, T, max_len)
+    int8: 1 where a query attends a key — the selection's mask, which holds
+    no position behind the query's own; ``n_keys`` (traced i32): the live
+    length, no block behind it is fetched. A query that may see nothing
+    gives 0. Returns (B, T, H, v)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _refuse_mesh("sparse_mla_chunk_attention")
+    B, T, H, nope = q_nope.shape
+    rope = q_rope.shape[-1]
+    S, W = cache.shape[2], cache.shape[-1]
+    vd = wkv_b.shape[-1] - nope
+    dtype = q_nope.dtype
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    blk = _key_block(S, block)
+    hp = _chunk_heads(H, vd, heads)
+    # a key's columns: k_nope's whole tiles, then k_rope beside the rest of
+    # k_nope in one tile more; the query's and the weight's to match
+    split = nope // LANES * LANES
+    gap = LANES - rope - (nope - split)
+
+    def columns(a, mid):
+        return jnp.concatenate(
+            [a[..., :split], mid, a[..., split:],
+             jnp.zeros(a.shape[:-1] + (gap,), a.dtype)], axis=-1)
+
+    wk = columns(wkv_b[..., :nope].astype(dtype),
+                 jnp.zeros((rank, H, rope), dtype)).transpose(1, 0, 2)
+    wv = wkv_b[..., nope:].astype(dtype).transpose(1, 0, 2)
+    Tp = -(-T // MASK_TILE) * MASK_TILE
+    q = jnp.pad(columns(q_nope, q_rope).transpose(0, 2, 1, 3),
+                ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+    keep = jnp.pad(keep, ((0, 0), (0, Tp - T), (0, 0)))
+    nb = jnp.minimum((jnp.asarray(n_keys, jnp.int32) + blk - 1) // blk,
+                     S // blk)
+    P = split + LANES
+    out = pl.pallas_call(
+        partial(_chunk_kernel, block=blk, rank=rank, rope=rope, split=split,
+                scale=scale, dtype=dtype),
+        name="sparse_mla_chunk_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H // hp),
+            in_specs=[pl.BlockSpec((None, hp, Tp, P),
+                                   lambda b, g, *_: (b, g, 0, 0)),
+                      pl.BlockSpec((hp, rank, P), lambda b, g, *_: (g, 0, 0)),
+                      pl.BlockSpec((hp, rank, vd),
+                                   lambda b, g, *_: (g, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, Tp, hp * vd),
+                                   lambda b, g, *_: (b, 0, g)),
+            scratch_shapes=[pltpu.VMEM((2, blk, W), cache.dtype),
+                            pltpu.VMEM((2, Tp, blk), jnp.int8),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((hp, Tp, 1), jnp.float32),
+                            pltpu.VMEM((hp, Tp, 1), jnp.float32),
+                            pltpu.VMEM((hp, Tp, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Tp, H * vd), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+    )(nb.reshape(1), jnp.asarray(layer, jnp.int32).reshape(1), q, wk, wv,
+      keep.astype(jnp.int8), cache)
+    return out[:, :T].reshape(B, T, H, vd)
 
 
 def attend_selected(q, lat, idx, length, *, rank: int, scale: float):

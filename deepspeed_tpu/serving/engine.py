@@ -177,6 +177,7 @@ class ServingEngine:
                 f"flash_decode needs max_len to be a multiple of 128 "
                 f"(Pallas lane blocks), got {self.cfg.max_len} — round up "
                 "or set flash_decode=False")
+        self.kind.flash, self.kind.max_len = self._flash, self.cfg.max_len
         # expert counters of chunks dispatched since the last decode
         # read-back: (span, device stats, chunk size)
         self._chunk_stats: list = []
@@ -537,6 +538,7 @@ class ServingEngine:
         # counts in forward_with_cache, where a step program is traced)
         get_registry().counter("Serve/retraces")
         get_registry().counter("Serve/decode_fallback_builds")
+        get_registry().counter("Serve/chunk_attention_fallback_builds")
         with self.engine.mesh:
             if self._paged:
                 self._state = self._prog("init_slots", lambda: jax.jit(
@@ -674,8 +676,8 @@ class ServingEngine:
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
         _, cache, stats, routing, passes = forward_with_cache(
-            self.model, mat(params), ids, cache, with_stats=True,
-            with_routing=True, with_passes=True)
+            self.model, mat(params), ids, cache, flash_decode=self._flash,
+            with_stats=True, with_routing=True, with_passes=True)
         if self.kind.exit_pdf:
             return cache, _mean_exit_pdf(passes, ids.shape[1]), None
         return (cache, stats, routing) if self.kind.moe_stats else cache
@@ -688,9 +690,9 @@ class ServingEngine:
         cache = cache._replace(length=start)
         mat = self._mat if self._mat is not None else (lambda p: p)
         logits, cache, stats, routing, passes = forward_with_cache(
-            self.model, mat(params), ids, cache, last_token_head=True,
-            last_index=last_index, with_stats=True, with_routing=True,
-            with_passes=True)
+            self.model, mat(params), ids, cache, flash_decode=self._flash,
+            last_token_head=True, last_index=last_index, with_stats=True,
+            with_routing=True, with_passes=True)
         rng, sub = split_keys(rng)
         tok = self._sampler(logits[:, -1], sub)
         done = (tok == self._eos) if self._eos is not None \

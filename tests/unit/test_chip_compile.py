@@ -771,7 +771,7 @@ def test_hybrid_trunk_keeps_the_state_in_place(one_chip, monkeypatch,
         final = program == "final chunk"
         compiled = jax.jit(
             lambda p, c, ids, start, last: forward_with_cache(
-                model, p, ids, c._replace(length=start),
+                model, p, ids, c._replace(length=start), flash_decode=True,
                 last_token_head=final, last_index=last if final else None,
                 with_stats=True, with_routing=True)[final ^ 1:],
             donate_argnums=(1,)).lower(params, cache, ids, i32,
@@ -865,7 +865,7 @@ def test_windowed_trunk_keeps_planes_and_rings_in_place(one_chip, monkeypatch,
         final = program == "final chunk"
         compiled = jax.jit(
             lambda p, c, ids, start, last: forward_with_cache(
-                model, p, ids, c._replace(length=start),
+                model, p, ids, c._replace(length=start), flash_decode=True,
                 last_token_head=final, last_index=last if final else None,
                 with_stats=True, with_routing=True)[final ^ 1:],
             donate_argnums=(1,)).lower(params, cache, ids, i32,
@@ -967,7 +967,7 @@ def test_cca_trunk_keeps_planes_and_tails_in_place(one_chip, monkeypatch,
         final = program == "final chunk"
         compiled = jax.jit(
             lambda p, c, ids, start, last: forward_with_cache(
-                model, p, ids, c._replace(length=start),
+                model, p, ids, c._replace(length=start), flash_decode=True,
                 last_token_head=final, last_index=last if final else None,
                 with_stats=True, with_routing=True)[final ^ 1:],
             donate_argnums=(1,)).lower(params, cache, ids, i32,
@@ -1147,7 +1147,9 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
     batch-1 prefill cache beside the step) stays under 15.0 GiB — ISSUE 51's
     limit, which 12 slots break; no (64, 512, 32 768) float32 score array
     stands in a chunk, and no program sorts its scores (512 x 32 768 a chunk,
-    10 x 32 768 the step); the step holds four calls of the sparse read (one
+    10 x 32 768 the step); a chunk and a final chunk hold four calls of the
+    chunk's attention kernel (one a run of layers) and nothing of the walk
+    it replaced; the step holds four calls of the sparse read (one
     a run of layers), two of the score and of the key append, and NO other
     operation touches the latents' buffer: a layer's live latents are read
     by no one, the selected rows by the kernel's own DMAs."""
@@ -1186,7 +1188,7 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
         final = program == "final chunk"
         compiled = jax.jit(
             lambda p, c, ids, start, last: forward_with_cache(
-                model, p, ids, c._replace(length=start),
+                model, p, ids, c._replace(length=start), flash_decode=True,
                 last_token_head=final, last_index=last if final else None,
                 with_stats=True, with_routing=True)[final ^ 1:],
             donate_argnums=(1,)).lower(params, cache, ids, i32,
@@ -1211,16 +1213,26 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
     assert not re.search(r"f32\[(1,)?64,512,32768\]", text)
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
-        "sparse_mla_decode_attention", "dsa_index_score", "mla_cache_append",
-        "mla_decode_attention", "moe_experts_up")}
+        "sparse_mla_decode_attention", "sparse_mla_chunk_attention",
+        "dsa_index_score", "mla_cache_append", "mla_decode_attention",
+        "moe_experts_up")}
     step = program == "slot step"
     # a selection is a threshold and counts (PR 52): no sort of a chunk's
     # 512 x 32 768 scores, none of the step's 10 x 32 768
     sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
     assert not any(re.search(r"\[(1,)?(512|10),(1,)?32768\]", ln)
                    for ln in sorts), sorts
+    # a chunk's attention is the kernel's (PR 54), a call a run of layers
+    # (3 + 2 + 1 + 1: seven a chunk): no accumulator of 64 heads x 512
+    # queries carried round a loop of XLA's, no block expanded into 64
+    # heads' k_nope | v, no (64, 512, 512) scores or probabilities
+    walk = [ln for ln in text.splitlines() if re.search(
+        r"f32\[1,64,512,256\]|bf16\[1,512,64,448\]|\[(1,)?64,512,512\]",
+        ln)]
+    assert not walk, walk[:3]
     assert count == {
         "sparse_mla_decode_attention": 4 if step else 0,
+        "sparse_mla_chunk_attention": 0 if step else 4,
         "dsa_index_score": 2 if step else 0,
         "mla_cache_append": 2 if step else 0, "mla_decode_attention": 0,
         # (a chunk with no head drops its last layer's experts)

@@ -440,3 +440,171 @@ def test_the_score_kernel_scores_the_live_keys_alone():
     live = jnp.arange(S)[None] < length[:, None]
     assert bool((got == -jnp.inf)[~live].all())
     assert float(jnp.abs(jnp.where(live, got - want, 0.0)).max()) < 1e-5
+
+
+# --------------------------------------------------- a chunk's attention
+WIDTHS = {"tiny": (4, 16, 8, 16, 32), "glm": (2, 192, 64, 256, 512)}
+
+
+def _mask(case, rng, T, S, pos):
+    causal = np.arange(S)[None] <= np.asarray(pos)[:, None]
+    if case == "all":           # fewer live keys than index_topk: every one
+        return causal
+    mask = causal & (rng.random((T, S)) < 0.5)
+    if case == "a block out":   # keys 128..255 hidden from half the queries,
+        mask[::2, 128:256] = False
+        mask[1] = False         # ... and a query that may see nothing
+    return mask
+
+
+# (T, the chunk's first position, max_len, the mask): blocks of 128 keys
+CHUNKS = {
+    "a live length inside a block": (32, 150, 384, "half"),
+    "a chunk that starts at 0": (64, 0, 256, "half"),
+    "under index_topk": (32, 0, 256, "all"),
+    "a final bucket of 8": (8, 300, 384, "half"),
+    "a final bucket of 16": (16, 300, 384, "half"),
+    "13 queries": (13, 243, 256, "half"),
+    "a final bucket of 64": (64, 200, 384, "half"),
+    "a key block left out": (32, 352, 384, "a block out"),
+    "the cache's last block": (48, 464, 512, "half"),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(F32, 1e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["a float32 cache", "packed rows"])
+@pytest.mark.parametrize("case,widths", [(case, "tiny") for case in CHUNKS] + [
+    ("a live length inside a block", "glm"), ("a final bucket of 16", "glm")])
+def test_the_chunk_kernel_attends_what_the_walk_attends(case, widths, dtype,
+                                                        tol):
+    """``sparse_mla_chunk_attention`` in interpret mode against
+    ``mla.attend_expanded(selected=)`` reading the same rows: two heads a
+    program, blocks of 128 keys, the layer read the second of two; at the
+    test's widths and, two cases, at the published ones (``k_rope`` beside
+    the last 64 of ``k_nope``'s 192 columns)."""
+    T, start, S, kind = CHUNKS[case]
+    H, nope, rope, vd, rank = WIDTHS[widths]
+    cfg = dataclasses.replace(
+        glm_moe_dsa("tiny"), n_head=H, qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope, v_head_dim=vd, kv_lora_rank=rank)
+    rng = np.random.default_rng(len(case))
+    lat = jnp.asarray(rng.standard_normal((1, S, rank + rope)), dtype)
+    cache = jnp.concatenate([sparse.pack_rows(lat * 0, dtype)[None],
+                             sparse.pack_rows(lat, dtype)[None]])
+    p = {"wkv_b": jnp.asarray(rng.standard_normal(
+        (rank, H * (nope + vd))) / np.sqrt(rank), dtype)}
+    qn = jnp.asarray(rng.standard_normal((1, T, H, nope)), dtype)
+    qr = jnp.asarray(rng.standard_normal((1, T, H, rope)), dtype)
+    pos = start + jnp.arange(T, dtype=jnp.int32)
+    mask = jnp.asarray(_mask(kind, rng, T, S, pos))[None]
+
+    def read(j, blk):
+        rows = jax.lax.dynamic_slice(cache, (1, 0, j * blk, 0, 0),
+                                     (1, 1, blk) + cache.shape[3:])[0]
+        return sparse.unpack_rows(rows, rank + rope, dtype).transpose(0, 2, 1)
+
+    want = mla.attend_expanded(cfg, p, qn, qr, (read, S), pos[None],
+                               start + T, block=128, selected=mask)
+    got = jax.jit(lambda n: sparse.sparse_mla_chunk_attention(
+        qn, qr, mla._wkv_b(cfg, p, dtype), cache, mask.astype(jnp.int8), n,
+        layer=jnp.int32(1), rank=rank, scale=mla.softmax_scale(cfg), heads=2,
+        block=128, interpret=True))(jnp.int32(start + T))
+    assert got.shape == want.shape == (1, T, H, vd)
+    err = jnp.abs(got.astype(F32) - want.astype(F32)).max()
+    assert float(err) <= tol * float(jnp.abs(want.astype(F32)).max())
+    if kind == "a block out":
+        assert float(jnp.abs(got[0, 1]).max()) == 0.0   # nothing to see: 0
+
+
+def _chunks(model, params, ids, cuts, flash, dtype, max_len=128):
+    """``ids`` prefilled in chunks ending at ``cuts``: (logits, the cache
+    before the last chunk, the cache behind it)."""
+    cache = init_cache(model.cfg, 1, max_len, dtype)
+    fwd = jax.jit(lambda p, ids, cache: forward_with_cache(
+        model, p, ids, cache, flash_decode=flash))
+    out, at = [], 0
+    for cut in cuts:
+        before = cache
+        lg, cache = fwd(params, ids[:, at:cut], cache)
+        out.append(lg)
+        at = cut
+    return jnp.concatenate(out, 1), before, cache
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_a_chunked_prefill_on_the_kernel_is_the_walk_s(tiny, dtype):
+    """Chunks of 32, 32 and a bucket of 16 through ``forward_with_cache``
+    with the kernels on: the walk's logits, the first layer's rows and the
+    first indexer's keys bit-equal (nothing attended stands in front of
+    them), nothing outside the chunk's positions touched in any layer. In
+    float32 to 2e-5 of the largest logit; in bf16 (packed rows; a trunk of
+    dense layers, so that no expert is chosen otherwise at a near-tie) a
+    position's worst logit within 2.5e-2 in the median — the second
+    indexer picks another key for a few positions, whose logits move by a
+    tenth."""
+    cfg, model, params = tiny
+    if dtype != F32:
+        cfg = glm_moe_dsa("tiny", dtype=dtype, moe_first_dense=cfg.n_layer)
+        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(0))
+    ids = ids_of(cfg, 80, seed=3)
+    cuts = (32, 64, 80)
+    walk, _, c0 = _chunks(model, params, ids, cuts, False, dtype)
+    got, before, c1 = _chunks(model, params, ids, cuts, True, dtype)
+    assert c1.c.dtype == (jnp.uint32 if dtype != F32 else F32)
+    if dtype == F32:
+        assert close(got, walk)
+    else:
+        worst = jnp.abs(got - walk).max(-1) / jnp.abs(walk).max()
+        assert float(jnp.median(worst)) <= 2.5e-2 and float(worst.max()) < 0.2
+    assert np.array_equal(np.asarray(c0.c[0]), np.asarray(c1.c[0]))
+    assert np.array_equal(np.asarray(c0.ik[0]), np.asarray(c1.ik[0]))
+    lo, hi = cuts[-2], cuts[-1]
+    for name, axis in (("c", 2), ("ik", 3)):
+        a = np.moveaxis(np.asarray(getattr(before, name)), axis, 0)
+        b = np.moveaxis(np.asarray(getattr(c1, name)), axis, 0)
+        assert np.array_equal(a[:lo], b[:lo]), name
+        assert np.array_equal(a[hi:], b[hi:]), name
+        assert not np.array_equal(a[lo:hi], b[lo:hi]), name
+
+
+@pytest.mark.parametrize("what,T,max_len,flash,walks", [
+    ("the kernel", 32, 128, True, 0),
+    ("a bucket of 8", 8, 128, True, 0),
+    ("a cache of no whole lane block", 32, 96, True, 1),
+    ("the kernels off", 32, 128, False, 0),
+])
+def test_a_chunk_traced_onto_the_walk_is_counted(tiny, what, T, max_len,
+                                                 flash, walks):
+    """``Serve/chunk_attention_fallback_builds``: one for every chunk program
+    traced onto ``mla.attend_expanded`` while the kernels are on; the
+    kind's own answer (what the ``prefill_chunk`` span says) agrees."""
+    from deepspeed_tpu.observability.metrics import get_registry
+
+    cfg, model, params = tiny
+    counter = get_registry().counter("Serve/chunk_attention_fallback_builds")
+    before = counter.value
+    cache = init_cache(cfg, 1, max_len, F32)
+    text = str(jax.make_jaxpr(lambda p, ids, cache: forward_with_cache(
+        model, p, ids, cache, flash_decode=flash))(
+            params, ids_of(cfg, T), cache))
+    assert counter.value - before == walks
+    kind = kind_of(cfg, 1, F32)
+    kind.flash, kind.max_len = flash, max_len
+    took = flash and not walks
+    assert kind.chunk_kernel(flash, T, max_len, F32, F32) == took
+    assert ("sparse_mla_chunk_attention" in text) == took
+
+
+def test_a_chunk_s_span_says_what_attended_and_over_how_many_keys(tiny):
+    from deepspeed_tpu.serving.scheduler import ChunkPlan
+
+    cfg, _, _ = tiny
+    kind = kind_of(cfg, 2, F32)
+    chunk = ChunkPlan(start=64, ids=np.zeros(32, np.int32))
+    assert kind.chunk_meta(chunk)["attn_live_keys"] == 96
+    assert kind.chunk_meta(chunk)["attn_kernel"] is False   # no engine's
+    kind.flash, kind.max_len = True, 128
+    assert kind.chunk_meta(chunk)["attn_kernel"] is True
+    kind.max_len = 96
+    assert kind.chunk_meta(chunk)["attn_kernel"] is False
