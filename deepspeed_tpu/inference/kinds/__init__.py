@@ -8,15 +8,17 @@ from .cca import CCA, CCACache
 from .dense import Dense, KVCache, PagedKVCache
 from .hybrid import Hybrid, HybridCache
 from .latent import Latent, LatentCache
+from .linear_sparse import LinearSparse, LinearSparseCache
 from .parallel import ParallelCache, ParallelHybrid
 from .sparse_latent import SparseLatent, SparseLatentCache
 from .windowed import Windowed, WindowedCache
 
-KINDS = (Dense, Latent, SparseLatent, Hybrid, Windowed, CCA, ParallelHybrid)
+KINDS = (Dense, Latent, LinearSparse, SparseLatent, Hybrid, Windowed, CCA,
+         ParallelHybrid)
 
-__all__ = ["KINDS", "FEATURES", "Kind", "kind_of", "KVCache", "PagedKVCache",
+__all__ = ["KINDS", "LinearSparse", "FEATURES", "Kind", "kind_of", "KVCache", "PagedKVCache",
            "LatentCache", "HybridCache", "WindowedCache", "CCACache",
-           "ParallelCache", "SparseLatentCache"]
+           "ParallelCache", "SparseLatentCache", "LinearSparseCache"]
 
 
 def kind_of(cfg, *serving) -> Kind:

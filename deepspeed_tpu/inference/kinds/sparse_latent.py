@@ -53,6 +53,26 @@ def _index(tree, i):
         lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False), tree)
 
 
+def query_blocks(fn, T: int, *xs):
+    """``fn`` over blocks of QUERY_BLOCK of the T queries (axis 1 of every
+    ``xs``), the results joined along it."""
+    if T <= QUERY_BLOCK:
+        return fn(*xs)
+    nb = -(-T // QUERY_BLOCK)
+    pad = nb * QUERY_BLOCK - T
+
+    def cut(a):
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2),
+                    mode="edge")
+        return a.reshape(a.shape[0], nb, QUERY_BLOCK,
+                         *a.shape[2:]).swapaxes(0, 1)
+
+    out = lax.map(lambda a: fn(*a), tuple(cut(a) for a in xs))
+    return jax.tree.map(
+        lambda a: a.swapaxes(0, 1).reshape(
+            a.shape[1], nb * QUERY_BLOCK, *a.shape[3:])[:, :T], out)
+
+
 class SparseLatent(Kind):
     cache = SparseLatentCache
     planes = ("ik", "c")      # the gate reads the first's last dimension
@@ -84,7 +104,9 @@ class SparseLatent(Kind):
 
     @staticmethod
     def matches(cfg) -> bool:
-        return bool(getattr(cfg, "index_pattern", ""))
+        # (beside the mixers of a mixer_pattern: kinds/linear_sparse.py)
+        return bool(getattr(cfg, "index_pattern", "")) \
+            and not getattr(cfg, "mixer_pattern", "")
 
     def chunk_kernel(self, flash_decode, T, max_len, *dtypes) -> bool:
         """Whether T > 1 queries over a cache of ``max_len`` attend in
@@ -185,23 +207,7 @@ class SparseLatent(Kind):
             return read
 
         def blocks(fn, *xs):
-            """``fn`` over blocks of QUERY_BLOCK of the T queries (axis 1 of
-            every ``xs``), the results joined along it."""
-            if T <= QUERY_BLOCK:
-                return fn(*xs)
-            nb = -(-T // QUERY_BLOCK)
-            pad = nb * QUERY_BLOCK - T
-
-            def cut(a):
-                a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2),
-                            mode="edge")
-                return a.reshape(a.shape[0], nb, QUERY_BLOCK,
-                                 *a.shape[2:]).swapaxes(0, 1)
-
-            out = lax.map(lambda a: fn(*a), tuple(cut(a) for a in xs))
-            return jax.tree.map(
-                lambda a: a.swapaxes(0, 1).reshape(
-                    a.shape[1], nb * QUERY_BLOCK, *a.shape[3:])[:, :T], out)
+            return query_blocks(fn, T, *xs)
 
         def layer_fn(carry, p, ip, layer, full, local, kind, banks):
             x, c, ik, sel = carry

@@ -52,7 +52,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.sparse_mla_attention import einsum_f32
 from .transformer import _norm, _rope
 
-KINDS = "Fs"
+KINDS = "Fs-"        # "-": a layer with no attention (a mixer of another kind)
 KEY_BLOCK = 1024     # keys a step of a chunk's score walk
 SELECT_BLOCK = 4096  # keys a step of the selection's counting walk
 SELECT_BITS = 2      # bits of the threshold a counting pass settles
@@ -61,23 +61,35 @@ POSITION_ROWS = 64   # queries a tile of it
 
 
 def check_config(c) -> None:
-    """Refuse what an ``index_pattern`` trunk does not run."""
+    """Refuse what an ``index_pattern`` trunk does not run, each with why."""
     pat = c.index_pattern
-    if len(pat) != c.n_layer or set(pat) - set(KINDS) or pat[0] != "F":
+    attends = pat.replace("-", "")
+    if len(pat) != c.n_layer or set(pat) - set(KINDS) \
+            or not attends.startswith("F"):
         raise ValueError(
             f"index_pattern {pat!r} has to name each of the {c.n_layer} "
-            f"layers, one of {KINDS!r}, the first an 'F': a layer that "
-            "takes a selection over needs one before it")
+            f"layers, one of {KINDS!r}, the first that attends an 'F': a "
+            "layer that takes a selection over needs one before it")
+    if "-" in pat and not c.mixer_pattern:
+        raise ValueError(
+            "index_pattern says '-' (no attention) of a layer: only beside "
+            "a mixer_pattern, which says what the layer has instead")
     if min(c.q_lora_rank, c.index_topk, c.index_heads,
            c.index_head_dim) <= 0 or c.index_head_dim < c.qk_rope_head_dim:
         raise ValueError(
             "an indexer reads the query's latent (q_lora_rank) and needs "
             "index_topk, index_heads and index_head_dim >= qk_rope_head_dim")
-    if c.moe_router not in ("gshard", "sigmoid") or c.loop_steps > 1 \
-            or c.block_pattern or c.attn_pattern:
-        raise ValueError("index_pattern is the glm_moe_dsa block: latent "
-                         "attention beside a dense FFN or sigmoid-routed "
-                         "experts")
+    if c.moe_router not in ("gshard", "sigmoid"):
+        raise ValueError("index_pattern: the zaya router's carried state is "
+                         "not threaded through the selection's layer loop")
+    if c.loop_steps > 1:
+        raise ValueError("index_pattern: a looped trunk's passes would each "
+                         "need indexer keys of their own")
+    if c.block_pattern or c.attn_pattern:
+        raise ValueError(
+            "index_pattern selects positions of a latent cache: it stands "
+            "beside neither a block_pattern (one mixer a layer over K/V "
+            "planes) nor an attn_pattern (window rings)")
 
 
 def runs(cfg) -> list:
@@ -125,6 +137,8 @@ def index_keys(cfg, y, ip, positions):
     layer caches beside its latents, one for all of the indexer's heads."""
     k = _norm(y @ ip["wk"].astype(y.dtype), ip["k_norm_scale"],
               ip["k_norm_bias"], "layernorm", cfg.norm_eps)
+    if not cfg.qk_rope_head_dim:
+        return k
     return _rope(k[:, :, None], k[:, :, None], positions, cfg.rope_theta,
                  cfg.qk_rope_head_dim)[1][:, :, 0]
 
@@ -135,7 +149,8 @@ def index_queries(cfg, y, cq, ip, positions):
     B, T, _ = y.shape
     H, D = cfg.index_heads, cfg.index_head_dim
     q = (cq @ ip["wq_b"].astype(cq.dtype)).reshape(B, T, H, D)
-    q = _rope(q, q, positions, cfg.rope_theta, cfg.qk_rope_head_dim)[0]
+    if cfg.qk_rope_head_dim:
+        q = _rope(q, q, positions, cfg.rope_theta, cfg.qk_rope_head_dim)[0]
     w = einsum_f32("btd,dh->bth", y, ip["weights_proj"].astype(y.dtype))
     return q, w * (1.0 / math.sqrt(H * D))
 
@@ -286,6 +301,44 @@ def select(score, q_pos, topk: int, want_mask: bool = True, n_keys=None):
             lambda: above | (tied & (jnp.cumsum(tied, axis=-1) <= room)),
             lambda: above | tied)
         return _positions(mask, k), mask if want_mask else None
+
+
+# ------------------------------------------------------------ pooled keys
+def pool_keys(keys, pool: int):
+    """The mean of every whole group of ``pool`` keys: ``keys`` (B, T, D),
+    the first at a group's edge -> (B, T // pool, D), in float32 and back."""
+    B, T, D = keys.shape
+    n = T // pool
+    return jnp.mean(keys[:, :n * pool].astype(jnp.float32).reshape(
+        B, n, pool, D), axis=2).astype(keys.dtype)
+
+
+def select_pooled(score, q_pos, topk: int, pool: int, want_mask: bool = True,
+                  n_groups=None):
+    """The selection over pooled keys: ``score`` (B, T, G) of the queries at
+    positions ``q_pos`` (B, T) against the groups' keys. A query scores the
+    closed groups before its own, takes the ``topk // pool`` best whole and
+    always reads its own group up to itself. Returns (``idx`` (B, T, (topk
+    // pool + 1) pool) i32 positions, the first ``n`` of a row valid; ``n``
+    (B, T) i32; ``mask`` (B, T, G pool) bool over positions, causal
+    included, or None). The open group rides through :func:`select` as the
+    one candidate nothing outscores."""
+    G = score.shape[-1]
+    own = q_pos // pool
+    score = jnp.where(jnp.arange(G, dtype=jnp.int32)[None, None]
+                      == own[..., None], jnp.inf, score)
+    groups, gmask = select(score, own, topk // pool + 1, want_mask, n_groups)
+    lane = jnp.arange(pool, dtype=jnp.int32)
+    idx = (groups[..., None] * pool + lane).reshape(
+        groups.shape[:-1] + (groups.shape[-1] * pool,))
+    chosen = jnp.minimum(own + 1, groups.shape[-1])
+    n = chosen * pool - (pool - 1 - q_pos % pool)
+    mask = None
+    if want_mask:
+        mask = jnp.repeat(gmask, pool, axis=-1) & (
+            jnp.arange(G * pool, dtype=jnp.int32)[None, None]
+            <= q_pos[..., None])
+    return idx, n, mask
 
 
 # ------------------------------------------------------------ full forward

@@ -32,7 +32,8 @@ BIG_NEG = -2.0 ** 30
 def project(cfg, y, p, positions):
     """``y`` (B, T, d) normed activations → ``q_nope`` (B, T, H, nope),
     ``q_rope`` (B, T, H, rope), and the T new latents ``(B, T, rank+rope)``
-    = [RMSNorm(c) | rope(k_rope)]: what the cache stores."""
+    = [RMSNorm(c) | rope(k_rope)]: what the cache stores (c alone where
+    ``qk_rope_head_dim`` is 0)."""
     B, T, _ = y.shape
     H, nope, rd, r = (cfg.n_head, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.kv_lora_rank)
@@ -49,6 +50,9 @@ def project(cfg, y, p, positions):
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     kva = y @ p["wkv_a"].astype(y.dtype)                     # (B, T, r + rd)
     c = _norm(kva[..., :r], p["kv_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+    if not rd:
+        # a latent with no rope part: the row is c alone
+        return q_nope, q_rope, c
     q_rope, k_rope = _rope(q_rope, kva[..., None, r:], positions,
                            cfg.rope_theta)
     return q_nope, q_rope, jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)
@@ -110,9 +114,10 @@ def attend_expanded(cfg, p, q_nope, q_rope, latents, q_pos, n_keys,
                 latents, (layer, 0, 0, j * blk),
                 (1,) + latents.shape[1:3] + (blk,))[0]
         kv = jnp.einsum("brs,rhm->bshm", lat[:, :r].astype(w.dtype), w)
-        s = jnp.einsum("bthn,bshn->bhts", q_nope, kv[..., :nope]) \
-            + jnp.einsum("bthr,brs->bhts", q_rope,
-                         lat[:, r:].astype(q_rope.dtype))
+        s = jnp.einsum("bthn,bshn->bhts", q_nope, kv[..., :nope])
+        if cfg.qk_rope_head_dim:
+            s = s + jnp.einsum("bthr,brs->bhts", q_rope,
+                               lat[:, r:].astype(q_rope.dtype))
         s = s.astype(jnp.float32) * scale
         k_pos = j * blk + jnp.arange(blk, dtype=jnp.int32)
         keep = (k_pos[None, None, :] <= q_pos[:, :, None])[:, None]

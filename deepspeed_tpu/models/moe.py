@@ -39,7 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..platform.mesh import BATCH_AXES, constrain
 from .transformer import (TransformerConfig, TransformerLM, _activation,
-                          _norm)
+                          _norm, _swiglu, clamp_gain)
 
 B_AXES = BATCH_AXES
 # the zaya router's last matrix at init, over the trunk's 1 / sqrt(fan-in)
@@ -391,7 +391,7 @@ class MoETransformerLM(TransformerLM):
                 held_layout(idx, cfg.held_experts, cfg.moe_first_held, bm)
             out = experts_swiglu(yt[row_token], bank["w_gate"], bank["w_in"],
                                  bank["w_out"], block_expert, used, bm=bm,
-                                 layer=layer)
+                                 layer=layer, limit=cfg.swiglu_limit)
             # a row no block wrote is never read: pairs held elsewhere add 0
             rows = jnp.where(pair_held[:, None], out[pair_row], 0)
             routed = jnp.sum(rows.reshape(N, k, d).astype(jnp.float32)
@@ -406,8 +406,9 @@ class MoETransformerLM(TransformerLM):
         if not self.cfg.moe_shared_d_ff:
             return routed
         with jax.named_scope("moe_shared"):
-            u = jax.nn.silu(yt @ p["ws_gate"].astype(yt.dtype)) \
-                * (yt @ p["ws_in"].astype(yt.dtype))
+            u = _swiglu(yt @ p["ws_gate"].astype(yt.dtype),
+                        lambda: yt @ p["ws_in"].astype(yt.dtype),
+                        self.cfg.swiglu_limit)
             return routed + u @ p["ws_out"].astype(yt.dtype)
 
     def _fold_aux(self, aux):
@@ -449,12 +450,15 @@ class MoETransformerLM(TransformerLM):
                 layers.update(self._init_zaya_router(next(k), L))
             else:
                 layers["router"] = dense(next(k), (L, d, E), 0.02)
-            layers["w_in"] = dense(next(k), (L, Eh, d, f), 1.0 / math.sqrt(d))
+            gain = clamp_gain(cfg)
+            layers["w_in"] = dense(next(k), (L, Eh, d, f),
+                                   gain / math.sqrt(d))
             layers["w_out"] = dense(next(k), (L, Eh, f, d),
-                                    1.0 / math.sqrt(2 * depth * f))
+                                    1.0 / (math.sqrt(2 * depth * f)
+                                           * gain ** 2))
             if cfg.is_glu:
                 layers["w_gate"] = dense(next(k), (L, Eh, d, f),
-                                         1.0 / math.sqrt(d))
+                                         gain / math.sqrt(d))
             if cfg.use_bias:
                 layers["b_in"] = jnp.zeros((L, Eh, f), jnp.float32)
                 layers["b_out"] = jnp.zeros((L, Eh, d), jnp.float32)
@@ -464,11 +468,13 @@ class MoETransformerLM(TransformerLM):
                 layers["router_bias"] = dense(next(k), (L, E), 0.02)
             if cfg.moe_shared_d_ff:
                 fs = cfg.moe_shared_d_ff
-                layers["ws_in"] = dense(next(k), (L, d, fs), 1.0 / math.sqrt(d))
+                layers["ws_in"] = dense(next(k), (L, d, fs),
+                                        gain / math.sqrt(d))
                 layers["ws_gate"] = dense(next(k), (L, d, fs),
-                                          1.0 / math.sqrt(d))
+                                          gain / math.sqrt(d))
                 layers["ws_out"] = dense(next(k), (L, fs, d),
-                                         1.0 / math.sqrt(2 * depth * fs))
+                                         1.0 / (math.sqrt(2 * depth * fs)
+                                                * gain ** 2))
         return params
 
     def _init_zaya_router(self, key, L: int) -> dict:
@@ -538,6 +544,11 @@ class MoETransformerLM(TransformerLM):
         precision governs tie-breaking stability)."""
         from .cca import FP32_NAMES as cca
 
-        return ("router", "router_bias", "router_bd", "router_gamma",
-                "router_norm", "router_w1", "router_w2", "router_w3") \
+        names = ("router", "router_bias", "router_bd", "router_gamma",
+                 "router_norm", "router_w1", "router_w2", "router_w3") \
             + (cca if self.cfg.attention == "cca" else ())
+        if self.cfg.mixer_pattern:
+            from . import kda, mhc
+
+            names += kda.FP32_NAMES + mhc.FP32_NAMES
+        return names

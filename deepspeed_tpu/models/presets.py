@@ -122,6 +122,51 @@ def glm_moe_dsa(size: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def glm5_next(size: str = "tiny", **overrides) -> TransformerConfig:
+    """GLM-5.3-Flash family (``model_type: glm5_next_text``): three layers
+    in four mix with Kimi Delta Attention (``mixer_pattern`` "K",
+    models/kda.py) where the fourth attends over a latent with no rope part
+    through an indexer's selection over pooled keys (``index_kpool``), four
+    residual streams mixed by Sinkhorn maps (``hc_mult``, models/mhc.py),
+    every gated FFN clamped (``swiglu_limit``). ``"5.3-flash"`` is
+    zai-org/GLM-5.3-Flash's ``config.json`` (45 layers ``KKKA`` eleven times
+    and ``K``; three dense layers, then 288 experts top-8 beside a shared
+    one; KDA's two low-rank widths are Kimi Linear's, the head width).
+    ``"tiny"`` keeps what the cache's layout turns on at unit-test size: a
+    whole period behind a dense KDA layer, a selection smaller than a
+    test's contexts."""
+    table = {
+        "tiny": dict(mixer_pattern="KAKKK", index_pattern="-F---", n_layer=5,
+                     n_head=4, d_model=64, d_ff=128, vocab_size=251,
+                     max_seq=256, q_lora_rank=48, kv_lora_rank=32,
+                     qk_nope_head_dim=16, v_head_dim=16, index_topk=16,
+                     index_heads=3, index_head_dim=16, kda_heads=4,
+                     kda_head_dim=16, kda_rank=16, num_experts=8,
+                     moe_top_k=2, moe_d_ff=32, moe_shared_d_ff=32,
+                     moe_first_dense=1),
+        "5.3-flash": dict(mixer_pattern=11 * "KKKA" + "K",
+                          index_pattern=11 * "---F" + "-", n_layer=45,
+                          n_head=64, d_model=4096, d_ff=12288,
+                          vocab_size=154880, max_seq=1048576,
+                          q_lora_rank=1536, kv_lora_rank=512,
+                          qk_nope_head_dim=256, v_head_dim=256,
+                          index_topk=2048, index_heads=32, index_head_dim=128,
+                          kda_heads=64, kda_head_dim=128, kda_rank=128,
+                          num_experts=288, moe_top_k=8, moe_d_ff=2048,
+                          moe_shared_d_ff=2048, moe_first_dense=3),
+    }
+    base = dict(attention="mla", pos_embedding="none", norm="rmsnorm",
+                norm_eps=1e-5, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, moe_router="sigmoid",
+                moe_norm_topk=True, moe_routed_scale=2.5, qk_rope_head_dim=0,
+                index_kpool=4, kda_conv=4, kda_gate_floor=-5.0, hc_mult=4,
+                hc_sinkhorn_iters=20, hc_eps=1e-6, swiglu_limit=10.0,
+                fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def nemotron_h(size: str = "3-super-120b-a12b", **overrides) -> TransformerConfig:
     """The NemotronH block (``model_type: nemotron_h``): every layer ONE
     mixer, its kind a letter of the published ``hybrid_override_pattern`` —
@@ -356,6 +401,11 @@ def build_model(cfg, attention_fn=None):
 
 # (what tells a trunk that is served here and not trained, why)
 _SERVED_NOT_TRAINED = (
+    (lambda cfg: getattr(cfg, "mixer_pattern", ""),
+     "a trunk of delta-rule mixers beside attention over an indexer's "
+     "selection (mixer_pattern) is served, not trained here: the chunkwise "
+     "delta-rule scan has no backward, and the selection has no gradient of "
+     "its own"),
     (lambda cfg: getattr(cfg, "index_pattern", ""),
      "a trunk whose attention reads an indexer's selection (index_pattern) "
      "is served, not trained here: the selection has no gradient of its own "

@@ -228,6 +228,39 @@ class TransformerConfig:
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # pooled indexer keys (GLM-5.3-Flash ``index_kpool``): ONE cached key a
+    # group of this many positions, the mean of the group's keys once it is
+    # full; a query scores the closed groups before its own, reads the
+    # ``index_topk / index_kpool`` best whole and its own group up to itself
+    index_kpool: int = 1
+    # a mixer a layer beside the FFN kinds (GLM-5.3-Flash, ``model_type:
+    # glm5_next_text``): layer i's mixer is ``mixer_pattern[i]`` — "K" Kimi
+    # Delta Attention (models/kda.py: ``kda_heads`` heads of ``kda_head_dim``
+    # key and value channels, a float32 delta-rule state a head, depthwise
+    # convs of ``kda_conv`` taps, the decay and the output gate behind
+    # low-rank maps ``kda_rank`` wide, the decay's log floored at
+    # ``kda_gate_floor``) | "A" the
+    # config's attention, which with an ``index_pattern`` ("-" for the "K"
+    # layers: no attention) reads a selection. "" is one mixer kind. The
+    # cache: inference/kinds/linear_sparse.py
+    mixer_pattern: str = ""
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_rank: int = 0
+    kda_gate_floor: float = -5.0
+    # manifold-constrained hyper-connections (mHC, arXiv:2512.24880;
+    # models/mhc.py): ``hc_mult`` residual streams a token, each sub-layer
+    # reading a mix of them and writing back through a doubly stochastic map
+    # (``hc_sinkhorn_iters`` Sinkhorn passes, ``hc_eps`` in the sums).
+    # 0 / 1: one stream, ``x + f(norm(x))``
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    # gated FFNs clamp before the product (``swiglu_limit``): silu(min(gate,
+    # limit)) * clip(up, -limit, limit), dense, shared and routed alike
+    # (0: no clamp)
+    swiglu_limit: float = 0.0
 
     @property
     def held_experts(self) -> int:
@@ -283,8 +316,8 @@ class TransformerConfig:
     @property
     def segment_attn(self) -> tuple:
         """Each segment's attention kind ("G" | "S" with an
-        ``attn_pattern``, else ""): a segment is a run of layers equal in
-        attention kind AND FFN kind."""
+        ``attn_pattern``, "K" | "A" with a ``mixer_pattern``, else ""): a
+        segment is a run of layers equal in mixer kind AND FFN kind."""
         if self.block_pattern:
             return ("",) * len(self.segments)
         return tuple(attn for (attn, _), _ in self._layer_runs())
@@ -292,7 +325,8 @@ class TransformerConfig:
     def _layer_runs(self) -> list:
         k = min(self.moe_first_dense, self.n_layer) if self.num_experts > 1 \
             else self.n_layer
-        kinds = [((self.attn_pattern[i] if self.attn_pattern else ""),
+        pat = self.attn_pattern or self.mixer_pattern
+        kinds = [((pat[i] if pat else ""),
                   "dense" if i < k else "moe") for i in range(self.n_layer)]
         return [(kind, len(list(run))) for kind, run in groupby(kinds)]
 
@@ -352,6 +386,12 @@ class TransformerConfig:
 
     def _attn_params_per_layer(self, kind: str = "") -> int:
         d, h = self.d_model, self.n_head
+        if kind == "K":
+            # q, k, v and the output; beta; the decay's and the gate's
+            # low-rank pairs
+            inner = self.kda_heads * self.kda_head_dim
+            return (4 * d * inner + d * self.kda_heads
+                    + 2 * self.kda_rank * (d + inner))
         if self.attention == "mla":
             r, ql = self.kv_lora_rank, self.q_lora_rank
             q = ql * (d + h * self.head_dim) if ql else d * h * self.head_dim
@@ -404,6 +444,10 @@ class TransformerConfig:
         total += self.index_pattern.count("F") * (
             self.q_lora_rank * self.index_heads * self.index_head_dim
             + d * (self.index_head_dim + self.index_heads))
+        if self.hc_mult > 1:
+            # two sub-layers a layer, each a map off the streams' whole width
+            n = self.hc_mult
+            total += self.n_layer * 2 * n * d * (n * n + 2 * n)
         total += emb if not non_embedding else 0
         if (not self.tie_embeddings and not non_embedding
                 and self.objective != "feature"):
@@ -478,6 +522,27 @@ def _activation(u, name: str):
     if name == "quick_gelu":
         return u * jax.nn.sigmoid(1.702 * u)       # CLIP's sigmoid approx
     raise ValueError(f"unknown activation {name!r}")
+
+
+def _swiglu(gate, up, limit: float = 0.0):
+    """``silu(gate) * up``; with ``limit`` (``swiglu_limit``) the gate
+    clamped from above and the up product on both sides first. ``up`` may
+    be a thunk, taken behind the gate's activation: a caller that used to
+    write ``silu(a) * (b)`` keeps the program it lowered to."""
+    if limit:
+        gate = jnp.minimum(gate, jnp.asarray(limit, gate.dtype))
+    act = jax.nn.silu(gate)
+    up = up() if callable(up) else up
+    return act * (jnp.clip(up, -limit, limit) if limit else up)
+
+
+def clamp_gain(cfg) -> float:
+    """What a clamped gated FFN's gate and up matrices are drawn wider by
+    (and its down matrix narrower by the square): with ``swiglu_limit`` the
+    pre-activations of a unit-RMS input then have sd 0.6 of the limit, a
+    tenth of them beyond it as in a trained model that needs the clamp, so
+    that a path which drops the clamp reads differently. 1 with no limit."""
+    return 0.6 * cfg.swiglu_limit if cfg.swiglu_limit else 1.0
 
 
 def exit_pdf(lam):
@@ -679,16 +744,24 @@ class TransformerLM:
                 "accepts neither a bias nor alibi slopes (flash and ring "
                 "attention do; sparse/Ulysses still do not)")
         if config.attention == "mla":
-            if (config.use_bias or config.pos_embedding != "rope"
+            # a latent with no rope part (GLM-5.3-Flash ``mla_use_nope``):
+            # no position code at all, beside mixers that carry the order
+            nope = config.pos_embedding == "none" \
+                and config.qk_rope_head_dim == 0 and config.mixer_pattern
+            if (config.use_bias
+                    or (config.pos_embedding != "rope" and not nope)
                     or not config.causal or config.post_ln
                     or config.parallel_residual or attention_fn is not None
                     or min(config.kv_lora_rank, config.qk_nope_head_dim,
-                           config.qk_rope_head_dim, config.v_head_dim) <= 0):
+                           config.v_head_dim) <= 0
+                    or (config.qk_rope_head_dim <= 0 and not nope)):
                 raise ValueError(
                     "attention='mla' is the DeepSeek block: causal, rope on "
-                    "qk_rope_head_dim, no biases, pre-norm, its own blocked "
-                    "attention (no attention_fn), and kv_lora_rank / "
-                    "qk_nope_head_dim / qk_rope_head_dim / v_head_dim set")
+                    "qk_rope_head_dim (none, with pos_embedding='none', only "
+                    "beside the mixers of a mixer_pattern), no biases, "
+                    "pre-norm, its own blocked attention (no attention_fn), "
+                    "and kv_lora_rank / qk_nope_head_dim / qk_rope_head_dim "
+                    "/ v_head_dim set")
             if config.index_pattern:
                 from .dsa import check_config as check_dsa
 
@@ -702,6 +775,14 @@ class TransformerLM:
         if config.index_pattern and config.attention != "mla":
             raise ValueError("index_pattern selects positions of a latent "
                              "(attention='mla') cache")
+        if config.mixer_pattern or config.hc_mult > 1 \
+                or config.index_kpool > 1:
+            from .kda import check_config as check_kda
+
+            check_kda(config)
+        if config.swiglu_limit and not config.is_glu:
+            raise ValueError("swiglu_limit clamps a gated FFN's gate and up "
+                             "(activation '*_glu')")
         if config.moe_router == "zaya" and (
                 config.attention != "cca" or config.num_experts < 2
                 or config.router_hidden < 1 or config.moe_top_k != 1
@@ -818,7 +899,15 @@ class TransformerLM:
         dense_ffn = kind == "dense"
         two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
         layers = {"ln1_scale": jnp.ones((L, d), jnp.float32)}
-        if cfg.attention == "mla":
+        if cfg.hc_mult > 1:
+            from .mhc import init_params as init_mhc
+
+            layers.update(init_mhc(cfg, next(k), L))
+        if attn == "K":
+            from .kda import init_params as init_kda
+
+            layers.update(init_kda(cfg, next(k), dense, L, depth))
+        elif cfg.attention == "mla":
             r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
             layers.update({
                 "wq_a": dense(next(k), (L, d, ql)),
@@ -870,11 +959,13 @@ class TransformerLM:
             layers["res_scale"] = jnp.asarray([1.0, 0.0, 1.0, 0.0])[:, None] \
                 + sd * jax.random.normal(next(k), (L, 2, 4, d), jnp.float32)
         if dense_ffn:
-            layers["w_in"] = dense(next(k), (L, d, f))
+            gain = clamp_gain(cfg)
+            layers["w_in"] = dense(next(k), (L, d, f)) * gain
             layers["w_out"] = dense(next(k), (L, f, d),
-                                    scale=1.0 / math.sqrt(2 * depth * f))
+                                    scale=1.0 / math.sqrt(2 * depth * f)) \
+                / gain ** 2
             if cfg.is_glu:
-                layers["w_gate"] = dense(next(k), (L, d, f))
+                layers["w_gate"] = dense(next(k), (L, d, f)) * gain
         if cfg.use_bias:
             layers.update({
                 "ln1_bias": jnp.zeros((L, d), jnp.float32),
@@ -942,7 +1033,15 @@ class TransformerLM:
         dense_ffn = kind == "dense"
         two_ln = not (cfg.parallel_residual and cfg.parallel_shared_ln)
         layers = {"ln1_scale": P(None, None)}
-        if cfg.attention == "mla":
+        if cfg.hc_mult > 1:
+            from .mhc import param_specs as mhc_specs
+
+            layers.update(mhc_specs())
+        if attn == "K":
+            from .kda import param_specs as kda_specs
+
+            layers.update(kda_specs())
+        elif cfg.attention == "mla":
             # heads column-split as wq/wo are; the latent projection and
             # its norm are shared by all heads and stay replicated
             layers.update({
@@ -1105,7 +1204,7 @@ class TransformerLM:
         if cfg.is_glu:
             # GLU: tag the gated product — bwd still recomputes the gate
             # matmul for the silu grad, but w_out's input is saved
-            u = jax.nn.silu(self._proj(y, p, "w_gate")) * u
+            u = _swiglu(self._proj(y, p, "w_gate"), u, cfg.swiglu_limit)
             u = checkpoint_name(u, "mlp_h")
         else:
             # Tag the PRE-activation: under save_names_mlp the bwd then
@@ -1354,6 +1453,15 @@ class TransformerLM:
         norm is then none) and, as aux, what :meth:`loop_passes` says of
         the passes."""
         x, positions = self._embed(params, input_ids)
+        if self.cfg.mixer_pattern:
+            if attn_mask is not None or remat_policy is not None:
+                raise NotImplementedError(
+                    "a trunk of delta-rule mixers beside selected attention "
+                    "(mixer_pattern) is served, not trained: no padding "
+                    "mask, no remat")
+            from .kda import trunk
+
+            return trunk(self, params, x, positions)
         if self.cfg.index_pattern:
             from .dsa import trunk
 

@@ -10,7 +10,8 @@ untouched expert's never. Blocks behind the last used one repeat its expert
 (no fetch) and are skipped.
 
 - ``experts_up``: ``silu(x @ w_gate[e]) * (x @ w_in[e])`` → (R, f); grid
-  (f tiles, blocks), the blocks innermost.
+  (f tiles, blocks), the blocks innermost. With ``limit`` both factors are
+  clamped first (``swiglu_limit``).
 - ``experts_down``: ``h @ w_out[e]`` → (R, d); grid (d tiles, blocks).
 
 Rows of skipped blocks are left unwritten; nothing reads them (the combine
@@ -19,6 +20,7 @@ gathers routed rows only).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -50,12 +52,15 @@ def _fits(k_in: int, weights: int, dtype, most: int) -> int:
     return max(LANES, min(most, room - room % LANES))
 
 
-def _up_kernel(be_ref, used_ref, _, x_ref, wg_ref, wi_ref, o_ref):
+def _up_kernel(be_ref, used_ref, _, x_ref, wg_ref, wi_ref, o_ref, *,
+               limit: float = 0.0):
     @pl.when(pl.program_id(1) < used_ref[0])
     def _():
         x = x_ref[...]
         g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wi_ref[...], preferred_element_type=jnp.float32)
+        if limit:
+            g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
         o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
 
 
@@ -88,7 +93,8 @@ def _call(kernel, name, rows, weights, block_expert, used, layer, bm, n_out,
 
 
 def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
-                   bm: int, layer=None, interpret: Optional[bool] = None):
+                   bm: int, layer=None, limit: float = 0.0,
+                   interpret: Optional[bool] = None):
     """``xs`` (R, d): rows sorted by expert and padded to blocks of ``bm``;
     ``w_gate``/``w_in`` (E, d, f), ``w_out`` (E, f, d) — or the banks of
     ALL layers, (L, E, ·, ·), with ``layer`` (traced i32) the one to use:
@@ -96,7 +102,9 @@ def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
     picks the layer, where slicing a layer's bank out for the call would
     copy 0.4 GB a matrix (PERF.md, PR 29). ``block_expert`` (R // bm,) i32
     the expert of every block; ``blocks_used`` i32 how many blocks hold
-    rows. Returns (R, d): row i through its block's expert."""
+    rows. ``limit`` (``swiglu_limit``, 0: none): the gate clamped from
+    above and the up product on both sides before they meet. Returns (R,
+    d): row i through its block's expert."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     R, d = xs.shape
@@ -108,7 +116,8 @@ def experts_swiglu(xs, w_gate, w_in, w_out, block_expert, blocks_used, *,
     be = block_expert.astype(jnp.int32)
     used = jnp.asarray(blocks_used, jnp.int32).reshape(1)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    h = _call(_up_kernel, "moe_experts_up", xs,
+    up = partial(_up_kernel, limit=float(limit)) if limit else _up_kernel
+    h = _call(up, "moe_experts_up", xs,
               (w_gate.astype(xs.dtype), w_in.astype(xs.dtype)), be, used,
               layer, bm, f, _tile(f, _fits(d, 2, xs.dtype, 512)), interpret)
     return _call(_down_kernel, "moe_experts_down", h,

@@ -67,6 +67,7 @@ KEY_BLOCK = 512      # keys a turn of a chunk's walk (a divisor of max_len)
 HEADS = 4            # heads a program of the chunk's kernel
 MASK_TILE = 32       # queries its int8 mask's sublane tile holds
 VMEM_LIMIT = 64 * 2 ** 20   # of a core's 128 MiB; the chunk's kernel holds ~20
+IDX_PREFETCH_BYTES = 256 * 2 ** 10   # of SMEM for a step's selected positions
 FLOOR = -2.0 ** 20   # under every score, over BIG_NEG: exp(BIG_NEG - FLOOR) = 0
 
 
@@ -121,13 +122,21 @@ def _halves(w, dtype):
                           jnp.float32).astype(dtype))
 
 
-def _kernel(idx_ref, n_ref, len_ref, layer_ref, q_ref, new_ref, cache_ref,
-            o_ref, out_cache_ref, buf, sem, wsem, m_ref, l_ref, acc_ref, *,
-            group: int, rank: int, scale: float, dtype):
+def _kernel(*refs, group: int, rank: int, scale: float, dtype,
+            idx_block: bool, run: int):
     from jax.experimental.pallas import tpu as pltpu
 
+    # the selection: the whole batch's by scalar prefetch, or (too many
+    # slots for SMEM) this slot's row as a block of its own
+    if idx_block:
+        n_ref, len_ref, layer_ref, idx_ref = refs[:4]
+    else:
+        idx_ref, n_ref, len_ref, layer_ref = refs[:4]
+    (q_ref, new_ref, cache_ref, o_ref, out_cache_ref, buf, sem, wsem, m_ref,
+     l_ref, acc_ref) = refs[4:]
     del cache_ref                       # aliased: out_cache_ref is the cache
     b = pl.program_id(0)
+    row = 0 if idx_block else b
     n, layer = n_ref[b], layer_ref[0]
     G, W = group, buf.shape[-1]
     ng = (n + G - 1) // G
@@ -145,19 +154,27 @@ def _kernel(idx_ref, n_ref, len_ref, layer_ref, q_ref, new_ref, cache_ref,
         write.wait()
 
     def copy(pos, slot, i):
-        return pltpu.make_async_copy(out_cache_ref.at[layer, b, pos],
-                                     buf.at[slot, i], sem.at[slot])
+        # ``run`` consecutive positions a descriptor (a selection of whole
+        # aligned groups: models/dsa.py select_pooled), one where run is 1
+        if run == 1:
+            return pltpu.make_async_copy(out_cache_ref.at[layer, b, pos],
+                                         buf.at[slot, i], sem.at[slot])
+        return pltpu.make_async_copy(
+            out_cache_ref.at[layer, b, pl.ds(pos, run)],
+            buf.at[slot, pl.ds(i * run, run)], sem.at[slot])
+
+    per = G // run                      # descriptors a group of G positions
 
     def each(g, fn):
         def one(i, _):
-            @pl.when(g * G + i < n)
+            @pl.when(g * G + (i if run == 1 else i * run) < n)
             def _():
                 fn(i)
             return 0
-        lax.fori_loop(0, G, one, 0)
+        lax.fori_loop(0, per, one, 0)
 
     def fetch(g, slot):
-        each(g, lambda i: copy(idx_ref[b, g * G + i], slot, i).start())
+        each(g, lambda i: copy(idx_ref[row, g * per + i], slot, i).start())
 
     @pl.when(ng > 0)
     def _():
@@ -201,6 +218,7 @@ def _kernel(idx_ref, n_ref, len_ref, layer_ref, q_ref, new_ref, cache_ref,
 
 def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
                                 rank: int, scale: float, group: int = GROUP,
+                                n=None, run: int = 1,
                                 interpret: Optional[bool] = None):
     """``q`` (B, H, rank + rope): the absorbed queries, in the order the
     latents lie; ``cache`` (L, B, max_len, 1, words) (:func:`pack_rows`),
@@ -208,7 +226,12 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
     rope): this step's latents, written at ``length - 1`` of every slot
     first, in place (the cache comes back aliased, every other position
     bit-untouched); ``idx`` (B, K) i32: the positions selected, the first
-    ``min(length, K)`` of a row valid; ``length`` (B,) AFTER the append.
+    ``min(length, K)`` of a row valid — or the first ``n`` (B,) i32, where
+    a selection of another count says so (pooled keys, ``models/dsa.py``);
+    ``length`` (B,) AFTER the append. ``run`` > 1: the selection is whole
+    aligned groups of ``run`` consecutive positions (``idx[:, j * run + i] =
+    idx[:, j * run] + i``, K a multiple of ``run``): one DMA fetches a group,
+    where a descriptor a position is what the read waits for (56 ns each).
     Returns (``o_lat`` (B, H, rank) = softmax(q . lat . scale) . c over the
     selected positions, the cache)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -222,19 +245,33 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lengths = jnp.minimum(_lengths(length, B), cache.shape[2])
-    n = jnp.minimum(lengths, K)
+    n = jnp.minimum(lengths, K) if n is None else jnp.where(
+        lengths > 0, jnp.minimum(n.astype(jnp.int32), K), 0)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, parts * W - D)))
     G = min(group, -(-K // 8) * 8)
+    if run > 1:
+        if K % run or G % run:
+            raise ValueError(f"{K} selected positions in groups of {G} are "
+                             f"not whole runs of {run}")
+        idx, K = idx[:, ::run], K // run     # (K counts descriptors now)
+    # (B, K) indices by scalar prefetch while they fit a quarter of SMEM's
+    # 1 MiB (10 slots x 2048: 80 KiB); beyond, a slot's row a program
+    idx_block = B * K * 4 > IDX_PREFETCH_BYTES
+    idx = jnp.maximum(idx, 0).astype(jnp.int32)
+    scalars = (n, lengths, jnp.asarray(layer, jnp.int32).reshape(1))
     o, cache = pl.pallas_call(
-        partial(_kernel, group=G, rank=rank, scale=scale, dtype=dtype),
+        partial(_kernel, group=G, rank=rank, scale=scale, dtype=dtype,
+                idx_block=idx_block, run=run),
         name="sparse_mla_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3 if idx_block else 4,
             grid=(B,),
-            in_specs=[pl.BlockSpec((None, H, parts * W),
-                                   lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=([pl.BlockSpec((None, 1, K), lambda b, *_: (b, 0, 0),
+                                    memory_space=pltpu.SMEM)]
+                      if idx_block else [])
+            + [pl.BlockSpec((None, H, parts * W), lambda b, *_: (b, 0, 0)),
+               pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
+               pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((None, H, rank), lambda b, *_: (b, 0, 0)),
                        pl.BlockSpec(memory_space=pl.ANY)],
             scratch_shapes=[pltpu.VMEM((2, G, 1, W), cache.dtype),
@@ -249,9 +286,8 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(jnp.maximum(idx, 0).astype(jnp.int32), n, lengths,
-      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(dtype),
-      pack_rows(new, dtype), cache)
+    )(*((*scalars, idx[:, None]) if idx_block else (idx, *scalars)),
+      q.astype(dtype), pack_rows(new, dtype), cache)
     return o, cache
 
 
@@ -365,18 +401,22 @@ def _chunk_kernel(nb_ref, layer_ref, q_ref, wk_ref, wv_ref, keep_ref,
             copy.wait()
         lat = jnp.concatenate(_halves(rows[slot], dtype), axis=1)
         c = lat[:, :rank].astype(dtype)
-        # k_rope in the first lanes of a tile of its own, 0 behind it
-        tail = lat[:, rank:rank + LANES].astype(jnp.float32)
-        if tail.shape[1] < LANES:
-            tail = jnp.pad(tail, ((0, 0), (0, LANES - tail.shape[1])))
-        tail = jnp.where(
-            lax.broadcasted_iota(jnp.int32, tail.shape, 1) < rope, tail, 0.0)
+        if rope:
+            # k_rope in the first lanes of a tile of its own, 0 behind it
+            tail = lat[:, rank:rank + LANES].astype(jnp.float32)
+            if tail.shape[1] < LANES:
+                tail = jnp.pad(tail, ((0, 0), (0, LANES - tail.shape[1])))
+            tail = jnp.where(
+                lax.broadcasted_iota(jnp.int32, tail.shape, 1) < rope, tail,
+                0.0)
         bias = (keep[slot].astype(jnp.float32) - 1.0) * -BIG_NEG
         nt = (((1,), (1,)), ((), ()))
         for h in range(heads):
             k = jnp.dot(c, wk_ref[h], preferred_element_type=jnp.float32)
-            k = jnp.concatenate([k[:, :split], k[:, split:] + tail],
-                                axis=1).astype(dtype)
+            if rope:
+                k = jnp.concatenate([k[:, :split], k[:, split:] + tail],
+                                    axis=1)
+            k = k.astype(dtype)
             v = jnp.dot(c, wv_ref[h],
                         preferred_element_type=jnp.float32).astype(dtype)
             s = lax.dot_general(q_ref[h], k, nt,
@@ -445,8 +485,11 @@ def sparse_mla_chunk_attention(q_nope, q_rope, wkv_b, cache, keep, n_keys, *,
     hp = _chunk_heads(H, vd, heads)
     # a key's columns: k_nope's whole tiles, then k_rope beside the rest of
     # k_nope in one tile more; the query's and the weight's to match
+    # (a latent with no rope part whose k_nope fills whole tiles: no tile
+    # more)
     split = nope // LANES * LANES
-    gap = LANES - rope - (nope - split)
+    bare = not rope and split == nope
+    gap = 0 if bare else LANES - rope - (nope - split)
 
     def columns(a, mid):
         return jnp.concatenate(
@@ -462,7 +505,7 @@ def sparse_mla_chunk_attention(q_nope, q_rope, wkv_b, cache, keep, n_keys, *,
     keep = jnp.pad(keep, ((0, 0), (0, Tp - T), (0, 0)))
     nb = jnp.minimum((jnp.asarray(n_keys, jnp.int32) + blk - 1) // blk,
                      S // blk)
-    P = split + LANES
+    P = split if bare else split + LANES
     out = pl.pallas_call(
         partial(_chunk_kernel, block=blk, rank=rank, rope=rope, split=split,
                 scale=scale, dtype=dtype),
@@ -495,16 +538,18 @@ def sparse_mla_chunk_attention(q_nope, q_rope, wkv_b, cache, keep, n_keys, *,
     return out[:, :T].reshape(B, T, H, vd)
 
 
-def attend_selected(q, lat, idx, length, *, rank: int, scale: float):
+def attend_selected(q, lat, idx, length, *, rank: int, scale: float,
+                    n=None):
     """The same read in plain ``jax.numpy``: ``q`` (B, H, rank + rope) over
     ``lat`` (B, max_len, rank + rope), the first ``min(length, K)`` of
-    ``idx`` (B, K) attended. What a step traced without the kernels pays,
+    ``idx`` (B, K) attended (or the first ``n`` (B,)). What a step traced
+    without the kernels pays,
     and what the kernel's test holds it to."""
     B, K = idx.shape
     rows = jnp.take_along_axis(lat, jnp.maximum(idx, 0)[..., None], axis=1)
     s = einsum_f32("bhd,bkd->bhk", q, rows.astype(q.dtype)) * scale
-    keep = jnp.arange(K, dtype=jnp.int32)[None, None] \
-        < jnp.minimum(_lengths(length, B), K)[:, None, None]
+    n = jnp.minimum(_lengths(length, B), K) if n is None else n
+    keep = jnp.arange(K, dtype=jnp.int32)[None, None] < n[:, None, None]
     s = jnp.where(keep, s, BIG_NEG)
     p = jnp.where(keep, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
     p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)

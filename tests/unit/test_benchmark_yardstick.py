@@ -34,7 +34,11 @@ below holds the entry itself. Since PR 51 the GLM-5.2 cell joins
 its cell alone) and ``moe.load_max_over_mean`` (which the Nemotron and MiMo
 tests read): a cell ``workloads`` appends after theirs, which ``nh_spec`` and
 ``mm_spec`` take out as they take out every later one;
-``test_glm_moe_dsa.py`` pins no position. The Ouro test has no
+``test_glm_moe_dsa.py`` pins no position. Since PR 55 the GLM-5.3-Flash cell
+joins 24 accepted metrics (among them the Nemotron test's
+``moe.held_rows_share`` and ``ssm.state_bytes_per_slot``), again a cell
+``workloads`` appends after theirs, which the fixtures take out; its three
+own metrics list it alone and ``test_glm5_next.py`` pins no position. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -58,12 +62,14 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
     "benchmark.tests.test_falcon_h1", "benchmark.tests.test_glm_moe_dsa",
+    "benchmark.tests.test_glm5_next",
     "benchmark.tests.test_program_lifecycle",
     "benchmark.tests.test_program_iterations")
 
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_falcon_h1 import *  # noqa: E402,F401,F403
+from benchmark.tests.test_glm5_next import *  # noqa: E402,F401,F403
 from benchmark.tests.test_glm_moe_dsa import *  # noqa: E402,F401,F403
 from benchmark.tests.test_mimo_v2_flash import *  # noqa: E402,F401,F403
 from benchmark.tests.test_nemotron_h import *  # noqa: E402,F401,F403
@@ -79,6 +85,7 @@ from benchmark.tests.test_zaya import *  # noqa: E402,F401,F403
 
 NEMOTRON_CELL = "nemotron-3-super-l11-e128.serve-backlog-think"
 FALCON_CELL = "falcon-h1-34b-l6.serve-backlog-shortchat"
+GLM52_CELL = "glm-5.2-l7-e16.serve-backlog-longctx"
 # appended since the two cells' tests pinned their sets, and listing them
 LATER = {"ssm.block_bytes_per_program"}
 
@@ -91,9 +98,34 @@ def _spec_without_later():
     return spec
 
 
+def _without_cells_after(spec, cell):
+    """``spec`` with the cells ``workloads`` appends after ``cell`` taken
+    out of every metric's list: the file as the PR that added ``cell`` left
+    those lists."""
+    cells = [w["name"] for w in spec["workloads"]]
+    later = set(cells[cells.index(cell) + 1:])
+    for m in spec["per_layer"] + spec["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"] = [w for w in m["workloads"] if w not in later]
+    return spec
+
+
+@pytest.fixture(scope="module")
+def glm_spec():  # noqa: F811
+    # (since PR 55 three of the five metrics the GLM-5.2 test counts as
+    # listing its cell alone list the GLM-5.3-Flash cell behind it)
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        return _without_cells_after(json.load(f), GLM52_CELL)
+
+
 @pytest.fixture(scope="module")
 def fh_spec():  # noqa: F811
-    return _spec_without_later()
+    # (since PR 55 ``ssm.state_share_of_step_bytes``, one of the metrics the
+    # Falcon-H1 test counts as listing its cell alone, lists a later cell)
+    spec = _without_cells_after(_spec_without_later(), FALCON_CELL)
+    spec["per_layer"] = [m for m in spec["per_layer"]
+                         if m.get("workloads") != []]
+    return spec
 
 
 def test_the_block_a_program_takes_is_read_in_both_cells():
@@ -129,12 +161,7 @@ def nh_spec():  # noqa: F811
     spec["per_layer"] = spec["per_layer"][:cut] + [
         m for m in spec["per_layer"][cut:]
         if cell in m.get("workloads", [cell])]
-    cells = [w["name"] for w in spec["workloads"]]
-    later = set(cells[cells.index(cell) + 1:])
-    for m in spec["per_layer"] + spec["end_to_end"]:
-        if "workloads" in m:
-            m["workloads"] = [w for w in m["workloads"] if w not in later]
-    return spec
+    return _without_cells_after(spec, cell)
 
 
 @pytest.fixture(scope="module")

@@ -16,12 +16,13 @@ from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
                                             cache_layout, init_cache,
                                             state_bytes_per_slot)
 from deepspeed_tpu.inference.kinds import (CCA, FEATURES, KINDS, Dense,
-                                           Hybrid, Latent, PagedKVCache,
-                                           ParallelHybrid, SparseLatent,
-                                           Windowed, kind_of)
-from deepspeed_tpu.models import (deepseek_v3, falcon_h1, glm_moe_dsa,
-                                  mimo_v2_flash, nemotron_h, ouro, presets,
-                                  tiny_test, why_not_trained, zaya)
+                                           Hybrid, Latent, LinearSparse,
+                                           PagedKVCache, ParallelHybrid,
+                                           SparseLatent, Windowed, kind_of)
+from deepspeed_tpu.models import (deepseek_v3, falcon_h1, glm5_next,
+                                  glm_moe_dsa, mimo_v2_flash, nemotron_h,
+                                  ouro, presets, tiny_test, why_not_trained,
+                                  zaya)
 from deepspeed_tpu.observability.capacity import kv_cache_bytes
 from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
 from deepspeed_tpu.serving.pages import init_paged_slots
@@ -46,6 +47,8 @@ CASES = {
     "cca": (CCA, lambda: zaya("tiny", dtype=F32)),
     "parallel": (ParallelHybrid, lambda: falcon_h1("tiny", dtype=F32)),
     "sparse-latent": (SparseLatent, lambda: glm_moe_dsa(
+        "tiny", dtype=F32, moe_experts_held=2)),
+    "linear-sparse": (LinearSparse, lambda: glm5_next(
         "tiny", dtype=F32, moe_experts_held=2)),
 }
 CONTIGUOUS = [name for name in CASES if name != "paged"]
@@ -235,6 +238,7 @@ PRESETS = [(fn, size) for fn, sizes in (
     (presets.mimo_v2_flash, ("tiny", "flash")), (presets.zaya, ("tiny",)),
     (presets.falcon_h1, ("tiny", "34b")),
     (presets.glm_moe_dsa, ("tiny", "5.2")),
+    (presets.glm5_next, ("tiny", "5.3-flash")),
     (presets.tiny_test, (None,))) for size in sizes]
 
 

@@ -1311,3 +1311,132 @@ def test_the_latent_kind_s_programs_are_the_parent_s(one_chip, monkeypatch,
     assert text.count("BODY") == (6 if program == "slot step" else 2)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == KANANA_TEXT[program]
+
+
+# --- delta-rule mixers beside selected latent attention (GLM-5.3-Flash) --
+GLM53 = dict(slots=160, max_len=8192, chunk=512)
+
+
+@pytest.fixture(scope="module")
+def glm53(one_chip):
+    """(cfg, model, abstract served params) of GLM-5.3-Flash's share
+    (``benchmark/configs/glm-5.3-flash-l5-e36.json``: layers K A K K K, 36 of
+    288 experts) at the published widths: built once for both programs."""
+    import json
+
+    from benchmark.models import glm5_next as fam
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.3-flash-l5-e36.json")) as f:
+        cfg = fam.model_config(json.load(f)["config"], "bfloat16")
+    model = build_model(cfg)
+    keep = set(model.fp32_param_names())
+
+    def served(path, a):
+        name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype if name in keep else jnp.bfloat16,
+            sharding=one_chip)
+
+    return cfg, model, jax.tree_util.tree_map_with_path(
+        served, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+
+@pytest.mark.parametrize("program", ["slot step", "final chunk"])
+def test_linear_sparse_trunk_fits_and_moves_its_state_in_place(
+        one_chip, monkeypatch, glm53, program, capsys):
+    """GLM-5.3-Flash's share at the cell's 160 slots x 8192, chunks of 512:
+    the five buffers enter donated and leave aliased; every program's live
+    set beside what else stands on the chip (the slots' state beside a
+    chunk, the batch-1 prefill cache beside the step) stays under 13.5 GiB
+    of the chip's 15.75; the step holds two calls of the state step (a run of
+    one layer, a scan of three), one each of the pooled keys' append, the
+    score and the selected read, and NO other operation touches the
+    delta-rule state; a final chunk holds one call of the chunk's attention
+    kernel and carries no (64, 512, 8192) score array."""
+    import time
+
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    g = GLM53
+    cfg, model, params = glm53
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    # a row is the latent alone (no rope part, no padding), a pooled key a
+    # group of 4; the state a slot as ISSUE 55 counts it
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 1024 + 64
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 17367808 \
+        == 4 * (64 * 128 * 128 * 4 + 3 * 3 * 8192 * 2) + 3 * 128 * 2
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 4718150030 \
+        and abs(weights / 2 ** 30 - 8.80) < 0.01
+    a_slot = g["max_len"] * 1088 + 17367808
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(lambda: init_slots(
+            cfg, g["slots"], g["max_len"], jnp.bfloat16)))
+        assert state.cache.kda.shape == (4, 160, 64, 128, 128) \
+            and state.cache.c.shape == (1, 160, 8192, 1, 256) \
+            and state.cache.ik.shape == (1, 160, 128, 2048)
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        held, beside = g["slots"] * a_slot, a_slot
+    else:
+        ids = jax.ShapeDtypeStruct((1, g["chunk"]), jnp.int32,
+                                   sharding=one_chip)
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, g["max_len"], jnp.bfloat16)))
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start), flash_decode=True,
+                last_token_head=True, last_index=last, with_stats=True,
+                with_routing=True),
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        held, beside = a_slot, g["slots"] * a_slot
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\n[linear sparse {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB; with "
+              f"what stands beside it {(live + beside) / 2 ** 30:.2f} GiB]")
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert live + beside < 13.5 * 2 ** 30, (live + beside) / 2 ** 30
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "kda_state_step", "sparse_mla_decode_attention",
+        "sparse_mla_chunk_attention", "dsa_index_score", "mla_cache_append",
+        "moe_experts_up")}
+    step = program == "slot step"
+    assert count == {
+        "kda_state_step": 2 if step else 0,
+        "sparse_mla_decode_attention": 1 if step else 0,
+        "sparse_mla_chunk_attention": 0 if step else 1,
+        "dsa_index_score": 1 if step else 0,
+        "mla_cache_append": 1 if step else 0, "moe_experts_up": 2}, count
+    assert not re.search(r"f32\[(1,)?64,512,8192\]", text)
+    if step:
+        state_buf = "f32[4,160,64,128,128]"
+        passes = ("custom-call(", "parameter(", "get-tuple-element(",
+                  " tuple(", "while(", "bitcast(")
+        touched = [ln for ln in text.splitlines()
+                   if ln.lstrip().startswith(("%", "ROOT")) and " = " in ln
+                   and state_buf in ln and not any(p in ln for p in passes)]
+        assert not touched, touched[:3]
