@@ -25,10 +25,12 @@ out, every sub-layer reading and writing through its own maps. The T == 1
 step, an attention layer: the new key joins the open group's (or closes it:
 the mean goes into ``ik``, in place, ``mla_cache_append``); the slot's closed
 groups are scored (``dsa_index_score``), the ``index_topk / index_kpool``
-best and the open one selected (``dsa.select_pooled``), their positions'
-latents fetched — a group's ``index_kpool`` rows lie side by side: one DMA a
-group — and attended absorbed (``sparse_mla_decode_attention``, which appends
-the step's own row first). A KDA layer: ``kda_state_step`` moves the
+best and the open one selected (``dsa.select_pooled``), and the slot's
+latents attended absorbed over them (``sparse_mla_decode_attention``, which
+appends the step's own row first, then reads the slot's live blocks whole
+under the selection's mask — or, past ``sparse.reads_dense``'s crossover,
+fetches the selected groups, whose ``index_kpool`` rows lie side by side: one
+DMA a group). A KDA layer: ``kda_state_step`` moves the
 state in place. T > 1 (a chunk that starts at a group's edge — the
 scheduler's chunks start at multiples of ``prefill_chunk`` — or a solo
 prefill): XLA's updates, the chunkwise scan (``kda.scan_chunked``), the
@@ -50,7 +52,7 @@ from ...ops import mla_attention
 from ...ops import sparse_mla_attention as sparse
 from ..quantization import matmul_any
 from .base import IN_POOL, MOVES_PAGES, Kind, _nbytes, held_counts, split_banks
-from .sparse_latent import SparseLatent, _index, query_blocks
+from .sparse_latent import SparseLatent, _index, query_blocks, read_meta
 
 LinearSparseCache = namedtuple("LinearSparseCache",
                                "ik c kda conv ikt length")
@@ -275,9 +277,14 @@ class LinearSparse(Kind):
                 groups = None if per_slot else (new_len + pool - 1) // pool
                 score = dsa.scores(qi, w, keys, groups)
             idx, n, mask = dsa.select_pooled(
-                score, pos, topk, pool, want_mask=T > 1, n_groups=groups)
-            # the kernel's DMAs take bytes
-            return idx, n, mask.astype(jnp.int8) if fused and T > 1 else mask
+                score, pos, topk, pool, want_mask=T > 1 or fused,
+                n_groups=groups)
+            if fused:
+                # the kernels' DMAs take bytes (a chunk's) or a row added
+                # to the scores (the step's, which may read dense under it)
+                mask = mask.astype(jnp.int8) if T > 1 \
+                    else sparse.step_mask(mask)
+            return idx, n, mask
 
         def read_block(c, ai):
             def read(j, blk):
@@ -298,14 +305,14 @@ class LinearSparse(Kind):
                 ik2, ikt2 = keys_in(ik, ikt, dsa.index_keys(cfg, y, ip,
                                                             positions), fi)
                 if T == 1:
-                    idx, n, _ = choose(y, cq, ip, ik2, fi, positions)
+                    idx, n, keep = choose(y, cq, ip, ik2, fi, positions)
                     idx, n = idx[:, 0], n[:, 0]
                     q = mla.absorb_q(cfg, p, q_nope, q_rope)
                     if fused:
                         o_lat, c2 = sparse.sparse_mla_decode_attention(
                             q, c, new[:, 0], idx, new_len, layer=ai,
                             rank=cfg.kv_lora_rank, scale=scale, n=n,
-                            run=pool)
+                            run=pool, mask=keep)
                     else:
                         slab = jax.vmap(
                             lambda s, r, at: lax.dynamic_update_slice(
@@ -403,6 +410,13 @@ class LinearSparse(Kind):
                                   length=new_len), stats, None)
 
     # ------------------------------------------------------------ the spans
+    def _chosen(self, n):
+        """The positions a query at the end of ``n`` reads (arrays): the
+        best closed groups whole and its own group up to itself."""
+        pool = self.cfg.index_kpool
+        return np.minimum((n - 1) // pool, self.cfg.index_topk // pool) \
+            * pool + (n - 1) % pool + 1
+
     def _dsa(self, n) -> dict:
         """Of queries at the ends of ``n`` positions each (an array): the
         positions their attention layers read, the keys their indexers
@@ -412,8 +426,7 @@ class LinearSparse(Kind):
         n = np.asarray(n)
         pool = cfg.index_kpool
         closed = (n - 1) // pool
-        chosen = int((np.minimum(closed, cfg.index_topk // pool) * pool
-                      + (n - 1) % pool + 1).sum())
+        chosen = int(self._chosen(n).sum())
         scored = int((closed + (n - 1) % pool + 1).sum())
         live = int(n.sum())
         return {"dsa_selected": chosen, "dsa_live": live,
@@ -458,7 +471,11 @@ class LinearSparse(Kind):
         meta = self.sizes()
         held = held_counts(self, read, pending)
         if lens is not None:
-            meta.update(self._dsa(lens[lens > 0]))
+            live = lens[lens > 0]
+            meta.update(self._dsa(live))
+            if self.flash:      # the kernel reads, by its own rule
+                meta.update(read_meta(self, live, self._chosen(live),
+                                      cfg.index_kpool))
             itemsize = jnp.dtype(self.dtype or cfg.dtype).itemsize
             state = 2 * len(running) * self.slot_bytes
             moved = {

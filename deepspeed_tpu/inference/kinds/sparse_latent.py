@@ -13,9 +13,12 @@
 The T == 1 step, a layer: append ``c`` (inside the sparse kernel) and, in
 an ``F`` layer, ``kI`` (``mla_cache_append``), both in place; in an ``F``
 layer score the slot's live keys (``dsa_index_score``) and select
-(``dsa.select``: a threshold by bisection over the scores' bits, no sort);
-fetch the selected positions' latents and attend absorbed
-(``sparse_mla_decode_attention``). An ``s`` layer takes the selection the
+(``dsa.select``: a threshold by bisection over the scores' bits, no sort:
+the positions and, on the kernels, their mask); bring in the slot's latents
+and attend absorbed over the selected (``sparse_mla_decode_attention``: a
+slot's live blocks whole under the mask where its live rows are few enough
+a selected one, a descriptor a selected row beyond — ``sparse.reads_dense``,
+which ``step_meta`` counts by too). An ``s`` layer takes the selection the
 carry holds. T > 1 (a chunk, a solo prefill) writes with XLA's update,
 selects likewise and walks the live blocks with the selection as a mask:
 exact, the work of dense attention, in blocks of :data:`QUERY_BLOCK` queries
@@ -71,6 +74,28 @@ def query_blocks(fn, T: int, *xs):
     return jax.tree.map(
         lambda a: a.swapaxes(0, 1).reshape(
             a.shape[1], nb * QUERY_BLOCK, *a.shape[3:])[:, :T], out)
+
+
+def read_meta(kind, lens, chosen, run: int) -> dict:
+    """How the step's kernel brings in the latents of running slots at
+    ``lens`` live positions of which ``chosen`` are selected (arrays, a
+    slot), by the kernel's own rule (``sparse.reads_dense``) on the host's
+    mirror: the share of the slots that read their live blocks whole, and
+    the rows the reads bring in — whole blocks on that side, the selected
+    rows on the other — over the selected rows."""
+    from ...observability.metrics import get_registry
+
+    words, wdt, _ = sparse.row_layout(kind.cfg.latent_dim,
+                                      kind.dtype or kind.cfg.dtype)
+    dense = sparse.reads_dense(lens, chosen, run,
+                               words * jnp.dtype(wdt).itemsize)
+    rows = np.where(dense, sparse.dense_rows(lens, kind.max_len), chosen)
+    reg = get_registry()
+    reg.counter("Serve/dsa_dense_reads").inc(int(dense.sum()))
+    reg.counter("Serve/dsa_gathered_reads").inc(int((~dense).sum()))
+    return {"dsa_dense_share": float(dense.sum()) / max(len(lens), 1),
+            "dsa_rows_read_over_selected":
+                float(rows.sum()) / max(int(chosen.sum()), 1)}
 
 
 class SparseLatent(Kind):
@@ -151,11 +176,12 @@ class SparseLatent(Kind):
                 fused):
         """Each run of layers equal in (FFN kind, indexer kind) scans its
         layers (``dsa.runs``), all carrying (x, c, ik, the selection). The
-        selection is ``idx`` (B, K) for a step and (``idx`` (B, T, K),
-        ``mask`` (B, T, max_len)) for T > 1. Stats: (counters (expert
-        layers, 4), (routing (expert layers, B, T, k), the F layers' idx (F,
-        B, T, K))) — the second what a comparison with a reference follows
-        and ``ServingEngine.routing_log`` taps."""
+        selection is (``idx`` (B, K), ``mask`` (B, 1, max_len) float32 as
+        the step's kernel adds it, None off the kernels) for a step and
+        (``idx`` (B, T, K), ``mask`` (B, T, max_len)) for T > 1. Stats:
+        (counters (expert layers, 4), (routing (expert layers, B, T, k), the
+        F layers' idx (F, B, T, K))) — the second what a comparison with a
+        reference follows and ``ServingEngine.routing_log`` taps."""
         cfg = self.cfg
         B, T, _ = x.shape
         per_slot = getattr(new_len, "ndim", 0) == 1
@@ -189,7 +215,10 @@ class SparseLatent(Kind):
             if fused and T == 1:
                 score = sparse.index_scores(qi[:, 0], w[:, 0], ik, new_len,
                                             layer=full)
-                return dsa.select(score[:, None], pos, K, want_mask=False)
+                # the mask beside the indices: the kernel reads a slot's
+                # live blocks whole under it where that is the cheaper fetch
+                idx, mask = dsa.select(score[:, None], pos, K)
+                return idx, sparse.step_mask(mask)
             keys = lax.dynamic_index_in_dim(ik, full, keepdims=False)
             live = None if per_slot else new_len
             idx, mask = dsa.select(dsa.scores(qi, w, keys, live), pos, K,
@@ -217,7 +246,8 @@ class SparseLatent(Kind):
                 ik = append_keys(ik, dsa.index_keys(cfg, y, ip, positions),
                                  full)
                 if T == 1:
-                    sel = choose(y, p, ip, ik, full, positions)[0][:, 0]
+                    idx, mask = choose(y, p, ip, ik, full, positions)
+                    sel = (idx[:, 0], mask)
                 else:
                     sel = blocks(lambda y, pos: choose(y, p, ip, ik, full,
                                                        pos), y, positions)
@@ -242,8 +272,8 @@ class SparseLatent(Kind):
                 q = mla.absorb_q(cfg, p, q_nope, q_rope)
                 if fused:
                     o_lat, c = sparse.sparse_mla_decode_attention(
-                        q, c, new[:, 0], sel, new_len, layer=layer,
-                        rank=cfg.kv_lora_rank, scale=scale)
+                        q, c, new[:, 0], sel[0], new_len, layer=layer,
+                        rank=cfg.kv_lora_rank, scale=scale, mask=sel[1])
                 else:
                     # XLA's update on the layer's slab, its rows unpacked
                     slab = jax.vmap(lambda s, r, at: lax.dynamic_update_slice(
@@ -256,15 +286,16 @@ class SparseLatent(Kind):
                                                  (layer, 0, 0, 0, 0))
                     o_lat = sparse.attend_selected(
                         q, sparse.unpack_rows(slab, cfg.latent_dim, dt),
-                        sel, new_len, rank=cfg.kv_lora_rank, scale=scale)
+                        sel[0], new_len, rank=cfg.kv_lora_rank, scale=scale)
                 o = mla.absorb_o(cfg, p, o_lat)
             x, stats = _out_ffn(model, x, o, p, banks, local,
                                 cfg.moe_router == "sigmoid")
-            pick = sel[0] if T > 1 else sel[:, None]
+            pick = sel[0] if T > 1 else sel[0][:, None]
             return (x, c, ik, sel), (stats, pick)
 
         if T == 1:
-            sel = jnp.zeros((B, K), jnp.int32)
+            sel = (jnp.zeros((B, K), jnp.int32),
+                   jnp.zeros((B, 1, S), jnp.float32) if fused else None)
         else:
             sel = (jnp.zeros((B, T, K), jnp.int32),
                    jnp.zeros((B, T, S), jnp.int8 if fused else bool))
@@ -302,6 +333,10 @@ class SparseLatent(Kind):
         return (x, SparseLatentCache(c=c, ik=ik, length=new_len), stats, None)
 
     # ------------------------------------------------------------ the spans
+    def _chosen(self, n):
+        """The positions a query at the end of ``n`` reads (arrays)."""
+        return np.minimum(n, self.cfg.index_topk)
+
     def _dsa(self, n) -> dict:
         """Of queries at the ends of ``n`` positions each (an array): the
         positions their layers' attention reads, those that are live, and
@@ -309,7 +344,7 @@ class SparseLatent(Kind):
         them."""
         cfg = self.cfg
         n = np.asarray(n)
-        chosen = int(np.minimum(n, cfg.index_topk).sum())
+        chosen = int(self._chosen(n).sum())
         live = int(n.sum())
         words, wdt, _ = sparse.row_layout(cfg.latent_dim, self.dtype
                                           or cfg.dtype)
@@ -334,7 +369,10 @@ class SparseLatent(Kind):
 
         meta = {"cache_bytes_per_token": self.token_bytes}
         if lens is not None:
-            meta.update(self._dsa(lens[lens > 0]))
+            live = lens[lens > 0]
+            meta.update(self._dsa(live))
+            if self.flash:      # the kernel reads, by its own rule
+                meta.update(read_meta(self, live, self._chosen(live), 1))
             reg = get_registry()
             reg.counter("Serve/dsa_selected_positions").inc(
                 meta["dsa_selected"])

@@ -1,7 +1,9 @@
 """The latent (MLA) decode step over SELECTED positions: one absorbed query
 per head against the latents of the positions a learned indexer chose
-(``models/dsa.py``), fetched one position at a time, and the in-place
-append of the step's own latents, in one Pallas kernel.
+(``models/dsa.py``) — fetched one position at a time or, where a slot's live
+positions are few enough a selected one, its live blocks read whole under
+the selection's mask — and the in-place append of the step's own latents, in
+one Pallas kernel.
 
 **The layout, and why.** ``ops/mla_attention.py`` keeps positions on the
 lanes (``(L, B, rank + rope, max_len)``): a block of 128 neighbours is one
@@ -22,11 +24,19 @@ holds its values as they are, padded likewise.
 
 ``sparse_mla_decode_attention``: grid (slots,). A program writes its slot's
 new latents at ``length - 1`` (DMA, waited for: the position may be among
-the selected), then walks its ``n = min(length, K)`` selected positions in
-groups of ``group``: the group's DMAs go out while the group before is
-multiplied (two buffers), ``s = q . lat^T`` for all heads at once, the
-running softmax, ``o += p . c``. A slot at length 0 writes, fetches and
-multiplies nothing.
+the selected), then brings the slot's latents into VMEM one of two ways,
+chosen from the scalars it prefetches (:func:`reads_dense`: ``length`` over
+``n`` against the ratio of the two measured costs). **Gathered:** it walks
+its ``n = min(length, K)`` selected positions in groups of ``group``: the
+group's DMAs — a descriptor a position, 56 ns each whatever it carries — go
+out while the group before is multiplied (two buffers). **Dense:** it walks
+its ``ceil(length / block)`` live blocks whole (a contiguous copy a block,
+re-tiled by the DMA, at 600-700 B/ns; two buffers) with the selection as a
+row added to the scores (:func:`step_mask`: 0 or ``BIG_NEG``), rows behind
+the live length zeroed. Behind either fetch ``s = q . lat^T`` for all heads
+at once, the running softmax, ``o += p . c``: the same softmax over the same
+positions, the order of addition apart. A slot at length 0 writes, fetches
+and multiplies nothing.
 
 ``index_scores`` (kernel ``dsa_index_score``): the indexer's weighted ReLU
 score of a slot's live keys for the step, block by block, nothing behind the
@@ -63,12 +73,53 @@ from .decode_attention import BIG_NEG, LANES
 from .mla_attention import _lengths, _refuse_mesh
 
 GROUP = 256          # positions a buffer holds (two of them in VMEM)
+DENSE_BLOCK = 1024   # live positions a turn of the step's dense walk
+# What decides how a slot's latents come into VMEM: two measured costs
+# (examples/sparse_mla_decode_attention_microbench.py, PR 56). A descriptor
+# costs what it costs to issue whatever it carries: 56 ns one row, 78 ns a
+# run of four. A row read among its neighbours costs its bytes at the rate
+# the dense walk holds over a long slot: 707 B/ns in rows of 1536 B, 597 in
+# rows of 1024 B (the products, not the HBM, bound the narrower row); the
+# lower of the two, rounded, so that a slot near the edge gathers.
+DESCRIPTOR_NS = (56.0, 78.0)
+DENSE_BYTES_PER_NS = 600.0
 KEY_BLOCK = 512      # keys a turn of a chunk's walk (a divisor of max_len)
 HEADS = 4            # heads a program of the chunk's kernel
 MASK_TILE = 32       # queries its int8 mask's sublane tile holds
 VMEM_LIMIT = 64 * 2 ** 20   # of a core's 128 MiB; the chunk's kernel holds ~20
 IDX_PREFETCH_BYTES = 256 * 2 ** 10   # of SMEM for a step's selected positions
 FLOOR = -2.0 ** 20   # under every score, over BIG_NEG: exp(BIG_NEG - FLOOR) = 0
+
+
+def crossover(run: int, row_bytes: int) -> float:
+    """Live rows a selected one at which fetching a slot's live blocks whole
+    costs what a descriptor a selected position (a run of ``run``) costs."""
+    return DESCRIPTOR_NS[run > 1] / run * DENSE_BYTES_PER_NS / row_bytes
+
+
+def reads_dense(length, n, run: int, row_bytes: int):
+    """Whether a slot of ``length`` live positions of which ``n`` are
+    selected reads its live blocks whole, the selection a mask, and not a
+    descriptor a selected row: ``length <= crossover * n``, in sixteenths so
+    that the kernel's scalar core, the wrapper and the host's mirror
+    (``step_meta``) count with the same integers. Python ints, numpy arrays
+    or traced int32."""
+    return length * 16 <= int(16 * crossover(run, row_bytes)) * n
+
+
+def dense_rows(length, max_len: int, block: int = DENSE_BLOCK):
+    """The rows a dense walk over ``length`` live positions of a cache of
+    ``max_len`` brings in: whole blocks."""
+    blk = _key_block(max_len, block)
+    return -(-length // blk) * blk
+
+
+def step_mask(mask):
+    """A step's selection ``mask`` (B, 1, S) bool as the kernel takes it: a
+    float32 row a slot, 0 where selected and ``BIG_NEG`` elsewhere — added
+    to the scores, and a row of ONE query in whole (1, 128) tiles, which an
+    int8 row is not."""
+    return jnp.where(mask, 0.0, BIG_NEG).astype(jnp.float32)
 
 
 def einsum_f32(spec: str, a, b):
@@ -122,8 +173,8 @@ def _halves(w, dtype):
                           jnp.float32).astype(dtype))
 
 
-def _kernel(*refs, group: int, rank: int, scale: float, dtype,
-            idx_block: bool, run: int):
+def _kernel(*refs, group: int, rank: int, values: int, scale: float, dtype,
+            idx_block: bool, run: int, block: int):
     from jax.experimental.pallas import tpu as pltpu
 
     # the selection: the whole batch's by scalar prefetch, or (too many
@@ -132,8 +183,12 @@ def _kernel(*refs, group: int, rank: int, scale: float, dtype,
         n_ref, len_ref, layer_ref, idx_ref = refs[:4]
     else:
         idx_ref, n_ref, len_ref, layer_ref = refs[:4]
-    (q_ref, new_ref, cache_ref, o_ref, out_cache_ref, buf, sem, wsem, m_ref,
-     l_ref, acc_ref) = refs[4:]
+    if block:       # the selection as a mask too: a slot may read dense
+        (q_ref, new_ref, mask_ref, cache_ref, o_ref, out_cache_ref, buf, sem,
+         wsem, m_ref, l_ref, acc_ref, rows, bias, dsem) = refs[4:]
+    else:
+        (q_ref, new_ref, cache_ref, o_ref, out_cache_ref, buf, sem, wsem,
+         m_ref, l_ref, acc_ref) = refs[4:]
     del cache_ref                       # aliased: out_cache_ref is the cache
     b = pl.program_id(0)
     row = 0 if idx_block else b
@@ -141,8 +196,18 @@ def _kernel(*refs, group: int, rank: int, scale: float, dtype,
     G, W = group, buf.shape[-1]
     ng = (n + G - 1) // G
     parts = 2 if buf.dtype == jnp.uint32 else 1
+    lowest = BIG_NEG
+    if block:
+        # one slot, one fetch: its live blocks whole where the live rows
+        # are few enough a selected one, else a descriptor a selected row
+        dense = reads_dense(len_ref[b], n, run,
+                            W * jnp.dtype(buf.dtype).itemsize)
+        nb = jnp.where(dense, (len_ref[b] + block - 1) // block, 0)
+        ng = jnp.where(dense, 0, ng)
+        # (the dense walk adds its mask to the scores: as the chunk's)
+        lowest = jnp.where(dense, FLOOR, BIG_NEG)
 
-    m_ref[...] = jnp.full(m_ref.shape, BIG_NEG, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, lowest, jnp.float32)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
@@ -212,13 +277,88 @@ def _kernel(*refs, group: int, rank: int, scale: float, dtype,
         return 0
 
     lax.fori_loop(0, ng, body, 0)
+    if block:
+        _dense_walk(nb, len_ref[b], out_cache_ref.at[layer, b],
+                    mask_ref.at[b], q_ref, rows, bias, dsem, m_ref, l_ref,
+                    acc_ref, block=block, rank=rank, values=values,
+                    scale=scale, dtype=dtype)
     o_ref[...] = (acc_ref[:, :rank] / jnp.maximum(l_ref[...], 1e-30)
                   ).astype(o_ref.dtype)
 
 
+def _dense_walk(nb, length, cache_ref, mask_ref, q_ref, rows, bias, sem,
+                m_ref, l_ref, acc_ref, *, block: int, rank: int, values: int,
+                scale: float, dtype):
+    """The other fetch of the step's kernel: the slot's first ``nb`` blocks
+    of ``block`` positions, whole (``cache_ref`` (max_len, 1, words): a
+    contiguous copy, re-tiled by the DMA, two buffers), under the selection
+    as an additive mask (``mask_ref`` (1, max_len)): the same softmax over
+    the same selected positions. Only the lanes that hold values are
+    multiplied: ``values`` of them in the scores, ``rank`` in the sums."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    W = rows.shape[-1]
+    parts = 2 if rows.dtype == jnp.uint32 else 1
+    nt = (((1,), (1,)), ((), ()))
+
+    def lanes(count):       # of each part's W lanes, those under ``count``
+        return [min(W, -(-max(count - i * W, 0) // LANES) * LANES)
+                for i in range(parts)]
+
+    scored, summed = lanes(values), lanes(rank)
+
+    def copies(j, slot):
+        at = pl.ds(pl.multiple_of(j * block, block), block)
+        return (pltpu.make_async_copy(cache_ref.at[at, 0], rows.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(mask_ref.at[:, at], bias.at[slot],
+                                      sem.at[1, slot]))
+
+    @pl.when(nb > 0)
+    def _():
+        for copy in copies(0, 0):
+            copy.start()
+
+    def body(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < nb)
+        def _():
+            for copy in copies(j + 1, 1 - slot):
+                copy.start()
+
+        for copy in copies(j, slot):
+            copy.wait()
+        # what lies behind the live length is another request's: 0 x NaN
+        live = j * block + lax.broadcasted_iota(jnp.int32, (block, 1), 0) \
+            < length
+        lat = _halves(jnp.where(live, rows[slot], 0), dtype)
+        q = q_ref[...]                                   # (H, parts * W)
+        s = sum(lax.dot_general(q[:, i * W:i * W + k], lat[i][:, :k], nt,
+                                preferred_element_type=jnp.float32)
+                for i, k in enumerate(scored) if k)
+        s = s * scale + bias[slot]
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        for i, k in enumerate(summed):
+            at = slice(i * W, i * W + k)
+            if k:
+                acc_ref[:, at] = acc_ref[:, at] * corr + jnp.dot(
+                    p.astype(dtype), lat[i][:, :k],
+                    preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return 0
+
+    lax.fori_loop(0, nb, body, 0)
+
+
 def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
                                 rank: int, scale: float, group: int = GROUP,
-                                n=None, run: int = 1,
+                                n=None, run: int = 1, mask=None,
+                                block: int = DENSE_BLOCK,
                                 interpret: Optional[bool] = None):
     """``q`` (B, H, rank + rope): the absorbed queries, in the order the
     latents lie; ``cache`` (L, B, max_len, 1, words) (:func:`pack_rows`),
@@ -232,19 +372,24 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
     aligned groups of ``run`` consecutive positions (``idx[:, j * run + i] =
     idx[:, j * run] + i``, K a multiple of ``run``): one DMA fetches a group,
     where a descriptor a position is what the read waits for (56 ns each).
+    ``mask`` (B, 1, max_len) f32 (:func:`step_mask`): the same selection —
+    the valid ``idx`` of a row, each once — as a row added to the scores.
+    With it a slot whose live rows are few enough a selected one
+    (:func:`reads_dense`) reads its live blocks of ``block`` positions whole
+    and attends under the mask; without it every slot fetches by ``idx``.
     Returns (``o_lat`` (B, H, rank) = softmax(q . lat . scale) . c over the
     selected positions, the cache)."""
     from jax.experimental.pallas import tpu as pltpu
 
     _refuse_mesh("sparse_mla_decode_attention")
     B, H, D = q.shape
-    W = cache.shape[-1]
+    S, W = cache.shape[2], cache.shape[-1]
     K = idx.shape[1]
     dtype = q.dtype
     parts = 2 if cache.dtype == jnp.uint32 else 1
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    lengths = jnp.minimum(_lengths(length, B), cache.shape[2])
+    lengths = jnp.minimum(_lengths(length, B), S)
     n = jnp.minimum(lengths, K) if n is None else jnp.where(
         lengths > 0, jnp.minimum(n.astype(jnp.int32), K), 0)
     q = jnp.pad(q, ((0, 0), (0, 0), (0, parts * W - D)))
@@ -259,9 +404,11 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
     idx_block = B * K * 4 > IDX_PREFETCH_BYTES
     idx = jnp.maximum(idx, 0).astype(jnp.int32)
     scalars = (n, lengths, jnp.asarray(layer, jnp.int32).reshape(1))
+    blk = 0 if mask is None else _key_block(S, block)
+    masks = [] if mask is None else [mask.astype(jnp.float32)]
     o, cache = pl.pallas_call(
-        partial(_kernel, group=G, rank=rank, scale=scale, dtype=dtype,
-                idx_block=idx_block, run=run),
+        partial(_kernel, group=G, rank=rank, values=D, scale=scale,
+                dtype=dtype, idx_block=idx_block, run=run, block=blk),
         name="sparse_mla_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 if idx_block else 4,
@@ -270,8 +417,8 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
                                     memory_space=pltpu.SMEM)]
                       if idx_block else [])
             + [pl.BlockSpec((None, H, parts * W), lambda b, *_: (b, 0, 0)),
-               pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
-               pl.BlockSpec(memory_space=pl.ANY)],
+               pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(masks)),
             out_specs=[pl.BlockSpec((None, H, rank), lambda b, *_: (b, 0, 0)),
                        pl.BlockSpec(memory_space=pl.ANY)],
             scratch_shapes=[pltpu.VMEM((2, G, 1, W), cache.dtype),
@@ -279,15 +426,19 @@ def sparse_mla_decode_attention(q, cache, new, idx, length, *, layer,
                             pltpu.SemaphoreType.DMA(()),
                             pltpu.VMEM((H, 1), jnp.float32),
                             pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, parts * W), jnp.float32)]),
+                            pltpu.VMEM((H, parts * W), jnp.float32)]
+            + ([pltpu.VMEM((2, blk, W), cache.dtype),
+                pltpu.VMEM((2, 1, blk), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2))] if masks else [])),
         out_shape=[jax.ShapeDtypeStruct((B, H, rank), dtype),
                    jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
-        input_output_aliases={6: 1},
+        input_output_aliases={6 + len(masks): 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT if masks else None),
         interpret=interpret,
     )(*((*scalars, idx[:, None]) if idx_block else (idx, *scalars)),
-      q.astype(dtype), pack_rows(new, dtype), cache)
+      q.astype(dtype), pack_rows(new, dtype), *masks, cache)
     return o, cache
 
 

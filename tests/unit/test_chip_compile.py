@@ -1150,9 +1150,12 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
     10 x 32 768 the step); a chunk and a final chunk hold four calls of the
     chunk's attention kernel (one a run of layers) and nothing of the walk
     it replaced; the step holds four calls of the sparse read (one
-    a run of layers), two of the score and of the key append, and NO other
-    operation touches the latents' buffer: a layer's live latents are read
-    by no one, the selected rows by the kernel's own DMAs."""
+    a run of layers), each handed the selection's mask beside its indices
+    (the dense side is in the program: a slot's live blocks whole under the
+    mask, or a descriptor a selected row, by ``reads_dense`` a slot), two of
+    the score and of the key append, and NO other operation touches the
+    latents' buffer: XLA reads no layer's latents, the kernel's own DMAs
+    bring in what it attends."""
     import time
 
     from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
@@ -1238,6 +1241,9 @@ def test_sparse_latent_trunk_fits_and_reads_the_selection_only(
         # (a chunk with no head drops its last layer's experts)
         "moe_experts_up": 3}, count
     if step:
+        reads = [ln for ln in calls
+                 if "/sparse_mla_decode_attention/pallas_call" in ln]
+        assert all("f32[10,1,32768]" in ln for ln in reads), reads[:1]
         latents = "u32[7,10,32768,1,384]"
         passes = ("custom-call(", "parameter(", "get-tuple-element(",
                   " tuple(", "while(", "bitcast(")
@@ -1353,8 +1359,9 @@ def test_linear_sparse_trunk_fits_and_moves_its_state_in_place(
     chunk, the batch-1 prefill cache beside the step) stays under 13.5 GiB
     of the chip's 15.75; the step holds two calls of the state step (a run of
     one layer, a scan of three), one each of the pooled keys' append, the
-    score and the selected read, and NO other operation touches the
-    delta-rule state; a final chunk holds one call of the chunk's attention
+    score and the selected read — handed the selection's mask beside its
+    groups of 4, the dense side in the program — and NO other operation
+    touches the delta-rule state or the latents' buffer; a final chunk holds one call of the chunk's attention
     kernel and carries no (64, 512, 8192) score array."""
     import time
 
@@ -1433,10 +1440,53 @@ def test_linear_sparse_trunk_fits_and_moves_its_state_in_place(
         "mla_cache_append": 1 if step else 0, "moe_experts_up": 2}, count
     assert not re.search(r"f32\[(1,)?64,512,8192\]", text)
     if step:
-        state_buf = "f32[4,160,64,128,128]"
+        reads = [ln for ln in calls
+                 if "/sparse_mla_decode_attention/pallas_call" in ln]
+        assert all("f32[160,1,8192]" in ln for ln in reads), reads[:1]
         passes = ("custom-call(", "parameter(", "get-tuple-element(",
                   " tuple(", "while(", "bitcast(")
-        touched = [ln for ln in text.splitlines()
-                   if ln.lstrip().startswith(("%", "ROOT")) and " = " in ln
-                   and state_buf in ln and not any(p in ln for p in passes)]
-        assert not touched, touched[:3]
+        for buf in ("f32[4,160,64,128,128]", "u32[1,160,8192,1,256]"):
+            touched = [ln for ln in text.splitlines()
+                       if ln.lstrip().startswith(("%", "ROOT"))
+                       and " = " in ln and buf in ln
+                       and not any(p in ln for p in passes)]
+            assert not touched, touched[:3]
+
+
+@pytest.mark.parametrize("B,S,D,K,run,block", [
+    (2, 131072, 576, 2048, 1, 1024), (10, 32768, 576, 2048, 1, 2048),
+    (160, 8192, 512, 2052, 4, 512)],
+    ids=["two slots of GLM-5.2's 131 072", "GLM-5.2's cell, blocks of 2048",
+         "GLM-5.3-Flash's cell, blocks of 512"])
+def test_the_selected_read_holds_both_fetches(one_chip, monkeypatch, B, S, D,
+                                              K, run, block):
+    """``sparse_mla_decode_attention`` with the selection's mask compiles
+    for a described v5e — the gathered fetch and the dense walk in one
+    program, chosen a slot from the prefetched scalars, so a long cache's
+    short slots read dense too — at the published widths (the rope part
+    beside the rank in a row of 384 words, or a bare row of 256), the
+    selection's indices prefetched or a block a slot, the cache aliased."""
+    from deepspeed_tpu.ops import sparse_mla_attention as sparse
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    words = sparse.row_layout(D, jnp.bfloat16)[0]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def read(q, cache, new, idx, length, n, mask, layer):
+        return sparse.sparse_mla_decode_attention(
+            q, cache, new, idx, length, layer=layer, rank=512,
+            scale=D ** -0.5, n=n, run=run, mask=mask, block=block)
+
+    compiled = jax.jit(read, donate_argnums=(1,)).lower(
+        sds((B, 64, D), jnp.bfloat16), sds((1, B, S, 1, words), jnp.uint32),
+        sds((B, D), jnp.bfloat16), sds((B, K), jnp.int32),
+        sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, 1, S), jnp.float32), sds((), jnp.int32)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= B * S * words * 4
+    text = compiled.as_text()
+    assert text.count("/sparse_mla_decode_attention/pallas_call") == 1
+    # the mask goes to the kernel as it came: no copy re-tiles it
+    assert not re.search(rf" copy\(.*f32\[{B},1,{S}\]", text)

@@ -242,6 +242,131 @@ def test_the_pooled_selection_is_a_recount_over_all_keys():
         assert np.flatnonzero(np.asarray(mask[0, t])).tolist() == want
 
 
+# --------------------------------------- the step's read of live blocks
+def _pooled_step(dtype, lens, S, topk, pool, D, seed=0, L=2, H=4):
+    """A step's operands at ``lens`` live positions a slot: a cache of rows
+    with no rope part, the pooled selection of random scores (and of their
+    negatives: another selection with the same own group)."""
+    from deepspeed_tpu.ops import sparse_mla_attention as sparse
+
+    B = len(lens)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    lat = jax.random.normal(next(keys), (L, B, S, D), F32).astype(dtype)
+    q = jax.random.normal(next(keys), (B, H, D), F32).astype(dtype)
+    new = jax.random.normal(next(keys), (B, D), F32).astype(dtype)
+    score = jax.random.normal(next(keys), (B, 1, S // pool), F32)
+    length = jnp.asarray(lens, jnp.int32)
+    pos = jnp.maximum(length - 1, 0)[:, None]
+    picks = [dsa.select_pooled(sc, pos, topk, pool) for sc in (score, -score)]
+    after = np.asarray(lat, np.float32).copy()
+    for b, n in enumerate(lens):
+        if n:
+            after[1, b, n - 1] = np.asarray(new[b], np.float32)
+    return (lat, sparse.pack_rows(lat, dtype), q, new, length, after,
+            [(idx[:, 0], n[:, 0], mask) for idx, n, mask in picks])
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2), (F32, 1e-5)],
+                         ids=["bf16", "f32"])
+def test_the_dense_read_of_a_pooled_selection_is_the_selected_read(dtype,
+                                                                   tol):
+    """``run`` 4, ``n`` given, a row that is the latent alone: with the
+    selection's mask every slot here walks its live blocks whole and gives
+    what ``attend_selected`` gives over ``select_pooled``'s positions — at
+    lengths 0 and 1, inside and at the edges of a group, of the budget (48 +
+    the open group) and of a block of 128, at the cache's end; the appended
+    position is the open group's last and always selected; every other
+    position of the cache stays bit-untouched."""
+    from deepspeed_tpu.ops import sparse_mla_attention as sparse
+
+    pool, topk, S, D = 4, 48, 512, 32
+    lens = [0, 1, 3, 4, 5, 47, 48, 49, 52, 53, 127, 128, 129, 300, 512]
+    lat, cache, q, new, length, after, picks = _pooled_step(
+        dtype, lens, S, topk, pool, D)
+    idx, n, mask = picks[0]
+    assert bool(np.all(sparse.reads_dense(
+        np.asarray(lens), np.asarray(n) * (np.asarray(lens) > 0), pool,
+        cache.shape[-1] * cache.dtype.itemsize)))
+    o, got = sparse.sparse_mla_decode_attention(
+        q, cache, new, idx, length, layer=jnp.int32(1), rank=D, scale=0.3,
+        group=16, n=n, run=pool, mask=sparse.step_mask(mask), block=128,
+        interpret=True)
+    assert np.array_equal(
+        np.asarray(sparse.unpack_rows(got, D, dtype), np.float32), after)
+    want = sparse.attend_selected(
+        q, jnp.asarray(after[1]).astype(dtype), idx, length, rank=D,
+        scale=0.3, n=jnp.where(length > 0, n, 0))
+    assert float(jnp.abs(o.astype(F32) - want.astype(F32)).max()) <= tol
+    assert float(jnp.abs(o[0]).max()) == 0.0        # length 0: nothing read
+    for b, live in enumerate(lens[1:], 1):
+        assert live - 1 in np.asarray(idx[b, :int(n[b])])
+
+
+def test_the_kind_s_host_count_is_what_the_kernel_took(small):
+    """Slots on both sides of the crossover at ``run`` 4, the kernel handed
+    a mask that holds ANOTHER selection than ``idx``: a slot's result is the
+    mask's where ``reads_dense`` says dense and ``idx``'s elsewhere, which is
+    what the kind's ``decode_step`` meta counts (``dsa_dense_share``,
+    ``dsa_rows_read_over_selected`` by hand); a row is the latent alone
+    (``dsa_fetched_over_selected`` 1.0)."""
+    from deepspeed_tpu.inference.kinds import kind_of
+    from deepspeed_tpu.ops import sparse_mla_attention as sparse
+
+    cfg = small[0]
+    pool, topk, D, S = cfg.index_kpool, cfg.index_topk, cfg.latent_dim, 2048
+    assert (pool, topk, D, cfg.kv_lora_rank) == (4, 48, 32, 32)
+    kind = kind_of(cfg, 5, F32)
+    kind.flash, kind.max_len = True, S
+    row_bytes = sparse.row_layout(D, F32)[0] * 4
+    most = topk + pool                      # 12 closed groups and the open
+    edge = int(sparse.crossover(pool, row_bytes) * 16) * most // 16 \
+        // pool * pool
+    assert 128 < edge < S - pool
+    lens = np.asarray([30, 0, edge, edge + pool, S], np.int32)
+    lat, cache, q, new, length, after, picks = _pooled_step(
+        F32, lens.tolist(), S, topk, pool, D, seed=5)
+    (idx, n, _), (other, n2, mask) = picks
+    assert np.array_equal(np.asarray(n), np.asarray(n2))
+    chosen = np.asarray(n) * (lens > 0)
+    assert np.array_equal(chosen[lens > 0], kind._chosen(lens[lens > 0]))
+    o = sparse.sparse_mla_decode_attention(
+        q, cache, new, idx, length, layer=jnp.int32(1), rank=D, scale=0.3,
+        group=16, n=n, run=pool, mask=sparse.step_mask(mask), block=128,
+        interpret=True)[0]
+    by_idx, by_mask = (sparse.attend_selected(
+        q, jnp.asarray(after[1]), sel, length, rank=D, scale=0.3,
+        n=jnp.asarray(chosen)) for sel in (idx, other))
+    dense = sparse.reads_dense(lens, chosen, pool, row_bytes)
+    assert dense.tolist() == [True, True, True, False, False]
+    for b in range(len(lens)):
+        took, left = (by_mask, by_idx) if dense[b] else (by_idx, by_mask)
+        assert float(jnp.abs(o[b] - took[b]).max()) <= 1e-5, b
+        assert lens[b] <= most \
+            or float(jnp.abs(o[b] - left[b]).max()) > 1e-3, b
+    meta = kind.step_meta([], [], lens, {})
+    assert meta["dsa_dense_share"] == 2 / 4
+    blk = min(sparse.DENSE_BLOCK, S)
+    walked = -(-30 // blk) * blk + -(-edge // blk) * blk
+    assert meta["dsa_rows_read_over_selected"] \
+        == (walked + 2 * most) / chosen.sum()
+    assert meta["dsa_selected"] == chosen.sum() == 30 + 3 * most
+    kind.flash = False                  # off the kernels nothing reads so
+    assert "dsa_dense_share" not in kind.step_meta([], [], lens, {})
+    # at the published widths (512 values in 256 words: nothing beside the
+    # latent) a slot of the cell's ~2700 reads its blocks whole, ~1.5 rows
+    # a selected one
+    with open(os.path.join(_ROOT, "benchmark", "configs",
+                           "glm-5.3-flash-l5-e36.json")) as f:
+        wide = kind_of(fam.model_config(json.load(f)["config"], "bfloat16"),
+                       2, jnp.bfloat16)
+    wide.flash, wide.max_len = True, 8192
+    meta = wide.step_meta([], [], np.asarray([2700, 0], np.int32), {})
+    walked = -(-2700 // sparse.DENSE_BLOCK) * sparse.DENSE_BLOCK
+    assert meta["dsa_fetched_over_selected"] == 1.0
+    assert meta["dsa_selected"] == 2052 and meta["dsa_dense_share"] == 1.0
+    assert meta["dsa_rows_read_over_selected"] == walked / 2052 < 1.6
+
+
 # ------------------------------------------------------- the chip's share
 def test_eight_shares_of_an_expert_layer_sum_to_the_whole_layer():
     """Each of 8 chips holds 2 of the router's 16 experts; every one routes

@@ -608,3 +608,144 @@ def test_a_chunk_s_span_says_what_attended_and_over_how_many_keys(tiny):
     assert kind.chunk_meta(chunk)["attn_kernel"] is True
     kind.max_len = 96
     assert kind.chunk_meta(chunk)["attn_kernel"] is False
+
+
+# ------------------------------------------- the step's read of live blocks
+def _lengths_and_the_rest(dtype, D, B, S, seed=0, L=2, H=4):
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    lat = jax.random.normal(next(keys), (L, B, S, D), F32).astype(dtype)
+    q = jax.random.normal(next(keys), (B, H, D), F32).astype(dtype)
+    new = jax.random.normal(next(keys), (B, D), F32).astype(dtype)
+    score = jax.random.normal(next(keys), (B, 1, S), F32)
+    return lat, sparse.pack_rows(lat, dtype), q, new, score
+
+
+def _appended(lat, new, length, layer=1):
+    want = np.asarray(lat, np.float32).copy()
+    for b, n in enumerate(np.asarray(length).tolist()):
+        if n:
+            want[layer, b, n - 1] = np.asarray(new[b], np.float32)
+    return want
+
+
+@pytest.mark.parametrize("dtype,D,tol", [
+    (jnp.bfloat16, 40, 2e-2), (F32, 40, 1e-5), (jnp.bfloat16, 300, 2e-2)],
+    ids=["bf16 packed with a rope part", "f32", "bf16 into the high halves"])
+def test_the_dense_read_is_the_selected_read(dtype, D, tol):
+    """With the selection's mask the kernel walks a slot's live blocks whole
+    (every slot here stands on that side of the rule) and gives what
+    ``attend_selected`` gives over ``dsa.select``'s positions: lengths 0 and
+    1, under, at and over ``index_topk`` and a block's edge, the cache's
+    end; the appended position among the selected (it is scored highest);
+    every other position of the cache bit-untouched; length 0 exactly 0."""
+    K, S, rank, block = 48, 512, 32, 128
+    length = jnp.asarray([0, 1, 47, 48, 49, 127, 128, 129, 300, 512],
+                         jnp.int32)
+    B = len(length)
+    lat, cache, q, new, score = _lengths_and_the_rest(dtype, D, B, S)
+    pos = jnp.maximum(length - 1, 0)[:, None]
+    score = jnp.where(jnp.arange(S)[None, None] == pos[..., None], 9.0,
+                      score)
+    idx, mask = dsa.select(score, pos, K)
+    idx = idx[:, 0]
+    assert all(n - 1 in row for n, row in zip(length.tolist()[1:],
+                                              np.asarray(idx)[1:]))
+    assert bool(np.all(sparse.reads_dense(
+        np.asarray(length), np.minimum(np.asarray(length), K), 1,
+        cache.shape[-1] * cache.dtype.itemsize)))
+    o, after = sparse.sparse_mla_decode_attention(
+        q, cache, new, idx, length, layer=jnp.int32(1), rank=rank, scale=0.3,
+        group=16, mask=sparse.step_mask(mask), block=block, interpret=True)
+    want = _appended(lat, new, length)
+    assert np.array_equal(
+        np.asarray(sparse.unpack_rows(after, D, dtype), np.float32), want)
+    ref_o = sparse.attend_selected(q, jnp.asarray(want[1]).astype(dtype), idx,
+                                   length, rank=rank, scale=0.3)
+    assert float(jnp.abs(o.astype(F32) - ref_o.astype(F32)).max()) <= tol
+    assert float(jnp.abs(o[0]).max()) == 0.0        # length 0: nothing read
+    # ... and it is not the read of everything live: the mask decides
+    everything = sparse.attend_selected(
+        q, jnp.asarray(want[1]).astype(dtype),
+        jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S)), length,
+        rank=rank, scale=0.3)
+    assert float(jnp.abs(o[-1].astype(F32)
+                         - everything[-1].astype(F32)).max()) > 10 * tol
+
+
+def test_a_slot_reads_as_the_rule_says_and_as_it_would_alone(tiny):
+    """One batch with slots on both sides of the crossover. The kernel is
+    handed a mask that holds ANOTHER selection than ``idx``, so a slot's
+    result says which fetch it took: the mask's positions where
+    ``reads_dense`` says dense, ``idx``'s elsewhere — the rule the host's
+    mirror counts by (``dsa_dense_share``, ``dsa_rows_read_over_selected``
+    of the ``decode_step`` span), at the kind's own widths. Every slot
+    equals the slot alone in a batch of one."""
+    cfg, _, _ = tiny
+    D, rank, K, S = cfg.latent_dim, cfg.kv_lora_rank, cfg.index_topk, 2048
+    kind = kind_of(cfg, 4, F32)
+    kind.flash, kind.max_len = True, S
+    row_bytes = sparse.row_layout(D, F32)[0] * 4
+    edge = int(sparse.crossover(1, row_bytes) * 16) * K // 16
+    assert 128 < edge < S - 1
+    lens = np.asarray([20, 0, edge, edge + 1, S], np.int32)
+    length, B = jnp.asarray(lens), len(lens)
+    lat, cache, q, new, score = _lengths_and_the_rest(F32, D, B, S, seed=3)
+    pos = jnp.maximum(length - 1, 0)[:, None]
+    idx = dsa.select(score, pos, K, want_mask=False)[0][:, 0]
+    other, mask = dsa.select(-score, pos, K)
+
+    def read(rows, mask_rows):
+        return sparse.sparse_mla_decode_attention(
+            q[rows], cache[:, rows], new[rows], idx[rows], length[rows],
+            layer=jnp.int32(1), rank=rank, scale=0.3, group=8,
+            mask=sparse.step_mask(mask_rows), block=128, interpret=True)[0]
+
+    o = read(slice(None), mask)
+    after = jnp.asarray(_appended(lat, new, length)[1])
+    by_idx, by_mask = (sparse.attend_selected(
+        q, after, sel, length, rank=rank, scale=0.3)
+        for sel in (idx, other[:, 0]))
+    dense = sparse.reads_dense(lens, np.minimum(lens, K), 1, row_bytes)
+    assert dense.tolist() == [True, True, True, False, False]
+    for b in range(B):
+        took, left = (by_mask, by_idx) if dense[b] else (by_idx, by_mask)
+        assert float(jnp.abs(o[b] - took[b]).max()) <= 1e-5, b
+        assert lens[b] < K or float(jnp.abs(o[b] - left[b]).max()) > 1e-3, b
+        alone = read(slice(b, b + 1), mask[b:b + 1])
+        assert np.array_equal(np.asarray(alone[0]), np.asarray(o[b])), b
+    # the host's count is the kernel's: two of the four running slots
+    # dense; rows by hand: each of the two walks its live rows in whole
+    # blocks of DENSE_BLOCK, the two others fetch their 16 selected
+    meta = kind.step_meta([], [], lens, {})
+    assert meta["dsa_dense_share"] == 2 / 4 == float(dense[lens > 0].mean())
+    blk = min(sparse.DENSE_BLOCK, S)
+    walked = -(-20 // blk) * blk + -(-edge // blk) * blk
+    assert meta["dsa_rows_read_over_selected"] == (walked + 2 * K) / (4 * K)
+    assert meta["dsa_selected"] == 4 * K
+    # ... and off the kernels nothing reads by the rule
+    kind.flash = False
+    assert "dsa_dense_share" not in kind.step_meta([], [], lens, {})
+
+
+def test_the_rows_a_read_brings_in_by_hand():
+    """GLM-5.2's widths (rows of 1536 B, 2048 selected): a slot of 17 700
+    reads dense — whole blocks of DENSE_BLOCK, 9 rows a selected one — and
+    a slot of 131 072 gathers its 2048; the layout's cost stays 1536 /
+    1152."""
+    from deepspeed_tpu.inference.kinds.sparse_latent import read_meta
+
+    kind = kind_of(glm_moe_dsa("5.2"), 2, jnp.bfloat16)
+    kind.flash, kind.max_len = True, 131072
+    assert 16 < sparse.crossover(1, 1536) < 32
+    walked = -(-17700 // sparse.DENSE_BLOCK) * sparse.DENSE_BLOCK
+    one = read_meta(kind, np.asarray([17700]), np.asarray([2048]), 1)
+    assert one == {"dsa_dense_share": 1.0,
+                   "dsa_rows_read_over_selected": walked / 2048}
+    assert 8.6 < walked / 2048 <= 9.0
+    far = read_meta(kind, np.asarray([131072]), np.asarray([2048]), 1)
+    assert far == {"dsa_dense_share": 0.0,
+                   "dsa_rows_read_over_selected": 1.0}
+    both = kind.step_meta([], [], np.asarray([17700, 0, 131072]), {})
+    assert both["dsa_dense_share"] == 0.5
+    assert both["dsa_rows_read_over_selected"] == (walked + 2048) / 4096
+    assert both["dsa_fetched_over_selected"] == 1536 / 1152
