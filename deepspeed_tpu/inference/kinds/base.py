@@ -7,6 +7,7 @@ import math
 from functools import cached_property
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from ...models import moe
@@ -180,6 +181,26 @@ def split_banks(model, seg, routed: bool):
     names = getattr(model, "BANKS", ()) if routed else ()
     return ({k: seg[k] for k in names} or None,
             {k: v for k, v in seg.items() if k not in names})
+
+
+def served_bytes(cfg, params, banks=("w_gate", "w_in", "w_out")) -> tuple:
+    """Of a served tree whose expert layers hold a share: (what a step
+    reads of the layers' weights whoever runs, the routed experts' banks
+    apart and an indexer's weights with them; ONE held expert's matrices;
+    the head's) in bytes — the step's counters say how many experts it
+    touched. Zeros of a kind built of the config alone."""
+    params = params or {}
+    segs = params.get("layers", ())
+    segs = segs if isinstance(segs, (tuple, list)) else (segs,)
+
+    def nbytes(tree):
+        return sum(a.nbytes for a in jax.tree.leaves(tree))
+
+    routed = [{k: seg[k] for k in banks} for seg in segs if "router" in seg]
+    layers = sum(n for k, n in cfg.segments if k == "moe")
+    return (nbytes(segs) - nbytes(routed) + nbytes(params.get("indexer", ())),
+            nbytes(routed) // max(1, cfg.held_experts * layers),
+            nbytes(params.get("lm_head", ())))
 
 
 def stacked(stats: list):

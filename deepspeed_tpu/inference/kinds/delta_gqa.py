@@ -1,0 +1,206 @@
+"""The cache of a trunk of delta-rule mixers beside softmax GQA layers
+(``cfg.mixer_pattern`` with ``attention='mha'``; Solar-Open2, ``model_type:
+solar_open2``): the two kinds of per-slot memory at their plainest, side by
+side.
+
+What grows with the position, for the attention ("A") layers only:
+
+- ``k`` / ``v`` ``(A, B, KV, head_dim, max_len)``: whole keys and values of
+  the layers' KV heads, laid out as ``KVCache``'s (``Kind.kv_planes``, as
+  ``kinds/hybrid.py``); no position code is applied to either.
+
+What a slot holds whatever its length (``kda.state_shapes``, as
+``kinds/linear_sparse.py``):
+
+- ``kda`` ``(K, B, H, D, D)`` float32: the KDA layers' delta-rule state;
+- ``conv`` ``(K, B, kda_conv - 1, 3 H D)``: the last inputs of their three
+  depthwise convs.
+
+One residual stream. The T == 1 step, an attention layer:
+``decode_attention`` appends the new K/V in place and reads the slot's live
+blocks, under the name ``nope_gqa_decode_attention``; the heads' output times
+``sigmoid(y w_ogate)`` (``attn_out_gate``), then ``wo``. A KDA layer:
+``kda_state_step`` moves the state in place. T > 1 (a chunk, or a solo
+prefill): XLA's update of the planes and a walk over the live key blocks
+(``windowed.attend_blocks``: the scores of one block of keys at a time, never
+``T x max_len``), and the chunkwise scan (``kda.scan_chunked``) behind
+``kda.mix_chunk``.
+"""
+
+from collections import namedtuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ...models import kda, windowed
+from ...models.transformer import _norm
+from ...ops.sparse_mla_attention import einsum_f32
+from ..quantization import matmul_any
+from .base import (IN_POOL, MOVES_PAGES, Kind, held_counts, served_bytes,
+                   split_banks, stacked)
+from .steps import _append_attend, _ffn, _qkv_proj, _run
+
+DeltaGQACache = namedtuple("DeltaGQACache", "k v kda conv length")
+
+
+class DeltaGQA(Kind):
+    cache = DeltaGQACache
+    recurrent = True
+    mirrors_lengths = True    # step_meta counts from the slots' lengths
+    refuses = {
+        "paged": "the paged pool and prefix sharing (page_size): a "
+                 "delta-rule state has no pages, and a shared prefix would "
+                 "need the state and the conv tails as they stood at the "
+                 "prefix's end beside the prefix's K/V pages",
+        "kv_quant": IN_POOL,
+        "speculation": "speculation: a rejected draft would have to roll "
+                       "the delta-rule state back while the K/V planes only "
+                       "rewind",
+        "host_kv": MOVES_PAGES,
+        "quantize": "weight-only quantization: the mixers' and the gated "
+                    "attention's projections take dense weights",
+        "mesh": "a mesh of several devices: the state step's kernel has no "
+                "shard_map rule and the experts held are told by the "
+                "configuration, no axis exchanges rows yet"}
+    contiguous_only = ("the paged pool holds pages of K and V; a delta-rule "
+                       "state and conv tails beside them have no pages: "
+                       "contiguous only")
+
+    def __init__(self, cfg, slots: int = 1, dtype=None, params=None):
+        super().__init__(cfg, slots, dtype, params)
+        self.what = (f"delta-rule mixers beside softmax GQA layers "
+                     f"(mixer_pattern={cfg.mixer_pattern!r}, attention="
+                     "'mha') do not yet compose with")
+        self.moe_stats = any(ffn == "moe" for ffn, _ in cfg.segments)
+        self.layers = cfg.mixer_pattern.count("A")
+        self.mixers = cfg.mixer_pattern.count("K")
+        self.layer_bytes, self.expert_bytes, self.head_bytes = served_bytes(
+            cfg, params)
+
+    @staticmethod
+    def matches(cfg) -> bool:
+        return bool(getattr(cfg, "mixer_pattern", "")) \
+            and getattr(cfg, "attention", "") == "mha"
+
+    def state(self, batch, dtype=None):
+        shapes = kda.state_shapes(self.cfg, batch)
+        return {"kda": ((self.mixers,) + shapes["kda"], jnp.float32),
+                "conv": ((self.mixers,) + shapes["conv"],
+                         dtype or self.cfg.dtype)}
+
+    # ------------------------------------------------------------ the loop
+    def forward(self, model, params, x, cache, new_len, positions, valid,
+                fused):
+        """Each run of layers equal in (mixer, FFN kind) over its own
+        stacked weights, all of them carrying (the stream, the four
+        buffers), a layer touching only its kind's two. Stats: (counters
+        (expert layers, 4), routing (expert layers, B, T, k)) or None."""
+        cfg = self.cfg
+        B, T, _ = x.shape
+        dt = x.dtype
+        per_slot = getattr(new_len, "ndim", 0) == 1
+        lens = new_len if per_slot else jnp.broadcast_to(new_len, (B,))
+        start = None if per_slot else new_len - T
+        in_place = kda.step_kernel_ok(cfg, fused and T == 1)
+
+        def mixer(carry, p, ki):
+            x, ck, cv, St, W = carry
+            y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+            out, St, W = kda.mix(cfg, p, y, St, W, ki, lens, valid, in_place)
+            return x + out, ck, cv, St, W
+
+        def attention(carry, p, ai):
+            x, ck, cv, St, W = carry
+            y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+            q, k, v = _qkv_proj(model, y, p)          # no position code
+            if T == 1:
+                o, ck, cv = _append_attend(
+                    q, ck, cv, k, v, ai, new_len, fused,
+                    name="nope_gqa_decode_attention")
+            else:
+                # the chunk into the carried planes, and the read block by
+                # block out of them, by layer: no slab, no T x max_len
+                ck, cv = (lax.dynamic_update_slice(
+                    c, n.transpose(0, 2, 3, 1)[None].astype(c.dtype),
+                    (ai, 0, 0, 0, start)) for c, n in ((ck, k), (cv, v)))
+                o = windowed.attend_blocks(q, ck, cv, positions, new_len,
+                                           layer=ai)
+            o = o.reshape(B, T, -1)
+            if cfg.attn_out_gate:
+                with jax.named_scope("attn_out_gate"):
+                    gate = jax.nn.sigmoid(einsum_f32(
+                        "btd,dc->btc", y.astype(dt),
+                        p["w_ogate"].astype(dt)))
+                    o = (o.astype(jnp.float32) * gate).astype(dt)
+            return (x + matmul_any(o, p["wo"], use_kernel=False), ck, cv, St,
+                    W)
+
+        mixers = {"K": mixer, "A": attention}
+        carry = (x, cache.k, cache.v, cache.kda, cache.conv)
+        seen = dict.fromkeys(mixers, 0)
+        stats = []
+        for (ffn, n), attn, seg in zip(
+                cfg.segments, cfg.segment_attn,
+                model.segment_params(params["layers"])):
+            banks, rest = split_banks(model, seg, ffn == "moe")
+
+            def body(carry, p, idx, attn=attn, banks=banks,
+                     first=seen[attn]):
+                carry = mixers[attn](carry, p, idx)
+                x, out = _ffn(model, carry[0], p, banks, idx - first)
+                return (x,) + carry[1:], out
+
+            with jax.named_scope("decode_layer"):
+                carry, out = _run(body, carry, rest, n, seen[attn])
+            seen[attn] += n
+            if ffn == "moe":
+                stats.append(out)
+        x, k, v, St, W = carry
+        return (x, DeltaGQACache(k=k, v=v, kda=St, conv=W, length=new_len),
+                stacked(stats), None)
+
+    # ------------------------------------------------------------ the spans
+    def chunk_meta(self, chunk) -> dict:
+        """:meth:`sizes`, the chunk's tokens, real and padded, and the keys
+        its attention layers' walk reads (``attn_live_keys``: the positions
+        before the chunk and its own)."""
+        real = chunk.last_index + 1 if chunk.final else chunk.size
+        return {**self.sizes(), "tokens_real": real,
+                "tokens_padded": chunk.size - real,
+                "attn_live_keys": chunk.start + chunk.size}
+
+    def step_meta(self, read, pending, lens, running):
+        """:meth:`sizes`; from the mirror of the slots' lengths what the
+        step has to move — ``state_bytes_step`` (the running slots'
+        delta-rule state and tails, in and out), ``kv_bytes_step``
+        (``live_positions`` x the bytes a cached position takes: the
+        attention layers read every live key and value),
+        ``weight_bytes_step`` (everything but the experts' banks),
+        ``expert_bytes_step`` (the held experts touched),
+        ``head_bytes_step`` — with the state's and the K/V's shares of their
+        sum; the held experts' counters."""
+        from ...observability.metrics import get_registry
+
+        cfg = self.cfg
+        meta = self.sizes()
+        held = held_counts(self, read, pending)
+        if lens is not None:
+            live = int(lens[lens > 0].sum())
+            moved = {
+                "state_bytes_step": 2 * len(running) * self.slot_bytes,
+                "kv_bytes_step": live * self.token_bytes,
+                "weight_bytes_step": self.layer_bytes,
+                "expert_bytes_step": int(
+                    held.get("experts_touched", 0.0) * self.expert_bytes
+                    * sum(n for k, n in cfg.segments if k == "moe")),
+                "head_bytes_step": self.head_bytes}
+            total = max(sum(moved.values()), 1)
+            meta.update(
+                moved, live_positions=live,
+                state_share_of_step_bytes=moved["state_bytes_step"] / total,
+                kv_share_of_step_bytes=moved["kv_bytes_step"] / total)
+            get_registry().counter("Serve/kda_state_bytes_moved").inc(
+                moved["state_bytes_step"])
+        meta.update(held)
+        return meta

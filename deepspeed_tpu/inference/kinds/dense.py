@@ -102,7 +102,8 @@ class Dense(Kind):
         # (duck-typed configs of other trunks have no attention kinds: K/V)
         return getattr(cfg, "attention", "mha") == "mha" \
             and not getattr(cfg, "block_pattern", "") \
-            and not getattr(cfg, "attn_pattern", "")
+            and not getattr(cfg, "attn_pattern", "") \
+            and not getattr(cfg, "mixer_pattern", "")
 
     def deferred_rows(self, dtype=None):
         """T of the deferred tail (``KVCache``): a sublane tile's rows where

@@ -51,7 +51,8 @@ from ...models.transformer import _norm
 from ...ops import mla_attention
 from ...ops import sparse_mla_attention as sparse
 from ..quantization import matmul_any
-from .base import IN_POOL, MOVES_PAGES, Kind, _nbytes, held_counts, split_banks
+from .base import (IN_POOL, MOVES_PAGES, Kind, _nbytes, held_counts,
+                   served_bytes, split_banks)
 from .sparse_latent import SparseLatent, _index, query_blocks, read_meta
 
 LinearSparseCache = namedtuple("LinearSparseCache",
@@ -94,27 +95,14 @@ class LinearSparse(Kind):
         self.layers = self.attends
         # what a step reads of the weights whoever runs, the routed experts'
         # banks apart (the step's counters say how many it touched)
-        model_banks = ("w_gate", "w_in", "w_out")
-        params = params or {"layers": (), "indexer": (), "lm_head": ()}
-        segs = params["layers"]
-        segs = segs if isinstance(segs, (tuple, list)) else (segs,)
-
-        def nbytes(tree):
-            return sum(a.nbytes for a in jax.tree.leaves(tree))
-
-        routed = [{k: seg[k] for k in model_banks} for seg in segs
-                  if "router" in seg]
-        self.layer_bytes = nbytes(segs) - nbytes(routed) \
-            + nbytes(params["indexer"])
-        # one held expert's three matrices
-        self.expert_bytes = nbytes(routed) // max(
-            1, cfg.held_experts * sum(n for k, n in cfg.segments
-                                      if k == "moe")) if routed else 0
-        self.head_bytes = nbytes(params["lm_head"])
+        self.layer_bytes, self.expert_bytes, self.head_bytes = served_bytes(
+            cfg, params)
 
     @staticmethod
     def matches(cfg) -> bool:
-        return bool(getattr(cfg, "mixer_pattern", ""))
+        # (beside the config's own GQA: kinds/delta_gqa.py)
+        return bool(getattr(cfg, "mixer_pattern", "")) \
+            and getattr(cfg, "attention", "") == "mla"
 
     # ---------------------------------------------------------- the layout
     def buffers(self, batch, max_len, dtype=None):
@@ -210,16 +198,9 @@ class LinearSparse(Kind):
             def f(xin):
                 y = _norm(xin, p["ln1_scale"], None, cfg.norm,
                           cfg.norm_eps).astype(dt)
-                if T == 1:
-                    out, S2, W2 = kda.mix_step(cfg, p, y, St, W, ki, lens,
-                                               in_place)
-                    return out, (S2, W2)
-                out, s_l, w_l = kda.mix_chunk(
-                    cfg, p, y, lax.dynamic_index_in_dim(St, ki, keepdims=False),
-                    lax.dynamic_index_in_dim(W, ki, keepdims=False), valid)
-                return out, (
-                    lax.dynamic_update_slice(St, s_l[None], (ki, 0, 0, 0, 0)),
-                    lax.dynamic_update_slice(W, w_l[None], (ki, 0, 0, 0)))
+                out, S2, W2 = kda.mix(cfg, p, y, St, W, ki, lens, valid,
+                                      in_place)
+                return out, (S2, W2)
 
             X, (St, W) = residual(X, p, 0, f)
             return X, c, ik, ikt, St, W

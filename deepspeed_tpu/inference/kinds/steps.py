@@ -354,13 +354,19 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
 
 def _out_ffn(model, x, o, p, banks, layer, sorted_rows: bool = True):
     """The attention's output ``o`` (B, T, H, vd) through ``wo`` onto the
-    stream, then the layer's FFN: the sorted expert rows where it has a
-    router (``banks`` / ``layer``: the segment's stacked expert weights and
+    stream, then the layer's FFN (:func:`_ffn`)."""
+    B, T, _ = x.shape
+    x = x + matmul_any(o.reshape(B, T, -1), p["wo"], use_kernel=False)
+    return _ffn(model, x, p, banks, layer, sorted_rows)
+
+
+def _ffn(model, x, p, banks, layer, sorted_rows: bool = True):
+    """The layer's FFN onto the stream: the sorted expert rows where it has
+    a router (``banks`` / ``layer``: the segment's stacked expert weights and
     this layer's index in them), else the dense block. Returns (x, (the
     expert layer's counters, the experts chosen (B, T, k))), zeros if dense."""
     cfg = model.cfg
     B, T, _ = x.shape
-    x = x + matmul_any(o.reshape(B, T, -1), p["wo"], use_kernel=False)
     y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
     if "router" in p and sorted_rows:
         out, stats, chose = model.experts(y2, p, banks=banks, layer=layer)

@@ -1,22 +1,29 @@
 """Kimi Delta Attention (KDA; Kimi Linear, arXiv:2510.26692 §3): the linear
-attention mixer of GLM-5.3-Flash (``model_type: glm5_next_text``,
-``cfg.mixer_pattern`` "K"), in the forms a served trunk needs. They have to
-agree, and ``tests/unit/test_linear_sparse.py`` holds them to the plain
-recurrence of ``benchmark/reference/glm5_next.py``.
+attention mixer of GLM-5.3-Flash (``model_type: glm5_next_text``) and of
+Solar-Open2 (``model_type: solar_open2``), ``cfg.mixer_pattern`` "K", in the
+forms a served trunk needs. They have to agree, and
+``tests/unit/test_linear_sparse.py`` and ``tests/unit/test_delta_gqa.py``
+hold them to the plain recurrence of ``benchmark/reference/glm5_next.py`` and
+``benchmark/reference/solar_open2.py``.
 
 On the layer's normed input ``y``, per head h of ``kda_heads`` with ``D =
 kda_head_dim`` key and value channels:
 
     q = L2(silu(conv(y W_q)));  k = L2(silu(conv(y W_k)));  v = silu(conv(y W_v))
-    beta = sigmoid(y W_beta)                                     (a head)
+    beta = c sigmoid(y W_beta)                                   (a head)
     g = floor * sigmoid(exp(A_log[h]) (y W_f1 W_f2 + dt_bias))   (a key channel)
+     or -exp(A_log[h]) softplus(y W_f1 W_f2 + dt_bias)           (no floor)
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t / sqrt(D);  out = (RMS_head(o_t) * sigmoid(y W_g1 W_g2)) W_o
 
-``conv`` is causal and depthwise, ``kda_conv`` taps, no bias; ``floor`` is
-``kda_gate_floor`` (-5: the bounded gate of flash-linear-attention's KDA, so
-that a block's decays stay inside float32). ``S`` (D x D a head) is float32,
-and everything that multiplies it.
+``conv`` is causal and depthwise, ``kda_conv`` taps, no bias. ``floor`` is
+``kda_gate_floor``: -5 is the bounded gate of flash-linear-attention's KDA
+(GLM-5.3-Flash's ``gate_lower_bound``), 0 reads "no floor" — Kimi Linear's
+own gate, unbounded below (Solar-Open2's). ``c`` is 2 with
+``kda_neg_eigval`` (``I - beta k k^T`` then has the eigenvalue ``1 - beta``
+in (-1, 1)), else 1. Every form here holds for any ``g <= 0``: no factor is
+the exponential of a positive number. ``S`` (D x D a head) is float32, and
+everything that multiplies it.
 
 - :func:`mix_chunk`: T tokens that take the conv tails and the state in and
   hand both out, the paper's chunkwise form (:func:`scan_chunked`): blocks
@@ -44,7 +51,7 @@ from ..ops.sparse_mla_attention import einsum_f32
 
 HI = lax.Precision.HIGHEST
 CHUNK = 64      # tokens a block of the chunkwise form (the paper's)
-SUB = 16        # tokens a sub-block: exp(SUB * 5) stays under float32's max
+SUB = 16        # tokens a sub-block: its decays are formed pair by pair
 FP32_NAMES = ("kda_A_log", "kda_dt_bias")
 KINDS = "KA"
 
@@ -57,25 +64,46 @@ def check_config(c) -> None:
             "hc_mult and index_kpool are the glm5_next_text trunk's "
             "(mixer_pattern): the other trunks' layer loops carry one "
             "stream and one indexer key a position")
+    if c.attn_out_gate and not (pat and c.attention == "mha"):
+        raise ValueError(
+            "attn_out_gate is the solar_open2 block's (mixer_pattern "
+            "beside attention='mha'): no other trunk's attention layer "
+            "multiplies its output by a gate")
     if len(pat) != c.n_layer or set(pat) - set(KINDS):
         raise ValueError(f"mixer_pattern {pat!r} has to name each of the "
                          f"{c.n_layer} layers, one of {KINDS!r}")
     if "K" in pat and (min(c.kda_heads, c.kda_head_dim, c.kda_rank) <= 0
-                       or c.kda_conv < 2 or c.kda_gate_floor >= 0
-                       or -c.kda_gate_floor * SUB > 85.0):
+                       or c.kda_conv < 2 or c.kda_gate_floor > 0):
         raise ValueError(
             "a 'K' layer needs kda_heads, kda_head_dim, kda_rank, kda_conv "
-            ">= 2 and a negative kda_gate_floor no lower than -85 / "
-            f"{SUB} (a sub-block's decay has to stay inside float32)")
-    if c.attention != "mla" or not c.index_pattern or c.num_experts < 2 \
-            or c.moe_router != "sigmoid" or c.norm != "rmsnorm" \
-            or c.use_bias or c.tie_embeddings or c.pos_embedding != "none" \
-            or c.loop_steps > 1 or c.block_pattern or c.attn_pattern:
+            ">= 2 and a kda_gate_floor that is negative (the bounded gate) "
+            "or 0 (no floor: the gate unbounded below)")
+    if c.num_experts < 2 or c.moe_router != "sigmoid" \
+            or c.norm != "rmsnorm" or c.use_bias or c.tie_embeddings \
+            or c.pos_embedding != "none" or c.loop_steps > 1 \
+            or c.block_pattern or c.attn_pattern:
         raise ValueError(
-            "mixer_pattern is the glm5_next_text block: KDA layers beside "
-            "latent attention over an indexer's selection (attention='mla', "
-            "index_pattern), no position code, RMSNorm, no biases, an "
-            "untied head, a dense FFN or sigmoid-routed experts")
+            "a mixer_pattern trunk has no position code (the KDA layers "
+            "carry the order), RMSNorm, no biases, an untied head and a "
+            "dense FFN or sigmoid-routed experts beside every mixer")
+    if c.attention == "mha":
+        # the solar_open2 block: "A" is the config's own GQA, gated
+        if c.index_pattern or c.hc_mult > 1 or c.index_kpool > 1 \
+                or c.v_head_dim not in (0, c.head_dim) \
+                or c.n_head % c.kv_heads:
+            raise ValueError(
+                "mixer_pattern beside attention='mha' is the solar_open2 "
+                "block: KDA layers beside softmax GQA over whole K/V planes "
+                "under one residual stream — no index_pattern, hc_mult or "
+                "index_kpool (the glm5_next_text block's, attention='mla'), "
+                "values as wide as keys, whole groups of query heads")
+        return
+    if c.attention != "mla" or not c.index_pattern:
+        raise ValueError(
+            "mixer_pattern beside attention='mla' is the glm5_next_text "
+            "block: KDA layers beside latent attention over an indexer's "
+            "selection (index_pattern); beside attention='mha' it is the "
+            "solar_open2 block; no other attention stands beside KDA layers")
     if "s" in c.index_pattern:
         raise ValueError(
             "index_pattern 's' (a layer that takes the selection of the one "
@@ -104,21 +132,37 @@ def state_shapes(cfg, batch: int) -> dict:
 
 def init_params(cfg, key, dense, n: int, depth: int) -> dict:
     """Stacked weights of ``n`` KDA layers. The decay at init is a trained
-    mixer's, not a coin toss a channel: ``A_log = log U(1, 4)`` a head and
-    ``dt_bias`` such that the gate's pre-activation stands in (-6, -1)
-    before the input moves it by about one (``W_f2`` drawn a quarter wide),
-    so a channel forgets over two to a few hundred tokens, as Mamba-2's
-    ``dt`` is drawn (``models/ssm.py``), where a draw about zero saturates
-    half the channels at the floor and half at none. A path that drops the
-    gate, the floor or a conv still reads differently."""
+    mixer's, not a coin toss a channel. Behind a floor: ``A_log = log U(1,
+    4)`` a head and ``dt_bias`` such that the gate's pre-activation stands
+    in (-6, -1) before the input moves it by about one (``W_f2`` drawn a
+    quarter wide), so a channel forgets over two to a few hundred tokens, as
+    Mamba-2's ``dt`` is drawn (``models/ssm.py``), where a draw about zero
+    saturates half the channels at the floor and half at none. With no floor
+    (``kda_gate_floor`` 0) Kimi Linear's own: ``A_log = log U(1, 16)`` a
+    head, ``dt_bias`` the inverse softplus of ``dt ~ logU(1e-3, 1e-1)``, and
+    one head in eight (the last of a trunk with fewer) with a bias 6 higher:
+    a trained gate saturates, and
+    such a head's channels forget within a token or two (``g`` of -6 to
+    -100), which is what no floor means and what the chunkwise form has to
+    hold. ``W_beta``'s draw (sd 1 / sqrt(d): a pre-activation of sd 1 on a
+    normed input) puts 14% of the sigmoids above 0.75: with
+    ``kda_neg_eigval`` so many betas lie above 1.5. A path that drops the
+    gate, the floor, the factor of beta or a conv still reads differently."""
     d, H, D, R, K = (cfg.d_model, cfg.kda_heads, cfg.kda_head_dim,
                      cfg.kda_rank, cfg.kda_conv)
     inner = H * D
     k = iter(jax.random.split(key, 12))
     bound = 1.0 / math.sqrt(K)
-    A = jax.random.uniform(next(k), (n, H), jnp.float32, 1.0, 4.0)
-    dt_bias = -jax.random.uniform(next(k), (n, H, D), jnp.float32, 1.0, 6.0) \
-        / A[..., None]
+    if cfg.kda_gate_floor:
+        A = jax.random.uniform(next(k), (n, H), jnp.float32, 1.0, 4.0)
+        dt_bias = -jax.random.uniform(next(k), (n, H, D), jnp.float32, 1.0,
+                                      6.0) / A[..., None]
+    else:
+        A = jax.random.uniform(next(k), (n, H), jnp.float32, 1.0, 16.0)
+        dt = jnp.exp(jax.random.uniform(next(k), (n, H, D), jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        dt_bias = dt + jnp.log(-jnp.expm1(-dt)) \
+            + 6.0 * (jnp.arange(H) % 8 == min(7, H - 1))[None, :, None]
     return {
         "kda_wqkv": dense(next(k), (n, d, 3 * inner)),
         "kda_conv_w": jax.random.uniform(next(k), (n, 3 * inner, K),
@@ -155,8 +199,10 @@ def step_kernel_ok(cfg, fused: bool) -> bool:
 
 # ---------------------------------------------------------------- the parts
 def _gates(cfg, p, y):
-    """y (B, T, d) -> (beta (B, T, H) f32, g (B, T, H, D) f32 in (floor,
-    0), the output gate's pre-activation (B, T, H, D) f32). Every product
+    """y (B, T, d) -> (beta (B, T, H) f32 in (0, 1), or (0, 2) with
+    ``kda_neg_eigval``; g (B, T, H, D) f32 in (floor, 0), or in (-inf, 0)
+    where ``kda_gate_floor`` is 0; the output gate's pre-activation (B, T,
+    H, D) f32). Every product
     leaves the MXU in float32, and the low-rank pairs' second product is a
     float32 one: a decay rounded to bf16 a token is an error of a few
     thousandths in its log that the running product of decays adds up over
@@ -171,10 +217,13 @@ def _gates(cfg, p, y):
 
     beta = jax.nn.sigmoid(einsum_f32("btd,dh->bth", y,
                                      p["kda_wbeta"].astype(y.dtype)))
+    if cfg.kda_neg_eigval:
+        beta = 2.0 * beta
     f = (low_rank("kda_wf1", "kda_wf2")
          + p["kda_dt_bias"].astype(f32)).reshape(lead + (H, D))
-    g = cfg.kda_gate_floor * jax.nn.sigmoid(
-        jnp.exp(p["kda_A_log"].astype(f32))[:, None] * f)
+    A = jnp.exp(p["kda_A_log"].astype(f32))[:, None]
+    g = cfg.kda_gate_floor * jax.nn.sigmoid(A * f) if cfg.kda_gate_floor \
+        else -A * jax.nn.softplus(f)
     return beta, g, low_rank("kda_wg1", "kda_wg2").reshape(lead + (H, D))
 
 
@@ -226,19 +275,42 @@ def _decayed(a, G, ref, hi: int):
     return jnp.where(keep, a * jnp.exp(jnp.where(keep, G - ref, 0.0)), 0.0)
 
 
+def _diagonal(rows, k, G):
+    """A sub-block against itself: ``sum_d rows_i[d] k_j[d] exp(G_i[d] -
+    G_j[d])`` for ``j <= i``, 0 above the diagonal — the decay formed pair
+    by pair, channel by channel, before the sum over the channels
+    (flash-linear-attention's way): ``G_i - G_j <= 0`` there whatever the
+    gate, so nothing overflows and what underflows is 0. rows, k, G (..., SUB,
+    H, D) -> (..., H, SUB, SUB)."""
+    n = G.shape[-3]
+    low = (jnp.arange(n)[:, None] >= jnp.arange(n)[None, :])[:, :, None, None]
+    dec = jnp.exp(jnp.where(low, G[..., :, None, :, :] - G[..., None, :, :, :],
+                            -jnp.inf))
+    out = jnp.sum(rows[..., :, None, :, :] * k[..., None, :, :, :] * dec, -1)
+    return jnp.moveaxis(out, -1, -3)
+
+
 @jax.named_scope("kda_chunk_scan")
 def scan_chunked(q, k, v, g, beta, S0):
     """The delta rule over T tokens in blocks of :data:`CHUNK`. q, k, v,
-    g (B, T, H, D) float32, beta (B, T, H) (a padded token: beta 0, g 0), S0
-    (B, H, D, D). Returns (o (B, T, H, D) float32, S_T).
+    g (B, T, H, D) float32, ``g <= 0`` and otherwise unbounded, beta (B, T,
+    H) (a padded token: beta 0, g 0), S0 (B, H, D, D). Returns (o (B, T, H,
+    D) float32, S_T).
 
     In a block with G the running sum of g and ``Gam = exp(G)``: ``u_i =
     beta_i (v_i - S_0^T (Gam_i k_i) - sum_{j<i} (Gam_i k_i . k_j / Gam_j)
-    u_j)``, a unit lower triangular system ``(I + A) U = beta (V - K+ S_0)``;
+    u_j)``, a unit lower triangular system ``(I + A) U = beta (V - K+ S_0)``
+    (``(I + A)^-1`` by forward substitution on the identity, then a product);
     ``o_i = S_0^T (Gam_i q_i) + sum_{j<=i} (Gam_i q_i . k_j / Gam_j) u_j``;
     ``S_C = Gam_C S_0 + sum_j (Gam_C / Gam_j) k_j u_j^T``. ``Gam_i / Gam_j``
-    is formed sub-block by sub-block of :data:`SUB` about the sub-block's
-    first position, so that neither factor leaves float32."""
+    is formed sub-block by sub-block of :data:`SUB`: against the columns
+    BEFORE a sub-block both factors stand about the log decay before its
+    first position (rows ``exp(G_i - ref)``, columns ``exp(ref - G_j)``,
+    both exponents <= 0); inside the sub-block pair by pair
+    (:func:`_diagonal`). No exponent is positive, so the form holds for any
+    ``g <= 0`` — a gate with no floor, a channel that forgets within a
+    token — where a factor ``1 / Gam_j`` about the sub-block's start would
+    be ``exp(SUB |g|)``."""
     B, T, H, D = q.shape
     C = min(CHUNK, -(-T // SUB) * SUB)
     pad = -T % C
@@ -250,35 +322,41 @@ def scan_chunked(q, k, v, g, beta, S0):
     q, k, v, g = (a.reshape(B, nc, C, H, D) for a in (q, k, v, g))
     beta = beta.reshape(B, nc, C, H)
     G = jnp.cumsum(g, axis=2)                       # (B, nc, C, H, D), <= 0
-    # rows i of sub-block a against columns j < (a + 1) SUB, both about the
-    # log decay standing before the sub-block
     A, QK = [], []
     for a in range(C // SUB):
         lo, hi = a * SUB, (a + 1) * SUB
-        ref = G[:, :, lo - 1:lo] if a else jnp.zeros_like(G[:, :, :1])
-        kn = _decayed(k, -G, -ref, hi)              # k_j / Gam_j x Gam_ref
         rows = slice(lo, hi)
+        # the columns before the sub-block (none before the first)
+        ref = G[:, :, lo - 1:lo] if a else jnp.zeros_like(G[:, :, :1])
+        kn = _decayed(k, -G, -ref, lo)              # k_j Gam_ref / Gam_j
         kp = k[:, :, rows] * jnp.exp(G[:, :, rows] - ref)
         qp = q[:, :, rows] * jnp.exp(G[:, :, rows] - ref)
-        A.append(jnp.einsum("bcihd,bcjhd->bchij", kp, kn, precision=HI))
-        QK.append(jnp.einsum("bcihd,bcjhd->bchij", qp, kn, precision=HI))
+        for out, rp, r in ((A, kp, k), (QK, qp, q)):
+            before = jnp.einsum("bcihd,bcjhd->bchij", rp, kn, precision=HI)
+            out.append(before.at[..., rows].set(
+                _diagonal(r[:, :, rows], k[:, :, rows], G[:, :, rows])))
     i, j = jnp.arange(C)[:, None], jnp.arange(C)[None, :]
     bt = beta.transpose(0, 1, 3, 2)                 # (B, nc, H, C)
     A = jnp.where(j < i, jnp.concatenate(A, axis=3), 0.0) * bt[..., None]
     QK = jnp.where(j <= i, jnp.concatenate(QK, axis=3), 0.0)
-    # forward substitution on [beta V | beta K+] for every block at once
+    # (I + A)^-1 by forward substitution on the identity, for every block
+    # at once, then ONE product with [beta V | beta K+]: a row of the
+    # substitution reads the whole of what it builds, C columns a row here
+    # where the right-hand sides have 2 D
     kplus = (k * jnp.exp(G)).transpose(0, 1, 3, 2, 4)        # (B, nc, H, C, D)
     rhs = jnp.concatenate([v.transpose(0, 1, 3, 2, 4), kplus], axis=-1) \
         * bt[..., None]
+    eye = jnp.eye(C, dtype=A.dtype)
 
-    def row(r, U):
-        new = lax.dynamic_index_in_dim(rhs, r, 3, keepdims=False) \
-            - jnp.einsum("bchj,bchjd->bchd",
+    def row(r, inv):
+        new = lax.dynamic_index_in_dim(eye, r, 0, keepdims=False) \
+            - jnp.einsum("bchj,bchjk->bchk",
                          lax.dynamic_index_in_dim(A, r, 3, keepdims=False),
-                         U, precision=HI)
-        return lax.dynamic_update_index_in_dim(U, new, r, 3)
+                         inv, precision=HI)
+        return lax.dynamic_update_index_in_dim(inv, new, r, 3)
 
-    U = lax.fori_loop(0, C, row, jnp.zeros_like(rhs))
+    inv = lax.fori_loop(0, C, row, jnp.zeros_like(A))
+    U = jnp.einsum("bchij,bchjd->bchid", inv, rhs, precision=HI)
     Uv, W = U[..., :D], U[..., D:]                  # T beta V, T beta K+
     qplus = (q * jnp.exp(G)).transpose(0, 1, 3, 2, 4)
     k_end = (k * jnp.exp(G[:, :, -1:] - G)).transpose(0, 1, 3, 2, 4)
@@ -354,20 +432,36 @@ def mix_step(cfg, p, y, S, W, layer, length, fused: bool):
     return _gate_out(cfg, p, o[:, None], z, y.dtype), S, W
 
 
+def mix(cfg, p, y, S, W, layer, lens, valid, fused: bool):
+    """A KDA layer of a served trunk on y (B, T, d) against the carried
+    buffers ``S`` (L, B, H, D, D) and ``W`` (L, B, K - 1, 3 inner) at
+    ``layer``: :func:`mix_step` for T == 1 (``lens`` (B,), ``fused``),
+    :func:`mix_chunk` with XLA's updates else (``valid``). Returns (out, S,
+    W): what every kind that holds KDA layers runs for one."""
+    if y.shape[1] == 1:
+        return mix_step(cfg, p, y, S, W, layer, lens, fused)
+    out, s_l, w_l = mix_chunk(
+        cfg, p, y, lax.dynamic_index_in_dim(S, layer, keepdims=False),
+        lax.dynamic_index_in_dim(W, layer, keepdims=False), valid)
+    return (out, lax.dynamic_update_slice(S, s_l[None], (layer, 0, 0, 0, 0)),
+            lax.dynamic_update_slice(W, w_l[None], (layer, 0, 0, 0)))
+
+
 # ------------------------------------------------------------ full forward
 def trunk(model, params, x, positions):
     """The layer stack on whole sequences, no cache handed in: the prefill
-    of an empty one, through the very loop the served path runs
-    (``inference/kinds/linear_sparse.py``), its kernels off. Returns (the
-    streams' sum (B, S, d), the expert layers' routing (layers, B, S, k))."""
-    from ..inference.kinds.linear_sparse import LinearSparse
+    of an empty one, through the very loop the served path runs (the
+    config's kind, ``inference/kinds``), its kernels off. Returns (the
+    stream (B, S, d), the expert layers' routing (layers, B, S, k))."""
+    from ..inference.kinds import kind_of
 
     B, S, _ = x.shape
-    kind = LinearSparse(model.cfg)
+    kind = kind_of(model.cfg)
     pool = model.cfg.index_kpool
     max_len = -(-S // pool) * pool
     cache = kind.empty(B, max_len, x.dtype)
     x, _, stats, _ = kind.forward(model, params, x, cache,
                                   jnp.asarray(S, jnp.int32), positions, None,
                                   False)
-    return x, stats[1][0]
+    routing = stats[1]
+    return x, routing[0] if isinstance(routing, tuple) else routing

@@ -167,6 +167,41 @@ def glm5_next(size: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def solar_open2(size: str = "tiny", **overrides) -> TransformerConfig:
+    """Solar-Open2 family (``model_type: solar_open2``): three layers in
+    four mix with Kimi Delta Attention behind Kimi Linear's own gate — no
+    floor (``kda_gate_floor`` 0) — and a beta in (0, 2)
+    (``kda_neg_eigval``), where the fourth (the first of every period: G K K
+    K) is softmax GQA with no position code whose output passes a sigmoid
+    gate a head a channel (``attn_out_gate``); one residual stream; sigmoid
+    experts beside a shared one in every layer. ``"250b"`` is
+    upstage/Solar-Open2-250B's ``config.json`` (48 layers, 64 query heads of
+    128 over 8 KV heads, 320 experts of 1280 top-8; KDA's two low-rank widths
+    are Kimi Linear's, the head width). ``"tiny"`` keeps a whole period at
+    unit-test size, four query heads a KV head."""
+    table = {
+        "tiny": dict(mixer_pattern="AKKK", n_layer=4, n_head=4, n_kv_head=1,
+                     d_model=64, qk_head_dim=32, d_ff=128, vocab_size=251,
+                     max_seq=512, kda_heads=4, kda_head_dim=16, kda_rank=16,
+                     num_experts=8, moe_top_k=2, moe_d_ff=32,
+                     moe_shared_d_ff=32),
+        "250b": dict(mixer_pattern=12 * "AKKK", n_layer=48, n_head=64,
+                     n_kv_head=8, d_model=4096, qk_head_dim=128, d_ff=10240,
+                     vocab_size=196608, max_seq=1048576, kda_heads=64,
+                     kda_head_dim=128, kda_rank=128, num_experts=320,
+                     moe_top_k=8, moe_d_ff=1280, moe_shared_d_ff=1280),
+    }
+    base = dict(attention="mha", pos_embedding="none", norm="rmsnorm",
+                norm_eps=1e-5, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, moe_router="sigmoid",
+                moe_norm_topk=True, moe_routed_scale=1.0, moe_first_dense=0,
+                kda_conv=4, kda_gate_floor=0.0, kda_neg_eigval=True,
+                attn_out_gate=True, fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def nemotron_h(size: str = "3-super-120b-a12b", **overrides) -> TransformerConfig:
     """The NemotronH block (``model_type: nemotron_h``): every layer ONE
     mixer, its kind a letter of the published ``hybrid_override_pattern`` —
@@ -402,10 +437,9 @@ def build_model(cfg, attention_fn=None):
 # (what tells a trunk that is served here and not trained, why)
 _SERVED_NOT_TRAINED = (
     (lambda cfg: getattr(cfg, "mixer_pattern", ""),
-     "a trunk of delta-rule mixers beside attention over an indexer's "
-     "selection (mixer_pattern) is served, not trained here: the chunkwise "
-     "delta-rule scan has no backward, and the selection has no gradient of "
-     "its own"),
+     "a trunk of delta-rule mixers beside attention layers (mixer_pattern) "
+     "is served, not trained here: the chunkwise delta-rule scan has no "
+     "backward, and an indexer's selection has no gradient of its own"),
     (lambda cfg: getattr(cfg, "index_pattern", ""),
      "a trunk whose attention reads an indexer's selection (index_pattern) "
      "is served, not trained here: the selection has no gradient of its own "

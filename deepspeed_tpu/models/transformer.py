@@ -234,21 +234,29 @@ class TransformerConfig:
     # ``index_topk / index_kpool`` best whole and its own group up to itself
     index_kpool: int = 1
     # a mixer a layer beside the FFN kinds (GLM-5.3-Flash, ``model_type:
-    # glm5_next_text``): layer i's mixer is ``mixer_pattern[i]`` — "K" Kimi
-    # Delta Attention (models/kda.py: ``kda_heads`` heads of ``kda_head_dim``
-    # key and value channels, a float32 delta-rule state a head, depthwise
-    # convs of ``kda_conv`` taps, the decay and the output gate behind
-    # low-rank maps ``kda_rank`` wide, the decay's log floored at
-    # ``kda_gate_floor``) | "A" the
-    # config's attention, which with an ``index_pattern`` ("-" for the "K"
-    # layers: no attention) reads a selection. "" is one mixer kind. The
-    # cache: inference/kinds/linear_sparse.py
+    # glm5_next_text``; Solar-Open2, ``model_type: solar_open2``): layer i's
+    # mixer is ``mixer_pattern[i]`` — "K" Kimi Delta Attention
+    # (models/kda.py: ``kda_heads`` heads of ``kda_head_dim`` key and value
+    # channels, a float32 delta-rule state a head, depthwise convs of
+    # ``kda_conv`` taps, the decay and the output gate behind low-rank maps
+    # ``kda_rank`` wide, the decay's log floored at ``kda_gate_floor`` or,
+    # at 0, not floored at all: ``-exp(A_log) softplus(.)``; beta in (0, 2)
+    # with ``kda_neg_eigval``) | "A" the config's attention: with
+    # ``attention='mla'`` and an ``index_pattern`` ("-" for the "K" layers:
+    # no attention) a latent read through a selection (the cache:
+    # inference/kinds/linear_sparse.py); with ``attention='mha'`` softmax
+    # GQA over whole K/V planes, no position code, its output times
+    # ``sigmoid(y w_ogate)`` a head a channel with ``attn_out_gate``
+    # (arXiv:2505.06708; the cache: inference/kinds/delta_gqa.py). "" is one
+    # mixer kind.
     mixer_pattern: str = ""
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_rank: int = 0
     kda_gate_floor: float = -5.0
+    kda_neg_eigval: bool = False
+    attn_out_gate: bool = False
     # manifold-constrained hyper-connections (mHC, arXiv:2512.24880;
     # models/mhc.py): ``hc_mult`` residual streams a token, each sub-layer
     # reading a mix of them and writing back through a doubly stochastic map
@@ -403,7 +411,8 @@ class TransformerConfig:
         # depthwise taps, like norms and biases, are left out)
         conv = self.cca_conv[1] * (h + kv) * hd * hd \
             if self.attention == "cca" else 0
-        return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d + conv
+        gate = d * h * vd if self.attn_out_gate else 0
+        return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d + conv + gate
 
     def _mixer_params_per_layer(self, kind: str, active_only: bool) -> int:
         """Matmul parameters of one ``block_pattern`` layer of ``kind``."""
@@ -776,7 +785,7 @@ class TransformerLM:
             raise ValueError("index_pattern selects positions of a latent "
                              "(attention='mla') cache")
         if config.mixer_pattern or config.hc_mult > 1 \
-                or config.index_kpool > 1:
+                or config.index_kpool > 1 or config.attn_out_gate:
             from .kda import check_config as check_kda
 
             check_kda(config)
@@ -934,6 +943,10 @@ class TransformerLM:
                 "wo": dense(next(k), (L, h * cfg.v_dim, d),
                             scale=1.0 / math.sqrt(2 * depth * d)),
             })
+            if cfg.attn_out_gate:
+                # a pre-activation of sd 1 on a normed input: the gate
+                # stands in 0.27 .. 0.73 for most channels, no constant half
+                layers["w_ogate"] = dense(next(k), (L, d, h * cfg.v_dim))
             if attn == "S" and cfg.attn_sink:
                 from .windowed import SINK_INIT
 
@@ -1064,6 +1077,8 @@ class TransformerLM:
                 "wv": P(None, None, "model"),
                 "wo": P(None, "model", None),
             })
+            if cfg.attn_out_gate:
+                layers["w_ogate"] = P(None, None, "model")
             if attn == "S" and cfg.attn_sink:
                 layers["sink"] = P(None, "model")
         if two_ln:
@@ -1456,7 +1471,7 @@ class TransformerLM:
         if self.cfg.mixer_pattern:
             if attn_mask is not None or remat_policy is not None:
                 raise NotImplementedError(
-                    "a trunk of delta-rule mixers beside selected attention "
+                    "a trunk of delta-rule mixers beside attention layers "
                     "(mixer_pattern) is served, not trained: no padding "
                     "mask, no remat")
             from .kda import trunk

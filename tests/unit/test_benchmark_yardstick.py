@@ -38,7 +38,10 @@ tests read): a cell ``workloads`` appends after theirs, which ``nh_spec`` and
 joins 24 accepted metrics (among them the Nemotron test's
 ``moe.held_rows_share`` and ``ssm.state_bytes_per_slot``), again a cell
 ``workloads`` appends after theirs, which the fixtures take out; its three
-own metrics list it alone and ``test_glm5_next.py`` pins no position. The Ouro test has no
+own metrics list it alone and ``test_glm5_next.py`` pins that its cell is
+the LAST of the 24 lists it joined: since PR 57 the Solar-Open2 cell stands
+behind it in 13 of them, and ``g53_spec`` below takes it out;
+``test_solar_open2.py`` pins no position. The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -62,7 +65,7 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_ouro", "benchmark.tests.test_nemotron_h",
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
     "benchmark.tests.test_falcon_h1", "benchmark.tests.test_glm_moe_dsa",
-    "benchmark.tests.test_glm5_next",
+    "benchmark.tests.test_glm5_next", "benchmark.tests.test_solar_open2",
     "benchmark.tests.test_program_lifecycle",
     "benchmark.tests.test_program_iterations")
 
@@ -78,6 +81,7 @@ from benchmark.tests.test_program_iterations import *  # noqa: E402,F401,F403
 from benchmark.tests.test_program_lifecycle import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reduce import *  # noqa: E402,F401,F403
 from benchmark.tests.test_reducers import *  # noqa: E402,F401,F403
+from benchmark.tests.test_solar_open2 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_traffic import *  # noqa: E402,F401,F403
 from benchmark.tests.test_window import *  # noqa: E402,F401,F403
 from benchmark.tests.test_zaya import *  # noqa: E402,F401,F403
@@ -86,6 +90,7 @@ from benchmark.tests.test_zaya import *  # noqa: E402,F401,F403
 NEMOTRON_CELL = "nemotron-3-super-l11-e128.serve-backlog-think"
 FALCON_CELL = "falcon-h1-34b-l6.serve-backlog-shortchat"
 GLM52_CELL = "glm-5.2-l7-e16.serve-backlog-longctx"
+GLM53_CELL = "glm-5.3-flash-l5-e36.serve-backlog-longgen"
 # appended since the two cells' tests pinned their sets, and listing them
 LATER = {"ssm.block_bytes_per_program"}
 
@@ -116,6 +121,43 @@ def glm_spec():  # noqa: F811
     # listing its cell alone list the GLM-5.3-Flash cell behind it)
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         return _without_cells_after(json.load(f), GLM52_CELL)
+
+
+@pytest.fixture(scope="module")
+def g53_spec():  # noqa: F811
+    # (since PR 57 the Solar-Open2 cell stands behind the GLM-5.3-Flash
+    # cell in the lists whose last entry that cell's test pins)
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        return _without_cells_after(json.load(f), GLM53_CELL)
+
+
+def test_the_seven_entries_read_the_record(monkeypatch):  # noqa: F811
+    """``test_program_iterations.py``'s test of that name, which pins the
+    lists of PR 53's seven metrics whole (it reads the file itself, through
+    no fixture): since PR 57 the Solar-Open2 cell stands behind the two
+    backlog cells in four of them. Run here on the file without the cells
+    ``workloads`` appends after the GLM-5.3-Flash cell."""
+    from benchmark.tests import test_program_iterations as pinned
+
+    load = json.load
+
+    def as_pr_56_left_it(f):
+        spec = load(f)
+        return _without_cells_after(spec, GLM53_CELL) \
+            if "per_layer" in spec else spec
+
+    monkeypatch.setattr(json, "load", as_pr_56_left_it)
+    pinned.test_the_seven_entries_read_the_record()
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        spec = load(f)
+    cell = spec["workloads"][-1]["name"]
+    assert cell.startswith("solar-open2") and all(
+        m["workloads"][-1] == cell for m in spec["per_layer"]
+        if m["name"].startswith(("host.stall_ms.inside",
+                                 "host.stall_ms.program",
+                                 "host.stall_ms.machine",
+                                 "sched.slots_running"))
+        and not m["name"].endswith("steady"))
 
 
 @pytest.fixture(scope="module")

@@ -15,14 +15,15 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
                                             cache_layout, init_cache,
                                             state_bytes_per_slot)
-from deepspeed_tpu.inference.kinds import (CCA, FEATURES, KINDS, Dense,
-                                           Hybrid, Latent, LinearSparse,
-                                           PagedKVCache, ParallelHybrid,
-                                           SparseLatent, Windowed, kind_of)
+from deepspeed_tpu.inference.kinds import (CCA, FEATURES, KINDS, DeltaGQA,
+                                           Dense, Hybrid, Latent,
+                                           LinearSparse, PagedKVCache,
+                                           ParallelHybrid, SparseLatent,
+                                           Windowed, kind_of)
 from deepspeed_tpu.models import (deepseek_v3, falcon_h1, glm5_next,
                                   glm_moe_dsa, mimo_v2_flash, nemotron_h,
-                                  ouro, presets, tiny_test, why_not_trained,
-                                  zaya)
+                                  ouro, presets, solar_open2, tiny_test,
+                                  why_not_trained, zaya)
 from deepspeed_tpu.observability.capacity import kv_cache_bytes
 from deepspeed_tpu.platform.mesh import MeshSpec, build_mesh
 from deepspeed_tpu.serving.pages import init_paged_slots
@@ -49,6 +50,8 @@ CASES = {
     "sparse-latent": (SparseLatent, lambda: glm_moe_dsa(
         "tiny", dtype=F32, moe_experts_held=2)),
     "linear-sparse": (LinearSparse, lambda: glm5_next(
+        "tiny", dtype=F32, moe_experts_held=2)),
+    "delta-gqa": (DeltaGQA, lambda: solar_open2(
         "tiny", dtype=F32, moe_experts_held=2)),
 }
 CONTIGUOUS = [name for name in CASES if name != "paged"]

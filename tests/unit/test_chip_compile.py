@@ -1323,19 +1323,14 @@ def test_the_latent_kind_s_programs_are_the_parent_s(one_chip, monkeypatch,
 GLM53 = dict(slots=160, max_len=8192, chunk=512)
 
 
-@pytest.fixture(scope="module")
-def glm53(one_chip):
-    """(cfg, model, abstract served params) of GLM-5.3-Flash's share
-    (``benchmark/configs/glm-5.3-flash-l5-e36.json``: layers K A K K K, 36 of
-    288 experts) at the published widths: built once for both programs."""
+def _served_share(one_chip, fam, name):
+    """(cfg, model, abstract served params) of the share that
+    ``benchmark/configs/<name>.json`` states, at the published widths."""
     import json
-
-    from benchmark.models import glm5_next as fam
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "glm-5.3-flash-l5-e36.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         cfg = fam.model_config(json.load(f)["config"], "bfloat16")
     model = build_model(cfg)
     keep = set(model.fp32_param_names())
@@ -1348,6 +1343,15 @@ def glm53(one_chip):
 
     return cfg, model, jax.tree_util.tree_map_with_path(
         served, jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+
+@pytest.fixture(scope="module")
+def glm53(one_chip):
+    """GLM-5.3-Flash's share (layers K A K K K, 36 of 288 experts): built
+    once for both programs."""
+    from benchmark.models import glm5_next as fam
+
+    return _served_share(one_chip, fam, "glm-5.3-flash-l5-e36")
 
 
 @pytest.mark.parametrize("program", ["slot step", "final chunk"])
@@ -1490,3 +1494,107 @@ def test_the_selected_read_holds_both_fetches(one_chip, monkeypatch, B, S, D,
     assert text.count("/sparse_mla_decode_attention/pallas_call") == 1
     # the mask goes to the kernel as it came: no copy re-tiles it
     assert not re.search(rf" copy\(.*f32\[{B},1,{S}\]", text)
+
+
+SOLAR = {"slots": 24, "max_len": 65536, "chunk": 512}
+
+
+@pytest.fixture(scope="module")
+def solar(one_chip):
+    """Solar-Open2's share (layers G K K K, 40 of 320 experts): built once
+    for both programs."""
+    from benchmark.models import solar_open2 as fam
+
+    return _served_share(one_chip, fam, "solar-open2-250b-l4-e40")
+
+
+@pytest.mark.parametrize("program", ["slot step", "final chunk"])
+def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
+        one_chip, monkeypatch, solar, program, capsys):
+    """Solar-Open2's share at the cell's 24 slots x 65 536, chunks of 512:
+    the four buffers enter donated and leave aliased; every program's live
+    set beside what else stands on the chip (the slots' state beside a
+    chunk, the batch-1 prefill cache beside the step) stays under 15.0 GiB
+    of the chip's 15.75; the step holds one call of the state step (a scan
+    of three KDA layers) and one of ``nope_gqa_decode_attention``, and NO
+    other operation touches the delta-rule state or the K/V planes; a
+    final chunk walks the key blocks and carries no (64, 512, 65 536) score
+    array."""
+    import time
+
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    g = SOLAR
+    cfg, model, params = solar
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 4096
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 13025280 \
+        == 3 * (64 * 128 * 128 * 4 + 3 * 3 * 8192 * 2)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 3308353344 \
+        and abs(weights / 2 ** 30 - 6.17) < 0.01
+    a_slot = g["max_len"] * 4096 + 13025280
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(lambda: init_slots(
+            cfg, g["slots"], g["max_len"], jnp.bfloat16)))
+        assert state.cache.kda.shape == (3, 24, 64, 128, 128) \
+            and state.cache.k.shape == (1, 24, 8, 128, 65536) \
+            and state.cache.conv.shape == (3, 24, 3, 24576)
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        held, beside = g["slots"] * a_slot, a_slot
+    else:
+        ids = jax.ShapeDtypeStruct((1, g["chunk"]), jnp.int32,
+                                   sharding=one_chip)
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, g["max_len"], jnp.bfloat16)))
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start), flash_decode=True,
+                last_token_head=True, last_index=last, with_stats=True,
+                with_routing=True),
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        held, beside = a_slot, g["slots"] * a_slot
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\n[delta gqa {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB; with "
+              f"what stands beside it {(live + beside) / 2 ** 30:.2f} GiB]")
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert live + beside < 15.0 * 2 ** 30, (live + beside) / 2 ** 30
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "kda_state_step", "nope_gqa_decode_attention", "moe_experts_up")}
+    step = program == "slot step"
+    assert count == {"kda_state_step": 1 if step else 0,
+                     "nope_gqa_decode_attention": 1 if step else 0,
+                     "moe_experts_up": 2}, count
+    assert not re.search(r"f32\[(1,)?64,512,65536\]", text)
+    if step:
+        passes = ("custom-call(", "parameter(", "get-tuple-element(",
+                  " tuple(", "while(", "bitcast(")
+        for buf in ("f32[3,24,64,128,128]", "bf16[1,24,8,128,65536]"):
+            touched = [ln for ln in text.splitlines()
+                       if ln.lstrip().startswith(("%", "ROOT"))
+                       and " = " in ln and buf in ln
+                       and not any(p in ln for p in passes)]
+            assert not touched, touched[:3]

@@ -409,9 +409,10 @@ def test_the_published_config_counts_the_card_s_parameters():
     (dict(mixer_pattern=""), "only beside the mixers of a mixer_pattern"),
     (dict(mixer_pattern="", pos_embedding="rope", qk_rope_head_dim=8,
           index_pattern="FFFFF"), "hc_mult and index_kpool"),
-    (dict(kda_gate_floor=-6.0), "stay inside float32"),
+    (dict(kda_gate_floor=1.0), "negative .the bounded gate. or 0"),
     (dict(index_topk=18), "a multiple of index_kpool"),
-    (dict(pos_embedding="rope", qk_rope_head_dim=8), "glm5_next_text block"),
+    (dict(pos_embedding="rope", qk_rope_head_dim=8), "no position code"),
+    (dict(index_pattern=""), "glm5_next_text\n?.*block|glm5_next_text"),
 ])
 def test_what_the_trunk_does_not_run_is_refused_with_why(over, why):
     with pytest.raises(ValueError, match=why):
