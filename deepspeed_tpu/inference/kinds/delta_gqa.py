@@ -21,10 +21,15 @@ One residual stream. The T == 1 step, an attention layer:
 blocks, under the name ``nope_gqa_decode_attention``; the heads' output times
 ``sigmoid(y w_ogate)`` (``attn_out_gate``), then ``wo``. A KDA layer:
 ``kda_state_step`` moves the state in place. T > 1 (a chunk, or a solo
-prefill): XLA's update of the planes and a walk over the live key blocks
+prefill): XLA's update of the planes, then the read of the live key blocks
+out of them — where the step's kernels run and the kernel tiles the shapes
+(:meth:`DeltaGQA.chunk_kernel`) ONE kernel a layer, ``gqa_chunk_attention``
+under the name ``nope_gqa_chunk_attention``, whose scores, probabilities and
+accumulators stay in VMEM; everywhere else the walk in plain ``jnp``
 (``windowed.attend_blocks``: the scores of one block of keys at a time, never
-``T x max_len``), and the chunkwise scan (``kda.scan_chunked``) behind
-``kda.mix_chunk``.
+``T x max_len``; ``Serve/chunk_attention_fallback_builds`` counts the chunk
+programs traced onto it with the kernels on) — and the chunkwise scan
+(``kda.scan_chunked``) behind ``kda.mix_chunk``.
 """
 
 from collections import namedtuple
@@ -35,11 +40,13 @@ from jax import lax
 
 from ...models import kda, windowed
 from ...models.transformer import _norm
+from ...ops import chunk_attention
 from ...ops.sparse_mla_attention import einsum_f32
 from ..quantization import matmul_any
 from .base import (IN_POOL, MOVES_PAGES, Kind, held_counts, served_bytes,
                    split_banks, stacked)
-from .steps import _append_attend, _ffn, _qkv_proj, _run
+from .steps import (_append_attend, _decode_kernel_ok, _ffn, _qkv_proj,
+                    _run)
 
 DeltaGQACache = namedtuple("DeltaGQACache", "k v kda conv length")
 
@@ -83,6 +90,29 @@ class DeltaGQA(Kind):
         return bool(getattr(cfg, "mixer_pattern", "")) \
             and getattr(cfg, "attention", "") == "mha"
 
+    def chunk_kernel(self, flash_decode, T, max_len, *dtypes) -> bool:
+        """Whether T > 1 queries over planes of ``max_len`` attend in
+        ``gqa_chunk_attention``: where the step's kernels would run
+        (``_decode_kernel_ok``: the switch, no float16, whole lane blocks)
+        and the kernel tiles the shapes (keys and values of whole lane
+        tiles, T a bucket its rows tile)."""
+        cfg = self.cfg
+        return T > 1 and _decode_kernel_ok(flash_decode, 1, max_len, *dtypes) \
+            and chunk_attention.chunk_kernel_fits(
+                T, cfg.n_head // cfg.kv_heads, max_len, cfg.head_dim,
+                cfg.head_dim)
+
+    def chunk_fused(self, flash_decode, T, max_len, *dtypes) -> bool:
+        fused = self.chunk_kernel(flash_decode, T, max_len, *dtypes)
+        if flash_decode and not fused:
+            from ...observability.metrics import get_registry
+
+            # counted where a chunk's program is built (a trace, not a
+            # call), as Serve/decode_fallback_builds counts the step's
+            get_registry().counter(
+                "Serve/chunk_attention_fallback_builds").inc()
+        return fused
+
     def state(self, batch, dtype=None):
         shapes = kda.state_shapes(self.cfg, batch)
         return {"kda": ((self.mixers,) + shapes["kda"], jnp.float32),
@@ -124,8 +154,13 @@ class DeltaGQA(Kind):
                 ck, cv = (lax.dynamic_update_slice(
                     c, n.transpose(0, 2, 3, 1)[None].astype(c.dtype),
                     (ai, 0, 0, 0, start)) for c, n in ((ck, k), (cv, v)))
-                o = windowed.attend_blocks(q, ck, cv, positions, new_len,
-                                           layer=ai)
+                if fused:
+                    o = chunk_attention.gqa_chunk_attention(
+                        q, ck, cv, start, layer=ai,
+                        name="nope_gqa_chunk_attention")
+                else:
+                    o = windowed.attend_blocks(q, ck, cv, positions, new_len,
+                                               layer=ai)
             o = o.reshape(B, T, -1)
             if cfg.attn_out_gate:
                 with jax.named_scope("attn_out_gate"):
@@ -162,13 +197,17 @@ class DeltaGQA(Kind):
 
     # ------------------------------------------------------------ the spans
     def chunk_meta(self, chunk) -> dict:
-        """:meth:`sizes`, the chunk's tokens, real and padded, and the keys
-        its attention layers' walk reads (``attn_live_keys``: the positions
-        before the chunk and its own)."""
+        """:meth:`sizes`, the chunk's tokens, real and padded, the keys its
+        attention layers read (``attn_live_keys``: the positions before the
+        chunk and its own) and whether the kernel reads them
+        (``attn_kernel``)."""
         real = chunk.last_index + 1 if chunk.final else chunk.size
         return {**self.sizes(), "tokens_real": real,
                 "tokens_padded": chunk.size - real,
-                "attn_live_keys": chunk.start + chunk.size}
+                "attn_live_keys": chunk.start + chunk.size,
+                "attn_kernel": self.chunk_kernel(
+                    self.flash, chunk.size, self.max_len,
+                    self.dtype or self.cfg.dtype)}
 
     def step_meta(self, read, pending, lens, running):
         """:meth:`sizes`; from the mirror of the slots' lengths what the

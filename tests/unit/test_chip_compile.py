@@ -1508,7 +1508,7 @@ def solar(one_chip):
     return _served_share(one_chip, fam, "solar-open2-250b-l4-e40")
 
 
-@pytest.mark.parametrize("program", ["slot step", "final chunk"])
+@pytest.mark.parametrize("program", ["slot step", "final chunk", "chunk"])
 def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
         one_chip, monkeypatch, solar, program, capsys):
     """Solar-Open2's share at the cell's 24 slots x 65 536, chunks of 512:
@@ -1518,8 +1518,10 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
     of the chip's 15.75; the step holds one call of the state step (a scan
     of three KDA layers) and one of ``nope_gqa_decode_attention``, and NO
     other operation touches the delta-rule state or the K/V planes; a
-    final chunk walks the key blocks and carries no (64, 512, 65 536) score
-    array."""
+    chunk, final or not, attends in ONE call of ``nope_gqa_chunk_attention``
+    (Mosaic takes the kernel at the cell's shape) and carries neither a
+    block's float32 scores ``(1, 8, 8, 512, 512)`` nor a (64, 512, 65 536)
+    score array."""
     import time
 
     from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
@@ -1560,11 +1562,12 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
                                    sharding=one_chip)
         cache = on_chip(jax.eval_shape(
             lambda: init_cache(cfg, 1, g["max_len"], jnp.bfloat16)))
+        final = program == "final chunk"
         compiled = jax.jit(
             lambda p, c, ids, start, last: forward_with_cache(
                 model, p, ids, c._replace(length=start), flash_decode=True,
-                last_token_head=True, last_index=last, with_stats=True,
-                with_routing=True),
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True),
             donate_argnums=(1,)).lower(params, cache, ids, i32,
                                        i32).compile()
         held, beside = a_slot, g["slots"] * a_slot
@@ -1588,7 +1591,10 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
     assert count == {"kda_state_step": 1 if step else 0,
                      "nope_gqa_decode_attention": 1 if step else 0,
                      "moe_experts_up": 2}, count
+    assert sum("/nope_gqa_chunk_attention/pallas_call" in ln
+               for ln in calls) == (0 if step else 1)
     assert not re.search(r"f32\[(1,)?64,512,65536\]", text)
+    assert "f32[1,8,8,512,512]" not in text
     if step:
         passes = ("custom-call(", "parameter(", "get-tuple-element(",
                   " tuple(", "while(", "bitcast(")

@@ -392,3 +392,123 @@ def test_the_kind_refuses_what_it_does_not_compose_with(served):
     kind = kind_of(eng.model.cfg)
     assert set(kind.refuses) == {"paged", "kv_quant", "speculation",
                                  "host_kv", "quantize", "mesh"}
+
+
+# ------------------------------------------------- the chunk's attention
+def _chunks(model, params, ids, cuts, flash, dtype, max_len=MAX_LEN):
+    """``ids`` prefilled in chunks ending at ``cuts``: (logits, the cache
+    before the last chunk, the cache behind it)."""
+    cache = init_cache(model.cfg, 1, max_len, dtype)
+    fwd = jax.jit(lambda p, ids, cache: forward_with_cache(
+        model, p, ids, cache, flash_decode=flash))
+    out, at = [], 0
+    for cut in cuts:
+        before = cache
+        lg, cache = fwd(params, ids[:, at:cut], cache)
+        out.append(lg)
+        at = cut
+    return jnp.concatenate(out, 1), before, cache
+
+
+@pytest.mark.parametrize("dtype,tol", [(F32, 2e-5), (jnp.bfloat16, 4e-2)],
+                         ids=["f32", "bf16"])
+def test_a_chunked_prefill_on_the_kernel_is_the_walk_s(small, dtype, tol):
+    """Chunks of 32, 32, 32 and a bucket of 16 through ``forward_with_cache``
+    with the kernels on (``nope_gqa_chunk_attention``, interpreted): the
+    walk's logits — to float32 rounding, and in bf16 to what two roundings
+    of ``p`` under another blocking differ by — the attention layer's planes
+    bit-equal in float32 (the kernel writes none and the one attention layer
+    stands first: nothing attended stands in front of its K/V), nothing
+    outside the last chunk's positions touched."""
+    cfg, model, params, ids, _ = small
+    if dtype != F32:
+        cfg, model = fam.build(published(), "bfloat16", False)
+        params = model.init(jax.random.PRNGKey(3))
+    cuts = (32, 64, 96, 112)
+    walk, _, c0 = _chunks(model, params, ids[:, :112], cuts, False, dtype)
+    got, before, c1 = _chunks(model, params, ids[:, :112], cuts, True, dtype)
+    scale = float(jnp.abs(walk.astype(F32)).max())
+    assert float(jnp.abs(got.astype(F32) - walk.astype(F32)).max()) \
+        <= tol * scale
+    if dtype == F32:
+        assert np.array_equal(np.asarray(c0.k), np.asarray(c1.k))
+        assert np.array_equal(np.asarray(c0.v), np.asarray(c1.v))
+    lo, hi = cuts[-2], cuts[-1]
+    for name in ("k", "v"):
+        a, b = (np.asarray(getattr(c, name).astype(F32))
+                for c in (before, c1))
+        assert np.array_equal(a[..., :lo], b[..., :lo]), name
+        assert np.array_equal(a[..., hi:], b[..., hi:]), name
+        assert not np.array_equal(a[..., lo:hi], b[..., lo:hi]), name
+
+
+@pytest.mark.parametrize("what,T,max_len,flash,walks", [
+    ("the kernel", 32, 128, True, 0),
+    ("a bucket of 8", 8, 128, True, 0),
+    ("a cache of no whole lane block", 32, 96, True, 1),
+    ("queries nothing tiles", 12, 128, True, 1),
+    ("the kernels off", 32, 128, False, 0),
+])
+def test_a_chunk_traced_onto_the_walk_is_counted(small, what, T, max_len,
+                                                 flash, walks):
+    """``Serve/chunk_attention_fallback_builds``: one for every chunk program
+    traced onto ``windowed.attend_blocks`` while the kernels are on; the
+    kind's own answer (what the ``prefill_chunk`` span says) agrees."""
+    from deepspeed_tpu.observability.metrics import get_registry
+
+    cfg, model, params, ids, _ = small
+    counter = get_registry().counter("Serve/chunk_attention_fallback_builds")
+    before = counter.value
+    cache = init_cache(cfg, 1, max_len, F32)
+    text = str(jax.make_jaxpr(lambda p, ids, cache: forward_with_cache(
+        model, p, ids, cache, flash_decode=flash))(params, ids[:, :T], cache))
+    assert counter.value - before == walks
+    kind = kind_of(cfg, 1, F32)
+    took = flash and not walks
+    assert kind.chunk_kernel(flash, T, max_len, F32, F32) == took
+    assert ("nope_gqa_chunk_attention" in text) == took
+
+
+def test_a_chunk_s_span_says_what_attended_and_over_how_many_keys(small):
+    from deepspeed_tpu.serving.scheduler import ChunkPlan
+
+    cfg = small[0]
+    kind = kind_of(cfg, 2, F32)
+    chunk = ChunkPlan(start=64, ids=np.zeros(32, np.int32))
+    assert kind.chunk_meta(chunk)["attn_live_keys"] == 96
+    assert kind.chunk_meta(chunk)["attn_kernel"] is False   # no engine's
+    kind.flash, kind.max_len = True, 128
+    assert kind.chunk_meta(chunk)["attn_kernel"] is True
+    kind.max_len = 96
+    assert kind.chunk_meta(chunk)["attn_kernel"] is False
+
+
+def test_served_with_the_kernels_every_chunk_attends_in_the_kernel(small):
+    """``ServingEngine`` with ``flash_decode`` on (interpreted): every
+    ``prefill_chunk`` span says ``attn_kernel`` and its live keys, no chunk
+    program was traced onto the walk, and the tokens are solo
+    ``generate()``'s (whose prefill is the walk's)."""
+    from deepspeed_tpu.observability.metrics import get_registry
+
+    cfg, model, params, _, _ = small
+    mesh = build_mesh(MeshSpec(data=1), devices=jax.devices()[:1])
+    eng = ds.init_inference(model, params, {"dtype": "float32",
+                                            "flash_decode": True}, mesh=mesh)
+    counter = get_registry().counter("Serve/chunk_attention_fallback_builds")
+    before = counter.value
+    srv = ds.ServingEngine(eng, {"slots": 2, "max_len": 128,
+                                 "prefill_chunk": 16, "greedy": True,
+                                 "spans": True})
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, (P,)).astype(np.int32)
+               for P in (9, 37)]
+    outs = srv.serve_batch(prompts, [3, 3])
+    for p, got in zip(prompts, outs):
+        want = eng.generate(p[None], 3, greedy=True, cache_len=128)
+        assert np.asarray(got).tolist() == np.asarray(want)[0].tolist()
+    chunks = [e.meta for e in srv.spans.events() if e.kind == "prefill_chunk"]
+    assert len(chunks) >= 4 and all(m["attn_kernel"] is True for m in chunks)
+    assert all(m["attn_live_keys"] == m["size"] + 16 * m["chunk"]
+               for m in chunks)
+    assert counter.value == before
+    srv.close()
