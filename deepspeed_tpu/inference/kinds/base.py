@@ -203,6 +203,22 @@ def served_bytes(cfg, params, banks=("w_gate", "w_in", "w_out")) -> tuple:
             nbytes(params.get("lm_head", ())))
 
 
+def count_chunk_fallbacks(flash_decode: bool, attention: bool, scan: bool):
+    """Where a chunk's program of a kind with KDA layers is built (a trace,
+    not a call, as ``Serve/decode_fallback_builds`` counts the step's): with
+    the kernels on, count the program onto ``Serve/
+    chunk_attention_fallback_builds`` if its ``attention`` fell back to
+    XLA's walk and onto ``Serve/chunk_scan_fallback_builds`` if its KDA
+    layers' ``scan`` fell back to XLA's (``kda.chunk_scan_falls_back``)."""
+    if not flash_decode:
+        return
+    from ...observability.metrics import get_registry
+
+    for name, fell_back in (("attention", attention), ("scan", scan)):
+        if fell_back:
+            get_registry().counter(f"Serve/chunk_{name}_fallback_builds").inc()
+
+
 def stacked(stats: list):
     """The expert segments' (counters, routing) as one of each, or None."""
     return tuple(jnp.concatenate(part) for part in zip(*stats)) \

@@ -28,8 +28,13 @@ under the name ``nope_gqa_chunk_attention``, whose scores, probabilities and
 accumulators stay in VMEM; everywhere else the walk in plain ``jnp``
 (``windowed.attend_blocks``: the scores of one block of keys at a time, never
 ``T x max_len``; ``Serve/chunk_attention_fallback_builds`` counts the chunk
-programs traced onto it with the kernels on) — and the chunkwise scan
-(``kda.scan_chunked``) behind ``kda.mix_chunk``.
+programs traced onto it with the kernels on) — and the chunkwise scan behind
+``kda.mix_chunk``: with the kernels on and T whole blocks of 64
+(``kda.chunk_kernel_ok``) ``kda_chunk_scan``, ONE kernel a layer whose
+decays, ``(I + A)^-1`` and carried state stay in VMEM; else
+``kda.scan_chunked`` in plain ``jnp`` (a bucket of 8 to 32 by the rule;
+``Serve/chunk_scan_fallback_builds`` counts the chunk programs of whole
+blocks traced onto it with the kernels on).
 """
 
 from collections import namedtuple
@@ -43,8 +48,8 @@ from ...models.transformer import _norm
 from ...ops import chunk_attention
 from ...ops.sparse_mla_attention import einsum_f32
 from ..quantization import matmul_any
-from .base import (IN_POOL, MOVES_PAGES, Kind, held_counts, served_bytes,
-                   split_banks, stacked)
+from .base import (IN_POOL, MOVES_PAGES, Kind, count_chunk_fallbacks,
+                   held_counts, served_bytes, split_banks, stacked)
 from .steps import (_append_attend, _decode_kernel_ok, _ffn, _qkv_proj,
                     _run)
 
@@ -104,13 +109,8 @@ class DeltaGQA(Kind):
 
     def chunk_fused(self, flash_decode, T, max_len, *dtypes) -> bool:
         fused = self.chunk_kernel(flash_decode, T, max_len, *dtypes)
-        if flash_decode and not fused:
-            from ...observability.metrics import get_registry
-
-            # counted where a chunk's program is built (a trace, not a
-            # call), as Serve/decode_fallback_builds counts the step's
-            get_registry().counter(
-                "Serve/chunk_attention_fallback_builds").inc()
+        count_chunk_fallbacks(flash_decode, not fused,
+                              kda.chunk_scan_falls_back(self.cfg, fused, T))
         return fused
 
     def state(self, batch, dtype=None):
@@ -132,12 +132,11 @@ class DeltaGQA(Kind):
         per_slot = getattr(new_len, "ndim", 0) == 1
         lens = new_len if per_slot else jnp.broadcast_to(new_len, (B,))
         start = None if per_slot else new_len - T
-        in_place = kda.step_kernel_ok(cfg, fused and T == 1)
 
         def mixer(carry, p, ki):
             x, ck, cv, St, W = carry
             y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
-            out, St, W = kda.mix(cfg, p, y, St, W, ki, lens, valid, in_place)
+            out, St, W = kda.mix(cfg, p, y, St, W, ki, lens, valid, fused)
             return x + out, ck, cv, St, W
 
         def attention(carry, p, ai):
@@ -199,15 +198,17 @@ class DeltaGQA(Kind):
     def chunk_meta(self, chunk) -> dict:
         """:meth:`sizes`, the chunk's tokens, real and padded, the keys its
         attention layers read (``attn_live_keys``: the positions before the
-        chunk and its own) and whether the kernel reads them
-        (``attn_kernel``)."""
+        chunk and its own), whether the kernel reads them (``attn_kernel``)
+        and whether the KDA layers' scan is the kernel's (``scan_kernel``)."""
         real = chunk.last_index + 1 if chunk.final else chunk.size
+        attn = self.chunk_kernel(self.flash, chunk.size, self.max_len,
+                                 self.dtype or self.cfg.dtype)
         return {**self.sizes(), "tokens_real": real,
                 "tokens_padded": chunk.size - real,
                 "attn_live_keys": chunk.start + chunk.size,
-                "attn_kernel": self.chunk_kernel(
-                    self.flash, chunk.size, self.max_len,
-                    self.dtype or self.cfg.dtype)}
+                "attn_kernel": attn,
+                "scan_kernel": kda.chunk_kernel_ok(self.cfg, attn,
+                                                   chunk.size)}
 
     def step_meta(self, read, pending, lens, running):
         """:meth:`sizes`; from the mirror of the slots' lengths what the

@@ -33,7 +33,10 @@ fetches the selected groups, whose ``index_kpool`` rows lie side by side: one
 DMA a group). A KDA layer: ``kda_state_step`` moves the
 state in place. T > 1 (a chunk that starts at a group's edge — the
 scheduler's chunks start at multiples of ``prefill_chunk`` — or a solo
-prefill): XLA's updates, the chunkwise scan (``kda.scan_chunked``), the
+prefill): XLA's updates, the chunkwise scan (``kda_chunk_scan``, ONE kernel
+a layer, where the kernels run and T is whole blocks of 64:
+``kda.chunk_kernel_ok``; else ``kda.scan_chunked`` in plain ``jnp``, a chunk
+of whole blocks counted by ``Serve/chunk_scan_fallback_builds``), the
 selection as a mask over ``sparse_mla_chunk_attention`` where the kernels
 run, else over ``mla.attend_expanded``.
 """
@@ -51,7 +54,8 @@ from ...models.transformer import _norm
 from ...ops import mla_attention
 from ...ops import sparse_mla_attention as sparse
 from ..quantization import matmul_any
-from .base import (IN_POOL, MOVES_PAGES, Kind, _nbytes, held_counts,
+from .base import (IN_POOL, MOVES_PAGES, Kind, _nbytes,
+                   count_chunk_fallbacks, held_counts,
                    served_bytes, split_banks)
 from .sparse_latent import SparseLatent, _index, query_blocks, read_meta
 
@@ -138,11 +142,8 @@ class LinearSparse(Kind):
         # (the gate hands the first plane's last dimension: the groups)
         fused = self.chunk_kernel(flash_decode, T,
                                   groups * self.cfg.index_kpool, *dtypes)
-        if flash_decode and not fused:
-            from ...observability.metrics import get_registry
-
-            get_registry().counter(
-                "Serve/chunk_attention_fallback_builds").inc()
+        count_chunk_fallbacks(flash_decode, not fused,
+                              kda.chunk_scan_falls_back(self.cfg, fused, T))
         return fused
 
     # ------------------------------------------------------------ the loop
@@ -162,7 +163,6 @@ class LinearSparse(Kind):
         scale = mla.softmax_scale(cfg)
         lens = new_len if per_slot else jnp.broadcast_to(new_len, (B,))
         live = lens > 0
-        in_place = kda.step_kernel_ok(cfg, fused and T == 1)
         segs = model.segment_params(params["layers"])
         streams = cfg.hc_mult > 1
 
@@ -199,7 +199,7 @@ class LinearSparse(Kind):
                 y = _norm(xin, p["ln1_scale"], None, cfg.norm,
                           cfg.norm_eps).astype(dt)
                 out, S2, W2 = kda.mix(cfg, p, y, St, W, ki, lens, valid,
-                                      in_place)
+                                      fused)
                 return out, (S2, W2)
 
             X, (St, W) = residual(X, p, 0, f)
@@ -427,15 +427,17 @@ class LinearSparse(Kind):
     def chunk_meta(self, chunk):
         real = chunk.last_index + 1 if chunk.final else chunk.size
         meta = self._dsa(chunk.start + 1 + np.arange(real))
-        dt = self.dtype or self.cfg.dtype
+        attn = self.chunk_kernel(self.flash, chunk.size, self.max_len,
+                                 self.dtype or self.cfg.dtype)
         return {**self.sizes(),
                 "dsa_selected_over_live": meta["dsa_selected_over_live"],
                 "dsa_keys_scored_over_live":
                     meta["dsa_keys_scored_over_live"],
                 "tokens_real": real, "tokens_padded": chunk.size - real,
                 "attn_live_keys": chunk.start + chunk.size,
-                "attn_kernel": self.chunk_kernel(self.flash, chunk.size,
-                                                 self.max_len, dt)}
+                "attn_kernel": attn,
+                "scan_kernel": kda.chunk_kernel_ok(self.cfg, attn,
+                                                   chunk.size)}
 
     def step_meta(self, read, pending, lens, running):
         """:meth:`sizes`; from the mirror of the slots' lengths what the

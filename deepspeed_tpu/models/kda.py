@@ -29,7 +29,10 @@ everything that multiplies it.
   hand both out, the paper's chunkwise form (:func:`scan_chunked`): blocks
   of :data:`CHUNK` tokens; inside a block the delta rule is a unit lower
   triangular system, solved by forward substitution for every block at once;
-  between blocks a scan over the block-start states. ``valid`` (traced) says
+  between blocks a scan over the block-start states — or, where the kind's
+  kernels run and T is whole blocks (:func:`chunk_kernel_ok`), the same
+  mathematics in ONE kernel with the blocks' temporaries and the carried
+  state in VMEM (``ops/kda_chunk.py``). ``valid`` (traced) says
   how many of the T tokens are real: what a bucket pads behind a prompt gets
   ``beta = 0`` and ``g = 0``, which changes nothing, and the tails end at
   the last real token.
@@ -195,6 +198,26 @@ def step_kernel_ok(cfg, fused: bool) -> bool:
     from ..ops.kda_step import kernel_fits
 
     return fused and kernel_fits(cfg.kda_heads, cfg.kda_head_dim)
+
+
+def chunk_kernel_ok(cfg, fused: bool, T: int) -> bool:
+    """Whether T > 1 tokens scan in the Pallas kernel (``ops/kda_chunk.py``:
+    a block's decays, its ``(I + A)^-1`` and the carried state in VMEM):
+    where the kind's kernels run (``fused``) and T is a whole number of
+    blocks of :data:`CHUNK` of whole lane tiles of channels. A bucket of 8,
+    16 or 32 keeps :func:`scan_chunked`: a block filled up costs the kernel
+    what a whole one does, 0.18 ms, where the scan of so few tokens reads
+    0.05 to 0.14 (PERF.md §6 "PR 61")."""
+    from ..ops.kda_chunk import kernel_fits
+
+    return bool(fused) and T > 1 and kernel_fits(T, cfg.kda_head_dim)
+
+
+def chunk_scan_falls_back(cfg, fused: bool, T: int) -> bool:
+    """A chunk whose shapes the kernel takes, scanned by XLA all the same:
+    the kind's kernels do not run (``Serve/chunk_scan_fallback_builds``
+    counts such a program where ``flash_decode`` is on)."""
+    return not fused and chunk_kernel_ok(cfg, True, T)
 
 
 # ---------------------------------------------------------------- the parts
@@ -377,11 +400,12 @@ def scan_chunked(q, k, v, g, beta, S0):
     return o.reshape(B, nc * C, H, D)[:, :T] / math.sqrt(D), S_T
 
 
-def mix_chunk(cfg, p, y, S, conv, valid=None):
+def mix_chunk(cfg, p, y, S, conv, valid=None, fused: bool = False):
     """T tokens y (B, T, d) after the layer's norm; ``S`` (B, H, D, D)
     float32 and ``conv`` (B, K - 1, 3 inner) the state before them;
-    ``valid`` (traced i32, None: T) how many are real. Returns (out (B, T,
-    d), S, conv) with the states as the last real token leaves them."""
+    ``valid`` (traced i32, None: T) how many are real. ``fused``: the Pallas
+    kernel scans (:func:`chunk_kernel_ok`). Returns (out (B, T, d), S, conv)
+    with the states as the last real token leaves them."""
     B, T, _ = y.shape
     K = cfg.kda_conv
     u = einsum_f32("btd,dc->btc", y, p["kda_wqkv"].astype(y.dtype))
@@ -399,7 +423,12 @@ def mix_chunk(cfg, p, y, S, conv, valid=None):
         beta = jnp.where(real, beta, 0.0)
         g = jnp.where(real[..., None], g, 0.0)
         new_conv = lax.dynamic_slice_in_dim(seq, valid, K - 1, axis=1)
-    o, S = scan_chunked(q, k, v, g, beta, S)
+    if fused:
+        from ..ops.kda_chunk import kda_chunk_scan
+
+        o, S = kda_chunk_scan(q, k, v, g, beta, S)
+    else:
+        o, S = scan_chunked(q, k, v, g, beta, S)
     return _gate_out(cfg, p, o, z, y.dtype), S, new_conv.astype(conv.dtype)
 
 
@@ -435,14 +464,19 @@ def mix_step(cfg, p, y, S, W, layer, length, fused: bool):
 def mix(cfg, p, y, S, W, layer, lens, valid, fused: bool):
     """A KDA layer of a served trunk on y (B, T, d) against the carried
     buffers ``S`` (L, B, H, D, D) and ``W`` (L, B, K - 1, 3 inner) at
-    ``layer``: :func:`mix_step` for T == 1 (``lens`` (B,), ``fused``),
-    :func:`mix_chunk` with XLA's updates else (``valid``). Returns (out, S,
-    W): what every kind that holds KDA layers runs for one."""
-    if y.shape[1] == 1:
-        return mix_step(cfg, p, y, S, W, layer, lens, fused)
+    ``layer``: :func:`mix_step` for T == 1 (``lens`` (B,)), :func:`mix_chunk`
+    with XLA's updates else (``valid``). ``fused``: the kind's kernels run;
+    whether this layer's does is told from the shapes
+    (:func:`step_kernel_ok`, :func:`chunk_kernel_ok`). Returns (out, S, W):
+    what every kind that holds KDA layers runs for one."""
+    T = y.shape[1]
+    if T == 1:
+        return mix_step(cfg, p, y, S, W, layer, lens,
+                        step_kernel_ok(cfg, fused))
     out, s_l, w_l = mix_chunk(
         cfg, p, y, lax.dynamic_index_in_dim(S, layer, keepdims=False),
-        lax.dynamic_index_in_dim(W, layer, keepdims=False), valid)
+        lax.dynamic_index_in_dim(W, layer, keepdims=False), valid,
+        chunk_kernel_ok(cfg, fused, T))
     return (out, lax.dynamic_update_slice(S, s_l[None], (layer, 0, 0, 0, 0)),
             lax.dynamic_update_slice(W, w_l[None], (layer, 0, 0, 0)))
 

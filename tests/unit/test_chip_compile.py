@@ -1366,7 +1366,9 @@ def test_linear_sparse_trunk_fits_and_moves_its_state_in_place(
     score and the selected read — handed the selection's mask beside its
     groups of 4, the dense side in the program — and NO other operation
     touches the delta-rule state or the latents' buffer; a final chunk holds one call of the chunk's attention
-    kernel and carries no (64, 512, 8192) score array."""
+    kernel and carries no (64, 512, 8192) score array; its KDA layers scan
+    in ``kda_chunk_scan``, one call a run of layers (two), and the XLA
+    scan's ``(1, 8, 64, 64, 64)`` float32 inverse is gone from its text."""
     import time
 
     from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
@@ -1434,15 +1436,17 @@ def test_linear_sparse_trunk_fits_and_moves_its_state_in_place(
     count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
         "kda_state_step", "sparse_mla_decode_attention",
         "sparse_mla_chunk_attention", "dsa_index_score", "mla_cache_append",
-        "moe_experts_up")}
+        "moe_experts_up", "kda_chunk_scan")}
     step = program == "slot step"
     assert count == {
         "kda_state_step": 2 if step else 0,
+        "kda_chunk_scan": 0 if step else 2,
         "sparse_mla_decode_attention": 1 if step else 0,
         "sparse_mla_chunk_attention": 0 if step else 1,
         "dsa_index_score": 1 if step else 0,
         "mla_cache_append": 1 if step else 0, "moe_experts_up": 2}, count
     assert not re.search(r"f32\[(1,)?64,512,8192\]", text)
+    assert "f32[1,8,64,64,64]" not in text      # the scan's inverse, in HBM
     if step:
         reads = [ln for ln in calls
                  if "/sparse_mla_decode_attention/pallas_call" in ln]
@@ -1521,7 +1525,9 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
     chunk, final or not, attends in ONE call of ``nope_gqa_chunk_attention``
     (Mosaic takes the kernel at the cell's shape) and carries neither a
     block's float32 scores ``(1, 8, 8, 512, 512)`` nor a (64, 512, 65 536)
-    score array."""
+    score array, and its three KDA layers scan in ONE call of
+    ``kda_chunk_scan`` (a scan of three layers) with the XLA scan's ``(1, 8,
+    64, 64, 64)`` float32 inverse gone from its text."""
     import time
 
     from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
@@ -1586,15 +1592,18 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
-        "kda_state_step", "nope_gqa_decode_attention", "moe_experts_up")}
+        "kda_state_step", "nope_gqa_decode_attention", "moe_experts_up",
+        "kda_chunk_scan")}
     step = program == "slot step"
     assert count == {"kda_state_step": 1 if step else 0,
+                     "kda_chunk_scan": 0 if step else 1,
                      "nope_gqa_decode_attention": 1 if step else 0,
                      "moe_experts_up": 2}, count
     assert sum("/nope_gqa_chunk_attention/pallas_call" in ln
                for ln in calls) == (0 if step else 1)
     assert not re.search(r"f32\[(1,)?64,512,65536\]", text)
     assert "f32[1,8,8,512,512]" not in text
+    assert "f32[1,8,64,64,64]" not in text      # the scan's inverse, in HBM
     if step:
         passes = ("custom-call(", "parameter(", "get-tuple-element(",
                   " tuple(", "while(", "bitcast(")

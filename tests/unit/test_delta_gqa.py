@@ -63,9 +63,11 @@ def worst(got, want):
                   / np.abs(want).max(-1)).max())
 
 
-def through_the_kind(cfg, model, params, ids, flash):
-    """The prompt in chunks of CHUNK, the last right-padded to its bucket
-    (107 = 3 x 32 + 11 in a bucket of 16), seated in slots 0 and 2 of three,
+def through_the_kind(cfg, model, params, ids, flash, size=CHUNK):
+    """The prompt in chunks of ``size``, the last right-padded to its bucket
+    (107 = 3 x 32 + 11 in a bucket of 16; 64 + 43 in a bucket of 64, where
+    the KDA layers scan in ``kda_chunk_scan`` with the kernels on, behind
+    ``valid`` in the second), seated in slots 0 and 2 of three,
     slot 2 then retired; 7 steps, one token a slot. Returns (slot 0's logit
     rows from the prompt's last on, the buffers of slots 1 and 2 before the
     steps, and after)."""
@@ -85,9 +87,9 @@ def through_the_kind(cfg, model, params, ids, flash):
     cache = init_cache(cfg, 1, MAX_LEN, F32)
     start = 0
     while start < PROMPT:
-        n = min(CHUNK, PROMPT - start)
-        size = CHUNK if n == CHUNK else max(8, 1 << (n - 1).bit_length())
-        blk = np.zeros((1, size), np.int32)
+        n = min(size, PROMPT - start)
+        bucket = size if n == size else max(8, 1 << (n - 1).bit_length())
+        blk = np.zeros((1, bucket), np.int32)
         blk[0, :n] = np.asarray(ids[0, start:start + n])
         row, cache = chunk(cache, jnp.asarray(blk), jnp.int32(start),
                            jnp.int32(n - 1))
@@ -111,21 +113,24 @@ def through_the_kind(cfg, model, params, ids, flash):
     return np.stack([np.asarray(r) for r in rows]), before, after
 
 
-@pytest.mark.parametrize("path", ["forward", "kind", "kind, kernels on"])
+@pytest.mark.parametrize("path", ["forward", "kind", "kind, kernels on",
+                                  "kind, chunks of 64, kernels on"])
 def test_the_trunk_matches_the_plain_reference(small, path):
     """The full forward; and prefill in chunks (a padded final one), seating
     and 7 decode steps, every logit row, with XLA's updates and with the
-    kernels (interpreted here: the state step and the appending decode
-    attention under its own name). A slot that is not running — retired with
-    a prompt's state in it, or never seated — keeps every buffer bit-equal
+    kernels (interpreted here: the state step, the appending decode
+    attention under its own name, the chunk's attention and — at chunks of
+    64 — the chunk's scan). A slot that is not running — retired with a
+    prompt's state in it, or never seated — keeps every buffer bit-equal
     with the kernels on."""
     cfg, model, params, ids, want = small
     with jax.default_matmul_precision("highest"):
         if path == "forward":
             assert worst(model.apply(params, ids), want) < 2e-4
             return
-        got, before, after = through_the_kind(cfg, model, params, ids,
-                                              path.endswith("on"))
+        got, before, after = through_the_kind(
+            cfg, model, params, ids, path.endswith("on"),
+            64 if "chunks of 64" in path else CHUNK)
     assert worst(got, want[0, PROMPT - 1:]) < 2e-4
     if path.endswith("on"):
         assert all(np.array_equal(a, b) for a, b in zip(before, after))
@@ -477,10 +482,15 @@ def test_a_chunk_s_span_says_what_attended_and_over_how_many_keys(small):
     chunk = ChunkPlan(start=64, ids=np.zeros(32, np.int32))
     assert kind.chunk_meta(chunk)["attn_live_keys"] == 96
     assert kind.chunk_meta(chunk)["attn_kernel"] is False   # no engine's
+    assert kind.chunk_meta(chunk)["scan_kernel"] is False
     kind.flash, kind.max_len = True, 128
     assert kind.chunk_meta(chunk)["attn_kernel"] is True
+    assert kind.chunk_meta(chunk)["scan_kernel"] is False   # a bucket of 32
+    whole = ChunkPlan(start=64, ids=np.zeros(64, np.int32))
+    assert kind.chunk_meta(whole)["scan_kernel"] is True
     kind.max_len = 96
     assert kind.chunk_meta(chunk)["attn_kernel"] is False
+    assert kind.chunk_meta(whole)["scan_kernel"] is False
 
 
 def test_served_with_the_kernels_every_chunk_attends_in_the_kernel(small):

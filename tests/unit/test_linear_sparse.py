@@ -61,8 +61,8 @@ def worst(got, want):
                   / np.abs(want).max(-1)).max())
 
 
-def through_the_cache(cfg, model, params, ids, flash):
-    """The prompt in chunks of CHUNK, the last right-padded to its bucket,
+def through_the_cache(cfg, model, params, ids, flash, size=CHUNK):
+    """The prompt in chunks of ``size``, the last right-padded to its bucket,
     then one token a step: the logit rows from the prompt's last on."""
     @partial(jax.jit, donate_argnums=(0,))
     def chunk(cache, blk, start, last):
@@ -80,9 +80,9 @@ def through_the_cache(cfg, model, params, ids, flash):
     cache = init_cache(cfg, 1, MAX_LEN, F32)
     start, rows = 0, []
     while start < PROMPT:
-        n = min(CHUNK, PROMPT - start)
-        size = CHUNK if n == CHUNK else max(8, 1 << (n - 1).bit_length())
-        blk = np.zeros((1, size), np.int32)
+        n = min(size, PROMPT - start)
+        bucket = size if n == size else max(8, 1 << (n - 1).bit_length())
+        blk = np.zeros((1, bucket), np.int32)
         blk[0, :n] = np.asarray(ids[0, start:start + n])
         row, cache = chunk(cache, jnp.asarray(blk), jnp.int32(start),
                            jnp.int32(n - 1))
@@ -95,20 +95,24 @@ def through_the_cache(cfg, model, params, ids, flash):
     return np.stack([np.asarray(r) for r in rows])
 
 
-@pytest.mark.parametrize("path", ["forward", "cache", "cache, kernels on"])
+@pytest.mark.parametrize("path", ["forward", "cache", "cache, kernels on",
+                                  "cache, chunks of 64, kernels on"])
 def test_the_trunk_matches_the_plain_reference(small, path):
     """The full forward; and prefill in chunks (a padded final one: 101 = 3 x
-    32 + 5 in a bucket of 8, ending mid-group) then 25 decode steps over
-    six group edges, with XLA's updates and with the kernels (interpreted
-    here: the state step, the pooled keys' append, the score, the selected
-    read, the chunk's attention)."""
+    32 + 5 in a bucket of 8, ending mid-group; or 64 + 37 in a bucket of 64)
+    then 25 decode steps over six group edges, with XLA's updates and with
+    the kernels (interpreted here: the state step, the pooled keys' append,
+    the score, the selected read, the chunk's attention and — at chunks of
+    64 — the chunk's scan, ``kda_chunk_scan``, behind ``valid`` in the
+    padded one)."""
     cfg, model, params, ids, want = small
     with jax.default_matmul_precision("highest"):
         if path == "forward":
             assert worst(model.apply(params, ids), want) < 2e-4
         else:
             got = through_the_cache(cfg, model, params, ids,
-                                    path.endswith("on"))
+                                    path.endswith("on"),
+                                    64 if "chunks of 64" in path else CHUNK)
             assert worst(got, want[0, PROMPT - 1:]) < 2e-4
 
 
@@ -365,6 +369,23 @@ def test_the_kind_s_host_count_is_what_the_kernel_took(small):
     assert meta["dsa_fetched_over_selected"] == 1.0
     assert meta["dsa_selected"] == 2052 and meta["dsa_dense_share"] == 1.0
     assert meta["dsa_rows_read_over_selected"] == walked / 2052 < 1.6
+
+
+@pytest.mark.parametrize("T,flash,max_len,scan", [
+    (64, True, 512, True), (32, True, 512, False), (8, True, 512, False),
+    (64, False, 512, False), (64, True, 500, False)])
+def test_a_chunk_s_span_says_which_scan_ran(small, T, flash, max_len, scan):
+    """``scan_kernel`` beside ``attn_kernel`` on a ``prefill_chunk`` span:
+    ``kda_chunk_scan`` with the kernels on (a cache of whole lane blocks:
+    the attention kernel's rule) and a chunk of whole blocks of 64."""
+    from deepspeed_tpu.inference.kinds import kind_of
+    from deepspeed_tpu.serving.scheduler import ChunkPlan
+
+    kind = kind_of(small[0], 2, F32)
+    kind.flash, kind.max_len = flash, max_len
+    meta = kind.chunk_meta(ChunkPlan(start=64, ids=np.zeros(T, np.int32)))
+    assert meta["scan_kernel"] is scan
+    assert meta["attn_kernel"] is (flash and max_len % 128 == 0)
 
 
 # ------------------------------------------------------- the chip's share
