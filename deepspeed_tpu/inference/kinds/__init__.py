@@ -6,6 +6,7 @@ below them (``steps.py``); nothing outside this package adds a kind."""
 from .base import FEATURES, Kind
 from .cca import CCA, CCACache
 from .delta_gqa import DeltaGQA, DeltaGQACache
+from .delta_latent import DeltaLatent, DeltaLatentCache
 from .dense import Dense, KVCache, PagedKVCache
 from .hybrid import Hybrid, HybridCache
 from .latent import Latent, LatentCache
@@ -15,12 +16,12 @@ from .sparse_latent import SparseLatent, SparseLatentCache
 from .windowed import Windowed, WindowedCache
 
 KINDS = (Dense, Latent, LinearSparse, SparseLatent, Hybrid, Windowed, CCA,
-         ParallelHybrid, DeltaGQA)
+         ParallelHybrid, DeltaGQA, DeltaLatent)
 
-__all__ = ["KINDS", "LinearSparse", "DeltaGQA", "FEATURES", "Kind", "kind_of",
+__all__ = ["KINDS", "LinearSparse", "DeltaGQA", "DeltaLatent", "FEATURES", "Kind", "kind_of",
            "KVCache", "PagedKVCache", "LatentCache", "HybridCache",
            "WindowedCache", "CCACache", "ParallelCache", "SparseLatentCache",
-           "LinearSparseCache", "DeltaGQACache"]
+           "LinearSparseCache", "DeltaGQACache", "DeltaLatentCache"]
 
 
 def kind_of(cfg, *serving) -> Kind:

@@ -46,12 +46,11 @@ from jax import lax
 from ...models import kda, windowed
 from ...models.transformer import _norm
 from ...ops import chunk_attention
-from ...ops.sparse_mla_attention import einsum_f32
 from ..quantization import matmul_any
 from .base import (IN_POOL, MOVES_PAGES, Kind, count_chunk_fallbacks,
                    held_counts, served_bytes, split_banks, stacked)
-from .steps import (_append_attend, _decode_kernel_ok, _ffn, _qkv_proj,
-                    _run)
+from .steps import (_append_attend, _decode_kernel_ok, _ffn, _out_gate,
+                    _qkv_proj, _run)
 
 DeltaGQACache = namedtuple("DeltaGQACache", "k v kda conv length")
 
@@ -120,27 +119,62 @@ class DeltaGQA(Kind):
                          dtype or self.cfg.dtype)}
 
     # ------------------------------------------------------------ the loop
+    def loop(self, model, params, x, planes, cache, lens, valid, fused,
+             attention, live=None):
+        """Each run of layers equal in (mixer, FFN kind, clamps) over its
+        own stacked weights, all of them carrying (the stream, the
+        attention layers' ``planes``, the state, the tails), a layer touching
+        only its kind's. ``attention(x, planes, p, layer) -> (x, planes)``: an
+        "A" layer's mixer, the kind's own; ``live`` (B,) bool or None: the
+        rows the expert layers' fifth counter counts. Returns (x, planes,
+        state, tails, stats): stats (counters (expert layers, 4 or 5),
+        routing (expert layers, B, T, k)) or None."""
+        cfg = self.cfg
+
+        def mixer(carry, p, ki):
+            x, planes, St, W = carry
+            y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+            out, St, W = kda.mix(cfg, p, y, St, W, ki, lens, valid, fused)
+            return x + out, planes, St, W
+
+        def attends(carry, p, ai):
+            x, planes, St, W = carry
+            x, planes = attention(x, planes, p, ai)
+            return x, planes, St, W
+
+        mixers = {"K": mixer, "A": attends}
+        carry = (x, planes, cache.kda, cache.conv)
+        seen = dict.fromkeys(mixers, 0)
+        stats = []
+        for (ffn, n), attn, limits, seg in zip(
+                cfg.segments, cfg.segment_attn, cfg.segment_limits,
+                model.segment_params(params["layers"])):
+            banks, rest = split_banks(model, seg, ffn == "moe")
+
+            def body(carry, p, idx, attn=attn, banks=banks, limits=limits,
+                     first=seen[attn]):
+                carry = mixers[attn](carry, p, idx)
+                x, out = _ffn(model, carry[0], p, banks, idx - first,
+                              limits=limits, live=live)
+                return (x,) + carry[1:], out
+
+            with jax.named_scope("decode_layer"):
+                carry, out = _run(body, carry, rest, n, seen[attn])
+            seen[attn] += n
+            if ffn == "moe":
+                stats.append(out)
+        return (*carry, stacked(stats))
+
     def forward(self, model, params, x, cache, new_len, positions, valid,
                 fused):
-        """Each run of layers equal in (mixer, FFN kind) over its own
-        stacked weights, all of them carrying (the stream, the four
-        buffers), a layer touching only its kind's two. Stats: (counters
-        (expert layers, 4), routing (expert layers, B, T, k)) or None."""
         cfg = self.cfg
         B, T, _ = x.shape
-        dt = x.dtype
         per_slot = getattr(new_len, "ndim", 0) == 1
         lens = new_len if per_slot else jnp.broadcast_to(new_len, (B,))
         start = None if per_slot else new_len - T
 
-        def mixer(carry, p, ki):
-            x, ck, cv, St, W = carry
-            y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
-            out, St, W = kda.mix(cfg, p, y, St, W, ki, lens, valid, fused)
-            return x + out, ck, cv, St, W
-
-        def attention(carry, p, ai):
-            x, ck, cv, St, W = carry
+        def attention(x, planes, p, ai):
+            ck, cv = planes
             y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
             q, k, v = _qkv_proj(model, y, p)          # no position code
             if T == 1:
@@ -160,39 +194,15 @@ class DeltaGQA(Kind):
                 else:
                     o = windowed.attend_blocks(q, ck, cv, positions, new_len,
                                                layer=ai)
-            o = o.reshape(B, T, -1)
-            if cfg.attn_out_gate:
-                with jax.named_scope("attn_out_gate"):
-                    gate = jax.nn.sigmoid(einsum_f32(
-                        "btd,dc->btc", y.astype(dt),
-                        p["w_ogate"].astype(dt)))
-                    o = (o.astype(jnp.float32) * gate).astype(dt)
-            return (x + matmul_any(o, p["wo"], use_kernel=False), ck, cv, St,
-                    W)
+            o = _out_gate(cfg, p, y, o) if cfg.attn_out_gate \
+                else o.reshape(B, T, -1)
+            return x + matmul_any(o, p["wo"], use_kernel=False), (ck, cv)
 
-        mixers = {"K": mixer, "A": attention}
-        carry = (x, cache.k, cache.v, cache.kda, cache.conv)
-        seen = dict.fromkeys(mixers, 0)
-        stats = []
-        for (ffn, n), attn, seg in zip(
-                cfg.segments, cfg.segment_attn,
-                model.segment_params(params["layers"])):
-            banks, rest = split_banks(model, seg, ffn == "moe")
-
-            def body(carry, p, idx, attn=attn, banks=banks,
-                     first=seen[attn]):
-                carry = mixers[attn](carry, p, idx)
-                x, out = _ffn(model, carry[0], p, banks, idx - first)
-                return (x,) + carry[1:], out
-
-            with jax.named_scope("decode_layer"):
-                carry, out = _run(body, carry, rest, n, seen[attn])
-            seen[attn] += n
-            if ffn == "moe":
-                stats.append(out)
-        x, k, v, St, W = carry
+        x, (k, v), St, W, stats = self.loop(
+            model, params, x, (cache.k, cache.v), cache, lens, valid, fused,
+            attention)
         return (x, DeltaGQACache(k=k, v=v, kda=St, conv=W, length=new_len),
-                stacked(stats), None)
+                stats, None)
 
     # ------------------------------------------------------------ the spans
     def chunk_meta(self, chunk) -> dict:

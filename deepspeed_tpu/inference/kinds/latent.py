@@ -23,20 +23,23 @@ from .steps import _dense_append, _out_ffn
 LatentCache = namedtuple("LatentCache", "c length")
 
 
-@jax.named_scope("decode_layer")
-def _layer_step(model, x, p, cache_c, length, positions, fused: bool,
-                layer, banks=None, bank_layer=None):
-    """One latent-attention layer over x: (B, T, d) against the carried
-    latent cache ``(L, B, rank + rope, max_len)``, layer ``layer`` of it.
-    ``banks`` / ``bank_layer``: as :func:`_out_ffn`'s. Returns (x_out,
-    cache, (the expert layer's counters, the experts chosen))."""
-    cfg = model.cfg
-    T = x.shape[1]
-    y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+def attend(cfg, p, y, cache_c, length, positions, fused: bool, layer,
+           keep_idle: bool = False):
+    """Latent attention of the layer's normed input ``y`` (B, T, d) against
+    the carried latent cache ``(L, B, rank + rope, max_len)``, layer ``layer``
+    of it: the T new latents appended, then the read — absorbed over the
+    slot's live latents for T == 1 (``fused``: ``ops/mla_attention.py``'s
+    kernels, in place), expanded block by block over the live prefix for a
+    chunk. ``keep_idle``: the kernels' append leaves a slot at length 0
+    bit-equal (else its position 0 is written, which the next insert
+    overwrites). Returns (o (B, T, H, v), cache): what every kind whose
+    attention layers are dense MLA runs for one."""
+    T = y.shape[1]
     q_nope, q_rope, new = mla.project(cfg, y, p, positions)
     if fused:
-        cache_c = mla_attention.latent_append(cache_c, new[:, 0], length,
-                                              layer=layer)
+        cache_c = mla_attention.latent_append(
+            cache_c, new[:, 0], length, layer=layer,
+            **({"keep_idle": True} if keep_idle else {}))
         o_lat = mla_attention.mla_decode_attention(
             mla.absorb_q(cfg, p, q_nope, q_rope), cache_c, length,
             layer=layer, rank=cfg.kv_lora_rank, scale=mla.softmax_scale(cfg))
@@ -61,6 +64,19 @@ def _layer_step(model, x, p, cache_c, length, positions, fused: bool,
         else:
             o = mla.attend_expanded(cfg, p, q_nope, q_rope, slab, positions,
                                     jnp.max(length))
+    return o, cache_c
+
+
+@jax.named_scope("decode_layer")
+def _layer_step(model, x, p, cache_c, length, positions, fused: bool,
+                layer, banks=None, bank_layer=None):
+    """One latent-attention layer over x: (B, T, d) against the carried
+    latent cache ``(L, B, rank + rope, max_len)``, layer ``layer`` of it.
+    ``banks`` / ``bank_layer``: as :func:`_out_ffn`'s. Returns (x_out,
+    cache, (the expert layer's counters, the experts chosen))."""
+    cfg = model.cfg
+    y = _norm(x, p["ln1_scale"], None, cfg.norm, cfg.norm_eps)
+    o, cache_c = attend(cfg, p, y, cache_c, length, positions, fused, layer)
     x, stats = _out_ffn(model, x, o, p, banks, bank_layer,
                         cfg.moe_router == "sigmoid")
     return x, cache_c, stats
@@ -86,8 +102,10 @@ class Latent(Kind):
     def matches(cfg) -> bool:
         # (with an index_pattern the latents lie a position a row:
         # kinds/sparse_latent.py)
+        # (beside the mixers of a mixer_pattern: kinds/delta_latent.py)
         return getattr(cfg, "attention", "") == "mla" \
-            and not getattr(cfg, "index_pattern", "")
+            and not getattr(cfg, "index_pattern", "") \
+            and not getattr(cfg, "mixer_pattern", "")
 
     def buffers(self, batch, max_len, dtype=None):
         cfg = self.cfg

@@ -104,9 +104,11 @@ class LinearSparse(Kind):
 
     @staticmethod
     def matches(cfg) -> bool:
-        # (beside the config's own GQA: kinds/delta_gqa.py)
+        # (beside the config's own GQA: kinds/delta_gqa.py; beside dense
+        # MLA, no indexer: kinds/delta_latent.py)
         return bool(getattr(cfg, "mixer_pattern", "")) \
-            and getattr(cfg, "attention", "") == "mla"
+            and getattr(cfg, "attention", "") == "mla" \
+            and bool(getattr(cfg, "index_pattern", ""))
 
     # ---------------------------------------------------------- the layout
     def buffers(self, batch, max_len, dtype=None):

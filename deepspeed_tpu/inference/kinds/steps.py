@@ -12,6 +12,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...models.transformer import _activation, _norm, _rope, alibi_slopes
 from ...ops import decode_attention as da     # (tests patch its function)
+from ...ops.sparse_mla_attention import einsum_f32
 from ...platform.mesh import BATCH_AXES, constrain
 from ..quantization import QuantizedTensor, matmul_any, tp_quant_dot
 
@@ -352,6 +353,22 @@ def _layer_step(model, x, p, cache_k, cache_v, length, positions,
     return (x, cache_k, cache_v, *tails)
 
 
+def _out_gate(cfg, p, y, o):
+    """The heads' output ``o`` (B, T, H, vd) times ``sigmoid(y w_ogate)`` off
+    the layer's normed input ``y`` (``attn_out_gate``, arXiv:2505.06708): a
+    value a head a channel, or with ``'head'`` one a head; the product in
+    float32. Returns (B, T, H vd)."""
+    B, T, _, vd = o.shape
+    dt = y.dtype
+    o = o.reshape(B, T, -1)
+    with jax.named_scope("attn_out_gate"):
+        gate = jax.nn.sigmoid(einsum_f32("btd,dc->btc", y.astype(dt),
+                                         p["w_ogate"].astype(dt)))
+        if cfg.attn_out_gate == "head":
+            gate = jnp.repeat(gate, vd, axis=-1)
+        return (o.astype(jnp.float32) * gate).astype(dt)
+
+
 def _out_ffn(model, x, o, p, banks, layer, sorted_rows: bool = True):
     """The attention's output ``o`` (B, T, H, vd) through ``wo`` onto the
     stream, then the layer's FFN (:func:`_ffn`)."""
@@ -360,16 +377,19 @@ def _out_ffn(model, x, o, p, banks, layer, sorted_rows: bool = True):
     return _ffn(model, x, p, banks, layer, sorted_rows)
 
 
-def _ffn(model, x, p, banks, layer, sorted_rows: bool = True):
+def _ffn(model, x, p, banks, layer, sorted_rows: bool = True, **segment):
     """The layer's FFN onto the stream: the sorted expert rows where it has
     a router (``banks`` / ``layer``: the segment's stacked expert weights and
-    this layer's index in them), else the dense block. Returns (x, (the
-    expert layer's counters, the experts chosen (B, T, k))), zeros if dense."""
+    this layer's index in them; ``segment``: its clamps and the rows that
+    are live, ``MoETransformerLM.experts``' ``limits`` / ``live``), else the
+    dense block. Returns (x, (the expert layer's counters, the experts
+    chosen (B, T, k))), zeros if dense."""
     cfg = model.cfg
     B, T, _ = x.shape
     y2 = _norm(x, p["ln2_scale"], None, cfg.norm, cfg.norm_eps)
     if "router" in p and sorted_rows:
-        out, stats, chose = model.experts(y2, p, banks=banks, layer=layer)
+        out, stats, chose = model.experts(y2, p, banks=banks, layer=layer,
+                                          **segment)
     else:
         out, stats = model._mlp_block(y2, p)[0], jnp.zeros((4,), jnp.float32)
         chose = jnp.zeros((B, T, 0), jnp.int32)

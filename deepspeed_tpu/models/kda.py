@@ -1,10 +1,11 @@
 """Kimi Delta Attention (KDA; Kimi Linear, arXiv:2510.26692 §3): the linear
-attention mixer of GLM-5.3-Flash (``model_type: glm5_next_text``) and of
-Solar-Open2 (``model_type: solar_open2``), ``cfg.mixer_pattern`` "K", in the
-forms a served trunk needs. They have to agree, and
-``tests/unit/test_linear_sparse.py`` and ``tests/unit/test_delta_gqa.py``
-hold them to the plain recurrence of ``benchmark/reference/glm5_next.py`` and
-``benchmark/reference/solar_open2.py``.
+attention mixer of GLM-5.3-Flash (``model_type: glm5_next_text``), of
+Solar-Open2 (``model_type: solar_open2``) and of Ling-3.0-flash (``model_type:
+bailing_hybrid``), ``cfg.mixer_pattern`` "K", in the forms a served trunk
+needs. They have to agree, and ``tests/unit/test_linear_sparse.py``,
+``tests/unit/test_delta_gqa.py`` and ``tests/unit/test_delta_latent.py`` hold
+them to the plain recurrence of ``benchmark/reference/glm5_next.py``,
+``solar_open2.py`` and ``bailing_hybrid.py``.
 
 On the layer's normed input ``y``, per head h of ``kda_heads`` with ``D =
 kda_head_dim`` key and value channels:
@@ -21,7 +22,10 @@ kda_head_dim`` key and value channels:
 (GLM-5.3-Flash's ``gate_lower_bound``), 0 reads "no floor" — Kimi Linear's
 own gate, unbounded below (Solar-Open2's). ``c`` is 2 with
 ``kda_neg_eigval`` (``I - beta k k^T`` then has the eigenvalue ``1 - beta``
-in (-1, 1)), else 1. Every form here holds for any ``g <= 0``: no factor is
+in (-1, 1)), else 1. With ``kda_rank`` 0 the two low-rank pairs ``W_f1 W_f2``
+and ``W_g1 W_g2`` are ONE full map each (``kda_wf``, ``kda_wg``:
+Ling-3.0-flash's ``no_kda_lora``); with ``kda_qk_norm`` q and k take a learned
+gain a channel before their L2 norm. Every form here holds for any ``g <= 0``: no factor is
 the exponential of a positive number. ``S`` (D x D a head) is float32, and
 everything that multiplies it.
 
@@ -67,28 +71,47 @@ def check_config(c) -> None:
             "hc_mult and index_kpool are the glm5_next_text trunk's "
             "(mixer_pattern): the other trunks' layer loops carry one "
             "stream and one indexer key a position")
-    if c.attn_out_gate and not (pat and c.attention == "mha"):
+    if c.attn_out_gate and not (pat and (
+            (c.attention == "mha" and c.attn_out_gate is True)
+            or (c.attention == "mla" and c.attn_out_gate == "head"
+                and not c.index_pattern))):
         raise ValueError(
             "attn_out_gate is the solar_open2 block's (mixer_pattern "
-            "beside attention='mha'): no other trunk's attention layer "
-            "multiplies its output by a gate")
+            "beside attention='mha': True, a value a head a channel) or the "
+            "bailing_hybrid block's (mixer_pattern beside attention='mla' "
+            "with no index_pattern: 'head', a value a head): no other "
+            "trunk's attention layer multiplies its output by a gate")
     if len(pat) != c.n_layer or set(pat) - set(KINDS):
         raise ValueError(f"mixer_pattern {pat!r} has to name each of the "
                          f"{c.n_layer} layers, one of {KINDS!r}")
-    if "K" in pat and (min(c.kda_heads, c.kda_head_dim, c.kda_rank) <= 0
+    if "K" in pat and (min(c.kda_heads, c.kda_head_dim) <= 0
+                       or c.kda_rank < 0
                        or c.kda_conv < 2 or c.kda_gate_floor > 0):
         raise ValueError(
-            "a 'K' layer needs kda_heads, kda_head_dim, kda_rank, kda_conv "
-            ">= 2 and a kda_gate_floor that is negative (the bounded gate) "
-            "or 0 (no floor: the gate unbounded below)")
+            "a 'K' layer needs kda_heads, kda_head_dim, kda_rank (0: full "
+            "maps), kda_conv >= 2 and a kda_gate_floor that is negative (the "
+            "bounded gate) or 0 (no floor: the gate unbounded below)")
+    # the bailing_hybrid block: "A" is roped dense MLA, every latent read
+    roped = c.attention == "mla" and not c.index_pattern
+    if roped and (c.hc_mult > 1 or c.index_kpool > 1 or c.q_lora_rank
+                  or c.qk_rope_head_dim <= 0 or c.pos_embedding != "rope"):
+        raise ValueError(
+            "mixer_pattern beside attention='mla' with no index_pattern is "
+            "the bailing_hybrid block: KDA layers beside roped latent "
+            "attention over every live position under one residual stream "
+            "— rope (pos_embedding='rope') on the 'A' layers' "
+            "qk_rope_head_dim, one query matrix, no hc_mult or index_kpool "
+            "(with an index_pattern it is the glm5_next_text block)")
     if c.num_experts < 2 or c.moe_router != "sigmoid" \
             or c.norm != "rmsnorm" or c.use_bias or c.tie_embeddings \
-            or c.pos_embedding != "none" or c.loop_steps > 1 \
-            or c.block_pattern or c.attn_pattern:
+            or c.pos_embedding != ("rope" if roped else "none") \
+            or c.loop_steps > 1 or c.block_pattern or c.attn_pattern:
         raise ValueError(
             "a mixer_pattern trunk has no position code (the KDA layers "
-            "carry the order), RMSNorm, no biases, an untied head and a "
-            "dense FFN or sigmoid-routed experts beside every mixer")
+            "carry the order; beside attention='mla' with no index_pattern "
+            "rope, on the 'A' layers' qk_rope_head_dim alone), RMSNorm, no "
+            "biases, an untied head and a dense FFN or sigmoid-routed "
+            "experts beside every mixer")
     if c.attention == "mha":
         # the solar_open2 block: "A" is the config's own GQA, gated
         if c.index_pattern or c.hc_mult > 1 or c.index_kpool > 1 \
@@ -101,12 +124,15 @@ def check_config(c) -> None:
                 "index_kpool (the glm5_next_text block's, attention='mla'), "
                 "values as wide as keys, whole groups of query heads")
         return
-    if c.attention != "mla" or not c.index_pattern:
+    if c.attention != "mla":
         raise ValueError(
             "mixer_pattern beside attention='mla' is the glm5_next_text "
-            "block: KDA layers beside latent attention over an indexer's "
-            "selection (index_pattern); beside attention='mha' it is the "
+            "block (KDA layers beside latent attention over an indexer's "
+            "selection: index_pattern) or, with no index_pattern, the "
+            "bailing_hybrid block; beside attention='mha' it is the "
             "solar_open2 block; no other attention stands beside KDA layers")
+    if roped:
+        return
     if "s" in c.index_pattern:
         raise ValueError(
             "index_pattern 's' (a layer that takes the selection of the one "
@@ -156,6 +182,15 @@ def init_params(cfg, key, dense, n: int, depth: int) -> dict:
     inner = H * D
     k = iter(jax.random.split(key, 12))
     bound = 1.0 / math.sqrt(K)
+
+    def maps(name, scale=1.0):
+        """The decay's or the gate's map d_model -> inner: a low-rank pair
+        or (``kda_rank`` 0) one full matrix."""
+        if not R:
+            return {name: dense(next(k), (n, d, inner)) * scale}
+        return {name + "1": dense(next(k), (n, d, R)),
+                name + "2": dense(next(k), (n, R, inner)) * scale}
+
     if cfg.kda_gate_floor:
         A = jax.random.uniform(next(k), (n, H), jnp.float32, 1.0, 4.0)
         dt_bias = -jax.random.uniform(next(k), (n, H, D), jnp.float32, 1.0,
@@ -166,30 +201,38 @@ def init_params(cfg, key, dense, n: int, depth: int) -> dict:
                                         math.log(1e-3), math.log(1e-1)))
         dt_bias = dt + jnp.log(-jnp.expm1(-dt)) \
             + 6.0 * (jnp.arange(H) % 8 == min(7, H - 1))[None, :, None]
-    return {
+    out = {
         "kda_wqkv": dense(next(k), (n, d, 3 * inner)),
         "kda_conv_w": jax.random.uniform(next(k), (n, 3 * inner, K),
                                          jnp.float32, -bound, bound),
         "kda_wbeta": dense(next(k), (n, d, H)),
-        "kda_wf1": dense(next(k), (n, d, R)),
-        "kda_wf2": dense(next(k), (n, R, inner)) * 0.25,
+        **maps("kda_wf", 0.25),
         "kda_A_log": jnp.log(A),
         "kda_dt_bias": dt_bias.reshape(n, inner),
-        "kda_wg1": dense(next(k), (n, d, R)),
-        "kda_wg2": dense(next(k), (n, R, inner)),
+        **maps("kda_wg"),
         "kda_norm_scale": jnp.ones((n, D), jnp.float32),
         "wo": dense(next(k), (n, inner, d),
                     scale=1.0 / math.sqrt(2 * depth * inner)),
     }
+    if cfg.kda_qk_norm:
+        # a trained gain is not 1 a channel: drawn in (0.5, 1.5), so that a
+        # path which drops it (the L2 norm behind it hides any constant)
+        # reads differently
+        out["kda_qk_scale"] = jax.random.uniform(
+            next(k), (n, 2, D), jnp.float32, 0.5, 1.5)
+    return out
 
 
-def param_specs() -> dict:
+def param_specs(cfg) -> dict:
     # whole on every device: the served trunk refuses a mesh
     three = P(None, None, None)
+    maps = ("kda_wf1", "kda_wf2", "kda_wg1", "kda_wg2") if cfg.kda_rank \
+        else ("kda_wf", "kda_wg")
     return {"kda_wqkv": three, "kda_conv_w": three, "kda_wbeta": three,
-            "kda_wf1": three, "kda_wf2": three, "kda_A_log": P(None, None),
-            "kda_dt_bias": P(None, None), "kda_wg1": three, "kda_wg2": three,
-            "kda_norm_scale": P(None, None), "wo": three}
+            "kda_A_log": P(None, None), "kda_dt_bias": P(None, None),
+            "kda_norm_scale": P(None, None), "wo": three,
+            **dict.fromkeys(maps, three),
+            **({"kda_qk_scale": three} if cfg.kda_qk_norm else {})}
 
 
 def step_kernel_ok(cfg, fused: bool) -> bool:
@@ -234,29 +277,38 @@ def _gates(cfg, p, y):
     f32 = jnp.float32
     lead = y.shape[:-1]
 
-    def low_rank(first, second):
-        a = einsum_f32("btd,dr->btr", y, p[first].astype(y.dtype))
-        return jnp.dot(a, p[second].astype(f32), precision=HI)
+    def through(name):
+        """y through the decay's or the gate's map: a low-rank pair, or
+        (``kda_rank`` 0) the one full matrix."""
+        first = p[name + "1" if cfg.kda_rank else name]
+        a = einsum_f32("btd,dr->btr", y, first.astype(y.dtype))
+        if not cfg.kda_rank:
+            return a
+        return jnp.dot(a, p[name + "2"].astype(f32), precision=HI)
 
     beta = jax.nn.sigmoid(einsum_f32("btd,dh->bth", y,
                                      p["kda_wbeta"].astype(y.dtype)))
     if cfg.kda_neg_eigval:
         beta = 2.0 * beta
-    f = (low_rank("kda_wf1", "kda_wf2")
+    f = (through("kda_wf")
          + p["kda_dt_bias"].astype(f32)).reshape(lead + (H, D))
     A = jnp.exp(p["kda_A_log"].astype(f32))[:, None]
     g = cfg.kda_gate_floor * jax.nn.sigmoid(A * f) if cfg.kda_gate_floor \
         else -A * jax.nn.softplus(f)
-    return beta, g, low_rank("kda_wg1", "kda_wg2").reshape(lead + (H, D))
+    return beta, g, through("kda_wg").reshape(lead + (H, D))
 
 
-def _heads(cfg, u):
+def _heads(cfg, p, u):
     """The convs' float32 output (..., 3 inner) after silu as q, k
-    (L2-normed) and v, each (..., H, D) float32."""
+    (L2-normed; with ``kda_qk_norm`` behind the layer's gain a channel) and
+    v, each (..., H, D) float32."""
     H, D = cfg.kda_heads, cfg.kda_head_dim
     u = jax.nn.silu(u.astype(jnp.float32))
     q, k, v = (a.reshape(a.shape[:-1] + (H, D))
                for a in jnp.split(u, 3, axis=-1))
+    if cfg.kda_qk_norm:
+        gain = p["kda_qk_scale"].astype(jnp.float32)
+        q, k = q * gain[0], k * gain[1]
 
     def l2(a):
         return a * lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
@@ -414,7 +466,7 @@ def mix_chunk(cfg, p, y, S, conv, valid=None, fused: bool = False):
     acc = 0.0
     for j in range(K):                  # out_t = sum_j w_j in_{t-(K-1)+j}
         acc = acc + seq[:, j:j + T] * wc[:, j]
-    q, k, v = _heads(cfg, acc)
+    q, k, v = _heads(cfg, p, acc)
     beta, g, z = _gates(cfg, p, y)
     if valid is None:
         new_conv = seq[:, T:]
@@ -445,7 +497,7 @@ def mix_step(cfg, p, y, S, W, layer, length, fused: bool):
     conv = lax.dynamic_index_in_dim(W, layer, keepdims=False)
     win = jnp.concatenate([conv.astype(f32), u], axis=1)     # (B, K, 3 inner)
     acc = jnp.sum(win * p["kda_conv_w"].astype(f32).T, axis=1)
-    q, k, v = _heads(cfg, acc)
+    q, k, v = _heads(cfg, p, acc)
     beta, g, z = _gates(cfg, p, y)
     W = lax.dynamic_update_slice(W, jnp.where(
         live[:, None, None], win[:, 1:].astype(W.dtype), conv)[None],

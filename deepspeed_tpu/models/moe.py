@@ -164,6 +164,18 @@ def held_layout(idx, held: int, first_held: int, bm: int):
     return row_token, pair_row, pair_held, block_expert, used, counts
 
 
+def kept_groups(biased, n_group: int, topk_group: int):
+    """DeepSeek-V3's group-limited choice: ``biased`` (N, E) float32 scores
+    (the selection bias added) in ``n_group`` equal groups of neighbouring
+    experts; a group's score is the sum of its two best, the ``topk_group``
+    best groups are kept (of equal ones the first, as ``lax.top_k`` ranks).
+    Returns (N, n_group) bool."""
+    N, E = biased.shape
+    best2, _ = jax.lax.top_k(biased.reshape(N, n_group, E // n_group), 2)
+    _, groups = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+    return jnp.any(groups[..., None] == jnp.arange(n_group), axis=1)
+
+
 class MoETransformerLM(TransformerLM):
     """TransformerLM with the dense FFN replaced by an expert-parallel MoE
     bank in every layer (Mixtral-style; the reference interleaves dense/MoE
@@ -307,9 +319,12 @@ class MoETransformerLM(TransformerLM):
         Returns (idx (N, k) i32, weights (N, k) f32, the router's state).
 
         ``"sigmoid"`` (DeepSeek-V3 ``noaux_tc``): sigmoid scores; the top-k
-        of score + ``router_bias`` are chosen (one group); a chosen expert's
-        weight is its UNBIASED score, normalised over the chosen and scaled.
-        No state: None in, None out.
+        of score + ``router_bias`` are chosen — with ``moe_n_group`` > 1
+        among the experts of the ``moe_topk_group`` best groups alone
+        (:func:`kept_groups`: the group-limited choice, over ALL the router's
+        experts whichever are held here) — and a chosen expert's weight is
+        its UNBIASED score, normalised over the chosen and scaled. No state:
+        None in; out None, or with groups the groups kept (N, n_group) bool.
 
         ``"zaya"`` (ZAYA1, arXiv:2511.17127): ``r = y Wd + bd`` into
         ``router_hidden``; **depth averaging** ``s = r + gamma * state``,
@@ -324,12 +339,18 @@ class MoETransformerLM(TransformerLM):
         score = jax.nn.sigmoid(jnp.dot(
             yt.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        _, idx = jax.lax.top_k(
-            score + p["router_bias"].astype(jnp.float32), cfg.moe_top_k)
+        biased = score + p["router_bias"].astype(jnp.float32)
+        kept = None
+        if cfg.moe_n_group > 1:
+            kept = kept_groups(biased, cfg.moe_n_group, cfg.moe_topk_group)
+            biased = jnp.where(jnp.repeat(
+                kept, cfg.num_experts // cfg.moe_n_group, axis=-1),
+                biased, -jnp.inf)
+        _, idx = jax.lax.top_k(biased, cfg.moe_top_k)
         w = jnp.take_along_axis(score, idx, axis=-1)
         if cfg.moe_norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return idx.astype(jnp.int32), w * cfg.moe_routed_scale, None
+        return idx.astype(jnp.int32), w * cfg.moe_routed_scale, kept
 
     def _route_zaya(self, yt, p, state):
         f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
@@ -349,7 +370,8 @@ class MoETransformerLM(TransformerLM):
 
     BANKS = ("w_gate", "w_in", "w_out")
 
-    def experts(self, y, p, banks=None, layer=None, routed=None):
+    def experts(self, y, p, banks=None, layer=None, routed=None,
+                limits=None, live=None):
         """The routed expert layer on (B, T, d) (``routed``: the (idx, w) a
         caller that threads a router's state took from :meth:`route` itself;
         None: the stateless sigmoid router's, here): every token's k
@@ -370,19 +392,26 @@ class MoETransformerLM(TransformerLM):
         ALL k chosen and adds nothing for the absent: its part of the sum
         (the model-configs guide's chip's share).
 
+        ``limits``: the (routed, shared) clamps of the layer's segment
+        (``cfg.segment_limits``; None: ``swiglu_limit`` for both).
+
         Returns (out (B, T, d), stats, idx): ``stats`` f32 [most rows one
         expert got, experts touched, rows multiplied (padding included),
         rows that chose a held expert] — the counters the serving spans
-        carry (rows routed = B·T·k is static); ``idx`` (B, T, k) i32 the
-        experts chosen."""
+        carry (rows routed = B·T·k is static) — and with ``moe_n_group`` > 1
+        a fifth: the tokens (of the rows ``live`` (B,) bool marks; None:
+        all) whose kept groups hold an expert held here; ``idx`` (B, T, k)
+        i32 the experts chosen."""
         from ..ops.moe_matmul import block_rows, experts_swiglu
 
         cfg = self.cfg
         B, T, d = y.shape
         N, k = B * T, cfg.moe_top_k
         yt = y.reshape(N, d)
-        idx, w = routed if routed is not None else self.route(yt, p)[:2]
+        idx, w, kept = routed + (None,) if routed is not None \
+            else self.route(yt, p)
         bank = banks if banks is not None else p
+        limit, shared_limit = limits or (cfg.swiglu_limit,) * 2
         with jax.named_scope("moe_experts"):
             bm = block_rows(y.dtype)
             # every expert held (held_experts = E from 0 on) is the same
@@ -391,24 +420,35 @@ class MoETransformerLM(TransformerLM):
                 held_layout(idx, cfg.held_experts, cfg.moe_first_held, bm)
             out = experts_swiglu(yt[row_token], bank["w_gate"], bank["w_in"],
                                  bank["w_out"], block_expert, used, bm=bm,
-                                 layer=layer, limit=cfg.swiglu_limit)
+                                 layer=layer, limit=limit)
             # a row no block wrote is never read: pairs held elsewhere add 0
             rows = jnp.where(pair_held[:, None], out[pair_row], 0)
             routed = jnp.sum(rows.reshape(N, k, d).astype(jnp.float32)
                              * w[..., None], axis=1).astype(y.dtype)
-        stats = jnp.stack([jnp.max(counts), jnp.sum(counts > 0),
-                           used * bm, jnp.sum(counts)]).astype(jnp.float32)
-        return self._with_shared(routed, yt, p).reshape(B, T, d), stats, \
-            idx.reshape(B, T, k)
+        stats = [jnp.max(counts), jnp.sum(counts > 0), used * bm,
+                 jnp.sum(counts)]
+        if kept is not None:
+            # the groups that hold an expert held here, by the kept groups
+            size = cfg.num_experts // cfg.moe_n_group
+            first = cfg.moe_first_held // size
+            last = (cfg.moe_first_held + cfg.held_experts - 1) // size
+            here = jnp.any(kept[:, first:last + 1], axis=-1).reshape(B, T)
+            if live is not None:
+                here &= live[:, None]
+            stats.append(jnp.sum(here))
+        stats = jnp.stack(stats).astype(jnp.float32)
+        return (self._with_shared(routed, yt, p, shared_limit).reshape(
+            B, T, d), stats, idx.reshape(B, T, k))
 
-    def _with_shared(self, routed, yt, p):
-        """``routed`` (N, d) with the shared MLP's rows added, if any."""
+    def _with_shared(self, routed, yt, p, limit=None):
+        """``routed`` (N, d) with the shared MLP's rows added, if any;
+        ``limit``: its clamp (None: ``swiglu_limit``)."""
         if not self.cfg.moe_shared_d_ff:
             return routed
+        limit = self.cfg.swiglu_limit if limit is None else limit
         with jax.named_scope("moe_shared"):
             u = _swiglu(yt @ p["ws_gate"].astype(yt.dtype),
-                        lambda: yt @ p["ws_in"].astype(yt.dtype),
-                        self.cfg.swiglu_limit)
+                        lambda: yt @ p["ws_in"].astype(yt.dtype), limit)
             return routed + u @ p["ws_out"].astype(yt.dtype)
 
     def _fold_aux(self, aux):
@@ -441,7 +481,8 @@ class MoETransformerLM(TransformerLM):
         def dense(key, shape, scale):
             return jax.random.normal(key, shape, jnp.float32) * scale
 
-        for i, ((kind, L), layers) in enumerate(zip(cfg.segments, segs)):
+        for i, ((kind, L), layers, limits) in enumerate(zip(
+                cfg.segments, segs, cfg.segment_limits)):
             if kind != "moe":
                 continue
             # base init skips the dense FFN of an expert segment
@@ -450,7 +491,7 @@ class MoETransformerLM(TransformerLM):
                 layers.update(self._init_zaya_router(next(k), L))
             else:
                 layers["router"] = dense(next(k), (L, d, E), 0.02)
-            gain = clamp_gain(cfg)
+            gain = clamp_gain(cfg, limits[0])
             layers["w_in"] = dense(next(k), (L, Eh, d, f),
                                    gain / math.sqrt(d))
             layers["w_out"] = dense(next(k), (L, Eh, f, d),
@@ -468,6 +509,7 @@ class MoETransformerLM(TransformerLM):
                 layers["router_bias"] = dense(next(k), (L, E), 0.02)
             if cfg.moe_shared_d_ff:
                 fs = cfg.moe_shared_d_ff
+                gain = clamp_gain(cfg, limits[1])
                 layers["ws_in"] = dense(next(k), (L, d, fs),
                                         gain / math.sqrt(d))
                 layers["ws_gate"] = dense(next(k), (L, d, fs),

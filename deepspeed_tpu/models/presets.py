@@ -202,6 +202,55 @@ def solar_open2(size: str = "tiny", **overrides) -> TransformerConfig:
     return TransformerConfig(**base)
 
 
+def bailing_hybrid(size: str = "tiny", **overrides) -> TransformerConfig:
+    """Ling-3.0-flash family (``model_type: bailing_hybrid``): five layers in
+    six mix with Kimi Delta Attention behind the bounded gate, full maps for
+    the decay and the output gate (``kda_rank`` 0: ``no_kda_lora``) and a
+    learned gain on q and k (``kda_qk_norm``), the sixth is DeepSeek's latent
+    attention over every live position with rope on its rope part and an
+    output gate a head (``attn_out_gate='head'``); one residual stream; two
+    dense layers, then sigmoid experts chosen within the best groups
+    (``moe_n_group`` / ``moe_topk_group``) beside a shared one, the routed
+    and the shared clamped by a value a layer. ``"3.0-flash"`` is
+    inclusionAI/Ling-3.0-flash's ``config.json`` (42 layers ``KKKKKA`` seven
+    times, 32 heads of 128, 512 experts of 768 top-8 in 8 groups of which 4
+    are kept). ``"tiny"`` keeps a whole period behind a dense KDA layer at
+    unit-test size, four groups of four experts, both clamps on the last
+    layers."""
+    table = {
+        "tiny": dict(mixer_pattern="KKKKKKA", n_layer=7, n_head=4,
+                     d_model=64, d_ff=128, vocab_size=251, max_seq=512,
+                     kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, kda_heads=4,
+                     kda_head_dim=16, num_experts=16, moe_top_k=4,
+                     moe_n_group=4, moe_topk_group=2, moe_d_ff=32,
+                     moe_shared_d_ff=32, moe_first_dense=1,
+                     moe_swiglu_limits=(0, 0, 0, 0, 4, 4, 4),
+                     moe_shared_swiglu_limits=(0, 0, 0, 5, 5, 7, 7)),
+        "3.0-flash": dict(mixer_pattern=7 * "KKKKKA", n_layer=42, n_head=32,
+                          d_model=2560, d_ff=6144, vocab_size=157184,
+                          max_seq=262144, kv_lora_rank=512,
+                          qk_nope_head_dim=128, qk_rope_head_dim=64,
+                          v_head_dim=128, kda_heads=32, kda_head_dim=128,
+                          num_experts=512, moe_top_k=8, moe_n_group=8,
+                          moe_topk_group=4, moe_d_ff=768,
+                          moe_shared_d_ff=768, moe_first_dense=2,
+                          moe_swiglu_limits=35 * (0,) + 7 * (4,),
+                          moe_shared_swiglu_limits=34 * (0,) + 6 * (5,)
+                          + 2 * (7,)),
+    }
+    base = dict(attention="mla", pos_embedding="rope", norm="rmsnorm",
+                norm_eps=1e-6, activation="silu_glu", use_bias=False,
+                tie_embeddings=False, moe_router="sigmoid",
+                moe_norm_topk=True, moe_routed_scale=2.5, rope_theta=6e6,
+                kda_conv=4, kda_rank=0, kda_gate_floor=-5.0,
+                kda_qk_norm=True, attn_out_gate="head",
+                mla_query_init_gain=3.0, fused_xent=False)
+    base.update(table[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def nemotron_h(size: str = "3-super-120b-a12b", **overrides) -> TransformerConfig:
     """The NemotronH block (``model_type: nemotron_h``): every layer ONE
     mixer, its kind a letter of the published ``hybrid_override_pattern`` —

@@ -215,6 +215,12 @@ class TransformerConfig:
     # latent attention's query through a latent of its own (DeepSeek-V2/V3
     # ``q_lora_rank``): cq = RMSNorm(y wq_a), q = cq wq_b (0: one matrix wq)
     q_lora_rank: int = 0
+    # the latent attention's query matrix drawn this much wider at init: a
+    # trained head's softmax is peaked (a few keys take most of a query's
+    # weight); a random one's, scores of sd 1 over thousands of keys, is
+    # diffuse, its output an average near zero, and a path that drops or
+    # mis-rotates the rope then reads the same (PERF.md §6 "PR 62")
+    mla_query_init_gain: float = 1.0
     # learned sparse attention over a latent cache (DeepSeek-V3.2's, with
     # GLM-5.2's ``indexer_types``; ``model_type: glm_moe_dsa``,
     # models/dsa.py): layer i is ``index_pattern[i]`` — "F" has an indexer
@@ -247,8 +253,16 @@ class TransformerConfig:
     # inference/kinds/linear_sparse.py); with ``attention='mha'`` softmax
     # GQA over whole K/V planes, no position code, its output times
     # ``sigmoid(y w_ogate)`` a head a channel with ``attn_out_gate``
-    # (arXiv:2505.06708; the cache: inference/kinds/delta_gqa.py). "" is one
-    # mixer kind.
+    # (arXiv:2505.06708; the cache: inference/kinds/delta_gqa.py); with
+    # ``attention='mla'`` and NO ``index_pattern`` (Ling-3.0-flash,
+    # ``model_type: bailing_hybrid``) every live latent read, rope
+    # (``pos_embedding='rope'``) on the "A" layers' ``qk_rope_head_dim``
+    # alone, the output times ``sigmoid(y w_ogate)`` a head with
+    # ``attn_out_gate='head'`` (the cache: inference/kinds/delta_latent.py).
+    # ``kda_rank`` 0: the decay's and the gate's maps are full (d_model x
+    # heads x head_dim; ``no_kda_lora``); ``kda_qk_norm``: a learned gain a
+    # channel on every head's q and k before the L2 norm. "" is one mixer
+    # kind.
     mixer_pattern: str = ""
     kda_heads: int = 0
     kda_head_dim: int = 0
@@ -256,7 +270,8 @@ class TransformerConfig:
     kda_rank: int = 0
     kda_gate_floor: float = -5.0
     kda_neg_eigval: bool = False
-    attn_out_gate: bool = False
+    kda_qk_norm: bool = False
+    attn_out_gate: Any = False            # False | True (a channel) | "head"
     # manifold-constrained hyper-connections (mHC, arXiv:2512.24880;
     # models/mhc.py): ``hc_mult`` residual streams a token, each sub-layer
     # reading a mix of them and writing back through a doubly stochastic map
@@ -267,8 +282,20 @@ class TransformerConfig:
     hc_eps: float = 1e-6
     # gated FFNs clamp before the product (``swiglu_limit``): silu(min(gate,
     # limit)) * clip(up, -limit, limit), dense, shared and routed alike
-    # (0: no clamp)
+    # (0: no clamp). Or a value a LAYER, one list for the routed experts and
+    # one for the shared expert (Ling-3.0-flash's ``expert_swiglu_limit_list``
+    # / ``share_expert_swiglu_limit_list``; 0: that layer's are not clamped):
+    # a segment is then a run of layers equal in both values too, each a
+    # constant of its programs (:attr:`segment_limits`)
     swiglu_limit: float = 0.0
+    moe_swiglu_limits: tuple = ()
+    moe_shared_swiglu_limits: tuple = ()
+    # group-limited choice (DeepSeek-V3's ``n_group`` / ``topk_group``): the
+    # router's experts in ``moe_n_group`` equal groups, a group's score the
+    # sum of its two best biased scores, the ``moe_topk_group`` best groups
+    # kept and the top-k taken among their experts (1: one group)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
 
     @property
     def held_experts(self) -> int:
@@ -319,7 +346,7 @@ class TransformerConfig:
         if self.block_pattern:
             return tuple((kind, len(list(run)))
                          for kind, run in groupby(self.block_pattern))
-        return tuple((ffn, n) for (_, ffn), n in self._layer_runs())
+        return tuple((kind[1], n) for kind, n in self._layer_runs())
 
     @property
     def segment_attn(self) -> tuple:
@@ -328,14 +355,30 @@ class TransformerConfig:
         segment is a run of layers equal in mixer kind AND FFN kind."""
         if self.block_pattern:
             return ("",) * len(self.segments)
-        return tuple(attn for (attn, _), _ in self._layer_runs())
+        return tuple(kind[0] for kind, _ in self._layer_runs())
+
+    @property
+    def segment_limits(self) -> tuple:
+        """Each segment's (routed experts', shared expert's) clamp: the
+        layer's own with ``moe_swiglu_limits`` / ``moe_shared_swiglu_limits``
+        (an expert segment's), else ``swiglu_limit`` twice."""
+        if self.block_pattern:
+            return ((self.swiglu_limit,) * 2,) * len(self.segments)
+        return tuple(kind[2:] for kind, _ in self._layer_runs())
 
     def _layer_runs(self) -> list:
         k = min(self.moe_first_dense, self.n_layer) if self.num_experts > 1 \
             else self.n_layer
         pat = self.attn_pattern or self.mixer_pattern
-        kinds = [((pat[i] if pat else ""),
-                  "dense" if i < k else "moe") for i in range(self.n_layer)]
+        one = self.swiglu_limit
+
+        def limit(per_layer, i):
+            return float(per_layer[i]) if per_layer and i >= k else one
+
+        kinds = [((pat[i] if pat else ""), "dense" if i < k else "moe",
+                  limit(self.moe_swiglu_limits, i),
+                  limit(self.moe_shared_swiglu_limits, i))
+                 for i in range(self.n_layer)]
         return [(kind, len(list(run))) for kind, run in groupby(kinds)]
 
     @property
@@ -397,21 +440,25 @@ class TransformerConfig:
         if kind == "K":
             # q, k, v and the output; beta; the decay's and the gate's
             # low-rank pairs
+            # low-rank pairs (or, at kda_rank 0, full maps)
             inner = self.kda_heads * self.kda_head_dim
-            return (4 * d * inner + d * self.kda_heads
-                    + 2 * self.kda_rank * (d + inner))
+            maps = 2 * self.kda_rank * (d + inner) if self.kda_rank \
+                else 2 * d * inner
+            return 4 * d * inner + d * self.kda_heads + maps
+        vd = self.v_dim
+        gate = d * h * (1 if self.attn_out_gate == "head" else vd) \
+            if self.attn_out_gate else 0
         if self.attention == "mla":
             r, ql = self.kv_lora_rank, self.q_lora_rank
             q = ql * (d + h * self.head_dim) if ql else d * h * self.head_dim
             return (q + d * self.latent_dim
-                    + r * h * (self.qk_nope_head_dim + self.v_dim)
-                    + h * self.v_dim * d)
-        kv, hd, vd = self.attn_kv_heads(kind), self.head_dim, self.v_dim
+                    + r * h * (self.qk_nope_head_dim + vd)
+                    + h * vd * d + gate)
+        kv, hd = self.attn_kv_heads(kind), self.head_dim
         # cca: the grouped conv's one hd x hd matrix a head a tap (the
         # depthwise taps, like norms and biases, are left out)
         conv = self.cca_conv[1] * (h + kv) * hd * hd \
             if self.attention == "cca" else 0
-        gate = d * h * vd if self.attn_out_gate else 0
         return d * (h * hd) + d * kv * (hd + vd) + (h * vd) * d + conv + gate
 
     def _mixer_params_per_layer(self, kind: str, active_only: bool) -> int:
@@ -545,13 +592,15 @@ def _swiglu(gate, up, limit: float = 0.0):
     return act * (jnp.clip(up, -limit, limit) if limit else up)
 
 
-def clamp_gain(cfg) -> float:
+def clamp_gain(cfg, limit=None) -> float:
     """What a clamped gated FFN's gate and up matrices are drawn wider by
-    (and its down matrix narrower by the square): with ``swiglu_limit`` the
-    pre-activations of a unit-RMS input then have sd 0.6 of the limit, a
-    tenth of them beyond it as in a trained model that needs the clamp, so
-    that a path which drops the clamp reads differently. 1 with no limit."""
-    return 0.6 * cfg.swiglu_limit if cfg.swiglu_limit else 1.0
+    (and its down matrix narrower by the square): with ``swiglu_limit`` (or
+    ``limit``, a segment's own: ``cfg.segment_limits``) the pre-activations
+    of a unit-RMS input then have sd 0.6 of the limit, a tenth of them beyond
+    it as in a trained model that needs the clamp, so that a path which drops
+    the clamp reads differently. 1 with no limit."""
+    limit = cfg.swiglu_limit if limit is None else limit
+    return 0.6 * limit if limit else 1.0
 
 
 def exit_pdf(lam):
@@ -789,9 +838,31 @@ class TransformerLM:
             from .kda import check_config as check_kda
 
             check_kda(config)
-        if config.swiglu_limit and not config.is_glu:
+        limits = (config.moe_swiglu_limits, config.moe_shared_swiglu_limits)
+        if (config.swiglu_limit or any(limits)) and not config.is_glu:
             raise ValueError("swiglu_limit clamps a gated FFN's gate and up "
                              "(activation '*_glu')")
+        if any(limits) and (
+                config.swiglu_limit or config.moe_router != "sigmoid"
+                or not config.mixer_pattern
+                or any(len(per) != config.n_layer for per in limits)):
+            raise ValueError(
+                "moe_swiglu_limits / moe_shared_swiglu_limits give every "
+                "layer's clamp, the routed experts' and the shared expert's "
+                "(n_layer values each, in place of the one swiglu_limit): a "
+                "mixer_pattern trunk's sigmoid-routed expert layers'")
+        if (config.moe_n_group, config.moe_topk_group) != (1, 1) and (
+                config.moe_router != "sigmoid" or config.block_pattern
+                or config.num_experts % config.moe_n_group
+                or not 1 <= config.moe_topk_group <= config.moe_n_group
+                or config.moe_top_k > config.moe_topk_group
+                * (config.num_experts // config.moe_n_group)
+                or config.num_experts // config.moe_n_group < 2):
+            raise ValueError(
+                "moe_n_group / moe_topk_group are the sigmoid router's "
+                "group-limited choice (models/moe.py route): num_experts in "
+                "equal groups of two or more, 1 <= topk_group <= n_group, "
+                "and room for the top-k in the groups kept")
         if config.moe_router == "zaya" and (
                 config.attention != "cca" or config.num_experts < 2
                 or config.router_hidden < 1 or config.moe_top_k != 1
@@ -918,11 +989,12 @@ class TransformerLM:
             layers.update(init_kda(cfg, next(k), dense, L, depth))
         elif cfg.attention == "mla":
             r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+            wide = cfg.mla_query_init_gain
             layers.update({
                 "wq_a": dense(next(k), (L, d, ql)),
                 "q_norm_scale": jnp.ones((L, ql), jnp.float32),
-                "wq_b": dense(next(k), (L, ql, h * hd)),
-            } if ql else {"wq": dense(next(k), (L, d, h * hd))})
+                "wq_b": dense(next(k), (L, ql, h * hd)) * wide,
+            } if ql else {"wq": dense(next(k), (L, d, h * hd)) * wide})
             layers.update({
                 "wkv_a": dense(next(k), (L, d, cfg.latent_dim)),
                 "kv_norm_scale": jnp.ones((L, r), jnp.float32),
@@ -931,6 +1003,8 @@ class TransformerLM:
                 "wo": dense(next(k), (L, h * cfg.v_dim, d),
                             scale=1.0 / math.sqrt(2 * depth * d)),
             })
+            if cfg.attn_out_gate:
+                layers["w_ogate"] = dense(next(k), (L, d, self._gate_width()))
         elif cfg.attention == "cca":
             from .cca import init_params as init_cca
 
@@ -946,7 +1020,7 @@ class TransformerLM:
             if cfg.attn_out_gate:
                 # a pre-activation of sd 1 on a normed input: the gate
                 # stands in 0.27 .. 0.73 for most channels, no constant half
-                layers["w_ogate"] = dense(next(k), (L, d, h * cfg.v_dim))
+                layers["w_ogate"] = dense(next(k), (L, d, self._gate_width()))
             if attn == "S" and cfg.attn_sink:
                 from .windowed import SINK_INIT
 
@@ -993,6 +1067,11 @@ class TransformerLM:
                 layers["b_in"] = jnp.zeros((L, f), jnp.float32)
                 layers["b_out"] = jnp.zeros((L, d), jnp.float32)
         return layers
+
+    def _gate_width(self) -> int:
+        """Columns of ``w_ogate``: a value a head a channel, or a head."""
+        cfg = self.cfg
+        return cfg.n_head * (1 if cfg.attn_out_gate == "head" else cfg.v_dim)
 
     @staticmethod
     def segment_params(layers) -> tuple:
@@ -1053,7 +1132,7 @@ class TransformerLM:
         if attn == "K":
             from .kda import param_specs as kda_specs
 
-            layers.update(kda_specs())
+            layers.update(kda_specs(cfg))
         elif cfg.attention == "mla":
             # heads column-split as wq/wo are; the latent projection and
             # its norm are shared by all heads and stay replicated
@@ -1066,6 +1145,8 @@ class TransformerLM:
                 "kv_norm_scale": P(None, None),
                 "wkv_b": P(None, None, "model"), "wo": P(None, "model", None),
             })
+            if cfg.attn_out_gate:
+                layers["w_ogate"] = P(None, None, "model")
         elif cfg.attention == "cca":
             from .cca import param_specs as cca_specs
 

@@ -41,7 +41,11 @@ joins 24 accepted metrics (among them the Nemotron test's
 own metrics list it alone and ``test_glm5_next.py`` pins that its cell is
 the LAST of the 24 lists it joined: since PR 57 the Solar-Open2 cell stands
 behind it in 13 of them, and ``g53_spec`` below takes it out;
-``test_solar_open2.py`` pins no position. The Ouro test has no
+``test_solar_open2.py`` pins no position, nor does
+``test_bailing_hybrid.py`` (since PR 62 the Ling-3.0-flash cell stands behind
+the Solar-Open2 cell in 29 lists, and behind Kanana's in
+``mla_decode_attention_roofline``: a cell ``workloads`` appends last, which
+every fixture here takes out). The Ouro test has no
 such pin and reads the file whole. ``test_contract.py`` holds every entry. A
 ``benchmark`` PR should loosen the ``[-5:]`` and ``[-1]`` pins and take these
 fixtures away.
@@ -66,9 +70,11 @@ pytest.register_assert_rewrite(
     "benchmark.tests.test_mimo_v2_flash", "benchmark.tests.test_zaya",
     "benchmark.tests.test_falcon_h1", "benchmark.tests.test_glm_moe_dsa",
     "benchmark.tests.test_glm5_next", "benchmark.tests.test_solar_open2",
+    "benchmark.tests.test_bailing_hybrid",
     "benchmark.tests.test_program_lifecycle",
     "benchmark.tests.test_program_iterations")
 
+from benchmark.tests.test_bailing_hybrid import *  # noqa: E402,F401,F403
 from benchmark.tests.test_contract import *  # noqa: E402,F401,F403
 from benchmark.tests.test_deepseek_v3 import *  # noqa: E402,F401,F403
 from benchmark.tests.test_falcon_h1 import *  # noqa: E402,F401,F403
@@ -135,8 +141,9 @@ def test_the_seven_entries_read_the_record(monkeypatch):  # noqa: F811
     """``test_program_iterations.py``'s test of that name, which pins the
     lists of PR 53's seven metrics whole (it reads the file itself, through
     no fixture): since PR 57 the Solar-Open2 cell stands behind the two
-    backlog cells in four of them. Run here on the file without the cells
-    ``workloads`` appends after the GLM-5.3-Flash cell."""
+    backlog cells in four of them, since PR 62 the Ling-3.0-flash cell
+    behind that. Run here on the file without the cells ``workloads``
+    appends after the GLM-5.3-Flash cell."""
     from benchmark.tests import test_program_iterations as pinned
 
     load = json.load
@@ -150,9 +157,9 @@ def test_the_seven_entries_read_the_record(monkeypatch):  # noqa: F811
     pinned.test_the_seven_entries_read_the_record()
     with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
         spec = load(f)
-    cell = spec["workloads"][-1]["name"]
-    assert cell.startswith("solar-open2") and all(
-        m["workloads"][-1] == cell for m in spec["per_layer"]
+    cells = [w["name"] for w in spec["workloads"][-2:]]
+    assert [c.split("-")[0] for c in cells] == ["solar", "ling"] and all(
+        m["workloads"][-2:] == cells for m in spec["per_layer"]
         if m["name"].startswith(("host.stall_ms.inside",
                                  "host.stall_ms.program",
                                  "host.stall_ms.machine",

@@ -16,11 +16,12 @@ from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
                                             cache_layout, init_cache,
                                             state_bytes_per_slot)
 from deepspeed_tpu.inference.kinds import (CCA, FEATURES, KINDS, DeltaGQA,
-                                           Dense, Hybrid, Latent,
+                                           DeltaLatent, Dense, Hybrid, Latent,
                                            LinearSparse, PagedKVCache,
                                            ParallelHybrid, SparseLatent,
                                            Windowed, kind_of)
-from deepspeed_tpu.models import (deepseek_v3, falcon_h1, glm5_next,
+from deepspeed_tpu.models import (bailing_hybrid, deepseek_v3, falcon_h1,
+                                  glm5_next,
                                   glm_moe_dsa, mimo_v2_flash, nemotron_h,
                                   ouro, presets, solar_open2, tiny_test,
                                   why_not_trained, zaya)
@@ -53,6 +54,8 @@ CASES = {
         "tiny", dtype=F32, moe_experts_held=2)),
     "delta-gqa": (DeltaGQA, lambda: solar_open2(
         "tiny", dtype=F32, moe_experts_held=2)),
+    "delta-latent": (DeltaLatent, lambda: bailing_hybrid(
+        "tiny", dtype=F32, moe_experts_held=4)),
 }
 CONTIGUOUS = [name for name in CASES if name != "paged"]
 SLOTS, MAX_LEN = 2, 128
@@ -242,6 +245,8 @@ PRESETS = [(fn, size) for fn, sizes in (
     (presets.falcon_h1, ("tiny", "34b")),
     (presets.glm_moe_dsa, ("tiny", "5.2")),
     (presets.glm5_next, ("tiny", "5.3-flash")),
+    (presets.solar_open2, ("tiny", "250b")),
+    (presets.bailing_hybrid, ("tiny", "3.0-flash")),
     (presets.tiny_test, (None,))) for size in sizes]
 
 

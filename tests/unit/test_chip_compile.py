@@ -1613,3 +1613,116 @@ def test_delta_gqa_trunk_fits_and_moves_its_state_in_place(
                        and " = " in ln and buf in ln
                        and not any(p in ln for p in passes)]
             assert not touched, touched[:3]
+
+
+LING = {"slots": 160, "max_len": 24576, "chunk": 512}
+
+
+@pytest.fixture(scope="module")
+def ling(one_chip):
+    """Ling-3.0-flash's share (layers K K K K K A, group 0's 64 of 512
+    experts): built once for the three programs."""
+    from benchmark.models import bailing_hybrid as fam
+
+    return _served_share(one_chip, fam, "ling-3.0-flash-l6-e64")
+
+
+@pytest.mark.parametrize("program", ["slot step", "final chunk", "chunk"])
+def test_delta_latent_trunk_fits_and_moves_its_state_in_place(
+        one_chip, monkeypatch, ling, program, capsys):
+    """Ling-3.0-flash's share at the cell's 160 slots x 24 576, chunks of
+    512: the three buffers enter donated and leave aliased; every program's
+    live set beside what else stands on the chip (the slots' state beside a
+    chunk, the batch-1 prefill cache beside the step) stays under 14.0 GiB
+    of the chip's 15.75; the step holds two calls of the state step (a scan
+    of four KDA layers and the fifth, whose shared expert is clamped
+    otherwise: a run of its own), one each of ``mla_cache_append`` and
+    ``mla_decode_attention`` (Kanana's kernels at Kanana's shapes), and NO
+    other operation touches the delta-rule state or the latents; a chunk,
+    final or not, scans its KDA layers in two calls of ``kda_chunk_scan``
+    (Mosaic takes the kernel at 32 heads of 128) and walks the live latents
+    in XLA with no (32, 512, 24 576) score array; three runs of expert
+    layers, each its own clamps a constant of the program."""
+    import time
+
+    from deepspeed_tpu.inference.decode import (cache_bytes_per_token,
+                                                forward_with_cache,
+                                                init_cache,
+                                                state_bytes_per_slot)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    g = LING
+    cfg, model, params = ling
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    assert cache_bytes_per_token(cfg, jnp.bfloat16) == 1152
+    assert state_bytes_per_slot(cfg, jnp.bfloat16) == 10854400 \
+        == 5 * (32 * 128 * 128 * 4 + 3 * 3 * 4096 * 2)
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 2756028448 \
+        and abs(weights / 2 ** 30 - 5.15) < 0.01
+    a_slot = g["max_len"] * 1152 + 10854400
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    t0 = time.perf_counter()
+    if program == "slot step":
+        state = on_chip(jax.eval_shape(lambda: init_slots(
+            cfg, g["slots"], g["max_len"], jnp.bfloat16)))
+        assert state.cache.kda.shape == (5, 160, 32, 128, 128) \
+            and state.cache.c.shape == (1, 160, 576, 24576) \
+            and state.cache.conv.shape == (5, 160, 3, 12288)
+        compiled = jax.jit(lambda p, c: decode_step(
+            model, p, c, flash_decode=True, logit_guard=True, moe_stats=True,
+            sampler=partial(sample_logits, temperature=1.0)),
+            donate_argnums=(1,)).lower(params, state).compile()
+        held, beside = g["slots"] * a_slot, a_slot
+    else:
+        ids = jax.ShapeDtypeStruct((1, g["chunk"]), jnp.int32,
+                                   sharding=one_chip)
+        cache = on_chip(jax.eval_shape(
+            lambda: init_cache(cfg, 1, g["max_len"], jnp.bfloat16)))
+        final = program == "final chunk"
+        compiled = jax.jit(
+            lambda p, c, ids, start, last: forward_with_cache(
+                model, p, ids, c._replace(length=start), flash_decode=True,
+                last_token_head=final, last_index=last if final else None,
+                with_stats=True, with_routing=True),
+            donate_argnums=(1,)).lower(params, cache, ids, i32,
+                                       i32).compile()
+        held, beside = a_slot, g["slots"] * a_slot
+    took = time.perf_counter() - t0
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    with capsys.disabled():
+        print(f"\n[delta latent {program}: compiled for a described v5e in "
+              f"{took:.1f} s; arguments {mem.argument_size_in_bytes / 1e9:.3f}"
+              f" GB, aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB; with "
+              f"what stands beside it {(live + beside) / 2 ** 30:.2f} GiB]")
+    assert mem.alias_size_in_bytes >= held             # donated, in place
+    assert live + beside < 14.0 * 2 ** 30, (live + beside) / 2 ** 30
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    count = {k: sum(f"/{k}/pallas_call" in ln for ln in calls) for k in (
+        "kda_state_step", "kda_chunk_scan", "mla_cache_append",
+        "mla_decode_attention", "moe_experts_up", "moe_experts_down")}
+    step = program == "slot step"
+    assert count == {"kda_state_step": 2 if step else 0,
+                     "kda_chunk_scan": 0 if step else 2,
+                     "mla_cache_append": 1 if step else 0,
+                     "mla_decode_attention": 1 if step else 0,
+                     "moe_experts_up": 3, "moe_experts_down": 3}, count
+    assert not re.search(r"f32\[(1,)?32,512,24576\]", text)
+    assert "f32[1,8,32,64,64]" not in text      # the XLA scan's inverse
+    if step:
+        passes = ("custom-call(", "parameter(", "get-tuple-element(",
+                  " tuple(", "while(", "bitcast(")
+        for buf in ("f32[5,160,32,128,128]", "bf16[1,160,576,24576]"):
+            touched = [ln for ln in text.splitlines()
+                       if ln.lstrip().startswith(("%", "ROOT"))
+                       and " = " in ln and buf in ln
+                       and not any(p in ln for p in passes)]
+            assert not touched, touched[:3]
