@@ -573,11 +573,13 @@ def test_latent_and_expert_kernels(one_chip, kernel):
     D, bf, i32 = k["rank"] + k["rope"], jnp.bfloat16, jnp.int32
     cache = ((k["L"], k["slots"], D, k["S"]), bf)
     if kernel == "mla_decode_attention":
-        _compile(lambda q, c, n, layer: mla_decode_attention(
-            q, c, n, layer=layer[0], rank=k["rank"], scale=0.072,
-            interpret=False), one_chip,
-            ((k["slots"], k["H"], D), bf), cache, ((k["slots"],), i32),
-            ((1,), i32))
+        # Kanana's cache, and Ling's one latent layer of 160 slots x 24 576
+        for L, slots, S in ((k["L"], k["slots"], k["S"]), (1, 160, 24576)):
+            _compile(lambda q, c, n, layer: mla_decode_attention(
+                q, c, n, layer=layer[0], rank=k["rank"], scale=0.072,
+                interpret=False), one_chip,
+                ((slots, k["H"], D), bf), ((L, slots, D, S), bf),
+                ((slots,), i32), ((1,), i32))
     elif kernel == "latent_append":
         _compile(lambda c, new, n, layer: latent_append(
             c, new, n, layer=layer[0], interpret=False), one_chip,
@@ -1270,7 +1272,10 @@ def test_held_experts_take_rows_of_6144(one_chip):
 
 # sha256 of the lowered text with every kernel's serialized body taken out
 # (a body carries its source's path and lines), taken at the parent of PR 51
-# (commit 2545243) by this very function
+# (commit 2545243) by this very function. PR 63 changed the step's
+# ``mla_decode_attention`` call (one program a slot, the cache in HBM, two
+# buffers and a semaphore each): grid, scratch and memory spaces are all
+# inside the body this text leaves out, so "slot step" stands to the digit
 KANANA_TEXT = {"slot step": "c8fe8b56f27a7bb2", "chunk": "81451999b8e18582"}
 
 

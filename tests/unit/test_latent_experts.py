@@ -129,11 +129,91 @@ def test_absorbed_step_equals_the_expanded_path_on_the_same_cache(tiny):
                                                             lengths))
         kernel = mla.absorb_o(cfg, p, mla_decode_attention(
             q, appended, lengths, layer=1, rank=cfg.kv_lora_rank,
-            scale=mla.softmax_scale(cfg), block=128))
+            scale=mla.softmax_scale(cfg)))
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(expanded),
                                atol=1e-5)
+
+
+# (dtype, H, rank, rope, blocks of 128 in the cache, blocks a turn): two
+# blocks a turn over a cache of seven (the width does not divide it), in
+# both dtypes; heads that fill no sublane tile; three blocks a turn over
+# eleven; one a turn (no lane is ever stale, nothing is zeroed); and the
+# published widths under the module's own byte target
+WALKS = {
+    "float32": (jnp.float32, 4, 32, 8, 7, 2),
+    "bfloat16": (jnp.bfloat16, 4, 32, 8, 7, 2),
+    "five heads": (jnp.float32, 5, 32, 8, 7, 2),
+    "three a turn": (jnp.float32, 4, 32, 8, 11, 3),
+    "one a turn": (jnp.float32, 4, 32, 8, 3, 1),
+    "published widths": (jnp.bfloat16, 32, 512, 64, 10, None),
+}
+
+
+@pytest.mark.parametrize("check", ["reference", "neighbours", "idle"])
+@pytest.mark.parametrize("walk", WALKS)
+def test_the_step_s_kernel_walks_each_slot_s_live_latents(monkeypatch, walk,
+                                                          check):
+    """``mla_decode_attention`` against ``mla.attend_absorbed`` on one cache
+    with ragged lengths a slot, at a layer other than 0: nothing, one
+    position, around a block's edge, one turn exactly, one turn and a
+    position, several turns, the whole cache and a length past it (clamped).
+    ``neighbours``: a slot's output is bit-equal whatever stands beside it;
+    ``idle``: a slot at length 0 gives zeros, and costs its neighbours
+    nothing (behind it a program fetches for itself)."""
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.ops import mla_attention
+
+    dtype, H, rank, rope, blocks, W = WALKS[walk]
+    D, S = rank + rope, blocks * 128
+    if W is None:
+        W = mla_attention.turn_blocks(D, S, dtype)
+        assert 1 < W < blocks
+    else:
+        monkeypatch.setattr(mla_attention, "_TURN_BYTES",
+                            W * D * 128 * jnp.dtype(dtype).itemsize)
+        assert mla_attention.turn_blocks(D, S, dtype) == W
+    turn = W * 128
+    cfg = SimpleNamespace(kv_lora_rank=rank, qk_nope_head_dim=24,
+                          qk_rope_head_dim=rope)
+    lengths = [0, 1, 127, 128, 129, turn, min(turn + 1, S),
+               min(2 * turn + 77, S - 5), S, S + 300]
+    B = len(lengths)
+    rng = np.random.default_rng(5)
+    cache = jnp.asarray(rng.normal(size=(2, B, D, S)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
+
+    def kernel(q, cache, n):
+        return np.asarray(mla_attention.mla_decode_attention(
+            q, cache, jnp.asarray(n, jnp.int32), layer=1, rank=rank,
+            scale=mla.softmax_scale(cfg)).astype(jnp.float32))
+
+    with jax.default_matmul_precision("highest"):
+        got = kernel(q, cache, lengths)
+        if check == "reference":
+            # (in float32 over the same values: in bf16 the reference
+            # rounds its scores, the kernel only ``p`` and the result)
+            want = np.asarray(mla.attend_absorbed(
+                cfg, q.astype(jnp.float32), cache[1].astype(jnp.float32),
+                jnp.asarray(lengths)))
+            tol = dict(atol=1e-5) if dtype == jnp.float32 \
+                else dict(atol=2e-2, rtol=2e-2)
+            np.testing.assert_allclose(got[1:], want[1:], **tol)
+        elif check == "neighbours":
+            for b in (2, 6, 7, 9):
+                alone = kernel(q[b:b + 1], cache[:, b:b + 1], lengths[b:b + 1])
+                np.testing.assert_array_equal(alone[0], got[b])
+            # the same slots behind a slot that is not running, which fetches
+            # nothing ahead, and in front of one
+            idle = kernel(q, cache, [0 if b % 2 else n
+                                     for b, n in enumerate(lengths)])
+            np.testing.assert_array_equal(idle[::2], got[::2])
+        else:
+            np.testing.assert_array_equal(got[0], np.zeros((H, rank)))
+            np.testing.assert_array_equal(
+                kernel(q, cache, [0] * B), np.zeros((B, H, rank)))
 
 
 def expert_layer(model, params):
